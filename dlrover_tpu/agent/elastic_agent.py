@@ -14,6 +14,7 @@ import os
 import signal
 import subprocess
 import sys
+import tempfile
 import threading
 import time
 from dataclasses import dataclass
@@ -55,6 +56,11 @@ class WorkerProc:
     process_id: int
     proc: subprocess.Popen
     log_path: str
+    # where this incarnation's output starts: the file is appended to
+    # across restarts, and what an earlier worker wrote (the runtime
+    # prints "SIGTERM received" when the agent stops it) is not this
+    # worker's failure signature
+    log_start: int = 0
 
 
 class ElasticAgent:
@@ -67,7 +73,8 @@ class ElasticAgent:
         self._config = config
         self._client = client or MasterClient.singleton_instance()
         self._log_dir = log_dir or os.path.join(
-            "/tmp", "dlrover_tpu_logs", config.job_name, f"node-{config.node_id}"
+            tempfile.gettempdir(), "dlrover_tpu_logs", config.job_name,
+            f"node-{config.node_id}",
         )
         os.makedirs(self._log_dir, exist_ok=True)
         self._node_ip = local_ip()
@@ -239,6 +246,10 @@ class ElasticAgent:
                 logger.info("node %s: workers succeeded", self._config.node_id)
                 self._client.report_succeeded()
                 if self._ckpt_saver is not None:
+                    # the job's last checkpoint: copied, but its fanout
+                    # and commit may still be running on the saver's
+                    # daemon thread, which dies with this process
+                    self._ckpt_saver.drain()
                     self._ckpt_saver.cleanup_shm()
                 return 0
             if result == RunResult.AGENT_STOPPED:
@@ -435,6 +446,7 @@ class ElasticAgent:
                 f"worker-{process_id}-restart{self._restart_count}.log",
             )
             log_file = open(log_path, "ab")
+            log_start = os.path.getsize(log_path)
             cmd = [sys.executable, self._config.entrypoint] + list(
                 self._config.entrypoint_args
             )
@@ -446,7 +458,9 @@ class ElasticAgent:
                 start_new_session=True,
             )
             log_file.close()
-            self._workers.append(WorkerProc(local_rank, process_id, proc, log_path))
+            self._workers.append(
+                WorkerProc(local_rank, process_id, proc, log_path, log_start)
+            )
             logger.info(
                 "node %s: started worker process_id=%s pid=%s log=%s",
                 self._config.node_id,
@@ -487,15 +501,15 @@ class ElasticAgent:
             return ""
         per = max(512, max_bytes // len(workers))
         return "\n".join(
-            t for t in (self._tail_log(w.log_path, per) for w in workers) if t
+            t for t in (self._tail_log(w, per) for w in workers) if t
         )
 
-    def _tail_log(self, path: str, max_bytes: int = 4096) -> str:
+    def _tail_log(self, worker: WorkerProc, max_bytes: int = 4096) -> str:
         try:
-            with open(path, "rb") as f:
+            with open(worker.log_path, "rb") as f:
                 f.seek(0, os.SEEK_END)
                 size = f.tell()
-                f.seek(max(0, size - max_bytes))
+                f.seek(max(worker.log_start, size - max_bytes))
                 return f.read().decode(errors="replace")
         except OSError:
             return ""
@@ -516,7 +530,7 @@ class ElasticAgent:
             states = [(w, w.proc.poll()) for w in self._workers]
             failed = next((s for s in states if s[1] not in (None, 0)), None)
             if failed is not None:
-                err = self._tail_log(failed[0].log_path)
+                err = self._tail_log(failed[0])
                 return RunResult.FAILED, failed[1] or 1, err
             if all(code == 0 for _, code in states):
                 return RunResult.SUCCEEDED, 0, ""

@@ -12,6 +12,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
+import tempfile
 import threading
 from typing import Optional
 
@@ -27,7 +28,8 @@ PARAL_CONFIG_PATH_ENV = flags.PARAL_CONFIG_PATH.name
 
 def default_config_path(job_name: str, node_id: int) -> str:
     return os.path.join(
-        "/tmp", "dlrover_tpu", job_name, f"node-{node_id}", "paral_config.json"
+        tempfile.gettempdir(), "dlrover_tpu", job_name, f"node-{node_id}",
+        "paral_config.json",
     )
 
 

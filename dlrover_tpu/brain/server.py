@@ -10,7 +10,9 @@ masters connect via ``BrainResourceOptimizer``
 from __future__ import annotations
 
 import argparse
+import os
 import sys
+import tempfile
 import threading
 
 from dlrover_tpu.brain import messages as bmsg
@@ -100,7 +102,10 @@ class BrainServer:
 def main(argv=None) -> int:
     p = argparse.ArgumentParser("dlrover_tpu brain")
     p.add_argument("--port", type=int, default=50051)
-    p.add_argument("--db", default="/tmp/dlrover_tpu_brain.db")
+    p.add_argument(
+        "--db",
+        default=os.path.join(tempfile.gettempdir(), "dlrover_tpu_brain.db"),
+    )
     p.add_argument(
         "--watch_cluster", action="store_true",
         help="poll k8s pods into cluster_state so optimize() sees cluster "

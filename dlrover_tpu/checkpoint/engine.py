@@ -1475,6 +1475,11 @@ class CheckpointEngine:
             self.wait_staging(timeout=300)
         except Exception as e:
             logger.warning("in-flight staging failed at close: %s", e)
+        # durability flush: the last queued persist reads this process's
+        # shm, and a finished job's agent unlinks it — leave only once
+        # the saver has copied the step, or the job's final checkpoint
+        # may never reach storage
+        self._wait_pending_persist(timeout=300.0)
         if self._event_queue is not None:
             self._event_queue.close()
         if self._shm_lock is not None:

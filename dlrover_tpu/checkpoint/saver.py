@@ -75,7 +75,7 @@ TRACKER_FILE = CheckpointConstant.TRACKER_FILE
 
 @dataclass
 class CheckpointEvent:
-    event_type: str  # "save" | "exit"
+    event_type: str  # "save" | "backup" | "flush" | "exit"
     step: int = -1
     persist: bool = False  # False = memory-only snapshot
     ckpt_dir: str = ""
@@ -553,6 +553,8 @@ class AsyncCheckpointSaver:
         self._event_queue: Optional[SharedQueue] = None
         self._thread: Optional[threading.Thread] = None
         self._stop_evt = threading.Event()
+        # drain(): flush token -> set when the event loop reaches it
+        self._flushes: Dict[int, threading.Event] = {}
 
     def start(self):
         self._ipc.start()
@@ -696,6 +698,32 @@ class AsyncCheckpointSaver:
         finally:
             lock.release()
 
+    def drain(self, timeout: float = 600.0) -> bool:
+        """Durability flush for a finished job: block until every persist
+        queued so far has been copied, fanned out AND committed. The
+        trainer is released as soon as the shm copy is done; the fanout
+        and the commit run on after it, on a daemon thread that dies
+        with the agent — without this wait a job's last checkpoint is
+        copied but never committed, and a restart resumes from the one
+        before. A ``flush`` event goes onto the same FIFO queue; the
+        single-threaded event loop reaches it only after it has handled
+        everything queued ahead of it."""
+        if self._thread is None or not self._thread.is_alive():
+            return True  # no event loop, nothing in flight
+        flushed = threading.Event()
+        token = id(flushed)
+        self._flushes[token] = flushed
+        # the server-side queue, put in-process: the queue client is the
+        # event loop's (one socket, one request at a time)
+        self._ipc.state.get_queue(CKPT_EVENT_QUEUE).put(
+            CheckpointEvent("flush", step=token).to_wire()
+        )
+        if flushed.wait(timeout):
+            return True
+        self._flushes.pop(token, None)
+        logger.warning("checkpoint saver still busy after %.0fs", timeout)
+        return False
+
     def cleanup_shm(self):
         """Unlink staged segments (only after a successful job end)."""
         for h in self.persister.local_handlers():
@@ -716,6 +744,11 @@ class AsyncCheckpointSaver:
             event = CheckpointEvent.from_wire(raw)
             if event.event_type == "exit":
                 return
+            if event.event_type == "flush":
+                flushed = self._flushes.pop(event.step, None)
+                if flushed is not None:
+                    flushed.set()
+                continue
             if event.event_type == "backup":
                 try:
                     self._push_replica(step_hint=event.step)

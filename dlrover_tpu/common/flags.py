@@ -197,12 +197,6 @@ FUSED_CE = _define(
     "dispatcher falls back to the chunked path regardless. Read at "
     "trace time.",
 )
-BENCH_STALE_HOURS = _define(
-    "DLROVER_TPU_BENCH_STALE_HOURS", 168.0, "float",
-    "Staleness horizon (hours) for the cached BENCH_TPU_LAST.json "
-    "headline bench re-reports on CPU-only hosts: older entries get "
-    "stale=true and an age warning instead of a silent re-report.",
-)
 COMM_METRICS_PORT = _define(
     "DLROVER_TPU_COMM_METRICS_PORT", None, "int",
     "Worker /metrics port for the per-collective comm ledger "
@@ -346,7 +340,7 @@ TRACE_DIR = _define(
     "DLROVER_TPU_TRACE_DIR", "", "str",
     "Directory where traced processes dump their span ring at exit "
     "(trace-<role>-*.json, merged by `profiler.analysis job-timeline`)."
-    " Empty: /tmp/dlrover_tpu_logs/<job>/traces.",
+    " Empty: <TMPDIR>/dlrover_tpu_logs/<job>/traces.",
 )
 TRACE_RING_CAP = _define(
     "DLROVER_TPU_TRACE_RING_CAP", 200_000, "int",

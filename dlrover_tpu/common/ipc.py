@@ -17,6 +17,7 @@ import os
 import queue
 import socket
 import socketserver
+import tempfile
 import threading
 import time
 from typing import Any, Dict, Optional
@@ -25,7 +26,10 @@ from dlrover_tpu.common.log import logger
 
 
 def default_socket_path(job_name: str, node_id: int) -> str:
-    d = f"/tmp/dlrover_tpu/{job_name}/node-{node_id}"
+    # under TMPDIR like the agent's logs; an AF_UNIX path holds 107 bytes
+    d = os.path.join(
+        tempfile.gettempdir(), "dlrover_tpu", job_name, f"node-{node_id}"
+    )
     os.makedirs(d, exist_ok=True)
     return os.path.join(d, "ipc.sock")
 

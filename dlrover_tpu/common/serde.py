@@ -25,7 +25,7 @@ class UnknownMessageError(ValueError):
     ``ValueError`` so pre-existing ``except ValueError`` sites keep
     working, but carries ``type_name`` so dispatch/decode paths can
     degrade deliberately (servers answer ``SimpleResponse``, clients
-    raise the typed taxonomy error — see rpc/policy.py) instead of
+    raise the typed classification error — see rpc/policy.py) instead of
     surfacing a raw parse error. wirecheck WC003 requires every
     ``deserialize`` call site outside this module to handle it."""
 

@@ -80,14 +80,25 @@ class CheckpointStorage(ABC):
 class PosixDiskStorage(CheckpointStorage):
     """Local disk / NFS / FUSE-mounted GCS."""
 
-    def write(self, content: bytes, path: str):
+    def _write_atomically(self, path: str, fill):
         os.makedirs(os.path.dirname(path), exist_ok=True)
         tmp = f"{path}.tmp.{os.getpid()}"
         with open(tmp, "wb") as f:
-            f.write(content)
+            fill(f)
             f.flush()
             os.fsync(f.fileno())
         os.replace(tmp, path)
+
+    def write(self, content: bytes, path: str):
+        self._write_atomically(path, lambda f: f.write(content))
+
+    def put_file(self, src_path: str, path: str):
+        """Streamed, not read whole: a leaf file can be a gigabyte and
+        the fanout copies several at once."""
+        with open(src_path, "rb") as src:
+            self._write_atomically(
+                path, lambda f: shutil.copyfileobj(src, f, 16 << 20)
+            )
 
     def read(self, path: str) -> bytes:
         with open(path, "rb") as f:

@@ -53,7 +53,8 @@ PEER_FAILURE_PATTERNS = (
     r"Failed to send RPC to coordination service",
 )
 RETRYABLE_PATTERNS = (
-    r"RESOURCE_EXHAUSTED|out of memory|OOM",
+    # matched ignoring case: OOM as a word, or "headroom" is an OOM
+    r"RESOURCE_EXHAUSTED|out of memory|\bOOM\b",
     r"UNAVAILABLE|DEADLINE_EXCEEDED",
     r"coordination service|heartbeat",
 )
@@ -106,7 +107,14 @@ class CheckTrainingHangOperator(InferenceOperator):
 
 
 class CheckFailureNodeOperator(InferenceOperator):
-    """Scan reported training logs for failure signatures per node."""
+    """Scan reported training logs for failure signatures per node.
+    One report is judged once: the latest record stays the latest until
+    the agent ships the next, and a restart answered every cycle with
+    another restart never lets the worker reach its first step."""
+
+    def __init__(self, data_manager: DiagnosisDataManager):
+        super().__init__(data_manager)
+        self._judged: dict = {}  # node_id -> timestamp of the last record
 
     def is_compatible(self, inference: Inference) -> bool:
         return inference == FAILURE_PROBLEM
@@ -116,6 +124,9 @@ class CheckFailureNodeOperator(InferenceOperator):
         for node_id, rec in self._data_manager.latest_per_node(
             DiagnosisDataType.TRAINING_LOG
         ).items():
+            if self._judged.get(node_id) == rec.timestamp:
+                continue
+            self._judged[node_id] = rec.timestamp
             kind = classify_log(rec.data_content)
             if kind is None:
                 continue

@@ -294,7 +294,7 @@ class LoopbackClient:
                 decoded = deserialize(wire)
             except UnknownMessageError as e:
                 # RpcClient._call parity: a response type this side
-                # cannot decode maps to the typed taxonomy error, never
+                # cannot decode maps to the typed classification error, never
                 # a raw ValueError — and the harness counts it (the
                 # version_skew verdict gates decode_errors at zero)
                 if self._stats:

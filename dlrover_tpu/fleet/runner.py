@@ -32,6 +32,7 @@ import contextlib
 import hashlib
 import json
 import os
+import tempfile
 import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional, Tuple
@@ -174,7 +175,7 @@ class FleetRunner:
                 f"(scenario has parallelism={scenario.parallelism})"
             )
         self.out_dir = out_dir or os.path.join(
-            "/tmp", "dlrover_tpu_fleet", scenario.name
+            tempfile.gettempdir(), "dlrover_tpu_fleet", scenario.name
         )
         os.makedirs(self.out_dir, exist_ok=True)
         #: armed BEFORE anything below constructs a lock: the gate,

@@ -8,7 +8,7 @@ advances a virtual clock tick by tick, so a 25-virtual-minute job with a
 preemption storm replays in well under a real minute on CPU, and the
 verdict is deterministic given ``seed``.
 
-Fault taxonomy (``FaultEvent.kind``):
+Fault classification (``FaultEvent.kind``):
 
 - ``preempt`` — nodes report a preemption failure (the agent's SIGTERM
   grace path), die, and rejoin after ``duration_vs``;

@@ -39,7 +39,7 @@ checked-in contract, three ways:
    - WC003 unknown-message hard-fail: every ``deserialize`` call site
      outside serde must lexically handle
      :class:`~dlrover_tpu.common.serde.UnknownMessageError` — servers
-     degrade to ``SimpleResponse``, clients raise the typed taxonomy
+     degrade to ``SimpleResponse``, clients raise the typed classification
      error — so an unknown ``_t`` can never escape as a raw
      ValueError (the OverloadedResponse bug class). A blanket
      ``except Exception`` deliberately does NOT count: that is the
@@ -100,7 +100,7 @@ WC_RULES = [
      "typed SimpleResponse fallback"),
     ("WC003", "unknown-message-hard-fail",
      "deserialize call site without UnknownMessageError handling: an "
-     "unknown _t must degrade (SimpleResponse / typed taxonomy error), "
+     "unknown _t must degrade (SimpleResponse / typed classification error), "
      "never escape as a raw ValueError"),
     ("WC004", "non-string-dict-keys",
      "Dict[non-str, ...] in a wire-message hint: JSON round-trips keys "
@@ -829,7 +829,7 @@ def _wc003(src: SourceFile) -> List[Violation]:
                 "deserialize call without UnknownMessageError handling "
                 "in an enclosing try: an unknown _t (version skew) "
                 "must degrade to SimpleResponse (servers) or the typed "
-                "taxonomy error (clients), never escape as a raw "
+                "classification error (clients), never escape as a raw "
                 "ValueError — and a blanket `except Exception` is the "
                 "abort path, not a skew degrade",
             ))

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import json
 import os
+import tempfile
 import threading
 import time
 from typing import Dict, List, Optional
@@ -243,7 +244,7 @@ def create_state_backend(
         kind = "configmap" if k8s_client is not None else "memory"
     if kind == "file":
         root = flags.STATE_DIR.get() or os.path.join(
-            "/tmp", f"dlrover_tpu_state_{job_name}"
+            tempfile.gettempdir(), f"dlrover_tpu_state_{job_name}"
         )
         return FileStateBackend(os.path.join(root, job_name))
     if kind == "configmap" and k8s_client is not None:

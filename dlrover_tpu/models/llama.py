@@ -284,7 +284,7 @@ def _attention(cfg: LlamaConfig, mesh: Optional[Mesh], q, k, v):
         impl = "ring" if sp_size > 1 else "flash"
     if impl in ("ring", "ulysses") and sp_size > 1:
         assert mesh is not None
-        from dlrover_tpu.ops.shard_map_compat import shard_map
+        from jax import shard_map
 
         if impl == "ulysses":
             from dlrover_tpu.ops.ulysses import ulysses_attention as sp_attn
@@ -305,7 +305,7 @@ def _attention(cfg: LlamaConfig, mesh: Optional[Mesh], q, k, v):
         return mha_reference(q, k, v, causal=True)
     return flash_attention(q, k, v, causal=True,
                            block_q=cfg.attn_block_q,
-                           block_k=cfg.attn_block_k)
+                           block_k=cfg.attn_block_k, mesh=mesh)
 
 
 # ---------------------------------------------------------------------------
@@ -755,7 +755,7 @@ def loss_fn(
         x = forward_hidden(params, tokens, cfg, mesh)
         nll_sum, n_valid = cross_entropy_sums(
             x, params["lm_head"], _shift_targets(tokens),
-            chunk_size=cfg.ce_chunk_size,
+            chunk_size=cfg.ce_chunk_size, mesh=mesh,
         )
     else:
         logits = forward(params, tokens, cfg, mesh)
@@ -873,8 +873,8 @@ def _pp_loss_impl(
     ``P(pp)`` on the layer axis so each stage holds a contiguous slab, and
     a ``shard_map`` manual over EVERY mesh axis runs the schedule with
     explicit collectives — ``ppermute`` stage handoffs on pp, megatron
-    tp psums, ZeRO-3 fsdp gathers — on the portable explicit-collective
-    path (``ops/shard_map_compat.py``), with no ``auto=`` partitioning.
+    tp psums, ZeRO-3 fsdp gathers — with nothing left to the automatic
+    partitioner.
 
     Two schedules (``cfg.pp_schedule``):
 
@@ -1025,7 +1025,7 @@ def _pp_gpipe(
     each input's cotangent over its unmentioned axes, which is exactly
     the dp/ep/fsdp/tp data reduction (``tp_mode="native"``: no markers,
     jax's scaled-partial cotangent discipline is exact on its own)."""
-    from dlrover_tpu.ops.shard_map_compat import shard_map
+    from jax import shard_map
 
     dp_size, fsdp_size, ep_size, tp_size = _pp_sizes(mesh)
     mb_l = mb // (dp_size * fsdp_size * ep_size)
@@ -1158,7 +1158,7 @@ def _pp_1f1b_run(static: _PPStatic, layers, x_micro, final_norm, lm_head,
     cfg, mesh = static.cfg, static.mesh
     pp_size, sp_size = static.pp, static.sp
     n_micro, mb, s_local = static.n_micro, static.mb, static.s_local
-    from dlrover_tpu.ops.shard_map_compat import shard_map
+    from jax import shard_map
 
     if cfg.pp_virtual_stages > 1:
         return _pp_interleaved_run(
@@ -1402,7 +1402,7 @@ def _pp_interleaved_run(static: _PPStatic, layers, x_micro, final_norm,
     v = cfg.pp_virtual_stages
     if sp_size > 1:
         raise ValueError("interleaved 1f1b does not compose with sp yet")
-    from dlrover_tpu.ops.shard_map_compat import shard_map
+    from jax import shard_map
 
     tables = build_interleaved_tables(pp_size, v, n_micro)
     dev_tables = {

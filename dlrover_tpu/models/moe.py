@@ -348,7 +348,7 @@ def loss_fn(
         nll_sum, n_valid = cross_entropy_sums(
             x.astype(jnp.float32), params["lm_head"],
             llama._shift_targets(tokens),
-            chunk_size=cfg.ce_chunk_size,
+            chunk_size=cfg.ce_chunk_size, mesh=mesh,
         )
         ce = nll_sum / jnp.maximum(n_valid, 1.0)
         return ce + cfg.router_aux_coef * aux

@@ -161,7 +161,7 @@ def _divisor_block(s: int, cap: int = 128) -> int:
     return 0
 
 
-def _encoder_layer(cfg: ViTConfig, lp, x):
+def _encoder_layer(cfg: ViTConfig, mesh, lp, x):
     dt = cfg.dtype
     b, s, d = x.shape
     h, hd = cfg.n_heads, cfg.head_dim
@@ -179,7 +179,7 @@ def _encoder_layer(cfg: ViTConfig, lp, x):
         attn = mha_reference(q, k, v, causal=False)
     else:
         attn = flash_attention(q, k, v, causal=False,
-                               block_q=blk, block_k=blk)
+                               block_q=blk, block_k=blk, mesh=mesh)
     x = x + attn.reshape(b, s, d) @ lp["wo"].astype(dt)
 
     y = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
@@ -196,7 +196,7 @@ def forward_pooled(params: Params, images: jnp.ndarray, cfg: ViTConfig,
     x = patchify(cfg, images.astype(dt)) @ params["patch_embed"].astype(dt)
     x = x + params["pos_embed"].astype(dt)[None]
 
-    layer_fn = lambda lp, x: _encoder_layer(cfg, lp, x)  # noqa: E731
+    layer_fn = lambda lp, x: _encoder_layer(cfg, mesh, lp, x)  # noqa: E731
     if cfg.remat:
         layer_fn = jax.checkpoint(
             layer_fn, policy=jax.checkpoint_policies.nothing_saveable
@@ -235,7 +235,7 @@ def loss_fn(params: Params, batch, cfg: ViTConfig, mesh=None) -> jnp.ndarray:
         # accumulation) defined in exactly one place
         pooled = forward_pooled(params, images, cfg, mesh)
         nll_sum, n_valid = cross_entropy_sums(
-            pooled, params["head"], labels
+            pooled, params["head"], labels, mesh=mesh
         )
         return nll_sum / jnp.maximum(n_valid, 1.0)
     logits = forward(params, images, cfg, mesh)

@@ -32,6 +32,7 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import tempfile
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -39,7 +40,7 @@ from typing import Any, Dict, List, Optional
 from dlrover_tpu.common import flags
 from dlrover_tpu.common.log import logger
 
-#: the span taxonomy (docs/design/observability.md). ``downtime`` is
+#: the span classification (docs/design/observability.md). ``downtime`` is
 #: master-side only (the SpeedMonitor's bracket spans); ``host`` is the
 #: catch-all PyTracer user spans map onto; ``kernel`` is the per-kernel
 #: breakdown lane the kernel ledger (profiler/kernel_ledger.py) emits —
@@ -225,7 +226,8 @@ def default_dump_dir() -> str:
     if configured:
         return configured
     return os.path.join(
-        "/tmp/dlrover_tpu_logs", str(flags.JOB_NAME.get()), "traces"
+        tempfile.gettempdir(), "dlrover_tpu_logs",
+        str(flags.JOB_NAME.get()), "traces",
     )
 
 

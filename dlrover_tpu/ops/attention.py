@@ -16,9 +16,11 @@ where ``lse`` is the per-row logsumexp of the attention logits:
   is what lets ring attention merge per-chunk results by logsumexp and
   still get exact gradients through the merge.
 
-On non-TPU backends both directions fall back to the jnp reference, so the
-same model code runs in CPU tests; ``interpret=True`` runs the Pallas
-kernels in interpreter mode for numerics tests without a TPU.
+On the TPU backend the Pallas kernels are the path: one the chip's
+compiler refuses fails the step. Off TPU both directions run the jnp
+reference, so the same model code runs in CPU tests; ``interpret=True``
+runs the Pallas kernels in interpreter mode for numerics tests without
+a TPU.
 
 The reference framework has no attention op at all (it launches
 Megatron/DeepSpeed which own the math, SURVEY.md §2.8) — this is part of
@@ -29,18 +31,16 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # pallas imports fail on some backends; the reference path still works
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from dlrover_tpu.parallel.mesh import BATCH_AXES, TP
 
 _NEG_INF = -1e30
 
@@ -446,10 +446,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -468,7 +465,7 @@ def _flash_with_lse_fwd(q, k, v, causal, block_q, block_k, interpret):
     # named scope = the kernel ledger's attribution key
     # (profiler/kernel_ledger.py classifies HLO sites by op_name path)
     with jax.named_scope("attention_fwd"):
-        if _HAS_PALLAS and (interpret or _on_tpu()):
+        if interpret or _on_tpu():
             out, lse = _flash_fwd_pallas(q, k, v, causal, block_q,
                                          block_k, interpret=interpret)
         else:
@@ -480,7 +477,7 @@ def _flash_with_lse_bwd(causal, block_q, block_k, interpret, res, g):
     q, k, v, o, lse = res
     g_out, g_lse = g
     with jax.named_scope("attention_bwd"):
-        if _HAS_PALLAS and (interpret or _on_tpu()):
+        if interpret or _on_tpu():
             return _flash_bwd_pallas(
                 q, k, v, o, lse, g_out, g_lse, causal, block_q, block_k,
                 interpret=interpret,
@@ -498,7 +495,25 @@ flash_attention_with_lse.defvjp(_flash_with_lse_fwd, _flash_with_lse_bwd)
 
 def flash_attention(q, k, v, causal: bool = True,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = False):
-    return flash_attention_with_lse(
-        q, k, v, causal, block_q, block_k, interpret
-    )[0]
+                    interpret: bool = False,
+                    mesh: Optional[Mesh] = None):
+    """``mesh``: the mesh the caller's jit partitions over. The compiler
+    partitions the reference path itself, but not a Mosaic kernel
+    ("cannot be automatically partitioned"), so over more than one
+    device the kernels run under ``shard_map`` on each device's batch
+    rows (data axes) and heads (tp), every sequence whole. Callers
+    already inside a manual ``shard_map`` (ring, ulysses, the pp
+    stages) pass no mesh."""
+    def attn(q, k, v):
+        return flash_attention_with_lse(
+            q, k, v, causal, block_q, block_k, interpret
+        )[0]
+
+    if mesh is None or mesh.size == 1 or not (interpret or _on_tpu()):
+        return attn(q, k, v)
+    spec = P(BATCH_AXES, None, TP, None)
+    return shard_map(
+        attn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
+    )(q, k, v)
+

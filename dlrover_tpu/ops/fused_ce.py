@@ -22,38 +22,36 @@ bitwise-comparable under tolerance:
   vocab tile written exactly once) — the dq/dkv split from the flash
   backward, ported to the CE geometry.
 
-Dispatch contract (``cross_entropy_sums``): the Pallas kernel runs only
-on TPU (or under ``interpret=True`` for CPU numerics tests); everywhere
-else — and when the ``DLROVER_TPU_FUSED_CE=0`` kill-switch is set — the
-scan-based ``chunked_cross_entropy`` is the fallback, so CPU tests,
+Dispatch contract (``cross_entropy_sums``): on the TPU backend the
+Pallas kernel is the path (and under ``interpret=True`` for CPU numerics
+tests) — a kernel the chip's compiler refuses fails the step, it is not
+replaced. Off TPU — and when the ``DLROVER_TPU_FUSED_CE=0`` kill-switch
+is set — the scan-based ``chunked_cross_entropy`` runs, so CPU tests,
 contract lowering and bisection all keep the PR 1 program. Same
 ``(nll_sum, n_valid)`` two-number return, same ``targets < 0`` pad
-sentinel, same f32 accumulation contract.
+sentinel, same compute-dtype-operands / f32-accumulation contract.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import Mesh, PartitionSpec as P
 
 from dlrover_tpu.common import flags
 from dlrover_tpu.ops.chunked_ce import (
     DEFAULT_CHUNK_SIZE,
     chunked_cross_entropy,
 )
-
-try:  # pallas imports fail on some backends; the chunked fallback remains
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from dlrover_tpu.parallel.mesh import BATCH_AXES, SP
 
 _NEG_INF = -1e30
 
@@ -62,11 +60,23 @@ _NEG_INF = -1e30
 #: dim divisible by 128 or equal to the array dim).
 _LANES = 8
 
-#: Default tile geometry: 256 tokens × 512 vocab columns keeps the live
-#: tile (256×512 f32 = 512 KB) plus the (block_t, d) / (d, block_v)
-#: operand blocks comfortably inside a v5e core's VMEM at d=2048.
+#: Largest tiles a kernel is given. Every kernel blocks the whole
+#: feature dim — ``(block_t, d)`` and ``(d, block_v)`` operand blocks,
+#: and in the backward an output block plus an f32 accumulator of the
+#: same shape — so its VMEM footprint grows with ``d``:
+#: ``_tile_geometry`` halves these until ``_vmem_bytes`` fits the budget.
 DEFAULT_BLOCK_T = 256
 DEFAULT_BLOCK_V = 512
+
+#: Scoped VMEM every kernel asks the compiler for. The default grant is
+#: 16 MiB, which the backward's blocks overflow from d=2048 up at the
+#: default tiles ("Scoped allocation with size 22.52M and limit 16.00M"
+#: at d=4096); a v5e core has 128 MiB of VMEM, v5p and v6e no less than
+#: this.
+_VMEM_LIMIT = 64 * 2**20
+#: What the blocks may take of it; the rest is left to the compiler's
+#: own temporaries (the live f32 logits tile and its copies).
+_VMEM_BUDGET = 48 * 2**20
 
 
 def fused_ce_enabled() -> bool:
@@ -78,10 +88,10 @@ def fused_ce_enabled() -> bool:
 
 
 def fused_ce_available(interpret: bool = False) -> bool:
-    """True when the Pallas kernel can actually run here: Pallas
-    importable AND (TPU backend or interpreter mode). The dispatcher
-    below and the bench sweep both key off this."""
-    return _HAS_PALLAS and (interpret or _on_tpu())
+    """True where the Pallas kernel is the path: the TPU backend, or
+    interpreter mode. The dispatcher below and the bench sweep both key
+    off this."""
+    return interpret or _on_tpu()
 
 
 def cross_entropy_sums(
@@ -93,18 +103,46 @@ def cross_entropy_sums(
     block_t: int = DEFAULT_BLOCK_T,
     block_v: int = DEFAULT_BLOCK_V,
     interpret: bool = False,
+    mesh: Optional[Mesh] = None,
 ):
-    """The models' CE entry: fused Pallas kernel when enabled AND
-    runnable, else the scan-based chunked path (same math, same
-    ``(nll_sum, n_valid)`` contract). ``chunk_size`` parameterizes the
-    fallback only; ``block_t``/``block_v`` the kernel only."""
-    if fused_ce_enabled() and fused_ce_available(interpret):
-        return fused_cross_entropy(
-            x, w_unembed, targets,
-            block_t=block_t, block_v=block_v, interpret=interpret,
-        )
-    return chunked_cross_entropy(x, w_unembed, targets,
-                                 chunk_size=chunk_size)
+    """The models' CE entry: the fused Pallas kernel on TPU (or under
+    ``interpret``) unless the kill-switch is set, else the scan-based
+    chunked path (same math, same ``(nll_sum, n_valid)`` contract).
+    ``chunk_size`` parameterizes the chunked path only;
+    ``block_t``/``block_v`` the kernel only.
+
+    ``mesh``: the mesh the caller's jit partitions over. The compiler
+    partitions the chunked path itself, but not a Mosaic kernel ("cannot
+    be automatically partitioned"), so over more than one device the
+    kernel runs on each device's tokens under ``shard_map`` — ``x`` and
+    ``targets`` split over the data axes (and the sequence over sp),
+    the unembed matrix gathered whole, the two sums reduced over those
+    axes. Callers already inside a manual ``shard_map`` pass no mesh."""
+    if not (fused_ce_enabled() and fused_ce_available(interpret)):
+        return chunked_cross_entropy(x, w_unembed, targets,
+                                     chunk_size=chunk_size)
+    kernel = functools.partial(
+        fused_cross_entropy,
+        block_t=block_t, block_v=block_v, interpret=interpret,
+    )
+    if mesh is None or mesh.size == 1:
+        return kernel(x, w_unembed, targets)
+    # (b, s) token grids split over (data axes, sp); (b,) over the data
+    # axes alone (vit's pooled head)
+    token_axes = (BATCH_AXES, SP)[: targets.ndim]
+    reduce_axes = BATCH_AXES + (SP,) * (targets.ndim > 1)
+
+    def per_shard(x, w, tgt):
+        nll_sum, n_valid = kernel(x, w, tgt)
+        return (lax.psum(nll_sum, reduce_axes),
+                lax.psum(n_valid, reduce_axes))
+
+    return shard_map(
+        per_shard, mesh=mesh,
+        in_specs=(P(*token_axes, None), P(None, None), P(*token_axes)),
+        out_specs=(P(), P()),
+        check_vma=False,
+    )(x, w_unembed, targets)
 
 
 def fused_cross_entropy(
@@ -121,8 +159,8 @@ def fused_cross_entropy(
     Args/returns match :func:`~dlrover_tpu.ops.chunked_ce.
     chunked_cross_entropy`: ``x (..., d)``, ``w_unembed (d, v)``,
     ``targets (...)`` with ``targets < 0`` ignored; returns f32
-    ``(nll_sum, n_valid)``. Raises if Pallas cannot run here — callers
-    wanting automatic fallback use :func:`cross_entropy_sums`.
+    ``(nll_sum, n_valid)``. Raises off TPU without ``interpret`` —
+    callers wanting the platform dispatch use :func:`cross_entropy_sums`.
     """
     if x.shape[:-1] != targets.shape:
         raise ValueError(
@@ -135,8 +173,8 @@ def fused_cross_entropy(
         )
     if not fused_ce_available(interpret):
         raise RuntimeError(
-            "fused_cross_entropy needs Pallas on TPU (or interpret=True); "
-            "use cross_entropy_sums for automatic chunked fallback"
+            "fused_cross_entropy needs the TPU backend (or interpret=True); "
+            "use cross_entropy_sums for the platform dispatch"
         )
     return _fused_ce(int(block_t), int(block_v), bool(interpret),
                      x, w_unembed, targets)
@@ -151,13 +189,49 @@ def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-def _tile_geometry(n: int, v: int, block_t: int, block_v: int):
-    """Clip the requested tiles to the (8, 128)-aligned problem size and
-    return ``(bt, bv, n_pad, v_pad)`` with the padded array dims exact
-    tile multiples — every BlockSpec start is then in range."""
+def _vmem_bytes(bt: int, bv: int, d: int, xb: int, wb: int,
+                backward: bool) -> int:
+    """VMEM one kernel's blocks occupy: the pipelined (double-buffered)
+    ``(bt, d)`` x and ``(d, bv)`` w blocks, w's compute-dtype copy when
+    the dtypes differ, and in the backward the larger of the dx
+    ``(bt, d)`` and dw ``(d, bv)`` kernels' output block (double-
+    buffered) with its f32 accumulator."""
+    total = 2 * bt * d * xb + 2 * d * bv * wb
+    if wb != xb:
+        total += d * bv * xb
+    if backward:
+        total += max(bt * d * (2 * xb + 4), d * bv * (2 * wb + 4))
+    return total
+
+
+def _tile_geometry(n: int, v: int, d: int, x_dtype, w_dtype,
+                   block_t: int, block_v: int, backward: bool):
+    """Clip the requested tiles to the (8, 128)-aligned problem size,
+    halve them until the kernel's blocks fit ``_VMEM_BUDGET`` at this
+    ``d``, and return ``(bt, bv, n_pad, v_pad)`` with the padded array
+    dims exact tile multiples — every BlockSpec start is then in
+    range."""
     bt = max(8, min(block_t, _round_up(n, 8)))
     bv = max(128, min(block_v, _round_up(v, 128)))
+    xb, wb = jnp.dtype(x_dtype).itemsize, jnp.dtype(w_dtype).itemsize
+    while _vmem_bytes(bt, bv, d, xb, wb, backward) > _VMEM_BUDGET:
+        # shrink the side that holds more VMEM; the vocab tile stays a
+        # multiple of the 128-lane width, the token tile of 8 sublanes
+        if bv > 128 and (bv * wb >= bt * xb or bt <= 8):
+            bv = _round_up(bv // 2, 128)
+        elif bt > 8:
+            bt = _round_up(bt // 2, 8)
+        else:
+            raise ValueError(
+                f"fused CE blocks the whole feature dim and cannot fit "
+                f"d={d} into {_VMEM_BUDGET} bytes of VMEM even at "
+                f"(8, 128) tiles; {flags.FUSED_CE.name}=0 selects the "
+                f"chunked path"
+            )
     return bt, bv, _round_up(n, bt), _round_up(v, bv)
+
+
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
 
 
 def _pad_operands(x2, w, tgt1, n_pad: int, v_pad: int):
@@ -181,12 +255,12 @@ def _lanes(a):
 
 
 def _tile_logits(x_ref, w_ref, vi, bt: int, bv: int, v: int):
-    """One tile's logits ``(bt, bv)`` f32: MXU matmul + padded-column
-    -inf masking (same contract as chunked_ce._chunk_logits)."""
-    x = x_ref[...].astype(jnp.float32)
-    w = w_ref[...].astype(jnp.float32)
+    """One tile's logits ``(bt, bv)`` f32: compute-dtype operands on the
+    MXU with f32 accumulation + padded-column -inf masking (same
+    contract as chunked_ce._chunk_logits)."""
     logits = lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())),
+        x_ref[...], w_ref[...].astype(x_ref.dtype),
+        (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
     col = vi * bv + lax.broadcasted_iota(jnp.int32, (bt, bv), 1)
@@ -269,6 +343,7 @@ def _fused_ce_fwd_pallas(x2, w, tgt1, v, bt, bv, interpret):
             pltpu.VMEM((bt, 128), jnp.float32),
             pltpu.VMEM((bt, 128), jnp.float32),
         ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(x2, w, _lanes(tgt1))
     return logz[:, 0], gold[:, 0]
@@ -294,7 +369,8 @@ def _bwd_q_tile(x_ref, w_ref, tgt_ref, logz_ref, scale_ref, vi,
     p = jnp.exp(logits - logz[:, None])  # padded cols: exp(-inf)=0
     tgt = tgt_ref[:, 0]
     onehot = (col == tgt[:, None]).astype(jnp.float32)
-    return (p - onehot) * scale_ref[:, 0][:, None]
+    # cast for the MXU, as chunked_ce._ce_bwd does
+    return ((p - onehot) * scale_ref[:, 0][:, None]).astype(x_ref.dtype)
 
 
 def _fused_ce_dx_kernel(
@@ -310,7 +386,7 @@ def _fused_ce_dx_kernel(
     q = _bwd_q_tile(x_ref, w_ref, tgt_ref, logz_ref, scale_ref, vi,
                     block_t, block_v, v)
     acc_ref[:] = acc_ref[:] + lax.dot_general(
-        q, w_ref[...].astype(jnp.float32), (((1,), (1,)), ((), ())),
+        q, w_ref[...].astype(q.dtype), (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
 
@@ -335,7 +411,7 @@ def _fused_ce_dw_kernel(
     q = _bwd_q_tile(x_ref, w_ref, tgt_ref, logz_ref, scale_ref, vi,
                     block_t, block_v, v)
     acc_ref[:] = acc_ref[:] + lax.dot_general(
-        x_ref[...].astype(jnp.float32), q, (((0,), (0,)), ((), ())),
+        x_ref[...], q, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
 
@@ -365,8 +441,9 @@ def _fused_ce_bwd_pallas(x2, w, tgt1, logz, row_scale, v, bt, bv,
             lane_spec, lane_spec, lane_spec,
         ],
         out_specs=pl.BlockSpec((bt, d), lambda ti, vi: (ti, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_pad, d), x2.dtype),
         scratch_shapes=[pltpu.VMEM((bt, d), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(x2, w, tgt_l, logz_l, scale_l)
 
@@ -383,18 +460,16 @@ def _fused_ce_bwd_pallas(x2, w, tgt1, logz, row_scale, v, bt, bv,
             lane_spec_vm, lane_spec_vm, lane_spec_vm,
         ],
         out_specs=pl.BlockSpec((d, bv), lambda vi, ti: (0, vi)),
-        out_shape=jax.ShapeDtypeStruct((d, v_pad), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((d, v_pad), w.dtype),
         scratch_shapes=[pltpu.VMEM((d, bv), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(x2, w, tgt_l, logz_l, scale_l)
     return dx, dw
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -415,7 +490,10 @@ def _fused_ce_run_fwd(block_t, block_v, interpret, x, w, tgt):
     with jax.named_scope("fused_ce_fwd"):
         x2, tgt1 = _flatten(x, tgt)
         n, v = x2.shape[0], w.shape[1]
-        bt, bv, n_pad, v_pad = _tile_geometry(n, v, block_t, block_v)
+        bt, bv, n_pad, v_pad = _tile_geometry(
+            n, v, x2.shape[1], x.dtype, w.dtype, block_t, block_v,
+            backward=False,
+        )
         x2p, wp, tgt1p = _pad_operands(x2, w, tgt1, n_pad, v_pad)
         logz, gold = _fused_ce_fwd_pallas(
             x2p, wp, tgt1p, v, bt, bv, interpret
@@ -450,7 +528,10 @@ def _fused_ce_bwd(block_t, block_v, interpret, res, cot):
     with jax.named_scope("fused_ce_bwd"):
         x2, tgt1 = _flatten(x, tgt)
         n, v = x2.shape[0], w.shape[1]
-        bt, bv, n_pad, v_pad = _tile_geometry(n, v, block_t, block_v)
+        bt, bv, n_pad, v_pad = _tile_geometry(
+            n, v, x2.shape[1], x.dtype, w.dtype, block_t, block_v,
+            backward=True,
+        )
         x2p, wp, tgt1p = _pad_operands(x2, w, tgt1, n_pad, v_pad)
         vf = (tgt1p >= 0).astype(jnp.float32)
         row_scale = vf * g_nll.astype(jnp.float32)
@@ -458,8 +539,8 @@ def _fused_ce_bwd(block_t, block_v, interpret, res, cot):
         dx, dw = _fused_ce_bwd_pallas(
             x2p, wp, tgt1p, logz_p, row_scale, v, bt, bv, interpret
         )
-        dx = dx[:n].reshape(x.shape).astype(x.dtype)
-        dw = dw[:, :v].astype(w.dtype)
+        dx = dx[:n].reshape(x.shape)
+        dw = dw[:, :v]
     dtgt = np.zeros(tgt.shape, jax.dtypes.float0)
     return dx, dw, dtgt
 

@@ -292,7 +292,7 @@ def hier_value_and_grad(
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from dlrover_tpu.ops.shard_map_compat import shard_map
+    from jax import shard_map
     from dlrover_tpu.parallel.sharding import batch_spec
     from dlrover_tpu.train import zero1
 
@@ -444,7 +444,7 @@ def overlap_value_and_grad(
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from dlrover_tpu.ops.shard_map_compat import shard_map
+    from jax import shard_map
     from dlrover_tpu.parallel.sharding import batch_spec
     from dlrover_tpu.train import zero1
 
@@ -636,7 +636,7 @@ def hier_param_gather(mesh, n_slices: int, p_specs, params):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from dlrover_tpu.ops.shard_map_compat import shard_map
+    from jax import shard_map
     from dlrover_tpu.train import zero1
 
     hmesh = hier_mesh(mesh, n_slices)

@@ -69,9 +69,7 @@ def ulysses_attention(
     ``axis_name``; returns the output in the same layout. Differentiable
     end to end (all_to_all is linear; the flash kernel carries its own
     VJP)."""
-    from dlrover_tpu.ops.shard_map_compat import axis_size
-
-    sp = axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
     if sp == 1:
         return flash_attention(q, k, v, causal=causal,
                                block_q=block_q, block_k=block_k)

@@ -457,10 +457,7 @@ def _bench_collective(mesh, axis: str, kind: str, nbytes: int):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from dlrover_tpu.ops.shard_map_compat import (
-        shard_map,
-        supports_partial_manual,
-    )
+    from jax import shard_map
 
     n = mesh.shape[axis]
     # per-shard length divisible by n too (all_to_all re-splits the
@@ -483,16 +480,12 @@ def _bench_collective(mesh, axis: str, kind: str, nbytes: int):
             return lax.all_gather(x, axis)
         raise ValueError(f"unknown collective kind {kind!r}")
 
-    # the body only touches the measured axis, so on legacy jax (no
-    # native partial-manual mode) the full-manual map is equivalent —
-    # and the auto= translation CHECK-aborts XLA on this program
-    extra = {"axis_names": {axis}} if supports_partial_manual() else {}
     fn = shard_map(
         body, mesh=mesh, in_specs=P(axis), out_specs=(
             P() if kind == "all_gather" else P(axis)
         ),
         check_vma=False,
-        **extra,
+        axis_names={axis},
     )
     return jax.jit(fn), x
 

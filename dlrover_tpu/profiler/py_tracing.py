@@ -28,7 +28,7 @@ from dlrover_tpu.common import flags
 from dlrover_tpu.observability import trace
 
 #: PyTracer categories -> trace-spine span kinds: GC pauses and
-#: dataloader fetches adopt the spine's taxonomy, everything else is a
+#: dataloader fetches adopt the spine's classification, everything else is a
 #: generic host span (docs/design/observability.md)
 _CAT_TO_KIND = {"gc": "gc_pause", "dataloader": "input_wait"}
 
@@ -100,7 +100,7 @@ class PyTracer:
             if len(self._events) > self._cap:
                 del self._events[: len(self._events) // 2]
         # mirror into the unified trace spine (no-op when it is off):
-        # GC + user spans adopt the typed-span taxonomy, so one merged
+        # GC + user spans adopt the typed-span classification, so one merged
         # job timeline carries them next to step/compile/ckpt spans
         trace.record(
             _CAT_TO_KIND.get(cat, "host"), name,

@@ -9,7 +9,7 @@ in the same rendezvous round polls in the same phase, so the master
 absorbs the whole fleet as a square wave instead of a flat rate.  This
 module is the one place the retry/backoff vocabulary is defined:
 
-- :func:`classify` — error taxonomy.  ``unavailable`` (master down /
+- :func:`classify` — error classification.  ``unavailable`` (master down /
   connection refused / mid-relaunch) and ``deadline`` (server slow or
   link black-holed) are retryable transport conditions; ``overloaded``
   is the server's *explicit* shed signal (``OverloadedResponse``)
@@ -32,7 +32,7 @@ import random
 import threading
 from typing import Iterator, Optional
 
-# -- error taxonomy ---------------------------------------------------------
+# -- error classification ---------------------------------------------------------
 
 UNAVAILABLE = "unavailable"
 DEADLINE = "deadline"
@@ -65,7 +65,7 @@ class OverloadedError(Exception):
 
 class UnknownMessageTypeError(Exception):
     """The peer answered with a message type this binary cannot decode
-    (``serde.UnknownMessageError`` mapped into the taxonomy by
+    (``serde.UnknownMessageError`` mapped into the classification by
     ``RpcClient._call``).
 
     This is version skew, not a transport blip: retrying replays the
@@ -98,7 +98,7 @@ class RetryBudgetExceeded(Exception):
 
 
 def classify(exc: BaseException) -> str:
-    """Map an exception to the taxonomy. gRPC status codes are read
+    """Map an exception to the classification. gRPC status codes are read
     duck-typed (``exc.code()``) so non-gRPC transports — the fleet
     harness's in-process loopback — classify identically."""
     if isinstance(exc, OverloadedError):

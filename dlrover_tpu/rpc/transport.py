@@ -18,7 +18,7 @@ Fleet-scale hardening (ROADMAP item 5, docs/design/fleet_harness.md):
   stalls training, a shed heartbeat costs nothing).
 - the client retries through the unified policy in
   :mod:`dlrover_tpu.rpc.policy`: jittered exponential backoff with a
-  budget, and an error taxonomy distinguishing unavailable vs deadline
+  budget, and an error classification distinguishing unavailable vs deadline
   vs application errors.  ``Overloaded`` replies either retry after the
   server's hint (default) or raise :class:`OverloadedError` for
   periodic reporters that honor backpressure by widening their
@@ -337,7 +337,7 @@ class RpcServer:
 class RpcClient:
     """Client side of the two generic RPCs, with the unified retry
     policy (jittered exponential backoff, budget-bounded, error
-    taxonomy — :mod:`dlrover_tpu.rpc.policy`)."""
+    classification — :mod:`dlrover_tpu.rpc.policy`)."""
 
     def __init__(
         self,
@@ -456,7 +456,7 @@ class RpcClient:
                     )
                 except UnknownMessageError as e:
                     # version skew INSIDE the retry loop: map to the
-                    # typed taxonomy error (named _t, actionable) and
+                    # typed classification error (named _t, actionable) and
                     # never retry — the peer is healthy, replaying the
                     # call replays the identical decode failure. This
                     # closes the documented OverloadedResponse hazard

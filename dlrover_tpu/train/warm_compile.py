@@ -105,10 +105,10 @@ def enable_persistent_cache(path: Optional[str] = None) -> Optional[str]:
     """Point JAX's persistent compilation cache at ``path`` (default:
     ``DLROVER_TPU_COMPILE_CACHE_DIR``). Idempotent, and never overrides
     a cache dir jax already has — the jax config is process-global and
-    the first owner (a user's ``JAX_COMPILATION_CACHE_DIR``, bench's
-    per-user cache) wins. Returns the effective dir, or None when
-    disabled/unconfigured. Purely an optimization: any failure logs and
-    returns None rather than failing the caller."""
+    the first owner (a ``JAX_COMPILATION_CACHE_DIR`` placed from
+    outside, bench's ``_enable_jit_cache``) wins. Returns the effective
+    dir, or None when disabled/unconfigured. Purely an optimization:
+    any failure logs and returns None rather than failing the caller."""
     global _enabled_dir
     if not warm_compile_enabled():
         return None
@@ -131,8 +131,8 @@ def enable_persistent_cache(path: Optional[str] = None) -> Optional[str]:
         except Exception as e:
             logger.warning("persistent compile cache unavailable: %s", e)
             return None
-        # children (speculative compile helpers, interposed probes,
-        # restarted workers forked from this env) inherit the same dir
+        # children (speculative compile helpers, restarted workers
+        # forked from this env) inherit the same dir
         flags.COMPILE_CACHE_DIR.propagate(path)
         _enabled_dir = path
         logger.info("persistent compile cache at %s", path)

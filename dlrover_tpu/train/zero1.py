@@ -231,7 +231,7 @@ def sharded_value_and_grad(local_loss, mesh, p_specs, params):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from dlrover_tpu.ops.shard_map_compat import shard_map
+    from jax import shard_map
     from dlrover_tpu.parallel.sharding import batch_spec
 
     axis_sizes = dict(mesh.shape)

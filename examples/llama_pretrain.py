@@ -7,6 +7,14 @@ Run single-host (CPU demo, 8 virtual devices):
         --accelerator=cpu examples/llama_pretrain.py -- \
         --model tiny --steps 20 --fsdp 2 --tp 2
 
+One TPU chip, Llama-3-8B widths cut to the depth 16 GB holds (what
+``chip_smoke.py`` runs; ``--accelerator=tpu`` is the default and fails
+when JAX finds no TPU):
+
+    dlrover-tpu-run --standalone --nnodes=1 --nproc_per_node=1 \
+        examples/llama_pretrain.py -- --model 8b --layers 2 \
+        --param-dtype bfloat16 --seq 2048 --micro-batch 1 --global-batch 1
+
 Multi-host TPU (per host, master already up):
 
     dlrover-tpu-run --master_addr $MASTER:50051 --nnodes=2:8 \
@@ -19,8 +27,11 @@ unchanged, and state restores from shm/replica/storage.
 """
 
 import argparse
+import dataclasses
+import json
 import os
 import sys
+import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -31,11 +42,20 @@ def parse_args():
     p = argparse.ArgumentParser("llama_pretrain")
     p.add_argument("--model", default="tiny",
                    choices=["tiny", "1b", "8b"])
+    p.add_argument("--layers", type=int, default=0,
+                   help="cut the model to this depth (0 = its own)")
+    p.add_argument("--param-dtype", default="",
+                   choices=["", "float32", "bfloat16"],
+                   help="params and adam moments dtype (empty = the "
+                        "model's own)")
     p.add_argument("--steps", type=int, default=100)
     p.add_argument("--global-batch", type=int, default=0,
                    help="0 = pick per model")
     p.add_argument("--micro-batch", type=int, default=2)
     p.add_argument("--seq", type=int, default=0, help="0 = model default")
+    p.add_argument("--devices", type=int, default=0,
+                   help="build the mesh from the first N devices only "
+                        "(0 = all the process sees)")
     p.add_argument("--fsdp", type=int, default=1)
     p.add_argument("--tp", type=int, default=1)
     p.add_argument("--sp", type=int, default=1)
@@ -74,6 +94,7 @@ def main():
 
     import jax
     import jax.numpy as jnp
+    import optax
 
     from dlrover_tpu.checkpoint.checkpointer import Checkpointer
     from dlrover_tpu.models import llama
@@ -81,16 +102,27 @@ def main():
     from dlrover_tpu.train.trainer import ElasticTrainer, TrainConfig
 
     cfg, default_gb = model_config(args.model, llama, jnp)
+    full_depth = cfg.n_layers
+    if args.layers:
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
+    if args.param_dtype:
+        cfg = dataclasses.replace(
+            cfg, param_dtype=jnp.dtype(args.param_dtype)
+        )
     seq = args.seq or cfg.max_seq_len
+    dev = jax.devices()[0]
+    print(f"device platform={dev.platform} kind={dev.device_kind!r} "
+          f"count={len(jax.devices())}", flush=True)
+    devices = jax.devices()[: args.devices or None]
     mc = MeshConfig(dp=-1, fsdp=args.fsdp, sp=args.sp, tp=args.tp).resolve(
-        len(jax.devices())
+        len(devices)
     )
-    mesh = build_mesh(mc)
+    mesh = build_mesh(mc, devices=devices)
     specs = llama.param_specs(cfg)
-    params = jax.jit(
+    init_params = jax.jit(
         lambda k: llama.init_params(cfg, k),
         out_shardings=named_shardings(mesh, specs),
-    )(jax.random.key(0))
+    )
 
     tc = TrainConfig(
         global_batch_size=args.global_batch or default_gb,
@@ -107,17 +139,56 @@ def main():
     trainer.shardcheck_hints = {
         "seq_len": seq, "vocab": cfg.vocab_size,
     }
-    state = trainer.init_state(params)
+
+    def fresh_state():
+        return trainer.init_state(init_params(jax.random.key(0)))
+
+    def release(tree):
+        for leaf in jax.tree.leaves(tree):
+            leaf.delete()
+
+    state = fresh_state()
+    n_params = llama.param_count(cfg)
+    state_bytes = sum(l.nbytes for l in jax.tree.leaves(state))
+    print(f"config model={args.model} dim={cfg.dim} "
+          f"layers={cfg.n_layers}/{full_depth} heads={cfg.n_heads}/"
+          f"{cfg.n_kv_heads} ffn={cfg.ffn_dim} vocab={cfg.vocab_size} "
+          f"seq={seq} dtype={jnp.dtype(cfg.dtype).name} "
+          f"param_dtype={jnp.dtype(cfg.param_dtype).name} "
+          f"remat={cfg.remat_policy if cfg.remat else 'off'} "
+          f"mesh={dict(mesh.shape)} batch={tc.global_batch_size} "
+          f"params={n_params} state_bytes={state_bytes}", flush=True)
+    # where the parameters live: bytes of parameter shards per device
+    per_dev = {}
+    for leaf in jax.tree.leaves(state["params"]):
+        for sh in leaf.addressable_shards:
+            per_dev[sh.device.id] = per_dev.get(sh.device.id, 0) \
+                + sh.data.nbytes
+    print(f"param_bytes_per_device {dict(sorted(per_dev.items()))}",
+          flush=True)
 
     ckpt = Checkpointer(args.ckpt_dir, save_storage_interval=args.save_every)
-    restored = ckpt.load(target=state)
+    # Restore against the state's shapes and shardings, not its buffers:
+    # a job that fills the device cannot hold the initial and the
+    # restored state at once. The initial buffers go first, and a miss
+    # builds them again.
+    target = jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype,
+                                       sharding=l.sharding),
+        state,
+    )
+    release(state)
+    restored = ckpt.load(target=target)
     start = 0
-    if restored is not None:
+    if restored is None:
+        state = fresh_state()
+    else:
         start, state = restored
         # seed the host step counter so report_step never regresses
         # the master's SpeedMonitor after a restart
         trainer.sync_host_step(state)
-        print(f"restored from step {start}", flush=True)
+        tier = ckpt.last_restore_stats.get("tier", "")
+        print(f"restored from step {start} tier={tier}", flush=True)
 
     a, b = trainer.step_batch_shape
     loader_iter = None
@@ -129,8 +200,6 @@ def main():
         args.ckpt_dir, f"loader_state-{jax.process_index()}.json"
     )
     if args.data:
-        import json
-
         import numpy as np
 
         from dlrover_tpu.train.data import (
@@ -226,24 +295,44 @@ def main():
                 jax.random.fold_in(jax.random.key(1), step), (a, b, seq),
                 0, cfg.vocab_size,
             )
+        t0 = time.perf_counter()
         state, loss = trainer.step(state, batch)
-        ckpt.save(step + 1, state)
+        loss = float(loss)  # waits for the device: step_s is the step
+        step_s = time.perf_counter() - t0
+        save_s = ckpt.save(step + 1, state)
         if loader is not None and (step + 1) % args.save_every == 0:
             # data position rides a per-host sidecar stamped with the
             # step: restore discards it when it is AHEAD of the restored
             # model (the storage persist is async), so a crash replays
             # data rather than skipping it. tmp+rename keeps each write
             # atomic against SIGKILL.
-            import json
-
             os.makedirs(args.ckpt_dir, exist_ok=True)
             tmp = loader_state_path + ".tmp"
             with open(tmp, "w") as f:
                 json.dump({"step": step + 1, "loader": loader_pos}, f)
             os.replace(tmp, loader_state_path)
         if jax.process_index() == 0:
-            print(f"step {step + 1} loss {float(loss):.4f}", flush=True)
+            print(f"step {step + 1} loss {loss:.4f} step_s {step_s:.3f} "
+                  f"save_s {save_s:.3f}", flush=True)
+    # what the optimizer has seen of the gradients: the norm of adam's
+    # first moment per parameter group. Two layouts of one run agree on
+    # these only if their backward passes do; the loss of a few warm-up
+    # steps hardly moves with the update.
+    norms = jax.jit(lambda mu: {
+        k: optax.global_norm(jax.tree.map(lambda m: m.astype(jnp.float32), v))
+        for k, v in mu.items()
+    })(optax.tree_utils.tree_get(state["opt"], "mu"))
+    if jax.process_index() == 0:
+        print("first_moment_norms " + json.dumps(
+            {k: float(v) for k, v in norms.items()}), flush=True)
+    # close() waits until the last persist has been copied out of shm;
+    # do not sit on the state's device buffers, and on the host copies
+    # the last save cached on them, meanwhile
+    ckpt.wait_staging()
+    release(state)
     ckpt.close()
+    stats = dev.memory_stats() or {}
+    print(f"peak_bytes_in_use {stats.get('peak_bytes_in_use')}", flush=True)
     print("DONE", flush=True)
 
 

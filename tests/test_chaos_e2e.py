@@ -16,6 +16,7 @@ walkthrough; these are automated):
 import os
 import subprocess
 import sys
+import tempfile
 
 import pytest
 
@@ -50,7 +51,7 @@ def _env(extra=None):
 
 
 def _agent_logs(job_name, node_id=0):
-    log_dir = f"/tmp/dlrover_tpu_logs/{job_name}/node-{node_id}"
+    log_dir = f"{tempfile.gettempdir()}/dlrover_tpu_logs/{job_name}/node-{node_id}"
     out = ""
     if os.path.isdir(log_dir):
         for f in sorted(os.listdir(log_dir)):
@@ -255,7 +256,7 @@ def test_master_sigkill_resumes_shards_exactly_once(tmp_path):
     job = "chaos-master-kill"
     import shutil
 
-    shutil.rmtree(f"/tmp/dlrover_tpu_logs/{job}", ignore_errors=True)
+    shutil.rmtree(f"{tempfile.gettempdir()}/dlrover_tpu_logs/{job}", ignore_errors=True)
     state_env = {
         "DLROVER_TPU_STATE_BACKEND": "file",
         "DLROVER_TPU_STATE_DIR": str(tmp_path / "state"),

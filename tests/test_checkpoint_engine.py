@@ -171,6 +171,36 @@ def test_async_persist_through_saver(job_env):
         saver.stop()
 
 
+def test_close_then_drain_commits_the_last_checkpoint(job_env, monkeypatch):
+    """Seen on the v5e at an 8.9 GB state: the trainer is released once
+    the saver has copied shm, but fanout + commit run on for tens of
+    seconds; a finished job's worker and agent left first and the last
+    checkpoint was never committed. ``engine.close()`` now waits for
+    the copy and ``saver.drain()`` for the commit."""
+    job, ckpt_dir = job_env
+    saver = AsyncCheckpointSaver(job_name=job, node_id=0)
+    slow_commit = saver.persister._maybe_commit
+
+    def commit_slowly(*args, **kwargs):
+        time.sleep(1.0)  # the fanout/commit tail that outlived the job
+        return slow_commit(*args, **kwargs)
+
+    monkeypatch.setattr(saver.persister, "_maybe_commit", commit_slowly)
+    saver.start()
+    try:
+        assert saver.drain(timeout=5)  # nothing queued: returns at once
+        mesh = _mesh((8,), ("dp",))
+        state = _make_state(mesh)
+        engine = CheckpointEngine(ckpt_dir)
+        engine.save_to_storage(9, state)
+        engine.close()  # the worker's last act: waits for the shm copy
+        assert engine.committed_step() != 9  # the commit is still running
+        assert saver.drain(timeout=30)  # the agent's, before it exits
+        assert engine.committed_step() == 9
+    finally:
+        saver.stop()
+
+
 def test_save_on_failure_persists_staged_step(job_env):
     """Memory-only save; then the 'node dies' -> saver persists staged shm."""
     job, ckpt_dir = job_env

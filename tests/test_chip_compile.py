@@ -1,0 +1,176 @@
+"""The main path's kernels, compiled at real widths for a described v5e.
+
+The TPU compiler is installed here and compiles for a chip that is
+described, not attached (on-chip-measurement guide, section 2): what it
+refuses here it refuses on the chip — a block that overflows scoped
+VMEM, a misaligned slice, a Mosaic kernel left to the automatic
+partitioner — and interpret mode shows none of that. Nothing runs, so
+these say nothing about results or times.
+
+The topology is described inside a fixture, never at import, and the
+compiles run in the test's own process: only one process may load the
+TPU's library, and under xdist only the worker given this file does.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from dlrover_tpu.ops import attention, fused_ce
+from dlrover_tpu.parallel import MeshConfig, build_mesh
+from dlrover_tpu.parallel.mesh import BATCH_AXES
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: keep the cache out of it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def kernels_are_the_path(monkeypatch):
+    """The public wrappers ask ``jax.default_backend()``, which is the
+    CPU here, and would take their reference branch."""
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(fused_ce, "_on_tpu", lambda: True)
+    monkeypatch.setenv("DLROVER_TPU_FUSED_CE", "1")
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    return compiled.as_text()
+
+
+# Llama-3-8B attention: b1, s2048, 32 q / 8 kv heads, head_dim 128
+def _qkv(sharding):
+    q = jax.ShapeDtypeStruct((1, 2048, 32, 128), jnp.bfloat16,
+                             sharding=sharding)
+    kv = jax.ShapeDtypeStruct((1, 2048, 8, 128), jnp.bfloat16,
+                              sharding=sharding)
+    return q, kv, kv
+
+
+FLASH_TILES = [(128, 128), (512, 1024)]
+
+
+@pytest.mark.parametrize("bq,bk", FLASH_TILES)
+def test_flash_fwd_compiles(one_chip, bq, bk):
+    hlo = _compile(
+        lambda q, k, v: attention._flash_fwd_pallas(q, k, v, True, bq, bk),
+        *_qkv(one_chip),
+    )
+    assert hlo.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("bq,bk", FLASH_TILES)
+def test_flash_fwd_bwd_compiles(one_chip, kernels_are_the_path, bq, bk):
+    def loss(q, k, v):
+        out = attention.flash_attention(q, k, v, True, bq, bk)
+        return out.astype(jnp.float32).sum()
+
+    hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), *_qkv(one_chip))
+    assert hlo.count("tpu_custom_call") == 3  # fwd, dq, dk/dv
+
+
+# (tokens, d, vocab): Llama-3-8B's head at seq 2048, the widths the
+# backward was refused at under the default 16 MiB of scoped VMEM
+# ("Scoped allocation with size 22.52M and limit 16.00M" in the dx
+# kernel at d=4096, 18.00M in the dw kernel at d=2048 with 8192 tokens),
+# and Llama-3-70B's d=8192, where the tiles have to shrink as well
+CE_SHAPES = [(2048, 4096, 128256), (8192, 2048, 32768),
+             (2048, 8192, 128256)]
+
+
+def _ce_args(n, d, v, sharding):
+    return (
+        jax.ShapeDtypeStruct((1, n, d), jnp.bfloat16, sharding=sharding),
+        jax.ShapeDtypeStruct((d, v), jnp.bfloat16, sharding=sharding),
+        jax.ShapeDtypeStruct((1, n), jnp.int32, sharding=sharding),
+    )
+
+
+def _fused_nll(x, w, t):
+    return fused_ce._fused_ce(
+        fused_ce.DEFAULT_BLOCK_T, fused_ce.DEFAULT_BLOCK_V, False, x, w, t
+    )[0]
+
+
+@pytest.mark.parametrize("n,d,v", CE_SHAPES)
+def test_fused_ce_fwd_compiles(one_chip, n, d, v):
+    hlo = _compile(_fused_nll, *_ce_args(n, d, v, one_chip))
+    assert hlo.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("n,d,v", CE_SHAPES)
+def test_fused_ce_fwd_bwd_compiles(one_chip, n, d, v):
+    hlo = _compile(
+        jax.grad(_fused_nll, argnums=(0, 1)), *_ce_args(n, d, v, one_chip)
+    )
+    assert hlo.count("tpu_custom_call") == 3  # fwd, dx, dw
+
+
+# Over more than one device the kernels run per shard under shard_map:
+# left to the partitioner they are refused ("Mosaic kernels cannot be
+# automatically partitioned").
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    return build_mesh(MeshConfig(dp=-1, fsdp=4), devices=list(topo.devices))
+
+
+def test_flash_compiles_over_four_chips(mesh4, kernels_are_the_path):
+    sh = NamedSharding(mesh4, P(BATCH_AXES, None, None, None))
+    q = jax.ShapeDtypeStruct((4, 2048, 32, 128), jnp.bfloat16, sharding=sh)
+    kv = jax.ShapeDtypeStruct((4, 2048, 8, 128), jnp.bfloat16, sharding=sh)
+
+    def loss(q, k, v):
+        out = attention.flash_attention(q, k, v, mesh=mesh4)
+        return out.astype(jnp.float32).sum()
+
+    hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert hlo.count("tpu_custom_call") == 3
+
+
+def test_fused_ce_compiles_over_four_chips(mesh4, kernels_are_the_path):
+    n, d, v = 2048, 4096, 128256
+    x = jax.ShapeDtypeStruct(
+        (4, n, d), jnp.bfloat16,
+        sharding=NamedSharding(mesh4, P(BATCH_AXES, None, None)))
+    w = jax.ShapeDtypeStruct(
+        (d, v), jnp.bfloat16, sharding=NamedSharding(mesh4, P("fsdp", None)))
+    t = jax.ShapeDtypeStruct(
+        (4, n), jnp.int32, sharding=NamedSharding(mesh4, P(BATCH_AXES, None)))
+
+    def nll(x, w, t):
+        return fused_ce.cross_entropy_sums(x, w, t, mesh=mesh4)[0]
+
+    hlo = _compile(jax.grad(nll, argnums=(0, 1)), x, w, t)
+    assert hlo.count("tpu_custom_call") == 3
+    assert "all-gather" in hlo  # the fsdp-sharded head, gathered whole

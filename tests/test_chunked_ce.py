@@ -48,9 +48,9 @@ def iter_avals(jaxpr):
 
 
 def _avals_in(val):
-    if isinstance(val, jax.core.ClosedJaxpr):
+    if isinstance(val, jax.extend.core.ClosedJaxpr):
         yield from iter_avals(val.jaxpr)
-    elif isinstance(val, jax.core.Jaxpr):
+    elif isinstance(val, jax.extend.core.Jaxpr):
         yield from iter_avals(val)
     elif isinstance(val, (tuple, list)):
         for v in val:

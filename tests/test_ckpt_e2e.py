@@ -18,6 +18,7 @@ import os
 import re
 import subprocess
 import sys
+import tempfile
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SCRIPT = os.path.join(REPO, "tests", "e2e", "train_ckpt.py")
@@ -28,7 +29,7 @@ def _run(tmp_path, job_name, crash_mode):
 
     # worker logs append under a fixed path; stale lines from a previous
     # pytest invocation would satisfy the resume asserts spuriously
-    shutil.rmtree(f"/tmp/dlrover_tpu_logs/{job_name}", ignore_errors=True)
+    shutil.rmtree(f"{tempfile.gettempdir()}/dlrover_tpu_logs/{job_name}", ignore_errors=True)
     ckpt_dir = str(tmp_path / "ckpt")
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
@@ -53,7 +54,7 @@ def _run(tmp_path, job_name, crash_mode):
         text=True,
         timeout=300,
     )
-    log_dir = f"/tmp/dlrover_tpu_logs/{job_name}/node-0"
+    log_dir = f"{tempfile.gettempdir()}/dlrover_tpu_logs/{job_name}/node-0"
     logs = ""
     for f in sorted(os.listdir(log_dir)):
         if os.path.isdir(os.path.join(log_dir, f)):

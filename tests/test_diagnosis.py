@@ -115,6 +115,56 @@ def test_hang_operator_confirms_and_denies():
     assert fact.attribution == InferenceAttribute.NOT
 
 
+def test_handled_warnings_are_not_failures():
+    """Seen on the v5e: the checkpoint engine's handled warning about
+    HBM headroom matched OOM ignoring case, and the master restarted a
+    healthy worker every cycle."""
+    assert classify_log(
+        "insufficient HBM headroom for a device-side checkpoint "
+        "snapshot; blocking for the d2h transfer instead"
+    ) is None
+    assert classify_log("worker hit OOM in step 3") == "retryable"
+    assert classify_log("oom-killer: killed process 12") == "retryable"
+
+
+def test_a_log_report_is_judged_once():
+    dm = DiagnosisDataManager()
+    op = CheckFailureNodeOperator(dm)
+    dm.store_data(
+        TrainingLogRecord(node_id=3, logs=["XlaRuntimeError: RESOURCE_EXHAUSTED"])
+    )
+    (fact,) = op.infer([FAILURE_PROBLEM])
+    assert fact.attribution == InferenceAttribute.IS
+    # the same report, still the latest on the next cycle: no new verdict
+    (fact,) = op.infer([FAILURE_PROBLEM])
+    assert fact.attribution == InferenceAttribute.NOT
+    # the restarted worker fails the same way: a new report, a new verdict
+    dm.store_data(
+        TrainingLogRecord(node_id=3, logs=["XlaRuntimeError: RESOURCE_EXHAUSTED"],
+                          timestamp=time.time() + 1)
+    )
+    (fact,) = op.infer([FAILURE_PROBLEM])
+    assert fact.attribution == InferenceAttribute.IS
+
+
+def test_agent_log_tail_starts_at_this_incarnation(tmp_path):
+    """The worker log is appended to across restarts; what the stopped
+    worker wrote (the TPU runtime prints "SIGTERM received") is not the
+    new worker's failure signature."""
+    from dlrover_tpu.agent.elastic_agent import ElasticAgent, WorkerProc
+
+    log = tmp_path / "worker-0-restart0.log"
+    log.write_text("*** SIGTERM received by PID 1229 ***\n")
+    start = log.stat().st_size
+    with open(log, "a") as f:
+        f.write("step 7 loss 12.5\n")
+    worker = WorkerProc(0, 0, None, str(log), start)
+    tail = ElasticAgent._tail_log(None, worker)
+    assert tail == "step 7 loss 12.5\n"
+    assert classify_log(tail) is None
+    assert ElasticAgent._tail_log(None, worker, max_bytes=5) == "12.5\n"
+
+
 def test_full_chain_failure_to_action():
     dm = DiagnosisDataManager()
     dm.store_data(

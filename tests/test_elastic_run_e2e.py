@@ -8,6 +8,7 @@ worker-crash recovery, and a 2-node elastic world with a mid-training crash
 import os
 import subprocess
 import sys
+import tempfile
 import time
 
 import pytest
@@ -32,7 +33,7 @@ def _run_cli(args, env_extra=None, timeout=300):
 
 
 def _agent_logs(job_name, node_id=0):
-    log_dir = f"/tmp/dlrover_tpu_logs/{job_name}/node-{node_id}"
+    log_dir = f"{tempfile.gettempdir()}/dlrover_tpu_logs/{job_name}/node-{node_id}"
     out = ""
     if os.path.isdir(log_dir):
         for f in sorted(os.listdir(log_dir)):

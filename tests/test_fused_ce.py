@@ -22,11 +22,6 @@ from dlrover_tpu.ops.fused_ce import (
 
 jax.config.update("jax_platforms", "cpu")
 
-pytestmark = pytest.mark.skipif(
-    not fused_ce_available(interpret=True),
-    reason="Pallas not importable here; chunked fallback covers numerics",
-)
-
 
 # ---------------------------------------------------------------------------
 # references
@@ -178,6 +173,95 @@ def test_composes_under_jit_and_scan(xwt):
 
 
 # ---------------------------------------------------------------------------
+# tile geometry: every kernel blocks the whole feature dim, so its VMEM
+# grows with d (at the default 16 MiB grant the chip's compiler refused
+# 256x512 at d=4096): the kernels ask for _VMEM_LIMIT and the tiles
+# shrink only past _VMEM_BUDGET
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("backward", [False, True])
+@pytest.mark.parametrize(
+    "d,x_dtype,w_dtype",
+    [
+        (16, jnp.float32, jnp.float32),
+        (2048, jnp.bfloat16, jnp.bfloat16),
+        (4096, jnp.bfloat16, jnp.bfloat16),   # Llama-3-8B
+        (4096, jnp.bfloat16, jnp.float32),    # f32 master weights
+        (4096, jnp.float32, jnp.float32),
+        (6144, jnp.bfloat16, jnp.bfloat16),
+        (8192, jnp.bfloat16, jnp.bfloat16),   # Llama-3-70B
+    ],
+)
+def test_tile_geometry_fits_vmem_budget(d, x_dtype, w_dtype, backward):
+    n, v = 8192, 128256
+    bt, bv, n_pad, v_pad = fused_ce._tile_geometry(
+        n, v, d, x_dtype, w_dtype,
+        fused_ce.DEFAULT_BLOCK_T, fused_ce.DEFAULT_BLOCK_V, backward,
+    )
+    xb, wb = jnp.dtype(x_dtype).itemsize, jnp.dtype(w_dtype).itemsize
+    assert fused_ce._vmem_bytes(bt, bv, d, xb, wb, backward) \
+        <= fused_ce._VMEM_BUDGET
+    assert bt % 8 == 0 and bv % 128 == 0
+    assert n_pad % bt == 0 and v_pad % bv == 0
+    assert n_pad >= n and v_pad >= v
+    if d <= 4096 and jnp.dtype(x_dtype).itemsize == 2:
+        # MXU-sized up to Llama-3-8B's width
+        assert (bt, bv) == (fused_ce.DEFAULT_BLOCK_T,
+                            fused_ce.DEFAULT_BLOCK_V)
+    assert bt >= 128 and bv >= 128
+
+
+def test_tile_geometry_says_when_d_cannot_fit():
+    with pytest.raises(ValueError, match="DLROVER_TPU_FUSED_CE=0"):
+        fused_ce._tile_geometry(
+            8192, 128256, 65536, jnp.bfloat16, jnp.bfloat16,
+            fused_ce.DEFAULT_BLOCK_T, fused_ce.DEFAULT_BLOCK_V, True,
+        )
+
+
+@pytest.mark.parametrize("fsdp,tp", [(4, 1), (2, 2)])
+def test_kernel_runs_per_shard_under_a_mesh(xwt, monkeypatch, fsdp, tp):
+    """Over more than one device the dispatcher runs the kernel on each
+    device's tokens under shard_map (the partitioner refuses a Mosaic
+    kernel) with the head gathered whole; sums and grads match the
+    chunked path on the unsharded operands."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from dlrover_tpu.parallel import MeshConfig, build_mesh
+    from dlrover_tpu.parallel.mesh import BATCH_AXES, FSDP, TP
+
+    monkeypatch.setenv("DLROVER_TPU_FUSED_CE", "1")
+    mesh = build_mesh(MeshConfig(dp=-1, fsdp=fsdp, tp=tp),
+                      devices=jax.devices()[: fsdp * tp])
+    _, w, _ = xwt
+    rng = np.random.default_rng(1)
+    x = jnp.asarray(rng.normal(size=(4, T, D)), jnp.float32)
+    t = jnp.asarray(rng.integers(0, V, size=(4, T)), jnp.int32)
+    t = t.at[:, -1].set(-1)
+    xs = jax.device_put(x, NamedSharding(mesh, P(BATCH_AXES, None, None)))
+    ws = jax.device_put(w, NamedSharding(mesh, P(FSDP, TP)))
+    ts = jax.device_put(t, NamedSharding(mesh, P(BATCH_AXES, None)))
+
+    def mean_nll(x, w, t, **kw):
+        ns, nv = cross_entropy_sums(x, w, t, chunk_size=64, **kw)
+        return ns / nv
+
+    sharded = jax.jit(jax.value_and_grad(
+        lambda x, w: mean_nll(x, w, ts, interpret=True, mesh=mesh),
+        argnums=(0, 1),
+    ))
+    assert "shard_map" in str(jax.make_jaxpr(sharded)(xs, ws))
+    val, (gx, gw) = sharded(xs, ws)
+    ref, (rx, rw) = jax.value_and_grad(
+        lambda x, w: mean_nll(x, w, t), argnums=(0, 1)
+    )(x, w)  # CPU, no interpret: the chunked path
+    assert rel_err(val, ref) <= 1e-6
+    assert rel_err(gx, rx) <= 1e-5
+    assert rel_err(gw, rw) <= 1e-5
+
+
+# ---------------------------------------------------------------------------
 # dispatch contract: TPU-gated, kill-switch, fallback equivalence
 # ---------------------------------------------------------------------------
 
@@ -194,7 +278,7 @@ def test_dispatcher_falls_back_off_tpu(xwt, monkeypatch):
     cs, cv = chunked_ce.chunked_cross_entropy(x, w, t, chunk_size=64)
     assert float(nv) == float(cv)
     assert rel_err(ns, cs) <= 1e-6
-    with pytest.raises(RuntimeError, match="needs Pallas on TPU"):
+    with pytest.raises(RuntimeError, match="needs the TPU backend"):
         fused_cross_entropy(x, w, t)
 
 
@@ -217,7 +301,11 @@ def test_scoped_false_actually_disables_bool_flags(monkeypatch):
     silently compares the fused program against itself."""
     from dlrover_tpu.common import flags
 
-    monkeypatch.delenv("DLROVER_TPU_FUSED_CE", raising=False)
+    # set, then delete: monkeypatch restores what it saw first, and a
+    # delenv of an absent name records nothing, so the "0" that
+    # propagate() writes below would outlive this test
+    monkeypatch.setenv("DLROVER_TPU_FUSED_CE", "1")
+    monkeypatch.delenv("DLROVER_TPU_FUSED_CE")
     with flags.FUSED_CE.scoped(False):
         assert os.environ["DLROVER_TPU_FUSED_CE"] == "0"
         assert flags.FUSED_CE.get() is False

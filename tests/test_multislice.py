@@ -61,7 +61,7 @@ def test_multislice_psum_crosses_dcn_axis():
     """A dp-psum over the 2-slice mesh must produce the global sum — the
     collective path that rides DCN in production."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from dlrover_tpu.ops.shard_map_compat import shard_map
+    from jax import shard_map
 
     mesh = build_mesh(
         MeshConfig(dp=4, fsdp=1, ep=1, sp=1, tp=2),

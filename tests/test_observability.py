@@ -85,7 +85,7 @@ def test_chrome_export_epoch_clock_and_dump(traced, tmp_path):
 
 
 def test_pytracer_mirrors_into_spine(traced, monkeypatch):
-    """GC + user spans adopt the spine's span taxonomy: gc -> gc_pause,
+    """GC + user spans adopt the spine's span classification: gc -> gc_pause,
     dataloader -> input_wait, other cats -> host."""
     import gc
 

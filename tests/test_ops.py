@@ -138,9 +138,44 @@ def test_flash_attention_grad_matches_reference():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 
 
+@pytest.mark.parametrize("fsdp,tp", [(4, 1), (2, 2)])
+def test_flash_attention_per_shard_under_a_mesh(fsdp, tp):
+    """Over more than one device the Mosaic kernels run under shard_map
+    (the partitioner refuses them): batch rows over the data axes, heads
+    over tp. Values and grads match the unsharded reference."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from dlrover_tpu.parallel import MeshConfig, build_mesh
+    from dlrover_tpu.parallel.mesh import BATCH_AXES, TP
+
+    mesh = build_mesh(MeshConfig(dp=-1, fsdp=fsdp, tp=tp),
+                      devices=jax.devices()[: fsdp * tp])
+    q, k, v = _qkv(b=4, s=128, h=4, hkv=2, d=32)
+    sh = NamedSharding(mesh, P(BATCH_AXES, None, TP, None))
+    args = jax.device_put((q, k, v), sh)
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v) ** 2).sum()
+
+    flash = jax.jit(jax.value_and_grad(loss(
+        lambda q, k, v: flash_attention(q, k, v, True, 64, 64,
+                                        interpret=True, mesh=mesh)
+    ), argnums=(0, 1, 2)))
+    val, grads = flash(*args)
+    assert "shard_map" in str(jax.make_jaxpr(flash)(*args))
+    ref_val, ref_grads = jax.value_and_grad(
+        loss(lambda q, k, v: mha_reference(q, k, v, causal=True)),
+        argnums=(0, 1, 2),
+    )(q, k, v)
+    np.testing.assert_allclose(float(val), float(ref_val), rtol=1e-5)
+    for g, r in zip(grads, ref_grads):
+        assert g.sharding.is_equivalent_to(sh, g.ndim)
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=2e-4)
+
+
 def test_ring_attention_matches_reference():
     """Ring over a 4-device sp axis == full causal attention."""
-    from dlrover_tpu.ops.shard_map_compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     q, k, v = _qkv(b=2, s=64, h=4, hkv=2, d=16)
@@ -159,7 +194,7 @@ def test_ring_attention_matches_reference():
 
 
 def test_ring_attention_grads():
-    from dlrover_tpu.ops.shard_map_compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     q, k, v = _qkv(b=1, s=32, h=2, hkv=1, d=8)
@@ -192,7 +227,7 @@ def test_ulysses_attention_matches_reference():
     """All-to-all sequence parallelism over 4 devices == full causal
     attention (Ulysses pattern: scatter heads / gather seq around a
     single-device kernel)."""
-    from dlrover_tpu.ops.shard_map_compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from dlrover_tpu.ops.ulysses import ulysses_attention
@@ -213,7 +248,7 @@ def test_ulysses_attention_matches_reference():
 
 
 def test_ulysses_attention_grads():
-    from dlrover_tpu.ops.shard_map_compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from dlrover_tpu.ops.ulysses import ulysses_attention
@@ -238,7 +273,7 @@ def test_ulysses_gqa_replicates_kv_heads_below_sp():
     """GQA with hkv < sp: kv heads replicate so the head scatter
     divides (DeepSpeed-Ulysses GQA treatment) — output matches the
     unsharded reference exactly."""
-    from dlrover_tpu.ops.shard_map_compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from dlrover_tpu.ops.attention import mha_reference
@@ -262,7 +297,7 @@ def test_ulysses_gqa_replicates_kv_heads_below_sp():
 
 
 def test_ulysses_rejects_unreplicatable_heads():
-    from dlrover_tpu.ops.shard_map_compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from dlrover_tpu.ops.ulysses import ulysses_attention
@@ -287,7 +322,7 @@ def test_ring_and_ulysses_agree_at_longer_seq():
     """The two SP strategies are interchangeable: at seq 512 over sp=4
     both match full attention (and therefore each other) with GQA-free
     heads — the swap a user makes via attn_impl must be numerics-neutral."""
-    from dlrover_tpu.ops.shard_map_compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from dlrover_tpu.ops.ulysses import ulysses_attention

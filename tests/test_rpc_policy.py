@@ -60,7 +60,7 @@ def test_poll_intervals_grow_jittered_and_never_exhaust():
     assert first != other
 
 
-def test_classify_taxonomy():
+def test_classify_error_classes():
     class FakeCode:
         def __init__(self, name):
             self.name = name

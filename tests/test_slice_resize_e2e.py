@@ -14,6 +14,7 @@ import re
 import signal
 import subprocess
 import sys
+import tempfile
 import time
 
 import pytest
@@ -49,7 +50,7 @@ def _env(slice_name, ckpt_dir):
 
 
 def _worker_log(job, node_id):
-    log_dir = f"/tmp/dlrover_tpu_logs/{job}/node-{node_id}"
+    log_dir = f"{tempfile.gettempdir()}/dlrover_tpu_logs/{job}/node-{node_id}"
     out = ""
     if os.path.isdir(log_dir):
         for f in sorted(os.listdir(log_dir)):
@@ -112,7 +113,7 @@ def test_slice_count_resize_2_1_2(tmp_path):
     # stale logs from a previous run would satisfy _wait_for patterns
     import shutil
 
-    shutil.rmtree(f"/tmp/dlrover_tpu_logs/{job}", ignore_errors=True)
+    shutil.rmtree(f"{tempfile.gettempdir()}/dlrover_tpu_logs/{job}", ignore_errors=True)
     try:
         addr = f"127.0.0.1:{master.port}"
         # start_new_session so killing an agent's group never touches the

@@ -437,7 +437,7 @@ def test_non_string_dict_keys_banned_at_encode():
     assert back.comm_links == {"ici": 5, "dcn": 7}
 
 
-def test_rpc_client_maps_unknown_type_into_taxonomy():
+def test_rpc_client_maps_unknown_type_into_classification():
     """The OverloadedResponse hazard class, closed: a response type
     this binary cannot decode surfaces as the typed, non-retryable
     UnknownMessageTypeError naming the _t — never a raw ValueError
