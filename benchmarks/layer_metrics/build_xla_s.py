@@ -1,0 +1,2 @@
+"""``build_xla_s``: see ``build_xla_s.json``."""
+from benchmarks.harness.program_spans import counter_seconds_mean as read  # noqa: F401

@@ -1,0 +1,2 @@
+"""``first_step_host_s``: see ``first_step_host_s.json``."""
+from benchmarks.harness.program_spans import counter_seconds_mean as read  # noqa: F401

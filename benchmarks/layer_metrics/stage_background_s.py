@@ -1,0 +1,2 @@
+"""``stage_background_s``: see ``stage_background_s.json``."""
+from benchmarks.harness.program_spans import counter_seconds_mean as read  # noqa: F401

@@ -179,6 +179,11 @@ def _install_drain_hooks():
         pass  # not the main thread: atexit alone still covers exits
 
 
+#: a device-side snapshot is taken only where every local device has
+#: this many times the bytes of its shards free
+_SNAPSHOT_SLACK = 1.15
+
+
 class CheckpointEngine:
     def __init__(
         self,
@@ -406,84 +411,87 @@ class CheckpointEngine:
                 total += v
             return total
 
-        for path, leaf in flat:
-            if isinstance(leaf, jax.Array) and hasattr(leaf, "addressable_shards"):
-                owned_by_parent = None
-                if own is not None:
-                    try:
-                        assigns = ownership.assign_leaf(
-                            tuple(leaf.shape), leaf.sharding, own[2], rr
-                        )
-                        owned_by_parent = {}
-                        for a in assigns:
-                            if a.owner == own[0]:
-                                owned_by_parent.setdefault(
-                                    a.parent_ranges, []
-                                ).append(a)
-                    except Exception as e:
-                        # degrade to staging every unique shard: a leaf we
-                        # cannot partition must never be silently dropped
-                        logger.warning(
-                            "ownership derivation failed for %s (%s); "
-                            "staging all unique shards", path, e,
-                        )
-                seen = set()
-                k = 0
-                for shard in leaf.addressable_shards:
-                    ranges = _index_to_ranges(shard.index, leaf.shape)
-                    if ranges in seen:
-                        continue
-                    seen.add(ranges)
-                    shard_bytes = int(
-                        np.prod(shard.data.shape, dtype=np.int64)
-                        * shard.data.dtype.itemsize
-                    )
-                    subs = None
-                    if owned_by_parent is not None:
-                        mine = owned_by_parent.get(ranges, [])
-                        if not mine:
-                            skipped_bytes += shard_bytes
-                            continue
-                        if len(mine) > 1 or mine[0].ranges != ranges:
-                            subs = mine
-                            skipped_bytes += shard_bytes - (
-                                _owned_vol(mine)
-                                * shard.data.dtype.itemsize
+        with trace.span("ckpt_save", "d2h.issue") as issued:
+            for path, leaf in flat:
+                if isinstance(leaf, jax.Array) and hasattr(
+                        leaf, "addressable_shards"):
+                    owned_by_parent = None
+                    if own is not None:
+                        try:
+                            assigns = ownership.assign_leaf(
+                                tuple(leaf.shape), leaf.sharding, own[2], rr
                             )
-                    try:
-                        shard.data.copy_to_host_async()
-                    except Exception:
-                        pass
-                    extent = tuple(e - s for s, e in ranges)
+                            owned_by_parent = {}
+                            for a in assigns:
+                                if a.owner == own[0]:
+                                    owned_by_parent.setdefault(
+                                        a.parent_ranges, []
+                                    ).append(a)
+                        except Exception as e:
+                            # degrade to staging every unique shard: a leaf we
+                            # cannot partition must never be silently dropped
+                            logger.warning(
+                                "ownership derivation failed for %s (%s); "
+                                "staging all unique shards", path, e,
+                            )
+                    seen = set()
+                    k = 0
+                    for shard in leaf.addressable_shards:
+                        ranges = _index_to_ranges(shard.index, leaf.shape)
+                        if ranges in seen:
+                            continue
+                        seen.add(ranges)
+                        shard_bytes = int(
+                            np.prod(shard.data.shape, dtype=np.int64)
+                            * shard.data.dtype.itemsize
+                        )
+                        subs = None
+                        if owned_by_parent is not None:
+                            mine = owned_by_parent.get(ranges, [])
+                            if not mine:
+                                skipped_bytes += shard_bytes
+                                continue
+                            if len(mine) > 1 or mine[0].ranges != ranges:
+                                subs = mine
+                                skipped_bytes += shard_bytes - (
+                                    _owned_vol(mine)
+                                    * shard.data.dtype.itemsize
+                                )
+                        try:
+                            shard.data.copy_to_host_async()
+                        except Exception:
+                            pass
+                        extent = tuple(e - s for s, e in ranges)
+                        plan.append(
+                            (f"{path}#s{k}", shard.data, extent, ranges,
+                             tuple(leaf.shape), subs)
+                        )
+                        k += 1
+                else:
+                    arr = np.asarray(leaf)
+                    full = tuple((0, d) for d in arr.shape)
+                    subs = None
+                    if own is not None:
+                        mine = [
+                            a
+                            for a in ownership.assign_host_leaf(
+                                tuple(arr.shape), own[1], rr
+                            )
+                            if a.owner == own[0]
+                        ]
+                        if not mine:
+                            skipped_bytes += int(arr.nbytes)
+                            continue
+                        if len(mine) > 1 or mine[0].ranges != full:
+                            subs = mine
+                            skipped_bytes += int(arr.nbytes) - (
+                                _owned_vol(mine) * arr.dtype.itemsize
+                            )
                     plan.append(
-                        (f"{path}#s{k}", shard.data, extent, ranges,
-                         tuple(leaf.shape), subs)
+                        (f"{path}#s0", arr, tuple(arr.shape), full,
+                         tuple(arr.shape), subs)
                     )
-                    k += 1
-            else:
-                arr = np.asarray(leaf)
-                full = tuple((0, d) for d in arr.shape)
-                subs = None
-                if own is not None:
-                    mine = [
-                        a
-                        for a in ownership.assign_host_leaf(
-                            tuple(arr.shape), own[1], rr
-                        )
-                        if a.owner == own[0]
-                    ]
-                    if not mine:
-                        skipped_bytes += int(arr.nbytes)
-                        continue
-                    if len(mine) > 1 or mine[0].ranges != full:
-                        subs = mine
-                        skipped_bytes += int(arr.nbytes) - (
-                            _owned_vol(mine) * arr.dtype.itemsize
-                        )
-                plan.append(
-                    (f"{path}#s0", arr, tuple(arr.shape), full,
-                     tuple(arr.shape), subs)
-                )
+            issued.set(shards=len(plan))
         # Pass 2: consume (np.asarray reuses the host literal the async
         # copy produced, so this is a wait + memcpy, not a transfer).
         # Split shards stage only their owned chunks — sliced views of
@@ -491,23 +499,25 @@ class CheckpointEngine:
         named_leaves: List[Tuple[str, np.ndarray]] = []
         shard_info: Dict[str, Tuple[Tuple[int, ...], Tuple]] = {}
         staged_bytes = 0
-        for name, data, extent, ranges, gshape, subs in plan:
-            host = np.asarray(data).reshape(extent)
-            if subs is None:
-                staged_bytes += int(host.nbytes)
-                named_leaves.append((name, host))
-                shard_info[name] = (gshape, ranges)
-                continue
-            for j, a in enumerate(subs):
-                rel = tuple(
-                    slice(s - ps, e - ps)
-                    for (s, e), (ps, _) in zip(a.ranges, ranges)
-                )
-                piece = np.ascontiguousarray(host[rel])
-                staged_bytes += int(piece.nbytes)
-                sub_name = f"{name}.{j}"
-                named_leaves.append((sub_name, piece))
-                shard_info[sub_name] = (gshape, a.ranges)
+        with trace.span("ckpt_save", "d2h.wait") as waited:
+            for name, data, extent, ranges, gshape, subs in plan:
+                host = np.asarray(data).reshape(extent)
+                if subs is None:
+                    staged_bytes += int(host.nbytes)
+                    named_leaves.append((name, host))
+                    shard_info[name] = (gshape, ranges)
+                    continue
+                for j, a in enumerate(subs):
+                    rel = tuple(
+                        slice(s - ps, e - ps)
+                        for (s, e), (ps, _) in zip(a.ranges, ranges)
+                    )
+                    piece = np.ascontiguousarray(host[rel])
+                    staged_bytes += int(piece.nbytes)
+                    sub_name = f"{name}.{j}"
+                    named_leaves.append((sub_name, piece))
+                    shard_info[sub_name] = (gshape, a.ranges)
+            waited.set(bytes=staged_bytes)
         # single-writer (the staging thread); readers only sample it
         self.last_stage_stats = {
             "staged_bytes": staged_bytes,
@@ -524,23 +534,22 @@ class CheckpointEngine:
         joins the in-flight stage first.
         """
         t0 = time.time()
-        m0 = time.monotonic()
-        if self._async_staging:
-            blocking = self._start_async_stage(t0, step, state, persist=False)
-        else:
-            try:
-                self._stage_sync(step, state)
-            except TimeoutError as e:
-                logger.warning("%s; skipping memory save", e)
-                return time.time() - t0
-            blocking = time.time() - t0
-            self._report_save(step, blocking)
         # trace spine: the training PAUSE this save cost (the background
-        # stage records its own span from the staging thread)
-        trace.record(
-            "ckpt_save", "save.blocking", m0, blocking,
-            tier="shm", step=step, mode=self.last_stage_mode,
-        )
+        # stage has its own span, on the staging thread, caused by this)
+        with trace.span("ckpt_save", "save.blocking", step=step,
+                        tier="shm") as pause:
+            if self._async_staging:
+                blocking = self._start_async_stage(
+                    t0, step, state, persist=False, cause=pause.id)
+            else:
+                try:
+                    self._stage_sync(step, state)
+                except TimeoutError as e:
+                    logger.warning("%s; skipping memory save", e)
+                    return time.time() - t0
+                blocking = time.time() - t0
+                self._report_save(step, blocking)
+            pause.set(mode=self.last_stage_mode)
         return blocking
 
     def _install_crash_drain(self):
@@ -577,19 +586,23 @@ class CheckpointEngine:
             logger.warning("checkpoint drain at exit failed: %s", e)
 
     def _start_async_stage(
-        self, t0: float, step: int, state: Any, persist: bool
+        self, t0: float, step: int, state: Any, persist: bool,
+        cause: Optional[int] = None,
     ) -> float:
         self._install_crash_drain()
         # Degrade, don't crash training: a failure of the PREVIOUS cycle's
         # staging (incl. its shm-lock timeout) means that step was lost —
         # log it and carry on with this one. The unbounded join means the
         # previous thread is always finished here, so the shm is free.
-        try:
-            self.wait_staging()
-        except Exception as e:
-            logger.warning(
-                "previous background staging failed (%s); continuing", e
-            )
+        with trace.span("ckpt_save", "save.join_previous",
+                        joined=int(self._staging_thread is not None
+                                   and self._staging_thread.is_alive())):
+            try:
+                self.wait_staging()
+            except Exception as e:
+                logger.warning(
+                    "previous background staging failed (%s); continuing", e
+                )
         self._staging_error = None
         # Preferred: device-side snapshot — blocking cost is one HBM->HBM
         # copy; the d2h transfer moves to the background thread, so the
@@ -615,7 +628,7 @@ class CheckpointEngine:
         pause = time.time() - t0
         self._staging_thread = threading.Thread(
             target=self._stage_in_background,
-            args=(step, payload, on_device, persist, pause),
+            args=(step, payload, on_device, persist, pause, cause),
             name="ckpt-staging",
             daemon=True,
         )
@@ -629,9 +642,17 @@ class CheckpointEngine:
         jitted copy (milliseconds). Returns the snapshot pytree, or None
         when the engine should fall back to the blocking d2h stage
         (snapshot disabled, nothing on device, insufficient HBM headroom,
-        or the copy itself failed, e.g. a racing allocation OOMed it)."""
+        or the copy itself failed, e.g. a racing allocation OOMed it).
+        The ``save.snapshot`` span says which (``why``)."""
+        with trace.span("ckpt_save", "save.snapshot") as sp:
+            snapshot, why = self._try_snapshot(state, sp)
+            sp.set(taken=int(snapshot is not None), why=why)
+        return snapshot
+
+    def _try_snapshot(self, state, sp):
+        """(snapshot, "") or (None, why not)."""
         if not self._device_snapshot_enabled:
-            return None
+            return None, "off"
         import jax
 
         flat, treedef = jax.tree_util.tree_flatten(state)
@@ -642,13 +663,15 @@ class CheckpointEngine:
             and hasattr(leaf, "addressable_shards")
         ]
         if not idx:
-            return None
-        if not self._hbm_headroom_ok([flat[i] for i in idx]):
+            return None, "no_arrays"
+        need, free = self._hbm_headroom([flat[i] for i in idx])
+        sp.set(need_bytes=need, free_bytes=free)
+        if free is not None and free < need * _SNAPSHOT_SLACK:
             logger.warning(
                 "insufficient HBM headroom for a device-side checkpoint "
                 "snapshot; blocking for the d2h transfer instead"
             )
-            return None
+            return None, "headroom"
         if self._snap_fn is None:
             import jax.numpy as jnp
 
@@ -667,15 +690,17 @@ class CheckpointEngine:
                 "device-side snapshot failed (%s); blocking for the d2h "
                 "transfer instead", e
             )
-            return None
+            return None, "failed"
         for i, c in zip(idx, copies):
             flat[i] = c
-        return jax.tree_util.tree_unflatten(treedef, flat)
+        return jax.tree_util.tree_unflatten(treedef, flat), ""
 
     @staticmethod
-    def _hbm_headroom_ok(arrays, slack: float = 1.15) -> bool:
-        """Check each local device can hold a second copy of its shards.
-        Optimistic when the backend exposes no memory stats (CPU)."""
+    def _hbm_headroom(arrays) -> Tuple[int, Optional[int]]:
+        """(bytes a second copy of its shards takes, bytes free) on the
+        local device with the least room to spare. Free is None when the
+        backend exposes no memory stats (CPU): the caller is optimistic
+        then."""
         need: Dict[Any, int] = {}
         for leaf in arrays:
             seen = set()
@@ -689,6 +714,7 @@ class CheckpointEngine:
                     * shard.data.dtype.itemsize
                 )
                 need[shard.device] = need.get(shard.device, 0) + nbytes
+        tightest = (max(need.values(), default=0), None)
         for dev, nbytes in need.items():
             try:
                 stats = dev.memory_stats()
@@ -698,9 +724,14 @@ class CheckpointEngine:
                 continue
             limit = stats.get("bytes_limit")
             used = stats.get("bytes_in_use")
-            if limit and used is not None and (limit - used) < nbytes * slack:
-                return False
-        return True
+            if not limit or used is None:
+                continue
+            free = int(limit - used)
+            if tightest[1] is None or (
+                free - nbytes < tightest[1] - tightest[0]
+            ):
+                tightest = (nbytes, free)
+        return tightest
 
     def wait_staging(self, timeout: Optional[float] = None):
         """Join any in-flight background stage; re-raise its failure.
@@ -720,17 +751,18 @@ class CheckpointEngine:
 
     def _stage_in_background(
         self, step: int, payload, on_device: bool, persist: bool,
-        pause: float
+        pause: float, cause: Optional[int] = None,
     ):
         try:
-            with trace.span("ckpt_save", "stage.background", tier="shm",
-                            step=step):
+            with trace.span("ckpt_save", "stage.background", cause=cause,
+                            step=step, tier="shm"):
                 if on_device:
                     # d2h off the training critical path: the source is
                     # the private device snapshot, untouchable by
                     # donation.
                     payload = self._gather_local_shards(payload)
-                self._wait_pending_persist()
+                with trace.span("ckpt_save", "stage.wait_persist"):
+                    self._wait_pending_persist()
                 self._write_shm(step, payload)
             if persist:
                 self._queue_persist(step)
@@ -753,7 +785,8 @@ class CheckpointEngine:
 
     def _stage_sync(self, step: int, state: Any):
         self.last_stage_mode = "sync"
-        self._wait_pending_persist()
+        with trace.span("ckpt_save", "stage.wait_persist"):
+            self._wait_pending_persist()
         self._write_shm(step, self._gather_local_shards(state))
 
     def _write_shm(self, step: int, snapshot):
@@ -761,25 +794,30 @@ class CheckpointEngine:
 
         named_leaves, shard_info, treedef_bytes, leaf_paths = snapshot
         lock = self._lock()
-        if lock is not None and not lock.acquire(timeout=120):
-            raise TimeoutError(
-                f"shm lock not acquired in 120s; step {step} not staged"
-            )
+        with trace.span("ckpt_save", "stage.shm_lock"):
+            if lock is not None and not lock.acquire(timeout=120):
+                raise TimeoutError(
+                    f"shm lock not acquired in 120s; step {step} not staged"
+                )
+        staged_bytes = sum(int(a.nbytes) for _, a in named_leaves)
         try:
-            self._shm.save_state(
-                step,
-                named_leaves,
-                treedef_bytes,
-                shard_info=shard_info,
-                world_size=jax.process_count(),
-                process_id=self.process_id,
-                ckpt_dir=os.path.abspath(self.ckpt_dir),
-                leaf_paths=leaf_paths,
-            )
+            with trace.span("ckpt_save", "stage.shm_write",
+                            bytes=staged_bytes):
+                self._shm.save_state(
+                    step,
+                    named_leaves,
+                    treedef_bytes,
+                    shard_info=shard_info,
+                    world_size=jax.process_count(),
+                    process_id=self.process_id,
+                    ckpt_dir=os.path.abspath(self.ckpt_dir),
+                    leaf_paths=leaf_paths,
+                )
         finally:
             if lock is not None:
                 lock.release()
         self.latest_saved_step = step
+        trace.gauge("ckpt.staged_bytes", staged_bytes)
         # replica mode (agent-set env): tell the saver to stream this staged
         # state to the backup peer, off the training critical path
         if flags.CKPT_REPLICA.get() == "1":
@@ -803,30 +841,25 @@ class CheckpointEngine:
     def save_to_storage(self, step: int, state: Any) -> float:
         """Stage + hand persistence to the agent saver (async)."""
         t0 = time.time()
-        m0 = time.monotonic()
-        if self._async_staging:
-            blocking = self._start_async_stage(t0, step, state, persist=True)
-            trace.record(
-                "ckpt_save", "save.blocking", m0, blocking,
-                tier="shm", step=step, mode=self.last_stage_mode,
-                persist=True,
-            )
-            return blocking
-        try:
-            self._stage_sync(step, state)
-        except TimeoutError as e:
-            # staging was skipped (shm lock timeout): queuing a persist
-            # event would make the saver persist a stale step as if it were
-            # this one — surface the failure instead
-            logger.error("%s; skipping persist", e)
-            return time.time() - t0
-        self._queue_persist(step)
-        blocking = time.time() - t0
-        self._report_save(step, blocking)
-        trace.record(
-            "ckpt_save", "save.blocking", m0, blocking,
-            tier="shm", step=step, mode=self.last_stage_mode, persist=True,
-        )
+        with trace.span("ckpt_save", "save.blocking", step=step,
+                        tier="shm", persist=True) as pause:
+            if self._async_staging:
+                blocking = self._start_async_stage(
+                    t0, step, state, persist=True, cause=pause.id)
+            else:
+                try:
+                    self._stage_sync(step, state)
+                except TimeoutError as e:
+                    # staging was skipped (shm lock timeout): queuing a
+                    # persist event would make the saver persist a stale
+                    # step as if it were this one — surface the failure
+                    # instead
+                    logger.error("%s; skipping persist", e)
+                    return time.time() - t0
+                self._queue_persist(step)
+                blocking = time.time() - t0
+                self._report_save(step, blocking)
+            pause.set(mode=self.last_stage_mode)
         return blocking
 
     def _persist_inline(self, step: int):

@@ -334,7 +334,9 @@ TRACE = _define(
     "Unified trace spine (observability/trace.py): record typed spans "
     "(step/compile/rendezvous/state_transfer/ckpt_save/ckpt_restore/"
     "input_wait/gc_pause/eval) into the process-wide ring. Off by "
-    "default; recording is lock+append only, never a host sync.",
+    "default; recording is lock+append only, never a host sync. The "
+    "spans' counters, gauges and profiler annotations do not depend "
+    "on it.",
 )
 TRACE_DIR = _define(
     "DLROVER_TPU_TRACE_DIR", "", "str",

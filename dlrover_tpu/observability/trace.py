@@ -1,4 +1,4 @@
-"""Unified trace spine: typed spans in a bounded process-wide ring.
+"""Unified trace spine: one ``span()`` call, three sinks.
 
 The repo's instruments grew as disjoint ledgers — the compile ledger
 (train/warm_compile.py), ResizeLedger (train/live_reshard.py), the comm
@@ -10,43 +10,74 @@ chrome-trace JSON that merges with every other rank's (and the
 interposer's ``/timeline`` dump) into a single perfetto-loadable job
 timeline (``python -m dlrover_tpu.profiler.analysis job-timeline``).
 
+Sinks
+-----
+Every span closed through ``span()`` reaches
+
+- the *counters*, always: a per-name ``(count, seconds)`` table, beside
+  the gauges ``gauge(name, value)`` sets. ``counters()`` / ``gauges()``
+  hand out copies; a reader in the same process (the benchmark's
+  per-layer metrics) needs nothing passed to it;
+- the *profiler's host plane*, whenever JAX is loaded and a profiler
+  session is on: a ``jax.profiler.TraceAnnotation`` named
+  ``dlrover/<name>`` with ``kind``, ``id``, ``parent``, ``step`` and the
+  span's attributes as stats, in the same ``.xplane.pb`` and on the same
+  clock as the device's ``XLA Ops`` line. This module never imports JAX
+  (master and agent import it): it finds ``jax.profiler`` in
+  ``sys.modules`` or goes without;
+- the *ring*, behind ``DLROVER_TPU_TRACE``: the operator's dumps and the
+  job timeline, as before, with ``id``, ``parent`` and ``step`` in
+  ``attrs``.
+
+Back-dated ``record()`` (an emitter that measured its own duration, the
+synthetic resize lane) reaches the ring only.
+
+Identity
+--------
+``id`` is a process-wide counter. ``parent`` is the span that caused
+this one: the enclosing span of the same thread (a context variable),
+or, for work handed to another thread, the ``cause=<id>`` the caller
+passes. ``step`` is the identifier the spans of one piece of work
+share (the host step for trainer spans, the checkpoint's step for every
+span of one save, on either thread); a span without one inherits its
+enclosing span's.
+
 Clock basis
 -----------
-Spans are stamped with ``time.monotonic()`` (immune to NTP steps while
-the process lives); the ring captures one ``(monotonic, wallclock)``
-pair at construction so exports map every span to absolute epoch
-microseconds. Ranks on NTP-synced hosts therefore merge on real time
-with no cross-process handshake; the merge CLI re-bases sources that
-lack the epoch metadata (interposer dumps) best-effort.
+The profiler stamps its events itself, on the clock of the device
+trace. Ring spans are stamped with ``time.monotonic()`` (immune to NTP
+steps while the process lives); the ring captures one ``(monotonic,
+wallclock)`` pair at construction so exports map every span to absolute
+epoch microseconds. Ranks on NTP-synced hosts therefore merge on real
+time with no cross-process handshake; the merge CLI re-bases sources
+that lack the epoch metadata (interposer dumps) best-effort.
 
 Hot-path contract
 -----------------
-``record()`` is two clock reads, a dict build and a lock+append —
-never a device sync (graftlint JG002 stays green for the emitters in
-``ElasticTrainer.step``). When ``DLROVER_TPU_TRACE`` is off (the
-default) every entry point returns after one dict lookup.
+A span is two clock reads, an inert profiler check and one locked table
+update — never a device sync (graftlint JG002 stays green for the
+emitters in ``ElasticTrainer.step``). When ``DLROVER_TPU_TRACE`` is off
+(the default) nothing is appended to the ring.
 """
 
 from __future__ import annotations
 
-import contextlib
+import contextvars
+import itertools
 import json
 import os
+import sys
 import tempfile
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 from dlrover_tpu.common import flags
 from dlrover_tpu.common.log import logger
 
 #: the span classification (docs/design/observability.md). ``downtime`` is
 #: master-side only (the SpeedMonitor's bracket spans); ``host`` is the
-#: catch-all PyTracer user spans map onto; ``kernel`` is the per-kernel
-#: breakdown lane the kernel ledger (profiler/kernel_ledger.py) emits —
-#: its spans nest INSIDE step spans, which is why the kind is absent
-#: from KIND_CATEGORY below (it decomposes "productive", it does not
-#: add to it).
+#: catch-all PyTracer user spans map onto.
 SPAN_KINDS = (
     "step",
     "compile",
@@ -59,8 +90,10 @@ SPAN_KINDS = (
     "eval",
     "downtime",
     "host",
-    "kernel",
 )
+
+#: what the profiler's host plane calls a span of this module
+PROFILER_PREFIX = "dlrover/"
 
 
 def enabled() -> bool:
@@ -68,10 +101,88 @@ def enabled() -> bool:
     return bool(flags.TRACE.get())
 
 
-class TraceRing:
-    """Process-wide bounded span recorder (thread-safe).
+_span_ids = itertools.count(1)
+_current_span: contextvars.ContextVar = contextvars.ContextVar(
+    "dlrover_tpu_span", default=None
+)
 
-    Spans: ``{"kind", "name", "t" (monotonic start, s), "dur" (s),
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` if the process has loaded JAX and
+    a profiler session is on, else None. Looked up, never imported."""
+    mod = sys.modules.get("jax.profiler")
+    cls = getattr(mod, "TraceAnnotation", None)
+    if cls is None or not cls.is_enabled():
+        return None
+    return cls
+
+
+class Span:
+    """One open span; ``with ring.span(...) as sp`` hands it out so the
+    block can ``sp.set(bytes=...)`` what it learns and pass ``sp.id`` as
+    the ``cause`` of work it hands to another thread. ``dur`` holds the
+    seconds once the block has closed."""
+
+    __slots__ = ("_ring", "kind", "name", "attrs", "id", "parent", "step",
+                 "dur", "_nested", "_t0", "_token", "_annotation")
+
+    def __init__(self, ring, kind, name, cause, step, attrs):
+        self._ring = ring
+        self.kind = kind
+        self.name = name
+        self.attrs = attrs
+        self.parent = cause
+        self.step = step
+        self.dur = 0.0
+
+    def set(self, **attrs):
+        self.attrs.update(attrs)
+
+    def __enter__(self):
+        outer = _current_span.get()
+        self.id = next(_span_ids)
+        # a span inside one of its own kind decomposes it: it adds
+        # nothing to the kind's total
+        self._nested = outer is not None and outer.kind == self.kind
+        if outer is not None:
+            if self.parent is None:
+                self.parent = outer.id
+            if self.step is None:
+                self.step = outer.step
+        self._token = _current_span.set(self)
+        self._annotation = None
+        cls = _profiler_annotation()
+        if cls is not None:
+            stats = {"kind": self.kind, "id": self.id,
+                     "parent": self.parent or 0}
+            if self.step is not None:
+                stats["step"] = self.step
+            self._annotation = cls(PROFILER_PREFIX + self.name, **stats)
+            self._annotation.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc):
+        self.dur = time.monotonic() - self._t0
+        if self._annotation is not None:
+            clean = _clean(self.attrs)
+            if clean:
+                self._annotation.set_metadata(**clean)
+            self._annotation.__exit__(*exc)
+        _current_span.reset(self._token)
+        self._ring._close(self)
+        return False
+
+
+def _clean(attrs: Dict) -> Dict:
+    return {k: v for k, v in attrs.items() if v not in (None, "")}
+
+
+class TraceRing:
+    """Process-wide span recorder (thread-safe): the always-on counters
+    and gauges, and the bounded ring behind ``DLROVER_TPU_TRACE``.
+
+    Ring spans: ``{"kind", "name", "t" (monotonic start, s), "dur" (s),
     "tid", "attrs"?}``. Per-kind cumulative seconds survive ring
     overflow — the attribution consumers read those, the timeline
     consumers read the (windowed) spans.
@@ -84,6 +195,8 @@ class TraceRing:
         self._mono0 = time.monotonic()
         self._wall0 = time.time()
         self._kind_seconds: Dict[str, float] = {}
+        self._counters: Dict[str, List] = {}     # name -> [count, seconds]
+        self._gauges: Dict[str, float] = {}
 
     # -- recording -----------------------------------------------------
 
@@ -105,11 +218,17 @@ class TraceRing:
         tid: Optional[int] = None,
         **attrs,
     ) -> None:
-        """Record one completed span. ``start_mono`` is a
-        ``time.monotonic()`` stamp; emitters that already measured a
-        duration call this with their own numbers."""
+        """Record one completed span in the ring (and nowhere else).
+        ``start_mono`` is a ``time.monotonic()`` stamp; emitters that
+        already measured a duration call this with their own numbers."""
         if not enabled():
             return
+        ev = self._event(kind, name, start_mono, dur_s, tid, attrs)
+        with self._lock:
+            self._append(ev, counts_for_kind=True)
+
+    @staticmethod
+    def _event(kind, name, start_mono, dur_s, tid, attrs) -> Dict[str, Any]:
         ev: Dict[str, Any] = {
             "kind": kind,
             "name": name,
@@ -117,30 +236,52 @@ class TraceRing:
             "dur": max(0.0, float(dur_s)),
             "tid": tid if tid is not None else threading.get_ident() % 100000,
         }
-        clean = {k: v for k, v in attrs.items() if v not in (None, "")}
+        clean = _clean(attrs)
         if clean:
             ev["attrs"] = clean
-        with self._lock:
-            self._events.append(ev)
+        return ev
+
+    def _append(self, ev: Dict, counts_for_kind: bool):
+        """Under ``self._lock``."""
+        self._events.append(ev)
+        if counts_for_kind:
+            kind = ev["kind"]
             self._kind_seconds[kind] = (
                 self._kind_seconds.get(kind, 0.0) + ev["dur"]
             )
-            cap = self.capacity
-            if len(self._events) > cap:
-                del self._events[: len(self._events) // 2]
+        cap = self.capacity
+        if len(self._events) > cap:
+            del self._events[: len(self._events) // 2]
 
-    @contextlib.contextmanager
-    def span(self, kind: str, name: Optional[str] = None, **attrs):
-        """``with trace_ring.span("ckpt_restore", tier="disk"): ...``"""
-        if not enabled():
-            yield
-            return
-        t0 = time.monotonic()
-        try:
-            yield
-        finally:
-            self.record(kind, name or kind, t0, time.monotonic() - t0,
-                        **attrs)
+    def span(self, kind: str, name: Optional[str] = None, *,
+             cause: Optional[int] = None, step: Optional[int] = None,
+             **attrs) -> Span:
+        """``with trace_ring.span("ckpt_restore", tier="disk") as sp: ...``
+        — to all three sinks (module docstring)."""
+        return Span(self, kind, name or kind, cause, step, attrs)
+
+    def _close(self, sp: Span):
+        ev = None
+        if enabled():
+            ev = self._event(
+                sp.kind, sp.name, sp._t0, sp.dur, None,
+                dict(sp.attrs, id=sp.id, parent=sp.parent, step=sp.step),
+            )
+        with self._lock:
+            row = self._counters.get(sp.name)
+            if row is None:
+                self._counters[sp.name] = [1, sp.dur]
+            else:
+                row[0] += 1
+                row[1] += sp.dur
+            if ev is not None:
+                self._append(ev, counts_for_kind=not sp._nested)
+
+    def gauge(self, name: str, value: float) -> None:
+        """A fact that is not a duration (bytes of the compiled step's
+        peak, bytes the last save staged): the last value set wins."""
+        with self._lock:
+            self._gauges[name] = float(value)
 
     # -- reading -------------------------------------------------------
 
@@ -153,10 +294,21 @@ class TraceRing:
         with self._lock:
             return dict(self._kind_seconds)
 
+    def counters(self) -> Dict[str, Tuple[int, float]]:
+        """``{span name: (times closed, seconds in all)}``, a copy."""
+        with self._lock:
+            return {k: (v[0], v[1]) for k, v in self._counters.items()}
+
+    def gauges(self) -> Dict[str, float]:
+        with self._lock:
+            return dict(self._gauges)
+
     def clear(self):
         with self._lock:
             self._events.clear()
             self._kind_seconds.clear()
+            self._counters.clear()
+            self._gauges.clear()
 
     # -- export --------------------------------------------------------
 
@@ -215,8 +367,10 @@ def record(kind: str, name: str, start_mono: float, dur_s: float, **attrs):
     trace_ring.record(kind, name, start_mono, dur_s, **attrs)
 
 
-def span(kind: str, name: Optional[str] = None, **attrs):
-    return trace_ring.span(kind, name, **attrs)
+span = trace_ring.span
+gauge = trace_ring.gauge
+counters = trace_ring.counters
+gauges = trace_ring.gauges
 
 
 def default_dump_dir() -> str:
@@ -301,9 +455,6 @@ def dump_at_exit(role: str = "worker", **meta) -> bool:
 
 #: span kind -> lost-time attribution category (the same vocabulary the
 #: master's SpeedMonitor.attribution() uses; docs/design/observability.md).
-#: ``kernel`` is deliberately unmapped: kernel spans are a breakdown of
-#: the step spans they nest inside — mapping them to "productive" would
-#: double-count step time in the attribution sums.
 KIND_CATEGORY = {
     "step": "productive",
     "eval": "productive",
@@ -355,10 +506,32 @@ def attribution_from_kind_seconds(
 
 
 def prometheus_lines() -> List[str]:
-    """Spine gauges for the worker ``/metrics`` endpoint
-    (profiler/comm.py): cumulative seconds per span kind plus the last
-    drained step-time digest window."""
+    """Spine rows for the worker ``/metrics`` endpoint
+    (profiler/comm.py): times closed and cumulative seconds per span
+    name, the gauges, cumulative seconds per span kind (ring on) plus
+    the last drained step-time digest window."""
     lines: List[str] = []
+    spans = trace_ring.counters()
+    if spans:
+        lines.append("# TYPE dlrover_tpu_span_seconds_total counter")
+        lines.append("# TYPE dlrover_tpu_span_count_total counter")
+        for name in sorted(spans):
+            count, seconds = spans[name]
+            lines.append(
+                f'dlrover_tpu_span_seconds_total{{name="{name}"}} '
+                f"{seconds:.6f}"
+            )
+            lines.append(
+                f'dlrover_tpu_span_count_total{{name="{name}"}} {count}'
+            )
+    gauge_rows = trace_ring.gauges()
+    if gauge_rows:
+        lines.append("# TYPE dlrover_tpu_trace_gauge gauge")
+        for name in sorted(gauge_rows):
+            lines.append(
+                f'dlrover_tpu_trace_gauge{{name="{name}"}} '
+                f"{gauge_rows[name]:.6g}"
+            )
     kinds = trace_ring.kind_seconds()
     if kinds:
         lines.append("# TYPE dlrover_tpu_trace_seconds_total gauge")

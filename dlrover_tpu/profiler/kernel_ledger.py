@@ -21,11 +21,11 @@ per-kernel breakdown:
    re-execution) — shares always sum to 1.0 across the whole program,
    so a top-k cut covering >=80 % of the step always exists.
 
-The result lands in three consumers: the :class:`KernelLedger` singleton
-(``dlrover_tpu_kernel_seconds_total{op=...}`` on /metrics), a ``kernel``
-span lane in the trace spine (spans laid out sequentially on their own
-tid inside the step window, so the job-timeline ``--check`` lane-nesting
-invariant holds), and ``detail.kernel_breakdown`` in bench's mfu phase.
+The result lands in two consumers: the :class:`KernelLedger` singleton
+(``dlrover_tpu_kernel_seconds_total{op=...}`` on /metrics) and
+``detail.kernel_breakdown`` in bench's mfu phase. It is never laid on a
+timeline: measured device operations are in the profiler's trace, and
+modelled durations beside them could only mislead.
 
 The weights are a *model*, not a measurement — the point is stable,
 named blame ("attention.bwd got 2x slower") rather than nanosecond
@@ -45,11 +45,6 @@ from typing import Dict, List, Optional, Sequence
 #: everything else against HBM bandwidth.
 PEAK_FLOPS = 2.0e14
 PEAK_BW_BYTES = 8.0e11
-
-#: the dedicated trace-spine lane kernel spans are emitted on — their
-#: own tid keeps them disjoint-per-lane for validate_trace_events even
-#: though they decompose the step spans on the step lane.
-KERNEL_TID = 90_001
 
 _COLLECTIVES = frozenset({
     "all-reduce", "all-gather", "reduce-scatter", "all-to-all",
@@ -341,7 +336,7 @@ def measure_step(run_fn, n: int = 3) -> float:
 
 
 # ---------------------------------------------------------------------------
-# ledger singleton + trace-spine / metrics emission
+# ledger singleton + metrics emission
 # ---------------------------------------------------------------------------
 
 
@@ -408,44 +403,16 @@ def prometheus_lines() -> List[str]:
     return kernel_ledger.prometheus_lines()
 
 
-def emit_spans(
-    rows: List[Dict], step_start_mono: float, step_dur_s: float
-) -> None:
-    """Lay the breakdown out as ``kernel`` spans on the dedicated
-    KERNEL_TID lane, back to back inside the step's window (scaled to
-    fill it). Sequential-on-their-own-lane keeps the job-timeline
-    ``--check`` nesting invariant trivially satisfied."""
-    from dlrover_tpu.observability import trace
-
-    if not trace.enabled() or not rows:
-        return
-    total = sum(max(0.0, r.get("seconds", 0.0)) for r in rows)
-    if total <= 0.0:
-        return
-    scale = max(0.0, float(step_dur_s)) / total
-    t = float(step_start_mono)
-    for r in rows:
-        dur = max(0.0, r.get("seconds", 0.0)) * scale
-        trace.record(
-            "kernel", r["op"], t, dur, tid=KERNEL_TID,
-            share=r.get("share"), sites=r.get("sites"),
-        )
-        t += dur
-
-
 def capture_step(
     compiled,
     step_s: float,
     *,
-    step_start_mono: Optional[float] = None,
     hlo_text: Optional[str] = None,
 ) -> List[Dict]:
     """The one-call on-demand capture: attribute ``step_s`` across the
-    compiled program's kernel sites, record into the ledger (/metrics),
-    and emit the ``kernel`` trace lane when the spine is on. Returns the
-    full breakdown (use :func:`top_k` for display cuts)."""
+    compiled program's kernel sites and record into the ledger
+    (/metrics). Returns the full breakdown (use :func:`top_k` for
+    display cuts)."""
     rows = attribute_step(compiled, step_s, hlo_text=hlo_text)
     kernel_ledger.record_breakdown(rows)
-    if step_start_mono is not None:
-        emit_spans(rows, step_start_mono, step_s)
     return rows

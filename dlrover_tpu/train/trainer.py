@@ -1040,34 +1040,36 @@ class ElasticTrainer:
         state_av, batch_av, out_sh = self._avatar_args(
             mesh, mesh_config, accum
         )
+        # trace spine: every real build (cold AND speculative) is a pair
+        # of compile spans; warm hits returned above and cost nothing
         t0 = time.perf_counter()
-        m0 = time.monotonic()
-        lowered = self._build_step(
-            mesh, mesh_config, out_shardings=out_sh
-        ).lower(state_av, batch_av)
-        compiled = lowered.compile()
+        with trace.span("compile", "build.lower", world=mesh.size,
+                        source=source, config=config_hash):
+            lowered = self._build_step(
+                mesh, mesh_config, out_shardings=out_sh
+            ).lower(state_av, batch_av)
+        # the XLA compile itself, or its load from the persistent cache
+        with trace.span("compile", "build.compile", world=mesh.size,
+                        source=source, config=config_hash):
+            compiled = lowered.compile()
         dt = time.perf_counter() - t0
-        # trace spine: every real XLA compile (cold AND speculative) is
-        # a span — warm hits returned above and cost nothing
-        trace.record(
-            "compile", f"lower_step.w{mesh.size}", m0, dt,
-            world=mesh.size, source=source, config=config_hash,
-        )
-        # IR-level analysis of the program just built (lint/shardcheck),
-        # opted in via DLROVER_TPU_SHARDCHECK. Runs for EVERY lowering —
-        # including the speculative neighbor worlds — so a sharding
-        # regression on the post-resize mesh is caught before the
-        # resize happens, not at its first step. Strict mode raises
-        # here, which keeps the poisoned executable out of the cache.
-        self._maybe_shardcheck(lowered, compiled, mesh, mesh_config,
-                               config_hash)
-        # memory-side analysis of the same build (lint/memcheck.py),
-        # opted in via DLROVER_TPU_MEMCHECK: the per-device memory
-        # model diffed against its contract and the device-class HBM
-        # budget. Strict mode raises BEFORE the cache put, like
-        # shardcheck — an executable that cannot fit its budget never
-        # becomes a warm hit.
-        self._maybe_memcheck(compiled, mesh, mesh_config, config_hash)
+        with trace.span("compile", "build.checks"):
+            # IR-level analysis of the program just built
+            # (lint/shardcheck), opted in via DLROVER_TPU_SHARDCHECK.
+            # Runs for EVERY lowering — including the speculative
+            # neighbor worlds — so a sharding regression on the
+            # post-resize mesh is caught before the resize happens, not
+            # at its first step. Strict mode raises here, which keeps
+            # the poisoned executable out of the cache.
+            self._maybe_shardcheck(lowered, compiled, mesh, mesh_config,
+                                   config_hash)
+            # memory-side analysis of the same build (lint/memcheck.py),
+            # opted in via DLROVER_TPU_MEMCHECK: the per-device memory
+            # model diffed against its contract and the device-class HBM
+            # budget. Strict mode raises BEFORE the cache put, like
+            # shardcheck — an executable that cannot fit its budget
+            # never becomes a warm hit.
+            self._maybe_memcheck(compiled, mesh, mesh_config, config_hash)
         self.warm.put(sig, compiled)
         warm_compile.compile_ledger.record(mesh.size, config_hash, dt, source)
         return compiled, {
@@ -1450,6 +1452,15 @@ class ElasticTrainer:
             )
             return self._build_step()
         self._last_build_info = info
+        # the live step's memory per device as the compiler planned it:
+        # the backend's own peak counter leaves out the program's
+        # temporaries
+        from dlrover_tpu.lint import memcheck
+
+        measured = memcheck.read_memory_analysis(fn)
+        for key in ("peak_bytes", "temp_bytes", "argument_bytes"):
+            if key in measured:
+                trace.gauge(f"step.hbm_{key}", measured[key])
         if info["cache"] == "warm":
             logger.info(
                 "step build: WARM (AOT cache hit, world=%d)", self.mesh.size
@@ -1459,7 +1470,8 @@ class ElasticTrainer:
                 "step build: cold compile %.2fs (world=%d config=%s)",
                 info["compile_s"], self.mesh.size, info["config_hash"],
             )
-        self._maybe_speculate()
+        with trace.span("compile", "build.speculate"):
+            self._maybe_speculate()
         return fn
 
     def _descriptor_for_world(
@@ -1719,26 +1731,10 @@ class ElasticTrainer:
             )
         return float(total) / count
 
-    def step(self, state: dict, batch) -> Tuple[dict, jnp.ndarray]:
-        """One optimizer step = ``accum_steps`` microbatches.
-
-        ``batch``: any pytree whose leaves lead with (accum_steps,
-        micro*dp, ...) — int32 token arrays for the LM families,
-        (images, labels) tuples for CV."""
-        first_build = self._step_fn is None
-        build_t0 = time.perf_counter()
-        if first_build:
-            self.record_avatars(state, batch)
-            self._step_fn = self._acquire_step_fn()
-        if self.worker_ctx is not None:
-            state = self.poll_runtime_config(state)
-        # step wall clock, measured WITHOUT a device sync: dispatch of
-        # step N blocks on donation until step N-1's buffers free, so in
-        # steady state this converges to the device step time. Feeds the
-        # per-rank digest and (when the spine is on) a `step` span.
-        step_m0 = time.monotonic()
+    def _dispatch(self, state: dict, batch):
+        """Hand the step to the device; returns before it has run."""
         try:
-            new_state, loss = self._step_fn(state, batch)
+            return self._step_fn(state, batch)
         except (ValueError, TypeError) as e:
             # an AOT executable (warm path) is stricter than jit: a
             # committed input with a different sharding raises
@@ -1770,22 +1766,47 @@ class ElasticTrainer:
             # this build: route _finalize_resize to the measured branch
             self._last_build_info = {"cache": "jit", "compile_s": None}
             self._step_fn = self._build_step()
-            new_state, loss = self._step_fn(state, batch)
-        step_dur = time.monotonic() - step_m0
+            return self._step_fn(state, batch)
+
+    def step(self, state: dict, batch) -> Tuple[dict, jnp.ndarray]:
+        """One optimizer step = ``accum_steps`` microbatches.
+
+        ``batch``: any pytree whose leaves lead with (accum_steps,
+        micro*dp, ...) — int32 token arrays for the LM families,
+        (images, labels) tuples for CV."""
+        first_build = self._step_fn is None
+        build_t0 = time.perf_counter()
+        if first_build:
+            # the first call is build-dominated: it has a span of its
+            # own and stays out of `train_step` and of the digest, or
+            # every (re)start would feed the straggler detector one
+            # giant sample per rank
+            with trace.span("host", "first_step", step=self._host_step + 1):
+                with trace.span("compile", "build",
+                                world=self.mesh.size) as built:
+                    with trace.span("compile", "build.avatars"):
+                        self.record_avatars(state, batch)
+                    self._step_fn = self._acquire_step_fn()
+                    built.set(cache=self._last_build_info["cache"])
+                if self.worker_ctx is not None:
+                    state = self.poll_runtime_config(state)
+                new_state, loss = self._dispatch(state, batch)
+        else:
+            if self.worker_ctx is not None:
+                state = self.poll_runtime_config(state)
+            # step wall clock, measured WITHOUT a device sync: dispatch
+            # of step N blocks on donation until step N-1's buffers
+            # free, so in steady state this converges to the device step
+            # time. Feeds the per-rank digest.
+            with trace.span("step", "train_step", step=self._host_step + 1,
+                            host_step=self._host_step + 1) as dispatched:
+                new_state, loss = self._dispatch(state, batch)
+            self.step_digest.add(dispatched.dur)
         if first_build and self._pending_resize is not None:
             self._finalize_resize(loss, build_t0)
         # host-side step counter: reading new_state["step"] would block on
         # the just-dispatched computation and kill async dispatch
         self._host_step += 1
-        if not first_build:
-            # the first call's wall is compile/build-dominated — keeping
-            # it out of the digest stops every (re)start from feeding
-            # the straggler detector one giant sample per rank
-            self.step_digest.add(step_dur)
-            trace.record(
-                "step", "train_step", step_m0, step_dur,
-                host_step=self._host_step,
-            )
         if self.worker_ctx is not None:
             try:
                 self.worker_ctx.report_step(

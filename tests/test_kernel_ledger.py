@@ -292,33 +292,6 @@ def test_metrics_endpoint_serves_kernel_lines():
         kl.kernel_ledger.clear()
 
 
-def test_emit_spans_kernel_lane(monkeypatch):
-    """Kernel spans lie back to back on their own KERNEL_TID lane,
-    scaled to exactly fill the step window — disjoint-per-lane, so the
-    job-timeline nesting invariant holds by construction."""
-    from dlrover_tpu.observability import trace
-
-    monkeypatch.setenv("DLROVER_TPU_TRACE", "1")
-    trace.trace_ring.clear()
-    try:
-        rows = _rows(**{"attention.fwd": 0.5, "matmul": 0.3,
-                        "other": 0.2})
-        kl.emit_spans(rows, step_start_mono=100.0, step_dur_s=2.0)
-        evs = [e for e in trace.trace_ring.events()
-               if e["kind"] == "kernel"]
-        assert len(evs) == 3
-        assert all(e["tid"] == kl.KERNEL_TID for e in evs)
-        # sequential + exactly filling [100, 102]
-        t = 100.0
-        for e in sorted(evs, key=lambda e: e["t"]):
-            assert e["t"] == pytest.approx(t)
-            t += e["dur"]
-        assert t == pytest.approx(102.0)
-        assert evs[0]["attrs"]["share"] == 0.5
-    finally:
-        trace.trace_ring.clear()
-
-
 def test_capture_step_records_into_ledger():
     kl.kernel_ledger.clear()
     try:

@@ -64,6 +64,196 @@ def test_trace_ring_bounded_but_totals_survive(traced, monkeypatch):
     assert traced.kind_seconds()["step"] == pytest.approx(1.0)
 
 
+# -- one span() call, three sinks ----------------------------------------
+
+
+@pytest.fixture
+def untraced(monkeypatch):
+    """Spine off (the default), clean counters."""
+    from dlrover_tpu.observability.trace import trace_ring
+
+    monkeypatch.delenv("DLROVER_TPU_TRACE", raising=False)
+    trace_ring.clear()
+    yield trace_ring
+    trace_ring.clear()
+
+
+@pytest.mark.parametrize("times", [1, 3])
+def test_counters_count_with_the_ring_off(untraced, times):
+    from dlrover_tpu.observability import trace
+
+    for _ in range(times):
+        with trace.span("ckpt_save", "d2h.wait") as sp:
+            time.sleep(0.002)
+        assert sp.dur >= 0.002
+    count, seconds = trace.counters()["d2h.wait"]
+    assert count == times and seconds >= 0.002 * times
+    assert untraced.events() == [] and untraced.kind_seconds() == {}
+    # what counters() hands out is a copy
+    trace.counters().clear()
+    assert "d2h.wait" in trace.counters()
+
+
+def test_gauges_hold_the_last_value_with_the_ring_off(untraced):
+    from dlrover_tpu.observability import trace
+
+    trace.gauge("step.hbm_peak_bytes", 10)
+    trace.gauge("step.hbm_peak_bytes", 12)
+    assert trace.gauges() == {"step.hbm_peak_bytes": 12.0}
+    assert untraced.events() == []
+    untraced.clear()
+    assert trace.gauges() == {} and trace.counters() == {}
+
+
+def test_record_reaches_the_ring_only(traced):
+    from dlrover_tpu.observability import trace
+
+    trace.record("compile", "resize.first_step_compile",
+                 time.monotonic() - 1.0, 1.0, tid="resize")
+    assert [e["name"] for e in traced.events()] == [
+        "resize.first_step_compile"]
+    assert trace.counters() == {}
+
+
+def test_span_identity_parent_and_inherited_step(traced):
+    from dlrover_tpu.observability import trace
+
+    with trace.span("ckpt_save", "save.blocking", step=9, tier="shm") as a:
+        with trace.span("ckpt_save", "d2h.issue") as b:
+            b.set(shards=4)
+        with trace.span("ckpt_save", "d2h.wait", step=10) as c:
+            pass
+    with trace.span("step", "train_step") as d:
+        pass
+    assert len({a.id, b.id, c.id, d.id}) == 4
+    assert (a.parent, b.parent, c.parent, d.parent) == (
+        None, a.id, a.id, None)
+    assert (a.step, b.step, c.step, d.step) == (9, 9, 10, None)
+    by_name = {e["name"]: e["attrs"] for e in traced.events()}
+    assert by_name["d2h.issue"] == {
+        "shards": 4, "id": b.id, "parent": a.id, "step": 9}
+    assert by_name["save.blocking"]["tier"] == "shm"
+    assert "parent" not in by_name["train_step"]
+
+
+def test_span_cause_crosses_threads(traced):
+    """A new thread starts with no enclosing span: the work it is
+    handed names its cause, and states its step, explicitly."""
+    import threading
+
+    from dlrover_tpu.observability import trace
+
+    seen = {}
+
+    def stage(cause, step):
+        with trace.span("ckpt_save", "stage.background", cause=cause,
+                        step=step) as bg:
+            with trace.span("ckpt_save", "stage.shm_write") as child:
+                pass
+        seen.update(bg=bg, child=child)
+
+    with trace.span("ckpt_save", "save.blocking", step=5) as pause:
+        t = threading.Thread(target=stage, args=(pause.id, 5))
+        t.start()
+        t.join(timeout=10)
+        assert not t.is_alive()
+    assert seen["bg"].parent == pause.id and seen["bg"].step == 5
+    assert seen["child"].parent == seen["bg"].id and seen["child"].step == 5
+    tids = {e["name"]: e["tid"] for e in traced.events()}
+    assert tids["stage.background"] != tids["save.blocking"]
+
+
+def test_span_inside_its_own_kind_adds_nothing_to_the_kind(traced):
+    from dlrover_tpu.observability import trace
+
+    with trace.span("ckpt_save", "save.blocking") as outer:
+        with trace.span("ckpt_save", "d2h.wait") as inner:
+            time.sleep(0.002)
+        with trace.span("host", "callback") as other:
+            pass
+    ks = traced.kind_seconds()
+    assert ks["ckpt_save"] == pytest.approx(outer.dur)
+    assert ks["host"] == pytest.approx(other.dur)
+    assert inner.dur > 0 and len(traced.events()) == 3
+
+
+def test_span_closes_and_unwinds_on_an_exception(untraced):
+    from dlrover_tpu.observability import trace
+
+    with pytest.raises(ValueError):
+        with trace.span("ckpt_save", "save.blocking"):
+            raise ValueError("boom")
+    assert trace.counters()["save.blocking"][0] == 1
+    with trace.span("step", "train_step") as after:
+        pass
+    assert after.parent is None
+
+
+@pytest.mark.parametrize("spine", ["0", "1"])
+def test_span_lands_in_the_profilers_host_plane(monkeypatch, tmp_path,
+                                                spine):
+    """Under a profiler session a span is a host event named
+    ``dlrover/<name>`` with its identity and attributes as stats, on the
+    profiler's clock; whether the ring is on makes no difference."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from dlrover_tpu.observability import trace
+
+    monkeypatch.setenv("DLROVER_TPU_TRACE", spine)
+    trace.trace_ring.clear()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with trace.span("ckpt_save", "save.blocking", step=7,
+                        tier="shm") as outer:
+            with trace.span("ckpt_save", "d2h.wait") as inner:
+                inner.set(bytes=4096)
+    finally:
+        jax.profiler.stop_trace()
+        trace.trace_ring.clear()
+    (path,) = glob.glob(
+        str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("dlrover/"):
+                    found[e.name] = (e, dict(e.stats))
+    assert set(found) == {"dlrover/save.blocking", "dlrover/d2h.wait"}
+    ev_out, out = found["dlrover/save.blocking"]
+    ev_in, inn = found["dlrover/d2h.wait"]
+    assert out == {"kind": "ckpt_save", "id": outer.id, "parent": 0,
+                   "step": 7, "tier": "shm"}
+    assert inn == {"kind": "ckpt_save", "id": inner.id,
+                   "parent": outer.id, "step": 7, "bytes": 4096}
+    assert ev_out.start_ns <= ev_in.start_ns
+    assert (ev_in.start_ns + ev_in.duration_ns
+            <= ev_out.start_ns + ev_out.duration_ns)
+
+
+def test_trace_module_imports_without_jax():
+    """Master and agent import the spine; it must never pull JAX in."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from dlrover_tpu.observability import trace\n"
+        "with trace.span('step', 'train_step', step=1):\n"
+        "    pass\n"
+        "trace.gauge('g', 1)\n"
+        "assert trace.counters()['train_step'][0] == 1\n"
+        "bad = [m for m in sys.modules if m == 'jax' or "
+        "m.startswith(('jax.', 'jaxlib'))]\n"
+        "assert not bad, bad\n"
+    )
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120,
+                   cwd=root)
+
+
 def test_chrome_export_epoch_clock_and_dump(traced, tmp_path):
     m0 = time.monotonic()
     wall_now_us = time.time() * 1e6
@@ -161,6 +351,23 @@ def test_spine_prometheus_lines(traced):
     assert 'dlrover_tpu_trace_seconds_total{kind="step"}' in text
     assert 'dlrover_tpu_step_time_seconds{stat="p95"} 0.3' in text
     assert "dlrover_tpu_step_window_steps 8" in text
+
+
+def test_spine_prometheus_lines_carry_counters_and_gauges(untraced):
+    """With the ring off the worker's /metrics still has what the spans
+    counted and the gauges: the operator's reader of both."""
+    from dlrover_tpu.observability import trace
+
+    for _ in range(2):
+        with trace.span("ckpt_save", "d2h.wait"):
+            pass
+    trace.gauge("ckpt.staged_bytes", 8154000000)
+    text = "\n".join(trace.prometheus_lines())
+    assert 'dlrover_tpu_span_count_total{name="d2h.wait"} 2' in text
+    assert 'dlrover_tpu_span_seconds_total{name="d2h.wait"} 0.0' in text
+    assert ('dlrover_tpu_trace_gauge{name="ckpt.staged_bytes"} 8.154e+09'
+            in text)
+    assert "dlrover_tpu_trace_seconds_total" not in text
 
 
 # ---------------------------------------------------------------------------
@@ -416,43 +623,104 @@ def test_failed_step_report_retries_digest_window(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.usefixtures("traced")
-def test_trainer_emits_step_compile_spans_and_digest(monkeypatch):
+@pytest.fixture(scope="module")
+def three_trainer_steps():
+    """Three steps of a tiny trainer with the ring on: (ring events,
+    counters, gauges, the digest's window, kind seconds)."""
     import jax
 
     from dlrover_tpu.models import llama
-    from dlrover_tpu.observability.trace import trace_ring
+    from dlrover_tpu.observability import trace
     from dlrover_tpu.parallel import MeshConfig, build_mesh, named_shardings
     from dlrover_tpu.train.trainer import ElasticTrainer, TrainConfig
 
-    cfg = llama.LlamaConfig.tiny()
-    mc = MeshConfig(dp=1, fsdp=1, sp=1, tp=1).resolve(1)
-    mesh = build_mesh(mc, devices=jax.devices()[:1])
-    specs = llama.param_specs(cfg)
-    params = jax.device_put(
-        llama.init_params(cfg, jax.random.key(0)),
-        named_shardings(mesh, specs),
-    )
-    tc = TrainConfig(global_batch_size=2, micro_batch_size=2,
-                     warmup_steps=0, total_steps=10)
-    trainer = ElasticTrainer(
-        lambda p, t: llama.loss_fn(p, t, cfg, None), specs, mesh, mc, tc
-    )
-    state = trainer.init_state(params)
-    batch = jax.random.randint(
-        jax.random.key(1), (1, 2, 16), 0, cfg.vocab_size
-    )
-    for _ in range(3):
-        state, loss = trainer.step(state, batch)
-    jax.block_until_ready(loss)
-    kinds = [e["kind"] for e in trace_ring.events()]
-    # warm-compile default on: the AOT build recorded a compile span
+    mp = pytest.MonkeyPatch()
+    mp.setenv("DLROVER_TPU_TRACE", "1")
+    trace.trace_ring.clear()
+    try:
+        cfg = llama.LlamaConfig.tiny()
+        mc = MeshConfig(dp=1, fsdp=1, sp=1, tp=1).resolve(1)
+        mesh = build_mesh(mc, devices=jax.devices()[:1])
+        specs = llama.param_specs(cfg)
+        params = jax.device_put(
+            llama.init_params(cfg, jax.random.key(0)),
+            named_shardings(mesh, specs),
+        )
+        tc = TrainConfig(global_batch_size=2, micro_batch_size=2,
+                         warmup_steps=0, total_steps=10)
+        trainer = ElasticTrainer(
+            lambda p, t: llama.loss_fn(p, t, cfg, None), specs, mesh, mc, tc
+        )
+        state = trainer.init_state(params)
+        batch = jax.random.randint(
+            jax.random.key(1), (1, 2, 16), 0, cfg.vocab_size
+        )
+        for _ in range(3):
+            state, loss = trainer.step(state, batch)
+        jax.block_until_ready(loss)
+        yield (trace.trace_ring.events(), trace.counters(), trace.gauges(),
+               trainer.step_digest.snapshot_and_reset(),
+               trace.trace_ring.kind_seconds())
+    finally:
+        trace.trace_ring.clear()
+        mp.undo()
+
+
+def test_trainer_emits_step_compile_spans_and_digest(three_trainer_steps):
+    events, counters, _, window, kind_seconds = three_trainer_steps
+    kinds = [e["kind"] for e in events]
+    # warm-compile default on: the AOT build recorded compile spans
     assert "compile" in kinds
     # steps after the first (build) call recorded step spans
     assert kinds.count("step") == 2
+    steps = [e["attrs"] for e in events if e["name"] == "train_step"]
+    assert [(a["step"], a["host_step"]) for a in steps] == [(2, 2), (3, 3)]
     # the digest folded the same steps
-    w = trainer.step_digest.snapshot_and_reset()
-    assert w is not None and w["count"] == 2
+    assert window is not None and window["count"] == 2
+    # ... from the span's own seconds (the digest rounds to microseconds)
+    assert window["mean_s"] * 2 == pytest.approx(
+        counters["train_step"][1], abs=2e-6)
+    # the build's children decompose it: the kind counts the build once
+    (build,) = [e for e in events if e["name"] == "build"]
+    assert kind_seconds["compile"] == pytest.approx(build["dur"])
+
+
+@pytest.mark.parametrize("name,parent", [
+    ("first_step", None),
+    ("build", "first_step"),
+    ("build.avatars", "build"),
+    ("build.lower", "build"),
+    ("build.compile", "build"),
+    ("build.checks", "build"),
+    ("build.speculate", "build"),
+])
+def test_trainer_first_step_spans(three_trainer_steps, name, parent):
+    events, counters, _, _, _ = three_trainer_steps
+    by_name = {e["name"]: e for e in events}
+    attrs = by_name[name]["attrs"]
+    assert counters[name][0] == 1
+    assert attrs["step"] == 1
+    if parent is None:
+        assert "parent" not in attrs
+        # the first call stays out of train_step
+        assert counters["train_step"][0] == 2
+    else:
+        assert attrs["parent"] == by_name[parent]["attrs"]["id"]
+        assert by_name[name]["dur"] <= by_name[parent]["dur"]
+    if name == "build":
+        assert (attrs["cache"], attrs["world"]) == ("miss", 1)
+        lowered = by_name["build.lower"]["dur"]
+        compiled = by_name["build.compile"]["dur"]
+        assert lowered + compiled <= by_name["build"]["dur"]
+
+
+@pytest.mark.parametrize("gauge", [
+    "step.hbm_peak_bytes", "step.hbm_temp_bytes", "step.hbm_argument_bytes",
+])
+def test_trainer_sets_hbm_gauges_at_build(three_trainer_steps, gauge):
+    _, _, gauges, _, _ = three_trainer_steps
+    assert gauges[gauge] > 0
+    assert gauges["step.hbm_peak_bytes"] >= gauges[gauge]
 
 
 # ---------------------------------------------------------------------------
@@ -606,11 +874,142 @@ def test_checkpoint_engine_emits_save_and_restore_spans(
     finally:
         engine.close(unlink_shm=True)
     evs = traced.events()
-    saves = [e for e in evs if e["kind"] == "ckpt_save"]
+    saves = [e for e in evs if e["name"] == "save.blocking"]
     restores = [e for e in evs if e["kind"] == "ckpt_restore"]
-    assert saves and saves[0]["attrs"]["tier"] == "shm"
+    assert len(saves) == 1 and saves[0]["kind"] == "ckpt_save"
+    assert saves[0]["attrs"]["tier"] == "shm"
     assert saves[0]["attrs"]["step"] == 3
+    assert saves[0]["attrs"]["mode"] == "sync"
+    # the children decompose the pause: the kind's total is the pause
+    assert traced.kind_seconds()["ckpt_save"] == pytest.approx(
+        saves[0]["dur"])
     assert len(restores) == 1
     assert restores[0]["attrs"]["step"] == 3
     assert restores[0]["attrs"]["ok"] is True
     assert restores[0]["attrs"]["tier"] == "shm"
+
+
+#: span -> the span it must hang under, per stage mode: the d2h copies
+#: block the caller in host_gather and move to the staging thread in
+#: device_snapshot
+_SAVE_TREE = {
+    "host_gather": {
+        "save.join_previous": "save.blocking",
+        "save.snapshot": "save.blocking",
+        "d2h.issue": "save.blocking",
+        "d2h.wait": "save.blocking",
+        "stage.background": "save.blocking",
+        "stage.wait_persist": "stage.background",
+        "stage.shm_lock": "stage.background",
+        "stage.shm_write": "stage.background",
+    },
+    "device_snapshot": {
+        "save.join_previous": "save.blocking",
+        "save.snapshot": "save.blocking",
+        "stage.background": "save.blocking",
+        "d2h.issue": "stage.background",
+        "d2h.wait": "stage.background",
+        "stage.wait_persist": "stage.background",
+        "stage.shm_lock": "stage.background",
+        "stage.shm_write": "stage.background",
+    },
+}
+
+
+@pytest.fixture(scope="module", params=sorted(_SAVE_TREE))
+def one_async_save(request, tmp_path_factory):
+    """One async save of device arrays in the given stage mode, with the
+    ring on; yields (mode, ring events by name, gauges, counters)."""
+    import jax.numpy as jnp
+
+    from dlrover_tpu.checkpoint.engine import CheckpointEngine
+    from dlrover_tpu.observability import trace
+
+    mode = request.param
+    mp = pytest.MonkeyPatch()
+    mp.setenv("DLROVER_TPU_TRACE", "1")
+    if mode == "host_gather":
+        # no room for a second copy: the d2h copies block the caller
+        mp.setattr(CheckpointEngine, "_hbm_headroom",
+                   staticmethod(lambda arrays: (8, 8)))
+    trace.trace_ring.clear()
+    engine = CheckpointEngine(
+        str(tmp_path_factory.mktemp("ckpt")), job_name=f"obs-{mode}",
+        node_id=0, process_id=0, async_staging=True,
+    )
+    try:
+        state = {"w": jnp.arange(32.0).reshape(8, 4), "b": jnp.ones(4)}
+        engine.save_to_memory(11, state)
+        engine.wait_staging()
+        assert engine.last_stage_mode == mode
+        events = {e["name"]: e for e in trace.trace_ring.events()}
+        yield mode, events, trace.gauges(), trace.counters()
+    finally:
+        engine.close(unlink_shm=True)
+        trace.trace_ring.clear()
+        mp.undo()
+
+
+@pytest.mark.parametrize("name", sorted(_SAVE_TREE["host_gather"]))
+def test_engine_save_children_hang_under_the_right_parent(
+    one_async_save, name
+):
+    mode, events, _, counters = one_async_save
+    parent = events[_SAVE_TREE[mode][name]]
+    attrs = events[name]["attrs"]
+    assert events[name]["kind"] == "ckpt_save"
+    assert attrs["parent"] == parent["attrs"]["id"]
+    # every span of one save carries the checkpoint's step, on either
+    # thread
+    assert attrs["step"] == 11
+    assert counters[name][0] == 1
+    on_caller = events[name]["tid"] == events["save.blocking"]["tid"]
+    assert on_caller == (
+        _SAVE_TREE[mode][name] == "save.blocking"
+        and name != "stage.background"
+    )
+
+
+def test_engine_save_spans_say_what_happened(one_async_save):
+    mode, events, gauges, _ = one_async_save
+    snap = events["save.snapshot"]["attrs"]
+    if mode == "host_gather":
+        assert (snap["taken"], snap["why"]) == (0, "headroom")
+        assert (snap["need_bytes"], snap["free_bytes"]) == (8, 8)
+    else:
+        assert snap["taken"] == 1 and "why" not in snap
+    assert events["save.blocking"]["attrs"]["mode"] == mode
+    assert events["save.join_previous"]["attrs"]["joined"] == 0
+    assert events["d2h.issue"]["attrs"]["shards"] == 2
+    staged = (32 + 4) * 4
+    assert events["d2h.wait"]["attrs"]["bytes"] == staged
+    assert events["stage.shm_write"]["attrs"]["bytes"] == staged
+    assert gauges["ckpt.staged_bytes"] == staged
+
+
+@pytest.mark.parametrize("enabled,state_kind,why", [
+    (False, "device", "off"), (True, "host", "no_arrays"),
+])
+def test_engine_snapshot_says_why_not(traced, tmp_path, enabled,
+                                      state_kind, why):
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dlrover_tpu.checkpoint.engine import CheckpointEngine
+
+    engine = CheckpointEngine(
+        str(tmp_path / "ckpt"), job_name=f"obs-why-{why}", node_id=0,
+        process_id=0, async_staging=True,
+    )
+    engine._device_snapshot_enabled = enabled
+    state = {"w": jnp.ones(4) if state_kind == "device" else np.ones(4)}
+    try:
+        engine.save_to_memory(1, state)
+        engine.wait_staging()
+    finally:
+        engine.close(unlink_shm=True)
+    (snap,) = [e["attrs"] for e in traced.events()
+               if e["name"] == "save.snapshot"]
+    assert (snap["taken"], snap["why"]) == (0, why)
+    assert "need_bytes" not in snap
+    assert engine.last_stage_mode == "host_gather"
