@@ -126,22 +126,22 @@ def _bench_candidates(llama, jnp):
     # headline.
     if fused_ce_enabled() and fused_ce_available():
         unlocked += [
-            ("llama_1.2B_seq2k_b16_mlp_q512k1024_fce",
-             b12(remat_policy="mlp", attn_block_q=512, attn_block_k=1024),
+            ("llama_1.2B_seq2k_b16_mlp_fce",
+             b12(remat_policy="mlp"),
              16, 2048, {"FUSED_CE": True}),
         ]
     if chunked_ce_enabled():
         unlocked += [
             # doubled batch over the r5 winner: the freed logits HBM fits
             # the extra activations under mlp-remat
-            ("llama_1.2B_seq2k_b16_mlp_q512k1024_cce",
-             b12(remat_policy="mlp", attn_block_q=512, attn_block_k=1024),
+            ("llama_1.2B_seq2k_b16_mlp_cce",
+             b12(remat_policy="mlp"),
              16, 2048, {"FUSED_CE": False}),
             # seq 4k at the winner's batch: doubles the CREDITED causal
             # attention flops per token; fits only without dense logits
-            ("llama_1.2B_seq4k_b4_mlp_q512k1024_cce",
-             b12(remat_policy="mlp", attn_block_q=512, attn_block_k=1024,
-                 max_seq_len=4096), 4, 4096, {"FUSED_CE": False}),
+            ("llama_1.2B_seq4k_b4_mlp_cce",
+             b12(remat_policy="mlp", max_seq_len=4096), 4, 4096,
+             {"FUSED_CE": False}),
         ]
     # Ordered by expected MFU: the metric credits MODEL flops only, so
     # recompute is pure loss — full-remat burns ~33% uncredited flops,
@@ -151,26 +151,21 @@ def _bench_candidates(llama, jnp):
         # r5 measured best: b4 mlp-remat 105.8 / b8 full-remat 103.0
         # model TFLOP/s — b8 mlp-remat is the untested gap between them;
         # if its activations OOM it falls through to the known winners
-        ("llama_1.2B_seq2k_b8_mlp_q512k1024",
-         b12(remat_policy="mlp", attn_block_q=512, attn_block_k=1024),
+        ("llama_1.2B_seq2k_b8_mlp",
+         b12(remat_policy="mlp"),
          8, 2048),
-        # lighter remat (save ffn gate/up) + long flash tiles
-        ("llama_1.2B_seq2k_b4_mlp_q512k1024",
-         b12(remat_policy="mlp", attn_block_q=512, attn_block_k=1024),
+        # lighter remat (save ffn gate/up)
+        ("llama_1.2B_seq2k_b4_mlp",
+         b12(remat_policy="mlp"),
          4, 2048),
         # same tokens as the b4/s2k winner, but seq 4k doubles the
         # CREDITED attention flops per token (the causal S^2 term)
-        ("llama_1.2B_seq4k_b2_mlp_q512k1024",
-         b12(remat_policy="mlp", attn_block_q=512, attn_block_k=1024,
-             max_seq_len=4096), 2, 4096),
+        ("llama_1.2B_seq4k_b2_mlp",
+         b12(remat_policy="mlp", max_seq_len=4096), 2, 4096),
         # no remat at all on the 0.8B: zero recompute if it fits
         ("llama_0.8B_seq2k_b4_noremat",
-         b08(remat=False, attn_block_q=512, attn_block_k=1024), 4, 2048),
-        # flagship size, biggest batch, long tiles (r3/r4 best measured)
-        ("llama_1.2B_seq2k_b8_q512k1024",
-         b12(attn_block_q=512, attn_block_k=1024), 8, 2048),
-        ("llama_1.2B_seq2k_b8_q256k512",
-         b12(attn_block_q=256, attn_block_k=512), 8, 2048),
+         b08(remat=False), 4, 2048),
+        # flagship size, biggest batch
         ("llama_1.2B_seq2k_b8", b12(), 8, 2048),
         ("llama_1.2B_seq2k_b4", b12(), 4, 2048),
         ("llama_0.8B_seq2k_b4", b08(), 4, 2048),
@@ -178,31 +173,17 @@ def _bench_candidates(llama, jnp):
     ]
 
 
-def _run_mfu(jax, jnp, llama, cfg, micro_batch: int, seq: int, steps: int,
-             attn_block_q: int = 0, attn_block_k: int = 0):
+def _run_mfu(jax, jnp, llama, cfg, micro_batch: int, seq: int, steps: int):
     """Build trainer + state, time `steps` donated train steps. Returns
     (trainer, state, batch, mean_step_seconds, per_step_seconds).
-    Raises on OOM. ``attn_block_q``/``attn_block_k`` are the TrainConfig
-    flash-tile knobs — non-zero values override the model config's
-    tiling (the autotune sweep's lever)."""
-    import dataclasses
-
+    Raises on OOM."""
     from dlrover_tpu.parallel import MeshConfig, build_mesh
     from dlrover_tpu.train.trainer import ElasticTrainer, TrainConfig
 
     tc = TrainConfig(
         global_batch_size=micro_batch, micro_batch_size=micro_batch,
         warmup_steps=0, total_steps=10_000,
-        attn_block_q=attn_block_q, attn_block_k=attn_block_k,
     )
-    # the TrainConfig knobs override the model default (0 = keep)
-    tiles = {}
-    if tc.attn_block_q:
-        tiles["attn_block_q"] = tc.attn_block_q
-    if tc.attn_block_k:
-        tiles["attn_block_k"] = tc.attn_block_k
-    if tiles:
-        cfg = dataclasses.replace(cfg, **tiles)
 
     mc = MeshConfig(dp=1, fsdp=1, sp=1, tp=1).resolve(1)
     mesh = build_mesh(mc, devices=jax.devices()[:1])
@@ -290,41 +271,6 @@ def _kernel_breakdown(trainer, step_s: float) -> dict:
         }
     except Exception as e:  # telemetry only
         return {"error": f"{type(e).__name__}: {str(e)[:200]}"}
-
-
-def _attn_tiling_sweep(jax, jnp, llama, cfg, micro: int, seq: int,
-                       steps: int, base_step_s: float, on_tpu: bool) -> dict:
-    """Measured flash-attention tile autotune on the mfu winner: re-run
-    the SAME winning candidate under alternative (block_q, block_k)
-    tilings via the TrainConfig knobs and keep each leg's step seconds.
-    The llama.py tile defaults are a VMEM-budget guess — this makes the
-    choice a measured number per hardware generation. TPU-only: the CPU
-    path runs reference attention, which ignores the tiles."""
-    if not on_tpu:
-        return {"skipped": "reference attention ignores tile sizes"}
-    base_q = getattr(cfg, "attn_block_q", 0) or 0
-    base_k = getattr(cfg, "attn_block_k", 0) or 0
-    legs = [{"tiling": f"q{base_q}k{base_k}",
-             "step_s": round(base_step_s, 4), "base": True}]
-    for q, k in ((256, 512), (512, 1024), (1024, 1024)):
-        if (q, k) == (base_q, base_k) or len(legs) >= 3:
-            continue
-        try:
-            tr, st, bt, dt, _ = _run_mfu(
-                jax, jnp, llama, cfg, micro, seq, steps,
-                attn_block_q=q, attn_block_k=k,
-            )
-            legs.append({"tiling": f"q{q}k{k}", "step_s": round(dt, 4)})
-            _release(jax, st, bt)
-            del tr, st, bt
-        except NanLossError:
-            raise
-        except Exception as e:  # OOM tilings fall through, recorded
-            legs.append({"tiling": f"q{q}k{k}",
-                         "error": f"{type(e).__name__}: {str(e)[:120]}"})
-    ok = [l for l in legs if "step_s" in l]
-    winner = min(ok, key=lambda l: l["step_s"]) if ok else {}
-    return {"legs": legs, "winner": winner.get("tiling", "")}
 
 
 def _memory_stats(trainer) -> dict:
@@ -1439,7 +1385,6 @@ def main():
     model_name = "none"
     cfg = None
     win_digest = {}
-    attn_tiling = {"skipped": "no winner"}
     if results:
         (_, model_name, cfg, micro, seq, step_s, _, win_digest,
          win_overrides) = max(results, key=lambda r: r[0])
@@ -1453,16 +1398,6 @@ def main():
             win_stack.enter_context(
                 getattr(_flags, flag_name).scoped(value)
             )
-        # flash-tile autotune on the winner, BEFORE its rebuild below
-        # holds HBM again (each leg builds a full trainer of its own)
-        attn_tiling = (
-            _attn_tiling_sweep(
-                jax, jnp, llama, cfg, micro, seq, timed_steps, step_s,
-                on_tpu,
-            )
-            if "mfu" in phases
-            else {"skipped": "mfu not in DLROVER_BENCH_PHASES"}
-        )
         # rebuild the winner (its arrays were freed during the sweep) for
         # the flash-checkpoint measurement below; untimed
         trainer, state, batch, _, _ = _run_mfu(
@@ -1511,9 +1446,9 @@ def main():
         # the top rows cover >= 80% of the step, so "what do we tune
         # next for MFU" is read straight off the bench JSON
         "kernel_breakdown": _kernel_breakdown(trainer, step_s),
-        # measured flash-tile autotune on the winner (TPU-only legs,
-        # run above before the winner rebuild re-occupied HBM)
-        "attn_tiling": attn_tiling,
+        # the flash kernels choose their own tiles from their shapes
+        # (ops/attention.py choose_tiles): there is nothing to sweep
+        "attn_tiling": {"skipped": "tiles are chosen by the kernel"},
         # XLA's HBM accounting for the winner, plus the zero-1 on/off
         # comparison on the same (tiny model, full-world dp mesh,
         # batch) — the measured form of the moment-sharding and

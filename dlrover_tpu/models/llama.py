@@ -73,15 +73,6 @@ class LlamaConfig:
     # fraction of its memory
     remat_policy: str = "all"
     attn_impl: str = "auto"   # auto | flash | reference | ring | ulysses
-    # flash-attention tile sizes — a hardware tuning knob (MXU is
-    # 128x128; longer q tiles amortize the kv-loop overhead when the
-    # per-core sequence is long enough). These defaults are a
-    # VMEM-budget guess, not a measurement: bench.py's mfu phase runs a
-    # tiling sweep (detail.attn_tiling) that times 2-3 tilings on the
-    # winning config, and TrainConfig.attn_block_q/attn_block_k let a
-    # deployment pin what its own chips prefer.
-    attn_block_q: int = 128
-    attn_block_k: int = 128
     # chunked fused cross-entropy (ops/chunked_ce.py): vocab columns per
     # scan step of the loss — peak loss activation is b*s*ce_chunk_size
     # f32 instead of the dense path's b*s*vocab. Gated globally by the
@@ -292,9 +283,7 @@ def _attention(cfg: LlamaConfig, mesh: Optional[Mesh], q, k, v):
             sp_attn = ring_attention
         qspec = P(BATCH_AXES, SP, TP, None)
         sharded = shard_map(
-            functools.partial(sp_attn, axis_name=SP, causal=True,
-                              block_q=cfg.attn_block_q,
-                              block_k=cfg.attn_block_k),
+            functools.partial(sp_attn, axis_name=SP, causal=True),
             mesh=mesh,
             in_specs=(qspec, qspec, qspec),
             out_specs=qspec,
@@ -303,9 +292,7 @@ def _attention(cfg: LlamaConfig, mesh: Optional[Mesh], q, k, v):
         return sharded(q, k, v)
     if impl == "reference":
         return mha_reference(q, k, v, causal=True)
-    return flash_attention(q, k, v, causal=True,
-                           block_q=cfg.attn_block_q,
-                           block_k=cfg.attn_block_k, mesh=mesh)
+    return flash_attention(q, k, v, causal=True, mesh=mesh)
 
 
 # ---------------------------------------------------------------------------
@@ -976,10 +963,7 @@ def _stage_layer_fn(cfg: LlamaConfig, mb: int, s_local: int, sp_size: int,
             from dlrover_tpu.ops.ulysses import ulysses_attention as sp_attn
         else:
             sp_attn = ring_attention
-        attn_fn = functools.partial(
-            sp_attn, axis_name=SP, causal=True,
-            block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
-        )
+        attn_fn = functools.partial(sp_attn, axis_name=SP, causal=True)
     else:
         offset = 0
         attn_fn = None  # _attention(mesh=None) -> flash

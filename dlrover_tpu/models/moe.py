@@ -63,8 +63,6 @@ class MoeConfig:
     param_dtype: Any = jnp.float32
     remat: bool = True
     attn_impl: str = "auto"
-    attn_block_q: int = 128
-    attn_block_k: int = 128
     # chunked fused cross-entropy (ops/chunked_ce.py): vocab columns per
     # loss scan step; DLROVER_TPU_CHUNKED_CE=0 restores dense logits
     ce_chunk_size: int = 2048
@@ -89,8 +87,6 @@ class MoeConfig:
             param_dtype=self.param_dtype,
             remat=self.remat,
             attn_impl=self.attn_impl,
-            attn_block_q=self.attn_block_q,
-            attn_block_k=self.attn_block_k,
             ce_chunk_size=self.ce_chunk_size,
         )
 

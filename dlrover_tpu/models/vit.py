@@ -23,7 +23,11 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from dlrover_tpu.ops.attention import flash_attention, mha_reference
+from dlrover_tpu.ops.attention import (
+    flash_attention,
+    flash_tiles,
+    mha_reference,
+)
 from dlrover_tpu.ops.chunked_ce import chunked_ce_enabled
 from dlrover_tpu.ops.fused_ce import cross_entropy_sums
 from dlrover_tpu.ops.norms import rms_norm
@@ -151,16 +155,6 @@ def patchify(cfg: ViTConfig, images: jnp.ndarray) -> jnp.ndarray:
     return x.reshape(b, gh * gw, p * p * c)
 
 
-def _divisor_block(s: int, cap: int = 128) -> int:
-    """Largest TPU-tile-aligned (multiple-of-8) divisor of ``s`` that is
-    <= cap, or 0 when none exists — the caller then takes the reference
-    attention path instead of handing Mosaic an unaligned tile."""
-    for b in range(min(cap, s) // 8 * 8, 0, -8):
-        if s % b == 0:
-            return b
-    return 0
-
-
 def _encoder_layer(cfg: ViTConfig, mesh, lp, x):
     dt = cfg.dtype
     b, s, d = x.shape
@@ -169,17 +163,15 @@ def _encoder_layer(cfg: ViTConfig, mesh, lp, x):
     y = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     qkv = (y @ lp["wqkv"].astype(dt)).reshape(b, s, 3, h, hd)
     q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-    # patch counts are rarely powers of two (ViT-B/16: 196, whose only
-    # divisors are tile-unfriendly): the flash kernel runs only when an
-    # aligned tile divides s; otherwise full attention — at patch-count
-    # sequence lengths the s x s score matrix is small enough that the
-    # reference path costs little
-    blk = _divisor_block(s)
-    if cfg.attn_impl == "reference" or blk == 0:
+    # patch counts are rarely powers of two (ViT-B/16: 196, with the
+    # class token 197, a prime): the kernel's chooser takes such a
+    # sequence as one block, which is always a legal tile. Only one too
+    # long for that has no tile, and takes full attention.
+    if (cfg.attn_impl == "reference"
+            or flash_tiles(s, s, hd, 1, q.dtype) is None):
         attn = mha_reference(q, k, v, causal=False)
     else:
-        attn = flash_attention(q, k, v, causal=False,
-                               block_q=blk, block_k=blk, mesh=mesh)
+        attn = flash_attention(q, k, v, causal=False, mesh=mesh)
     x = x + attn.reshape(b, s, d) @ lp["wo"].astype(dt)
 
     y = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)

@@ -16,6 +16,27 @@ where ``lse`` is the per-row logsumexp of the attention logits:
   is what lets ring attention merge per-chunk results by logsumexp and
   still get exact gradients through the merge.
 
+How the three kernels walk the (q, k) plane:
+
+- **tiles** come from `choose_tiles`: per kernel, from the sequence
+  lengths, ``head_dim``, the GQA group, the operand dtype and causal or
+  not, the largest that divide the sequences and fit ``_VMEM_BUDGET``.
+  ``block_q=None, block_k=None`` on the public entry points means
+  "choose"; there is no knob.
+- **GQA**: forward and dq take a kv head's whole group of query heads
+  as one ``(group * block_q, d)`` operand against one K/V block; dk/dv
+  keeps its K/V block resident and sweeps (query head, q block). K and V
+  are fetched once a group.
+- **causal**: a block wholly above the diagonal costs neither compute
+  nor DMA: its ``index_map`` names the block the pipeline already
+  holds. (Masking only the blocks the diagonal crosses was measured
+  and dropped: under 2 % of a kernel, PERF.md section 6, PR 26.)
+- **operands** go to the MXU in the dtype they arrive in (bf16 under
+  ``activation_dtype: bfloat16``, f32 in the CPU tests) and accumulate
+  in f32; ``P`` and ``dS`` are rounded to that dtype before their
+  matmuls; the scale is applied to f32 values. Max, sum, ``lse``,
+  ``delta`` and every accumulator stay f32.
+
 On the TPU backend the Pallas kernels are the path: one the chip's
 compiler refuses fails the step. Off TPU both directions run the jnp
 reference, so the same model code runs in CPU tests; ``interpret=True``
@@ -31,7 +52,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +61,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
+from dlrover_tpu.observability import trace
 from dlrover_tpu.parallel.mesh import BATCH_AXES, TP
 
 _NEG_INF = -1e30
@@ -90,65 +112,304 @@ def mha_reference(q, k, v, causal: bool = True, q_offset=0, k_offset=0):
 
 
 # ---------------------------------------------------------------------------
+# Tiles: chosen by the kernel from what it can see
+# ---------------------------------------------------------------------------
+
+#: Scoped VMEM every kernel asks the compiler for. The default grant is
+#: 16 MiB, which a (512, 1024) score tile with its temporaries already
+#: overflows; a v5e core has 128 MiB of VMEM, v5p and v6e no less.
+_VMEM_LIMIT = 64 * 2**20
+
+#: What `choose_tiles` lets its own count of a kernel's blocks, scratch
+#: and temporaries fill. Under the limit by the margin the count cannot
+#: see: the compiler's own spills and relayout copies.
+_VMEM_BUDGET = 40 * 2**20
+
+#: Largest ``(rows, block_k)`` the chooser offers each kernel, where
+#: ``rows`` is ``group * block_q`` for forward and dq (the GQA group is
+#: one operand) and ``block_q`` for dk/dv. Measured on the v5e at seq
+#: 4096, causal and not (PERF.md section 6, PR 26): the kernels are
+#: within 5 % of their best from (1024, 512) up, and past these sides
+#: the area a causal call computes and masks away, about
+#: ``(block_q + block_k) / 2 / seq`` of it, outgrows the grid steps saved.
+_MAX_TILE = {"fwd": (2048, 512), "dq": (2048, 512), "dkv": (1024, 1024)}
+
+_KERNELS = tuple(_MAX_TILE)
+
+_STAT_LANES = 128  # running max / sum: every lane of a row holds it
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _vmem_bytes(kernel: str, bq: int, bk: int, d: int, group: int,
+                itemsize: int) -> int:
+    """VMEM one grid step of ``kernel`` occupies at tiles ``(bq, bk)``:
+    the pipelined (double-buffered) blocks, the scratch accumulators and
+    the ``(rows, bk)`` temporaries of the body, minor dims padded to the
+    128 lanes they take."""
+    dl = _round_up(d, 128)
+    stat = _STAT_LANES * 4  # one row of lse / delta / running max, f32
+    if kernel == "dkv":
+        tile = _round_up(bk, 8) * _round_up(bq, 128)
+        blocks = 2 * (2 * bq * dl * itemsize          # q, do
+                      + 4 * bk * dl * itemsize        # k, v, dk, dv
+                      + 2 * 8 * _round_up(bq, 128) * 4)  # lse, delta rows
+        scratch = 2 * bk * dl * 4
+        return blocks + scratch + tile * (4 * 4 + itemsize)
+    rows = group * bq
+    tile = _round_up(rows, 8) * _round_up(bk, 128)
+    if kernel == "fwd":
+        blocks = 2 * (2 * rows * dl * itemsize        # q, out
+                      + 2 * bk * dl * itemsize        # k, v
+                      + rows * stat)                  # lse
+        scratch = rows * dl * 4 + 2 * rows * stat     # acc, m, l
+        return blocks + scratch + tile * (3 * 4 + itemsize)
+    blocks = 2 * (3 * rows * dl * itemsize            # q, do, dq
+                  + 2 * bk * dl * itemsize            # k, v
+                  + 2 * rows * stat)                  # lse, delta
+    scratch = rows * dl * 4
+    return blocks + scratch + tile * (4 * 4 + itemsize)
+
+
+def _tile_sides(s: int, cap: int, lanes_only: bool):
+    """Tile sides for a sequence of ``s``, best first: its divisors up
+    to ``cap`` that are multiples of 128 (whole MXU passes, whole vector
+    registers). A sequence 128 does not divide goes as one block, which
+    is always legal, if that fits; else in the multiples of 8 a block
+    may start at (not where the side is a block's lane dim)."""
+    def divisors(align):
+        return [t for t in range(min(cap, s - 1) // align * align, 0, -align)
+                if s % t == 0]
+
+    if s % 128 == 0:
+        return [s] * (s <= cap) + divisors(128)
+    return [s] + ([] if lanes_only else divisors(8))
+
+
+def choose_tiles(kernel: str, sq: int, sk: int, head_dim: int, group: int,
+                 dtype) -> Optional[Tuple[int, int]]:
+    """``(block_q, block_k)`` for one of the three kernels (``"fwd"``,
+    ``"dq"``, ``"dkv"``): the pair of largest area, among the sides
+    `_tile_sides` offers up to ``_MAX_TILE``, whose `_vmem_bytes` fit
+    ``_VMEM_BUDGET``. ``None`` if not even the smallest pair fits (a
+    sequence with no aligned divisor that is too long to be one block):
+    the caller has the reference path. Causal or not does not enter: on
+    the chip both want the same tiles.
+
+    Short and awkward sequences come out as before there was a
+    chooser: 8 and 64 as one block, 196 and 197 as one block, anything
+    128 divides at 128 or more."""
+    max_rows, max_k = _MAX_TILE[kernel]
+    itemsize = jnp.dtype(dtype).itemsize
+    if kernel == "dkv":
+        # block_q is the lane dim of the transposed score tile and of
+        # the lse / delta rows
+        q_sides = _tile_sides(sq, max_rows, lanes_only=True)
+    else:
+        q_sides = _tile_sides(sq, max(max_rows // group, 128),
+                              lanes_only=False)
+    k_sides = _tile_sides(sk, max_k, lanes_only=False)
+    best = None
+    for bk in k_sides:
+        for bq in q_sides:
+            if _vmem_bytes(kernel, bq, bk, head_dim, group,
+                           itemsize) > _VMEM_BUDGET:
+                continue
+            # largest area; of equals the squarer, then the wider block_k
+            if best is None or (bq * bk, min(bq, bk)) > (
+                    best[0] * best[1], min(best)):
+                best = (bq, bk)
+            break  # q_sides descend: smaller ones have smaller area
+    return best
+
+
+def flash_tiles(sq: int, sk: int, head_dim: int, group: int, dtype):
+    """``{kernel: (block_q, block_k)}`` for the three kernels of one
+    call, or None if one of them has no tile that fits."""
+    tiles = {
+        kernel: choose_tiles(kernel, sq, sk, head_dim, group, dtype)
+        for kernel in _KERNELS
+    }
+    return None if None in tiles.values() else tiles
+
+
+#: call sites of this build that got tiles of 128 or less
+_fallback_sites = 0
+
+
+def reset_tile_report():
+    """A step build starts: `ElasticTrainer.lower_step` calls this
+    before it traces, so ``attn.tile_fallback`` counts one build's call
+    sites."""
+    global _fallback_sites
+    _fallback_sites = 0
+    trace.gauge("attn.tile_fallback", 0)
+
+
+def _tiles_for(q, k, block_q, block_k):
+    """``{kernel: (block_q, block_k)}`` for this call: a pinned pair
+    goes to all three kernels; None (both) is `flash_tiles`' choice,
+    made while the step is traced."""
+    if block_q is not None or block_k is not None:
+        assert block_q is not None and block_k is not None, (block_q, block_k)
+        return dict.fromkeys(_KERNELS, (block_q, block_k))
+    sq, h, d = q.shape[1:]
+    sk, hkv = k.shape[1:3]
+    tiles = flash_tiles(sq, sk, d, h // hkv, q.dtype)
+    if tiles is None:
+        raise ValueError(
+            f"flash attention: no tile of seq ({sq}, {sk}) at head_dim "
+            f"{d}, group {h // hkv} fits {_VMEM_BUDGET} bytes of VMEM; "
+            "pad the sequence to a multiple of 128 or take mha_reference"
+        )
+    return tiles
+
+
+def _report_tiles(block_q: int, block_k: int):
+    """The gauges that say which tiling the job runs: the forward's own
+    chosen tiles, and the count of call sites left at 128 or less."""
+    global _fallback_sites
+    trace.gauge("attn.block_q", block_q)
+    trace.gauge("attn.block_k", block_k)
+    if max(block_q, block_k) <= 128:
+        _fallback_sites += 1
+        trace.gauge("attn.tile_fallback", _fallback_sites)
+
+
+# ---------------------------------------------------------------------------
+# What the kernels share
+# ---------------------------------------------------------------------------
+
+_NT = (((1,), (1,)), ((), ()))  # a @ b^T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
+
+
+def _dot(a, b, dims):
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _lane_fill(x, n: int):
+    """``x`` (rows, lanes) with every lane of a row equal -> (rows, n),
+    by whole-register copies where ``n`` allows it."""
+    lanes = x.shape[1]
+    if n == lanes:
+        return x
+    if n % lanes == 0:
+        return pltpu.repeat(x, n // lanes, axis=1)
+    if n < lanes:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _clip_tiles(sq, sk, block_q, block_k):
+    block_q, block_k = min(block_q, sq), min(block_k, sk)
+    assert sq % block_q == 0 and sk % block_k == 0, (sq, sk, block_q, block_k)
+    return block_q, block_k
+
+
+def _last_k_block(qi, block_q: int, block_k: int, n_k: int):
+    """Last k block the q block ``qi`` needs under the causal mask."""
+    return jnp.minimum(((qi + 1) * block_q - 1) // block_k, n_k - 1)
+
+
+def _first_q_block(ki, block_q: int, block_k: int, n_q: int):
+    """First q block the k block ``ki`` needs under the causal mask."""
+    return jnp.minimum((ki * block_k) // block_q, n_q - 1)
+
+
+def _when_needed(causal: bool, qi, ki, block_q: int, block_k: int):
+    """Decorator: run the body unless the (qi, ki) block of the score
+    plane lies wholly above the causal diagonal."""
+    if not causal:
+        return lambda body: body()
+    return pl.when(ki * block_k <= qi * block_q + block_q - 1)
+
+
+def _causal_mask(qi, ki, group: int, block_q: int, block_k: int):
+    """(group * block_q, block_k) bool: query position >= key position.
+    The group's heads repeat the q block's positions."""
+    shape = (group, block_q, block_k)
+    qpos = qi * block_q + lax.broadcasted_iota(jnp.int32, shape, 1)
+    kpos = ki * block_k + lax.broadcasted_iota(jnp.int32, shape, 2)
+    rows = (group * block_q, block_k)
+    return qpos.reshape(rows) >= kpos.reshape(rows)
+
+
+def _compiler_params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=_VMEM_LIMIT,
+    )
+
+
+# ---------------------------------------------------------------------------
 # Pallas TPU forward kernel
 # ---------------------------------------------------------------------------
 
 def _flash_fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-    *, block_q: int, block_k: int, n_kblocks: int, causal: bool, scale: float
+    *, group: int, block_q: int, block_k: int, n_kblocks: int,
+    causal: bool, scale: float
 ):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
+    rows, d = acc_ref.shape
+    last_k = (_last_k_block(qi, block_q, block_k, n_kblocks) if causal
+              else n_kblocks - 1)
 
     @pl.when(ki == 0)
     def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
 
-    # Entire k block above the causal diagonal → skip all compute.
-    if causal:
-        block_needed = ki * block_k <= qi * block_q + block_q - 1
-    else:
-        block_needed = qi >= 0  # always true, traced
-
-    @pl.when(block_needed)
+    # k block 0 is every row's first and holds its position 0, so the
+    # running max is a real score before any wholly masked row of a
+    # later block meets it (exp(-1e30 - m) == 0, never exp(0))
+    @_when_needed(causal, qi, ki, block_q, block_k)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale          # (bq, d)
-        k = k_ref[0, 0].astype(jnp.float32)                  # (bk, d)
-        v = v_ref[0, 0].astype(jnp.float32)                  # (bk, d)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                     # (bq, bk)
+        q = q_ref[0, 0].reshape(rows, d)                     # (G*bq, d)
+        k = k_ref[0, 0]                                      # (bk, d)
+        v = v_ref[0, 0]
+        s = _dot(q, k, _NT) * scale                          # (G*bq, bk) f32
         if causal:
-            qpos = qi * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            kpos = ki * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            s = jnp.where(qpos >= kpos, s, _NEG_INF)
-        m_prev = m_ref[:, 0]                                  # (bq,)
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_cur[:, None])
-        corr = jnp.exp(m_prev - m_cur)
-        l_ref[:, 0] = l_ref[:, 0] * corr + jnp.sum(p, axis=1)
-        acc_ref[:] = acc_ref[:] * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            s = jnp.where(_causal_mask(qi, ki, group, block_q, block_k),
+                          s, _NEG_INF)
+        m_prev = m_ref[...]                                  # (G*bq, 128)
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _lane_fill(m_next, block_k))
+        corr = jnp.exp(m_prev - m_next)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[...] = m_next
+        acc_ref[...] = acc_ref[...] * _lane_fill(corr, d) + _dot(
+            p.astype(v.dtype), v, _NN
         )
-        m_ref[:, 0] = m_cur
 
-    @pl.when(ki == n_kblocks - 1)
+    @pl.when(ki == last_k)
     def _finalize():
-        l = l_ref[:, 0]
+        l = l_ref[...]
         lsafe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[:] / lsafe[:, None]).astype(o_ref.dtype)
+        out = acc_ref[...] / _lane_fill(lsafe, d)
+        o_ref[0, 0] = out.reshape(group, block_q, d).astype(o_ref.dtype)
         # lse carries a broadcast minor lane dim for TPU block tiling
         # (see _LSE_LANES)
-        lse = m_ref[:, 0] + jnp.log(lsafe)
-        lse_ref[0, 0] = jnp.broadcast_to(lse[:, None], lse_ref[0, 0].shape)
+        lse = (m_ref[...] + jnp.log(lsafe))[:, :_LSE_LANES]
+        lse_ref[0, 0] = lse.reshape(group, block_q, _LSE_LANES)
+
+
+def _kv_specs(block_k: int, d: int, causal: bool, block_q: int, n_k: int):
+    """K and V BlockSpecs of the (b, hkv, n_q, n_k) grids. Above the
+    causal diagonal the index stays on the last block the q block
+    needs: the pipeline sees an unchanged index and issues no DMA."""
+    def index(bi, hi, qi, ki):
+        if causal:
+            ki = jnp.minimum(ki, _last_k_block(qi, block_q, block_k, n_k))
+        return (bi, hi, ki, 0)
+
+    spec = pl.BlockSpec((1, 1, block_k, d), index)
+    return spec, spec
 
 
 def _flash_fwd_pallas(q, k, v, causal: bool, block_q: int, block_k: int,
@@ -156,58 +417,45 @@ def _flash_fwd_pallas(q, k, v, causal: bool, block_q: int, block_k: int,
     b, sq, h, d = q.shape
     sk, hkv = k.shape[1], k.shape[2]
     group = h // hkv
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
-    assert sq % block_q == 0 and sk % block_k == 0, (sq, sk, block_q, block_k)
+    block_q, block_k = _clip_tiles(sq, sk, block_q, block_k)
     n_q, n_k = sq // block_q, sk // block_k
-    scale = 1.0 / math.sqrt(d)
+    rows = group * block_q
 
-    # (b, s, h, d) → (b, h, s, d) so the contiguous minor dims tile cleanly.
-    qt = q.transpose(0, 2, 1, 3)
+    # (b, s, h, d) → (b, h, s, d) so the contiguous minor dims tile
+    # cleanly; the query heads of a kv head are adjacent, so splitting
+    # h into (hkv, group) is free
+    qt = q.transpose(0, 2, 1, 3).reshape(b, hkv, group, sq, d)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
 
-    grid = (b, h, n_q, n_k)
-    kernel = functools.partial(
-        _flash_fwd_kernel,
-        block_q=block_q, block_k=block_k, n_kblocks=n_k,
-        causal=causal, scale=scale,
-    )
+    def q_rows(lanes):
+        return pl.BlockSpec((1, 1, group, block_q, lanes),
+                            lambda bi, hi, qi, ki: (bi, hi, 0, qi, 0))
+
     out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec(
-                (1, 1, block_k, d),
-                lambda bi, hi, qi, ki, _g=group: (bi, hi // _g, ki, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, block_k, d),
-                lambda bi, hi, qi, ki, _g=group: (bi, hi // _g, ki, 0),
-            ),
-        ],
-        out_specs=[
-            pl.BlockSpec(
-                (1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
-            ),
-            pl.BlockSpec(
-                (1, 1, block_q, _LSE_LANES),
-                lambda bi, hi, qi, ki: (bi, hi, qi, 0),
-            ),
-        ],
+        functools.partial(
+            _flash_fwd_kernel, group=group, block_q=block_q,
+            block_k=block_k, n_kblocks=n_k, causal=causal,
+            scale=1.0 / math.sqrt(d),
+        ),
+        grid=(b, hkv, n_q, n_k),
+        in_specs=[q_rows(d), *_kv_specs(block_k, d, causal, block_q, n_k)],
+        out_specs=[q_rows(d), q_rows(_LSE_LANES)],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, sq, _LSE_LANES), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, group, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((b, hkv, group, sq, _LSE_LANES),
+                                 jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((rows, d), jnp.float32),
+            pltpu.VMEM((rows, _STAT_LANES), jnp.float32),
+            pltpu.VMEM((rows, _STAT_LANES), jnp.float32),
         ],
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(qt, kt, vt)
-    return out.transpose(0, 2, 1, 3), lse[..., 0]
+    out = out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+    return out, lse.reshape(b, h, sq, _LSE_LANES)[..., 0]
 
 
 # ---------------------------------------------------------------------------
@@ -219,61 +467,49 @@ def _flash_fwd_pallas(q, k, v, causal: bool, block_q: int, block_k: int,
 #   dp = do @ v^T
 #   ds = p * (dp - delta) * scale     delta = rowsum(do * o) - g_lse
 #   dq = ds @ k ; dk = ds^T @ q ; dv = p^T @ do
-# dq iterates k blocks per q block; dk/dv iterates q blocks per k block
-# (per *query* head — the group sum down to kv heads happens outside,
-# keeping the kernels free of cross-block output contention).
+# dq iterates k blocks per q block, the GQA group as one operand like
+# the forward. dk/dv iterates (query head of the group, q block) per k
+# block and computes the transposed tiles s^T, p^T, dp^T, ds^T
+# directly, so every product is a plain a @ b or a @ b^T and lse /
+# delta broadcast along sublanes from a (1, block_q) row. The scale
+# factor of ds is applied once to the finished dq / dk accumulators.
 
 
 def _flash_bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref,
-    *, block_q: int, block_k: int, n_kblocks: int, causal: bool, scale: float
+    *, group: int, block_q: int, block_k: int, n_kblocks: int,
+    causal: bool, scale: float
 ):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
+    rows, d = acc_ref.shape
+    last_k = (_last_k_block(qi, block_q, block_k, n_kblocks) if causal
+              else n_kblocks - 1)
 
     @pl.when(ki == 0)
     def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    if causal:
-        block_needed = ki * block_k <= qi * block_q + block_q - 1
-    else:
-        block_needed = qi >= 0
-
-    @pl.when(block_needed)
+    @_when_needed(causal, qi, ki, block_q, block_k)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0, :, 0]                             # (bq,)
-        delta = delta_ref[0, 0, :, 0]                         # (bq,)
-        s = jax.lax.dot_general(
-            q * scale, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        q = q_ref[0, 0].reshape(rows, d)
+        do = do_ref[0, 0].reshape(rows, d)
+        k = k_ref[0, 0]
+        v = v_ref[0, 0]
+        lse = lse_ref[0, 0].reshape(rows, _LSE_LANES)[:, :1]     # (G*bq, 1)
+        delta = delta_ref[0, 0].reshape(rows, _LSE_LANES)[:, :1]
+        s = _dot(q, k, _NT) * scale
         if causal:
-            qpos = qi * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            kpos = ki * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            s = jnp.where(qpos >= kpos, s, _NEG_INF)
-        p = jnp.exp(s - lse[:, None])                         # (bq, bk)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta[:, None]) * scale
-        acc_ref[:] = acc_ref[:] + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+            s = jnp.where(_causal_mask(qi, ki, group, block_q, block_k),
+                          s, _NEG_INF)
+        p = jnp.exp(s - lse)                                     # (G*bq, bk)
+        ds = p * (_dot(do, v, _NT) - delta)
+        acc_ref[...] = acc_ref[...] + _dot(ds.astype(k.dtype), k, _NN)
 
-    @pl.when(ki == n_kblocks - 1)
+    @pl.when(ki == last_k)
     def _finalize():
-        dq_ref[0, 0] = acc_ref[:].astype(dq_ref.dtype)
+        dq = acc_ref[...] * scale
+        dq_ref[0, 0] = dq.reshape(group, block_q, d).astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(
@@ -291,66 +527,44 @@ def _flash_bwd_dkv_kernel(
 
     @pl.when(j == 0)
     def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    if causal:
-        block_needed = qi * block_q + block_q - 1 >= ki * block_k
-    else:
-        block_needed = ki >= 0
-
-    @pl.when(block_needed)
+    @_when_needed(causal, qi, ki, block_q, block_k)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0, :, 0]
-        delta = delta_ref[0, 0, :, 0]
-        s = jax.lax.dot_general(
-            q * scale, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                     # (bq, bk)
+        q = q_ref[0, 0]                                       # (bq, d)
+        do = do_ref[0, 0]
+        k = k_ref[0, 0]                                       # (bk, d)
+        v = v_ref[0, 0]
+        lse = lse_ref[0, 0]                                   # (1, bq)
+        delta = delta_ref[0, 0]
+        st = _dot(k, q, _NT) * scale                          # (bk, bq)
         if causal:
-            qpos = qi * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
             kpos = ki * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
+                jnp.int32, (block_k, block_q), 0
             )
-            s = jnp.where(qpos >= kpos, s, _NEG_INF)
-        p = jnp.exp(s - lse[:, None])
+            qpos = qi * block_q + lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 1
+            )
+            st = jnp.where(qpos >= kpos, st, _NEG_INF)
+        pt = jnp.exp(st - lse)
         # dv += p^T @ do
-        dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta[:, None]) * scale
+        dv_acc[...] = dv_acc[...] + _dot(pt.astype(do.dtype), do, _NN)
+        dst = pt * (_dot(v, do, _NT) - delta)
         # dk += ds^T @ q
-        dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dk_acc[...] = dk_acc[...] + _dot(dst.astype(q.dtype), q, _NN)
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _finalize():
-        dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
+        dk_ref[0, 0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
-                      block_q, block_k, interpret=False):
+                      dq_tiles, dkv_tiles, interpret=False):
     b, sq, h, d = q.shape
     sk, hkv = k.shape[1], k.shape[2]
     group = h // hkv
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
-    assert sq % block_q == 0 and sk % block_k == 0, (sq, sk, block_q, block_k)
-    n_q, n_k = sq // block_q, sk // block_k
     scale = 1.0 / math.sqrt(d)
 
     # delta rows; the lse cotangent folds in here (see module docstring)
@@ -364,52 +578,62 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     dot = do.transpose(0, 2, 1, 3)
-    # broadcast minor lane dim for TPU block tiling (see fwd kernel)
-    lse4 = jnp.broadcast_to(lse[..., None], (b, h, sq, _LSE_LANES))
-    delta4 = jnp.broadcast_to(delta[..., None], (b, h, sq, _LSE_LANES))
 
-    # -- dq: grid (b, h, n_q, n_k), q block fixed per-(i), k rotates (j) --
+    # -- dq: grid (b, hkv, n_q, n_k), the group's q block fixed, k rotates --
+    block_q, block_k = _clip_tiles(sq, sk, *dq_tiles)
+    n_q, n_k = sq // block_q, sk // block_k
+
+    def q_rows(lanes):
+        return pl.BlockSpec((1, 1, group, block_q, lanes),
+                            lambda bi, hi, i, j: (bi, hi, 0, i, 0))
+
+    def lanes8(x):
+        # broadcast minor lane dim for TPU block tiling (see fwd kernel)
+        return jnp.broadcast_to(
+            x.reshape(b, hkv, group, sq, 1), (b, hkv, group, sq, _LSE_LANES)
+        )
+
     dq = pl.pallas_call(
         functools.partial(
-            _flash_bwd_dq_kernel, block_q=block_q, block_k=block_k,
-            n_kblocks=n_k, causal=causal, scale=scale,
+            _flash_bwd_dq_kernel, group=group, block_q=block_q,
+            block_k=block_k, n_kblocks=n_k, causal=causal, scale=scale,
         ),
-        grid=(b, h, n_q, n_k),
+        grid=(b, hkv, n_q, n_k),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, i, j: (bi, hi, i, 0)),
-            pl.BlockSpec(
-                (1, 1, block_k, d),
-                lambda bi, hi, i, j, _g=group: (bi, hi // _g, j, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, block_k, d),
-                lambda bi, hi, i, j, _g=group: (bi, hi // _g, j, 0),
-            ),
-            pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, i, j: (bi, hi, i, 0)),
-            pl.BlockSpec(
-                (1, 1, block_q, _LSE_LANES),
-                lambda bi, hi, i, j: (bi, hi, i, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, block_q, _LSE_LANES),
-                lambda bi, hi, i, j: (bi, hi, i, 0),
-            ),
+            q_rows(d), *_kv_specs(block_k, d, causal, block_q, n_k),
+            q_rows(d), q_rows(_LSE_LANES), q_rows(_LSE_LANES),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1, block_q, d), lambda bi, hi, i, j: (bi, hi, i, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        out_specs=q_rows(d),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, group, sq, d), q.dtype),
+        scratch_shapes=[pltpu.VMEM((group * block_q, d), jnp.float32)],
+        compiler_params=_compiler_params(),
         interpret=interpret,
-    )(qt, kt, vt, dot, lse4, delta4)
+    )(
+        qt.reshape(b, hkv, group, sq, d), kt, vt,
+        dot.reshape(b, hkv, group, sq, d), lanes8(lse), lanes8(delta),
+    )
 
     # -- dk/dv: kv-head-major grid (b, hkv, n_k, group*n_q): the group's
     # query heads accumulate into one VMEM scratch per kv head, so HBM
     # holds (b, hkv, sk, d) outputs — group x less traffic than the
     # per-query-head form (round-2 Weak #7), which matters at 8:1 GQA.
-    def _q_head(bi, hi, i, j, _g=group, _nq=n_q):
-        return (bi, hi * _g + j // _nq, j % _nq, 0)
+    block_q, block_k = _clip_tiles(sq, sk, *dkv_tiles)
+    n_q, n_k = sq // block_q, sk // block_k
 
+    def q_head(bi, hi, i, j):
+        # above the diagonal the index waits on the first q block this
+        # k block needs: no DMA for blocks that compute nothing
+        qi = j % n_q
+        if causal:
+            qi = jnp.maximum(qi, _first_q_block(i, block_q, block_k, n_q))
+        return (bi, hi * group + j // n_q, qi, 0)
+
+    def q_head_row(bi, hi, i, j):
+        bi, head, qi, _ = q_head(bi, hi, i, j)
+        return (bi, head, 0, qi)
+
+    kv_block = pl.BlockSpec((1, 1, block_k, d),
+                            lambda bi, hi, i, j: (bi, hi, i, 0))
     dkh, dvh = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
@@ -417,32 +641,27 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
         ),
         grid=(b, hkv, n_k, group * n_q),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), _q_head),
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, i, j: (bi, hi, i, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, i, j: (bi, hi, i, 0)),
-            pl.BlockSpec((1, 1, block_q, d), _q_head),
-            pl.BlockSpec((1, 1, block_q, _LSE_LANES), _q_head),
-            pl.BlockSpec((1, 1, block_q, _LSE_LANES), _q_head),
+            pl.BlockSpec((1, 1, block_q, d), q_head),
+            kv_block, kv_block,
+            pl.BlockSpec((1, 1, block_q, d), q_head),
+            pl.BlockSpec((1, 1, 1, block_q), q_head_row),
+            pl.BlockSpec((1, 1, 1, block_q), q_head_row),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, i, j: (bi, hi, i, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, i, j: (bi, hi, i, 0)),
-        ],
+        out_specs=[kv_block, kv_block],
         out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, sk, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, sk, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, sk, d), k.dtype),
+            jax.ShapeDtypeStruct((b, hkv, sk, d), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
+        compiler_params=_compiler_params(),
         interpret=interpret,
-    )(qt, kt, vt, dot, lse4, delta4)
+    )(qt, kt, vt, dot, lse.reshape(b, h, 1, sq), delta.reshape(b, h, 1, sq))
 
-    dq = dq.transpose(0, 2, 1, 3)
-    dk = dkh.transpose(0, 2, 1, 3).astype(k.dtype)
-    dv = dvh.transpose(0, 2, 1, 3).astype(v.dtype)
-    return dq, dk, dv
+    dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+    return dq, dkh.transpose(0, 2, 1, 3), dvh.transpose(0, 2, 1, 3)
 
 
 def _on_tpu() -> bool:
@@ -455,9 +674,12 @@ def _on_tpu() -> bool:
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention_with_lse(q, k, v, causal: bool = True,
-                             block_q: int = 128, block_k: int = 128,
+                             block_q: Optional[int] = None,
+                             block_k: Optional[int] = None,
                              interpret: bool = False):
-    """(out (b,s,h,d), lse (b,h,s)) — both differentiable."""
+    """(out (b,s,h,d), lse (b,h,s)) — both differentiable. ``block_q``
+    / ``block_k`` None (both): each kernel takes `choose_tiles`' pair;
+    a pinned pair goes to all three."""
     return _flash_with_lse_fwd(q, k, v, causal, block_q, block_k, interpret)[0]
 
 
@@ -466,8 +688,11 @@ def _flash_with_lse_fwd(q, k, v, causal, block_q, block_k, interpret):
     # (profiler/kernel_ledger.py classifies HLO sites by op_name path)
     with jax.named_scope("attention_fwd"):
         if interpret or _on_tpu():
-            out, lse = _flash_fwd_pallas(q, k, v, causal, block_q,
-                                         block_k, interpret=interpret)
+            tiles = _tiles_for(q, k, block_q, block_k)
+            if block_q is None:
+                _report_tiles(*tiles["fwd"])
+            out, lse = _flash_fwd_pallas(q, k, v, causal, *tiles["fwd"],
+                                         interpret=interpret)
         else:
             out, lse = mha_reference_with_lse(q, k, v, causal=causal)
     return (out, lse), (q, k, v, out, lse)
@@ -478,9 +703,10 @@ def _flash_with_lse_bwd(causal, block_q, block_k, interpret, res, g):
     g_out, g_lse = g
     with jax.named_scope("attention_bwd"):
         if interpret or _on_tpu():
+            tiles = _tiles_for(q, k, block_q, block_k)
             return _flash_bwd_pallas(
-                q, k, v, o, lse, g_out, g_lse, causal, block_q, block_k,
-                interpret=interpret,
+                q, k, v, o, lse, g_out, g_lse, causal,
+                tiles["dq"], tiles["dkv"], interpret=interpret,
             )
         _, vjp = jax.vjp(
             lambda q, k, v: mha_reference_with_lse(q, k, v,
@@ -494,7 +720,8 @@ flash_attention_with_lse.defvjp(_flash_with_lse_fwd, _flash_with_lse_bwd)
 
 
 def flash_attention(q, k, v, causal: bool = True,
-                    block_q: int = 128, block_k: int = 128,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     interpret: bool = False,
                     mesh: Optional[Mesh] = None):
     """``mesh``: the mesh the caller's jit partitions over. The compiler
@@ -516,4 +743,3 @@ def flash_attention(q, k, v, causal: bool = True,
         attn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)
-

@@ -29,6 +29,8 @@ backward does not keep every rotated kv copy.
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -42,8 +44,8 @@ def ring_attention(
     v: jnp.ndarray,  # (b, s_local, hkv, d)
     axis_name: str,
     causal: bool = True,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
 ) -> jnp.ndarray:
     b, s_local, h, d = q.shape
     n = lax.psum(1, axis_name)

@@ -36,6 +36,8 @@ shapes where even replication can't produce a valid GQA grouping
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax.numpy as jnp
 from jax import lax
 
@@ -62,8 +64,8 @@ def ulysses_attention(
     v: jnp.ndarray,  # (b, s_local, hkv, d)
     axis_name: str,
     causal: bool = True,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
 ) -> jnp.ndarray:
     """Call under ``shard_map`` with q/k/v sequence-sharded over
     ``axis_name``; returns the output in the same layout. Differentiable

@@ -40,7 +40,7 @@ from dlrover_tpu.common.world import WorldDescriptor
 from dlrover_tpu.lint import retrace_guard
 from dlrover_tpu.observability import trace
 from dlrover_tpu.observability.digest import StepTimeDigest
-from dlrover_tpu.ops import hier_collectives
+from dlrover_tpu.ops import attention, hier_collectives
 from dlrover_tpu.parallel.mesh import MeshConfig
 from dlrover_tpu.parallel.sharding import batch_spec
 from dlrover_tpu.train import live_reshard, warm_compile, zero1
@@ -105,14 +105,6 @@ class TrainConfig:
     # applies; with accum == 1 there is no backward to hide behind and
     # the schedule degenerates to hier's.
     overlap_collectives: bool = True
-    # Flash-attention Pallas tile sizes (ops/attention.py block_q /
-    # block_k). 0 = keep the model config's default (the llama.py
-    # numbers are a VMEM-budget guess, not a measurement — bench.py's
-    # mfu tiling sweep measures 2–3 tilings and reports the winner, so
-    # a deployment pins what its own chips prefer). Callers that build
-    # a model config thread non-zero values into it.
-    attn_block_q: int = 0
-    attn_block_k: int = 0
 
 
 def make_optimizer(tc: TrainConfig) -> optax.GradientTransformation:
@@ -1043,6 +1035,9 @@ class ElasticTrainer:
         # trace spine: every real build (cold AND speculative) is a pair
         # of compile spans; warm hits returned above and cost nothing
         t0 = time.perf_counter()
+        # the flash kernels choose their tiles while the step is traced
+        # and say so in the attn.* gauges: one build, one count
+        attention.reset_tile_report()
         with trace.span("compile", "build.lower", world=mesh.size,
                         source=source, config=config_hash):
             lowered = self._build_step(

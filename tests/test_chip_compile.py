@@ -98,6 +98,67 @@ def test_flash_fwd_bwd_compiles(one_chip, kernels_are_the_path, bq, bk):
     assert hlo.count("tpu_custom_call") == 3  # fwd, dq, dk/dv
 
 
+# The listed cells' own attention shapes, bf16, causal, 32 q / 8 kv
+# heads of 128, at the tiles the kernels choose for themselves: a tile
+# the v5e's compiler refuses fails here and not in the chip run.
+CELL_SEQ, CELL_HEADS, CELL_KV_HEADS, CELL_HEAD_DIM = 4096, 32, 8, 128
+
+
+def _cell_qkv(batch, sharding):
+    q = jax.ShapeDtypeStruct(
+        (batch, CELL_SEQ, CELL_HEADS, CELL_HEAD_DIM), jnp.bfloat16,
+        sharding=sharding)
+    kv = jax.ShapeDtypeStruct(
+        (batch, CELL_SEQ, CELL_KV_HEADS, CELL_HEAD_DIM), jnp.bfloat16,
+        sharding=sharding)
+    return q, kv, kv
+
+
+def _chosen_loss(mesh=None):
+    def loss(q, k, v):
+        out = attention.flash_attention(q, k, v, mesh=mesh)  # tiles: chosen
+        return out.astype(jnp.float32).sum()
+    return loss
+
+
+def test_cell_tiles_are_larger_than_128():
+    tiles = attention.flash_tiles(
+        CELL_SEQ, CELL_SEQ, CELL_HEAD_DIM, CELL_HEADS // CELL_KV_HEADS,
+        jnp.bfloat16)
+    assert all(min(t) > 128 for t in tiles.values()), tiles
+
+
+def test_flash_one_chip_cell_compiles_at_chosen_tiles(
+        one_chip, kernels_are_the_path):
+    # mistral7b-d5-steady: 2 sequences a step on one chip
+    args = _cell_qkv(2, one_chip)
+    assert _compile(_chosen_loss(), *args).count("tpu_custom_call") == 1
+    hlo = _compile(jax.grad(_chosen_loss(), argnums=(0, 1, 2)), *args)
+    assert hlo.count("tpu_custom_call") == 3  # fwd, dq, dk/dv
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize(
+    "b,s,h,hkv,d",
+    [(1, 1024, 32, 8, 128),   # a ring chunk: diagonal and off it
+     (8, 196, 12, 12, 64),    # ViT-B/16's patches: one whole block
+     (8, 197, 12, 12, 64),    # with the class token: a prime
+     (1, 8192, 64, 8, 128)],  # group 8 (Llama-3-70B heads)
+)
+def test_flash_other_callers_compile_at_chosen_tiles(
+        one_chip, kernels_are_the_path, b, s, h, hkv, d, causal):
+    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, s, hkv, d), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        out, lse = attention.flash_attention_with_lse(q, k, v, causal)
+        return out.astype(jnp.float32).sum() + lse.sum()
+
+    hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert hlo.count("tpu_custom_call") == 3
+
+
 # (tokens, d, vocab): Llama-3-8B's head at seq 2048, the widths the
 # backward was refused at under the default 16 MiB of scoped VMEM
 # ("Scoped allocation with size 22.52M and limit 16.00M" in the dx
@@ -155,6 +216,17 @@ def test_flash_compiles_over_four_chips(mesh4, kernels_are_the_path):
         return out.astype(jnp.float32).sum()
 
     hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert hlo.count("tpu_custom_call") == 3
+
+
+def test_flash_four_chip_cell_compiles_at_chosen_tiles(
+        mesh4, kernels_are_the_path):
+    # mistral7b-d20-fsdp4-steady: 4 sequences a step, one a device
+    sh = NamedSharding(mesh4, P(BATCH_AXES, None, None, None))
+    args = _cell_qkv(4, sh)
+    loss = _chosen_loss(mesh4)
+    assert _compile(loss, *args).count("tpu_custom_call") == 1
+    hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), *args)
     assert hlo.count("tpu_custom_call") == 3
 
 

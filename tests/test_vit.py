@@ -51,11 +51,11 @@ def test_forward_shapes_and_loss(params):
 
 def test_noncausal_flash_kernel_matches_reference():
     """The Pallas kernel itself (interpret mode, so the real kernel code
-    runs on CPU) against full attention, non-causal, at an aligned tile
-    the ViT path would pick."""
-    from dlrover_tpu.models.vit import _divisor_block
+    runs on CPU) against full attention, non-causal, at the tiles the
+    ViT path gets: the kernel's own choice."""
     from dlrover_tpu.ops.attention import (
         flash_attention,
+        flash_tiles,
         mha_reference,
     )
 
@@ -63,10 +63,8 @@ def test_noncausal_flash_kernel_matches_reference():
     q = jax.random.normal(k1, (2, 256, 4, 32), jnp.float32)
     k = jax.random.normal(k2, (2, 256, 4, 32), jnp.float32)
     v = jax.random.normal(k3, (2, 256, 4, 32), jnp.float32)
-    blk = _divisor_block(256)
-    assert blk == 128
-    out = flash_attention(q, k, v, causal=False, block_q=blk, block_k=blk,
-                          interpret=True)
+    assert flash_tiles(256, 256, 32, 1, q.dtype)["fwd"] == (256, 256)
+    out = flash_attention(q, k, v, causal=False, interpret=True)
     ref = mha_reference(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
@@ -94,16 +92,23 @@ def test_vit_trains_sharded_with_elastic_trainer(params):
 
 
 def test_base16_patch_count_gets_valid_flash_blocks():
-    """ViT-B/16 has 196 patches; only MXU-aligned tiles that divide the
-    sequence may reach the kernel — anything else takes reference."""
-    from dlrover_tpu.models.vit import _divisor_block
+    """ViT-B/16 has 196 patches, 197 with a class token: no aligned
+    tile divides them, so the kernel's chooser takes the sequence as
+    one block (a block equal to the array's dim is always legal); only
+    a sequence too long for that has no tile and takes reference."""
+    from dlrover_tpu.ops.attention import flash_tiles
 
-    # 196's divisors are all tile-unfriendly -> 0 = reference fallback
-    assert _divisor_block(196) == 0
-    assert _divisor_block(256) == 128
-    assert _divisor_block(16) == 16
-    assert _divisor_block(97) == 0  # prime: no aligned tile
-    assert _divisor_block(192) == 96
+    def fwd(s):
+        tiles = flash_tiles(s, s, 64, 1, jnp.bfloat16)
+        return tiles and tiles["fwd"]
+
+    assert fwd(196) == (196, 196)
+    assert fwd(197) == (197, 197)  # prime
+    assert fwd(16) == (16, 16)
+    assert fwd(192) == (192, 192)
+    assert fwd(576) == (576, 576)  # ViT-L/14 at 336 px
+    assert fwd(1024) == (1024, 512)
+    assert fwd(8191) is None  # prime, and too long for one block
 
 
 def test_loss_ignores_pad_labels():
