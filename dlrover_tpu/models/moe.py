@@ -1,36 +1,60 @@
-"""Mixtral-family sparse-MoE decoder with expert parallelism, TPU-first.
+"""Sparse-MoE decoders (Mixtral, OLMoE) with expert parallelism, TPU-first.
 
 Expert parallelism is green-field relative to the reference (it is only
 checkpoint-aware of Megatron EP ranks, ``megatron_dist_ckpt.py:247``); here
-it is a real compute path:
+it is a real compute path, and there is one of it:
 
-- **dense one-hot dispatch** (GShard/Switch style): routing builds
-  ``dispatch``/``combine`` tensors and the token->expert shuffle is two
-  einsums — everything stays MXU-shaped matmuls, and with expert weights
-  sharded ``P(EP, ...)`` and tokens sharded over the batch axes the XLA
-  SPMD partitioner inserts the all-to-alls over ICI itself. No per-token
-  gather/scatter, no dynamic shapes.
-- **capacity factor** bounds per-expert work so shapes are static under
-  jit; overflow tokens fall through the residual (standard Switch
-  behavior).
-- **aux load-balance loss** (Switch Transformers eq. 4) keeps routing
-  uniform; it is accumulated through the layer scan.
+- **route** (``moe_route``): router matmul and softmax in float32 over
+  all experts, the ``k`` largest probabilities of a token and their
+  experts; renormalised over the chosen ``k`` only where the model's
+  config says so (``norm_topk_prob``: Mixtral true, OLMoE false).
+- **sorted dispatch** (``moe_dispatch``): the ``t * k`` (token, choice)
+  pairs are ordered by expert (one stable sort of int32 keys);
+  ``group_sizes (e,)`` counts each expert's pairs and the rows are
+  gathered to ``(t * k, d)``. Dropless: every pair is computed whatever
+  the load, shapes are static, cost is linear in ``t``. No tensor of
+  size ``t x e x capacity`` exists.
+- **grouped matmul** (``moe_experts``): gate, up and down are each one
+  ``lax.ragged_dot`` over the ragged groups. The TPU compiler lowers it
+  to its own grouped-matmul kernel (forward, d-lhs and d-rhs alike:
+  ``ragged-dot-none`` in a device trace), bf16 operands under f32
+  accumulation; docs/design/kernels.md has what was measured.
+- **combine** (``moe_combine``): rows go back to token order weighted by
+  the router's probabilities and the ``k`` of a token are summed.
+  Dispatch and combine are ``custom_vjp`` pairs whose backward is again a
+  gather (by the inverse permutation), never a scatter-add.
+- **under a mesh** the same path runs inside ``shard_map``: tokens stay
+  where the batch axes put them, the rows are gathered over ``ep``, each
+  rank sorts by its local experts (pairs for other ranks' experts fall
+  in a tail past ``sum(group_sizes)`` that the grouped matmul leaves
+  zero) and the result is ``psum_scatter``-ed back; ``tp`` splits the
+  expert width (a ``psum`` closes the down projection) and ``fsdp``
+  shards are gathered on the way in. Correct on the CPU meshes of
+  tests/test_moe.py; its speed is nobody's subject until a four-chip
+  MoE cell can exist (PERF.md section 7).
+- **aux load-balance loss** ``E * sum_i f_i P_i`` per layer (``f``: share
+  of (token, choice) pairs on expert ``i``; ``P``: mean router
+  probability), averaged over the layers through the layer scan.
 - attention/rope/norm reuse the Llama blocks (ring attention over sp when
-  the mesh has it).
+  the mesh has it); OLMoE adds RMSNorm over the whole q and k
+  projections (``qk_norm``) before the split into heads and rotary.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+import numpy as np
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dlrover_tpu.models import llama
+from dlrover_tpu.observability import trace
 from dlrover_tpu.ops import (
     apply_rope,
     chunked_ce_enabled,
@@ -39,6 +63,7 @@ from dlrover_tpu.ops import (
     rms_norm,
     rope_frequencies,
 )
+from dlrover_tpu.ops.grouped_matmul import grouped_matmul
 from dlrover_tpu.parallel.mesh import BATCH_AXES, EP, FSDP, SP, TP
 
 Params = Dict[str, Any]
@@ -54,7 +79,12 @@ class MoeConfig:
     ffn_dim: int = 14336
     n_experts: int = 8
     experts_per_token: int = 2
-    capacity_factor: float = 1.25
+    # what the model's config.json states: whether the chosen experts'
+    # probabilities are renormalised to sum to one (Mixtral) or used as
+    # they are (OLMoE), and whether q and k pass through an RMSNorm of
+    # their own before rotary (OLMoE)
+    norm_topk_prob: bool = True
+    qk_norm: bool = False
     router_aux_coef: float = 0.01
     max_seq_len: int = 8192
     rope_theta: float = 1000000.0
@@ -96,6 +126,16 @@ class MoeConfig:
         return MoeConfig()
 
     @staticmethod
+    def olmoe_1b_7b() -> "MoeConfig":
+        """allenai/OLMoE-1B-7B-0125-Instruct's config.json."""
+        return MoeConfig(
+            vocab_size=50304, dim=2048, n_layers=16, n_heads=16,
+            n_kv_heads=16, ffn_dim=1024, n_experts=64, experts_per_token=8,
+            norm_topk_prob=False, qk_norm=True, max_seq_len=4096,
+            rope_theta=10000.0, norm_eps=1e-5,
+        )
+
+    @staticmethod
     def tiny(**kw) -> "MoeConfig":
         base = dict(
             vocab_size=256, dim=64, n_layers=2, n_heads=4, n_kv_heads=2,
@@ -135,6 +175,9 @@ def init_params(cfg: MoeConfig, rng: jax.Array) -> Params:
         "w_up": norm_init(ks[6], (L, E, D, F), std),
         "w_down": norm_init(ks[7], (L, E, F, D), out_scale),
     }
+    if cfg.qk_norm:
+        layers["q_norm"] = jnp.ones((L, H), pd)
+        layers["k_norm"] = jnp.ones((L, KV), pd)
     return {
         "embed": norm_init(k_embed, (cfg.vocab_size, D), std),
         "layers": layers,
@@ -146,20 +189,25 @@ def init_params(cfg: MoeConfig, rng: jax.Array) -> Params:
 def param_specs(cfg: MoeConfig) -> Params:
     """Expert weights shard over EP on the expert axis; within an expert
     the ffn shards like the dense model (fsdp x tp)."""
+    layers = {
+        "attn_norm": P(None, None),
+        "wq": P(None, FSDP, TP),
+        "wk": P(None, FSDP, TP),
+        "wv": P(None, FSDP, TP),
+        "wo": P(None, TP, FSDP),
+        "mlp_norm": P(None, None),
+        "router": P(None, FSDP, None),
+        "w_gate": P(None, EP, FSDP, TP),
+        "w_up": P(None, EP, FSDP, TP),
+        "w_down": P(None, EP, TP, FSDP),
+    }
+    if cfg.qk_norm:
+        # the norm runs over the whole projection, across tp's head shards
+        layers["q_norm"] = P(None, None)
+        layers["k_norm"] = P(None, None)
     return {
         "embed": P(TP, FSDP),
-        "layers": {
-            "attn_norm": P(None, None),
-            "wq": P(None, FSDP, TP),
-            "wk": P(None, FSDP, TP),
-            "wv": P(None, FSDP, TP),
-            "wo": P(None, TP, FSDP),
-            "mlp_norm": P(None, None),
-            "router": P(None, FSDP, None),
-            "w_gate": P(None, EP, FSDP, TP),
-            "w_up": P(None, EP, FSDP, TP),
-            "w_down": P(None, EP, TP, FSDP),
-        },
+        "layers": layers,
         "final_norm": P(None),
         "lm_head": P(FSDP, TP),
     }
@@ -170,8 +218,6 @@ def abstract_params(cfg: MoeConfig) -> Params:
 
 
 def param_count(cfg: MoeConfig) -> int:
-    import math
-
     return sum(
         math.prod(l.shape) for l in jax.tree.leaves(abstract_params(cfg))
     )
@@ -185,67 +231,204 @@ def active_param_count(cfg: MoeConfig) -> int:
 
 
 # ---------------------------------------------------------------------------
-# MoE block
+# MoE block: route, sorted dispatch, grouped matmul, combine
 # ---------------------------------------------------------------------------
 
-def _capacity(tokens: int, cfg: MoeConfig) -> int:
-    cap = int(
-        cfg.capacity_factor * tokens * cfg.experts_per_token / cfg.n_experts
+def route(cfg: MoeConfig, router: jnp.ndarray, yt: jnp.ndarray):
+    """``yt (t, d)`` -> ``(probs (t, e), top_p (t, k), top_e (t, k))``,
+    all float32 / int32. The router runs in float32 at full matmul
+    precision whatever the activations' dtype: which expert is a token's
+    8th and which its 9th hangs on differences bf16 does not hold."""
+    logits = jnp.dot(
+        yt.astype(jnp.float32), router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,
     )
-    return max(cap, cfg.experts_per_token)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_e = lax.top_k(probs, cfg.experts_per_token)
+    if cfg.norm_topk_prob:
+        top_p = top_p / top_p.sum(-1, keepdims=True)
+    return probs, top_p, top_e
+
+
+def sort_pairs(top_e: jnp.ndarray, n_groups: int, first: Any = 0):
+    """Order the ``n = t * k`` (token, choice) pairs of ``top_e (t, k)``
+    by expert. Pair ``i`` is token ``i // k``'s choice ``i % k``. Returns
+    ``order (n,)``: the pair at each sorted row; ``inverse (n,)``: the
+    sorted row of each pair; ``group_sizes (n_groups,)``: how many pairs
+    chose each of the experts ``first .. first + n_groups - 1``. Pairs
+    for any other expert (another ep rank's) sort into a tail past
+    ``sum(group_sizes)``, which a grouped matmul leaves zero."""
+    flat = top_e.reshape(-1) - first
+    key = jnp.where((flat >= 0) & (flat < n_groups), flat, n_groups)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    n = order.shape[0]
+    inverse = jnp.zeros((n,), jnp.int32).at[order].set(
+        jnp.arange(n, dtype=jnp.int32), unique_indices=True
+    )
+    group_sizes = jnp.sum(
+        key[:, None] == jnp.arange(n_groups, dtype=key.dtype)[None, :],
+        axis=0, dtype=jnp.int32,
+    )
+    return order, inverse, group_sizes
+
+
+def _int_zeros(*arrays):
+    return tuple(np.zeros(a.shape, jax.dtypes.float0) for a in arrays)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def dispatch_rows(yt, order, inverse, k: int):
+    """``yt (t, d)`` -> ``(t * k, d)``: sorted row ``r`` is the token of
+    pair ``order[r]``. The backward gathers by ``inverse`` and sums a
+    token's ``k`` rows, where autodiff would scatter-add."""
+    return yt[order // k]
+
+
+def _dispatch_fwd(yt, order, inverse, k):
+    return dispatch_rows.fun(yt, order, inverse, k), (order, inverse)
+
+
+def _dispatch_bwd(k, res, g):
+    order, inverse = res
+    n, d = g.shape
+    d_yt = jnp.sum(
+        g[inverse].reshape(n // k, k, d), axis=1, dtype=jnp.float32
+    ).astype(g.dtype)
+    return (d_yt,) + _int_zeros(order, inverse)
+
+
+dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def combine_rows(rows, weights, order, inverse):
+    """``rows (t * k, d)`` in sorted order, ``weights (t, k)`` float32 ->
+    ``(t, d)``: token ``i``'s output is the sum over its ``k`` pairs of
+    weight x row. Forward and backward are gathers."""
+    t, k = weights.shape
+    picked = rows[inverse].reshape(t, k, rows.shape[1])
+    return jnp.sum(
+        picked.astype(jnp.float32) * weights[..., None], axis=1
+    ).astype(rows.dtype)
+
+
+def _combine_fwd(rows, weights, order, inverse):
+    return (combine_rows.fun(rows, weights, order, inverse),
+            (rows, weights, order, inverse))
+
+
+def _combine_bwd(res, g):
+    rows, weights, order, inverse = res
+    k = weights.shape[1]
+    # both cotangents from one gather of g, in sorted order; the
+    # weights' comes back to (t, k) as a permutation of t * k scalars
+    g_rows = g[order // k]
+    d_rows = (
+        g_rows.astype(jnp.float32) * weights.reshape(-1)[order][:, None]
+    ).astype(rows.dtype)
+    d_weights = jnp.sum(
+        rows.astype(jnp.float32) * g_rows.astype(jnp.float32), axis=1
+    )
+    return (d_rows, d_weights[inverse].reshape(weights.shape)) + _int_zeros(
+        order, inverse)
+
+
+combine_rows.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _route(cfg: MoeConfig, router, yt, token_axes=()):
+    """Route ``yt (t, d)``: ``(top_p, top_e, aux)``. ``aux`` is the
+    load-balancing loss ``E * sum_i f_i P_i`` (1 when routing is
+    uniform) over all the tokens of the step: inside a ``shard_map`` the
+    counts and the mean probabilities are reduced over ``token_axes``."""
+    e = cfg.n_experts
+    with jax.named_scope("moe_route"):
+        probs, top_p, top_e = route(cfg, router, yt)
+        counts = jnp.sum(jax.nn.one_hot(top_e, e, dtype=jnp.int32), (0, 1))
+        mean_prob = probs.mean(axis=0)
+        if token_axes:
+            counts = lax.psum(counts, token_axes)
+            mean_prob = lax.pmean(mean_prob, token_axes)
+        fraction = counts.astype(jnp.float32) / jnp.sum(counts)
+        aux = e * jnp.sum(fraction * mean_prob)
+    return top_p, top_e, aux
+
+
+def _experts(lp: Params, yt, top_p, top_e, n_local: int, first: Any = 0):
+    """The ``n_local`` experts ``first ..`` applied to the pairs of
+    ``yt (t, d)`` that chose them, weighted and summed: ``(t, d)``."""
+    k = top_e.shape[1]
+    with jax.named_scope("moe_dispatch"):
+        order, inverse, group_sizes = sort_pairs(top_e, n_local, first)
+        xs = dispatch_rows(yt, order, inverse, k)
+    with jax.named_scope("moe_experts"):
+        gate = grouped_matmul(xs, lp["w_gate"], group_sizes)
+        up = grouped_matmul(xs, lp["w_up"], group_sizes)
+        rows = grouped_matmul(
+            jax.nn.silu(gate) * up, lp["w_down"], group_sizes
+        )
+    with jax.named_scope("moe_combine"):
+        return combine_rows(rows, top_p, order, inverse)
+
+
+def _moe_tokens_sharded(cfg: MoeConfig, lp: Params, y):
+    """The body of ``moe_mlp``'s ``shard_map``: this device's tokens
+    ``y (b, s, d)``, its ``e / ep`` experts at ``1 / tp`` of their width.
+    Rows, choices and weights are gathered over ep; every rank computes
+    the pairs that chose its experts for all of the group's tokens and
+    the partial outputs are summed back to their owners. An axis of size
+    one makes its collective a no-op, so one body serves every mesh."""
+    b, s, d = y.shape
+    e_local = lp["w_gate"].shape[0]
+    yt = y.reshape(b * s, d)
+    top_p, top_e, aux = _route(cfg, lp["router"], yt, BATCH_AXES + (SP,))
+    yt, top_p, top_e = (
+        lax.all_gather(a, EP, axis=0, tiled=True) for a in (yt, top_p, top_e)
+    )
+    # each tp rank holds a slice of every expert's width, so what comes
+    # back for the rows and the weights is a partial sum over tp: say
+    # so, and autodiff closes them with a psum (dispatch and combine are
+    # custom_vjps, which get no such help by themselves)
+    yt, top_p = (lax.pcast(a, TP, to="varying") for a in (yt, top_p))
+    first = lax.axis_index(EP) * e_local
+    out = _experts(lp, yt, top_p, top_e, e_local, first)
+    out = lax.psum_scatter(out, EP, scatter_dimension=0, tiled=True)
+    return lax.psum(out, TP).reshape(b, s, d), aux
 
 
 def moe_mlp(
-    cfg: MoeConfig, lp: Params, y: jnp.ndarray
+    cfg: MoeConfig, lp: Params, y: jnp.ndarray, mesh: Optional[Mesh] = None
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(B, S, D) -> (out (B, S, D), aux_loss scalar)."""
-    dt = cfg.dtype
     b, s, d = y.shape
-    t = b * s
-    e, k = cfg.n_experts, cfg.experts_per_token
-    cap = _capacity(t, cfg)
-    yt = y.reshape(t, d)
-
-    router_logits = (yt @ lp["router"].astype(dt)).astype(jnp.float32)
-    probs = jax.nn.softmax(router_logits, axis=-1)  # (t, e)
-    top_p, top_e = lax.top_k(probs, k)  # (t, k)
-    # renormalize the chosen experts' weights (mixtral convention)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
-
-    # position of each (token, choice) in its expert's capacity buffer
-    choice_mask = jax.nn.one_hot(top_e, e, dtype=jnp.float32)  # (t, k, e)
-    # order: all k=0 choices first, then k=1 — priority to primary experts
-    flat_mask = choice_mask.transpose(1, 0, 2).reshape(k * t, e)
-    pos_in_expert = (jnp.cumsum(flat_mask, axis=0) - 1.0) * flat_mask
-    pos_in_expert = pos_in_expert.reshape(k, t, e).transpose(1, 0, 2)
-    within_cap = (pos_in_expert < cap).astype(jnp.float32) * choice_mask
-
-    # dispatch (t, e, cap) one-hot; combine carries router weights
-    # (positions where the mask is 0 one-hot to slot 0 but are zeroed by
-    # the within_cap factor in the einsums below)
-    pos_oh = jax.nn.one_hot(
-        pos_in_expert.astype(jnp.int32), cap, dtype=jnp.float32
+    _report_shapes(cfg, b * s)
+    if mesh is None or mesh.size == 1:
+        yt = y.reshape(b * s, d)
+        top_p, top_e, aux = _route(cfg, lp["router"], yt)
+        out = _experts(lp, yt, top_p, top_e, cfg.n_experts)
+        return out.reshape(b, s, d), aux
+    weights = {k: lp[k] for k in ("router", "w_gate", "w_up", "w_down")}
+    sharded = shard_map(
+        functools.partial(_moe_tokens_sharded, cfg),
+        mesh=mesh,
+        # fsdp's shards of the weights are gathered on the way in
+        in_specs=(
+            {"router": P(None, None), "w_gate": P(EP, None, TP),
+             "w_up": P(EP, None, TP), "w_down": P(EP, TP, None)},
+            P(BATCH_AXES, SP, None),
+        ),
+        out_specs=(P(BATCH_AXES, SP, None), P()),
     )
-    dispatch = jnp.einsum("tke,tkec->tec", within_cap, pos_oh)
-    combine = jnp.einsum(
-        "tke,tkec->tec", within_cap * top_p[..., None], pos_oh
-    )
+    return sharded(weights, y)
 
-    expert_in = jnp.einsum("tec,td->ecd", dispatch.astype(dt), yt)
-    gate = jax.nn.silu(
-        jnp.einsum("ecd,edf->ecf", expert_in, lp["w_gate"].astype(dt))
-    )
-    up = jnp.einsum("ecd,edf->ecf", expert_in, lp["w_up"].astype(dt))
-    expert_out = jnp.einsum(
-        "ecf,efd->ecd", gate * up, lp["w_down"].astype(dt)
-    )
-    out = jnp.einsum("tec,ecd->td", combine.astype(dt), expert_out)
 
-    # Switch aux loss: E * sum_e(fraction_dispatched_e * mean_prob_e)
-    fraction = jnp.einsum("tke->e", choice_mask) / (t * k)
-    mean_prob = probs.mean(axis=0)
-    aux = e * jnp.sum(fraction * mean_prob)
-    return out.reshape(b, s, d), aux
+def _report_shapes(cfg: MoeConfig, tokens: int):
+    """The gauges that say what the expert layer of this build is given
+    (set while the step is traced, as ``attn.block_q`` is)."""
+    k, e = cfg.experts_per_token, cfg.n_experts
+    trace.gauge("moe.experts", e)
+    trace.gauge("moe.top_k", k)
+    trace.gauge("moe.rows_per_expert", tokens * k / e)
 
 
 def _decoder_layer(cfg: MoeConfig, mesh, inv_freq, positions, lp, x):
@@ -254,8 +437,14 @@ def _decoder_layer(cfg: MoeConfig, mesh, inv_freq, positions, lp, x):
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
     y = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = (y @ lp["wq"].astype(dt)).reshape(b, s, h, hd)
-    k = (y @ lp["wk"].astype(dt)).reshape(b, s, kvh, hd)
+    q = y @ lp["wq"].astype(dt)
+    k = y @ lp["wk"].astype(dt)
+    if cfg.qk_norm:
+        # over the whole projection, before the split into heads
+        q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
+    q = q.reshape(b, s, h, hd)
+    k = k.reshape(b, s, kvh, hd)
     v = (y @ lp["wv"].astype(dt)).reshape(b, s, kvh, hd)
     q = apply_rope(q, positions, inv_freq)
     k = apply_rope(k, positions, inv_freq)
@@ -263,7 +452,7 @@ def _decoder_layer(cfg: MoeConfig, mesh, inv_freq, positions, lp, x):
     x = x + attn @ lp["wo"].astype(dt)
 
     y = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    moe_out, aux = moe_mlp(cfg, lp, y)
+    moe_out, aux = moe_mlp(cfg, lp, y, mesh)
     x = x + moe_out
 
     if mesh is not None:
@@ -273,12 +462,26 @@ def _decoder_layer(cfg: MoeConfig, mesh, inv_freq, positions, lp, x):
     return x, aux
 
 
-def validate_for_mesh(cfg: MoeConfig, mesh: Mesh, seq_len: int = 0) -> None:
+def validate_for_mesh(
+    cfg: MoeConfig, mesh: Mesh, seq_len: int = 0, batch: int = 0
+) -> None:
     llama.validate_for_mesh(cfg.as_llama(), mesh, seq_len)
-    ep = dict(mesh.shape).get(EP, 1)
+    shape = dict(mesh.shape)
+    shards = math.prod(shape.get(a, 1) for a in BATCH_AXES)
+    if batch % shards:
+        raise ValueError(
+            f"batch={batch} does not divide over the mesh's {shards} data "
+            f"shards (dp x fsdp x ep): the expert layer runs on each "
+            f"shard's own tokens under shard_map"
+        )
+    ep, tp = shape.get(EP, 1), shape.get(TP, 1)
     if cfg.n_experts % max(1, ep):
         raise ValueError(
             f"n_experts={cfg.n_experts} not divisible by mesh ep={ep}"
+        )
+    if cfg.ffn_dim % max(1, tp):
+        raise ValueError(
+            f"ffn_dim={cfg.ffn_dim} not divisible by mesh tp={tp}"
         )
 
 
@@ -293,7 +496,7 @@ def forward_hidden(
     (same split as models/llama.py forward_hidden)."""
     b, s = tokens.shape
     if mesh is not None:
-        validate_for_mesh(cfg, mesh, seq_len=s)
+        validate_for_mesh(cfg, mesh, seq_len=s, batch=b)
     x = embed_lookup(params["embed"], tokens, mesh, cfg.dtype)
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta)
@@ -324,8 +527,7 @@ def forward(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(logits (b, s, vocab) float32, aux_loss scalar)."""
     x, aux = forward_hidden(params, tokens, cfg, mesh)
-    logits = x.astype(jnp.float32) @ params["lm_head"].astype(jnp.float32)
-    return logits, aux
+    return llama.unembed(x, params["lm_head"]), aux
 
 
 def loss_fn(
@@ -334,28 +536,17 @@ def loss_fn(
     cfg: MoeConfig,
     mesh: Optional[Mesh] = None,
 ) -> jnp.ndarray:
-    """Next-token CE + router aux loss (pad tokens < 0 ignored)."""
+    """Next-token CE + router aux loss (pad tokens < 0 ignored). The
+    head runs as models/llama.py runs it: operands in the dtype they
+    arrive in, f32 accumulation, the fused-CE kernel on the TPU."""
     if chunked_ce_enabled():
         x, aux = forward_hidden(params, tokens, cfg, mesh)
-        # f32 operands, matching this model's dense unembed contract
-        # (x.astype(f32) @ lm_head.astype(f32)) — the op casts w to x's
-        # dtype, so promoting x keeps chunked-vs-dense numerics identical
-        # rather than silently moving MoE to bf16-operand logits
         nll_sum, n_valid = cross_entropy_sums(
-            x.astype(jnp.float32), params["lm_head"],
-            llama._shift_targets(tokens),
+            x, params["lm_head"], llama._shift_targets(tokens),
             chunk_size=cfg.ce_chunk_size, mesh=mesh,
         )
-        ce = nll_sum / jnp.maximum(n_valid, 1.0)
-        return ce + cfg.router_aux_coef * aux
-    logits, aux = forward(params, tokens, cfg, mesh)
-    logits = logits[:, :-1]
-    targets = tokens[:, 1:]
-    valid = (targets >= 0).astype(jnp.float32)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(
-        logits, jnp.maximum(targets, 0)[..., None], axis=-1
-    )[..., 0]
-    nll = (logz - gold) * valid
-    ce = jnp.sum(nll) / jnp.maximum(jnp.sum(valid), 1.0)
+    else:
+        logits, aux = forward(params, tokens, cfg, mesh)
+        nll_sum, n_valid = llama._ce_sums(logits, tokens)
+    ce = nll_sum / jnp.maximum(n_valid, 1.0)
     return ce + cfg.router_aux_coef * aux

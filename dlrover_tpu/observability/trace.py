@@ -15,7 +15,10 @@ Sinks
 Every span closed through ``span()`` reaches
 
 - the *counters*, always: a per-name ``(count, seconds)`` table, beside
-  the gauges ``gauge(name, value)`` sets. ``counters()`` / ``gauges()``
+  the gauges ``gauge(name, value)`` sets and the texts
+  ``provide_text(name, producer)`` offers (``step.hlo``: the live step's
+  compiled HLO, whose ``op_name`` metadata says which named scope each
+  instruction of a device trace came from). ``counters()`` / ``gauges()``
   hand out copies; a reader in the same process (the benchmark's
   per-layer metrics) needs nothing passed to it;
 - the *profiler's host plane*, whenever JAX is loaded and a profiler
@@ -197,6 +200,7 @@ class TraceRing:
         self._kind_seconds: Dict[str, float] = {}
         self._counters: Dict[str, List] = {}     # name -> [count, seconds]
         self._gauges: Dict[str, float] = {}
+        self._texts: Dict[str, Any] = {}
 
     # -- recording -----------------------------------------------------
 
@@ -283,7 +287,19 @@ class TraceRing:
         with self._lock:
             self._gauges[name] = float(value)
 
+    def provide_text(self, name: str, producer) -> None:
+        """A text too long to keep and rarely wanted (the live step's
+        compiled HLO): ``producer()`` makes it when a reader asks, the
+        last one set wins, and setting it costs nothing."""
+        with self._lock:
+            self._texts[name] = producer
+
     # -- reading -------------------------------------------------------
+
+    def text(self, name: str) -> Optional[str]:
+        with self._lock:
+            producer = self._texts.get(name)
+        return None if producer is None else producer()
 
     def events(self) -> List[Dict]:
         with self._lock:
@@ -309,6 +325,7 @@ class TraceRing:
             self._kind_seconds.clear()
             self._counters.clear()
             self._gauges.clear()
+            self._texts.clear()
 
     # -- export --------------------------------------------------------
 
@@ -371,6 +388,8 @@ span = trace_ring.span
 gauge = trace_ring.gauge
 counters = trace_ring.counters
 gauges = trace_ring.gauges
+provide_text = trace_ring.provide_text
+text = trace_ring.text
 
 
 def default_dump_dir() -> str:

@@ -228,6 +228,11 @@ def _tile_geometry(n: int, v: int, d: int, x_dtype, w_dtype,
                 f"(8, 128) tiles; {flags.FUSED_CE.name}=0 selects the "
                 f"chunked path"
             )
+    # a vocabulary the tile does not divide is padded into a copy of the
+    # whole head every step (206 MB at d=2048, V=50304): where a smaller
+    # multiple of the 128 lanes divides it, take the largest such
+    # (50304 = 131 x 384); V=32768 stays at 512
+    bv = next((t for t in range(bv, 127, -128) if v % t == 0), bv)
     return bt, bv, _round_up(n, bt), _round_up(v, bv)
 
 

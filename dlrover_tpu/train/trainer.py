@@ -1456,6 +1456,9 @@ class ElasticTrainer:
         for key in ("peak_bytes", "temp_bytes", "argument_bytes"):
             if key in measured:
                 trace.gauge(f"step.hbm_{key}", measured[key])
+        # a device trace names instructions, not the scopes they came
+        # from: the compiled text has both, for whoever reads a trace
+        trace.provide_text("step.hlo", fn.as_text)
         if info["cache"] == "warm":
             logger.info(
                 "step build: WARM (AOT cache hit, world=%d)", self.mesh.size

@@ -1,14 +1,18 @@
-"""Elastic Mixtral-class sparse-MoE pretraining with expert parallelism.
+"""Elastic sparse-MoE pretraining with expert parallelism.
 
     LOCAL_DEVICES=8 STEPS=10 \
     dlrover-tpu-run --standalone --nnodes=1 --nproc_per_node=1 \
-        --accelerator=cpu examples/moe_pretrain.py
+        --accelerator=cpu examples/moe_pretrain.py --model olmoe
 
-Experts shard over the ``ep`` mesh axis; tokens are routed with a
-capacity-bounded top-2 router and travel via all-to-all inside the
-jitted step. On TPU pods set ep to the expert count and dp=-1.
+``--model mixtral|olmoe`` picks the family's conventions (Mixtral: top-2
+of 8, renormalised; OLMoE: top-8 of 64, not renormalised, QK-norm) at a
+toy size; ``--full`` takes the published widths of the preset instead.
+Experts shard over the ``ep`` mesh axis; routing is dropless (sorted
+dispatch into a grouped matmul, models/moe.py) and the rows travel over
+ep inside the jitted step.
 """
 
+import argparse
 import os
 import sys
 
@@ -34,7 +38,24 @@ ep = 2 if n_dev % 2 == 0 else 1
 mc = MeshConfig(dp=-1, fsdp=1, ep=ep, sp=1, tp=1).resolve(n_dev)
 mesh = build_mesh(mc)
 
-cfg = moe.MoeConfig.tiny(n_heads=4, n_kv_heads=2, max_seq_len=SEQ)
+ap = argparse.ArgumentParser()
+ap.add_argument("--model", choices=("mixtral", "olmoe"), default="mixtral")
+ap.add_argument("--full", action="store_true",
+                help="the preset's published widths, not the toy size")
+args = ap.parse_args()
+
+preset = {"mixtral": moe.MoeConfig.mixtral_8x7b,
+          "olmoe": moe.MoeConfig.olmoe_1b_7b}[args.model]()
+if args.full:
+    cfg = preset
+else:
+    # the family's conventions and its experts-to-choices ratio, toy widths
+    cfg = moe.MoeConfig.tiny(
+        n_heads=4, n_kv_heads=2, max_seq_len=SEQ,
+        n_experts=min(preset.n_experts, 16),
+        experts_per_token=min(preset.experts_per_token, 4),
+        norm_topk_prob=preset.norm_topk_prob, qk_norm=preset.qk_norm,
+    )
 specs = moe.param_specs(cfg)
 params = jax.jit(
     lambda k: moe.init_params(cfg, k),

@@ -22,7 +22,7 @@ import pytest
 from jax.sharding import NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
-from dlrover_tpu.ops import attention, fused_ce
+from dlrover_tpu.ops import attention, fused_ce, grouped_matmul
 from dlrover_tpu.parallel import MeshConfig, build_mesh
 from dlrover_tpu.parallel.mesh import BATCH_AXES
 
@@ -59,6 +59,7 @@ def kernels_are_the_path(monkeypatch):
     CPU here, and would take their reference branch."""
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     monkeypatch.setattr(fused_ce, "_on_tpu", lambda: True)
+    monkeypatch.setattr(grouped_matmul, "_on_tpu", lambda: True)
     monkeypatch.setenv("DLROVER_TPU_FUSED_CE", "1")
 
 
@@ -159,13 +160,94 @@ def test_flash_other_callers_compile_at_chosen_tiles(
     assert hlo.count("tpu_custom_call") == 3
 
 
+# olmoe-1chip-steady: 16 query = 16 kv heads of 128 (group 1) at the
+# model's whole context, a shape no Mistral cell has
+def test_flash_olmoe_cell_compiles_at_chosen_tiles(
+        one_chip, kernels_are_the_path):
+    tiles = attention.flash_tiles(4096, 4096, 128, 1, jnp.bfloat16)
+    assert all(min(t) > 128 for t in tiles.values()), tiles
+    q = jax.ShapeDtypeStruct((2, 4096, 16, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    assert _compile(_chosen_loss(), q, q, q).count("tpu_custom_call") == 1
+    hlo = _compile(jax.grad(_chosen_loss(), argnums=(0, 1, 2)), q, q, q)
+    assert hlo.count("tpu_custom_call") == 3  # fwd, dq, dk/dv
+
+
+# The expert layer of that cell: 8192 tokens x 8 choices = 65536 rows
+# through 64 experts of 2048 x 1024, bf16. What the test holds is that
+# the v5e's compiler takes the grouped-matmul kernels at the tiles they
+# choose (ops/grouped_matmul.py), forward, d-lhs and d-rhs, and that no
+# tensor of (tokens, experts, capacity) is in the program.
+def _olmoe_expert_layer(sharding):
+    import dataclasses
+
+    from dlrover_tpu.models import moe
+
+    cfg = dataclasses.replace(
+        moe.MoeConfig.olmoe_1b_7b(), n_layers=1, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16)
+    layers = moe.abstract_params(cfg)["layers"]
+    lp = {
+        k: jax.ShapeDtypeStruct(layers[k].shape[1:], layers[k].dtype,
+                                sharding=sharding)
+        for k in ("router", "w_gate", "w_up", "w_down")
+    }
+    y = jax.ShapeDtypeStruct((2, 4096, cfg.dim), jnp.bfloat16,
+                             sharding=sharding)
+
+    def loss(lp, y):
+        out, aux = moe.moe_mlp(cfg, lp, y)
+        return out.astype(jnp.float32).sum() + aux
+
+    return loss, lp, y
+
+
+def _kernel_calls(hlo, name):
+    return sum("custom-call(" in line
+               and line.split(" = ")[0].strip().lstrip("%").startswith(name)
+               for line in hlo.splitlines())
+
+
+def test_olmoe_expert_layer_compiles(one_chip, kernels_are_the_path):
+    loss, lp, y = _olmoe_expert_layer(one_chip)
+    hlo = _compile(loss, lp, y)
+    assert _kernel_calls(hlo, "grouped_matmul") == 3  # gate, up, down
+    assert "ragged-dot" not in hlo
+    assert "[8192,64," not in hlo  # no (tokens, experts, ...) dispatch tensor
+
+
+def test_olmoe_expert_layer_fwd_bwd_compiles(one_chip, kernels_are_the_path):
+    loss, lp, y = _olmoe_expert_layer(one_chip)
+    hlo = _compile(jax.grad(loss, argnums=(0, 1)), lp, y)
+    # forward, d-lhs and d-rhs of each of the three products
+    assert _kernel_calls(hlo, "grouped_matmul_dlhs") == 3
+    assert _kernel_calls(hlo, "grouped_matmul_drhs") == 3
+    assert _kernel_calls(hlo, "grouped_matmul") == 9
+    assert "[8192,64," not in hlo
+
+
+def test_grouped_matmul_falls_back_where_shapes_do_not_tile(
+        one_chip, kernels_are_the_path):
+    # an expert width that is no multiple of 128: the compiler's own
+    # grouped kernel takes it (lax.ragged_dot), not a masked dense dot
+    from dlrover_tpu.ops.grouped_matmul import grouped_matmul
+
+    x = jax.ShapeDtypeStruct((4096, 2048), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((8, 2048, 1000), jnp.bfloat16,
+                             sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+    hlo = _compile(grouped_matmul, x, w, sizes)
+    assert "ragged-dot" in hlo and _kernel_calls(hlo, "grouped_matmul") == 0
+
+
 # (tokens, d, vocab): Llama-3-8B's head at seq 2048, the widths the
 # backward was refused at under the default 16 MiB of scoped VMEM
 # ("Scoped allocation with size 22.52M and limit 16.00M" in the dx
 # kernel at d=4096, 18.00M in the dw kernel at d=2048 with 8192 tokens),
 # and Llama-3-70B's d=8192, where the tiles have to shrink as well
 CE_SHAPES = [(2048, 4096, 128256), (8192, 2048, 32768),
-             (2048, 8192, 128256)]
+             (2048, 8192, 128256),
+             (8192, 2048, 50304)]   # OLMoE's head: vocabulary tile 384
 
 
 def _ce_args(n, d, v, sharding):
@@ -194,6 +276,9 @@ def test_fused_ce_fwd_bwd_compiles(one_chip, n, d, v):
         jax.grad(_fused_nll, argnums=(0, 1)), *_ce_args(n, d, v, one_chip)
     )
     assert hlo.count("tpu_custom_call") == 3  # fwd, dx, dw
+    # a vocabulary some multiple of 128 up to the tile divides is not
+    # padded to the tile into a copy of the head (50304 -> 50688 at 512)
+    assert f",{-(-v // 512) * 512}]" not in hlo or v % 512 == 0
 
 
 # Over more than one device the kernels run per shard under shard_map:

@@ -206,10 +206,26 @@ def test_tile_geometry_fits_vmem_budget(d, x_dtype, w_dtype, backward):
     assert n_pad % bt == 0 and v_pad % bv == 0
     assert n_pad >= n and v_pad >= v
     if d <= 4096 and jnp.dtype(x_dtype).itemsize == 2:
-        # MXU-sized up to Llama-3-8B's width
-        assert (bt, bv) == (fused_ce.DEFAULT_BLOCK_T,
-                            fused_ce.DEFAULT_BLOCK_V)
+        # MXU-sized up to Llama-3-8B's width; 128256 = 334 x 384, so
+        # the vocabulary tile is the one that needs no padded head
+        assert (bt, bv) == (fused_ce.DEFAULT_BLOCK_T, 384)
+        assert v_pad == v
     assert bt >= 128 and bv >= 128
+
+
+@pytest.mark.parametrize("v,want_bv,padded", [
+    (32768, 512, False),    # Mistral: 512 divides, as before
+    (50304, 384, False),    # OLMoE: 131 x 384
+    (128256, 384, False),   # Llama 3: 334 x 384
+    (50257, 512, True),     # GPT-2: no multiple of 128 divides; padded
+    (256, 256, False),      # narrower than the tile: one block
+])
+def test_vocab_tile_divides_the_vocabulary_where_one_can(v, want_bv, padded):
+    _, bv, _, v_pad = fused_ce._tile_geometry(
+        8192, v, 2048, jnp.bfloat16, jnp.bfloat16,
+        fused_ce.DEFAULT_BLOCK_T, fused_ce.DEFAULT_BLOCK_V, True,
+    )
+    assert bv == want_bv and (v_pad != v) == padded
 
 
 def test_tile_geometry_says_when_d_cannot_fit():

@@ -1,0 +1,119 @@
+"""The grouped-matmul Pallas kernels (ops/grouped_matmul.py) in
+interpreter mode against ``lax.ragged_dot`` and its autodiff: forward,
+d-lhs and d-rhs, over group sizes that straddle row tiles, leave groups
+empty, pile every row on one group, or leave a tail of rows in none."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+
+from dlrover_tpu.ops import grouped_matmul as gm
+
+M, K, N, G = 512, 128, 256, 6
+
+GROUPS = {
+    "even": [96, 80, 88, 72, 96, 80],          # straddles every tile edge
+    "aligned": [256, 0, 256, 0, 0, 0],         # whole tiles, empty groups
+    "one_takes_all": [0, 0, 512, 0, 0, 0],
+    "tail": [100, 3, 0, 61, 40, 9],            # 299 rows of no group
+    "all_tail": [0, 0, 0, 0, 0, 0],
+    "tiny_groups": [1, 2, 3, 500, 5, 1],
+}
+
+
+def _operands(dtype=jnp.float32, seed=0):
+    k1, k2 = jax.random.split(jax.random.key(seed))
+    lhs = jax.random.normal(k1, (M, K), dtype)
+    rhs = jax.random.normal(k2, (G, K, N), dtype) / np.sqrt(K)
+    return lhs, rhs
+
+
+def _reference(lhs, rhs, sizes):
+    return lax.ragged_dot(lhs, rhs, sizes, precision=lax.Precision.HIGHEST)
+
+
+@pytest.mark.parametrize("name", sorted(GROUPS))
+def test_forward_matches_ragged_dot(name):
+    lhs, rhs = _operands()
+    sizes = jnp.asarray(GROUPS[name], jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        out = gm.grouped_matmul(lhs, rhs, sizes, interpret=True)
+    want = _reference(lhs, rhs, sizes)
+    np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
+    total = int(sizes.sum())
+    assert not np.asarray(out[total:]).any()   # rows of no group: zero
+
+
+@pytest.mark.parametrize("name", sorted(GROUPS))
+def test_gradients_match_ragged_dot(name):
+    lhs, rhs = _operands(seed=1)
+    sizes = jnp.asarray(GROUPS[name], jnp.int32)
+    weight = jax.random.normal(jax.random.key(2), (M, N))
+
+    def loss(fn):
+        return lambda lhs, rhs: jnp.sum(fn(lhs, rhs, sizes) * weight)
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(loss(lambda a, b, s: gm.grouped_matmul(
+            a, b, s, interpret=True)), argnums=(0, 1))(lhs, rhs)
+        want = jax.grad(loss(_reference), argnums=(0, 1))(lhs, rhs)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-5)
+    # an empty group's block is written, and is zero
+    np.testing.assert_allclose(got[1], want[1], rtol=1e-4, atol=1e-4)
+
+
+def test_bf16_operands_accumulate_in_f32():
+    lhs, rhs = _operands(jnp.bfloat16)
+    sizes = jnp.asarray(GROUPS["even"], jnp.int32)
+    out = gm.grouped_matmul(lhs, rhs, sizes, interpret=True)
+    assert out.dtype == jnp.bfloat16
+    want = _reference(lhs.astype(jnp.float32), rhs.astype(jnp.float32), sizes)
+    # one rounding of an f32 sum to bf16: 2^-8 of the value
+    np.testing.assert_allclose(out.astype(jnp.float32), want,
+                               rtol=1e-2, atol=1e-2)
+
+
+@pytest.mark.parametrize("name", sorted(GROUPS))
+def test_visits_cover_every_row_once(name):
+    sizes = np.asarray(GROUPS[name])
+    block = 128
+    offsets, groups, tiles, num = (np.asarray(a) for a in gm._visits(
+        jnp.asarray(sizes, jnp.int32), M, block, tail=True, empty=False))
+    num = int(num[0])
+    assert len(groups) == M // block + G + 1 and 0 < num <= len(groups)
+    seen = np.zeros(M, int)
+    for grp, tile in zip(groups[:num], tiles[:num]):
+        lo = max(offsets[grp], tile * block)
+        hi = min(offsets[grp + 1], (tile + 1) * block)
+        assert hi > lo           # no visit without rows of its group
+        seen[lo:hi] += 1
+    assert (seen == 1).all()
+    assert (np.diff(tiles[:num]) >= 0).all()      # in row order
+    # the d-rhs walk: no tail, one visit for an empty group
+    _, groups, _, num = (np.asarray(a) for a in gm._visits(
+        jnp.asarray(sizes, jnp.int32), M, block, tail=False, empty=True))
+    assert set(groups[: int(num[0])]) == set(range(G))
+
+
+def test_tiles_come_from_the_shapes():
+    # the listed cell: 65536 rows between 2048 and 1024, bf16
+    assert gm.choose_tiles(65536, 2048, 1024, jnp.bfloat16) == (
+        256, 1024, 1024)
+    assert gm.choose_tiles(65536, 1024, 2048, jnp.bfloat16) == (
+        256, 1024, 1024)
+    # Mixtral's expert: 8192 rows between 4096 and 14336 (= 112 x 128)
+    bm, bn, bk = gm.choose_tiles(8192, 4096, 14336, jnp.bfloat16)
+    assert 8192 % bm == 0 and 14336 % bn == 0 and 4096 % bk == 0
+    assert bn % 128 == 0 and bk % 128 == 0
+    # widths that are no multiple of 128 go to lax.ragged_dot
+    assert gm.choose_tiles(96, 64, 128, jnp.float32) is None
+
+
+def test_off_tpu_the_path_is_ragged_dot():
+    lhs, rhs = _operands()
+    sizes = jnp.asarray(GROUPS["tail"], jnp.int32)
+    out = gm.grouped_matmul(lhs, rhs, sizes)     # CPU, no interpret
+    np.testing.assert_allclose(out, _reference(lhs, rhs, sizes),
+                               rtol=1e-5, atol=1e-5)
