@@ -1,73 +1,18 @@
-"""Headline benchmark: train-step MFU + flash-checkpoint blocking pause.
+"""What is left of the pre-chip sweep: one function.
 
-Two numbers, one JSON line:
-
-- **train_step_mfu** (headline): achieved model FLOPs/s of the full
-  ElasticTrainer step (fwd + bwd + adamw, donated buffers, remat) on the
-  largest Llama config that fits one chip in bf16, divided by the chip's
-  peak bf16 FLOPs/s. Model FLOPs use the standard 6*N*T matmul count plus
-  causal attention FLOPs — rematerialization recompute is *not* credited,
-  so the number is conservative. Baseline: Megatron-LM-class GPU training
-  efficiency for 1–2B dense models is ~40% MFU (Megatron-LM paper, tables
-  1–3; nanoGPT GPT-2 1.5B on A100 reports ~33%); the reference trains via
-  those stacks (BASELINE.json configs).
-- **flash_ckpt_blocking_save_s** (detail.ckpt): wall-clock the training
-  loop is blocked while the *freshly updated* train state is staged
-  device→shm, persistence off the training path. A real (donating) train
-  step runs between saves so every save pays the true d2h cost — saving
-  an immutable pytree repeatedly would let jax cache host literals and
-  measure ~0 (round-2 verdict, Weak #2). Reference flagship: 0.5 s pause
-  for a GPT-2-xl 1.5B (`docs/blogs/megatron_flash_checkpoint.md:105-161`
-  in the reference; BASELINE.md). vs_baseline for the ckpt number is
-  suppressed (null) when the model is < 1B params.
-
-Prints ONE json line:
-  {"metric": "train_step_mfu", "value": ..., "unit": "fraction",
-   "vs_baseline": <ours / 0.40 reference-class GPU MFU>, "detail": {...}}
+``benchmarks/tests/test_flops.py::test_flops_agree_with_bench_py`` loads
+this file by path and holds ``benchmarks/harness/flops.py`` to
+``_model_flops_per_step``, and ISSUE 29 could not edit ``benchmarks/``.
+The next ``benchmark`` issue deletes that test and this file together
+(``ROADMAP.md``, Queue 3). The yardstick is ``benchmarks/run.py``.
 """
-
-import contextlib
-import json
-import os
-import shutil
-import sys
-import tempfile
-import time
-
-BASELINE_MFU = 0.40        # Megatron-LM-class GPU MFU, 1-2B dense models
-BASELINE_CKPT_S = 0.5      # reference FCP blocking save, 1.5B model
-
-
-class NanLossError(RuntimeError):
-    """Loss went NaN — a correctness signal, never a capacity fallback."""
-
-
-def _release(jax, *trees):
-    """Delete a pytree's device arrays NOW: a retained 1.2B state
-    (params + Adam moments) would OOM the next candidate/leg and
-    silently shrink the measurement."""
-    for tree in trees:
-        for leaf in jax.tree.leaves(tree):
-            try:
-                leaf.delete()
-            except Exception:
-                pass
-
-
-def _peak_flops(device) -> float:
-    from dlrover_tpu.utils.tpu_info import peak_bf16_flops
-
-    return peak_bf16_flops(getattr(device, "device_kind", ""))
 
 
 def _model_flops_per_step(cfg, batch: int, seq: int) -> float:
     """Model FLOPs for one fwd+bwd step: 6*N_matmul*tokens + causal
     attention (QK^T and AV matmuls, fwd 2x + bwd 4x, halved for the
-    causal mask). Embedding gather and remat recompute excluded — and the
-    chunked-CE backward's re-computation of the per-chunk logits (one
-    extra 2*dim*vocab per token, ops/chunked_ce.py) is likewise remat
-    recompute, deliberately NOT credited: the lm_head term below counts
-    the fwd+bwd matmul exactly once, same as the dense path."""
+    causal mask). Embedding gather and remat recompute excluded; the
+    lm_head term counts the fwd+bwd matmul exactly once."""
     hd = cfg.head_dim
     per_layer = (
         cfg.dim * cfg.n_heads * hd            # wq
@@ -80,1559 +25,3 @@ def _model_flops_per_step(cfg, batch: int, seq: int) -> float:
     mm = 6.0 * n_mm * tokens
     attn = 6.0 * cfg.n_layers * batch * cfg.n_heads * seq * seq * hd
     return mm + attn
-
-
-def _bench_candidates(llama, jnp):
-    """Candidate sweep for one 16 GB chip in bf16, roughly fastest-guess
-    first. On TPU the bench MEASURES several fitting candidates and keeps
-    the best (r3 verdict: sweep flash tiles + relax the remat policy);
-    OOM candidates fall through."""
-    common = dict(
-        vocab_size=32768, n_heads=16, n_kv_heads=16, max_seq_len=2048,
-        rope_theta=10000.0, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
-        remat=True,
-    )
-
-    def b12(**kw):
-        return llama.LlamaConfig(
-            dim=2048, n_layers=16, ffn_dim=8192, **{**common, **kw}
-        )
-
-    def b08(**kw):
-        return llama.LlamaConfig(
-            dim=2048, n_layers=10, ffn_dim=8192, **{**common, **kw}
-        )
-
-    b035 = llama.LlamaConfig(
-        dim=1024, n_layers=12, ffn_dim=4096,
-        **{**common, "n_heads": 8, "n_kv_heads": 8})
-    # Chunked fused CE (ops/chunked_ce.py) removes the [B, T, 32768] f32
-    # logits (+ bwd residual) from peak HBM — ~0.5 GB/batch-of-4 at seq
-    # 2k — which is exactly the headroom that previously OOMed the
-    # larger-batch / longer-seq variants. Try those first; they are
-    # gated on the same DLROVER_TPU_CHUNKED_CE kill-switch as the op, so
-    # a bisection run with =0 sweeps the known-fitting dense candidates.
-    from dlrover_tpu.ops.chunked_ce import chunked_ce_enabled
-    from dlrover_tpu.ops.fused_ce import fused_ce_available, fused_ce_enabled
-
-    unlocked = []
-    # Fused-CE Pallas kernel (ops/fused_ce.py): the whole CE loss in
-    # VMEM, no per-chunk logits HBM round-trip. TPU-gated — off-TPU the
-    # dispatcher falls back to the chunked scan, so a CPU candidate
-    # named _fce would silently measure the chunked program. The _cce
-    # counterpart below pins FUSED_CE off (candidate entry 5th element:
-    # flag overrides), so fce-vs-cce is a real kernel A/B on the same
-    # config and the sweep's winner records which kernel earned the
-    # headline.
-    if fused_ce_enabled() and fused_ce_available():
-        unlocked += [
-            ("llama_1.2B_seq2k_b16_mlp_fce",
-             b12(remat_policy="mlp"),
-             16, 2048, {"FUSED_CE": True}),
-        ]
-    if chunked_ce_enabled():
-        unlocked += [
-            # doubled batch over the r5 winner: the freed logits HBM fits
-            # the extra activations under mlp-remat
-            ("llama_1.2B_seq2k_b16_mlp_cce",
-             b12(remat_policy="mlp"),
-             16, 2048, {"FUSED_CE": False}),
-            # seq 4k at the winner's batch: doubles the CREDITED causal
-            # attention flops per token; fits only without dense logits
-            ("llama_1.2B_seq4k_b4_mlp_cce",
-             b12(remat_policy="mlp", max_seq_len=4096), 4, 4096,
-             {"FUSED_CE": False}),
-        ]
-    # Ordered by expected MFU: the metric credits MODEL flops only, so
-    # recompute is pure loss — full-remat burns ~33% uncredited flops,
-    # mlp-remat ~10%, no-remat 0%. Measure the low-recompute configs
-    # first (the sweep keeps the best of the first 3 that fit).
-    return unlocked + [
-        # r5 measured best: b4 mlp-remat 105.8 / b8 full-remat 103.0
-        # model TFLOP/s — b8 mlp-remat is the untested gap between them;
-        # if its activations OOM it falls through to the known winners
-        ("llama_1.2B_seq2k_b8_mlp",
-         b12(remat_policy="mlp"),
-         8, 2048),
-        # lighter remat (save ffn gate/up)
-        ("llama_1.2B_seq2k_b4_mlp",
-         b12(remat_policy="mlp"),
-         4, 2048),
-        # same tokens as the b4/s2k winner, but seq 4k doubles the
-        # CREDITED attention flops per token (the causal S^2 term)
-        ("llama_1.2B_seq4k_b2_mlp",
-         b12(remat_policy="mlp", max_seq_len=4096), 2, 4096),
-        # no remat at all on the 0.8B: zero recompute if it fits
-        ("llama_0.8B_seq2k_b4_noremat",
-         b08(remat=False), 4, 2048),
-        # flagship size, biggest batch
-        ("llama_1.2B_seq2k_b8", b12(), 8, 2048),
-        ("llama_1.2B_seq2k_b4", b12(), 4, 2048),
-        ("llama_0.8B_seq2k_b4", b08(), 4, 2048),
-        ("llama_0.35B_seq2k_b4", b035, 4, 2048),
-    ]
-
-
-def _run_mfu(jax, jnp, llama, cfg, micro_batch: int, seq: int, steps: int):
-    """Build trainer + state, time `steps` donated train steps. Returns
-    (trainer, state, batch, mean_step_seconds, per_step_seconds).
-    Raises on OOM."""
-    from dlrover_tpu.parallel import MeshConfig, build_mesh
-    from dlrover_tpu.train.trainer import ElasticTrainer, TrainConfig
-
-    tc = TrainConfig(
-        global_batch_size=micro_batch, micro_batch_size=micro_batch,
-        warmup_steps=0, total_steps=10_000,
-    )
-
-    mc = MeshConfig(dp=1, fsdp=1, sp=1, tp=1).resolve(1)
-    mesh = build_mesh(mc, devices=jax.devices()[:1])
-    params = jax.jit(lambda k: llama.init_params(cfg, k))(jax.random.key(0))
-    jax.block_until_ready(params)
-    # mesh=None in the loss: single chip wants the plain-gather embedding
-    trainer = ElasticTrainer(
-        lambda p, t: llama.loss_fn(p, t, cfg, None), llama.param_specs(cfg),
-        mesh, mc, tc,
-    )
-    state = trainer.init_state(params)
-    batch = jax.random.randint(
-        jax.random.key(1), (1, micro_batch, seq), 0, cfg.vocab_size,
-        dtype=jnp.int32,
-    )
-
-    # compile + settle
-    for _ in range(2):
-        state, loss = trainer.step(state, batch)
-    jax.block_until_ready(loss)
-
-    t0 = time.perf_counter()
-    step_times = []
-    for _ in range(steps):
-        t_i = time.perf_counter()
-        state, loss = trainer.step(state, batch)
-        # per-step wall WITHOUT a sync: dispatch of step N blocks on
-        # donation until N-1's buffers free, so these samples carry the
-        # step-time distribution (p50/p95 in the candidate detail) —
-        # the straggler-shaped signal a mean alone hides
-        step_times.append(time.perf_counter() - t_i)
-    lval = float(jax.block_until_ready(loss))
-    dt = (time.perf_counter() - t0) / steps
-    if lval != lval:
-        raise NanLossError(f"loss is NaN after {steps} steps")
-    return trainer, state, batch, dt, step_times
-
-
-def _comm_census(trainer) -> dict:
-    """SC001 collective census of the live step program
-    (lint/shardcheck): op counts + total bytes per mesh axis, recorded
-    into the phase detail so the perf trajectory carries a comms
-    fingerprint alongside wall time — a BENCH round whose MFU moved can
-    be read against whether (and where) the program's communication
-    moved with it. Cheap by construction: ``lower_step`` is a warm
-    cache hit for a trainer that already stepped. Never fails a bench
-    phase over a fingerprint."""
-    try:
-        from dlrover_tpu.lint import shardcheck
-
-        compiled, _ = trainer.lower_step(trainer.mesh, trainer.mesh_config)
-        coords = shardcheck.MeshCoords(dict(trainer.mesh.shape))
-        return shardcheck.collective_census(compiled.as_text(), coords)
-    except Exception as e:  # telemetry only
-        return {"error": str(e)[:200]}
-
-
-def _kernel_breakdown(trainer, step_s: float) -> dict:
-    """Per-kernel attribution of the winner's measured step time
-    (profiler/kernel_ledger): walk the compiled step's optimized HLO,
-    classify every attributable site onto the census operator names
-    (attention fwd/bwd, ce fwd/bwd, matmul, comm.*, optimizer) and
-    distribute ``step_s`` by roofline weight. ``top`` is the smallest
-    prefix covering >= 80% of the step — the MFU-gap shortlist. Warm
-    (``lower_step`` cache hit) and telemetry only: never fails a bench
-    phase. Also records into the kernel-ledger singleton, so a bench
-    process serving /metrics exports dlrover_tpu_kernel_seconds_total."""
-    try:
-        from dlrover_tpu.profiler import kernel_ledger
-
-        compiled, _ = trainer.lower_step(trainer.mesh, trainer.mesh_config)
-        rows = kernel_ledger.capture_step(compiled, step_s)
-        top = kernel_ledger.top_k(rows)
-        # coverage counts the NAMED prefix only — the folded tail row
-        # is the loud remainder, not part of the >=80 % claim
-        named = [r for r in top if not r.get("tail")]
-        return {
-            "top": [
-                {"op": r["op"], "seconds": round(r["seconds"], 6),
-                 "share": round(r["share"], 4), "sites": r["sites"]}
-                for r in top
-            ],
-            "covered_share": round(sum(r["share"] for r in named), 4),
-            "ops_total": len(rows),
-        }
-    except Exception as e:  # telemetry only
-        return {"error": f"{type(e).__name__}: {str(e)[:200]}"}
-
-
-def _memory_stats(trainer) -> dict:
-    """XLA's own HBM accounting for the compiled step executable, read
-    through the ONE guarded reader every caller shares
-    (``memcheck.read_memory_analysis`` — None / partial / throwing
-    backends degrade to a warn-once instead of a crash): argument /
-    output / temp / generated-code bytes plus the derived peak. Warm by
-    construction — ``lower_step`` is a cache hit for a trainer that
-    already stepped — and telemetry only: never fails a bench phase.
-    This is what makes HBM claims (zero-1 moment sharding, the pinned
-    grad accumulator) measured numbers on CPU instead of assertions."""
-    from dlrover_tpu.lint import memcheck
-
-    try:
-        compiled, _ = trainer.lower_step(trainer.mesh, trainer.mesh_config)
-        out = memcheck.read_memory_analysis(compiled, label="bench")
-        if not out:
-            return {"error": "memory_analysis returned no known fields"}
-        return out
-    except Exception as e:  # telemetry only
-        return {"error": f"{type(e).__name__}: {str(e)[:200]}"}
-
-
-def _hbm_parity(trainer) -> dict:
-    """Predicted-vs-measured HBM peak for the winner's executable: the
-    memcheck analytic per-component model (params / moments /
-    grads_accum / activations / temp, lint/memcheck.py) against XLA's
-    own accounting of the same build. ``parity_frac`` is the bench's
-    standing evidence that the static model the planner's OOM veto
-    prices candidate worlds with tracks the real executable (the
-    contract gate holds it within 10% on the pinned program). Warm —
-    ``memcheck_payload`` re-lowers through the executable cache — and
-    telemetry only."""
-    try:
-        payload = trainer.memcheck_payload(trainer.mesh,
-                                           trainer.mesh_config)
-        out = {
-            "components": payload["components"],
-            "predicted_peak_bytes": int(payload["peak_bytes"]),
-        }
-        measured = payload.get("measured") or {}
-        peak = measured.get("peak_bytes")
-        if peak:
-            out["measured_peak_bytes"] = int(peak)
-            out["parity_frac"] = round(
-                abs(out["predicted_peak_bytes"] - peak) / peak, 4
-            )
-            out["within_10pct"] = out["parity_frac"] <= 0.10
-        return out
-    except Exception as e:  # telemetry only
-        return {"error": f"{type(e).__name__}: {str(e)[:200]}"}
-
-
-def _zero1_hbm_compare(jax, llama) -> dict:
-    """ZeRO-1's HBM saving as a measured number: lower the SAME tiny
-    model / mesh / batch with weight-update sharding off and on (AOT
-    lowering from avatars — nothing executes) and report both programs'
-    ``memory_analysis()`` plus their dp-axis collective bytes. Runs on
-    the full device world; needs >= 2 devices for a dp axis to exist.
-
-    The legs are decided by the TrainConfig knob alone: an exported
-    ``DLROVER_TPU_ZERO1`` (the documented way to turn the feature on
-    for a run) would otherwise override BOTH legs to the same program
-    and the compare would report ~zero savings under an 'off' label."""
-    from dlrover_tpu.common import flags
-
-    with flags.ZERO1.scoped(None):
-        return _zero1_hbm_compare_legs(jax, llama)
-
-
-def _zero1_hbm_compare_legs(jax, llama) -> dict:
-    import numpy as np
-
-    from dlrover_tpu.lint import shardcheck
-    from dlrover_tpu.parallel import MeshConfig, build_mesh, named_shardings
-    from dlrover_tpu.train.trainer import ElasticTrainer, TrainConfig
-
-    world = len(jax.devices())
-    if world < 2:
-        return {"skipped": "needs >= 2 devices for a dp axis"}
-    cfg = llama.LlamaConfig.tiny()
-    specs = llama.param_specs(cfg)
-    mc = MeshConfig(dp=-1).resolve(world)
-    mesh = build_mesh(mc, devices=jax.devices()[:world])
-    seq, micro = 64, 2
-    out = {"world": world, "model": "llama_tiny", "seq": seq,
-           "micro_batch": micro}
-    for leg in ("off", "on"):
-        tc = TrainConfig(
-            global_batch_size=micro * mc.data_parallel_size,
-            micro_batch_size=micro, warmup_steps=0, total_steps=100,
-            zero1=(leg == "on"),
-        )
-        tr = ElasticTrainer(
-            None, specs, mesh, mc, tc,
-            loss_factory=lambda m: (lambda p, t: llama.loss_fn(p, t, cfg, m)),
-        )
-        params = jax.device_put(
-            llama.init_params(cfg, jax.random.key(0)),
-            named_shardings(mesh, specs),
-        )
-        state = tr.init_state(params)
-        a, b = tr.step_batch_shape
-        tr.record_avatars(state, np.zeros((a, b, seq), np.int32))
-        leg_out = {"mode": tr._zero1_mode(mesh), **_memory_stats(tr)}
-        try:
-            compiled, _ = tr.lower_step(mesh, mc)
-            census = shardcheck.collective_census(
-                compiled.as_text(),
-                shardcheck.MeshCoords(dict(mesh.shape)),
-            )
-            leg_out["dp_axis_bytes"] = sum(
-                c["bytes"] for k, c in census.items()
-                if k.split("|")[1] == "dp"
-            )
-        except Exception as e:
-            leg_out["census_error"] = str(e)[:200]
-        out[leg] = leg_out
-        _release(jax, state, params)
-        del tr, state, params
-    for k in ("argument_bytes", "temp_bytes"):
-        if k in out.get("off", {}) and k in out.get("on", {}):
-            out[f"{k.replace('_bytes', '')}_saved_bytes"] = (
-                out["off"][k] - out["on"][k]
-            )
-    return out
-
-
-def _bench_multislice(jax, jnp, llama) -> dict:
-    """Multislice leg: the hierarchical DCN-aware gradient reduction
-    (ops/hier_collectives.py) vs the flat collective, on VIRTUAL slices
-    — the full CPU/TPU device world built slice-major as 2 slices
-    (``build_mesh(n_slices=2)``), so the strategy, the per-link SC001
-    census and the comm ledger's ici/dcn split all exercise for real
-    with no multislice hardware. Per leg: a few timed steps, the
-    per-link census (``dcn_bytes`` from the modeled slow-link
-    accounting, lint/shardcheck.py) and the analytic ledger's
-    bytes/step per link class; the contract test pins the hier leg's
-    ledger DCN bytes at 1/dp_in of the flat leg's.
-
-    The third leg is the overlap SCHEDULE of the hierarchical
-    reduction (``+overlap``): per-leg ``overlap_ratio`` /
-    exposed-vs-overlapped DCN bytes come from the shardcheck SC006
-    classifier over the lowered HLO, and the contract test pins the
-    overlap leg's *exposed* DCN bytes strictly below the fused-hier
-    baseline at loss parity.
-
-    The legs are decided by the TrainConfig knob alone — an exported
-    ``DLROVER_TPU_HIER_COLLECTIVES`` / ``DLROVER_TPU_OVERLAP_*`` would
-    otherwise override every leg to the same program (same reasoning
-    as the zero-1 compare)."""
-    from dlrover_tpu.common import flags
-
-    with flags.HIER_COLLECTIVES.scoped(None), flags.ZERO1.scoped(None), \
-            flags.OVERLAP_COLLECTIVES.scoped(None), \
-            flags.OVERLAP_BUCKET_MB.scoped(None):
-        return _bench_multislice_legs(jax, jnp, llama)
-
-
-def _bench_multislice_legs(jax, jnp, llama) -> dict:
-    import numpy as np
-
-    from dlrover_tpu.lint import shardcheck
-    from dlrover_tpu.parallel import MeshConfig, build_mesh, named_shardings
-    from dlrover_tpu.profiler.comm import comm_ledger
-    from dlrover_tpu.train.trainer import ElasticTrainer, TrainConfig
-
-    world = len(jax.devices())
-    n_slices = 2
-    if world < 4 or world % n_slices:
-        return {"skipped": f"needs >= 4 devices in {n_slices} even "
-                           f"slices (have {world})"}
-    cfg = llama.LlamaConfig.tiny()
-    specs = llama.param_specs(cfg)
-    mc = MeshConfig(dp=-1).resolve(world)
-    mesh = build_mesh(mc, devices=jax.devices()[:world],
-                      n_slices=n_slices)
-    seq, micro, steps = 64, 2, 3
-    # accum=3 for EVERY leg: the overlap schedule pipelines the DCN
-    # exchange across gradient-accumulation microbatches, and its
-    # peeled scan must survive to the optimized HLO (trip 2 — XLA
-    # inlines a trip-1 loop and the schedule evidence with it). Same
-    # batch for the other legs keeps the loss parity comparable.
-    accum = 3
-    out = {"world": world, "n_slices": n_slices, "model": "llama_tiny",
-           "seq": seq, "micro_batch": micro, "accum_steps": accum}
-    losses = {}
-    for leg in ("flat", "hier", "overlap"):
-        tc = TrainConfig(
-            global_batch_size=accum * micro * mc.data_parallel_size,
-            micro_batch_size=micro, warmup_steps=0, total_steps=100,
-            hier_collectives=(leg != "flat"),
-            overlap_collectives=(leg == "overlap"),
-        )
-        tr = ElasticTrainer(
-            None, specs, mesh, mc, tc,
-            loss_factory=lambda m: (lambda p, t: llama.loss_fn(p, t, cfg, m)),
-            n_slices=n_slices,
-        )
-        params = jax.device_put(
-            llama.init_params(cfg, jax.random.key(0)),
-            named_shardings(mesh, specs),
-        )
-        state = tr.init_state(params)
-        a, b = tr.step_batch_shape
-        leg_losses = []
-        for i in range(steps + 1):
-            batch = np.asarray(jax.random.randint(
-                jax.random.key(1000 + i), (a, b, seq), 0, cfg.vocab_size
-            ))
-            if i == 1:  # step 0 is the compile
-                t0 = time.perf_counter()
-            state, loss = tr.step(state, batch)
-            if i > 0:
-                leg_losses.append(float(loss))
-        jax.block_until_ready(loss)
-        step_s = (time.perf_counter() - t0) / steps
-        losses[leg] = leg_losses
-        leg_out = {
-            "mode": tr._hier_mode(mesh),
-            "step_time_s": round(step_s, 4),
-            # analytic per-link bytes/step (profiler/comm.py): what
-            # /metrics' dlrover_tpu_comm_bytes_total{link=...} exports
-            "ledger_link_bytes": comm_ledger.link_bytes(),
-        }
-        try:
-            program = tr.step_ir()
-            census = shardcheck.collective_census(
-                program.hlo, program.coords()
-            )
-            leg_out["census_dcn_bytes"] = \
-                shardcheck.census_dcn_bytes(census)
-            leg_out["census_dp_cells"] = {
-                k: c for k, c in sorted(census.items())
-                if k.split("|")[1] == "dp"
-            }
-            leg_out["contract_spec"] = tr._contract_spec(mesh)
-            # the SC006 split: trip-weighted DCN bytes the schedule
-            # hides behind compute vs. bytes exposed on the critical
-            # path — the overlap leg's selling point, measured from
-            # the same lowered HLO the census reads
-            rep = shardcheck.overlap_report(
-                program.hlo, program.coords()
-            )
-            leg_out["overlap_ratio"] = rep["overlap_ratio"]
-            leg_out["dcn_exposed_bytes"] = rep["dcn_exposed_bytes"]
-            leg_out["dcn_overlapped_bytes"] = rep["dcn_overlapped_bytes"]
-        except Exception as e:
-            leg_out["census_error"] = str(e)[:200]
-        out[leg] = leg_out
-        _release(jax, state, params)
-        del tr, state, params
-    done = [leg for leg in ("flat", "hier", "overlap") if losses.get(leg)]
-    if len(done) > 1:
-        # the fast path is the same math: per-step loss parity across
-        # the flat, fused-hier and overlap-scheduled reductions
-        out["max_loss_delta"] = max(
-            abs(x - y)
-            for i, a in enumerate(done) for b in done[i + 1:]
-            for x, y in zip(losses[a], losses[b])
-        )
-    flat_dcn = out.get("flat", {}).get(
-        "ledger_link_bytes", {}).get("dcn", 0)
-    hier_dcn = out.get("hier", {}).get(
-        "ledger_link_bytes", {}).get("dcn", 0)
-    if flat_dcn:
-        out["dcn_bytes_ratio"] = round(hier_dcn / flat_dcn, 4)
-    return out
-
-
-def _bench_ckpt_dedup(jax, jnp, llama) -> dict:
-    """Replica-deduplicated persist + tiered restore legs of the ckpt
-    phase (checkpoint/ownership.py, docs/design/checkpoint_tiers.md).
-
-    ``persist``: the full-device dp world simulated as dp virtual
-    nodes (one engine per dp slice, ``ownership_world``); each persists
-    only its owned pieces through the local-disk tier, and the
-    per-node persisted bytes are compared against the replicated
-    baseline (every node writing the whole state — what every save
-    paid before dedup). ``tiered_restore``: node 0's shm AND local
-    disk are destroyed, then a replacement engine restores through the
-    tier ladder — union of the survivors' pieces + the object tier —
-    with the tier attribution from ``last_restore_stats``."""
-    import shutil
-    import tempfile
-
-    import numpy as np
-
-    from dlrover_tpu.checkpoint.engine import CheckpointEngine
-    from dlrover_tpu.checkpoint.saver import local_tier_dir, step_dir
-    from dlrover_tpu.parallel import MeshConfig, build_mesh, named_shardings
-
-    devs = jax.devices()
-    world = len(devs)
-    if world < 2:
-        return {"skipped": "single-device world: no replicas to dedup"}
-    mc = MeshConfig(dp=-1).resolve(world)
-    mesh = build_mesh(mc, devices=devs)
-    dp = int(mc.data_parallel_size)
-    if dp < 2:
-        return {"skipped": f"dp={dp}: no replicas to dedup"}
-    cfg = llama.LlamaConfig.tiny()
-    specs = llama.param_specs(cfg)
-    params = jax.jit(
-        lambda k: llama.init_params(cfg, k),
-        out_shardings=named_shardings(mesh, specs),
-    )(jax.random.key(3))
-    state = {"params": params, "step": jnp.array(7)}
-    # replicated baseline: each node used to stage+persist every unique
-    # shard it addresses — on this dp mesh the params are replicated, so
-    # that is the full state bytes PER NODE
-    baseline = int(sum(
-        int(np.prod(l.shape, dtype=np.int64)) * l.dtype.itemsize
-        for l in jax.tree.leaves(state)
-    ))
-    if baseline > (1 << 30):
-        _release(jax, params, state)
-        return {"skipped": f"state too large for the disk legs "
-                           f"({baseline} bytes)"}
-    from dlrover_tpu.common import flags as _flags
-
-    base = tempfile.mkdtemp(prefix="dlrover_bench_dedup_")
-    obj_dir = os.path.join(base, "obj")
-    engines = []
-    out = {"dp": dp, "replicated_baseline_bytes": baseline}
-    # pin the local tier INSIDE the bench tempdir: an operator's
-    # exported DLROVER_TPU_CKPT_LOCAL_DIR points at a real node SSD
-    # shared with live jobs — this leg deletes node dirs to simulate
-    # loss, and must never do that to the real tier
-    ctx = _flags.CKPT_LOCAL_DIR.scoped(os.path.join(base, "local"))
-    ctx.__enter__()
-    try:
-        t0 = time.perf_counter()
-        for k in range(dp):
-            eng = CheckpointEngine(
-                obj_dir, job_name="bench-dedup", node_id=k, process_id=k,
-                async_staging=False, ownership_world=(k, dp),
-            )
-            engines.append(eng)
-            eng.save_to_storage(1, state)
-            eng.wait_staging()
-        persist_wall = time.perf_counter() - t0
-        per_node = []
-        for k in range(dp):
-            node_dir = step_dir(local_tier_dir(obj_dir, k), 1)
-            nbytes = 0
-            for root, _, files in os.walk(node_dir):
-                nbytes += sum(
-                    os.path.getsize(os.path.join(root, f))
-                    for f in files if f.endswith(".bin")
-                )
-            per_node.append(nbytes)
-        out.update({
-            "per_node_persisted_bytes": per_node,
-            "max_node_bytes": max(per_node),
-            "dedup_ratio": round(max(per_node) / max(baseline, 1), 4),
-            "persist_wall_s": round(persist_wall, 4),
-        })
-        # ---- tiered restore with node 0 LOST (shm + local disk) ----
-        engines[0]._shm.close(unlink=True)
-        shutil.rmtree(local_tier_dir(obj_dir, 0), ignore_errors=True)
-        eng_r = CheckpointEngine(
-            obj_dir, job_name="bench-dedup", node_id=0, process_id=0,
-            async_staging=False, ownership_world=(0, dp),
-        )
-        engines.append(eng_r)
-        t0 = time.perf_counter()
-        restored = eng_r.load(target=state)
-        tiered = {"ok": restored is not None}
-        if restored is not None:
-            jax.block_until_ready(restored[1])
-            tiered["restore_s"] = round(time.perf_counter() - t0, 4)
-            tiered.update({
-                k: v for k, v in eng_r.last_restore_stats.items()
-                if k in ("tier", "tiers_read", "pieces", "bytes")
-            })
-            tiered["bitwise_equal"] = bool(all(
-                np.array_equal(np.asarray(a), np.asarray(b))
-                for a, b in zip(
-                    jax.tree.leaves(restored[1]), jax.tree.leaves(state)
-                )
-            ))
-            _release(jax, restored[1])
-        out["tiered_restore"] = tiered
-    finally:
-        ctx.__exit__(None, None, None)
-        _release(jax, params, state)
-        for eng in engines:
-            try:
-                eng.close(unlink_shm=True)
-            except Exception:
-                pass
-        shutil.rmtree(base, ignore_errors=True)
-    return out
-
-
-KNOWN_PHASES = ("mfu", "ckpt", "resize", "multislice")
-
-
-def _requested_phases() -> set:
-    """DLROVER_BENCH_PHASES parsed ONCE as a comma-separated token set —
-    membership tests, not substring tests (a value containing the letters
-    of a phase must not enable it), and unknown names warn instead of
-    being silently dropped (a typo'd phase reads as 'skip it')."""
-    raw = os.environ.get("DLROVER_BENCH_PHASES", ",".join(KNOWN_PHASES))
-    phases = {tok.strip() for tok in raw.split(",") if tok.strip()}
-    unknown = phases - set(KNOWN_PHASES)
-    if unknown:
-        print(
-            f"DLROVER_BENCH_PHASES: unknown phase name(s) "
-            f"{sorted(unknown)} ignored (known: {', '.join(KNOWN_PHASES)})",
-            file=sys.stderr,
-        )
-    return phases & set(KNOWN_PHASES)
-
-
-def _enable_jit_cache(jax):
-    """Persistent jit cache where ``JAX_COMPILATION_CACHE_DIR`` says,
-    else at a fixed path in the checkout: the path is part of the
-    cache's key, so a directory that moves never hits."""
-    path = os.environ.setdefault(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                     ".jax_cache"),
-    )
-    try:
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        pass  # the cache is an optimization; never fail the bench over it
-
-
-def _bench_state_transfer(
-    jax, make_trainer, world: int, target: int, mc_full, devs, seq, cfg
-) -> dict:
-    """State half of the resize: live reshard (remesh(state=…)) vs the
-    shm round-trip (stage + target-placed restore) of the SAME state.
-    Returns the detail dict (state_transfer_s / compile_s /
-    shm_restore_s / shm_roundtrip_s)."""
-    import shutil
-    import tempfile
-
-    import jax.numpy as jnp  # noqa: F401  (kept local like the caller)
-
-    from dlrover_tpu.checkpoint.engine import CheckpointEngine
-    from dlrover_tpu.common.world import WorldDescriptor
-    from dlrover_tpu.parallel import config_for, mesh_for
-    from dlrover_tpu.parallel.mesh import remesh as remesh_config
-    from dlrover_tpu.train import live_reshard as lrs
-
-    lrs.resize_ledger.clear()
-    tr, state, batch = make_trainer(world)
-    st, l0 = tr.step(state, batch)
-    jax.block_until_ready(st)
-    avatars = tr._state_avatar
-    state_bytes = sum(av.size * av.dtype.itemsize
-                      for av in jax.tree.leaves(avatars))
-    # the one checked world vocabulary (common/world.py): the shm
-    # round-trip's restore targets and the live transfer resize to the
-    # SAME descriptor
-    wd_t = WorldDescriptor.from_axis_sizes(
-        remesh_config(mc_full, target).resolve(target).shape()
-    )
-    mc_t = config_for(wd_t)
-    mesh_t = mesh_for(wd_t, devices=devs)
-
-    # shm round-trip reference: what the restart path pays for state
-    tmpd = tempfile.mkdtemp(prefix="dlrover_bench_reshard_")
-    eng = CheckpointEngine(tmpd, job_name="bench-reshard")
-    try:
-        # warmup: the restart path's saves run during training with the
-        # snapshot jit + shm segment warm — don't bill its first-use
-        # compile/alloc to the round-trip
-        eng.save_to_memory(0, st)
-        eng.wait_staging()
-        t0 = time.perf_counter()
-        eng.save_to_memory(1, st)
-        eng.wait_staging()
-        shm_save_s = time.perf_counter() - t0
-        # trainer-derived targets (zero-1 aware: moment specs re-derive
-        # against the target world's dp)
-        target_tree = tr.state_targets(mesh_t)
-        t0 = time.perf_counter()
-        restored = eng.load(target=target_tree)
-        assert restored is not None
-        jax.block_until_ready(restored[1])
-        shm_restore_s = time.perf_counter() - t0
-        _release(jax, restored[1])
-    finally:
-        eng.close(unlink_shm=True)
-        shutil.rmtree(tmpd, ignore_errors=True)
-
-    # live path: the in-process remesh moves the same bytes D2D
-    new_state = tr.remesh(mesh_t, mc_t, state=st)
-    out = {"state_bytes": state_bytes}
-    if new_state is None:
-        out["live_reshard"] = "unavailable"
-        _release(jax, st, batch)
-        return out
-    a, b = tr.step_batch_shape
-    batch_t = jax.random.randint(
-        jax.random.key(5), (a, b, seq), 0, cfg.vocab_size, dtype=jnp.int32
-    )
-    next_state, loss = tr.step(new_state, batch_t)  # finalizes the event
-    jax.block_until_ready(loss)
-    ev = lrs.resize_ledger.last() or {}
-    out.update({
-        "state_transfer_s": ev.get("state_transfer_s", 0.0),
-        "compile_s": ev.get("compile_s", 0.0),
-        "transfer_path": ev.get("path", ""),
-        "shm_restore_s": round(shm_restore_s, 4),
-        "shm_roundtrip_s": round(shm_save_s + shm_restore_s, 4),
-        "live_vs_shm_ratio": round(
-            ev.get("state_transfer_s", 0.0)
-            / max(shm_save_s + shm_restore_s, 1e-9),
-            4,
-        ),
-    })
-    _release(jax, next_state, batch_t, batch, st)
-    return out
-
-
-def _bench_pp_resize(jax, jnp, llama) -> dict:
-    """Elastic pipeline leg of the resize phase: a ``dp2xpp2`` world
-    shrinks dp within each stage down to ``pp2`` — the per-stage
-    reshard path (train/live_reshard.py stage_transfer_plan), cold
-    (plain jit rebuild) vs warm (AOT + stage-aware speculative
-    neighbor compile). Alongside the downtime bracket the leg records
-    the schedule-table bubble fraction against the analytic
-    ``(p-1)/(p·m)`` and the SC008 fingerprint of the live program, so
-    the trajectory JSON carries the pipeline-efficiency claim as
-    measured numbers every round."""
-    from dlrover_tpu.common.world import WorldDescriptor
-    from dlrover_tpu.lint import shardcheck
-    from dlrover_tpu.parallel import config_for, mesh_for, named_shardings
-    from dlrover_tpu.parallel.pp_schedule import build_interleaved_tables
-    from dlrover_tpu.train import live_reshard as lrs
-    from dlrover_tpu.train import warm_compile as wc
-    from dlrover_tpu.train.trainer import ElasticTrainer, TrainConfig
-
-    devs = jax.devices()
-    world = len(devs)
-    if world < 4:
-        return {"skipped": f"needs >= 4 devices (have {world})"}
-    pp, v, m = 2, 2, 4
-    cfg = llama.LlamaConfig.tiny(
-        n_layers=4, pp_schedule="1f1b", pp_virtual_stages=v,
-        pp_microbatches=m,
-    )
-    seq = 64
-    specs = llama.param_specs(cfg, pp=pp)
-    from_wd = WorldDescriptor.from_axis_sizes({"dp": 2, "pp": pp})
-    to_wd = WorldDescriptor.from_axis_sizes({"pp": pp})
-    # one accum row of 8 feeds the schedule's own microbatching on the
-    # dp2xpp2 world; the pp2 world re-derives accum=2 with 4-row calls
-    # (m=4 microbatches of one row each) — global batch unchanged, the
-    # core elasticity invariant
-    tc = TrainConfig(global_batch_size=8, micro_batch_size=4,
-                     warmup_steps=0, total_steps=10_000)
-
-    tables = build_interleaved_tables(pp, v, m)
-    ideal_ticks = tables.T - tables.bubble_ticks
-    hints = {"schedule": cfg.pp_schedule, "microbatches": m,
-             "virtual_stages": v}
-
-    def make_trainer(wd):
-        mesh = mesh_for(wd, devices=devs)
-        tr = ElasticTrainer(
-            None, specs, mesh, config_for(wd), tc,
-            loss_factory=lambda msh: (
-                lambda p, t: llama.loss_fn(p, t, cfg, msh)
-            ),
-        )
-        tr.shardcheck_hints["pp_schedule"] = dict(hints)
-        state, batch = place(tr)
-        return tr, state, batch
-
-    def place(tr):
-        params = jax.jit(
-            lambda k: llama.init_params(cfg, k),
-            out_shardings=named_shardings(tr.mesh, specs),
-        )(jax.random.key(0))
-        state = tr.init_state(params)
-        a, b = tr.step_batch_shape
-        batch = jax.random.randint(
-            jax.random.key(1), (a, b, seq), 0, cfg.vocab_size,
-            dtype=jnp.int32,
-        )
-        return state, batch
-
-    def resize_downtime(tr):
-        tr.remesh(mesh_for(to_wd, devices=devs), config_for(to_wd))
-        state_t, batch_t = place(tr)
-        t0 = time.perf_counter()
-        new_state, loss = tr.step(state_t, batch_t)
-        jax.block_until_ready(loss)
-        dt = time.perf_counter() - t0
-        lval = float(loss)
-        _release(jax, new_state, batch_t)
-        return dt, lval
-
-    plan = lrs.stage_transfer_plan(from_wd, to_wd) or {}
-    out = {
-        "from": from_wd.spec,
-        "to": to_wd.spec,
-        "stage_plan_kind": plan.get("kind", ""),
-        "stage_map": list(map(list, to_wd.stage_map())),
-        "schedule": dict(
-            hints,
-            pp=pp,
-            ticks=tables.T,
-            bubble_ticks=tables.bubble_ticks,
-        ),
-        # the schedule-table measurement vs the paper's closed form:
-        # fill/drain ticks over ideal compute ticks
-        "bubble_fraction": round(tables.bubble_ticks / ideal_ticks, 6),
-        "bubble_fraction_analytic": round((pp - 1) / (pp * m), 6),
-    }
-    saved_kill = os.environ.get(wc.ENV_KILL_SWITCH)
-    try:
-        # ---- cold: plain jit, no caches ----
-        os.environ[wc.ENV_KILL_SWITCH] = "0"
-        jax.config.update("jax_enable_compilation_cache", False)
-        tr, state, batch = make_trainer(from_wd)
-        st1, l0 = tr.step(state, batch)
-        jax.block_until_ready(l0)
-        cold_s, cold_loss = resize_downtime(tr)
-        _release(jax, st1, batch)
-        del tr, state, batch, st1
-
-        # ---- warm: AOT + stage-aware speculative neighbor compile ----
-        os.environ[wc.ENV_KILL_SWITCH] = "1"
-        jax.config.update("jax_enable_compilation_cache", True)
-        tr2, state2, batch2 = make_trainer(from_wd)
-        st2, l1 = tr2.step(state2, batch2)
-        jax.block_until_ready(l1)
-        tr2.warm.wait_idle(timeout=600)
-        speculated = any(
-            e["world"] == to_wd.world_size
-            and any(c["source"] == "speculative" for c in e["compiles"])
-            for e in wc.compile_ledger.entries().values()
-        )
-        warm_s, warm_loss = resize_downtime(tr2)
-        out.update({
-            "cold_downtime_s": round(cold_s, 4),
-            "warm_downtime_s": round(warm_s, 4),
-            "warm_cold_ratio": round(warm_s / max(cold_s, 1e-9), 4),
-            "speculation_completed": speculated,
-            # the definitive evidence: the post-resize step landed on
-            # the speculatively-compiled executable, not a fresh build
-            "warm_hit": tr2._last_build_info.get("cache") == "warm",
-        })
-        if abs(cold_loss - warm_loss) > 1e-3:
-            out["loss_mismatch"] = [cold_loss, warm_loss]
-        # census + SC008 fingerprint of the POST-RESIZE pp program
-        out["collective_census"] = _comm_census(tr2)
-        try:
-            report = shardcheck.pp_schedule_report(tr2.step_ir())
-            if report is not None:
-                out["pp_schedule_report"] = report
-        except Exception as e:  # telemetry only
-            out["pp_schedule_report"] = {"error": str(e)[:200]}
-        _release(jax, st2, batch2)
-        del tr2, state2, batch2, st2
-    finally:
-        if saved_kill is None:
-            os.environ.pop(wc.ENV_KILL_SWITCH, None)
-        else:
-            os.environ[wc.ENV_KILL_SWITCH] = saved_kill
-        try:
-            jax.config.update("jax_enable_compilation_cache", True)
-        except Exception:
-            pass
-    return out
-
-
-def _bench_pp_multislice(jax, jnp, llama) -> dict:
-    """pp×2-slice leg: whole stages pinned one per (virtual) slice —
-    the ``pp2+2slice`` stage-map world, where the activation handoffs
-    ARE the DCN traffic. Records the per-link census + SC008
-    fingerprint of the stage-per-slice program, then resizes across
-    the slice boundary (the stage map collapses to single-slice
-    ``pp2``; stage 1's state crosses DCN) and times the cold
-    remesh→first-step downtime with the per-stage transfer plan."""
-    from dlrover_tpu.common.world import WorldDescriptor
-    from dlrover_tpu.lint import shardcheck
-    from dlrover_tpu.parallel import config_for, mesh_for, named_shardings
-    from dlrover_tpu.train import live_reshard as lrs
-    from dlrover_tpu.train.trainer import ElasticTrainer, TrainConfig
-
-    devs = jax.devices()
-    if len(devs) < 2:
-        return {"skipped": f"needs >= 2 devices (have {len(devs)})"}
-    pp, v, m = 2, 2, 4
-    cfg = llama.LlamaConfig.tiny(
-        n_layers=4, pp_schedule="1f1b", pp_virtual_stages=v,
-        pp_microbatches=m,
-    )
-    seq = 64
-    specs = llama.param_specs(cfg, pp=pp)
-    from_wd = WorldDescriptor.parse("pp2+2slice")
-    to_wd = WorldDescriptor.parse("pp2")
-    tc = TrainConfig(global_batch_size=8, micro_batch_size=8,
-                     warmup_steps=0, total_steps=10_000)
-    mesh = mesh_for(from_wd, devices=devs)
-    tr = ElasticTrainer(
-        None, specs, mesh, config_for(from_wd), tc,
-        loss_factory=lambda msh: (
-            lambda p, t: llama.loss_fn(p, t, cfg, msh)
-        ),
-        n_slices=from_wd.n_slices,
-    )
-    tr.shardcheck_hints["pp_schedule"] = {
-        "schedule": cfg.pp_schedule, "microbatches": m,
-        "virtual_stages": v,
-    }
-
-    def place():
-        params = jax.jit(
-            lambda k: llama.init_params(cfg, k),
-            out_shardings=named_shardings(tr.mesh, specs),
-        )(jax.random.key(0))
-        state = tr.init_state(params)
-        a, b = tr.step_batch_shape
-        batch = jax.random.randint(
-            jax.random.key(1), (a, b, seq), 0, cfg.vocab_size,
-            dtype=jnp.int32,
-        )
-        return state, batch
-
-    plan = lrs.stage_transfer_plan(from_wd, to_wd) or {}
-    out = {
-        "from": from_wd.spec,
-        "to": to_wd.spec,
-        "stage_map": list(map(list, from_wd.stage_map())),
-        "stage_plan_kind": plan.get("kind", ""),
-        "cross_slice_stages": [
-            i for i, st in enumerate(plan.get("stages", []))
-            if st.get("cross_slice")
-        ],
-    }
-    state, batch = place()
-    st1, l0 = tr.step(state, batch)
-    jax.block_until_ready(l0)
-    try:
-        program = tr.step_ir()
-        census = shardcheck.collective_census(
-            program.hlo, program.coords()
-        )
-        out["collective_census"] = census
-        out["census_dcn_bytes"] = shardcheck.census_dcn_bytes(census)
-        report = shardcheck.pp_schedule_report(program)
-        if report is not None:
-            out["pp_schedule_report"] = report
-    except Exception as e:  # telemetry only
-        out["census_error"] = str(e)[:200]
-    # cross-slice per-stage reshard: same two devices re-seated as one
-    # slice — stage 1's layer slab moves across the (virtual) DCN cut
-    tr.remesh(
-        mesh_for(to_wd, devices=devs), config_for(to_wd), n_slices=1
-    )
-    state_t, batch_t = place()
-    t0 = time.perf_counter()
-    new_state, loss = tr.step(state_t, batch_t)
-    jax.block_until_ready(loss)
-    out["cross_slice_resize_s"] = round(time.perf_counter() - t0, 4)
-    _release(jax, new_state, batch_t, st1, batch)
-    return out
-
-
-def _bench_resize(jax, jnp, llama, on_tpu: bool) -> dict:
-    """remesh→first-step downtime, cold vs warm (train/warm_compile.py).
-
-    Cold: kill-switch off AND the compilation cache disabled — the
-    plain jit rebuild every resize paid before this subsystem existed.
-    Warm: the real production path — AOT build, speculative neighbor
-    compile in the background, resize lands on the cached executable.
-    With ≥2 devices the resize is a genuine world change (world →
-    world/2, the speculative thread's own target); on one device it
-    degrades to a same-world remesh (still exercising the rebuild
-    path, flagged in ``mode``)."""
-    import numpy as np
-
-    from dlrover_tpu.common.world import WorldDescriptor
-    from dlrover_tpu.parallel import (
-        MeshConfig,
-        build_mesh,
-        config_for,
-        mesh_for,
-        named_shardings,
-    )
-    from dlrover_tpu.parallel.mesh import remesh as remesh_config
-    from dlrover_tpu.train import warm_compile as wc
-    from dlrover_tpu.train.trainer import ElasticTrainer, TrainConfig
-
-    devs = jax.devices()
-    world = len(devs)
-    target = world // 2 if world >= 2 else world
-    mode = "half_world" if world >= 2 else "same_world"
-    if on_tpu:
-        # small-but-real: compile long enough that the cold number
-        # means something, phase still bounded in minutes
-        cfg = llama.LlamaConfig(
-            dim=1024, n_layers=8, ffn_dim=4096, vocab_size=32768,
-            n_heads=8, n_kv_heads=8, max_seq_len=512,
-            dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, remat=True,
-        )
-        micro, seq = 2, 512
-    else:
-        cfg = llama.LlamaConfig.tiny()
-        micro, seq = 2, 64
-    specs = llama.param_specs(cfg)
-    mc_full = MeshConfig(dp=-1).resolve(world)
-    gb = micro * mc_full.data_parallel_size
-    tc = TrainConfig(global_batch_size=gb, micro_batch_size=micro,
-                     warmup_steps=0, total_steps=10_000)
-
-    def factory(mesh):
-        return lambda p, t: llama.loss_fn(p, t, cfg, mesh)
-
-    def drop(*trees):
-        # release between legs: the cold leg's state must not crowd
-        # the warm leg's trainers out of a 16 GB chip
-        _release(jax, *trees)
-
-    def place_for(tr):
-        """A resized world's state/batch (the restore itself is the ckpt
-        phase's number; downtime here isolates remesh→first-step)."""
-        mesh = tr.mesh
-        params = jax.jit(
-            lambda k: llama.init_params(cfg, k),
-            out_shardings=named_shardings(mesh, specs),
-        )(jax.random.key(0))
-        state = tr.init_state(params)
-        a, b = tr.step_batch_shape
-        batch = jax.random.randint(
-            jax.random.key(1), (a, b, seq), 0, cfg.vocab_size,
-            dtype=jnp.int32,
-        )
-        return state, batch
-
-    def descriptor_for(world_n) -> WorldDescriptor:
-        """Candidate worlds as WorldDescriptors (common/world.py): the
-        same checked type the warm-compile speculation targets and the
-        contract specs use, so the cold and warm legs resize to the
-        identical world by construction instead of re-deriving mesh
-        shape per leg."""
-        return WorldDescriptor.from_axis_sizes(
-            remesh_config(mc_full, world_n).resolve(world_n).shape()
-        )
-
-    target_world = descriptor_for(target)
-
-    def make_trainer(world_n):
-        wd = descriptor_for(world_n)
-        mesh = mesh_for(wd, devices=devs)
-        tr = ElasticTrainer(None, specs, mesh, config_for(wd), tc,
-                            loss_factory=factory)
-        state, batch = place_for(tr)
-        return tr, state, batch
-
-    def resize_downtime(tr):
-        """remesh to the target world (a no-op world change in
-        same_world mode) and time remesh→first-step."""
-        mc_t = config_for(target_world)
-        mesh_t = mesh_for(target_world, devices=devs)
-        tr.remesh(mesh_t, mc_t)
-        state_t, batch_t = place_for(tr)
-        t0 = time.perf_counter()
-        new_state, loss = tr.step(state_t, batch_t)
-        jax.block_until_ready(loss)
-        dt = time.perf_counter() - t0
-        lval = float(loss)
-        drop(new_state, batch_t)  # state_t was donated into the step
-        return dt, lval
-
-    saved_kill = os.environ.get(wc.ENV_KILL_SWITCH)
-    out = {"mode": mode, "world": world, "target_world": target,
-           "model_params": llama.param_count(cfg)}
-    try:
-        # ---- cold: today's behavior, no caches anywhere ----
-        os.environ[wc.ENV_KILL_SWITCH] = "0"
-        jax.config.update("jax_enable_compilation_cache", False)
-        tr, state, batch = make_trainer(world)
-        st1, l0 = tr.step(state, batch)  # world-A compile, not measured
-        jax.block_until_ready(l0)
-        cold_s, cold_loss = resize_downtime(tr)
-        drop(st1, batch)  # cold leg done: free its HBM for the warm leg
-        del tr, state, batch, st1
-
-        # ---- warm: AOT + speculative neighbor compile ----
-        os.environ[wc.ENV_KILL_SWITCH] = "1"
-        jax.config.update("jax_enable_compilation_cache", True)
-        tr2, state2, batch2 = make_trainer(world)
-        st2, l1 = tr2.step(state2, batch2)  # kicks the speculative thread
-        jax.block_until_ready(l1)
-        if mode == "half_world":
-            # resize lands after speculation finished (the steady-state
-            # case: memberships change minutes apart, compiles take
-            # seconds); the cache-hit rebuild is what we measure
-            tr2.warm.wait_idle(timeout=600)
-        # "completed" means the ledger actually holds a speculative
-        # compile for the target world — wait_idle alone returns True
-        # when the thread never started (no cache dir) or every target
-        # failed, which must not read as "the warm path works"
-        speculated = any(
-            e["world"] == target
-            and any(c["source"] == "speculative" for c in e["compiles"])
-            for e in wc.compile_ledger.entries().values()
-        )
-        warm_s, warm_loss = resize_downtime(tr2)
-        if abs(cold_loss - warm_loss) > 1e-3:
-            out["loss_mismatch"] = [cold_loss, warm_loss]
-        # comms fingerprint of the POST-RESIZE program (tr2 now lives on
-        # the target mesh): the half the mfu-phase census cannot see
-        out["collective_census"] = _comm_census(tr2)
-        out.update({
-            "cold_downtime_s": round(cold_s, 4),
-            "warm_downtime_s": round(warm_s, 4),
-            "warm_cold_ratio": round(warm_s / max(cold_s, 1e-9), 4),
-            "speculation_completed": speculated,
-            "compile_ledger": {
-                k: [
-                    {"source": c["source"], "seconds": c["seconds"]}
-                    for c in v["compiles"]
-                ]
-                for k, v in wc.compile_ledger.entries().items()
-            },
-        })
-        drop(st2, batch2)
-        del tr2, state2, batch2, st2
-
-        # ---- state leg: live reshard vs the shm round-trip ----
-        # (train/live_reshard.py) — the STATE half of resize downtime.
-        # Same bytes, two paths: remesh(state=…) moving the train state
-        # device-to-device, vs staging it to shm and restoring it placed
-        # for the target mesh (what every resize paid before).
-        if mode == "half_world":
-            out["state"] = _bench_state_transfer(
-                jax, make_trainer, world, target, mc_full, devs, seq, cfg
-            )
-
-        # ---- layout leg: same-world dp ↔ dp×fsdp flip ----
-        # The planner's layout_payback action (brain/planner.py
-        # layout_candidates): no membership change, the same chips
-        # re-factorized. Flip A→B pays B's first compile in the first
-        # step; flipping back B→A lands on the executable this very
-        # trainer built minutes ago — the warm in-process remesh a
-        # planner-hinted layout flip is promised. Needs an even world.
-        if target >= 2 and target % 2 == 0:
-            dp_wd = descriptor_for(target)
-            fs_wd = WorldDescriptor.from_axis_sizes(
-                {"dp": target // 2, "fsdp": 2}
-            )
-            tr3, state3, batch3 = make_trainer(target)
-            st3, l3 = tr3.step(state3, batch3)  # dp-layout compile
-            jax.block_until_ready(l3)
-            drop(st3, batch3)
-            del state3  # donated into the step above
-
-            def flip(wd):
-                tr3.remesh(mesh_for(wd, devices=devs), config_for(wd))
-                s, b = place_for(tr3)
-                t0 = time.perf_counter()
-                ns, loss = tr3.step(s, b)
-                jax.block_until_ready(loss)
-                dt = time.perf_counter() - t0
-                drop(ns, b)
-                return dt
-
-            flip_to_s = flip(fs_wd)    # pays the fsdp-layout compile
-            flip_back_s = flip(dp_wd)  # warm: the dp executable is cached
-            out["layout"] = {
-                "from": dp_wd.spec,
-                "to": fs_wd.spec,
-                "flip_to_s": round(flip_to_s, 4),
-                "flip_back_warm_s": round(flip_back_s, 4),
-                "warm_hit": bool(flip_back_s <= flip_to_s),
-            }
-            del tr3, batch3
-    finally:
-        if saved_kill is None:
-            os.environ.pop(wc.ENV_KILL_SWITCH, None)
-        else:
-            os.environ[wc.ENV_KILL_SWITCH] = saved_kill
-        try:
-            jax.config.update("jax_enable_compilation_cache", True)
-        except Exception:
-            pass
-    return out
-
-
-def main():
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from dlrover_tpu.checkpoint.engine import CheckpointEngine
-    from dlrover_tpu.models import llama
-
-    _enable_jit_cache(jax)
-
-    # the bench observes itself through the trace spine: every phase's
-    # step/compile/ckpt spans accumulate per-kind seconds, and the
-    # goodput detail block at the end decomposes the bench wall time
-    # (observability/trace.py). propagate() so subprocess legs inherit.
-    from dlrover_tpu.common import flags as _flags
-    from dlrover_tpu.observability import trace as _trace
-
-    _flags.TRACE.propagate("1")
-    bench_wall_t0 = time.perf_counter()
-
-    on_tpu = jax.default_backend() == "tpu"
-    if not on_tpu and os.environ.get("JAX_PLATFORMS") != "cpu":
-        # a measurement path that finds no chip fails; the tiny CPU run
-        # is the contract test's mode and has to be asked for
-        raise SystemExit(
-            f"bench.py: JAX's backend is {jax.default_backend()!r}, not "
-            "tpu (set JAX_PLATFORMS=cpu for the tiny CPU run)"
-        )
-    dev = jax.devices()[0]
-    peak = _peak_flops(dev)
-    timed_steps = 10
-
-    if on_tpu:
-        candidates = _bench_candidates(llama, jnp)
-    else:
-        candidates = [("tiny_cpu", llama.LlamaConfig.tiny(), 2, 128)]
-        timed_steps = 3
-
-    def _free(*trees):
-        _release(jax, *trees)
-
-    results = []  # (rate, name, cfg, micro, seq, step_s, hbm)
-    measured = 0
-    phases = _requested_phases()
-    # sweep: measure up to 3 fitting candidates and keep the fastest
-    # (model FLOPs/s, so differently-sized candidates compare fairly).
-    # When the chunked-CE-unlocked candidates lead the list they are
-    # SPECULATIVE — widen the window to 4 so the r5 measured winner
-    # (b4 mlp-remat) still gets a slot and the headline can never
-    # regress just because the new configs underperformed.
-    max_measured = 3 if on_tpu else 1
-    if any("_cce" in c[0] for c in candidates):
-        max_measured += 1
-    if any("_fce" in c[0] for c in candidates):
-        # the fused-CE kernel candidate is speculative too: widen so
-        # it cannot evict a known-fitting chunked config from the sweep
-        max_measured += 1
-    if "mfu" not in phases:
-        # phase excluded: one candidate still builds (the later phases
-        # and the JSON contract need a winner), but the multi-candidate
-        # sweep is skipped and phases_done won't claim "mfu"
-        max_measured = 1
-    from dlrover_tpu.common import flags as _flags
-
-    for entry in candidates:
-        name, cand, cand_micro, cand_seq = entry[:4]
-        # optional 5th element: env-flag overrides for this candidate
-        # (the fused-vs-chunked CE A/B); scoped so a candidate's pin
-        # never leaks into the next one's trace
-        overrides = entry[4] if len(entry) > 4 else {}
-        try:
-            with contextlib.ExitStack() as cand_stack:
-                for flag_name, value in overrides.items():
-                    cand_stack.enter_context(
-                        getattr(_flags, flag_name).scoped(value)
-                    )
-                c_trainer, c_state, c_batch, c_step_s, c_samples = _run_mfu(
-                    jax, jnp, llama, cand, cand_micro, cand_seq, timed_steps
-                )
-        except NanLossError:
-            raise
-        except Exception as e:
-            # capacity failures (HBM OOM, compile-helper death) fall through
-            # to a smaller config; anything else is a real bug and aborts —
-            # a silently downsized headline number is worse than a failure
-            msg = f"{type(e).__name__}: {e}"
-            capacity = any(
-                tok in msg
-                for tok in ("RESOURCE_EXHAUSTED", "Out of memory", "OOM",
-                            "remote_compile", "Allocat")
-            )
-            if not capacity:
-                raise
-            print(f"config {name} failed ({msg[:300]})", file=sys.stderr)
-            continue
-        rate = _model_flops_per_step(cand, cand_micro, cand_seq) / c_step_s
-        print(f"candidate {name}: {rate / 1e12:.2f} model TFLOP/s "
-              f"({c_step_s:.3f}s/step)", file=sys.stderr)
-        # per-candidate HBM fingerprint while its executable is warm
-        cand_hbm = _memory_stats(c_trainer)
-        # step-time distribution, not just the mean behind MFU: a
-        # straggler-shaped regression (fine p50, fat p95 tail) shows in
-        # the bench trajectory (observability/digest.py percentiles)
-        from dlrover_tpu.observability.digest import digest_of
-
-        cand_digest = digest_of(c_samples) or {}
-        results.append(
-            (rate, name, cand, cand_micro, cand_seq, c_step_s, cand_hbm,
-             cand_digest, overrides)
-        )
-        measured += 1
-        _free(c_state, c_batch)
-        del c_trainer, c_state, c_batch
-        if measured >= max_measured:
-            break
-
-    trainer = state = batch = None
-    step_s = float("nan")
-    model_name = "none"
-    cfg = None
-    win_digest = {}
-    if results:
-        (_, model_name, cfg, micro, seq, step_s, _, win_digest,
-         win_overrides) = max(results, key=lambda r: r[0])
-        # the winner's flag pins stay in force for the REST of the
-        # bench (never exited — the process ends with main): the ckpt
-        # phase re-steps this exact program, and a _cce
-        # winner re-traced under the ambient fused-CE default would be
-        # a different program than the one that won
-        win_stack = contextlib.ExitStack()
-        for flag_name, value in win_overrides.items():
-            win_stack.enter_context(
-                getattr(_flags, flag_name).scoped(value)
-            )
-        # rebuild the winner (its arrays were freed during the sweep) for
-        # the flash-checkpoint measurement below; untimed
-        trainer, state, batch, _, _ = _run_mfu(
-            jax, jnp, llama, cfg, micro, seq, 1
-        )
-    if cfg is None:
-        print(json.dumps({
-            "metric": "train_step_mfu", "value": 0.0, "unit": "fraction",
-            "vs_baseline": 0.0,
-            "detail": {"error": "no config ran", "backend":
-                       jax.default_backend()},
-        }))
-        return 1
-
-    nparams = llama.param_count(cfg)
-    flops = _model_flops_per_step(cfg, micro, seq)
-    achieved = flops / step_s
-    mfu = achieved / peak if peak else 0.0
-
-    detail = {
-        "backend": jax.default_backend(),
-        "device_kind": getattr(dev, "device_kind", "?"),
-        **({"warning": "unknown device_kind: peak FLOPs unknown, "
-                       "mfu reported as 0"} if peak == 0.0 else {}),
-        "peak_bf16_tflops": peak / 1e12,
-        "model": model_name,
-        "params": nparams,
-        "tokens_per_step": micro * seq,
-        "step_time_s": round(step_s, 4),
-        "step_time_p50_s": win_digest.get("p50_s"),
-        "step_time_p95_s": win_digest.get("p95_s"),
-        "achieved_tflops": round(achieved / 1e12, 2),
-        "sweep": [
-            {"name": n, "model_tflops": round(r / 1e12, 2),
-             "step_s": round(t, 4),
-             "step_p50_s": dg.get("p50_s"), "step_p95_s": dg.get("p95_s"),
-             "hbm": h,
-             **({"flags": {k: v for k, v in ov.items()}} if ov else {})}
-            for r, n, _, _, _, t, h, dg, ov in results
-        ],
-        "phases_done": ["mfu"] if "mfu" in phases else [],
-        # ckpt re-measures THIS program, so one census covers both
-        # same-program phases; resize records its own below
-        "collective_census": _comm_census(trainer),
-        # where the measured step seconds actually go, by operator —
-        # the top rows cover >= 80% of the step, so "what do we tune
-        # next for MFU" is read straight off the bench JSON
-        "kernel_breakdown": _kernel_breakdown(trainer, step_s),
-        # the flash kernels choose their own tiles from their shapes
-        # (ops/attention.py choose_tiles): there is nothing to sweep
-        "attn_tiling": {"skipped": "tiles are chosen by the kernel"},
-        # XLA's HBM accounting for the winner, plus the zero-1 on/off
-        # comparison on the same (tiny model, full-world dp mesh,
-        # batch) — the measured form of the moment-sharding and
-        # grad-accumulator claims (lower-only, nothing executes). The
-        # compare rides the resize phase's budget: it needs the same
-        # multi-device world, and skipping it with phases keeps the
-        # single-phase mfu contract run lean.
-        "hbm": {
-            "winner": _memory_stats(trainer),
-            # the static memcheck model vs XLA's accounting on the
-            # winner — the same analytic components the planner's
-            # oom_veto oracle scales to candidate worlds
-            "predicted": _hbm_parity(trainer),
-            "zero1": (
-                _zero1_hbm_compare(jax, llama)
-                if "resize" in phases
-                else {"skipped": "resize not in DLROVER_BENCH_PHASES"}
-            ),
-        },
-    }
-    result = {
-        "metric": "train_step_mfu",
-        "value": round(mfu, 4),
-        "unit": "fraction",
-        "vs_baseline": round(mfu / BASELINE_MFU, 3),
-        "detail": detail,
-    }
-
-    # ---- flash-checkpoint pause on the live (fresh) train state --------
-    # Save params from the state the trainer just produced; run a real
-    # donating train step between saves so every trial stages
-    # freshly-written device arrays (full d2h, no host-literal caching).
-    ckpt = {}
-    rate = float("nan")
-    if "ckpt" not in phases:
-        ckpt = {"skipped": "not in DLROVER_BENCH_PHASES"}
-    elif on_tpu:
-        probe = jax.jit(lambda: jnp.ones((32 << 20,), jnp.float32))()  # 128MB
-        jax.device_get(jnp.sum(probe))  # force materialization
-        t0 = time.perf_counter()
-        np.asarray(probe)
-        rate = 0.125 / max(time.perf_counter() - t0, 1e-6)  # GB/s
-        del probe
-    param_bytes = sum(
-        l.size * l.dtype.itemsize for l in jax.tree.leaves(state["params"])
-    )
-    projected = param_bytes / 2**30 / max(rate, 1e-6) if on_tpu else 0.0
-    if "skipped" in ckpt:
-        pass
-    elif on_tpu and projected > 240.0:
-        ckpt = {"skipped": f"d2h link {rate:.3f} GB/s; projected "
-                           f"{projected:.0f}s per save"}
-    else:
-        trials = 1 if projected > 60.0 else 2
-        ckpt_dir = tempfile.mkdtemp(prefix="dlrover_bench_")
-        engine = CheckpointEngine(ckpt_dir, job_name="bench", node_id=0,
-                                  process_id=0, async_staging=True)
-        try:
-            # warmup save allocates the shm segment (reference excludes its
-            # ~20 s first-export warmup too)
-            engine.save_to_memory(0, {"params": state["params"]})
-            engine.wait_staging()
-            pauses = []
-            for i in range(1, trials + 1):
-                state, loss = trainer.step(state, batch)  # fresh arrays
-                jax.device_get(loss)  # drain compute off the save timing
-                t0 = time.perf_counter()
-                engine.save_to_memory(i, {"params": state["params"]})
-                pauses.append(time.perf_counter() - t0)
-                engine.wait_staging()  # drain off-path stage (not counted)
-            blocking = min(pauses)
-            # restore-from-shm: the crash-recovery path ("order of
-            # seconds" reference claim, flash_checkpoint.md:390-393).
-            # Call the memory path DIRECTLY — engine.load silently falls
-            # back to a disk restore, which must not masquerade as shm
-            t0 = time.perf_counter()
-            restored = engine._load_from_memory(
-                target={"params": state["params"]}
-            )
-            restore_s = time.perf_counter() - t0
-            if restored is not None:
-                jax.block_until_ready(restored[1])
-                restore_s = time.perf_counter() - t0
-            ckpt = {
-                "blocking_save_s": round(blocking, 4),
-                "stage_mode": engine.last_stage_mode,
-                "vs_baseline": (round(BASELINE_CKPT_S / max(blocking, 1e-9),
-                                      3) if nparams >= 1e9 else None),
-                "restore_from_shm_s": (
-                    round(restore_s, 4) if restored is not None else None
-                ),
-                # tier + piece/byte attribution of that restore (the
-                # tiered ladder's tier-0 fast path — pinned by the
-                # bench contract alongside the dedup legs below)
-                "restore_stats": (
-                    dict(engine.last_restore_stats)
-                    if restored is not None else None
-                ),
-                "staged_gb": round(param_bytes / 2**30, 3),
-                "d2h_gbps": round(rate, 3) if on_tpu else None,
-                "trials": trials,
-            }
-            if on_tpu and rate < 1.0:
-                # TPU hosts stage at several GB/s; a sub-GB/s link means
-                # the host link is the bottleneck, not the staging design
-                ckpt["link_limited"] = True
-                ckpt["projected_at_5gbps_s"] = round(
-                    param_bytes / 2**30 / 5.0, 3
-                )
-        except Exception as e:  # keep the already-persisted MFU headline
-            ckpt = {"error": f"{type(e).__name__}: {str(e)[:300]}"}
-        finally:
-            engine.close()
-            shutil.rmtree(ckpt_dir, ignore_errors=True)
-
-    if "skipped" not in ckpt and "error" not in ckpt:
-        # dedup persist + missing-node tiered restore legs (multi-device
-        # dp worlds only; self-skips on one device / oversized states)
-        try:
-            ckpt["dedup"] = _bench_ckpt_dedup(jax, jnp, llama)
-        except Exception as e:
-            ckpt["dedup"] = {"error": f"{type(e).__name__}: {str(e)[:300]}"}
-
-    detail["ckpt"] = ckpt
-    if "skipped" not in ckpt and "error" not in ckpt:
-        detail["phases_done"].append("ckpt")
-
-    # ---- resize leg: remesh→first-step downtime, cold vs warm ----------
-    # (train/warm_compile.py). Runs last: it frees the winner's state —
-    # a 1.2B params+adam tree would crowd the resize trainers out of a
-    # 16 GB chip — and nothing after this needs it.
-    if "resize" in phases:
-        _free(state, batch)
-        del trainer, state, batch
-        try:
-            rz = _bench_resize(jax, jnp, llama, on_tpu)
-        except Exception as e:  # keep the already-persisted headline
-            rz = {"error": f"{type(e).__name__}: {str(e)[:300]}"}
-        # pipeline legs: per-stage warm reshard + bubble fraction, and
-        # the stage-per-slice world resharding across the slice cut
-        try:
-            rz["pp"] = _bench_pp_resize(jax, jnp, llama)
-        except Exception as e:
-            rz["pp"] = {"error": f"{type(e).__name__}: {str(e)[:300]}"}
-        try:
-            rz["pp_multislice"] = _bench_pp_multislice(jax, jnp, llama)
-        except Exception as e:
-            rz["pp_multislice"] = {
-                "error": f"{type(e).__name__}: {str(e)[:300]}"
-            }
-        detail["resize"] = rz
-        if "error" not in rz:
-            detail["phases_done"].append("resize")
-
-    # ---- multislice leg: hierarchical vs flat DCN collectives ----------
-    # (ops/hier_collectives.py) on 2 VIRTUAL slices over the full
-    # device world — per-link census + step time into the trajectory,
-    # so the slow-link bytes claim is a measured number every round.
-    if "multislice" in phases:
-        try:
-            ms = _bench_multislice(jax, jnp, llama)
-        except Exception as e:  # keep the already-persisted headline
-            ms = {"error": f"{type(e).__name__}: {str(e)[:300]}"}
-        detail["multislice"] = ms
-        if "error" not in ms and "skipped" not in ms:
-            detail["phases_done"].append("multislice")
-
-    # ---- goodput self-accounting: where did the bench's wall time go? --
-    # The same category vocabulary as the master's attribution
-    # (productive/compile/checkpoint/.../unattributed); the contract
-    # bound on `unattributed` lives with the chaos e2e's master-side
-    # ledger, this block keeps the single-process view in the bench
-    # trajectory. Telemetry only — never fails a bench.
-    try:
-        detail["goodput"] = _trace.attribution_from_kind_seconds(
-            _trace.trace_ring.kind_seconds(),
-            time.perf_counter() - bench_wall_t0,
-        )
-    except Exception as e:
-        detail["goodput"] = {"error": f"{type(e).__name__}: {str(e)[:200]}"}
-
-    print(json.dumps(result))
-    return 0
-
-
-if __name__ == "__main__":
-    sys.exit(main())

@@ -274,7 +274,7 @@ class CheckpointEngine:
         # Replica-deduplicated tiered checkpointing (ownership.py):
         # `dedup` overrides the DLROVER_TPU_CKPT_DEDUP kill-switch;
         # `ownership_world` = (rank, world) simulates an N-process world
-        # from one process (tests / the bench dedup leg) — the device
+        # from one process (the tests) — the device
         # list is split into `world` contiguous virtual nodes.
         self._dedup = dedup
         self._ownership_world = ownership_world
@@ -344,7 +344,7 @@ class CheckpointEngine:
         """(rank, world, device->rank) when replica-deduplicated staging
         applies, else None. Real worlds partition by process; the
         ``ownership_world`` ctor override simulates N virtual nodes from
-        one process (tests, the bench dedup leg)."""
+        one process (the tests)."""
         enabled = (
             bool(self._dedup) if self._dedup is not None
             else flags.CKPT_DEDUP.get()

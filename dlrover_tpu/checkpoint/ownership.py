@@ -45,12 +45,12 @@ Determinism argument: the pytree flatten order, each leaf's global
 every process, so every process computes the same full assignment and
 simply keeps its own slice of it.
 
-Virtual worlds: single-process test/bench runs (the 8-device CPU mesh)
+Virtual worlds: single-process test runs (the 8-device CPU mesh)
 have ``jax.process_count() == 1``, which makes the real partition
 trivial. :func:`virtual_proc_of` splits the device list into ``world``
 contiguous groups so a single process can *simulate* an N-node world —
-the bench's dedup-persist leg and the node-loss recovery tests stage
-one virtual node at a time through it.
+the node-loss recovery tests stage one virtual node at a time through
+it.
 """
 
 from __future__ import annotations
@@ -112,7 +112,7 @@ def virtual_proc_of(world: int) -> Callable[[Any], int]:
     """device -> virtual rank: the device list split into ``world``
     contiguous groups. Matches the dp-major device order ``build_mesh``
     lays out, so on a pure-dp mesh each virtual rank is one dp slice.
-    Test/bench-only — real multi-process worlds use the device's
+    Test-only — real multi-process worlds use the device's
     ``process_index``."""
     import jax
 

@@ -109,7 +109,7 @@ def local_tier_dir(ckpt_dir: str, node_id: int) -> str:
     emptyDir volume (deploy/k8s/README.md); unset, it defaults under
     the checkpoint dir — correctness-equivalent (the tier ladder still
     works), just without the locality win. The ``node-<id>`` suffix
-    keeps simulated multi-node worlds (tests, the bench dedup leg) on
+    keeps simulated multi-node worlds (the tests) on
     one host from sharing a tier they are supposed to lose
     independently."""
     root = flags.CKPT_LOCAL_DIR.get()

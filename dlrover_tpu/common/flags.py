@@ -88,9 +88,9 @@ class EnvFlag:
     def scoped(self, value: Optional[Any]):
         """Temporarily pin the flag (``None`` clears it → unset), then
         restore the previous environment on exit. For builds whose
-        value an explicit knob decides — contract lowering, bench A/B
-        legs — where an operator's exported override must not leak in
-        and silently flip which program gets built."""
+        value an explicit knob decides — contract lowering — where an
+        operator's exported override must not leak in and silently
+        flip which program gets built."""
         prev = os.environ.get(self.name)
         try:
             if value is None:
@@ -108,9 +108,9 @@ class EnvFlag:
 def env_snapshot() -> Dict[str, str]:
     """The sanctioned raw clone of the current process environment, for
     call sites that must hand a subprocess the *whole* inherited
-    environment (bench legs re-execing python, the dryrun stress
-    spawn). This is deliberately the only place the clone happens: the
-    registry is the one reader/writer of its flags, and a site that
+    environment (the dryrun stress spawn). This is deliberately the
+    only place the clone happens: the registry is the one
+    reader/writer of its flags, and a site that
     needs the full environment says so by calling here instead of
     scattering ``dict(os.environ)`` (graftlint JG003)."""
     return dict(os.environ)
@@ -185,17 +185,13 @@ LIVE_RESHARD = _define(
     "ignore the passed state so callers restore through the "
     "checkpoint round-trip exactly as before (train/live_reshard.py).",
 )
-CHUNKED_CE = _define(
-    "DLROVER_TPU_CHUNKED_CE", True, "bool",
-    "Chunked fused cross-entropy kill-switch: 0 restores the dense "
-    "[B,T,V] logits path (ops/chunked_ce.py). Read at trace time.",
-)
 FUSED_CE = _define(
     "DLROVER_TPU_FUSED_CE", True, "bool",
-    "Fused-CE Pallas kernel kill-switch: 0 restores the scan-based "
-    "chunked-CE path even on TPU (ops/fused_ce.py). Off-TPU the "
-    "dispatcher falls back to the chunked path regardless. Read at "
-    "trace time.",
+    "Fused-CE Pallas kernel switch: 0 runs the scan-based chunked-CE "
+    "path even on TPU (ops/fused_ce.py); chip_smoke.py phase R sets "
+    "it to select the reference its fused run is held to. Off-TPU "
+    "the dispatcher takes the chunked path regardless. Read at trace "
+    "time.",
 )
 COMM_METRICS_PORT = _define(
     "DLROVER_TPU_COMM_METRICS_PORT", None, "int",
@@ -284,40 +280,6 @@ MEMCHECK_BUDGET_GB = _define(
     "oracle — overrides the device-class table (tests, odd SKUs). "
     "0 = defer to DLROVER_TPU_MEMCHECK_DEVICE_CLASS; with neither "
     "set the MC002 budget gate and the speculation filter are off.",
-)
-ZERO1 = _define(
-    "DLROVER_TPU_ZERO1", "", "str",
-    "ZeRO-1 weight-update sharding across the dp axis (train/zero1.py):"
-    " overrides the TrainConfig.zero1 knob in BOTH directions — 0 "
-    "forces the replicated update, any other non-empty value forces "
-    "zero-1 on; empty defers to the config. Read at step-build time.",
-)
-HIER_COLLECTIVES = _define(
-    "DLROVER_TPU_HIER_COLLECTIVES", "", "str",
-    "Hierarchical DCN-aware collectives on multislice meshes "
-    "(ops/hier_collectives.py): overrides the "
-    "TrainConfig.hier_collectives knob in BOTH directions — 0 forces "
-    "the flat (one collective over the full dp axis) path, any other "
-    "non-empty value forces the ICI-first hierarchy on; empty defers "
-    "to the config. Read at step-build time; no-op on single-slice "
-    "meshes.",
-)
-OVERLAP_COLLECTIVES = _define(
-    "DLROVER_TPU_OVERLAP_COLLECTIVES", "", "str",
-    "Latency-hiding overlap schedule for the hierarchical DCN "
-    "gradient reduction (ops/hier_collectives.py): overrides the "
-    "TrainConfig.overlap_collectives knob in BOTH directions — 0 is "
-    "the kill-switch (the hier engine runs its fused, serialized "
-    "schedule), any other non-empty value forces the bucketed "
-    "DCN-behind-backward pipeline on; empty defers to the config. "
-    "Only effective where the hier engine itself applies.",
-)
-OVERLAP_BUCKET_MB = _define(
-    "DLROVER_TPU_OVERLAP_BUCKET_MB", None, "int",
-    "Size bound (MiB) of one gradient bucket in the overlap schedule "
-    "— each bucket becomes one fused DCN collective carried behind "
-    "the next microbatch's backward. Unset = the engine default "
-    "(ops/hier_collectives.py DEFAULT_BUCKET_MB).",
 )
 RETRACE_GUARD = _define(
     "DLROVER_TPU_RETRACE_GUARD", 0, "int",

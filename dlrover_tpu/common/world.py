@@ -1,10 +1,10 @@
 """``WorldDescriptor`` — the one checked vocabulary for "a world".
 
 ROADMAP's licensed refactor: warm-compile neighbor speculation, the
-live-reshard transfer targets, the shardcheck contract specs and the
-bench resize phase each used to re-derive "what world is this program
-for" independently — an int here, an ``axis_sizes`` dict there, a
-``+Nslice+zero1`` suffix string somewhere else — which is exactly the
+live-reshard transfer targets and the shardcheck contract specs each
+used to re-derive "what world is this program for" independently — an
+int here, an ``axis_sizes`` dict there, a ``+Nslice+zero1`` suffix
+string somewhere else — which is exactly the
 class of convention drift graftlint/shardcheck exist to replace with a
 checked invariant. This module is the single source: a candidate world
 is **mesh axes x n_slices x zero1/hier program modes**, validated at
@@ -21,7 +21,6 @@ Consumers:
   world by construction);
 - ``train/live_reshard.py`` — transfer targets are checked against the
   descriptor that also keys the executable signature;
-- ``bench.py`` resize phase — cold/warm legs resize to one descriptor;
 - ``brain/planner.py`` — candidate worlds the goodput planner scores,
   and the speculation hint it publishes on the rendezvous world poll.
 

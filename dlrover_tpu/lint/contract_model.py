@@ -90,10 +90,7 @@ def build_contract_trainer(
     the TrainConfig knob; ``n_slices > 1`` builds the mesh slice-major
     (virtual slices on CPU) and hands the trainer the slice count, so
     the hierarchical-collectives strategy and the per-link census see
-    the multislice topology. Callers that must not let exported
-    ``DLROVER_TPU_ZERO1`` / ``DLROVER_TPU_HIER_COLLECTIVES`` overrides
-    leak in wrap the build in ``flags.*.scoped(None)``
-    (``build_program`` does)."""
+    the multislice topology."""
     import jax
     import numpy as np
 
@@ -177,29 +174,14 @@ def build_contract_trainer(
 
 
 def _pinned_flags():
-    """The contract-program flag pins, as one ExitStack: the SPEC
-    decides the variant; exported DLROVER_TPU_ZERO1 /
-    DLROVER_TPU_HIER_COLLECTIVES / DLROVER_TPU_OVERLAP_* would
-    otherwise override the knobs at init_state/lower time and build
-    (or ``--fix-contracts``: RECORD) the wrong program. The CE path
-    choice is part of the contracted program too, so the kernel
-    dispatch flags pin to their defaults (fused falls back to chunked
-    off-TPU — the recorded program is the PR 1 one)."""
-    import contextlib
-
+    """The contract-program flag pin: the SPEC alone decides the
+    reduction form (TrainConfig and the mesh), but the CE path choice
+    is part of the contracted program too, so the one kernel-dispatch
+    flag pins to its default (fused falls back to chunked off-TPU —
+    the recorded program is the chunked-scan one)."""
     from dlrover_tpu.common import flags
 
-    stack = contextlib.ExitStack()
-    for flag in (
-        flags.ZERO1,
-        flags.HIER_COLLECTIVES,
-        flags.OVERLAP_COLLECTIVES,
-        flags.OVERLAP_BUCKET_MB,
-        flags.CHUNKED_CE,
-        flags.FUSED_CE,
-    ):
-        stack.enter_context(flag.scoped(None))
-    return stack
+    return flags.FUSED_CE.scoped(None)
 
 
 def build_program(

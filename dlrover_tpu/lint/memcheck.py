@@ -125,7 +125,7 @@ def _violation(rule: str, label: str, message: str) -> Violation:
 # ---------------------------------------------------------------------------
 
 #: (attr on the backend object, key we publish) — `*_bytes` names so the
-#: dict is self-describing in contracts / bench detail
+#: dict is self-describing in contracts
 _MEMORY_FIELDS: Tuple[Tuple[str, str], ...] = (
     ("argument_size_in_bytes", "argument_bytes"),
     ("output_size_in_bytes", "output_bytes"),
@@ -280,7 +280,7 @@ def analytic_components(
     With all five summed the analytic peak tracks the measured one up
     to the donation residue (outputs − aliased bytes): arguments are
     params + moments + activations, and grads + temp reassemble the
-    measured arena — that near-identity is the parity bench asserts.
+    measured arena — that near-identity is the parity the tests assert.
     """
     params = 0.0
     moments = 0.0

@@ -944,7 +944,7 @@ def overlap_report(
 
     ``overlap_ratio`` = overlapped / (overlapped + exposed), 0.0 when
     the program moves no DCN bytes at all.  ``ops`` carries the
-    per-collective verdicts for the CLI/bench surface; the contract
+    per-collective verdicts for the CLI surface; the contract
     stores only the three totals."""
     if collectives is None:
         collectives = parse_collectives(hlo_text, coords)
@@ -1095,7 +1095,7 @@ def schedule_bubble_fraction(
 
     - interleaved 1f1b: fill/drain costs ``2(p-1)`` chunk-granular
       ticks against ``2·m·v`` ideal ticks -> ``(p-1)/(m·v)``; with the
-      bench geometry ``v = p`` this is the paper's ``(p-1)/(p·m)``.
+      contract geometry ``v = p`` this is the paper's ``(p-1)/(p·m)``.
     - gpipe / non-interleaved 1f1b (``v = 1``): ``(p-1)/m`` — the
       fill/drain is microbatch-granular, so losing interleave DOUBLES
       the bubble at ``v = 2`` and the contract diff sees it.

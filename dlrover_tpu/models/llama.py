@@ -18,7 +18,7 @@ torch layout:
 - bfloat16 compute / float32 params + optimizer; the loss fuses the
   unembed matmul into a chunked cross-entropy (``ops/chunked_ce.py``) so
   full [B, T, V] f32 logits are never materialized — f32 accumulation per
-  vocab chunk instead (``DLROVER_TPU_CHUNKED_CE=0`` restores dense logits).
+  vocab chunk instead.
 
 The reference has no model code at all (it orchestrates wrapped trainers,
 SURVEY.md §2.8); configs here mirror the public Llama-3 shapes.
@@ -38,7 +38,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from dlrover_tpu.ops import (
     apply_rope,
-    chunked_ce_enabled,
     cross_entropy_sums,
     embed_lookup,
     flash_attention,
@@ -75,8 +74,7 @@ class LlamaConfig:
     attn_impl: str = "auto"   # auto | flash | reference | ring | ulysses
     # chunked fused cross-entropy (ops/chunked_ce.py): vocab columns per
     # scan step of the loss — peak loss activation is b*s*ce_chunk_size
-    # f32 instead of the dense path's b*s*vocab. Gated globally by the
-    # DLROVER_TPU_CHUNKED_CE env kill-switch (=0 restores dense logits).
+    # f32 instead of dense logits' b*s*vocab.
     ce_chunk_size: int = 2048
     # pipeline parallelism: microbatches in flight per step (0 → pp size).
     # More microbatches shrink the GPipe bubble (pp-1)/(n_micro+pp-1).
@@ -732,21 +730,16 @@ def loss_fn(
     if mesh is not None:
         _record_sp_comm(cfg, mesh, tokens.shape[0], tokens.shape[1])
         _record_tp_comm(cfg, mesh, tokens.shape[0], tokens.shape[1])
-    if chunked_ce_enabled():
-        # fused lm-head + CE: never materializes [b, s, vocab] logits.
-        # Shifted-target form (last position's target is the -1 sentinel)
-        # computes the head on the same b*s positions the dense path does,
-        # so the bench's model-FLOPs accounting is unchanged.
-        # cross_entropy_sums dispatches: Pallas fused-CE kernel on TPU
-        # (ops/fused_ce.py), the chunked scan everywhere else.
-        x = forward_hidden(params, tokens, cfg, mesh)
-        nll_sum, n_valid = cross_entropy_sums(
-            x, params["lm_head"], _shift_targets(tokens),
-            chunk_size=cfg.ce_chunk_size, mesh=mesh,
-        )
-    else:
-        logits = forward(params, tokens, cfg, mesh)
-        nll_sum, n_valid = _ce_sums(logits, tokens)
+    # fused lm-head + CE: never materializes [b, s, vocab] logits.
+    # Shifted-target form (last position's target is the -1 sentinel)
+    # computes the head on all b*s positions, as `forward` + `_ce_sums`
+    # does. cross_entropy_sums dispatches: Pallas fused-CE kernel on TPU
+    # (ops/fused_ce.py), the chunked scan everywhere else.
+    x = forward_hidden(params, tokens, cfg, mesh)
+    nll_sum, n_valid = cross_entropy_sums(
+        x, params["lm_head"], _shift_targets(tokens),
+        chunk_size=cfg.ce_chunk_size, mesh=mesh,
+    )
     return nll_sum / jnp.maximum(n_valid, 1.0)
 
 
@@ -768,9 +761,7 @@ def _pp_loss(
     _record_pp_comm(cfg, mesh, tokens.shape[0], tokens.shape[1])
     from dlrover_tpu.ops import fused_ce_enabled
 
-    return _jitted_pp_loss(
-        cfg, mesh, chunked_ce_enabled(), fused_ce_enabled()
-    )(params, tokens)
+    return _jitted_pp_loss(cfg, mesh, fused_ce_enabled())(params, tokens)
 
 
 def _record_pp_comm(cfg: LlamaConfig, mesh: Mesh, b: int, s: int):
@@ -833,14 +824,12 @@ def _record_pp_comm(cfg: LlamaConfig, mesh: Mesh, b: int, s: int):
 
 
 @functools.lru_cache(maxsize=32)
-def _jitted_pp_loss(cfg: LlamaConfig, mesh: Mesh, chunked_ce: bool,
-                    fused_ce: bool = True):
-    # ``chunked_ce``/``fused_ce`` are part of the cache KEY only:
-    # _head_loss_sums re-reads the env vars at trace time (which happens
-    # on the first call for this key, when the env still matches), so
-    # toggling DLROVER_TPU_CHUNKED_CE / DLROVER_TPU_FUSED_CE between
-    # calls retraces instead of silently reusing the other path's cached
-    # program.
+def _jitted_pp_loss(cfg: LlamaConfig, mesh: Mesh, fused_ce: bool = True):
+    # ``fused_ce`` is part of the cache KEY only: cross_entropy_sums
+    # re-reads the env var at trace time (which happens on the first
+    # call for this key, when the env still matches), so toggling
+    # DLROVER_TPU_FUSED_CE between calls retraces instead of silently
+    # reusing the other path's cached program.
     return jax.jit(
         functools.partial(_pp_loss_impl, cfg=cfg, mesh=mesh)
     )
@@ -992,11 +981,7 @@ def _head_loss_sums(cfg: LlamaConfig, out, final_norm, lm_head, tgt):
     inside the pp shard_map manual regions (and under the jax.vjp /
     value_and_grad the 1f1b schedule takes through this function)."""
     h = rms_norm(out, final_norm, cfg.norm_eps)
-    if chunked_ce_enabled():
-        return cross_entropy_sums(
-            h, lm_head, tgt, chunk_size=cfg.ce_chunk_size
-        )
-    return _ce_sums_shifted(unembed(h, lm_head), tgt)
+    return cross_entropy_sums(h, lm_head, tgt, chunk_size=cfg.ce_chunk_size)
 
 
 def _pp_gpipe(

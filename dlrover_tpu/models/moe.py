@@ -57,7 +57,6 @@ from dlrover_tpu.models import llama
 from dlrover_tpu.observability import trace
 from dlrover_tpu.ops import (
     apply_rope,
-    chunked_ce_enabled,
     cross_entropy_sums,
     embed_lookup,
     rms_norm,
@@ -94,7 +93,7 @@ class MoeConfig:
     remat: bool = True
     attn_impl: str = "auto"
     # chunked fused cross-entropy (ops/chunked_ce.py): vocab columns per
-    # loss scan step; DLROVER_TPU_CHUNKED_CE=0 restores dense logits
+    # loss scan step
     ce_chunk_size: int = 2048
 
     @property
@@ -539,14 +538,10 @@ def loss_fn(
     """Next-token CE + router aux loss (pad tokens < 0 ignored). The
     head runs as models/llama.py runs it: operands in the dtype they
     arrive in, f32 accumulation, the fused-CE kernel on the TPU."""
-    if chunked_ce_enabled():
-        x, aux = forward_hidden(params, tokens, cfg, mesh)
-        nll_sum, n_valid = cross_entropy_sums(
-            x, params["lm_head"], llama._shift_targets(tokens),
-            chunk_size=cfg.ce_chunk_size, mesh=mesh,
-        )
-    else:
-        logits, aux = forward(params, tokens, cfg, mesh)
-        nll_sum, n_valid = llama._ce_sums(logits, tokens)
+    x, aux = forward_hidden(params, tokens, cfg, mesh)
+    nll_sum, n_valid = cross_entropy_sums(
+        x, params["lm_head"], llama._shift_targets(tokens),
+        chunk_size=cfg.ce_chunk_size, mesh=mesh,
+    )
     ce = nll_sum / jnp.maximum(n_valid, 1.0)
     return ce + cfg.router_aux_coef * aux

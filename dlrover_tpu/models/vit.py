@@ -28,7 +28,6 @@ from dlrover_tpu.ops.attention import (
     flash_tiles,
     mha_reference,
 )
-from dlrover_tpu.ops.chunked_ce import chunked_ce_enabled
 from dlrover_tpu.ops.fused_ce import cross_entropy_sums
 from dlrover_tpu.ops.norms import rms_norm
 from dlrover_tpu.parallel.mesh import BATCH_AXES, FSDP, TP
@@ -220,20 +219,12 @@ def loss_fn(params: Params, batch, cfg: ViTConfig, mesh=None) -> jnp.ndarray:
     < 0 are the pad sentinel (``pad_batch_to`` after an elastic resize)
     and contribute nothing."""
     images, labels = batch
-    if chunked_ce_enabled():
-        # same fused head-matmul + masked-CE path as the LM families —
-        # n_classes is small so one chunk covers it (the op clips), but
-        # sharing the op keeps the CE semantics (pad < 0, f32 MXU
-        # accumulation) defined in exactly one place
-        pooled = forward_pooled(params, images, cfg, mesh)
-        nll_sum, n_valid = cross_entropy_sums(
-            pooled, params["head"], labels, mesh=mesh
-        )
-        return nll_sum / jnp.maximum(n_valid, 1.0)
-    logits = forward(params, images, cfg, mesh)
-    logz = jax.scipy.special.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(
-        logits, jnp.maximum(labels, 0)[..., None], axis=-1
-    )[..., 0]
-    mask = (labels >= 0).astype(jnp.float32)
-    return jnp.sum((logz - gold) * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+    # same fused head-matmul + masked-CE path as the LM families —
+    # n_classes is small so one chunk covers it (the op clips), but
+    # sharing the op keeps the CE semantics (pad < 0, f32 MXU
+    # accumulation) defined in exactly one place
+    pooled = forward_pooled(params, images, cfg, mesh)
+    nll_sum, n_valid = cross_entropy_sums(
+        pooled, params["head"], labels, mesh=mesh
+    )
+    return nll_sum / jnp.maximum(n_valid, 1.0)

@@ -496,7 +496,7 @@ def attribution_from_kind_seconds(
     kind_seconds: Dict[str, float], wall_s: float
 ) -> Dict:
     """Single-process wall-time decomposition from the ring's per-kind
-    totals (bench's ``goodput`` detail block). Categories sum to
+    totals. Categories sum to
     ``wall_s`` by construction: ``unattributed`` is the residual, and
     when measured categories overlap past the wall (nested spans) they
     are scaled down proportionally rather than summing past it."""

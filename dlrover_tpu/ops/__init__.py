@@ -6,10 +6,7 @@ Green-field relative to the reference, which owns no kernels (SURVEY.md
 """
 
 from dlrover_tpu.ops.attention import flash_attention, mha_reference  # noqa: F401
-from dlrover_tpu.ops.chunked_ce import (  # noqa: F401
-    chunked_ce_enabled,
-    chunked_cross_entropy,
-)
+from dlrover_tpu.ops.chunked_ce import chunked_cross_entropy  # noqa: F401
 from dlrover_tpu.ops.fused_ce import (  # noqa: F401
     cross_entropy_sums,
     fused_ce_available,

@@ -1,10 +1,9 @@
 """Chunked fused cross-entropy: unembed matmul + softmax-CE without the
 [B, T, V] logits materialization.
 
-The dense loss path computes full f32 logits ``x @ w_unembed`` of shape
-``[B, T, V]`` before logsumexp — for the bench flagship (1.2B, seq 2k,
-V=32768) that is ~0.5 GB of f32 activations (plus the bwd residuals) on a
-16 GB chip, capping batch size and flash-attention tile choices. This op
+A dense loss computes full f32 logits ``x @ w_unembed`` of shape
+``[B, T, V]`` before logsumexp — at 8192 tokens and V=32768 that is 1 GiB
+of f32 activations (plus the bwd residuals) on a 16 GB chip. This op
 fuses the lm-head matmul into the loss and iterates VOCAB chunks under
 ``lax.scan``:
 
@@ -19,8 +18,8 @@ fuses the lm-head matmul into the loss and iterates VOCAB chunks under
 Peak activation memory drops from ``O(B*T*V)`` to ``O(B*T*chunk)`` in both
 fwd and bwd: the custom VJP recomputes each chunk's logits in the backward
 (one extra unembed-matmul pass, the same trade rematerialization makes for
-the decoder layers — and like remat, the recompute is NOT credited in the
-bench's model-FLOPs accounting) and writes the ``dW`` chunks disjointly,
+the decoder layers — and like remat, the recompute is NOT credited in
+``mfu``'s model-FLOPs accounting) and writes the ``dW`` chunks disjointly,
 so no ``[tokens, V]`` intermediate ever exists in either direction.
 Megatron-LM's fused vocab-parallel CE is the reference design.
 
@@ -39,21 +38,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from dlrover_tpu.common import flags
-
 #: Default vocab-chunk width: 16 MXU lanes of 128 — wide enough that the
 #: per-chunk [tokens, chunk] matmul stays MXU-bound, narrow enough that
 #: the largest live loss activation is tokens*2048*4 bytes, not tokens*V*4.
 DEFAULT_CHUNK_SIZE = 2048
-
-
-def chunked_ce_enabled() -> bool:
-    """Env kill-switch (bisection aid): ``DLROVER_TPU_CHUNKED_CE=0``
-    restores the dense [B, T, V] logits path everywhere the models route
-    through this op. Read at trace time — set it before the first loss
-    call / trainer step of the process (the jitted step caches the trace).
-    """
-    return flags.CHUNKED_CE.get()
 
 
 def chunked_cross_entropy(

@@ -89,8 +89,7 @@ def fused_ce_enabled() -> bool:
 
 def fused_ce_available(interpret: bool = False) -> bool:
     """True where the Pallas kernel is the path: the TPU backend, or
-    interpreter mode. The dispatcher below and the bench sweep both key
-    off this."""
+    interpreter mode. The dispatcher below keys off this."""
     return interpret or _on_tpu()
 
 

@@ -42,16 +42,14 @@ zero-1 shard, bitwise contiguous (tests pin parity vs the flat
 ``psum_scatter``).
 
 Strategy selection (:func:`mode_for`) is per-mesh, driven by
-``TrainConfig.hier_collectives`` with the ``DLROVER_TPU_HIER_COLLECTIVES``
-typed flag overriding in both directions; the flat path is the
-kill-switch fallback and stays byte-identical to before.
+``TrainConfig.hier_collectives`` / ``overlap_collectives``; the flat
+path is the fallback.
 """
 
 from __future__ import annotations
 
 from typing import Any, Dict, Optional
 
-from dlrover_tpu.common import flags
 from dlrover_tpu.common.log import logger
 
 PyTree = Any
@@ -61,18 +59,14 @@ PyTree = Any
 SLICE_AXIS = "slice"
 DP_IN_AXIS = "dp_in"
 
-#: default size bound (MiB) of one overlap bucket — each bucket is one
-#: fused DCN collective in the exchange half of the pipeline; the
-#: DLROVER_TPU_OVERLAP_BUCKET_MB typed flag overrides it
+#: size bound (MiB) of one overlap bucket — each bucket is one fused
+#: DCN collective in the exchange half of the pipeline
 DEFAULT_BUCKET_MB = 4
 
 __all__ = [
     "SLICE_AXIS",
     "DP_IN_AXIS",
     "DEFAULT_BUCKET_MB",
-    "enabled",
-    "overlap_enabled",
-    "overlap_bucket_bytes",
     "mode_for",
     "hier_mesh",
     "split_spec",
@@ -80,38 +74,6 @@ __all__ = [
     "overlap_value_and_grad",
     "hier_param_gather",
 ]
-
-
-def enabled(train_config) -> bool:
-    """Effective hier-collectives setting: the
-    ``DLROVER_TPU_HIER_COLLECTIVES`` env flag when set (``0`` = off,
-    anything else = on), else the ``TrainConfig.hier_collectives``
-    knob."""
-    flag = flags.HIER_COLLECTIVES
-    if flag.present():
-        return flag.get() != "0"
-    return bool(getattr(train_config, "hier_collectives", True))
-
-
-def overlap_enabled(train_config) -> bool:
-    """Effective overlap-schedule setting: the
-    ``DLROVER_TPU_OVERLAP_COLLECTIVES`` env flag when set (``0`` =
-    kill-switch, anything else = on), else the
-    ``TrainConfig.overlap_collectives`` knob."""
-    flag = flags.OVERLAP_COLLECTIVES
-    if flag.present():
-        return flag.get() != "0"
-    return bool(getattr(train_config, "overlap_collectives", True))
-
-
-def overlap_bucket_bytes() -> int:
-    """Size bound of one overlap bucket in bytes (the
-    ``DLROVER_TPU_OVERLAP_BUCKET_MB`` flag, else
-    :data:`DEFAULT_BUCKET_MB`)."""
-    mb = flags.OVERLAP_BUCKET_MB.get()
-    if mb is None or mb <= 0:
-        mb = DEFAULT_BUCKET_MB
-    return int(mb) << 20
 
 
 #: one-time latch for the mixed-mesh silent-fallback warning (the
@@ -126,8 +88,6 @@ def mode_for(
     train_config,
     has_factory: bool,
     zero1_mode: str = "off",
-    enabled_override: Optional[bool] = None,
-    overlap_override: Optional[bool] = None,
 ) -> str:
     """``"flat"`` | ``"hier"`` | ``"overlap"`` for this build.
 
@@ -143,19 +103,10 @@ def mode_for(
 
     ``overlap`` is ``hier`` plus the latency-hiding bucketed schedule
     (:func:`overlap_value_and_grad`): same eligibility, gated by
-    :func:`overlap_enabled`. It is a schedule of the SAME reduction —
-    every ``mode != "flat"`` check treats the two alike.
-
-    ``enabled_override`` / ``overlap_override`` mirror
-    ``zero1.mode_for``'s: the trainer pins the flag reads once per
-    build so a concurrent ``scoped`` window can never flip the answer
-    between cache key and program build."""
+    ``TrainConfig.overlap_collectives``. It is a schedule of the SAME
+    reduction — every ``mode != "flat"`` check treats the two alike."""
     global _warned_mixed_flat
-    on = (
-        enabled(train_config)
-        if enabled_override is None else enabled_override
-    )
-    if not on or n_slices <= 1:
+    if not train_config.hier_collectives or n_slices <= 1:
         return "flat"
     shape = dict(mesh.shape)
     dp = shape.get("dp", 1)
@@ -167,9 +118,9 @@ def mode_for(
         # the body is single-device model code; a non-trivial model
         # axis would need its own manual handling (or a GSPMD-level
         # schedule — docs/design/hier_collectives.md "limits" explains
-        # why that stays out on this jax). Loud, once: an operator who
-        # exported the flag on a mixed multislice world would otherwise
-        # pay full-gradient DCN with no hint why.
+        # why that stays out on this jax). Loud, once: an operator of
+        # a mixed multislice world would otherwise pay full-gradient
+        # DCN with no hint why.
         if not _warned_mixed_flat:
             _warned_mixed_flat = True
             nontrivial = {
@@ -179,7 +130,7 @@ def mode_for(
                 "hier collectives: multislice mesh has non-trivial "
                 "model axes %s — the manual ICI-first engine needs a "
                 "pure-dp mesh, running the FLAT dp reduction (full "
-                "gradient on the DCN cut). DLROVER_TPU_HIER_COLLECTIVES"
+                "gradient on the DCN cut). TrainConfig.hier_collectives"
                 " cannot force hier here; see docs/design/"
                 "hier_collectives.md (limits).", nontrivial,
             )
@@ -192,11 +143,7 @@ def mode_for(
             SLICE_AXIS, DP_IN_AXIS,
         )
         return "flat"
-    ov = (
-        overlap_enabled(train_config)
-        if overlap_override is None else overlap_override
-    )
-    return "overlap" if ov else "hier"
+    return "overlap" if train_config.overlap_collectives else "hier"
 
 
 def hier_mesh(mesh, n_slices: int):
@@ -419,9 +366,10 @@ def overlap_value_and_grad(
       carry untouched.
     - ``exchange_fn(pending) -> grads`` runs the deferred DCN leg —
       partials are grouped into size-bounded buckets
-      (``DLROVER_TPU_OVERLAP_BUCKET_MB``) and each bucket is ONE fused
-      DCN collective: a single ``psum`` over ``slice`` of the bucket's
-      concatenated partials (replicated update + non-divisible leaves),
+      (``bucket_bytes``, else :data:`DEFAULT_BUCKET_MB`) and each
+      bucket is ONE fused DCN collective: a single ``psum`` over
+      ``slice`` of the bucket's concatenated partials (replicated
+      update + non-divisible leaves),
       or a single ``psum_scatter`` over ``slice`` straight into the
       owned zero-1 shards — then the trailing ICI all-gather per
       replicated leaf. Because the exchange consumes only the CARRIED
@@ -454,7 +402,7 @@ def overlap_value_and_grad(
     dp_in = dp // n_slices
     inv_dp = 1.0 / dp
     if bucket_bytes is None:
-        bucket_bytes = overlap_bucket_bytes()
+        bucket_bytes = DEFAULT_BUCKET_MB << 20
     is_spec = lambda x: isinstance(x, P)  # noqa: E731
 
     # flatten once; the pending list and every bucket layout follow

@@ -269,7 +269,7 @@ def mesh_for(world, devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
     """Build the Mesh a WorldDescriptor describes (slice-major when it
     is multislice) and CHECK the result against it — the one
     descriptor→mesh path, shared by the warm-compile speculation
-    targets, the bench resize phase and planner-directed resizes, so a
+    targets and planner-directed resizes, so a
     candidate world and the mesh built for it can never disagree."""
     if devices is None:
         devices = jax.devices()

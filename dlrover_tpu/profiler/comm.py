@@ -282,7 +282,7 @@ def start_metrics_server(port: int = 0):
     it with ``DLROVER_TPU_COMM_METRICS_PORT`` (see train/trainer.py).
 
     Process-wide singleton: the ledger being served is process-global,
-    and rebuilding trainers (elastic resizes, bench sweeps) must not
+    and rebuilding trainers (elastic resizes) must not
     leak one listener thread per trainer."""
     global _server_singleton
     with _server_lock:

@@ -17,15 +17,14 @@ per-kernel breakdown:
    fwd/bwd, fused/chunked CE, DCN buckets, optimizer, matmul, comm.*)
    from its ``metadata op_name`` path and custom-call target;
 3. **attribute**: normalize the weights and scale by the *measured* step
-   seconds (bench's timed loop, or :func:`measure_step`'s sampled
-   re-execution) — shares always sum to 1.0 across the whole program,
-   so a top-k cut covering >=80 % of the step always exists.
+   seconds (:func:`measure_step`'s sampled re-execution) — shares
+   always sum to 1.0 across the whole program, so a top-k cut covering
+   >=80 % of the step always exists.
 
-The result lands in two consumers: the :class:`KernelLedger` singleton
-(``dlrover_tpu_kernel_seconds_total{op=...}`` on /metrics) and
-``detail.kernel_breakdown`` in bench's mfu phase. It is never laid on a
-timeline: measured device operations are in the profiler's trace, and
-modelled durations beside them could only mislead.
+The result lands in the :class:`KernelLedger` singleton
+(``dlrover_tpu_kernel_seconds_total{op=...}`` on /metrics). It is
+never laid on a timeline: measured device operations are in the
+profiler's trace, and modelled durations beside them could only mislead.
 
 The weights are a *model*, not a measurement — the point is stable,
 named blame ("attention.bwd got 2x slower") rather than nanosecond
@@ -145,7 +144,7 @@ def classify_site(opcode: str, target: str, op_name: str) -> str:
         if "pp_send_recv" in s:
             # pp stage handoff (ppermute under the pp executors' scope):
             # its own census row instead of folding into comm.collective-
-            # permute, so the bench/metrics can see pipeline comm
+            # permute, so /metrics can see pipeline comm
             return "comm.pp_send_recv"
         if "dcn" in s or "bucket" in s or "hier" in s:
             return "comm.dcn_bucket"

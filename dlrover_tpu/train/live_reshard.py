@@ -103,7 +103,7 @@ def state_targets(avatar_tree: PyTree, mesh, world=None) -> PyTree:
     """``ShapeDtypeStruct`` (with sharding) pytree for ``mesh`` — the
     restore-target form of :func:`state_shardings`, for callers driving
     the checkpoint engine's placed restore against the same avatars
-    (bench's shm-round-trip leg, parity tests). ``world``: optional
+    (the parity tests). ``world``: optional
     WorldDescriptor checked against ``mesh`` exactly as in
     :func:`state_shardings`."""
     import jax
@@ -362,7 +362,7 @@ class ResizeLedger:
         return lines
 
 
-#: process-wide ledger (trainer records; /metrics and bench read)
+#: process-wide ledger (trainer records; /metrics reads)
 resize_ledger = ResizeLedger()
 
 

@@ -21,10 +21,7 @@ boundaries. TPU-native version:
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
-import functools
-import threading
 import time
 from typing import Any, Callable, Optional, Tuple
 
@@ -85,25 +82,21 @@ class TrainConfig:
     b2: float = 0.95
     # ZeRO-1 weight-update sharding across dp (train/zero1.py):
     # reduce-scatter grads, update dp-sharded adam moments, all-gather
-    # the params. The DLROVER_TPU_ZERO1 env flag overrides this knob in
-    # both directions. No-op on meshes without a dp axis > 1.
+    # the params. No-op on meshes without a dp axis > 1.
     zero1: bool = False
     # Hierarchical DCN-aware gradient reduction on multislice meshes
     # (ops/hier_collectives.py): ICI reduce-scatter within each slice,
     # DCN exchange of only the slice-local 1/dp_in shard, ICI
-    # all-gather. The DLROVER_TPU_HIER_COLLECTIVES env flag overrides
-    # this knob in both directions; the flat path is the fallback.
-    # No-op on single-slice meshes (the trainer's n_slices).
+    # all-gather. The flat path is the fallback. No-op on single-slice
+    # meshes (the trainer's n_slices).
     hier_collectives: bool = True
     # Latency-hiding schedule of the hierarchical reduction
     # (ops/hier_collectives.py overlap_value_and_grad): bucket the
     # grads, run the ICI leg eagerly and carry each microbatch's DCN
     # exchange through the accumulation scan behind the NEXT
-    # microbatch's backward. Same reduction, pipelined — the
-    # DLROVER_TPU_OVERLAP_COLLECTIVES env flag overrides in both
-    # directions (0 = kill-switch). Only effective where hier itself
-    # applies; with accum == 1 there is no backward to hide behind and
-    # the schedule degenerates to hier's.
+    # microbatch's backward. Same reduction, pipelined. Only effective
+    # where hier itself applies; with accum == 1 there is no backward
+    # to hide behind and the schedule degenerates to hier's.
     overlap_collectives: bool = True
 
 
@@ -121,18 +114,6 @@ def make_optimizer(tc: TrainConfig) -> optax.GradientTransformation:
         optax.clip_by_global_norm(tc.grad_clip),
         optax.adamw(sched, b1=tc.b1, b2=tc.b2, weight_decay=tc.weight_decay),
     )
-
-
-def _pin_zero1(fn):
-    """Run a build entry point under ``ElasticTrainer._zero1_pin`` so
-    every zero-1 read inside one build sees one consistent answer."""
-
-    @functools.wraps(fn)
-    def wrapped(self, *args, **kwargs):
-        with self._zero1_pin():
-            return fn(self, *args, **kwargs)
-
-    return wrapped
 
 
 class ElasticTrainer:
@@ -187,9 +168,6 @@ class ElasticTrainer:
         self._state_avatar: Optional[PyTree] = None
         self._batch_avatar: Optional[PyTree] = None
         self._params_avatar: Optional[PyTree] = None
-        # per-thread zero-1 pin (see _zero1_pin): holds the effective
-        # enabled decision for the duration of one build on that thread
-        self._zero1_tls = threading.local()
         # optional semantic hints for the shardcheck IR rules (SC003
         # needs seq_len and vocab to recognize a dense-logits tensor);
         # entry scripts that know the model set this, e.g.
@@ -233,44 +211,10 @@ class ElasticTrainer:
             pass  # port taken (another trainer in-process)
 
     # ---- zero-1 weight-update sharding (train/zero1.py) ----------------
-    @contextlib.contextmanager
-    def _zero1_pin(self):
-        """Pin the effective zero-1 AND hier-collectives decisions for
-        the calling thread.
-
-        The ``DLROVER_TPU_ZERO1`` / ``DLROVER_TPU_HIER_COLLECTIVES``
-        env flags are read live at build time (flips take effect at the
-        next build — the documented resize/restore-boundary semantics).
-        But ONE build reads them several times (cache key, avatars,
-        contract lookup, the step body), and another thread's
-        ``flags.*.scoped`` window (bench A/B legs, contract lowering)
-        can flip the env between those reads — a cache key that says
-        scatter over a replicated program, cached forever. Pinning
-        makes every ``_zero1_mode`` / ``_hier_mode`` call within the
-        ``with`` block (on this thread) see one consistent answer.
-        Re-entrant: an outer pin wins."""
-        tls = self._zero1_tls
-        if getattr(tls, "enabled", None) is not None:
-            yield
-            return
-        tls.enabled = zero1.enabled(self.tc)
-        tls.hier_enabled = hier_collectives.enabled(self.tc)
-        tls.overlap_enabled = hier_collectives.overlap_enabled(self.tc)
-        try:
-            yield
-        finally:
-            tls.enabled = None
-            tls.hier_enabled = None
-            tls.overlap_enabled = None
-
     def _zero1_mode(self, mesh: Mesh) -> str:
         """``"off"`` | ``"scatter"`` | ``"gspmd"`` — how the weight
-        update shards over dp on ``mesh``. Inside a ``_zero1_pin``
-        block the enabled decision is the pinned snapshot."""
-        return zero1.mode_for(
-            mesh, self.tc, self.loss_factory is not None,
-            enabled_override=getattr(self._zero1_tls, "enabled", None),
-        )
+        update shards over dp on ``mesh``."""
+        return zero1.mode_for(mesh, self.tc, self.loss_factory is not None)
 
     def _slices_for(self, mesh: Mesh) -> int:
         """Slice count of ``mesh``: the live mesh carries the trainer's
@@ -296,19 +240,11 @@ class ElasticTrainer:
         """``"flat"`` | ``"hier"`` | ``"overlap"`` — how the dp
         gradient reduction is scheduled over the slice topology
         (ops/hier_collectives.py); ``overlap`` is the hierarchy plus
-        the latency-hiding bucketed DCN pipeline. Inside a
-        ``_zero1_pin`` block the flag reads are the pinned snapshot,
-        same as zero-1's."""
+        the latency-hiding bucketed DCN pipeline."""
         return hier_collectives.mode_for(
             mesh, self._slices_for(mesh), self.tc,
             self.loss_factory is not None,
             zero1_mode=self._zero1_mode(mesh),
-            enabled_override=getattr(
-                self._zero1_tls, "hier_enabled", None
-            ),
-            overlap_override=getattr(
-                self._zero1_tls, "overlap_enabled", None
-            ),
         )
 
     def _state_avatar_for(self, mesh: Mesh) -> Optional[PyTree]:
@@ -355,8 +291,8 @@ class ElasticTrainer:
         """``ShapeDtypeStruct`` (with sharding) restore/transfer targets
         for ``mesh`` (default: live): state shapes from the avatars,
         optimizer-state specs re-derived for the target world (zero-1
-        aware). The one tree checkpoint restore and the bench's
-        round-trip leg should place against — placing by raw captured
+        aware). The one tree checkpoint restore should place
+        against — placing by raw captured
         avatars instead would pin a resized world to the OLD dp's
         moment layout."""
         mesh = mesh if mesh is not None else self.mesh
@@ -473,7 +409,7 @@ class ElasticTrainer:
 
         # a new trainer means a new program inventory: drop rows from any
         # previous mesh/config so /metrics never mixes dead and live
-        # configurations (elastic resize, bench candidate sweeps)
+        # configurations (elastic resize)
         comm_ledger.clear()
         comm_ledger.set_accum_steps(self.accum_steps)
         # per-link classification: on a multislice mesh the dp axis is
@@ -905,9 +841,9 @@ class ElasticTrainer:
             # the feature is off. Keyed on the EFFECTIVE mode, not the
             # request: a mesh where zero-1 cannot apply (dp<=1, pp>1)
             # builds the replicated program and must hash like it —
-            # else an exported DLROVER_TPU_ZERO1=1 makes that program
-            # miss its own checked-in plain contract (a spurious
-            # config_hash-mismatch failure, a veto under strict mode)
+            # else a zero1=True config makes that program miss its own
+            # checked-in plain contract (a spurious config_hash-mismatch
+            # failure, a veto under strict mode)
             parts.append("zero1=1")
         hier_mode = self._hier_mode(mesh)
         if hier_mode != "flat":
@@ -996,7 +932,6 @@ class ElasticTrainer:
         out_sh = (out_state_sh, NamedSharding(mesh, P()))
         return state_av, batch_av, out_sh
 
-    @_pin_zero1
     def lower_step(
         self,
         mesh: Mesh,
@@ -1117,7 +1052,7 @@ class ElasticTrainer:
             ),
             # pipeline-schedule geometry for the SC008 bubble-fraction
             # contract dimension — supplied by callers that know the
-            # model's schedule knobs (contract_model, bench)
+            # model's schedule knobs (contract_model)
             pp_schedule=hints.get("pp_schedule"),
         )
 
@@ -1271,11 +1206,10 @@ class ElasticTrainer:
             payload["argument_delta_frac"] = round(delta, 4)
         return payload
 
-    @_pin_zero1
     def memcheck_payload(self, mesh=None, mesh_config=None) -> dict:
         """Build (AOT, host-only — warm cache makes repeats free) the
         step for ``(mesh, mesh_config)`` and return its memory payload.
-        The CLI ``--mem`` mode and bench ``detail.hbm`` entry point:
+        The CLI ``--mem`` mode's entry point:
         like ``step_ir``, the substrate for any admissible world comes
         from the avatars, so no TPU — and no live training process —
         is needed."""
@@ -1388,11 +1322,10 @@ class ElasticTrainer:
         for v in violations:
             logger.warning("memcheck: %s", v.format())
 
-    @_pin_zero1
     def step_ir(self, mesh=None, mesh_config=None, pinned: bool = True):
         """Lower (and compile — on the host, no device execution) the
         step for ``(mesh, mesh_config)`` and return the shardcheck
-        ``StepProgram`` for it. This is the CLI / bench / CI entry: the
+        ``StepProgram`` for it. This is the CLI / CI entry: the
         analysis substrate for any admissible world comes from the same
         avatars the warm-compile path lowers from, so none of it needs
         a live training process — or a TPU.

@@ -90,7 +90,7 @@ _enabled_dir: Optional[str] = None
 def configured_cache_dir() -> Optional[str]:
     """The persistent-cache dir this process actually runs with: what
     this module configured, else whatever jax was already given
-    (``JAX_COMPILATION_CACHE_DIR``, bench's ``_enable_jit_cache``)."""
+    (``JAX_COMPILATION_CACHE_DIR``)."""
     if _enabled_dir:
         return _enabled_dir
     try:
@@ -106,7 +106,7 @@ def enable_persistent_cache(path: Optional[str] = None) -> Optional[str]:
     ``DLROVER_TPU_COMPILE_CACHE_DIR``). Idempotent, and never overrides
     a cache dir jax already has — the jax config is process-global and
     the first owner (a ``JAX_COMPILATION_CACHE_DIR`` placed from
-    outside, bench's ``_enable_jit_cache``) wins. Returns the effective
+    outside) wins. Returns the effective
     dir, or None when disabled/unconfigured. Purely an optimization:
     any failure logs and returns None rather than failing the caller."""
     global _enabled_dir
@@ -162,7 +162,7 @@ def default_cache_under(base_dir: str) -> Optional[str]:
 class CompileLedger:
     """Compile seconds per ``(world, config-hash)``, with provenance.
 
-    In-memory always (tests and the bench's resize phase read it); when
+    In-memory always (the tests read it); when
     a persistent cache dir is configured the ledger is also mirrored to
     ``compile_ledger.json`` inside it, atomically, so post-mortems can
     see what each membership's step cost to build and whether resizes
@@ -305,7 +305,7 @@ class CompileLedger:
 
 
 #: process-wide ledger (one trainer per process is the normal shape;
-#: bench sweeps share it, which is fine — entries are keyed by config)
+#: entries are keyed by config)
 compile_ledger = CompileLedger()
 
 
@@ -447,8 +447,8 @@ def signature_hash(parts: Sequence[str]) -> str:
 class WarmCompiler:
     """Holds compiled step executables and runs the speculative thread.
 
-    The cache is in-process: a same-process remesh (bench resize phase,
-    slice-count change absorbed without a restart) reuses the compiled
+    The cache is in-process: a same-process remesh (a slice-count
+    change absorbed without a restart) reuses the compiled
     executable directly. Across restarts the persistent XLA cache does
     the same job one layer down. One ``WarmCompiler`` per trainer.
 
@@ -547,7 +547,7 @@ class WarmCompiler:
                 )
 
     def wait_idle(self, timeout: Optional[float] = None) -> bool:
-        """Join the speculative thread (tests / bench). True if idle."""
+        """Join the speculative thread (tests). True if idle."""
         t = self._thread
         if t is None:
             return True

@@ -41,16 +41,14 @@ mesh, which is what keeps warm-compile AOT signatures, live-reshard
 transfer targets and checkpoint restore placements in agreement across
 resizes and zero-on/off transitions.
 
-Kill-switch: ``DLROVER_TPU_ZERO1`` (common/flags.py) overrides the
-``TrainConfig.zero1`` knob in both directions — ``0`` forces the
-replicated path, any other value forces zero-1 on.
+``TrainConfig.zero1`` alone turns it on; a flip at a resize boundary
+replaces ``trainer.tc`` before ``remesh()``.
 """
 
 from __future__ import annotations
 
 from typing import Any, Dict, Optional
 
-from dlrover_tpu.common import flags
 from dlrover_tpu.common.log import logger
 
 PyTree = Any
@@ -61,7 +59,6 @@ ZERO1_AXIS = "dp"
 
 __all__ = [
     "ZERO1_AXIS",
-    "enabled",
     "mode_for",
     "spec_has_dp",
     "strip_spec",
@@ -83,20 +80,7 @@ def spec_has_dp(spec) -> bool:
     return False
 
 
-def enabled(train_config) -> bool:
-    """Effective zero-1 setting: the ``DLROVER_TPU_ZERO1`` env flag
-    when set (``0`` = off, anything else = on), else the
-    ``TrainConfig.zero1`` knob."""
-    flag = flags.ZERO1
-    if flag.present():
-        return flag.get() != "0"
-    return bool(getattr(train_config, "zero1", False))
-
-
-def mode_for(
-    mesh, train_config, has_factory: bool,
-    enabled_override: Optional[bool] = None,
-) -> str:
+def mode_for(mesh, train_config, has_factory: bool) -> str:
     """``"off"`` | ``"scatter"`` | ``"gspmd"`` for this build.
 
     ``scatter`` needs every non-dp axis trivial (the whole mesh goes
@@ -104,17 +88,8 @@ def mode_for(
     factory form of the loss (``loss_factory(None)`` is the
     constraint-free local loss). pp is excluded entirely: its loss
     already runs its own shard_map schedule and the pipeline grads
-    never meet a plain dp psum this rule could rewrite.
-
-    ``enabled_override`` replaces the live :func:`enabled` read — the
-    trainer pins it once per build so a concurrent env flip (a
-    ``flags.ZERO1.scoped`` window on another thread) can never land
-    between the cache-key computation and the program build."""
-    on = (
-        enabled(train_config)
-        if enabled_override is None else enabled_override
-    )
-    if not on:
+    never meet a plain dp psum this rule could rewrite."""
+    if not train_config.zero1:
         return "off"
     shape = dict(mesh.shape)
     if shape.get(ZERO1_AXIS, 1) <= 1:

@@ -61,7 +61,7 @@ def parse_args():
     p.add_argument("--sp", type=int, default=1)
     p.add_argument("--zero1", action="store_true",
                    help="ZeRO-1 weight-update sharding across dp "
-                   "(train/zero1.py; DLROVER_TPU_ZERO1 overrides)")
+                   "(train/zero1.py)")
     p.add_argument("--ckpt-dir", default="/tmp/llama_pretrain_ckpt")
     p.add_argument("--save-every", type=int, default=10)
     p.add_argument("--data", default="",

@@ -2,8 +2,8 @@
 with the dense [B, T, V] logits path (incl. masked tokens and chunk sizes
 that do not divide V), jaxpr proof that no [B, T, V] intermediate survives
 the fwd+bwd of the chunked path, peak-activation scaling with chunk_size,
-the DLROVER_TPU_CHUNKED_CE=0 kill-switch, and composition with the
-trainer's grad-accumulation scan."""
+and composition with the trainer's grad-accumulation scan. The models'
+dense reference is ``forward`` + ``_ce_sums``."""
 
 import jax
 import jax.numpy as jnp
@@ -11,10 +11,8 @@ import numpy as np
 import pytest
 
 from dlrover_tpu.models import llama, moe, vit
-from dlrover_tpu.ops.chunked_ce import (
-    chunked_ce_enabled,
-    chunked_cross_entropy,
-)
+from dlrover_tpu.ops.chunked_ce import chunked_cross_entropy
+from dlrover_tpu.ops.norms import rms_norm
 
 jax.config.update("jax_platforms", "cpu")
 
@@ -247,7 +245,7 @@ def test_peak_intermediate_scales_with_chunk_not_vocab():
 
 
 # ---------------------------------------------------------------------------
-# model wiring: llama / moe / vit / pp head + kill-switch
+# model wiring: llama / moe / vit / pp head
 # ---------------------------------------------------------------------------
 
 LCFG = llama.LlamaConfig.tiny(ce_chunk_size=64)
@@ -265,41 +263,34 @@ def ltoks():
     return toks.at[:, -3:].set(-1)
 
 
-def test_llama_loss_matches_dense(monkeypatch, lparams, ltoks):
-    monkeypatch.setenv("DLROVER_TPU_CHUNKED_CE", "1")
-    assert chunked_ce_enabled()
+def _dense_llama_loss(params, tokens, cfg):
+    nll_sum, n_valid = llama._ce_sums(
+        llama.forward(params, tokens, cfg), tokens
+    )
+    return nll_sum / jnp.maximum(n_valid, 1.0)
+
+
+def test_llama_loss_matches_dense(lparams, ltoks):
     chunked = llama.loss_fn(lparams, ltoks, LCFG)
     gc = jax.grad(llama.loss_fn)(lparams, ltoks, LCFG)
-    monkeypatch.setenv("DLROVER_TPU_CHUNKED_CE", "0")
-    assert not chunked_ce_enabled()
-    dense = llama.loss_fn(lparams, ltoks, LCFG)
-    gd = jax.grad(llama.loss_fn)(lparams, ltoks, LCFG)
+    dense = _dense_llama_loss(lparams, ltoks, LCFG)
+    gd = jax.grad(_dense_llama_loss)(lparams, ltoks, LCFG)
     assert rel_err(chunked, dense) <= 1e-5
     for kc, kd in zip(jax.tree.leaves(gc), jax.tree.leaves(gd)):
         assert rel_err(kc, kd) <= 1e-5
-
-
-def test_llama_kill_switch_restores_dense_logits(monkeypatch, lparams,
-                                                 ltoks):
+    # and only the reference carries [B, T, V] logits
     b, s = ltoks.shape
     n_tok = {b * s, b * (s - 1)}
 
-    def jaxpr_of_loss():
-        return jax.make_jaxpr(
-            lambda p: llama.loss_fn(p, ltoks, LCFG)
-        )(lparams)
+    def logits_avals(fn):
+        jaxpr = jax.make_jaxpr(lambda p: fn(p, ltoks, LCFG))(lparams)
+        return logits_sized_avals(jaxpr.jaxpr, n_tok, LCFG.vocab_size)
 
-    monkeypatch.setenv("DLROVER_TPU_CHUNKED_CE", "0")
-    assert logits_sized_avals(
-        jaxpr_of_loss().jaxpr, n_tok, LCFG.vocab_size
-    ), "kill-switch must restore the dense [B, T, V] logits path"
-    monkeypatch.setenv("DLROVER_TPU_CHUNKED_CE", "1")
-    assert not logits_sized_avals(
-        jaxpr_of_loss().jaxpr, n_tok, LCFG.vocab_size
-    )
+    assert logits_avals(_dense_llama_loss)
+    assert not logits_avals(llama.loss_fn)
 
 
-def test_pp_head_loss_sums_matches_dense(monkeypatch, lparams):
+def test_pp_head_loss_sums_matches_dense(lparams):
     """The pipeline schedules' shared head+loss helper (the path 1f1b
     differentiates with jax.vjp inside the tick) takes the chunked route
     too."""
@@ -308,45 +299,48 @@ def test_pp_head_loss_sums_matches_dense(monkeypatch, lparams):
     tgt = jnp.asarray(
         rng.integers(0, LCFG.vocab_size, size=(2, 10)), jnp.int32
     ).at[:, -1].set(-1)
-    monkeypatch.setenv("DLROVER_TPU_CHUNKED_CE", "1")
     ns_c, nv_c = llama._head_loss_sums(
         LCFG, out, lparams["final_norm"], lparams["lm_head"], tgt
     )
-    monkeypatch.setenv("DLROVER_TPU_CHUNKED_CE", "0")
-    ns_d, nv_d = llama._head_loss_sums(
-        LCFG, out, lparams["final_norm"], lparams["lm_head"], tgt
+    h = rms_norm(out, lparams["final_norm"], LCFG.norm_eps)
+    ns_d, nv_d = llama._ce_sums_shifted(
+        llama.unembed(h, lparams["lm_head"]), tgt
     )
     assert float(nv_c) == float(nv_d)
     assert rel_err(ns_c, ns_d) <= 1e-5
 
 
-def test_moe_loss_matches_dense(monkeypatch):
+def test_moe_loss_matches_dense():
     cfg = moe.MoeConfig.tiny(ce_chunk_size=48)
     params = moe.init_params(cfg, jax.random.key(0))
     toks = jax.random.randint(jax.random.key(1), (2, 12), 0,
                               cfg.vocab_size).at[:, -2:].set(-1)
-    monkeypatch.setenv("DLROVER_TPU_CHUNKED_CE", "1")
     chunked = moe.loss_fn(params, toks, cfg)
-    monkeypatch.setenv("DLROVER_TPU_CHUNKED_CE", "0")
-    dense = moe.loss_fn(params, toks, cfg)
+    logits, aux = moe.forward(params, toks, cfg)
+    nll_sum, n_valid = llama._ce_sums(logits, toks)
+    dense = nll_sum / jnp.maximum(n_valid, 1.0) + cfg.router_aux_coef * aux
     assert rel_err(chunked, dense) <= 1e-5
 
 
-def test_vit_loss_matches_dense(monkeypatch):
+def test_vit_loss_matches_dense():
     cfg = vit.ViTConfig.tiny()
     params = vit.init_params(cfg, jax.random.key(0))
     images = jax.random.normal(
         jax.random.key(1), (2, cfg.image_size, cfg.image_size, 3)
     )
     labels = jnp.asarray([3, -1], jnp.int32)  # one pad-sentinel label
-    monkeypatch.setenv("DLROVER_TPU_CHUNKED_CE", "1")
     chunked = vit.loss_fn(params, (images, labels), cfg)
-    monkeypatch.setenv("DLROVER_TPU_CHUNKED_CE", "0")
-    dense = vit.loss_fn(params, (images, labels), cfg)
+    logits = vit.forward(params, images, cfg)
+    logz = jax.scipy.special.logsumexp(logits, axis=-1)
+    gold = jnp.take_along_axis(
+        logits, jnp.maximum(labels, 0)[..., None], axis=-1
+    )[..., 0]
+    mask = (labels >= 0).astype(jnp.float32)
+    dense = jnp.sum((logz - gold) * mask) / jnp.maximum(jnp.sum(mask), 1.0)
     assert rel_err(chunked, dense) <= 1e-5
 
 
-def test_trainer_grad_accum_composes(monkeypatch, lparams, ltoks):
+def test_trainer_grad_accum_composes(lparams, ltoks):
     """End to end through ElasticTrainer: accum=2 wraps the chunked-CE
     custom_vjp in the grad-accumulation lax.scan inside the donating
     jitted step; first-step loss must match the dense path's."""
@@ -360,9 +354,9 @@ def test_trainer_grad_accum_composes(monkeypatch, lparams, ltoks):
     batch = jax.random.randint(jax.random.key(3), (2, 2, 10), 0,
                                LCFG.vocab_size)
 
-    def first_step_loss():
+    def first_step_loss(loss):
         trainer = ElasticTrainer(
-            lambda p, t: llama.loss_fn(p, t, LCFG, None),
+            lambda p, t: loss(p, t, LCFG),
             llama.param_specs(LCFG), mesh, mc, tc,
         )
         assert trainer.accum_steps == 2
@@ -372,8 +366,6 @@ def test_trainer_grad_accum_composes(monkeypatch, lparams, ltoks):
         assert np.isfinite(float(loss2))
         return float(loss)
 
-    monkeypatch.setenv("DLROVER_TPU_CHUNKED_CE", "1")
-    chunked = first_step_loss()
-    monkeypatch.setenv("DLROVER_TPU_CHUNKED_CE", "0")
-    dense = first_step_loss()
+    chunked = first_step_loss(llama.loss_fn)
+    dense = first_step_loss(_dense_llama_loss)
     assert abs(chunked - dense) / max(abs(dense), 1e-30) <= 1e-5

@@ -1,9 +1,8 @@
 """Fused-CE Pallas kernel (ops/fused_ce.py): value and grad parity with
 the dense and chunked references under interpret mode (masks, tile sizes
 that do not divide tokens/vocab), the ``cross_entropy_sums`` dispatch
-contract (TPU-gated, DLROVER_TPU_FUSED_CE=0 kill-switch), composition
-with the trainer's grad-accumulation scan, and the bench sweep's
-fce-vs-cce A/B gating."""
+contract (TPU-gated, DLROVER_TPU_FUSED_CE=0 kill-switch), and
+composition with the trainer's grad-accumulation scan."""
 
 import os
 
@@ -312,9 +311,9 @@ def test_kill_switch(xwt, monkeypatch):
 
 def test_scoped_false_actually_disables_bool_flags(monkeypatch):
     """str(False) == "False" reads back TRUE under the raw != "0" env
-    parse — a scoped(False) pin (the bench _cce candidates' FUSED_CE
-    override) must round-trip through "0" or the fce-vs-cce A/B on TPU
-    silently compares the fused program against itself."""
+    parse — a scoped(False) pin must round-trip through "0" or a
+    fused-vs-chunked comparison on TPU silently compares the fused
+    program against itself."""
     from dlrover_tpu.common import flags
 
     # set, then delete: monkeypatch restores what it saw first, and a
@@ -346,45 +345,3 @@ def test_dispatcher_uses_kernel_when_runnable(xwt, monkeypatch):
     ns, nv = cross_entropy_sums(x, w, t, interpret=True)
     fs, fv = fused_cross_entropy(x, w, t, interpret=True)
     assert float(ns) == float(fs) and float(nv) == float(fv)
-
-
-# ---------------------------------------------------------------------------
-# bench sweep gating: fce-vs-cce A/B (ISSUE 17 acceptance)
-# ---------------------------------------------------------------------------
-
-
-def _candidates(monkeypatch, available: bool):
-    import bench
-
-    monkeypatch.setattr(
-        "dlrover_tpu.ops.fused_ce._on_tpu", lambda: available
-    )
-    from dlrover_tpu.models import llama
-
-    return bench._bench_candidates(llama, jnp)
-
-
-def test_bench_fce_candidate_tpu_only(monkeypatch):
-    """The _fce candidate appears exactly when the kernel can actually
-    run (TPU + flag), pinned FUSED_CE=True; the _cce counterparts pin
-    FUSED_CE=False so the A/B measures two real programs."""
-    monkeypatch.setenv("DLROVER_TPU_FUSED_CE", "1")
-    monkeypatch.setenv("DLROVER_TPU_CHUNKED_CE", "1")
-    names = [c[0] for c in _candidates(monkeypatch, available=False)]
-    assert not any(n.endswith("_fce") for n in names)  # CPU: gated out
-
-    cands = _candidates(monkeypatch, available=True)
-    fce = [c for c in cands if c[0].endswith("_fce")]
-    assert len(fce) == 1
-    assert fce[0][4] == {"FUSED_CE": True}
-    # ordered first: if the fused kernel wins the A/B it takes the
-    # headline; if it loses (or OOMs) the sweep keeps a _cce winner
-    assert cands[0][0].endswith("_fce")
-    for c in cands:
-        if c[0].endswith("_cce"):
-            assert c[4] == {"FUSED_CE": False}
-
-    # kill-switch sweeps the chunked/dense candidates only (bisection)
-    monkeypatch.setenv("DLROVER_TPU_FUSED_CE", "0")
-    names = [c[0] for c in _candidates(monkeypatch, available=True)]
-    assert not any(n.endswith("_fce") for n in names)

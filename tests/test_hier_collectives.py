@@ -8,10 +8,12 @@ training is numerically equivalent to the flat path (the acceptance
 criterion's step-loss parity), the DCN bytes drop to ~1/dp_in of the
 flat path's — provable three ways (the analytic ledger exactly, the
 per-link census against the flat per-issue baseline, and the
-checked-in ``dp4+2slice`` / ``dp4+2slice+zero1`` contracts) — and the
-``DLROVER_TPU_HIER_COLLECTIVES`` kill-switch restores the flat path
+checked-in ``dp4+2slice`` / ``dp4+2slice+zero1`` contracts) — and
+``TrainConfig(hier_collectives=False)`` builds the flat path
 byte-identically (plain contract spec, plain config hash).
 """
+
+import dataclasses
 
 import numpy as np
 import pytest
@@ -19,7 +21,6 @@ import pytest
 import jax
 from jax.sharding import PartitionSpec as P
 
-from dlrover_tpu.common import flags
 from dlrover_tpu.lint import shardcheck
 from dlrover_tpu.models import llama
 from dlrover_tpu.ops import hier_collectives as hc
@@ -34,10 +35,6 @@ GB = 16  # micro=2 → accum 2 on dp4 (the grad-accum scan composes)
 
 @pytest.fixture(autouse=True)
 def _fresh(monkeypatch):
-    monkeypatch.delenv(flags.HIER_COLLECTIVES.name, raising=False)
-    monkeypatch.delenv(flags.OVERLAP_COLLECTIVES.name, raising=False)
-    monkeypatch.delenv(flags.OVERLAP_BUCKET_MB.name, raising=False)
-    monkeypatch.delenv(flags.ZERO1.name, raising=False)
     monkeypatch.delenv(wc.ENV_KILL_SWITCH, raising=False)
     monkeypatch.delenv(wc.ENV_CACHE_DIR, raising=False)
     yield
@@ -135,8 +132,8 @@ def test_mode_for():
 def test_mixed_mesh_flat_fallback_warns_once(monkeypatch):
     """satellite: the mixed-mesh silent flat fallback is silent no
     more — the FIRST multislice mixed-mesh build logs a warning naming
-    the flag and the docs, subsequent ones stay quiet (one latch, not
-    one log line per lowering)."""
+    the config field and the docs, subsequent ones stay quiet (one
+    latch, not one log line per lowering)."""
     monkeypatch.setattr(hc, "_warned_mixed_flat", False)
     warnings = []
     monkeypatch.setattr(
@@ -146,21 +143,9 @@ def test_mixed_mesh_flat_fallback_warns_once(monkeypatch):
     ov = TrainConfig()
     assert hc.mode_for(_FakeMesh(dp=4, tp=2), 2, ov, True) == "flat"
     assert hc.mode_for(_FakeMesh(dp=4, tp=2), 2, ov, True) == "flat"
-    named = [w for w in warnings if "DLROVER_TPU_HIER_COLLECTIVES" in w]
+    named = [w for w in warnings if "TrainConfig.hier_collectives" in w]
     assert len(named) == 1, warnings
     assert "tp" in named[0]  # names the offending axes too
-
-
-def test_overlap_kill_switch_overrides_both_directions(monkeypatch):
-    tc_on = TrainConfig()
-    tc_off = TrainConfig(overlap_collectives=False)
-    assert hc.overlap_enabled(tc_on) and not hc.overlap_enabled(tc_off)
-    monkeypatch.setenv(flags.OVERLAP_COLLECTIVES.name, "0")
-    assert not hc.overlap_enabled(tc_on)  # forced off
-    monkeypatch.setenv(flags.OVERLAP_COLLECTIVES.name, "1")
-    assert hc.overlap_enabled(tc_off)  # forced on
-    monkeypatch.setenv(flags.OVERLAP_COLLECTIVES.name, "")
-    assert hc.overlap_enabled(tc_on) and not hc.overlap_enabled(tc_off)
 
 
 def test_partition_buckets():
@@ -173,18 +158,6 @@ def test_partition_buckets():
         [[i] for i in items]
     assert hc._partition_buckets(items, sizes, 10 ** 9) == [items]
     assert hc._partition_buckets([], [], 5) == []
-
-
-def test_kill_switch_overrides_both_directions(monkeypatch):
-    tc_on = TrainConfig(hier_collectives=True)
-    tc_off = TrainConfig(hier_collectives=False)
-    assert hc.enabled(tc_on) and not hc.enabled(tc_off)
-    monkeypatch.setenv(flags.HIER_COLLECTIVES.name, "0")
-    assert not hc.enabled(tc_on)  # forced off
-    monkeypatch.setenv(flags.HIER_COLLECTIVES.name, "1")
-    assert hc.enabled(tc_off)  # forced on
-    monkeypatch.setenv(flags.HIER_COLLECTIVES.name, "")
-    assert hc.enabled(tc_on) and not hc.enabled(tc_off)
 
 
 def test_hier_mesh_preserves_flat_device_order():
@@ -273,18 +246,18 @@ def test_parity_overlap_zero1_dp4_2slice():
     _assert_parity(l_h, l_o, s_h, s_o)
 
 
-def test_overlap_kill_switch_restores_hier_program(monkeypatch):
-    """DLROVER_TPU_OVERLAP_COLLECTIVES=0 downgrades an overlap trainer
-    to the fused hier program — contract key and mode revert, hier
-    itself stays on."""
+def test_overlap_kill_switch_restores_hier_program():
+    """``overlap_collectives=False`` downgrades an overlap trainer to
+    the fused hier program — contract key and mode revert, hier itself
+    stays on."""
     tr, _ = _make(4, 2, overlap=True)
     assert tr._hier_mode(tr.mesh) == "overlap"
     assert tr._contract_spec(tr.mesh) == "dp4+2slice+overlap"
-    monkeypatch.setenv(flags.OVERLAP_COLLECTIVES.name, "0")
+    tr.tc = dataclasses.replace(tr.tc, overlap_collectives=False)
     assert tr._hier_mode(tr.mesh) == "hier"
     assert tr._contract_spec(tr.mesh) == "dp4+2slice"
-    # and the hier kill-switch still flattens everything
-    monkeypatch.setenv(flags.HIER_COLLECTIVES.name, "0")
+    # and hier_collectives=False still flattens everything
+    tr.tc = dataclasses.replace(tr.tc, hier_collectives=False)
     assert tr._hier_mode(tr.mesh) == "flat"
     assert tr._contract_spec(tr.mesh) == "dp4"
 
@@ -717,10 +690,10 @@ def test_link_classification_units():
 # ---------------------------------------------------------------------------
 
 
-def test_signatures_and_labels_separate_programs(monkeypatch):
+def test_signatures_and_labels_separate_programs():
     """Flat and hier builds on the same mesh must never share an AOT
-    executable or a contract key; the kill-switch restores the plain
-    label and the plain (pre-hier) config hash."""
+    executable or a contract key; turning the field off restores the
+    plain label and the plain (pre-hier) config hash."""
     tr_h, state = _make(4, 2, hier=True)
     tr_f, _ = _make(4, 2, hier=False)
     batch = np.asarray(_batch(tr_h, 1))
@@ -733,9 +706,9 @@ def test_signatures_and_labels_separate_programs(monkeypatch):
     assert sig_h != sig_f and hash_h != hash_f
     assert tr_h._contract_spec(tr_h.mesh) == "dp4+2slice"
     assert tr_f._contract_spec(tr_f.mesh) == "dp4"
-    # the env kill-switch downgrades the hier trainer to the flat
+    # hier_collectives=False downgrades the hier trainer to the flat
     # program — label, hash and signature all revert
-    monkeypatch.setenv(flags.HIER_COLLECTIVES.name, "0")
+    tr_h.tc = dataclasses.replace(tr_h.tc, hier_collectives=False)
     sig_k, hash_k = tr_h._step_signature(tr_h.mesh, tr_h.mesh_config,
                                          tr_h.accum_steps)
     assert (sig_k, hash_k) == (sig_f, hash_f)

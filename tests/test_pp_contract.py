@@ -14,7 +14,6 @@ import json
 
 import pytest
 
-from dlrover_tpu.common import flags
 from dlrover_tpu.lint import contract_model, shardcheck
 from dlrover_tpu.lint.__main__ import main as lint_main
 
@@ -162,10 +161,8 @@ def test_seeded_reserialization_fails_the_contract(
     ), [x.message for x in v]
 
 
-def test_checked_in_pp_contracts_pass(monkeypatch):
+def test_checked_in_pp_contracts_pass():
     """The acceptance gate: ``python -m dlrover_tpu.lint --hlo`` exits
     0 against the checked-in pp contracts — the single-slice dp2xpp2
-    world and the stage-per-slice pp2+2slice world — with exported
-    flag overrides pinned out of the build."""
-    monkeypatch.setenv(flags.ZERO1.name, "1")
+    world and the stage-per-slice pp2+2slice world."""
     assert lint_main(["--hlo", "dp2xpp2", "--hlo", "pp2+2slice"]) == 0

@@ -14,9 +14,9 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dlrover_tpu.common import flags
 from dlrover_tpu.lint import contract_model, shardcheck
 from dlrover_tpu.lint.__main__ import main as lint_main
+from dlrover_tpu.models import llama
 
 # ---------------------------------------------------------------------------
 # parser units (text only — no lowering)
@@ -181,14 +181,10 @@ def test_census_improvements_reported(contract_setup, tmp_path):
     assert notes and key in notes[0]
 
 
-def test_checked_in_contracts_pass_for_all_three_meshes(monkeypatch):
+def test_checked_in_contracts_pass_for_all_three_meshes():
     """The acceptance gate: ``python -m dlrover_tpu.lint --hlo`` exits
     0 against the checked-in contracts for dp=4, dp=2×fsdp=2 and
-    sp=2×dp=2 — including with ``DLROVER_TPU_ZERO1`` exported, which
-    must NOT leak into the contract build (the spec decides the
-    variant; a leak would lower the zero-1 program and diff its
-    reduce-scatters against the plain census)."""
-    monkeypatch.setenv(flags.ZERO1.name, "1")
+    sp=2×dp=2 (the spec alone decides the variant)."""
     assert lint_main(
         ["--hlo", "dp4", "--hlo", "dp2xfsdp2", "--hlo", "sp2xdp2"]
     ) == 0
@@ -297,13 +293,27 @@ def test_sc002_quiet_on_sharded_constraint_and_below_threshold():
 # ---------------------------------------------------------------------------
 
 
-def test_sc003_fires_when_dense_ce_reenabled(monkeypatch):
-    """Flipping the chunked-CE kill-switch brings the [B,T,V] f32
-    logits back — shardcheck sees them in the lowered program."""
-    monkeypatch.setenv("DLROVER_TPU_CHUNKED_CE", "0")
+def test_sc003_fires_when_dense_ce_reenabled():
+    """A loss written as ``_ce_sums(forward(...))`` brings the [B,T,V]
+    f32 logits back — shardcheck sees them in the lowered program."""
     trainer, _, _ = contract_model.build_contract_trainer(
         {"dp": 2, "fsdp": 2}
     )
+    cfg = llama.LlamaConfig.tiny(
+        vocab_size=contract_model.VOCAB,
+        ce_chunk_size=contract_model.CE_CHUNK,
+    )
+
+    def dense_loss(mesh):
+        def loss(params, tokens):
+            nll_sum, n_valid = llama._ce_sums(
+                llama.forward(params, tokens, cfg, mesh), tokens
+            )
+            return nll_sum / jnp.maximum(n_valid, 1.0)
+
+        return loss
+
+    trainer.loss_factory = dense_loss
     program = trainer.step_ir()
     v = [x for x in shardcheck.check_program(program)
          if x.rule == "SC003"]

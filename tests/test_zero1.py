@@ -6,11 +6,11 @@ reduce-scatter + all-gather rewrite (REAL ops in the dp4 HLO — the
 checked-in ``dp4+zero1`` contract pins them), training is numerically
 equivalent to the replicated baseline, the sharded moments survive
 resizes (live reshard AND checkpoint restore, including zero-on↔off
-transitions), and the ``DLROVER_TPU_ZERO1`` kill-switch overrides the
-config knob in both directions. Plus the comm-ledger↔IR-census
-agreement the analytic inventory claims.
+transitions, flipped through ``trainer.tc``). Plus the
+comm-ledger↔IR-census agreement the analytic inventory claims.
 """
 
+import dataclasses
 import time
 
 import jax
@@ -48,13 +48,12 @@ def _drain_speculation():
 
 @pytest.fixture(autouse=True)
 def _fresh(monkeypatch):
-    """No zero-1 / reshard kill-switches leaking in from the outer
+    """No reshard kill-switches leaking in from the outer
     environment; fresh ledgers; isolated shm name space."""
     job = f"zero1-{int(time.time() * 1000) % 100000}"
     monkeypatch.setenv(NodeEnv.JOB_NAME, job)
     monkeypatch.setenv(NodeEnv.NODE_ID, "0")
     monkeypatch.setenv(NodeEnv.PROCESS_ID, "0")
-    monkeypatch.delenv(flags.ZERO1.name, raising=False)
     monkeypatch.delenv(flags.LIVE_RESHARD.name, raising=False)
     monkeypatch.delenv(wc.ENV_KILL_SWITCH, raising=False)
     monkeypatch.delenv(wc.ENV_CACHE_DIR, raising=False)
@@ -174,39 +173,6 @@ def test_mode_for():
     assert zero1.mode_for(_FakeMesh(dp=1, fsdp=4), tc, True) == "off"
     assert zero1.mode_for(_FakeMesh(dp=4), off, True) == "off"
     assert zero1.mode_for(_FakeMesh(dp=2, pp=2), tc, True) == "off"
-
-
-def test_kill_switch_overrides_both_directions(monkeypatch):
-    tc_on = TrainConfig(zero1=True)
-    tc_off = TrainConfig(zero1=False)
-    assert zero1.enabled(tc_on) and not zero1.enabled(tc_off)
-    monkeypatch.setenv(flags.ZERO1.name, "0")
-    assert not zero1.enabled(tc_on)  # forced off
-    monkeypatch.setenv(flags.ZERO1.name, "1")
-    assert zero1.enabled(tc_off)  # forced on
-    monkeypatch.setenv(flags.ZERO1.name, "")
-    assert zero1.enabled(tc_on) and not zero1.enabled(tc_off)
-
-
-def test_flag_scoped_pin_and_restore(monkeypatch):
-    """``flags.ZERO1.scoped(None)`` makes knob-decided builds immune to
-    an exported override (contract lowering, bench A/B legs) and
-    restores the outer environment on exit — including on error."""
-    tc_off = TrainConfig(zero1=False)
-    monkeypatch.setenv(flags.ZERO1.name, "1")
-    assert zero1.enabled(tc_off)  # the leak scoped() exists to stop
-    with flags.ZERO1.scoped(None):
-        assert not zero1.enabled(tc_off)
-    assert zero1.enabled(tc_off)  # restored
-    with pytest.raises(RuntimeError):
-        with flags.ZERO1.scoped("0"):
-            assert not zero1.enabled(tc_off)
-            raise RuntimeError("boom")
-    assert zero1.enabled(tc_off)  # restored past the raise
-    monkeypatch.delenv(flags.ZERO1.name)
-    with flags.ZERO1.scoped("1"):
-        assert zero1.enabled(tc_off)
-    assert flags.ZERO1.raw() is None  # unset restored to unset
 
 
 def test_contract_spec_roundtrip():
@@ -390,7 +356,7 @@ def test_resize_parity_live_vs_checkpoint(tmp_path):
     assert tr2._zero1_mode(mesh_b) != "off"
 
 
-def test_resize_grow_and_zero_transitions(monkeypatch, tmp_path):
+def test_resize_grow_and_zero_transitions(tmp_path):
     """One elastic journey: dp2(on) → grow dp4 while flipping zero-1
     OFF (moments gather back to replicated) → flip ON again and shrink
     to dp2 (moments re-shard). Each hop is checked against the
@@ -402,8 +368,8 @@ def test_resize_grow_and_zero_transitions(monkeypatch, tmp_path):
     jax.block_until_ready(state)
     assert any("'dp'" in s for s in _moment_specs(state))
 
-    # grow dp2→dp4 with zero-1 forced OFF: the off-transition
-    monkeypatch.setenv(flags.ZERO1.name, "0")
+    # grow dp2→dp4 with zero-1 turned OFF: the off-transition
+    tr.tc = dataclasses.replace(tr.tc, zero1=False)
     mesh_b, mc_b = _mk(4)
     ref = _ckpt_reference(tr, state, mesh_b, str(tmp_path / "c1"))
     off_state = tr.remesh(mesh_b, mc_b, state=state)
@@ -415,7 +381,7 @@ def test_resize_grow_and_zero_transitions(monkeypatch, tmp_path):
     jax.block_until_ready(off_state)
 
     # back ON and shrink dp4→dp2: the on-transition re-shards
-    monkeypatch.setenv(flags.ZERO1.name, "1")
+    tr.tc = dataclasses.replace(tr.tc, zero1=True)
     mesh_c, mc_c = _mk(2)
     ref2 = _ckpt_reference(tr, off_state, mesh_c, str(tmp_path / "c2"))
     on_state = tr.remesh(mesh_c, mc_c, state=off_state)
@@ -683,27 +649,6 @@ def test_config_hash_keys_on_effective_mode():
     assert (
         tr_d_on._config_hash(mesh_d) != tr_d_off._config_hash(mesh_d)
     )
-
-
-def test_zero1_pin_freezes_decision_within_build(monkeypatch):
-    """Inside one build (``_zero1_pin``), a concurrent env flip — a
-    ``flags.ZERO1.scoped`` window on another thread — cannot change
-    the answer between the cache-key computation and the program
-    build; the NEXT build sees the flip (the documented boundary
-    semantics)."""
-    mesh, mc = _mk(2)
-    tc = TrainConfig(global_batch_size=GB, micro_batch_size=2,
-                     warmup_steps=0, total_steps=100, zero1=False)
-    tr = ElasticTrainer(None, llama.param_specs(CFG), mesh, mc, tc,
-                        loss_factory=_factory)
-    assert tr._zero1_mode(mesh) == "off"
-    with tr._zero1_pin():
-        assert tr._zero1_mode(mesh) == "off"
-        monkeypatch.setenv(flags.ZERO1.name, "1")
-        assert tr._zero1_mode(mesh) == "off"  # pinned for this build
-        with tr._zero1_pin():  # re-entrant: outer pin wins
-            assert tr._zero1_mode(mesh) == "off"
-    assert tr._zero1_mode(mesh) != "off"  # next build sees the flip
 
 
 @pytest.mark.slow
