@@ -8,19 +8,35 @@ closes that last round-trip: a vocab tile's logits live only in VMEM
 registers between the MXU matmul and the streaming-lse update, exactly as
 flash attention (ops/attention.py) keeps the s×s matrix out of HBM.
 
-Structure mirrors the chunked path's custom-VJP 1:1 so the two stay
-bitwise-comparable under tolerance:
+One "unit" below is a product over the whole vocabulary, 2·T·d·V FLOPs.
+The mathematics needs three (logits, dX, dW); a differentiated loss here
+executes four, an undifferentiated one one:
 
 - **forward** (grid token-blocks × vocab-tiles): per-tile logits
   ``x_blk @ w_tile`` with f32 MXU accumulation, online-softmax carry
   ``(m, s)`` in VMEM scratch, target-logit gather via an iota==target
   one-hot reduction (the target's column lands in exactly one tile); the
-  last tile finalizes per-token ``logz`` and ``gold``. O(tokens) outputs.
-- **backward**: two kernels recomputing tile logits from the saved
-  ``(x, w, logz)`` residual — ``dx`` token-major (vocab tiles accumulate
-  in VMEM), ``dw`` vocab-major (token blocks accumulate in VMEM, each
-  vocab tile written exactly once) — the dq/dkv split from the flash
-  backward, ported to the CE geometry.
+  last tile finalizes per-token ``logz`` and ``gold``. O(tokens)
+  outputs, 1 unit: what a loss that nobody differentiates runs.
+- **forward under differentiation** (``jax.custom_vjp``'s rule; nothing
+  selects it but ``jax.grad``): the same sweep also carries
+  ``acc = acc · exp(m_prev − m_cur) + exp(logits − m_cur) @ w_tile^T``
+  in its ``(block_t, d)`` f32 output block, the online rescaling flash
+  attention uses, and its last tile divides by ``s``, which leaves
+  ``softmax(logits) @ w^T``: dX's vocabulary-wide term, from the logits
+  the sweep already had in VMEM. It leaves the kernel in f32: where the
+  model is sure of its target the term all but equals the target's
+  column, and what the subtraction leaves would be the rounding of a
+  narrower residual. 2 units.
+- **backward**: dX is that residual less the targets' columns of the
+  head (a lookup of T columns, not a product), times the cotangent, in
+  f32 and rounded once. dW cannot ride the forward sweep the same way:
+  its sum runs over tokens, and a token's normaliser is known only when
+  that token's sweep ends, so no vocabulary tile of dW can be finished
+  before every token block has been swept. It stays a kernel of its
+  own, vocab-major (token blocks accumulate in VMEM, each vocab tile
+  written exactly once), re-forming tile logits from the saved
+  ``(x, w, logz)``. 2 units.
 
 Dispatch contract (``cross_entropy_sums``): on the TPU backend the
 Pallas kernel is the path (and under ``interpret=True`` for CPU numerics
@@ -47,6 +63,7 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dlrover_tpu.common import flags
+from dlrover_tpu.observability import trace
 from dlrover_tpu.ops.chunked_ce import (
     DEFAULT_CHUNK_SIZE,
     chunked_cross_entropy,
@@ -62,9 +79,10 @@ _LANES = 8
 
 #: Largest tiles a kernel is given. Every kernel blocks the whole
 #: feature dim — ``(block_t, d)`` and ``(d, block_v)`` operand blocks,
-#: and in the backward an output block plus an f32 accumulator of the
-#: same shape — so its VMEM footprint grows with ``d``:
-#: ``_tile_geometry`` halves these until ``_vmem_bytes`` fits the budget.
+#: and where it accumulates a gradient an output block of one of those
+#: shapes (dw's with an f32 accumulator beside it) — so its VMEM
+#: footprint grows with ``d``: ``_tile_geometry`` halves these until
+#: ``_vmem_bytes`` fits the budget.
 DEFAULT_BLOCK_T = 256
 DEFAULT_BLOCK_V = 512
 
@@ -188,32 +206,40 @@ def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
+#: The three kernels, as ``_vmem_bytes`` and ``_tile_geometry`` name them.
+LOSS, LOSS_DX, DW = "loss", "loss_dx", "dw"
+
+
 def _vmem_bytes(bt: int, bv: int, d: int, xb: int, wb: int,
-                backward: bool) -> int:
+                kernel: str) -> int:
     """VMEM one kernel's blocks occupy: the pipelined (double-buffered)
     ``(bt, d)`` x and ``(d, bv)`` w blocks, w's compute-dtype copy when
-    the dtypes differ, and in the backward the larger of the dx
-    ``(bt, d)`` and dw ``(d, bv)`` kernels' output block (double-
-    buffered) with its f32 accumulator."""
+    the dtypes differ, and the gradient block the kernel accumulates
+    (double-buffered): ``(bt, d)`` f32 in the training forward
+    (``LOSS_DX``), which accumulates in the block itself; ``(d, bv)``
+    with an f32 accumulator in ``DW``; none in ``LOSS``."""
     total = 2 * bt * d * xb + 2 * d * bv * wb
     if wb != xb:
         total += d * bv * xb
-    if backward:
-        total += max(bt * d * (2 * xb + 4), d * bv * (2 * wb + 4))
+    if kernel == LOSS_DX:
+        total += bt * d * 2 * 4
+    elif kernel == DW:
+        total += d * bv * (2 * wb + 4)
     return total
 
 
 def _tile_geometry(n: int, v: int, d: int, x_dtype, w_dtype,
-                   block_t: int, block_v: int, backward: bool):
+                   block_t: int, block_v: int, kernel: str):
     """Clip the requested tiles to the (8, 128)-aligned problem size,
     halve them until the kernel's blocks fit ``_VMEM_BUDGET`` at this
     ``d``, and return ``(bt, bv, n_pad, v_pad)`` with the padded array
     dims exact tile multiples — every BlockSpec start is then in
-    range."""
+    range. Two kernels of one loss may end with different tiles:
+    ``logz`` is per token, so nothing depends on that."""
     bt = max(8, min(block_t, _round_up(n, 8)))
     bv = max(128, min(block_v, _round_up(v, 128)))
     xb, wb = jnp.dtype(x_dtype).itemsize, jnp.dtype(w_dtype).itemsize
-    while _vmem_bytes(bt, bv, d, xb, wb, backward) > _VMEM_BUDGET:
+    while _vmem_bytes(bt, bv, d, xb, wb, kernel) > _VMEM_BUDGET:
         # shrink the side that holds more VMEM; the vocab tile stays a
         # multiple of the 128-lane width, the token tile of 8 sublanes
         if bv > 128 and (bv * wb >= bt * xb or bt <= 8):
@@ -277,9 +303,16 @@ def _tile_logits(x_ref, w_ref, vi, bt: int, bv: int, v: int):
 
 
 def _fused_ce_fwd_kernel(
-    x_ref, w_ref, tgt_ref, logz_ref, gold_ref, m_ref, s_ref, g_ref,
-    *, block_t: int, block_v: int, n_vblocks: int, v: int
+    x_ref, w_ref, tgt_ref, logz_ref, gold_ref, *rest,
+    block_t: int, block_v: int, n_vblocks: int, v: int, with_dx: bool
 ):
+    """One body, two forms: ``with_dx`` adds the f32 ``softmax @ w^T``
+    output to the refs (outputs before scratch). Its block stays in VMEM
+    through a token block's sweep, so the sum accumulates in it."""
+    if with_dx:
+        dx_ref, m_ref, s_ref, g_ref = rest
+    else:
+        m_ref, s_ref, g_ref = rest
     vi = pl.program_id(1)
 
     @pl.when(vi == 0)
@@ -287,6 +320,8 @@ def _fused_ce_fwd_kernel(
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         s_ref[:] = jnp.zeros_like(s_ref)
         g_ref[:] = jnp.zeros_like(g_ref)
+        if with_dx:
+            dx_ref[...] = jnp.zeros_like(dx_ref)
 
     logits, col = _tile_logits(x_ref, w_ref, vi, block_t, block_v, v)
     # online softmax: rescale the running sumexp to the new max. Fully
@@ -294,9 +329,9 @@ def _fused_ce_fwd_kernel(
     # columns, so the final s is positive for every row.
     m_prev = m_ref[:, 0]
     m_cur = jnp.maximum(m_prev, jnp.max(logits, axis=1))
-    s_ref[:, 0] = s_ref[:, 0] * jnp.exp(m_prev - m_cur) + jnp.sum(
-        jnp.exp(logits - m_cur[:, None]), axis=1
-    )
+    alpha = jnp.exp(m_prev - m_cur)
+    p = jnp.exp(logits - m_cur[:, None])
+    s_ref[:, 0] = s_ref[:, 0] * alpha + jnp.sum(p, axis=1)
     m_ref[:, 0] = m_cur
     # the target column lands in exactly one tile: one-hot reduction
     # instead of a gather (pad sentinel -1 matches no column)
@@ -304,19 +339,32 @@ def _fused_ce_fwd_kernel(
     g_ref[:, 0] = g_ref[:, 0] + jnp.sum(
         jnp.where(col == tgt[:, None], logits, 0.0), axis=1
     )
+    if with_dx:
+        # sum_v exp(l_v - m) w_v under the same carry: what it held is
+        # rescaled to the new max, as s is; p goes to the MXU in the
+        # operands' dtype, as chunked_ce._ce_bwd's q does
+        dx_ref[...] = dx_ref[...] * alpha[:, None] + lax.dot_general(
+            p.astype(x_ref.dtype), w_ref[...].astype(x_ref.dtype),
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
 
     @pl.when(vi == n_vblocks - 1)
     def _finalize():
         s = s_ref[:, 0]
-        logz = m_ref[:, 0] + jnp.log(jnp.where(s == 0.0, 1.0, s))
+        s = jnp.where(s == 0.0, 1.0, s)
+        logz = m_ref[:, 0] + jnp.log(s)
         logz_ref[...] = jnp.broadcast_to(logz[:, None], logz_ref.shape)
         gold_ref[...] = jnp.broadcast_to(
             g_ref[:, 0][:, None], gold_ref.shape
         )
+        if with_dx:
+            dx_ref[...] = dx_ref[...] / s[:, None]
 
 
-def _fused_ce_fwd_pallas(x2, w, tgt1, v, bt, bv, interpret):
-    """Padded-operand forward: returns (logz (n_pad,), gold (n_pad,)).
+def _fused_ce_fwd_pallas(x2, w, tgt1, v, bt, bv, interpret, with_dx):
+    """Padded-operand forward: returns (logz (n_pad,), gold (n_pad,))
+    and, ``with_dx``, ``softmax(logits) @ w^T`` (n_pad, d) in f32.
     ``v`` is the REAL vocab width — padded columns beyond it are masked
     to -inf inside the kernel."""
     n_pad, d = x2.shape
@@ -324,79 +372,45 @@ def _fused_ce_fwd_pallas(x2, w, tgt1, v, bt, bv, interpret):
     n_t, n_v = n_pad // bt, v_pad // bv
     kernel = functools.partial(
         _fused_ce_fwd_kernel,
-        block_t=bt, block_v=bv, n_vblocks=n_v, v=v,
+        block_t=bt, block_v=bv, n_vblocks=n_v, v=v, with_dx=with_dx,
     )
-    logz, gold = pl.pallas_call(
+    lane_spec = pl.BlockSpec((bt, _LANES), lambda ti, vi: (ti, 0))
+    lane_shape = jax.ShapeDtypeStruct((n_pad, _LANES), jnp.float32)
+    carry = pltpu.VMEM((bt, 128), jnp.float32)
+    x_spec = pl.BlockSpec((bt, d), lambda ti, vi: (ti, 0))
+    out_specs, out_shape = [lane_spec, lane_spec], [lane_shape, lane_shape]
+    if with_dx:
+        out_specs.append(x_spec)
+        out_shape.append(jax.ShapeDtypeStruct((n_pad, d), jnp.float32))
+    logz, gold, *dx = pl.pallas_call(
         kernel,
         grid=(n_t, n_v),
         in_specs=[
-            pl.BlockSpec((bt, d), lambda ti, vi: (ti, 0)),
+            x_spec,
             pl.BlockSpec((d, bv), lambda ti, vi: (0, vi)),
-            pl.BlockSpec((bt, _LANES), lambda ti, vi: (ti, 0)),
+            lane_spec,
         ],
-        out_specs=[
-            pl.BlockSpec((bt, _LANES), lambda ti, vi: (ti, 0)),
-            pl.BlockSpec((bt, _LANES), lambda ti, vi: (ti, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_pad, _LANES), jnp.float32),
-            jax.ShapeDtypeStruct((n_pad, _LANES), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bt, 128), jnp.float32),
-            pltpu.VMEM((bt, 128), jnp.float32),
-            pltpu.VMEM((bt, 128), jnp.float32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[carry, carry, carry],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(x2, w, _lanes(tgt1))
-    return logz[:, 0], gold[:, 0]
+    return (logz[:, 0], gold[:, 0], *dx)
 
 
 # ---------------------------------------------------------------------------
-# backward kernels
+# backward kernel
 # ---------------------------------------------------------------------------
 #
 # d(nll_sum)/d(logits_tile) = (softmax_tile - onehot_tile) * row_scale,
 # recomputed tile by tile from the O(tokens) logz residual:
 #   p = exp(logits - logz) ; q = (p - onehot) * row_scale
-#   dx = q @ w^T   (token-major: vocab tiles accumulate per token block)
 #   dw = x^T @ q   (vocab-major: token blocks accumulate per vocab tile,
 #                   each dw tile written exactly once — disjoint, like
 #                   the chunked path's dynamic_update_slice chunks)
-
-
-def _bwd_q_tile(x_ref, w_ref, tgt_ref, logz_ref, scale_ref, vi,
-                bt: int, bv: int, v: int):
-    logits, col = _tile_logits(x_ref, w_ref, vi, bt, bv, v)
-    logz = logz_ref[:, 0]
-    p = jnp.exp(logits - logz[:, None])  # padded cols: exp(-inf)=0
-    tgt = tgt_ref[:, 0]
-    onehot = (col == tgt[:, None]).astype(jnp.float32)
-    # cast for the MXU, as chunked_ce._ce_bwd does
-    return ((p - onehot) * scale_ref[:, 0][:, None]).astype(x_ref.dtype)
-
-
-def _fused_ce_dx_kernel(
-    x_ref, w_ref, tgt_ref, logz_ref, scale_ref, dx_ref, acc_ref,
-    *, block_t: int, block_v: int, n_vblocks: int, v: int
-):
-    vi = pl.program_id(1)
-
-    @pl.when(vi == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    q = _bwd_q_tile(x_ref, w_ref, tgt_ref, logz_ref, scale_ref, vi,
-                    block_t, block_v, v)
-    acc_ref[:] = acc_ref[:] + lax.dot_general(
-        q, w_ref[...].astype(q.dtype), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(vi == n_vblocks - 1)
-    def _finalize():
-        dx_ref[...] = acc_ref[:].astype(dx_ref.dtype)
+# dx = q @ w^T needs no kernel here: its softmax term left the forward
+# sweep and its one-hot term is a lookup (_fused_ce_bwd).
 
 
 def _fused_ce_dw_kernel(
@@ -412,8 +426,11 @@ def _fused_ce_dw_kernel(
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    q = _bwd_q_tile(x_ref, w_ref, tgt_ref, logz_ref, scale_ref, vi,
-                    block_t, block_v, v)
+    logits, col = _tile_logits(x_ref, w_ref, vi, block_t, block_v, v)
+    p = jnp.exp(logits - logz_ref[:, 0][:, None])  # padded cols: exp(-inf)=0
+    onehot = (col == tgt_ref[:, 0][:, None]).astype(jnp.float32)
+    # cast for the MXU, as chunked_ce._ce_bwd does
+    q = ((p - onehot) * scale_ref[:, 0][:, None]).astype(x_ref.dtype)
     acc_ref[:] = acc_ref[:] + lax.dot_general(
         x_ref[...], q, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -424,35 +441,15 @@ def _fused_ce_dw_kernel(
         dw_ref[...] = acc_ref[:].astype(dw_ref.dtype)
 
 
-def _fused_ce_bwd_pallas(x2, w, tgt1, logz, row_scale, v, bt, bv,
-                         interpret):
-    """Padded-operand backward: returns (dx (n_pad, d), dw (d, v_pad)).
-    ``v`` is the REAL vocab width (padded-column mask, as in fwd)."""
+def _fused_ce_dw_pallas(x2, w, tgt1, logz, row_scale, v, bt, bv,
+                        interpret):
+    """Padded-operand backward: returns dw (d, v_pad). ``v`` is the REAL
+    vocab width (padded-column mask, as in fwd)."""
     n_pad, d = x2.shape
     v_pad = w.shape[1]
     n_t, n_v = n_pad // bt, v_pad // bv
-    tgt_l, logz_l, scale_l = _lanes(tgt1), _lanes(logz), _lanes(row_scale)
-    lane_spec = pl.BlockSpec((bt, _LANES), lambda ti, vi: (ti, 0))
-    dx = pl.pallas_call(
-        functools.partial(
-            _fused_ce_dx_kernel,
-            block_t=bt, block_v=bv, n_vblocks=n_v, v=v,
-        ),
-        grid=(n_t, n_v),
-        in_specs=[
-            pl.BlockSpec((bt, d), lambda ti, vi: (ti, 0)),
-            pl.BlockSpec((d, bv), lambda ti, vi: (0, vi)),
-            lane_spec, lane_spec, lane_spec,
-        ],
-        out_specs=pl.BlockSpec((bt, d), lambda ti, vi: (ti, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, d), x2.dtype),
-        scratch_shapes=[pltpu.VMEM((bt, d), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS,
-        interpret=interpret,
-    )(x2, w, tgt_l, logz_l, scale_l)
-
-    lane_spec_vm = pl.BlockSpec((bt, _LANES), lambda vi, ti: (ti, 0))
-    dw = pl.pallas_call(
+    lane_spec = pl.BlockSpec((bt, _LANES), lambda vi, ti: (ti, 0))
+    return pl.pallas_call(
         functools.partial(
             _fused_ce_dw_kernel,
             block_t=bt, block_v=bv, n_tblocks=n_t, v=v,
@@ -461,19 +458,35 @@ def _fused_ce_bwd_pallas(x2, w, tgt1, logz, row_scale, v, bt, bv,
         in_specs=[
             pl.BlockSpec((bt, d), lambda vi, ti: (ti, 0)),
             pl.BlockSpec((d, bv), lambda vi, ti: (0, vi)),
-            lane_spec_vm, lane_spec_vm, lane_spec_vm,
+            lane_spec, lane_spec, lane_spec,
         ],
         out_specs=pl.BlockSpec((d, bv), lambda vi, ti: (0, vi)),
         out_shape=jax.ShapeDtypeStruct((d, v_pad), w.dtype),
         scratch_shapes=[pltpu.VMEM((d, bv), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-    )(x2, w, tgt_l, logz_l, scale_l)
-    return dx, dw
+    )(x2, w, _lanes(tgt1), _lanes(logz), _lanes(row_scale))
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def reset_sweep_report():
+    """A step build starts: `ElasticTrainer.lower_step` calls this
+    before it traces, so ``fused_ce.logit_sweeps`` says what that build's
+    losses run, whatever is traced after it."""
+    trace.gauge("fused_ce.logit_sweeps", 0)
+
+
+def _report_sweeps(n: int):
+    """The gauge: products over the whole vocabulary that form logits in
+    the costliest loss traced since the last step build. 1 where nobody
+    differentiates it, 2 in a training step (the forward sweep and the
+    dw kernel). An evaluation or a reference check traced after the
+    step does not lower it."""
+    name = "fused_ce.logit_sweeps"
+    trace.gauge(name, max(trace.gauges().get(name, 0), n))
 
 
 # ---------------------------------------------------------------------------
@@ -487,64 +500,78 @@ def _flatten(x, tgt):
     return x.reshape(n, d), tgt.reshape(n)
 
 
-def _fused_ce_run_fwd(block_t, block_v, interpret, x, w, tgt):
-    """Shared fwd: returns (nll_sum, n_valid, logz (n,) f32 residual)."""
+def _padded(kernel, block_t, block_v, x, w, tgt):
+    """The kernel's tiles for these operands and the operands padded to
+    them: ``(bt, bv, n, x2p, wp, tgt1p)``, ``n`` the real token count."""
+    x2, tgt1 = _flatten(x, tgt)
+    bt, bv, n_pad, v_pad = _tile_geometry(
+        x2.shape[0], w.shape[1], x2.shape[1], x.dtype, w.dtype,
+        block_t, block_v, kernel,
+    )
+    return (bt, bv, x2.shape[0],
+            *_pad_operands(x2, w, tgt1, n_pad, v_pad))
+
+
+def _fused_ce_run_fwd(block_t, block_v, interpret, x, w, tgt, with_dx):
+    """Shared fwd: returns (nll_sum, n_valid, logz (n,) f32 residual)
+    and, ``with_dx``, the f32 ``softmax(logits) @ w^T`` (n, d) residual."""
+    _report_sweeps(1)
     # named scope = the kernel ledger's attribution key
     # (profiler/kernel_ledger.py classifies HLO sites by op_name path)
     with jax.named_scope("fused_ce_fwd"):
-        x2, tgt1 = _flatten(x, tgt)
-        n, v = x2.shape[0], w.shape[1]
-        bt, bv, n_pad, v_pad = _tile_geometry(
-            n, v, x2.shape[1], x.dtype, w.dtype, block_t, block_v,
-            backward=False,
+        bt, bv, n, x2p, wp, tgt1p = _padded(
+            LOSS_DX if with_dx else LOSS, block_t, block_v, x, w, tgt
         )
-        x2p, wp, tgt1p = _pad_operands(x2, w, tgt1, n_pad, v_pad)
-        logz, gold = _fused_ce_fwd_pallas(
-            x2p, wp, tgt1p, v, bt, bv, interpret
+        logz, gold, *dx_soft = _fused_ce_fwd_pallas(
+            x2p, wp, tgt1p, w.shape[1], bt, bv, interpret, with_dx
         )
         logz, gold = logz[:n], gold[:n]
-        vf = (tgt1 >= 0).astype(jnp.float32)
+        vf = (tgt1p[:n] >= 0).astype(jnp.float32)
         nll_sum = jnp.sum((logz - gold) * vf)
         n_valid = jnp.sum(vf)
-    return nll_sum, n_valid, logz
+    return (nll_sum, n_valid, logz, *(a[:n] for a in dx_soft))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
 def _fused_ce(block_t: int, block_v: int, interpret: bool, x, w, tgt):
+    # the primal: a loss nobody differentiates (evaluation, a reference
+    # check) runs the lean sweep, one unit and O(tokens) outputs
     nll_sum, n_valid, _ = _fused_ce_run_fwd(
-        block_t, block_v, interpret, x, w, tgt
+        block_t, block_v, interpret, x, w, tgt, with_dx=False
     )
     return nll_sum, n_valid
 
 
 def _fused_ce_fwd(block_t, block_v, interpret, x, w, tgt):
-    nll_sum, n_valid, logz = _fused_ce_run_fwd(
-        block_t, block_v, interpret, x, w, tgt
+    nll_sum, n_valid, logz, dx_soft = _fused_ce_run_fwd(
+        block_t, block_v, interpret, x, w, tgt, with_dx=True
     )
-    return (nll_sum, n_valid), (x, w, tgt, logz)
+    return (nll_sum, n_valid), (x, w, tgt, logz, dx_soft)
 
 
 def _fused_ce_bwd(block_t, block_v, interpret, res, cot):
     """n_valid carries no float dependence on (x, w); its cotangent is
     dropped — same contract as the chunked path."""
-    x, w, tgt, logz = res
+    x, w, tgt, logz, dx_soft = res
     g_nll, _g_nv = cot
+    _report_sweeps(2)   # the forward's, and dw's
     with jax.named_scope("fused_ce_bwd"):
-        x2, tgt1 = _flatten(x, tgt)
-        n, v = x2.shape[0], w.shape[1]
-        bt, bv, n_pad, v_pad = _tile_geometry(
-            n, v, x2.shape[1], x.dtype, w.dtype, block_t, block_v,
-            backward=True,
+        bt, bv, n, x2p, wp, tgt1p = _padded(
+            DW, block_t, block_v, x, w, tgt
         )
-        x2p, wp, tgt1p = _pad_operands(x2, w, tgt1, n_pad, v_pad)
         vf = (tgt1p >= 0).astype(jnp.float32)
         row_scale = vf * g_nll.astype(jnp.float32)
-        logz_p = jnp.pad(logz, (0, n_pad - n)) if n_pad != n else logz
-        dx, dw = _fused_ce_bwd_pallas(
-            x2p, wp, tgt1p, logz_p, row_scale, v, bt, bv, interpret
-        )
-        dx = dx[:n].reshape(x.shape)
-        dw = dw[:, :v]
+        logz_p = jnp.pad(logz, (0, tgt1p.shape[0] - n))
+        dw = _fused_ce_dw_pallas(
+            x2p, wp, tgt1p, logz_p, row_scale, w.shape[1], bt, bv,
+            interpret,
+        )[:, :w.shape[1]]
+        # dx = (softmax @ w^T - w[:, tgt]^T) * row_scale: the one-hot
+        # term is the targets' columns of the head, exact (a masked
+        # target's is scaled by zero); one f32 pass, rounded once
+        w_tgt = jnp.take(w, tgt1p[:n], axis=1, mode="clip")
+        dx = (dx_soft - w_tgt.T.astype(jnp.float32)) * row_scale[:n, None]
+        dx = dx.astype(x.dtype).reshape(x.shape)
     dtgt = np.zeros(tgt.shape, jax.dtypes.float0)
     return dx, dw, dtgt
 

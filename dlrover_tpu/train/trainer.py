@@ -37,7 +37,7 @@ from dlrover_tpu.common.world import WorldDescriptor
 from dlrover_tpu.lint import retrace_guard
 from dlrover_tpu.observability import trace
 from dlrover_tpu.observability.digest import StepTimeDigest
-from dlrover_tpu.ops import attention, hier_collectives
+from dlrover_tpu.ops import attention, fused_ce, hier_collectives
 from dlrover_tpu.parallel.mesh import MeshConfig
 from dlrover_tpu.parallel.sharding import batch_spec
 from dlrover_tpu.train import live_reshard, warm_compile, zero1
@@ -971,8 +971,10 @@ class ElasticTrainer:
         # of compile spans; warm hits returned above and cost nothing
         t0 = time.perf_counter()
         # the flash kernels choose their tiles while the step is traced
-        # and say so in the attn.* gauges: one build, one count
+        # and say so in the attn.* gauges: one build, one count; the
+        # loss says how often it forms its logits the same way
         attention.reset_tile_report()
+        fused_ce.reset_sweep_report()
         with trace.span("compile", "build.lower", world=mesh.size,
                         source=source, config=config_hash):
             lowered = self._build_step(

@@ -22,6 +22,7 @@ import pytest
 from jax.sharding import NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
+from dlrover_tpu.observability import trace
 from dlrover_tpu.ops import attention, fused_ce, grouped_matmul
 from dlrover_tpu.parallel import MeshConfig, build_mesh
 from dlrover_tpu.parallel.mesh import BATCH_AXES
@@ -247,7 +248,8 @@ def test_grouped_matmul_falls_back_where_shapes_do_not_tile(
 # and Llama-3-70B's d=8192, where the tiles have to shrink as well
 CE_SHAPES = [(2048, 4096, 128256), (8192, 2048, 32768),
              (2048, 8192, 128256),
-             (8192, 2048, 50304)]   # OLMoE's head: vocabulary tile 384
+             (8192, 2048, 50304),   # OLMoE's head: vocabulary tile 384
+             (8192, 4096, 32768)]   # mistral7b-d5-steady's
 
 
 def _ce_args(n, d, v, sharding):
@@ -275,7 +277,8 @@ def test_fused_ce_fwd_bwd_compiles(one_chip, n, d, v):
     hlo = _compile(
         jax.grad(_fused_nll, argnums=(0, 1)), *_ce_args(n, d, v, one_chip)
     )
-    assert hlo.count("tpu_custom_call") == 3  # fwd, dx, dw
+    # the forward sweep that also carries dX's softmax term, and dw
+    assert hlo.count("tpu_custom_call") == 2
     # a vocabulary some multiple of 128 up to the tile divides is not
     # padded to the tile into a copy of the head (50304 -> 50688 at 512)
     assert f",{-(-v // 512) * 512}]" not in hlo or v % 512 == 0
@@ -328,6 +331,9 @@ def test_fused_ce_compiles_over_four_chips(mesh4, kernels_are_the_path):
     def nll(x, w, t):
         return fused_ce.cross_entropy_sums(x, w, t, mesh=mesh4)[0]
 
+    fused_ce.reset_sweep_report()
     hlo = _compile(jax.grad(nll, argnums=(0, 1)), x, w, t)
-    assert hlo.count("tpu_custom_call") == 3
+    assert hlo.count("tpu_custom_call") == 2
     assert "all-gather" in hlo  # the fsdp-sharded head, gathered whole
+    # the gauge says the same of each shard's loss
+    assert trace.gauges()["fused_ce.logit_sweeps"] == 2
