@@ -1,0 +1,2 @@
+"""``hc_mix_ms``: see ``hc_mix_ms.json``."""
+from benchmarks.harness.hlo_scopes import scoped_ms_per_step as read  # noqa: F401

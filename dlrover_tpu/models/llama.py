@@ -415,6 +415,15 @@ def _gather_lm_head(lm_head, fsdp_size, tp_size, marker=False):
     return lm_head
 
 
+def swiglu(y, w_gate, w_up, w_down, dt):
+    """The dense feed-forward every family shares: ``(silu(y Wg) * (y
+    Wu)) Wd``, its two wide activations named for the "mlp" remat
+    policy."""
+    gate = checkpoint_name(jax.nn.silu(y @ w_gate.astype(dt)), "ffn_gate")
+    up = checkpoint_name(y @ w_up.astype(dt), "ffn_up")
+    return (gate * up) @ w_down.astype(dt)
+
+
 def _decoder_layer(cfg: LlamaConfig, mesh, inv_freq, positions, lp, x,
                    attn_fn=None, tp_size=1, tp_mode="native"):
     """One block: pre-norm attention + pre-norm swiglu, residual adds.
@@ -457,9 +466,8 @@ def _decoder_layer(cfg: LlamaConfig, mesh, inv_freq, positions, lp, x,
     y = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     if marker:
         y = _tp_region_in(y, TP)
-    gate = checkpoint_name(jax.nn.silu(y @ lp["w_gate"].astype(dt)), "ffn_gate")
-    up = checkpoint_name(y @ lp["w_up"].astype(dt), "ffn_up")
-    x = x + close_row_parallel((gate * up) @ lp["w_down"].astype(dt))
+    x = x + close_row_parallel(
+        swiglu(y, lp["w_gate"], lp["w_up"], lp["w_down"], dt))
 
     if mesh is not None:
         from jax.sharding import NamedSharding

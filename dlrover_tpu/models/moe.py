@@ -4,10 +4,21 @@ Expert parallelism is green-field relative to the reference (it is only
 checkpoint-aware of Megatron EP ranks, ``megatron_dist_ckpt.py:247``); here
 it is a real compute path, and there is one of it:
 
-- **route** (``moe_route``): router matmul and softmax in float32 over
-  all experts, the ``k`` largest probabilities of a token and their
-  experts; renormalised over the chosen ``k`` only where the model's
-  config says so (``norm_topk_prob``: Mixtral true, OLMoE false).
+- **route** (``moe_route``): router matmul and scores in float32 over
+  all experts (``scoring``: softmax, or a sigmoid an expert), the ``k``
+  largest of a token and their experts, chosen by score plus a
+  per-expert bias where the layer has one (``router_bias``; the weights
+  stay the scores); renormalised over the chosen ``k`` only where the
+  model's config says so (``norm_topk_prob``: Mixtral true, OLMoE
+  false) and scaled by ``routed_scaling``.
+- **the held share** (``experts_held``, ``first_expert``): a chip that
+  holds ``experts_held`` of the ``n_experts`` the router scores (one
+  chip's share of an expert-parallel job) computes the pairs that chose
+  its experts; the others sort into the tail and add nothing. Under a
+  mesh ``ep`` divides the held experts further.
+- **shared expert**: where the layer has ``ws_gate`` / ``ws_up`` /
+  ``ws_down``, every token also passes through that dense SwiGLU
+  (``moe_shared``), added to the routed result.
 - **sorted dispatch** (``moe_dispatch``): the ``t * k`` (token, choice)
   pairs are ordered by expert (one stable sort of int32 keys);
   ``group_sizes (e,)`` counts each expert's pairs and the rows are
@@ -84,6 +95,14 @@ class MoeConfig:
     # their own before rotary (OLMoE)
     norm_topk_prob: bool = True
     qk_norm: bool = False
+    # how the router scores ("softmax" over all experts, or "sigmoid" an
+    # expert) and what the chosen weights are multiplied by
+    scoring: str = "softmax"
+    routed_scaling: float = 1.0
+    # the experts this job holds of the n_experts the router scores:
+    # experts first_expert .. first_expert + experts_held - 1 (None: all)
+    experts_held: Optional[int] = None
+    first_expert: int = 0
     router_aux_coef: float = 0.01
     max_seq_len: int = 8192
     rope_theta: float = 1000000.0
@@ -99,6 +118,11 @@ class MoeConfig:
     @property
     def head_dim(self) -> int:
         return self.dim // self.n_heads
+
+    @property
+    def n_held(self) -> int:
+        return self.n_experts if self.experts_held is None else (
+            self.experts_held)
 
     def as_llama(self) -> llama.LlamaConfig:
         """The attention-relevant view (reused Llama blocks)."""
@@ -153,7 +177,7 @@ def init_params(cfg: MoeConfig, rng: jax.Array) -> Params:
     pd = cfg.param_dtype
     k_embed, k_layers, k_head = jax.random.split(rng, 3)
     std = 0.02
-    L, D, E, F = cfg.n_layers, cfg.dim, cfg.n_experts, cfg.ffn_dim
+    L, D, E, F = cfg.n_layers, cfg.dim, cfg.n_held, cfg.ffn_dim
     H = cfg.n_heads * cfg.head_dim
     KV = cfg.n_kv_heads * cfg.head_dim
 
@@ -169,7 +193,7 @@ def init_params(cfg: MoeConfig, rng: jax.Array) -> Params:
         "wv": norm_init(ks[2], (L, D, KV), std),
         "wo": norm_init(ks[3], (L, H, D), out_scale),
         "mlp_norm": jnp.ones((L, D), pd),
-        "router": norm_init(ks[4], (L, D, E), std),
+        "router": norm_init(ks[4], (L, D, cfg.n_experts), std),
         "w_gate": norm_init(ks[5], (L, E, D, F), std),
         "w_up": norm_init(ks[6], (L, E, D, F), std),
         "w_down": norm_init(ks[7], (L, E, F, D), out_scale),
@@ -226,26 +250,41 @@ def active_param_count(cfg: MoeConfig) -> int:
     """Params touched per token (the 'x7B' in 8x7B marketing math)."""
     total = param_count(cfg)
     expert = 3 * cfg.dim * cfg.ffn_dim * cfg.n_layers
-    return total - expert * (cfg.n_experts - cfg.experts_per_token)
+    return total - expert * (cfg.n_held - cfg.experts_per_token)
 
 
 # ---------------------------------------------------------------------------
 # MoE block: route, sorted dispatch, grouped matmul, combine
 # ---------------------------------------------------------------------------
 
-def route(cfg: MoeConfig, router: jnp.ndarray, yt: jnp.ndarray):
+def route(cfg: MoeConfig, router: jnp.ndarray, yt: jnp.ndarray,
+          bias: Optional[jnp.ndarray] = None):
     """``yt (t, d)`` -> ``(probs (t, e), top_p (t, k), top_e (t, k))``,
     all float32 / int32. The router runs in float32 at full matmul
     precision whatever the activations' dtype: which expert is a token's
-    8th and which its 9th hangs on differences bf16 does not hold."""
+    8th and which its 9th hangs on differences bf16 does not hold.
+    ``bias (e,)`` enters the choice only (``noaux_tc``): the weights are
+    the chosen experts' own scores."""
     logits = jnp.dot(
         yt.astype(jnp.float32), router.astype(jnp.float32),
         precision=lax.Precision.HIGHEST,
     )
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = lax.top_k(probs, cfg.experts_per_token)
+    if cfg.scoring == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    elif cfg.scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"scoring={cfg.scoring!r}: softmax or sigmoid")
+    if bias is None:
+        top_p, top_e = lax.top_k(probs, cfg.experts_per_token)
+    else:
+        _, top_e = lax.top_k(
+            probs + bias.astype(jnp.float32), cfg.experts_per_token)
+        top_p = jnp.take_along_axis(probs, top_e, axis=1)
     if cfg.norm_topk_prob:
         top_p = top_p / top_p.sum(-1, keepdims=True)
+    if cfg.routed_scaling != 1.0:
+        top_p = top_p * cfg.routed_scaling
     return probs, top_p, top_e
 
 
@@ -335,14 +374,14 @@ def _combine_bwd(res, g):
 combine_rows.defvjp(_combine_fwd, _combine_bwd)
 
 
-def _route(cfg: MoeConfig, router, yt, token_axes=()):
+def _route(cfg: MoeConfig, router, yt, token_axes=(), bias=None):
     """Route ``yt (t, d)``: ``(top_p, top_e, aux)``. ``aux`` is the
     load-balancing loss ``E * sum_i f_i P_i`` (1 when routing is
     uniform) over all the tokens of the step: inside a ``shard_map`` the
     counts and the mean probabilities are reduced over ``token_axes``."""
     e = cfg.n_experts
     with jax.named_scope("moe_route"):
-        probs, top_p, top_e = route(cfg, router, yt)
+        probs, top_p, top_e = route(cfg, router, yt, bias)
         counts = jnp.sum(jax.nn.one_hot(top_e, e, dtype=jnp.int32), (0, 1))
         mean_prob = probs.mean(axis=0)
         if token_axes:
@@ -380,7 +419,8 @@ def _moe_tokens_sharded(cfg: MoeConfig, lp: Params, y):
     b, s, d = y.shape
     e_local = lp["w_gate"].shape[0]
     yt = y.reshape(b * s, d)
-    top_p, top_e, aux = _route(cfg, lp["router"], yt, BATCH_AXES + (SP,))
+    top_p, top_e, aux = _route(cfg, lp["router"], yt, BATCH_AXES + (SP,),
+                               lp.get("router_bias"))
     yt, top_p, top_e = (
         lax.all_gather(a, EP, axis=0, tiled=True) for a in (yt, top_p, top_e)
     )
@@ -389,10 +429,18 @@ def _moe_tokens_sharded(cfg: MoeConfig, lp: Params, y):
     # so, and autodiff closes them with a psum (dispatch and combine are
     # custom_vjps, which get no such help by themselves)
     yt, top_p = (lax.pcast(a, TP, to="varying") for a in (yt, top_p))
-    first = lax.axis_index(EP) * e_local
+    first = cfg.first_expert + lax.axis_index(EP) * e_local
     out = _experts(lp, yt, top_p, top_e, e_local, first)
     out = lax.psum_scatter(out, EP, scatter_dimension=0, tiled=True)
     return lax.psum(out, TP).reshape(b, s, d), aux
+
+
+def _shared_expert(lp: Params, y: jnp.ndarray) -> jnp.ndarray:
+    """The always-on expert: the dense SwiGLU of ``models/llama.py`` on
+    every token, partitioned as any dense matmul is."""
+    with jax.named_scope("moe_shared"):
+        return llama.swiglu(
+            y, lp["ws_gate"], lp["ws_up"], lp["ws_down"], y.dtype)
 
 
 def moe_mlp(
@@ -400,34 +448,43 @@ def moe_mlp(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(B, S, D) -> (out (B, S, D), aux_loss scalar)."""
     b, s, d = y.shape
-    _report_shapes(cfg, b * s)
+    _report_shapes(cfg, b * s, "ws_gate" in lp)
     if mesh is None or mesh.size == 1:
         yt = y.reshape(b * s, d)
-        top_p, top_e, aux = _route(cfg, lp["router"], yt)
-        out = _experts(lp, yt, top_p, top_e, cfg.n_experts)
-        return out.reshape(b, s, d), aux
-    weights = {k: lp[k] for k in ("router", "w_gate", "w_up", "w_down")}
-    sharded = shard_map(
-        functools.partial(_moe_tokens_sharded, cfg),
-        mesh=mesh,
-        # fsdp's shards of the weights are gathered on the way in
-        in_specs=(
-            {"router": P(None, None), "w_gate": P(EP, None, TP),
-             "w_up": P(EP, None, TP), "w_down": P(EP, TP, None)},
-            P(BATCH_AXES, SP, None),
-        ),
-        out_specs=(P(BATCH_AXES, SP, None), P()),
-    )
-    return sharded(weights, y)
+        top_p, top_e, aux = _route(
+            cfg, lp["router"], yt, bias=lp.get("router_bias"))
+        out = _experts(lp, yt, top_p, top_e, cfg.n_held, cfg.first_expert)
+        out = out.reshape(b, s, d)
+    else:
+        specs = {"router": P(None, None), "w_gate": P(EP, None, TP),
+                 "w_up": P(EP, None, TP), "w_down": P(EP, TP, None)}
+        if "router_bias" in lp:
+            specs["router_bias"] = P(None)
+        sharded = shard_map(
+            functools.partial(_moe_tokens_sharded, cfg),
+            mesh=mesh,
+            # fsdp's shards of the weights are gathered on the way in
+            in_specs=(specs, P(BATCH_AXES, SP, None)),
+            out_specs=(P(BATCH_AXES, SP, None), P()),
+        )
+        out, aux = sharded({k: lp[k] for k in specs}, y)
+    if "ws_gate" in lp:
+        out = out + _shared_expert(lp, y)
+    return out, aux
 
 
-def _report_shapes(cfg: MoeConfig, tokens: int):
+def _report_shapes(cfg: MoeConfig, tokens: int, shared: bool = False):
     """The gauges that say what the expert layer of this build is given
-    (set while the step is traced, as ``attn.block_q`` is)."""
+    (set while the step is traced, as ``attn.block_q`` is).
+    ``moe.rows_held`` is what uniform routing sends the held experts of
+    a layer: the step's own count hangs on the router."""
     k, e = cfg.experts_per_token, cfg.n_experts
     trace.gauge("moe.experts", e)
     trace.gauge("moe.top_k", k)
     trace.gauge("moe.rows_per_expert", tokens * k / e)
+    trace.gauge("moe.experts_held", cfg.n_held)
+    trace.gauge("moe.rows_held", tokens * k * cfg.n_held / e)
+    trace.gauge("moe.shared_experts", int(shared))
 
 
 def _decoder_layer(cfg: MoeConfig, mesh, inv_freq, positions, lp, x):
@@ -474,9 +531,9 @@ def validate_for_mesh(
             f"shard's own tokens under shard_map"
         )
     ep, tp = shape.get(EP, 1), shape.get(TP, 1)
-    if cfg.n_experts % max(1, ep):
+    if cfg.n_held % max(1, ep):
         raise ValueError(
-            f"n_experts={cfg.n_experts} not divisible by mesh ep={ep}"
+            f"n_experts={cfg.n_held} (held) not divisible by mesh ep={ep}"
         )
     if cfg.ffn_dim % max(1, tp):
         raise ValueError(

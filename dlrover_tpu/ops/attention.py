@@ -80,18 +80,21 @@ def mha_reference_with_lse(
     causal: bool = True,
     q_offset=0,
     k_offset=0,
+    scale: Optional[float] = None,
 ):
     """Stable-softmax attention in float32, GQA-aware; returns
-    ``(out (b,sq,h,d), lse (b,h,sq))``. ``q_offset`` / ``k_offset`` are
+    ``(out (b,sq,h,dv), lse (b,h,sq))``. ``q_offset`` / ``k_offset`` are
     *global* positions of element 0 — this is what lets ring-attention
-    chunks mask causally against each other."""
+    chunks mask causally against each other. ``v`` may be narrower or
+    wider than ``q`` and ``k`` (latent attention: 192 against 128);
+    ``scale`` None is ``1 / sqrt(d)`` of the q/k width."""
     b, sq, h, d = q.shape
     hkv = k.shape[2]
     group = h // hkv
     if group > 1:
         k = jnp.repeat(k, group, axis=2)
         v = jnp.repeat(v, group, axis=2)
-    scale = 1.0 / math.sqrt(d)
+    scale = _scale_for(d, scale)
     qf = q.astype(jnp.float32) * scale
     logits = jnp.einsum("bqhd,bkhd->bhqk", qf, k.astype(jnp.float32))
     if causal:
@@ -105,10 +108,18 @@ def mha_reference_with_lse(
     return out.astype(q.dtype), lse
 
 
-def mha_reference(q, k, v, causal: bool = True, q_offset=0, k_offset=0):
+def mha_reference(q, k, v, causal: bool = True, q_offset=0, k_offset=0,
+                  scale: Optional[float] = None):
     return mha_reference_with_lse(
-        q, k, v, causal=causal, q_offset=q_offset, k_offset=k_offset
+        q, k, v, causal=causal, q_offset=q_offset, k_offset=k_offset,
+        scale=scale,
     )[0]
+
+
+def _scale_for(d: int, scale: Optional[float]) -> float:
+    """The softmax scale: the caller's (latent attention with yarn
+    states its own), else ``1 / sqrt(d)`` of the q/k head width."""
+    return 1.0 / math.sqrt(d) if scale is None else float(scale)
 
 
 # ---------------------------------------------------------------------------
@@ -144,30 +155,32 @@ def _round_up(n: int, m: int) -> int:
 
 
 def _vmem_bytes(kernel: str, bq: int, bk: int, d: int, group: int,
-                itemsize: int) -> int:
+                itemsize: int, dv: Optional[int] = None) -> int:
     """VMEM one grid step of ``kernel`` occupies at tiles ``(bq, bk)``:
     the pipelined (double-buffered) blocks, the scratch accumulators and
     the ``(rows, bk)`` temporaries of the body, minor dims padded to the
-    128 lanes they take."""
+    128 lanes they take. ``d`` is the width of a q/k head, ``dv`` that
+    of a v head (and of out, do and dv), ``d`` where None."""
     dl = _round_up(d, 128)
+    dvl = dl if dv is None else _round_up(dv, 128)
     stat = _STAT_LANES * 4  # one row of lse / delta / running max, f32
     if kernel == "dkv":
         tile = _round_up(bk, 8) * _round_up(bq, 128)
-        blocks = 2 * (2 * bq * dl * itemsize          # q, do
-                      + 4 * bk * dl * itemsize        # k, v, dk, dv
+        blocks = 2 * (bq * (dl + dvl) * itemsize      # q, do
+                      + 2 * bk * (dl + dvl) * itemsize  # k, v, dk, dv
                       + 2 * 8 * _round_up(bq, 128) * 4)  # lse, delta rows
-        scratch = 2 * bk * dl * 4
+        scratch = bk * (dl + dvl) * 4
         return blocks + scratch + tile * (4 * 4 + itemsize)
     rows = group * bq
     tile = _round_up(rows, 8) * _round_up(bk, 128)
     if kernel == "fwd":
-        blocks = 2 * (2 * rows * dl * itemsize        # q, out
-                      + 2 * bk * dl * itemsize        # k, v
+        blocks = 2 * (rows * (dl + dvl) * itemsize    # q, out
+                      + bk * (dl + dvl) * itemsize    # k, v
                       + rows * stat)                  # lse
-        scratch = rows * dl * 4 + 2 * rows * stat     # acc, m, l
+        scratch = rows * dvl * 4 + 2 * rows * stat    # acc, m, l
         return blocks + scratch + tile * (3 * 4 + itemsize)
-    blocks = 2 * (3 * rows * dl * itemsize            # q, do, dq
-                  + 2 * bk * dl * itemsize            # k, v
+    blocks = 2 * (rows * (2 * dl + dvl) * itemsize    # q, dq, do
+                  + bk * (dl + dvl) * itemsize        # k, v
                   + 2 * rows * stat)                  # lse, delta
     scratch = rows * dl * 4
     return blocks + scratch + tile * (4 * 4 + itemsize)
@@ -189,14 +202,16 @@ def _tile_sides(s: int, cap: int, lanes_only: bool):
 
 
 def choose_tiles(kernel: str, sq: int, sk: int, head_dim: int, group: int,
-                 dtype) -> Optional[Tuple[int, int]]:
+                 dtype, v_head_dim: Optional[int] = None
+                 ) -> Optional[Tuple[int, int]]:
     """``(block_q, block_k)`` for one of the three kernels (``"fwd"``,
     ``"dq"``, ``"dkv"``): the pair of largest area, among the sides
     `_tile_sides` offers up to ``_MAX_TILE``, whose `_vmem_bytes` fit
     ``_VMEM_BUDGET``. ``None`` if not even the smallest pair fits (a
     sequence with no aligned divisor that is too long to be one block):
     the caller has the reference path. Causal or not does not enter: on
-    the chip both want the same tiles.
+    the chip both want the same tiles. ``head_dim`` is the q/k head's
+    width, ``v_head_dim`` the v head's where it differs.
 
     Short and awkward sequences come out as before there was a
     chooser: 8 and 64 as one block, 196 and 197 as one block, anything
@@ -215,7 +230,7 @@ def choose_tiles(kernel: str, sq: int, sk: int, head_dim: int, group: int,
     for bk in k_sides:
         for bq in q_sides:
             if _vmem_bytes(kernel, bq, bk, head_dim, group,
-                           itemsize) > _VMEM_BUDGET:
+                           itemsize, v_head_dim) > _VMEM_BUDGET:
                 continue
             # largest area; of equals the squarer, then the wider block_k
             if best is None or (bq * bk, min(bq, bk)) > (
@@ -225,11 +240,13 @@ def choose_tiles(kernel: str, sq: int, sk: int, head_dim: int, group: int,
     return best
 
 
-def flash_tiles(sq: int, sk: int, head_dim: int, group: int, dtype):
+def flash_tiles(sq: int, sk: int, head_dim: int, group: int, dtype,
+                v_head_dim: Optional[int] = None):
     """``{kernel: (block_q, block_k)}`` for the three kernels of one
     call, or None if one of them has no tile that fits."""
     tiles = {
-        kernel: choose_tiles(kernel, sq, sk, head_dim, group, dtype)
+        kernel: choose_tiles(kernel, sq, sk, head_dim, group, dtype,
+                             v_head_dim)
         for kernel in _KERNELS
     }
     return None if None in tiles.values() else tiles
@@ -248,7 +265,7 @@ def reset_tile_report():
     trace.gauge("attn.tile_fallback", 0)
 
 
-def _tiles_for(q, k, block_q, block_k):
+def _tiles_for(q, k, v, block_q, block_k):
     """``{kernel: (block_q, block_k)}`` for this call: a pinned pair
     goes to all three kernels; None (both) is `flash_tiles`' choice,
     made while the step is traced."""
@@ -257,11 +274,12 @@ def _tiles_for(q, k, block_q, block_k):
         return dict.fromkeys(_KERNELS, (block_q, block_k))
     sq, h, d = q.shape[1:]
     sk, hkv = k.shape[1:3]
-    tiles = flash_tiles(sq, sk, d, h // hkv, q.dtype)
+    tiles = flash_tiles(sq, sk, d, h // hkv, q.dtype, v.shape[3])
     if tiles is None:
         raise ValueError(
             f"flash attention: no tile of seq ({sq}, {sk}) at head_dim "
-            f"{d}, group {h // hkv} fits {_VMEM_BUDGET} bytes of VMEM; "
+            f"{d} (v {v.shape[3]}), group {h // hkv} fits {_VMEM_BUDGET} "
+            "bytes of VMEM; "
             "pad the sequence to a multiple of 128 or take mha_reference"
         )
     return tiles
@@ -355,7 +373,8 @@ def _flash_fwd_kernel(
 ):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
-    rows, d = acc_ref.shape
+    rows, dv = acc_ref.shape          # out is as wide as a v head
+    d = q_ref.shape[-1]               # scores run over the q/k width
     last_k = (_last_k_block(qi, block_q, block_k, n_kblocks) if causal
               else n_kblocks - 1)
 
@@ -383,7 +402,7 @@ def _flash_fwd_kernel(
         corr = jnp.exp(m_prev - m_next)
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
         m_ref[...] = m_next
-        acc_ref[...] = acc_ref[...] * _lane_fill(corr, d) + _dot(
+        acc_ref[...] = acc_ref[...] * _lane_fill(corr, dv) + _dot(
             p.astype(v.dtype), v, _NN
         )
 
@@ -391,15 +410,16 @@ def _flash_fwd_kernel(
     def _finalize():
         l = l_ref[...]
         lsafe = jnp.where(l == 0.0, 1.0, l)
-        out = acc_ref[...] / _lane_fill(lsafe, d)
-        o_ref[0, 0] = out.reshape(group, block_q, d).astype(o_ref.dtype)
+        out = acc_ref[...] / _lane_fill(lsafe, dv)
+        o_ref[0, 0] = out.reshape(group, block_q, dv).astype(o_ref.dtype)
         # lse carries a broadcast minor lane dim for TPU block tiling
         # (see _LSE_LANES)
         lse = (m_ref[...] + jnp.log(lsafe))[:, :_LSE_LANES]
         lse_ref[0, 0] = lse.reshape(group, block_q, _LSE_LANES)
 
 
-def _kv_specs(block_k: int, d: int, causal: bool, block_q: int, n_k: int):
+def _kv_specs(block_k: int, d: int, dv: int, causal: bool, block_q: int,
+              n_k: int):
     """K and V BlockSpecs of the (b, hkv, n_q, n_k) grids. Above the
     causal diagonal the index stays on the last block the q block
     needs: the pipeline sees an unchanged index and issues no DMA."""
@@ -408,14 +428,15 @@ def _kv_specs(block_k: int, d: int, causal: bool, block_q: int, n_k: int):
             ki = jnp.minimum(ki, _last_k_block(qi, block_q, block_k, n_k))
         return (bi, hi, ki, 0)
 
-    spec = pl.BlockSpec((1, 1, block_k, d), index)
-    return spec, spec
+    return (pl.BlockSpec((1, 1, block_k, d), index),
+            pl.BlockSpec((1, 1, block_k, dv), index))
 
 
 def _flash_fwd_pallas(q, k, v, causal: bool, block_q: int, block_k: int,
-                      interpret: bool = False):
+                      interpret: bool = False,
+                      scale: Optional[float] = None):
     b, sq, h, d = q.shape
-    sk, hkv = k.shape[1], k.shape[2]
+    sk, hkv, dv = k.shape[1], k.shape[2], v.shape[3]
     group = h // hkv
     block_q, block_k = _clip_tiles(sq, sk, block_q, block_k)
     n_q, n_k = sq // block_q, sk // block_k
@@ -436,25 +457,26 @@ def _flash_fwd_pallas(q, k, v, causal: bool, block_q: int, block_k: int,
         functools.partial(
             _flash_fwd_kernel, group=group, block_q=block_q,
             block_k=block_k, n_kblocks=n_k, causal=causal,
-            scale=1.0 / math.sqrt(d),
+            scale=_scale_for(d, scale),
         ),
         grid=(b, hkv, n_q, n_k),
-        in_specs=[q_rows(d), *_kv_specs(block_k, d, causal, block_q, n_k)],
-        out_specs=[q_rows(d), q_rows(_LSE_LANES)],
+        in_specs=[q_rows(d),
+                  *_kv_specs(block_k, d, dv, causal, block_q, n_k)],
+        out_specs=[q_rows(dv), q_rows(_LSE_LANES)],
         out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, group, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((b, hkv, group, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((b, hkv, group, sq, _LSE_LANES),
                                  jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((rows, d), jnp.float32),
+            pltpu.VMEM((rows, dv), jnp.float32),
             pltpu.VMEM((rows, _STAT_LANES), jnp.float32),
             pltpu.VMEM((rows, _STAT_LANES), jnp.float32),
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
     )(qt, kt, vt)
-    out = out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+    out = out.reshape(b, h, sq, dv).transpose(0, 2, 1, 3)
     return out, lse.reshape(b, h, sq, _LSE_LANES)[..., 0]
 
 
@@ -493,7 +515,7 @@ def _flash_bwd_dq_kernel(
     @_when_needed(causal, qi, ki, block_q, block_k)
     def _compute():
         q = q_ref[0, 0].reshape(rows, d)
-        do = do_ref[0, 0].reshape(rows, d)
+        do = do_ref[0, 0].reshape(rows, do_ref.shape[-1])
         k = k_ref[0, 0]
         v = v_ref[0, 0]
         lse = lse_ref[0, 0].reshape(rows, _LSE_LANES)[:, :1]     # (G*bq, 1)
@@ -561,11 +583,11 @@ def _flash_bwd_dkv_kernel(
 
 
 def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
-                      dq_tiles, dkv_tiles, interpret=False):
+                      dq_tiles, dkv_tiles, interpret=False, scale=None):
     b, sq, h, d = q.shape
-    sk, hkv = k.shape[1], k.shape[2]
+    sk, hkv, dv = k.shape[1], k.shape[2], v.shape[3]
     group = h // hkv
-    scale = 1.0 / math.sqrt(d)
+    scale = _scale_for(d, scale)
 
     # delta rows; the lse cotangent folds in here (see module docstring)
     delta = jnp.einsum(
@@ -600,8 +622,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
         ),
         grid=(b, hkv, n_q, n_k),
         in_specs=[
-            q_rows(d), *_kv_specs(block_k, d, causal, block_q, n_k),
-            q_rows(d), q_rows(_LSE_LANES), q_rows(_LSE_LANES),
+            q_rows(d), *_kv_specs(block_k, d, dv, causal, block_q, n_k),
+            q_rows(dv), q_rows(_LSE_LANES), q_rows(_LSE_LANES),
         ],
         out_specs=q_rows(d),
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, sq, d), q.dtype),
@@ -610,7 +632,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
         interpret=interpret,
     )(
         qt.reshape(b, hkv, group, sq, d), kt, vt,
-        dot.reshape(b, hkv, group, sq, d), lanes8(lse), lanes8(delta),
+        dot.reshape(b, hkv, group, sq, dv), lanes8(lse), lanes8(delta),
     )
 
     # -- dk/dv: kv-head-major grid (b, hkv, n_k, group*n_q): the group's
@@ -632,8 +654,10 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
         bi, head, qi, _ = q_head(bi, hi, i, j)
         return (bi, head, 0, qi)
 
-    kv_block = pl.BlockSpec((1, 1, block_k, d),
+    def kv_block(lanes):
+        return pl.BlockSpec((1, 1, block_k, lanes),
                             lambda bi, hi, i, j: (bi, hi, i, 0))
+
     dkh, dvh = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
@@ -642,19 +666,19 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
         grid=(b, hkv, n_k, group * n_q),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), q_head),
-            kv_block, kv_block,
-            pl.BlockSpec((1, 1, block_q, d), q_head),
+            kv_block(d), kv_block(dv),
+            pl.BlockSpec((1, 1, block_q, dv), q_head),
             pl.BlockSpec((1, 1, 1, block_q), q_head_row),
             pl.BlockSpec((1, 1, 1, block_q), q_head_row),
         ],
-        out_specs=[kv_block, kv_block],
+        out_specs=[kv_block(d), kv_block(dv)],
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((b, hkv, sk, d), v.dtype),
+            jax.ShapeDtypeStruct((b, hkv, sk, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
@@ -672,45 +696,50 @@ def _on_tpu() -> bool:
 # custom_vjp surfaces
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention_with_lse(q, k, v, causal: bool = True,
                              block_q: Optional[int] = None,
                              block_k: Optional[int] = None,
-                             interpret: bool = False):
-    """(out (b,s,h,d), lse (b,h,s)) — both differentiable. ``block_q``
+                             interpret: bool = False,
+                             scale: Optional[float] = None):
+    """(out (b,s,h,dv), lse (b,h,s)) — both differentiable. ``block_q``
     / ``block_k`` None (both): each kernel takes `choose_tiles`' pair;
-    a pinned pair goes to all three."""
-    return _flash_with_lse_fwd(q, k, v, causal, block_q, block_k, interpret)[0]
+    a pinned pair goes to all three. ``v`` heads may have another width
+    than q/k heads; ``scale`` None is ``1 / sqrt`` of the q/k width."""
+    return _flash_with_lse_fwd(q, k, v, causal, block_q, block_k, interpret,
+                               scale)[0]
 
 
-def _flash_with_lse_fwd(q, k, v, causal, block_q, block_k, interpret):
+def _flash_with_lse_fwd(q, k, v, causal, block_q, block_k, interpret,
+                        scale=None):
     # named scope = the kernel ledger's attribution key
     # (profiler/kernel_ledger.py classifies HLO sites by op_name path)
     with jax.named_scope("attention_fwd"):
         if interpret or _on_tpu():
-            tiles = _tiles_for(q, k, block_q, block_k)
+            tiles = _tiles_for(q, k, v, block_q, block_k)
             if block_q is None:
                 _report_tiles(*tiles["fwd"])
             out, lse = _flash_fwd_pallas(q, k, v, causal, *tiles["fwd"],
-                                         interpret=interpret)
+                                         interpret=interpret, scale=scale)
         else:
-            out, lse = mha_reference_with_lse(q, k, v, causal=causal)
+            out, lse = mha_reference_with_lse(q, k, v, causal=causal,
+                                              scale=scale)
     return (out, lse), (q, k, v, out, lse)
 
 
-def _flash_with_lse_bwd(causal, block_q, block_k, interpret, res, g):
+def _flash_with_lse_bwd(causal, block_q, block_k, interpret, scale, res, g):
     q, k, v, o, lse = res
     g_out, g_lse = g
     with jax.named_scope("attention_bwd"):
         if interpret or _on_tpu():
-            tiles = _tiles_for(q, k, block_q, block_k)
+            tiles = _tiles_for(q, k, v, block_q, block_k)
             return _flash_bwd_pallas(
                 q, k, v, o, lse, g_out, g_lse, causal,
-                tiles["dq"], tiles["dkv"], interpret=interpret,
+                tiles["dq"], tiles["dkv"], interpret=interpret, scale=scale,
             )
         _, vjp = jax.vjp(
-            lambda q, k, v: mha_reference_with_lse(q, k, v,
-                                                   causal=causal),
+            lambda q, k, v: mha_reference_with_lse(q, k, v, causal=causal,
+                                                   scale=scale),
             q, k, v,
         )
         return vjp((g_out, g_lse))
@@ -723,7 +752,8 @@ def flash_attention(q, k, v, causal: bool = True,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: bool = False,
-                    mesh: Optional[Mesh] = None):
+                    mesh: Optional[Mesh] = None,
+                    scale: Optional[float] = None):
     """``mesh``: the mesh the caller's jit partitions over. The compiler
     partitions the reference path itself, but not a Mosaic kernel
     ("cannot be automatically partitioned"), so over more than one
@@ -733,7 +763,7 @@ def flash_attention(q, k, v, causal: bool = True,
     stages) pass no mesh."""
     def attn(q, k, v):
         return flash_attention_with_lse(
-            q, k, v, causal, block_q, block_k, interpret
+            q, k, v, causal, block_q, block_k, interpret, scale
         )[0]
 
     if mesh is None or mesh.size == 1 or not (interpret or _on_tpu()):

@@ -472,21 +472,28 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+#: losses traced since the last step build: [lean forwards, backwards]
+_traced = [0, 0]
+
+
 def reset_sweep_report():
     """A step build starts: `ElasticTrainer.lower_step` calls this
     before it traces, so ``fused_ce.logit_sweeps`` says what that build's
     losses run, whatever is traced after it."""
+    _traced[:] = [0, 0]
     trace.gauge("fused_ce.logit_sweeps", 0)
 
 
-def _report_sweeps(n: int):
+def _report_sweeps(differentiated: bool):
     """The gauge: products over the whole vocabulary that form logits in
-    the costliest loss traced since the last step build. 1 where nobody
-    differentiates it, 2 in a training step (the forward sweep and the
-    dw kernel). An evaluation or a reference check traced after the
-    step does not lower it."""
-    name = "fused_ce.logit_sweeps"
-    trace.gauge(name, max(trace.gauges().get(name, 0), n))
+    the losses traced since the last step build. A differentiated loss
+    counts 2 (the forward sweep and the dw kernel), so a training step
+    reads 2 and one with a second head through the same kernel 4; where
+    nothing is differentiated, each lean forward counts 1. An evaluation
+    or a reference check traced after the step changes nothing."""
+    _traced[differentiated] += 1
+    lean, backward = _traced
+    trace.gauge("fused_ce.logit_sweeps", 2 * backward if backward else lean)
 
 
 # ---------------------------------------------------------------------------
@@ -515,7 +522,7 @@ def _padded(kernel, block_t, block_v, x, w, tgt):
 def _fused_ce_run_fwd(block_t, block_v, interpret, x, w, tgt, with_dx):
     """Shared fwd: returns (nll_sum, n_valid, logz (n,) f32 residual)
     and, ``with_dx``, the f32 ``softmax(logits) @ w^T`` (n, d) residual."""
-    _report_sweeps(1)
+    _report_sweeps(False)
     # named scope = the kernel ledger's attribution key
     # (profiler/kernel_ledger.py classifies HLO sites by op_name path)
     with jax.named_scope("fused_ce_fwd"):
@@ -554,7 +561,7 @@ def _fused_ce_bwd(block_t, block_v, interpret, res, cot):
     dropped — same contract as the chunked path."""
     x, w, tgt, logz, dx_soft = res
     g_nll, _g_nv = cot
-    _report_sweeps(2)   # the forward's, and dw's
+    _report_sweeps(True)    # the forward's sweep, and dw's
     with jax.named_scope("fused_ce_bwd"):
         bt, bv, n, x2p, wp, tgt1p = _padded(
             DW, block_t, block_v, x, w, tgt

@@ -38,6 +38,18 @@ from jax import lax
 from dlrover_tpu.ops.attention import _NEG_INF, flash_attention_with_lse
 
 
+def _one_head_width(q, v, who: str):
+    """The sequence-parallel forms merge chunks of one head width at the
+    softmax scale ``1 / sqrt(d)``; latent attention (q/k heads wider
+    than v heads, its own scale) runs through ``flash_attention``."""
+    if q.shape[-1] != v.shape[-1]:
+        raise ValueError(
+            f"{who}: q/k heads of {q.shape[-1]} against v heads of "
+            f"{v.shape[-1]}: two head widths run only through "
+            "flash_attention (no sequence parallelism)"
+        )
+
+
 def ring_attention(
     q: jnp.ndarray,  # (b, s_local, h, d)
     k: jnp.ndarray,  # (b, s_local, hkv, d)
@@ -48,6 +60,7 @@ def ring_attention(
     block_k: Optional[int] = None,
 ) -> jnp.ndarray:
     b, s_local, h, d = q.shape
+    _one_head_width(q, v, "ring_attention")
     n = lax.psum(1, axis_name)
     my_idx = lax.axis_index(axis_name)
     # NB: comm attribution for the ring hops is recorded at the MODEL

@@ -8,6 +8,8 @@ their *global* positions.
 
 from __future__ import annotations
 
+import math
+
 import jax.numpy as jnp
 
 
@@ -17,8 +19,38 @@ def rope_frequencies(head_dim: int, theta: float = 500000.0) -> jnp.ndarray:
     return 1.0 / (theta ** exponents)
 
 
+def yarn_frequencies(
+    rot_dim: int, theta: float, factor: float, original_max: int,
+    beta_fast: float = 32.0, beta_slow: float = 1.0,
+) -> jnp.ndarray:
+    """Yarn's inverse frequencies (arXiv 2309.00071, as ``deepseek_v3``
+    computes them), shape (rot_dim // 2,), float32: pair ``i`` keeps its
+    plain frequency ``theta^(-2i/rot_dim)`` where it turns more than
+    ``beta_fast`` times over the original context, takes that frequency
+    over ``factor`` where it turns less than ``beta_slow`` times, and a
+    linear blend of the two between (``low``, ``high``: the pairs at
+    which it turns exactly so often, floor and ceiling)."""
+    def pair_turning(beta):
+        return (rot_dim * math.log(original_max / (beta * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(pair_turning(beta_fast)), 0)
+    high = min(math.ceil(pair_turning(beta_slow)), rot_dim - 1)
+    plain = rope_frequencies(rot_dim, theta)
+    ramp = (jnp.arange(rot_dim // 2, dtype=jnp.float32) - low) / max(
+        high - low, 0.001)
+    keep = 1.0 - jnp.clip(ramp, 0.0, 1.0)
+    return plain / factor * (1.0 - keep) + plain * keep
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """Yarn's attention-temperature factor ``0.1 m ln(factor) + 1``
+    (1 where the context is not stretched)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
 def apply_rope(
-    x: jnp.ndarray,          # (..., seq, n_heads, head_dim)
+    x: jnp.ndarray,          # (..., seq, n_heads, head_dim): rotated whole
     positions: jnp.ndarray,  # (..., seq) int32 global positions
     inv_freq: jnp.ndarray,   # (head_dim // 2,)
 ) -> jnp.ndarray:

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from dlrover_tpu.ops.attention import flash_attention
+from dlrover_tpu.ops.ring_attention import _one_head_width
 
 
 def _a2a_scatter_heads(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
@@ -71,6 +72,7 @@ def ulysses_attention(
     ``axis_name``; returns the output in the same layout. Differentiable
     end to end (all_to_all is linear; the flash kernel carries its own
     VJP)."""
+    _one_head_width(q, v, "ulysses_attention")
     sp = lax.axis_size(axis_name)
     if sp == 1:
         return flash_attention(q, k, v, causal=causal,

@@ -4,12 +4,14 @@
     dlrover-tpu-run --standalone --nnodes=1 --nproc_per_node=1 \
         --accelerator=cpu examples/moe_pretrain.py --model olmoe
 
-``--model mixtral|olmoe`` picks the family's conventions (Mixtral: top-2
-of 8, renormalised; OLMoE: top-8 of 64, not renormalised, QK-norm) at a
-toy size; ``--full`` takes the published widths of the preset instead.
-Experts shard over the ``ep`` mesh axis; routing is dropless (sorted
-dispatch into a grouped matmul, models/moe.py) and the rows travel over
-ep inside the jitted step.
+``--model mixtral|olmoe|xing4`` picks the family's conventions (Mixtral:
+top-2 of 8, renormalised; OLMoE: top-8 of 64, not renormalised, QK-norm;
+Xing4.0: latent attention, four residual streams, sigmoid top-4 of 64
+with a shared expert, a leading dense layer and a multi-token head,
+models/xing4.py) at a toy size; ``--full`` takes the published widths of
+the preset instead. Experts shard over the ``ep`` mesh axis; routing is
+dropless (sorted dispatch into a grouped matmul, models/moe.py) and the
+rows travel over ep inside the jitted step.
 """
 
 import argparse
@@ -26,7 +28,7 @@ ctx = dtrain.init(local_device_count=int(_n) if _n else None)
 import jax
 
 from dlrover_tpu.checkpoint.checkpointer import Checkpointer
-from dlrover_tpu.models import moe
+from dlrover_tpu.models import moe, xing4
 from dlrover_tpu.parallel import MeshConfig, build_mesh, named_shardings
 from dlrover_tpu.train.trainer import ElasticTrainer, TrainConfig
 
@@ -39,26 +41,34 @@ mc = MeshConfig(dp=-1, fsdp=1, ep=ep, sp=1, tp=1).resolve(n_dev)
 mesh = build_mesh(mc)
 
 ap = argparse.ArgumentParser()
-ap.add_argument("--model", choices=("mixtral", "olmoe"), default="mixtral")
+ap.add_argument("--model", choices=("mixtral", "olmoe", "xing4"),
+                default="mixtral")
 ap.add_argument("--full", action="store_true",
                 help="the preset's published widths, not the toy size")
 args = ap.parse_args()
 
-preset = {"mixtral": moe.MoeConfig.mixtral_8x7b,
-          "olmoe": moe.MoeConfig.olmoe_1b_7b}[args.model]()
-if args.full:
-    cfg = preset
+if args.model == "xing4":
+    family = xing4
+    cfg = (xing4.Xing4Config() if args.full
+           else xing4.Xing4Config.tiny(max_seq_len=SEQ))
 else:
-    # the family's conventions and its experts-to-choices ratio, toy widths
-    cfg = moe.MoeConfig.tiny(
-        n_heads=4, n_kv_heads=2, max_seq_len=SEQ,
-        n_experts=min(preset.n_experts, 16),
-        experts_per_token=min(preset.experts_per_token, 4),
-        norm_topk_prob=preset.norm_topk_prob, qk_norm=preset.qk_norm,
-    )
-specs = moe.param_specs(cfg)
+    family = moe
+    preset = {"mixtral": moe.MoeConfig.mixtral_8x7b,
+              "olmoe": moe.MoeConfig.olmoe_1b_7b}[args.model]()
+    if args.full:
+        cfg = preset
+    else:
+        # the family's conventions and its experts-to-choices ratio, toy
+        # widths
+        cfg = moe.MoeConfig.tiny(
+            n_heads=4, n_kv_heads=2, max_seq_len=SEQ,
+            n_experts=min(preset.n_experts, 16),
+            experts_per_token=min(preset.experts_per_token, 4),
+            norm_topk_prob=preset.norm_topk_prob, qk_norm=preset.qk_norm,
+        )
+specs = family.param_specs(cfg)
 params = jax.jit(
-    lambda k: moe.init_params(cfg, k),
+    lambda k: family.init_params(cfg, k),
     out_shardings=named_shardings(mesh, specs),
 )(jax.random.key(0))
 
@@ -67,7 +77,7 @@ tc = TrainConfig(
     total_steps=STEPS,
 )
 trainer = ElasticTrainer(
-    lambda p, t: moe.loss_fn(p, t, cfg, mesh), specs, mesh, mc, tc,
+    lambda p, t: family.loss_fn(p, t, cfg, mesh), specs, mesh, mc, tc,
     worker_ctx=ctx,
 )
 state = trainer.init_state(params)

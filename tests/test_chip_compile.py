@@ -227,6 +227,69 @@ def test_olmoe_expert_layer_fwd_bwd_compiles(one_chip, kernels_are_the_path):
     assert "[8192,64," not in hlo
 
 
+# xing4-ep8-1chip-steady (PR 31): latent attention's kernels take q/k
+# heads of 192 against v heads of 128 and the scale yarn states; and one
+# whole expert block of the step at the published widths (four streams
+# of 2 x 4096 x 3584, ranks 768 / 512, 8 held experts of 64, the shared
+# expert), forward and backward, remat as the cell runs it.
+def test_flash_two_widths_compiles_at_chosen_tiles(
+        one_chip, kernels_are_the_path):
+    tiles = attention.flash_tiles(4096, 4096, 192, 1, jnp.bfloat16, 128)
+    assert all(min(t) > 128 for t in tiles.values()), tiles
+    qk = jax.ShapeDtypeStruct((2, 4096, 32, 192), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((2, 4096, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        out = attention.flash_attention(q, k, v, scale=0.1447)
+        assert out.shape == (2, 4096, 32, 128)
+        return out.astype(jnp.float32).sum()
+
+    hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), qk, qk, v)
+    assert hlo.count("tpu_custom_call") == 3  # fwd, dq, dk/dv
+
+
+def test_xing4_expert_block_fwd_bwd_compiles(one_chip, kernels_are_the_path):
+    from dlrover_tpu.models import xing4
+    from dlrover_tpu.ops import yarn_frequencies
+
+    cfg = xing4.Xing4Config(
+        vocab_size=16384, n_dense_layers=1, n_moe_layers=1, experts_held=8,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    layers = xing4.abstract_params(cfg)["layers"]
+    lp = jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(l.shape[1:], l.dtype,
+                                       sharding=one_chip), layers)
+    X = jax.ShapeDtypeStruct((4, 2, 4096, cfg.dim), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(lp, X):
+        positions = jnp.broadcast_to(jnp.arange(4096, dtype=jnp.int32),
+                                     (2, 4096))
+        inv_freq = yarn_frequencies(64, 10000.0, 64.0, 4096)
+        fn = jax.checkpoint(
+            lambda lp, X: xing4.block(cfg, None, positions, inv_freq, lp, X),
+            policy=jax.checkpoint_policies.nothing_saveable)
+        return fn(lp, X).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(lp, X).compile()
+    hlo = compiled.as_text()
+    # the remat forward is the only forward here (nothing else wants the
+    # block's output): 1 + 2 of attention, and forward, d-lhs and d-rhs
+    # of each of the three grouped products
+    assert _kernel_calls(hlo, "attention_fwd") == 1
+    assert _kernel_calls(hlo, "attention_bwd") == 2
+    assert _kernel_calls(hlo, "grouped_matmul_dlhs") == 3
+    assert _kernel_calls(hlo, "grouped_matmul_drhs") == 3
+    assert _kernel_calls(hlo, "grouped_matmul") == 9
+    assert "[8192,64,8" not in hlo  # no (tokens, experts, ...) dispatch tensor
+    # a block's own temporaries fit beside the cell's state and carries
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2**30
+    assert trace.gauges()["moe.rows_held"] == 4096
+    assert cfg.softmax_scale == pytest.approx(0.14468, rel=1e-4)
+
+
 def test_grouped_matmul_falls_back_where_shapes_do_not_tile(
         one_chip, kernels_are_the_path):
     # an expert width that is no multiple of 128: the compiler's own

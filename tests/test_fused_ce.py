@@ -250,6 +250,28 @@ def test_undifferentiated_loss_runs_the_lean_forward(xwt):
     assert trace.gauges()["fused_ce.logit_sweeps"] == 2
 
 
+def test_two_heads_through_one_kernel_read_four_sweeps(xwt):
+    """A step with a second loss through the same head (a multi-token
+    module) forms its logits four times: the gauge counts every
+    differentiated loss of the build, not the costliest one."""
+    from dlrover_tpu.observability import trace
+
+    x, w, t = xwt
+    x, t = x.reshape(B * T, D), t.reshape(B * T)
+
+    def two_heads(x, w):
+        nll = lambda x: fused_cross_entropy(
+            x, w, t, block_t=8, block_v=128, interpret=True)[0]
+        return nll(x) + 0.3 * nll(x * 0.5)
+
+    fused_ce.reset_sweep_report()
+    jax.make_jaxpr(two_heads)(x, w)
+    assert trace.gauges()["fused_ce.logit_sweeps"] == 2   # two lean forwards
+    fused_ce.reset_sweep_report()
+    jax.make_jaxpr(jax.grad(two_heads, argnums=(0, 1)))(x, w)
+    assert trace.gauges()["fused_ce.logit_sweeps"] == 4
+
+
 def test_all_tokens_masked(xwt):
     x, w, _ = xwt
     t = jnp.full((B, T), -1, jnp.int32)
