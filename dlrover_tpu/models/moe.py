@@ -484,6 +484,7 @@ def _report_shapes(cfg: MoeConfig, tokens: int, shared: bool = False):
     trace.gauge("moe.rows_per_expert", tokens * k / e)
     trace.gauge("moe.experts_held", cfg.n_held)
     trace.gauge("moe.rows_held", tokens * k * cfg.n_held / e)
+    trace.gauge("moe.tail_rows", tokens * k * (e - cfg.n_held) / e)
     trace.gauge("moe.shared_experts", int(shared))
 
 

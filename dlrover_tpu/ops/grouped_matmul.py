@@ -4,8 +4,11 @@
 the rows of ``lhs`` come sorted by group, group ``i`` has
 ``group_sizes[i]`` of them, and each row is multiplied by its group's
 matrix. Rows past ``sum(group_sizes)`` belong to no group and come out
-zero (models/moe.py's ep path leaves other ranks' pairs there). It is a
-``jax.custom_vjp`` over three products of the same FLOPs:
+zero, in the forward and in d-lhs (models/moe.py sorts there the pairs
+that chose an expert this rank does not hold, (ep - 1) / ep of all
+pairs, and ``combine_rows`` gathers them with non-zero weights: the
+zeros are load-bearing). It is a ``jax.custom_vjp`` over three products
+of the same FLOPs:
 
 - **forward**  ``out[rows_i] = lhs[rows_i] @ rhs[i]``;
 - **d-lhs**    ``d_lhs[rows_i] = d_out[rows_i] @ rhs[i]^T``: the same
@@ -20,18 +23,28 @@ How the kernels walk the rows (the shape of JAX's own
 
 - **row tiles and visits.** Rows are cut into tiles of ``block_m``. A
   tile that holds rows of several groups is *visited* once for each, and
-  a visit computes the whole tile against one group's matrix and keeps
+  a group's visit computes the whole tile against its matrix and keeps
   only that group's rows. The visits (tile, group), in row order, are
   worked out from ``group_sizes`` with a few ``jnp`` operations on
   ``g``-long vectors and handed to the kernel as scalar-prefetch
   operands, so the ``index_map``s pick the row tile and the group's
   matrix for each grid step. Their number depends on the data; its
-  static bound, ``m / block_m + g``, is the grid, and steps past the
+  static bound, ``m / block_m + g + 1``, is the grid, and steps past the
   last real visit do nothing. A visit to a tile that straddles groups is
   work done twice, so ``block_m`` is what decides the kernel's share of
   the MXU at ~1000 rows a group: 63 of 191 visits are repeats at 512
   rows a tile (what the compiler's own ``ragged_dot`` kernel walks: 44-50
   % of the v5e's peak, PERF.md section 6, PR 27), 63 of 319 at 256.
+- **the tail costs its zeros.** The forward and d-lhs walks count the
+  rows of no group as one more group, so that their tiles are visited;
+  such a visit forms no product and asks for no operand block (``lhs``'s
+  ``index_map`` stays on the row tile the last real visit fetched,
+  ``rhs``'s on the last group's panel): it stores zeros in its rows of
+  the output tile, and the pipeline moves that tile out and nothing in:
+  0.75-0.93 us against a live visit's 10.2 where 112 of a call's 135
+  visits are the tail's (v5e, docs/design/kernels.md 1c). d-rhs never
+  visits the tail. The kernel tells a tail visit by its group id, visit
+  by visit: a call without a tail never takes the branch.
 - **no k loop.** A visit multiplies a ``(block_m, k)`` tile of rows by a
   whole ``(k, block_n)`` panel of the group's matrix (``k`` is 2048 or
   1024 here): one MXU pass sequence, f32 accumulation, one store. The
@@ -162,6 +175,15 @@ def _row_mask(offsets_ref, group, tile, block_m: int, width: int):
 # forward and d-lhs: walk the row tiles, one group's panel a visit
 # ---------------------------------------------------------------------------
 
+def _lhs_tile(offsets, tile, n_groups: int, block_m: int):
+    """The row tile of ``lhs`` a visit to ``tile`` asks for: its own,
+    but the tail's visits stay on the last tile that holds a row of a
+    group (the block the last real visit fetched), so the pipeline moves
+    nothing in for them. ``offsets[n_groups]`` is ``sum(group_sizes)``."""
+    live = offsets[n_groups]
+    return jnp.minimum(tile, jnp.maximum(live - 1, 0) // block_m)
+
+
 def _gmm_kernel(offsets_ref, group_ref, tile_ref, visits_ref,
                 lhs_ref, rhs_ref, out_ref, *, block_m: int, n_groups: int,
                 dims):
@@ -170,24 +192,34 @@ def _gmm_kernel(offsets_ref, group_ref, tile_ref, visits_ref,
 
     @pl.when(v < visits_ref[0])
     def _visit():
-        acc = lax.dot_general(lhs_ref[...], rhs_ref[...], dims,
-                              preferred_element_type=jnp.float32)
         start, stop = offsets_ref[group], offsets_ref[group + 1]
         whole = (start <= tile * block_m) & (stop >= (tile + 1) * block_m)
         in_group = group < n_groups      # else the tail: rows of no group
 
-        @pl.when(whole & in_group)
-        def _():
-            out_ref[...] = acc.astype(out_ref.dtype)
-
-        @pl.when(jnp.logical_not(whole & in_group))
-        def _():
-            # a tile other groups share: keep what their visits wrote
+        def store(value):
+            # a tile other groups share keeps what their visits wrote
             # (the block stays in VMEM between visits to one tile)
-            mask = _row_mask(offsets_ref, group, tile, block_m,
-                             out_ref.shape[1])
-            mine = jnp.where(in_group, acc, 0.0).astype(out_ref.dtype)
-            out_ref[...] = jnp.where(mask, mine, out_ref[...])
+            @pl.when(whole)
+            def _():
+                out_ref[...] = value
+
+            @pl.when(jnp.logical_not(whole))
+            def _():
+                mask = _row_mask(offsets_ref, group, tile, block_m,
+                                 out_ref.shape[1])
+                out_ref[...] = jnp.where(mask, value, out_ref[...])
+
+        @pl.when(in_group)
+        def _():
+            acc = lax.dot_general(lhs_ref[...], rhs_ref[...], dims,
+                                  preferred_element_type=jnp.float32)
+            store(acc.astype(out_ref.dtype))
+
+        @pl.when(jnp.logical_not(in_group))
+        def _():
+            # rows of no group cost their zeros: no product, and no
+            # operand block is read (the index_maps stay where they were)
+            store(jnp.zeros(out_ref.shape, out_ref.dtype))
 
 
 def _gmm(lhs, rhs, group_sizes, *, transpose_rhs: bool, tiles, interpret):
@@ -196,7 +228,7 @@ def _gmm(lhs, rhs, group_sizes, *, transpose_rhs: bool, tiles, interpret):
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     block_m, block_n = tiles[0], tiles[2 if transpose_rhs else 1]
     meta = _visits(group_sizes, m, block_m, tail=True, empty=False)
-    last = g - 1   # the tail's visits fetch the last group's panel, unused
+    last = g - 1   # the tail's visits stay on the last group's panel
     if transpose_rhs:
         rhs_spec = pl.BlockSpec(
             (None, block_n, k),
@@ -215,8 +247,10 @@ def _gmm(lhs, rhs, group_sizes, *, transpose_rhs: bool, tiles, interpret):
             num_scalar_prefetch=4,
             grid=(n // block_n, meta[1].shape[0]),
             in_specs=[
-                pl.BlockSpec((block_m, k),
-                             lambda ni, v, off, grp, til, nv: (til[v], 0)),
+                pl.BlockSpec(
+                    (block_m, k),
+                    lambda ni, v, off, grp, til, nv: (
+                        _lhs_tile(off, til[v], g, block_m), 0)),
                 rhs_spec,
             ],
             out_specs=pl.BlockSpec(

@@ -287,7 +287,30 @@ def test_xing4_expert_block_fwd_bwd_compiles(one_chip, kernels_are_the_path):
     # a block's own temporaries fit beside the cell's state and carries
     assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2**30
     assert trace.gauges()["moe.rows_held"] == 4096
+    assert trace.gauges()["moe.tail_rows"] == 28672
     assert cfg.softmax_scale == pytest.approx(0.14468, rel=1e-4)
+
+
+def test_grouped_matmul_compiles_at_xing4_shape(
+        one_chip, kernels_are_the_path):
+    # one grouped product of that block alone, forward and backward:
+    # 32768 rows of which the 8 held experts own what the router sends
+    # (the rest is the tail the kernels only zero), 3584 -> 1024, bf16
+    from dlrover_tpu.ops.grouped_matmul import grouped_matmul
+
+    x = jax.ShapeDtypeStruct((32768, 3584), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((8, 3584, 1024), jnp.bfloat16,
+                             sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+
+    def loss(x, w, sizes):
+        return grouped_matmul(x, w, sizes).astype(jnp.float32).sum()
+
+    hlo = _compile(jax.value_and_grad(loss, argnums=(0, 1)), x, w, sizes)
+    assert _kernel_calls(hlo, "grouped_matmul_dlhs") == 1
+    assert _kernel_calls(hlo, "grouped_matmul_drhs") == 1
+    assert _kernel_calls(hlo, "grouped_matmul") == 3
+    assert "ragged-dot" not in hlo
 
 
 def test_grouped_matmul_falls_back_where_shapes_do_not_tile(

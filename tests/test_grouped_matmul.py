@@ -1,7 +1,8 @@
 """The grouped-matmul Pallas kernels (ops/grouped_matmul.py) in
 interpreter mode against ``lax.ragged_dot`` and its autodiff: forward,
 d-lhs and d-rhs, over group sizes that straddle row tiles, leave groups
-empty, pile every row on one group, or leave a tail of rows in none."""
+empty, pile every row on one group, or leave a tail of rows in none
+(which the forward and d-lhs walks zero without reading or multiplying)."""
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +20,7 @@ GROUPS = {
     "one_takes_all": [0, 0, 512, 0, 0, 0],
     "tail": [100, 3, 0, 61, 40, 9],            # 299 rows of no group
     "all_tail": [0, 0, 0, 0, 0, 0],
+    "tail_on_a_tile_edge": [100, 28, 0, 64, 60, 4],   # 256 live rows
     "tiny_groups": [1, 2, 3, 500, 5, 1],
 }
 
@@ -95,6 +97,68 @@ def test_visits_cover_every_row_once(name):
     _, groups, _, num = (np.asarray(a) for a in gm._visits(
         jnp.asarray(sizes, jnp.int32), M, block, tail=False, empty=True))
     assert set(groups[: int(num[0])]) == set(range(G))
+
+
+TAILS = ["tail", "all_tail", "tail_on_a_tile_edge"]
+NO_TAIL = {   # the (group, tile) visits at 128 rows a tile, as PR 27 walked
+    "even": [(0, 0), (1, 0), (1, 1), (2, 1), (2, 2), (3, 2), (4, 2),
+             (4, 3), (5, 3)],
+    "aligned": [(0, 0), (0, 1), (2, 2), (2, 3)],
+    "one_takes_all": [(2, 0), (2, 1), (2, 2), (2, 3)],
+}
+
+
+@pytest.mark.parametrize("name", TAILS)
+def test_tail_rows_are_zeroed_unread(name):
+    # NaN in the rows of no group, operand and cotangent: a walk that
+    # multiplied them into a kept row, or passed them on, would show it
+    lhs, rhs = _operands(seed=3)
+    sizes = jnp.asarray(GROUPS[name], jnp.int32)
+    total = int(sizes.sum())
+    ct = jax.random.normal(jax.random.key(4), (M, N))
+    poison = lambda a: a.at[total:].set(jnp.nan)
+    with jax.default_matmul_precision("highest"):
+        out, vjp = jax.vjp(
+            lambda a: gm.grouped_matmul(a, rhs, sizes, interpret=True),
+            poison(lhs))
+        d_lhs, = vjp(poison(ct))
+        want, want_vjp = jax.vjp(lambda a: _reference(a, rhs, sizes), lhs)
+        want_d_lhs, = want_vjp(ct)
+    for got, ref in ((out, want), (d_lhs, want_d_lhs)):
+        assert not np.asarray(got[total:]).any()
+        np.testing.assert_allclose(got[:total], ref[:total],
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("name", sorted(GROUPS))
+def test_tail_visits_ask_for_no_operand_block(name):
+    sizes = jnp.asarray(GROUPS[name], jnp.int32)
+    block = 128
+    offsets, groups, tiles, num = gm._visits(
+        sizes, M, block, tail=True, empty=False)
+    lhs_tiles = np.asarray(gm._lhs_tile(offsets, tiles, G, block))
+    groups, tiles, num = np.asarray(groups), np.asarray(tiles), int(num[0])
+    real = groups[:num] < G                 # the visits that form a product
+    live = int(real.sum())
+    assert real[:live].all()                # the tail's come last
+    np.testing.assert_array_equal(lhs_tiles[:live], tiles[:live])
+    # past the last real visit the lhs block never changes, whatever
+    # output tile the visit zeroes (rhs's stays on the last group's panel)
+    assert (lhs_tiles[live:] == (lhs_tiles[live - 1] if live else 0)).all()
+    _, _, _, no_tail = gm._visits(sizes, M, block, tail=False, empty=False)
+    assert live == int(no_tail[0])
+
+
+@pytest.mark.parametrize("name", sorted(NO_TAIL))
+def test_walk_without_a_tail_is_what_it_was(name):
+    sizes = jnp.asarray(GROUPS[name], jnp.int32)
+    offsets, groups, tiles, num = gm._visits(
+        sizes, M, 128, tail=True, empty=False)
+    num = int(num[0])
+    walk = list(zip(np.asarray(groups)[:num].tolist(),
+                    np.asarray(tiles)[:num].tolist()))
+    assert walk == NO_TAIL[name]            # no visit of group G
+    np.testing.assert_array_equal(gm._lhs_tile(offsets, tiles, G, 128), tiles)
 
 
 def test_tiles_come_from_the_shapes():

@@ -290,6 +290,7 @@ def test_gauges_say_what_the_build_is(built):
     assert g["hc.streams"] == 4 and g["hc.sinkhorn_iters"] == 20
     assert g["moe.experts"] == 8 and g["moe.experts_held"] == 4
     assert g["moe.rows_held"] == 2 * 48 * 2 * 4 / 8
+    assert g["moe.tail_rows"] == 2 * 48 * 2 - g["moe.rows_held"]
     assert g["moe.shared_experts"] == 1
     assert g["mtp.depth"] == 1 and g["mtp.loss_weight"] == 0.3
 
