@@ -418,23 +418,39 @@ def hc_sublayer(cfg: Xing4Config, lp: Params, name: str, X, fn):
 # Latent attention, the block, the forward
 # ---------------------------------------------------------------------------
 
-def latent_attention(cfg: Xing4Config, mesh, positions, inv_freq, lp, y):
+def latent_attention(cfg, mesh, positions, inv_freq, lp, y):
+    """Latent attention of ``y (b, s, d)``, already pre-normed: q heads
+    of ``qk_nope_dim + qk_rope_dim``, k and v through one bottleneck of
+    rank ``kv_lora_rank``, the last ``qk_rope_dim`` of a key one vector
+    for all heads. The one function of its kind: ``cfg`` is any config
+    with those widths, ``dtype``, ``norm_eps``, ``softmax_scale`` and,
+    where there is rotary, ``rope_magnitude`` (this family's,
+    ``models/kimi_linear.py``'s). q
+    goes through a rank bottleneck with its own norm where ``lp`` has
+    ``w_qa`` / ``w_qb`` and through one matrix ``w_q`` where not;
+    ``inv_freq`` None leaves the ``qk_rope_dim`` part without rotary."""
     dt, eps = cfg.dtype, cfg.norm_eps
     b, s, _ = y.shape
     h, rkv = cfg.n_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     with jax.named_scope("mla_proj"):
-        c_q = rms_norm(y @ lp["w_qa"].astype(dt), lp["q_a_norm"], eps)
-        q = (c_q @ lp["w_qb"].astype(dt)).reshape(b, s, h, dn + dr)
+        if "w_qa" in lp:
+            c_q = rms_norm(y @ lp["w_qa"].astype(dt), lp["q_a_norm"], eps)
+            q = c_q @ lp["w_qb"].astype(dt)
+        else:
+            q = y @ lp["w_q"].astype(dt)
+        q = q.reshape(b, s, h, dn + dr)
         kva = y @ lp["w_kva"].astype(dt)
         c_kv = rms_norm(kva[..., :rkv], lp["kv_a_norm"], eps)
         kv = (c_kv @ lp["w_kvb"].astype(dt)).reshape(b, s, h, dn + dv)
-        q_rope = apply_rope(q[..., dn:], positions, inv_freq)
-        k_rope = apply_rope(kva[:, :, None, rkv:], positions, inv_freq)
-        if cfg.rope_magnitude != 1.0:
-            q_rope = q_rope * cfg.rope_magnitude
-            k_rope = k_rope * cfg.rope_magnitude
-        q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
+        k_rope = kva[:, :, None, rkv:]
+        if inv_freq is not None:
+            q_rope = apply_rope(q[..., dn:], positions, inv_freq)
+            k_rope = apply_rope(k_rope, positions, inv_freq)
+            if cfg.rope_magnitude != 1.0:
+                q_rope = q_rope * cfg.rope_magnitude
+                k_rope = k_rope * cfg.rope_magnitude
+            q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
         # the rotary part of a key is one vector for all heads
         k = jnp.concatenate(
             [kv[..., :dn], jnp.broadcast_to(k_rope, (b, s, h, dr))], axis=-1)

@@ -4,12 +4,14 @@
     dlrover-tpu-run --standalone --nnodes=1 --nproc_per_node=1 \
         --accelerator=cpu examples/moe_pretrain.py --model olmoe
 
-``--model mixtral|olmoe|xing4`` picks the family's conventions (Mixtral:
-top-2 of 8, renormalised; OLMoE: top-8 of 64, not renormalised, QK-norm;
-Xing4.0: latent attention, four residual streams, sigmoid top-4 of 64
-with a shared expert, a leading dense layer and a multi-token head,
-models/xing4.py) at a toy size; ``--full`` takes the published widths of
-the preset instead. Experts shard over the ``ep`` mesh axis; routing is
+``--model mixtral|olmoe|xing4|kimi_linear`` picks the family's
+conventions (Mixtral: top-2 of 8, renormalised; OLMoE: top-8 of 64, not renormalised,
+QK-norm; Xing4.0: latent attention, four residual streams, sigmoid top-4
+of 64 with a shared expert, a leading dense layer and a multi-token head,
+models/xing4.py; Kimi-Linear: three gated-delta-rule layers to one
+latent-attention layer without rotary in one layer pattern, sigmoid top-8
+of 256 with a shared expert, models/kimi_linear.py) at a toy size;
+``--full`` takes the published widths of the preset instead. Experts shard over the ``ep`` mesh axis; routing is
 dropless (sorted dispatch into a grouped matmul, models/moe.py) and the
 rows travel over ep inside the jitted step.
 """
@@ -28,7 +30,7 @@ ctx = dtrain.init(local_device_count=int(_n) if _n else None)
 import jax
 
 from dlrover_tpu.checkpoint.checkpointer import Checkpointer
-from dlrover_tpu.models import moe, xing4
+from dlrover_tpu.models import kimi_linear, moe, xing4
 from dlrover_tpu.parallel import MeshConfig, build_mesh, named_shardings
 from dlrover_tpu.train.trainer import ElasticTrainer, TrainConfig
 
@@ -41,7 +43,8 @@ mc = MeshConfig(dp=-1, fsdp=1, ep=ep, sp=1, tp=1).resolve(n_dev)
 mesh = build_mesh(mc)
 
 ap = argparse.ArgumentParser()
-ap.add_argument("--model", choices=("mixtral", "olmoe", "xing4"),
+ap.add_argument("--model",
+                choices=("mixtral", "olmoe", "xing4", "kimi_linear"),
                 default="mixtral")
 ap.add_argument("--full", action="store_true",
                 help="the preset's published widths, not the toy size")
@@ -51,6 +54,10 @@ if args.model == "xing4":
     family = xing4
     cfg = (xing4.Xing4Config() if args.full
            else xing4.Xing4Config.tiny(max_seq_len=SEQ))
+elif args.model == "kimi_linear":
+    family = kimi_linear
+    cfg = (kimi_linear.KimiLinearConfig() if args.full
+           else kimi_linear.KimiLinearConfig.tiny())
 else:
     family = moe
     preset = {"mixtral": moe.MoeConfig.mixtral_8x7b,

@@ -291,6 +291,64 @@ def test_xing4_expert_block_fwd_bwd_compiles(one_chip, kernels_are_the_path):
     assert cfg.softmax_scale == pytest.approx(0.14468, rel=1e-4)
 
 
+def test_chunked_delta_rule_fwd_bwd_compiles_in_its_memory(one_chip):
+    """``ops/kda.py`` at the kimi-linear cell's shapes: what its backward
+    keeps is one 16-chunk segment's intermediates, not the sequence's
+    (3.39 GiB before the segments, which the step could not hold)."""
+    from dlrover_tpu.ops import kda
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    wide = (1, 8192, 32, 128)
+    args = [arg(wide, jnp.bfloat16)] * 3 + [
+        arg(wide, jnp.float32), arg(wide[:3], jnp.float32)]
+
+    def loss(*a):
+        return kda.chunk_kda(*a, chunk=64).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=range(5))).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.75 * 2**30
+
+
+@pytest.mark.parametrize("attn", ["kda", "mla"])
+def test_kimi_linear_expert_block_fwd_bwd_compiles(
+        one_chip, kernels_are_the_path, attn):
+    from dlrover_tpu.models import kimi_linear
+
+    cfg = kimi_linear.KimiLinearConfig(
+        vocab_size=20480, n_layers=5, kda_layers=(1, 2, 3, 5),
+        full_attn_layers=(4,), experts_held=32, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16)
+    lp = {
+        name: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+        for name, (shape, _, _) in kimi_linear._block_shapes(
+            cfg, attn, "moe").items()
+    }
+    x = jax.ShapeDtypeStruct((1, 8192, cfg.dim), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(lp, x):
+        fn = jax.checkpoint(
+            lambda lp, x: kimi_linear.block(cfg, None, attn, "moe", lp, x),
+            policy=jax.checkpoint_policies.nothing_saveable)
+        return fn(lp, x).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(lp, x).compile()
+    hlo = compiled.as_text()
+    # latent attention without rotary still runs the 192 / 128 kernels;
+    # a KDA block runs none
+    flash = 1 if attn == "mla" else 0
+    assert _kernel_calls(hlo, "attention_fwd") == flash
+    assert _kernel_calls(hlo, "attention_bwd") == 2 * flash
+    assert _kernel_calls(hlo, "grouped_matmul") == 9
+    # a block's own temporaries fit beside the cell's 7.16 GiB of state
+    # and 4.78 of float32 gradients
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
+    assert trace.gauges()["moe.rows_held"] == 8192
+    assert trace.gauges()["moe.tail_rows"] == 57344
+
+
 def test_grouped_matmul_compiles_at_xing4_shape(
         one_chip, kernels_are_the_path):
     # one grouped product of that block alone, forward and backward:

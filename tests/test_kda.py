@@ -1,0 +1,125 @@
+"""``ops/kda.py`` at tiny sizes on the CPU: the chunked gated delta rule
+against the token-by-token recurrence (``benchmarks/families/
+kimi_linear.py ref_delta_rule``), outputs and every gradient, at strong
+and weak decay and small and large steps; the convolution against a
+shifted sum."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.families.kimi_linear import ref_delta_rule
+from dlrover_tpu.ops import kda
+
+H, DK, DV = 2, 16, 8
+
+
+def _inputs(seq, decay, step, seed=0):
+    """Normalised q and k as the layer makes them; ``decay``: "strong"
+    is g = -5 a token and channel (G = -320 over a chunk of 64),
+    "weak" within 0.01 of zero, "init" the layer's own range at init;
+    ``step``: beta near 0, near 1, or across (0, 1)."""
+    ks = jax.random.split(jax.random.key(seed), 5)
+    q = jax.random.normal(ks[0], (2, seq, H, DK))
+    k = jax.random.normal(ks[1], (2, seq, H, DK))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * DK ** -0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (2, seq, H, DV))
+    u = jax.random.uniform(ks[3], (2, seq, H, DK), minval=0.5, maxval=1.0)
+    g = {"strong": jnp.full_like(u, -5.0), "weak": -0.01 * u,
+         "init": -1.6 * u}[decay]
+    shift = {"small": -6.0, "large": 6.0, "mid": 0.0}[step]
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (2, seq, H)) + shift)
+    return q, k, v, g, beta
+
+
+def _close(got, want, tol):
+    scale = float(jnp.max(jnp.abs(want))) + 1e-30
+    assert float(jnp.max(jnp.abs(got - want))) <= tol * scale
+
+
+@pytest.mark.parametrize("step", ["small", "large", "mid"])
+@pytest.mark.parametrize("decay", ["strong", "weak", "init"])
+@pytest.mark.parametrize("seq,chunk", [
+    (64, 64),      # one chunk
+    (192, 64),     # many
+    (96, 32),
+    (64, 16),      # a chunk of one sub-block
+])
+def test_chunked_form_matches_the_recurrence(seq, chunk, decay, step):
+    args = _inputs(seq, decay, step)
+    weight = jax.random.normal(jax.random.key(9), (2, seq, H, DV))
+
+    def chunked(*a):
+        return jnp.sum(kda.chunk_kda(*a, chunk=chunk) * weight)
+
+    def recurrent(*a):
+        return jnp.sum(ref_delta_rule(*a) * weight)
+
+    out = kda.chunk_kda(*args, chunk=chunk)
+    assert bool(jnp.all(jnp.isfinite(out)))
+    _close(out, ref_delta_rule(*args), 5e-6)
+    got = jax.grad(chunked, argnums=range(5))(*args)
+    want = jax.grad(recurrent, argnums=range(5))(*args)
+    for a, b in zip(got, want):
+        # d/dg at g = -5 sums terms 1e5 apart in size: float32's order
+        _close(a, b, 2e-4)
+
+
+@pytest.mark.parametrize("segment", [1, 2, 3])
+def test_segments_carry_the_state(segment):
+    """A sequence of several segments (padded to whole ones where 3 does
+    not divide its 4 chunks) is the sequence of one."""
+    args = _inputs(128, "init", "mid", seed=3)
+    whole = kda.chunk_kda(*args, chunk=32, segment=16)
+    _close(kda.chunk_kda(*args, chunk=32, segment=segment), whole, 5e-6)
+    _close(whole, ref_delta_rule(*args), 5e-6)
+
+
+@pytest.mark.parametrize("seq", [40, 70])
+def test_a_sequence_that_is_no_multiple_of_the_chunk(seq):
+    args = _inputs(seq, "init", "mid", seed=5)
+    out = kda.chunk_kda(*args, chunk=32)
+    assert out.shape == (2, seq, H, DV)
+    _close(out, ref_delta_rule(*args), 5e-6)
+
+
+def test_bfloat16_operands_float32_state():
+    args = _inputs(128, "init", "mid", seed=7)
+    q, k, v = (a.astype(jnp.bfloat16) for a in args[:3])
+    out = kda.chunk_kda(q, k, v, *args[3:], chunk=64)
+    assert out.dtype == jnp.bfloat16
+    want = ref_delta_rule(*(a.astype(jnp.float32) for a in (q, k, v)),
+                          *args[3:])
+    _close(out.astype(jnp.float32), want, 2e-2)
+
+
+def test_the_decay_bound():
+    """At the stated bound, |g| = 9 a token, every factor is a normal
+    float32 and the form still agrees to rounding; the sub-block
+    references keep G = -576 over a chunk out of any single exponent."""
+    q, k, v, g, beta = _inputs(64, "strong", "mid")
+    g = jnp.full_like(g, -9.0)
+    out = kda.chunk_kda(q, k, v, g, beta, chunk=64)
+    assert bool(jnp.all(jnp.isfinite(out)))
+    _close(out, ref_delta_rule(q, k, v, g, beta), 5e-6)
+
+
+def test_chunk_must_be_whole_sub_blocks():
+    with pytest.raises(ValueError, match="multiple of 16"):
+        kda.chunk_kda(*_inputs(48, "init", "mid"), chunk=24)
+
+
+@pytest.mark.parametrize("taps", [1, 4])
+def test_convolution_is_a_causal_shifted_sum(taps):
+    x = jax.random.normal(jax.random.key(0), (2, 11, 6))
+    w = jax.random.normal(jax.random.key(1), (6, taps))
+    want = np.zeros(x.shape, np.float32)
+    for t in range(x.shape[1]):
+        for back in range(min(taps, t + 1)):
+            want[:, t] += np.asarray(x[:, t - back] * w[:, taps - 1 - back])
+    np.testing.assert_allclose(kda.causal_conv(x, w), want, atol=1e-5)
+    # nothing of a later token reaches an earlier one
+    bumped = kda.causal_conv(x.at[:, 7].add(1.0), w)
+    np.testing.assert_array_equal(bumped[:, :7], kda.causal_conv(x, w)[:, :7])
