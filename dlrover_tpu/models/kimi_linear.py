@@ -371,11 +371,11 @@ def kda_inputs(cfg: KimiLinearConfig, lp: Params, y):
     return q, k, v, g, beta, gate.reshape(b, s, h, d)
 
 
-def kda_attention(cfg: KimiLinearConfig, lp: Params, y):
+def kda_attention(cfg: KimiLinearConfig, lp: Params, y, mesh=None):
     b, s, _ = y.shape
     q, k, v, g, beta, gate = kda_inputs(cfg, lp, y)
     with jax.named_scope("kda_chunk"):
-        o = kda.chunk_kda(q, k, v, g, beta, chunk=cfg.kda_chunk)
+        o = kda.chunk_kda(q, k, v, g, beta, chunk=cfg.kda_chunk, mesh=mesh)
     with jax.named_scope("kda_out"):
         o = rms_norm(o, lp["o_norm"], cfg.norm_eps)
         o = (o.astype(jnp.float32)
@@ -389,7 +389,7 @@ def block(cfg: KimiLinearConfig, mesh, attn: str, ffn: str, lp: Params, x):
     eps = cfg.norm_eps
     y = rms_norm(x, lp["attn_norm"], eps)
     if attn == "kda":
-        x = x + kda_attention(cfg, lp, y)
+        x = x + kda_attention(cfg, lp, y, mesh=mesh)
     else:
         x = x + xing4.latent_attention(cfg, mesh, None, None, lp, y)
     y = rms_norm(x, lp["mlp_norm"], eps)

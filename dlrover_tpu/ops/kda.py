@@ -1,7 +1,10 @@
 """Kimi Delta Attention (arXiv 2510.26692): a gated delta rule with a
 decay per channel, in chunked form, and the short depthwise causal
-convolution its inputs pass through. Plain XLA ops: matrix products on
-the MXU, one triangular solve a chunk, a ``lax.scan`` over the chunks.
+convolution its inputs pass through. On the TPU two Pallas kernels
+under one ``custom_vjp`` (forward, and a hand-written backward); off it
+the same equations in XLA ops (matrix products, one triangular solve a
+chunk, a ``lax.scan`` over the chunks), which are also the kernels'
+oracle.
 
 The recurrence, one head, ``S`` in R^(dk x dv) float32, ``S_0 = 0``::
 
@@ -21,9 +24,9 @@ chunk starts from). With ``u_i = b_i (v_i - S'_i^T k_i)`` the state is
     O   = (Q * e^G) S + A_qk U
     S_C = Diag(e^G_C) S + (K * e^(G_C - G))^T U
 
-Everything but the last three lines is formed for all chunks at once
-(batched products); the last three are the scan's body, ``seq / C``
-steps carrying the (dk, dv) state.
+Everything but the last three lines does not depend on the state (the
+*state-free part*); the last three carry the (dk, dv) state from chunk
+to chunk, ``seq / C`` dependent steps.
 
 **The decays.** ``e^(G_i - G_j)`` is a product over channels, so it has
 to be split into a factor on row ``i`` and one on row ``j`` before it
@@ -43,33 +46,64 @@ e^(G_r - G_j)``:
   ``e^-72 x_c`` then stays a normal float32 for every component above
   1e-7 of the row's norm. Past it the smallest components flush to zero
   (a relative error of 1e-3 at ``|g| = 10``), and past ``|g| = 11`` the
-  large factor overflows to inf. ``tests/test_kda.py`` holds the form at
-  ``g = -5`` a token (G = -320 over a chunk) and at the bound against
+  large factor overflows to inf. ``tests/test_kda.py`` holds both forms
+  at ``g = -5`` a token (G = -320 over a chunk) and at the bound against
   the token-by-token recurrence.
 - ``e^G_i``, ``e^(G_C - G_i)`` and ``e^G_C`` are at most 1 as they are.
 
 Operands go to the MXU in the activations' dtype and accumulate in
-float32; gates, cumulative decays, the solve, ``U`` and the state are
-float32 (the state enters its products in the activations' dtype).
+float32; gates, cumulative decays, the solve, ``U``, the state and its
+cotangent are float32 (the state enters its products in the
+activations' dtype).
 
-**The backward** is JAX's own through the batched part, the solve and
-the scans. What autodiff keeps of the chunked form (about twenty
-(tokens, heads x 128) arrays and a state a chunk: 3.4 GiB a layer at
-8192 tokens) would be the step's peak, so the sequence is walked in
-*segments* of 16 chunks by an outer scan whose body is rematerialised:
-the op keeps its five inputs and a state a segment, and the backward
-forms one segment's intermediates at a time. docs/design/kernels.md 1e
-has the sizes and why no ``custom_vjp``.
+**The kernels** (docs/design/kernels.md 1e has the sizes and the
+chip's times). Arrays stay ``(b, s, h d)``, a free reshape: a block is
+``TILE`` = 128 tokens of ``HEADS`` = 4 heads' lanes, read where it
+lies; nothing is transposed in HBM. A grid step of the forward forms a
+tile's state-free part a head (``_prep_heads``: a (128, 128) matrix
+holds the tile's ``128 / C`` chunks as diagonal blocks) and then walks
+the tile's chunks through the state, which stays in VMEM scratch from
+tile to tile, the four heads' chains side by side for the scheduler to
+interleave. The solve is forward substitution: row by row inside the
+eight 16 x 16 diagonal sub-blocks at once, then the sub-blocks under
+the diagonal block row by block row; the inverse is formed once a tile
+and applied by the MXU at float32 precision.
+
+**The backward** is written by hand (``_bwd_kernel``, ``_prep_bwd_tile``):
+under differentiation the forward also writes the state every chunk
+started from (float32, 64 KiB a head and chunk), and the backward walks
+the tiles from the last, carrying the state's cotangent in scratch: it
+forms a tile's state-free part again, takes its chunks in reverse, and
+differentiates the state-free part (the solve through ``dM = -T^T dW
+W^T`` under the diagonal; every decay factor ``y = x e^E`` through ``dx
+= dy e^E``, ``dE = dy y``; ``dg`` the reverse cumulative sum of ``dG``
+inside the chunk). The XLA form's backward is JAX's own, through
+rematerialised *segments* of 16 chunks (what autodiff would keep of the
+whole sequence, 3.4 GiB a layer at 8192 tokens, is the step's peak
+otherwise).
 """
 
 from __future__ import annotations
 
+import functools
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
-from jax import lax
+import numpy as np
+from jax import lax, shard_map
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import Mesh, PartitionSpec as P
+
+from dlrover_tpu.observability import trace
+from dlrover_tpu.parallel.mesh import BATCH_AXES
 
 SUB = 16          # rows of a sub-block of a chunk
 _MID = SUB // 2 - 1   # the reference row inside a sub-block
+TILE = 128        # rows the kernels take at a time: 128 / chunk chunks
+HEADS = 4         # heads a grid step: independent chains, side by side
+KERNEL_CHUNKS = (16, 32, 64)   # the chunk sizes the kernels admit
 
 
 def causal_conv(x: jnp.ndarray, weight: jnp.ndarray) -> jnp.ndarray:
@@ -128,8 +162,8 @@ def _decay_products(x, k, G, dtype, strict: bool):
     return jnp.concatenate(rows, axis=-2)
 
 
-def chunk_kda(q, k, v, g, beta, *, chunk: int = 64, segment: int = 16):
-    """The chunked gated delta rule. ``q, k (b, s, h, dk)`` (``q``
+def _chunk_kda_xla(q, k, v, g, beta, *, chunk: int = 64, segment: int = 16):
+    """The chunked gated delta rule in XLA ops. ``q, k (b, s, h, dk)`` (``q``
     already scaled, both already normalised), ``v (b, s, h, dv)``,
     ``g (b, s, h, dk)`` float32 log-decays (<= 0), ``beta (b, s, h)``
     float32 step sizes -> ``o (b, s, h, dv)`` in ``v``'s dtype, which is
@@ -203,3 +237,563 @@ def chunk_kda(q, k, v, g, beta, *, chunk: int = 64, segment: int = 16):
     # (n_seg, seg, b, h, C, dv) -> (b, s, h, dv)
     o = jnp.moveaxis(o, (0, 1, 3), (1, 2, 4))
     return o.reshape(b, s + pad, h, dv)[:, :s]
+
+
+# ---------------------------------------------------------------------------
+# The Pallas kernels
+# ---------------------------------------------------------------------------
+
+_NN = (((1,), (0,)), ((), ()))  # a @ b
+_NT = (((1,), (1,)), ((), ()))  # a @ b^T
+_TN = (((0,), (0,)), ((), ()))  # a^T @ b
+_F32 = jnp.float32
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _dot(a, b, dims, exact: bool = False):
+    """A product on the MXU, float32 out. ``exact``: float32 operands at
+    float32 precision (the solve's products); else the operands as they
+    come, which is the activations' dtype."""
+    return lax.dot_general(
+        a, b, dims, precision=lax.Precision.HIGHEST if exact else None,
+        preferred_element_type=_F32)
+
+
+def _iota(shape, dim):
+    return lax.broadcasted_iota(jnp.int32, shape, dim)
+
+
+def _rows_of(x, starts, rows):
+    """``x (TILE, d)``: row ``starts[i]`` repeated over ``rows`` rows,
+    for every ``i``, stacked: the reference row of each group of rows."""
+    return jnp.concatenate([
+        jnp.broadcast_to(x[at:at + 1], (rows, x.shape[1])) for at in starts
+    ], axis=0)
+
+
+def _sel_matrix() -> np.ndarray:
+    """``(TILE, (SUB - 1) TILE)`` of 0 / 1: column ``(i, s, c)`` takes
+    row ``(s, i)``, for ``i`` in 1 .. SUB - 1: a product with it spreads
+    lane ``i`` of every group of SUB lanes over the group."""
+    sel = np.zeros((TILE, SUB - 1, TILE), np.float32)
+    for i in range(1, SUB):
+        for s in range(TILE // SUB):
+            sel[s * SUB + i, i - 1, s * SUB:(s + 1) * SUB] = 1.0
+    return sel.reshape(TILE, (SUB - 1) * TILE)
+
+
+def _dot_pieces(a, b, dims):
+    """A product of a float32 operand with one that bfloat16 holds
+    exactly (a 0 / 1 matrix, an activation): the float32 one goes in
+    three bfloat16 pieces that sum to it to 24 bits, so the result has
+    float32 precision at three MXU passes, not ``exact``'s six."""
+    split = a.dtype == _F32
+    x, out = (a if split else b), None
+    for _ in range(3):
+        piece = x.astype(jnp.bfloat16)
+        x = x - piece.astype(_F32)
+        part = _dot(piece, b, dims) if split else _dot(a, piece, dims)
+        out = part if out is None else out + part
+    return out
+
+
+def _masks(chunk: int):
+    """(TILE, TILE) masks of a tile's matrices: rows and columns in the
+    same 16-row sub-block, in the same chunk, the sub-block's position
+    in its chunk by row and by column, and the row and column indices."""
+    r, c = _iota((TILE, TILE), 0), _iota((TILE, TILE), 1)
+    shift = chunk.bit_length() - 1
+    at = chunk // SUB - 1
+    return ((r >> 4) == (c >> 4), (r >> shift) == (c >> shift),
+            (r >> 4) & at, (c >> 4) & at, r, c)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk",), inline=True)
+def _decay_part(q, k, v, g, b_col, *, chunk: int):
+    """The state-free part of ``TILE`` rows of one head up to the solve:
+    ``q, k (TILE, dk)``, ``v (TILE, dv)`` in the operands' dtype, ``g
+    (TILE, dk)`` float32, ``b_col (TILE, 1)`` float32. The tile holds
+    ``TILE / chunk`` chunks; a (TILE, TILE) matrix of it has their
+    (chunk, chunk) blocks on its diagonal and zeros elsewhere.
+
+    Returns what ``_solve_part`` and the backward read: ``a_kk`` under
+    the diagonal sub-blocks, ``a_qk`` whole, ``G`` and the decay factors
+    (``up, down`` inside a sub-block, ``below``: ``(lo, hi)`` a later
+    sub-block position), and ``ltp (SUB, TILE)``: entry ``[j, 16 s +
+    i]`` is ``b_i A_kk[i, j]`` of diagonal sub-block ``s`` (``j < i``),
+    which the substitution takes its rows from."""
+    dt = v.dtype
+    same_sub, same_chunk, pos_r, pos_c, r, c = _masks(chunk)
+    q32, k32 = q.astype(_F32), k.astype(_F32)
+
+    # G: the cumulative log-decay inside each chunk
+    tril = jnp.where(same_chunk & (c <= r), 1.0, 0.0).astype(jnp.bfloat16)
+    G = _dot_pieces(tril, g, _NN)
+    firsts = [i * chunk for i in range(TILE // chunk)]
+    decay = jnp.exp(G)
+    to_end = jnp.exp(_rows_of(G, [f + chunk - 1 for f in firsts], chunk) - G)
+
+    # the diagonal sub-blocks, relative to each sub-block's row _MID
+    rel = G - _rows_of(G, [s * SUB + _MID for s in range(TILE // SUB)], SUB)
+    up, down = jnp.exp(rel), jnp.exp(-rel)
+    k_down = (k32 * down).astype(dt)
+    # transposed: [j, i] = b_i A_kk[i, j], i's sub-block = j's, j < i
+    b_row = jnp.sum(jnp.where(r == c, b_col, 0.0), axis=0, keepdims=True)
+    lt = jnp.where(
+        same_sub & (c > r), _dot(k_down, (k32 * up).astype(dt), _NT), 0.0
+    ) * b_row
+    ltp = lt[:SUB]
+    for s in range(1, TILE // SUB):
+        ltp = ltp + lt[s * SUB:(s + 1) * SUB]
+    a_qk = jnp.where(
+        same_sub & (c <= r), _dot((q32 * up).astype(dt), k_down, _NT), 0.0)
+
+    # sub-block p of each chunk against the chunk's earlier sub-blocks,
+    # relative to the last row before p
+    a_kk = jnp.zeros((TILE, TILE), _F32)
+    below = []
+    for p in range(1, chunk // SUB):
+        ref = _rows_of(G, [f + p * SUB - 1 for f in firsts], chunk)
+        lo = jnp.exp(jnp.minimum(G - ref, 0.0))
+        hi = jnp.exp(jnp.minimum(ref - G, 0.0))
+        k_hi = (k32 * hi).astype(dt)
+        here = same_chunk & (pos_r == p) & (pos_c < p)
+        a_kk = a_kk + jnp.where(
+            here, _dot((k32 * lo).astype(dt), k_hi, _NT), 0.0)
+        a_qk = a_qk + jnp.where(
+            here, _dot((q32 * lo).astype(dt), k_hi, _NT), 0.0)
+        below.append((lo, hi))
+    return {"a_kk": a_kk, "a_qk": a_qk, "ltp": ltp, "G": G, "decay": decay,
+            "to_end": to_end, "up": up, "down": down, "below": below,
+            "b_row": b_row}
+
+
+@functools.partial(jax.jit, static_argnames=("chunk",), inline=True)
+def _solve_part(q, k, v, b_col, part, coef, *, chunk: int):
+    """The solve and what the chunks read. ``part``: ``_decay_part``'s;
+    ``coef (SUB, (SUB - 1) TILE)``: ``part["ltp"]`` spread by
+    ``_sel_matrix``, ``coef[j, (i - 1, s, .)] = L_s[i, j]``.
+
+    Returns ``part`` and: ``w_v`` float32, ``w_k``, ``a_qk``, ``q_in``,
+    ``k_out`` in the operands' dtype, ``keep (TILE / chunk, dk)``
+    float32, and for the backward the inverse ``t = (I + Diag(b)
+    A_kk)^-1`` and ``w_k32``, ``W_k`` before its cast."""
+    dt = v.dtype
+    n = chunk // SUB
+    G, decay = part["G"], part["decay"]
+    k32 = k.astype(_F32)
+    # the inverse of the diagonal sub-blocks of I + Diag(b) A_kk, row by
+    # row (forward substitution), all TILE / SUB of them at once: x[j,
+    # (s, c)] is entry (j, c) of sub-block s's inverse; row i is e_i -
+    # sum_(j < i) L[i, j] x[j]
+    row = _iota((SUB, TILE), 0)
+    x = jnp.where(row == (_iota((SUB, TILE), 1) & (SUB - 1)), 1.0, 0.0
+                  ).astype(_F32)
+    for i in range(1, SUB):
+        ci = coef[:, (i - 1) * TILE:i * TILE]
+        new = jnp.sum(ci * x, axis=0, keepdims=True)
+        x = x - jnp.where(row == i, new, 0.0)
+    t = jnp.where(_masks(chunk)[0],
+                  jnp.concatenate([x] * (TILE // SUB), axis=0), 0.0)
+    # the sub-blocks under the diagonal, block row by block row:
+    # (I + D N)^-1 D with D N nilpotent of index n, in Horner's form
+    if n > 1:
+        d = t
+        p_mat = _dot(d, b_col * part["a_kk"], _NN, exact=True)
+        for _ in range(n - 1):
+            t = d - _dot(p_mat, t, _NN, exact=True)
+    if dt == jnp.bfloat16:
+        # T Diag(b) V with V as it is: exact in three passes
+        w_v = _dot_pieces(t * part["b_row"], v, _NN)
+    else:
+        w_v = _dot(t, b_col * v.astype(_F32), _NN, exact=True)
+    w_k = _dot(t, b_col * (k32 * decay), _NN, exact=True)
+    lasts = range(chunk - 1, TILE, chunk)
+    return dict(
+        part, t=t, w_v=w_v, w_k32=w_k, w_k=w_k.astype(dt),
+        a_qk=part["a_qk"].astype(dt),
+        q_in=(q.astype(_F32) * decay).astype(dt),
+        k_out=(k32 * part["to_end"]).astype(dt),
+        keep=jnp.concatenate([jnp.exp(G[at:at + 1]) for at in lasts], axis=0))
+
+
+def _prep_heads(ins, sel, *, chunk: int):
+    """``_decay_part`` and ``_solve_part`` of every head of a grid step
+    (``ins``: ``[(q, k, v, g, b_col)]``), the heads' substitution
+    coefficients spread by one product with ``sel``."""
+    parts = [_decay_part(*x, chunk=chunk) for x in ins]
+    coef = _dot_pieces(
+        jnp.concatenate([p["ltp"] for p in parts], axis=0), sel, _NN)
+    return [
+        _solve_part(q, k, v, b_col, part, coef[hd * SUB:(hd + 1) * SUB],
+                    chunk=chunk)
+        for hd, ((q, k, v, _, b_col), part) in enumerate(zip(ins, parts))]
+
+
+def _head_column(beta_ref, head):
+    """``beta_ref (rows, h)``: column ``head`` as ``(rows, 1)``."""
+    blk = beta_ref[...]
+    return jnp.sum(jnp.where(_iota(blk.shape, 1) == head, blk, 0.0),
+                   axis=1, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk",), inline=True)
+def _prep_bwd_tile(q, k, v, b_col, p, ct, *, chunk: int):
+    """The derivative of a head's state-free part (``_decay_part`` and
+    ``_solve_part``) by hand: its inputs, ``p``, what ``_solve_part``
+    returned, and ``ct``, the float32 cotangents of ``w_v, w_k, a_qk,
+    q_in, k_out, keep`` by name -> ``dq, dk, dv, dg`` float32 ``(TILE,
+    .)`` and ``dbeta (TILE, 1)``.
+
+    The solve: with ``W = T R``, ``dR = T^T dW`` and the cotangent of
+    ``I + Diag(b) A_kk`` is ``-dR W^T`` under the diagonal. The decays:
+    every factor ``y = x e^E`` gives ``dx = dy e^E`` and ``dE = dy y``,
+    ``E`` being ``+-(G - G_ref)``; the reference rows get nothing (a
+    product does not depend on its reference), ``dG`` collects the
+    rest, and ``dg`` is its reverse cumulative sum inside the chunk."""
+    dt = v.dtype
+    same_sub, same_chunk, pos_r, pos_c, r, c = _masks(chunk)
+    q32, k32, v32 = (a.astype(_F32) for a in (q, k, v))
+    dw_v, dw_k, da_qk, dq_in, dk_out, dkeep = (
+        ct[n] for n in ("w_v", "w_k", "a_qk", "q_in", "k_out", "keep"))
+    G, decay, to_end, up, down = (
+        p[n] for n in ("G", "decay", "to_end", "up", "down"))
+    t, w_v, w_k = p["t"], p["w_v"], p["w_k32"]
+    k_up, k_down, q_up = k32 * up, k32 * down, q32 * up
+    diag_kk, diag_qk = same_sub & (c < r), same_sub & (c <= r)
+    # A_kk whole: the forward formed its diagonal sub-blocks transposed
+    a_kk = p["a_kk"] + jnp.where(
+        diag_kk, _dot(k_up.astype(dt), k_down.astype(dt), _NT), 0.0)
+
+    # the solve
+    r_k = b_col * (k32 * decay)
+    d_rv = _dot(t, dw_v, _TN, exact=True)
+    d_rk = _dot(t, dw_k, _TN, exact=True)
+    dl = jnp.where(
+        same_chunk & (c < r),
+        -(_dot(d_rv, w_v, _NT, exact=True) + _dot(d_rk, w_k, _NT, exact=True)),
+        0.0)
+    db = (jnp.sum(dl * a_kk, axis=1, keepdims=True)
+          + jnp.sum(d_rv * v32, axis=1, keepdims=True)
+          + jnp.sum(d_rk * (k32 * decay), axis=1, keepdims=True))
+    da_kk = b_col * dl
+    dv32 = b_col * d_rv
+    dk32 = b_col * decay * d_rk
+    dG = d_rk * r_k
+
+    # what the scan reads: Q e^G, K e^(G_C - G), e^G_C
+    dq32 = dq_in * decay
+    dG = dG + dq_in * (q32 * decay)
+    e = dk_out * (k32 * to_end)
+    dk32 = dk32 + dk_out * to_end
+    dG = dG - e
+    row = _iota(G.shape, 0)
+    for i, last in enumerate(range(chunk - 1, TILE, chunk)):
+        total = (jnp.sum(e[last + 1 - chunk:last + 1], axis=0, keepdims=True)
+                 + dkeep[i:i + 1] * jnp.exp(G[last:last + 1]))
+        dG = dG + jnp.where(row == last, total, 0.0)
+
+    # the decay products
+    def pair(mask, x_k, x_q, y_k, fx, fy):
+        """``A = mask(x y^T)`` for the k rows and the q rows against
+        one ``y``: the cotangents of both land on x's factor ``fx`` and
+        y's ``fy``."""
+        nonlocal dk32, dq32, dG
+        dak = jnp.where(mask[0], da_kk, 0.0).astype(dt)
+        daq = jnp.where(mask[1], da_qk, 0.0).astype(dt)
+        y = y_k.astype(dt)
+        dxk, dxq = _dot(dak, y, _NN), _dot(daq, y, _NN)
+        dy = _dot(dak, x_k.astype(dt), _TN) + _dot(daq, x_q.astype(dt), _TN)
+        dk32 = dk32 + dxk * fx + dy * fy
+        dq32 = dq32 + dxq * fx
+        dG = dG + dxk * x_k + dxq * x_q - dy * y_k
+
+    pair((diag_kk, diag_qk), k_up, q_up, k_down, up, down)
+    for at, (lo, hi) in enumerate(p["below"], 1):
+        here = same_chunk & (pos_r == at) & (pos_c < at)
+        pair((here, here), k32 * lo, q32 * lo, k32 * hi, lo, hi)
+    tril = jnp.where(same_chunk & (c <= r), 1.0, 0.0).astype(jnp.bfloat16)
+    return dq32, dk32, dv32, _dot_pieces(tril, dG, _TN), db
+
+
+_SCAN_IN = ("w_k", "q_in", "k_out", "a_qk", "w_v")
+
+
+def _chunk_rows(x, j, chunk):
+    return x[j * chunk:(j + 1) * chunk]
+
+
+def _into_tile(x, j, chunk):
+    """``x (chunk, d)`` as chunk ``j``'s rows of a tile of zeros: a row
+    of ``a_qk`` holds the whole tile's columns."""
+    parts = [jnp.zeros_like(x)] * (TILE // chunk)
+    parts[j] = x
+    return jnp.concatenate(parts, axis=0)
+
+
+def _head_inputs(refs, beta_ref, heads, dk, dv):
+    """A grid step's blocks, a head at a time: ``refs`` are q, k, v, g
+    ``(TILE, heads d)`` -> ``[(q, k, v, g, b_col)]``."""
+    out = []
+    for hd in range(heads):
+        kc, vc = slice(hd * dk, (hd + 1) * dk), slice(hd * dv, (hd + 1) * dv)
+        q_ref, k_ref, v_ref, g_ref = refs
+        out.append((q_ref[:, kc], k_ref[:, kc], v_ref[:, vc], g_ref[:, kc],
+                    _head_column(beta_ref, pl.program_id(1) * heads + hd)))
+    return out
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, sel_ref, o_ref, *rest,
+                chunk, heads, dk, dv):
+    """One tile of ``heads`` heads a grid step: the state-free part,
+    then the tile's chunks through the state, the heads' chains side by
+    side for the scheduler to interleave. The state is held transposed,
+    ``(dv, dk)`` (a chunk's decay scales its lanes), in scratch through
+    a head's tiles. ``rest``: the output of a state a chunk (what each
+    chunk started from) where the backward will want it, then the
+    scratch."""
+    states_ref = rest[0] if len(rest) == 2 else None
+    s_ref = rest[-1]
+    dt = o_ref.dtype
+    per = TILE // chunk
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    ins = _head_inputs((q_ref, k_ref, v_ref, g_ref), beta_ref, heads, dk, dv)
+    p = _prep_heads(ins, sel_ref[...], chunk=chunk)
+    st = [s_ref[hd] for hd in range(heads)]
+    out = [[] for _ in range(heads)]
+    for j in range(per):
+        for hd in range(heads):
+            if states_ref is not None:
+                states_ref[hd, j] = st[hd]
+            sd = st[hd].astype(dt)
+            w_k, q_in, k_out, a_qk, w_v = (
+                _chunk_rows(p[hd][n], j, chunk) for n in _SCAN_IN)
+            ud = (w_v - _dot(w_k, sd, _NT)).astype(dt)
+            out[hd].append(_dot(q_in, sd, _NT) + _dot(
+                a_qk, _into_tile(ud, j, chunk), _NN))
+            st[hd] = p[hd]["keep"][j:j + 1] * st[hd] + _dot(ud, k_out, _TN)
+    for hd in range(heads):
+        o_ref[:, hd * dv:(hd + 1) * dv] = jnp.concatenate(
+            out[hd], axis=0).astype(dt)
+        s_ref[hd] = st[hd]
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, sel_ref, do_ref,
+                states_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, ds_ref,
+                *, chunk, heads, dk, dv):
+    """The forward kernel backwards: the grid walks the tiles from the
+    last. A tile: its state-free part again (with the inverse), its
+    chunks from the last through the cotangent of the (transposed)
+    state, each reading the state it started from and forming ``U``
+    again, then the state-free part's derivative."""
+    dt = do_ref.dtype
+    per = TILE // chunk
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        ds_ref[...] = jnp.zeros_like(ds_ref)
+
+    ins = _head_inputs((q_ref, k_ref, v_ref, g_ref), beta_ref, heads, dk, dv)
+    p = _prep_heads(ins, sel_ref[...], chunk=chunk)
+    ds = [ds_ref[hd] for hd in range(heads)]
+    # the cotangents of what the chunks read of the state-free part
+    cts = [{n: [None] * per for n in _SCAN_IN + ("keep",)}
+           for _ in range(heads)]
+    for j in reversed(range(per)):
+        for hd in range(heads):
+            st = states_ref[hd, j]
+            sd, dsd = st.astype(dt), ds[hd].astype(dt)
+            w_k, q_in, k_out, a_qk, w_v = (
+                _chunk_rows(p[hd][n], j, chunk) for n in _SCAN_IN)
+            do = _chunk_rows(do_ref[:, hd * dv:(hd + 1) * dv], j, chunk)
+            ud = (w_v - _dot(w_k, sd, _NT)).astype(dt)
+            du = (_chunk_rows(_dot(a_qk, do, _TN), j, chunk)
+                  + _dot(k_out, dsd, _NT))
+            dud = du.astype(dt)
+            ct = cts[hd]
+            ct["w_v"][j] = du
+            ct["w_k"][j] = -_dot(dud, sd, _NN)
+            ct["a_qk"][j] = _dot(do, _into_tile(ud, j, chunk), _NT)
+            ct["q_in"][j] = _dot(do, sd, _NN)
+            ct["k_out"][j] = _dot(ud, dsd, _NN)
+            ct["keep"][j] = jnp.sum(ds[hd] * st, axis=0, keepdims=True)
+            ds[hd] = (p[hd]["keep"][j:j + 1] * ds[hd]
+                      + _dot(do, q_in, _TN) - _dot(dud, w_k, _TN))
+    eye = _iota((TILE, TILE), 0) == _iota((TILE, TILE), 1)
+    for hd in range(heads):
+        ct = {n: jnp.concatenate(x, axis=0) for n, x in cts[hd].items()}
+        q, k, v, _, b_col = ins[hd]
+        dq, dk_, dv_, dg, db = _prep_bwd_tile(
+            q, k, v, b_col, p[hd], ct, chunk=chunk)
+        kc, vc = slice(hd * dk, (hd + 1) * dk), slice(hd * dv, (hd + 1) * dv)
+        dq_ref[:, kc] = dq.astype(dq_ref.dtype)
+        dk_ref[:, kc] = dk_.astype(dk_ref.dtype)
+        dv_ref[:, vc] = dv_.astype(dv_ref.dtype)
+        dg_ref[:, kc] = dg
+        # a tile's steps as a row: lanes are its tokens
+        dbeta_ref[hd, 0] = jnp.sum(jnp.where(eye, db, 0.0), axis=0,
+                                   keepdims=True)
+        ds_ref[hd] = ds[hd]
+
+
+def _heads_a_step(h: int) -> int:
+    return max(d for d in range(1, HEADS + 1) if h % d == 0)
+
+
+def _call(kernel, name, arrays, more_in, out, *, chunk, backwards, interpret):
+    """``arrays``: q, k, v, g as ``(b, s, h d)`` (free reshapes: a
+    head's channels are the lanes of a block, its tokens lie where they
+    lay, nothing is transposed in HBM) and beta ``(b, s, h)``, whole
+    tiles. ``more_in`` and ``out``: ``(kind, array or its shape)``,
+    ``kind`` the lanes a head has of a ``(b, s, h kind)`` array, or the
+    block of a ``(b, h, s / TILE, ., .)`` one. The grid is (batch, heads
+    / heads a step, tiles), the last axis in order (from the end where
+    ``backwards``): it carries the state."""
+    b, s, h = arrays[4].shape
+    steps = s // TILE
+    dk, dv = arrays[0].shape[-1] // h, arrays[2].shape[-1] // h
+    heads = _heads_a_step(h)
+    sel = jnp.asarray(_sel_matrix(), jnp.bfloat16)
+
+    def at(ti):
+        return steps - 1 - ti if backwards else ti
+
+    def spec(kind):
+        if isinstance(kind, int):
+            return pl.BlockSpec((None, TILE, heads * kind),
+                                lambda bi, hi, ti: (bi, at(ti), hi))
+        return pl.BlockSpec((None, heads) + kind,
+                            lambda bi, hi, ti: (bi, hi, at(ti), 0, 0))
+
+    in_specs = [spec(dk), spec(dk), spec(dv), spec(dk),
+                pl.BlockSpec((None, TILE, h),
+                             lambda bi, hi, ti: (bi, at(ti), 0)),
+                pl.BlockSpec(sel.shape, lambda bi, hi, ti: (0, 0))]
+    return pl.pallas_call(
+        functools.partial(kernel, chunk=chunk, heads=heads, dk=dk, dv=dv),
+        grid=(b, h // heads, steps),
+        in_specs=in_specs + [spec(kind) for kind, _ in more_in],
+        out_specs=[spec(kind) for kind, _ in out],
+        out_shape=[shape for _, shape in out],
+        scratch_shapes=[pltpu.VMEM((heads, dv, dk), _F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name=name,
+    )(*arrays, sel, *(x for _, x in more_in))
+
+
+def _tiles(arrays, s):
+    """(b, s, h, d) -> (b, s, h d), free; whole tiles: the rows past the
+    end have no decay and no step, and nothing of them is read back."""
+    pad = ((0, 0), (0, -s % TILE))
+    return [jnp.pad(a.reshape(*a.shape[:2], -1) if a.ndim == 4 else a,
+                    pad + ((0, 0),)) for a in arrays]
+
+
+# Both calls are jitted (and inlined where they are called): a kernel's
+# body is some thousand operations to trace, the step calls each kernel
+# once a run of like layers, and jit's cache traces it once a shape.
+
+@functools.partial(jax.jit, static_argnums=(5, 6, 7), inline=True)
+def _kda_forward(q, k, v, g, beta, chunk, interpret, states: bool):
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    arrays = _tiles([q, k, v, g.astype(_F32), beta.astype(_F32)], s)
+    padded = arrays[0].shape[1]
+    out = [(dv, jax.ShapeDtypeStruct((b, padded, h * dv), v.dtype))]
+    if states:
+        out.append(((TILE // chunk, dv, dk), jax.ShapeDtypeStruct(
+            (b, h, padded // chunk, dv, dk), _F32)))
+    o, *kept = _call(_fwd_kernel, "kda_fwd", arrays, (), out, chunk=chunk,
+                     backwards=False, interpret=interpret)
+    return (o[:, :s].reshape(b, s, h, dv), *kept)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1), inline=True)
+def _kda_backward(chunk, interpret, res, do):
+    q, k, v, g, beta, states = res
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    # a custom_vjp's backward is traced outside the caller's scopes: the
+    # device metrics find the op by this one
+    with jax.named_scope("kda_chunk"):
+        *arrays, do = _tiles(
+            [q, k, v, g.astype(_F32), beta.astype(_F32), do], s)
+        padded = do.shape[1]
+
+        def flat(d, dtype):
+            return d, jax.ShapeDtypeStruct((b, padded, h * d), dtype)
+
+        dq, dk_, dv_, dg, dbeta = _call(
+            _bwd_kernel, "kda_bwd", arrays,
+            [(dv, do), ((TILE // chunk, dv, dk), states)],
+            [flat(dk, q.dtype), flat(dk, k.dtype), flat(dv, v.dtype),
+             flat(dk, _F32),
+             ((1, 1, TILE), jax.ShapeDtypeStruct(
+                 (b, h, padded // TILE, 1, TILE), _F32))],
+            chunk=chunk, backwards=True, interpret=interpret)
+        dbeta = jnp.swapaxes(dbeta.reshape(b, h, padded), 1, 2)
+    return (dq[:, :s].reshape(q.shape), dk_[:, :s].reshape(k.shape),
+            dv_[:, :s].reshape(v.shape),
+            dg[:, :s].reshape(g.shape).astype(g.dtype),
+            dbeta[:, :s].astype(beta.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _kda_kernels(q, k, v, g, beta, chunk, interpret):
+    return _kda_forward(q, k, v, g, beta, chunk, interpret, False)[0]
+
+
+def _kda_kernels_fwd(q, k, v, g, beta, chunk, interpret):
+    o, states = _kda_forward(q, k, v, g, beta, chunk, interpret, True)
+    return o, (q, k, v, g, beta, states)
+
+
+_kda_kernels.defvjp(_kda_kernels_fwd, _kda_backward)
+
+
+def chunk_kda(q, k, v, g, beta, *, chunk: int = 64, segment: int = 16,
+              interpret: bool = False, mesh: Optional[Mesh] = None):
+    """The chunked gated delta rule. ``q, k (b, s, h, dk)`` (``q``
+    already scaled, both already normalised), ``v (b, s, h, dv)``,
+    ``g (b, s, h, dk)`` float32 log-decays (<= 0), ``beta (b, s, h)``
+    float32 step sizes -> ``o (b, s, h, dv)`` in ``v``'s dtype, which
+    is also the matmul operands'. The state starts at zero and is not
+    returned. ``chunk`` is a multiple of ``SUB``.
+
+    On the TPU (or with ``interpret``, for the CPU's numerics tests),
+    and for a chunk of ``KERNEL_CHUNKS``, the Pallas kernels under one
+    ``custom_vjp``; anything else the XLA form, whose rematerialised
+    segments are ``segment`` chunks long. ``mesh``: the mesh the
+    caller's jit partitions over; the compiler does not partition a
+    Mosaic kernel, so over more than one device the kernels run under
+    ``shard_map`` on each device's batch rows, every sequence and every
+    head whole."""
+    if chunk % SUB:
+        raise ValueError(f"chunk_kda: chunk {chunk} is no multiple of {SUB}")
+    if not ((interpret or _on_tpu()) and chunk in KERNEL_CHUNKS):
+        trace.gauge("kda.kernel", 0)
+        return _chunk_kda_xla(q, k, v, g, beta, chunk=chunk, segment=segment)
+
+    trace.gauge("kda.kernel", 1)
+    trace.gauge("kda.heads_per_step", _heads_a_step(q.shape[2]))
+    trace.gauge("kda.chunks_per_step", TILE // chunk)
+
+    def kernels(*args):
+        return _kda_kernels(*args, chunk, interpret)
+
+    if mesh is None or mesh.size == 1:
+        return kernels(q, k, v, g, beta)
+    wide, narrow = P(BATCH_AXES, None, None, None), P(BATCH_AXES, None, None)
+    return shard_map(
+        kernels, mesh=mesh, in_specs=(wide, wide, wide, wide, narrow),
+        out_specs=wide, check_vma=False,
+    )(q, k, v, g, beta)

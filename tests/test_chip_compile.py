@@ -16,14 +16,17 @@ import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
 
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
 from dlrover_tpu.observability import trace
-from dlrover_tpu.ops import attention, fused_ce, grouped_matmul
+from dlrover_tpu.ops import attention, fused_ce, grouped_matmul, kda
 from dlrover_tpu.parallel import MeshConfig, build_mesh
 from dlrover_tpu.parallel.mesh import BATCH_AXES
 
@@ -61,6 +64,7 @@ def kernels_are_the_path(monkeypatch):
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     monkeypatch.setattr(fused_ce, "_on_tpu", lambda: True)
     monkeypatch.setattr(grouped_matmul, "_on_tpu", lambda: True)
+    monkeypatch.setattr(kda, "_on_tpu", lambda: True)
     monkeypatch.setenv("DLROVER_TPU_FUSED_CE", "1")
 
 
@@ -291,24 +295,69 @@ def test_xing4_expert_block_fwd_bwd_compiles(one_chip, kernels_are_the_path):
     assert cfg.softmax_scale == pytest.approx(0.14468, rel=1e-4)
 
 
-def test_chunked_delta_rule_fwd_bwd_compiles_in_its_memory(one_chip):
-    """``ops/kda.py`` at the kimi-linear cell's shapes: what its backward
-    keeps is one 16-chunk segment's intermediates, not the sequence's
-    (3.39 GiB before the segments, which the step could not hold)."""
-    from dlrover_tpu.ops import kda
-
+def _kda_args(sharding, batch=1):
     def arg(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-    wide = (1, 8192, 32, 128)
-    args = [arg(wide, jnp.bfloat16)] * 3 + [
+    wide = (batch, 8192, 32, 128)
+    return [arg(wide, jnp.bfloat16)] * 3 + [
         arg(wide, jnp.float32), arg(wide[:3], jnp.float32)]
 
-    def loss(*a):
-        return kda.chunk_kda(*a, chunk=64).astype(jnp.float32).sum()
 
-    compiled = jax.jit(jax.grad(loss, argnums=range(5))).lower(*args).compile()
+def _kda_loss(mesh=None):
+    def loss(*a):
+        with jax.named_scope("kda_chunk"):      # as kda_attention calls it
+            o = kda.chunk_kda(*a, chunk=64, mesh=mesh)
+        return o.astype(jnp.float32).sum()
+    return loss
+
+
+def _op_names(hlo, target="tpu_custom_call"):
+    """The ``op_name`` of every custom call to ``target``."""
+    return [re.search(r'op_name="([^"]*)"', line).group(1)
+            for line in hlo.splitlines()
+            if f'custom_call_target="{target}"' in line]
+
+
+def _in_scope(op_name, scope):
+    # as benchmarks/harness/hlo_scopes.py reads it: a whole component,
+    # bare or wrapped by a transform
+    return scope in re.split(r"[/()]", op_name)
+
+
+def test_chunked_delta_rule_fwd_bwd_compiles_in_its_memory(one_chip):
+    """``ops/kda.py``'s XLA form at the kimi-linear cell's shapes: what
+    its backward keeps is one 16-chunk segment's intermediates, not the
+    sequence's (3.39 GiB before the segments, which the step could not
+    hold)."""
+    compiled = jax.jit(jax.grad(_kda_loss(), argnums=range(5))).lower(
+        *_kda_args(one_chip)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 0.75 * 2**30
+    assert not _op_names(compiled.as_text())
+    assert trace.gauges()["kda.kernel"] == 0
+
+
+def test_chunked_delta_rule_kernels_compile_in_the_same_memory(
+        one_chip, kernels_are_the_path):
+    """The Pallas kernels there: one call forward; under differentiation
+    the forward again with a state a chunk (256 MiB, all the backward
+    keeps beside the inputs) and the hand-written backward. Nothing
+    passes between kernels but that, so no segments."""
+    args = _kda_args(one_chip)
+    names = _op_names(_compile(_kda_loss(), *args))
+    assert len(names) == 1 and _in_scope(names[0], "kda_chunk")
+    compiled = jax.jit(jax.grad(_kda_loss(), argnums=range(5))).lower(
+        *args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.75 * 2**30
+    hlo = compiled.as_text()
+    names = _op_names(hlo)
+    assert len(names) == 2 and all(_in_scope(n, "kda_chunk") for n in names)
+    assert sum("kda_fwd" in n for n in names) == 1
+    assert sum("kda_bwd" in n for n in names) == 1
+    assert "riangular" not in hlo         # no triangular_solve is left
+    assert trace.gauges()["kda.kernel"] == 1
+    assert trace.gauges()["kda.heads_per_step"] == 4
+    assert trace.gauges()["kda.chunks_per_step"] == 2
 
 
 @pytest.mark.parametrize("attn", ["kda", "mla"])
@@ -337,11 +386,29 @@ def test_kimi_linear_expert_block_fwd_bwd_compiles(
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(lp, x).compile()
     hlo = compiled.as_text()
     # latent attention without rotary still runs the 192 / 128 kernels;
-    # a KDA block runs none
+    # a KDA block runs none of them and its own two instead: the remat
+    # forward and the backward (the loss's value is not asked for, so
+    # the first forward is gone), both under the scope the device
+    # metrics select by
     flash = 1 if attn == "mla" else 0
     assert _kernel_calls(hlo, "attention_fwd") == flash
     assert _kernel_calls(hlo, "attention_bwd") == 2 * flash
     assert _kernel_calls(hlo, "grouped_matmul") == 9
+    delta = [n for n in _op_names(hlo) if "/kda_" in n]
+    assert len(delta) == (0 if flash else 2)
+    assert all(_in_scope(n, "kda_chunk") for n in delta)
+    if not flash:
+        # what the XLA form cost beside its loops: the solves and the
+        # float32 moves of (8192, 4096) into chunk-major order
+        assert "riangular" not in hlo
+        moved = [line for line in hlo.splitlines()
+                 if "f32[" in line and " transpose(" in line
+                 and _in_scope(line, "kda_chunk")
+                 and re.search(r"f32\[[0-9,]*\]", line)
+                 and np.prod([int(d) for d in re.search(
+                     r"f32\[([0-9,]*)\]", line).group(1).split(",")
+                 ]) >= 8192 * 4096]
+        assert not moved
     # a block's own temporaries fit beside the cell's 7.16 GiB of state
     # and 4.78 of float32 gradients
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
@@ -460,6 +527,15 @@ def test_flash_four_chip_cell_compiles_at_chosen_tiles(
     assert _compile(loss, *args).count("tpu_custom_call") == 1
     hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), *args)
     assert hlo.count("tpu_custom_call") == 3
+
+
+def test_chunked_delta_rule_compiles_over_four_chips(
+        mesh4, kernels_are_the_path):
+    # a sequence a device: the same kernels on each device's batch row
+    args = _kda_args(NamedSharding(mesh4, P(BATCH_AXES)), batch=4)
+    hlo = _compile(jax.grad(_kda_loss(mesh4), argnums=range(5)), *args)
+    names = _op_names(hlo)
+    assert len(names) == 2 and all(_in_scope(n, "kda_chunk") for n in names)
 
 
 def test_fused_ce_compiles_over_four_chips(mesh4, kernels_are_the_path):
