@@ -1,0 +1,2 @@
+"""``attn_proj_ms``: see ``attn_proj_ms.json``."""
+from benchmarks.harness.step_phases import read_scopes as read  # noqa: F401

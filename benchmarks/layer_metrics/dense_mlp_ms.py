@@ -1,0 +1,2 @@
+"""``dense_mlp_ms``: see ``dense_mlp_ms.json``."""
+from benchmarks.harness.step_phases import read_scopes as read  # noqa: F401

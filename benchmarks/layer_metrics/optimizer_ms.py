@@ -1,0 +1,2 @@
+"""``optimizer_ms``: see ``optimizer_ms.json``."""
+from benchmarks.harness.step_phases import read_phase as read  # noqa: F401

@@ -1,0 +1,2 @@
+"""``remat_fwd_ms``: see ``remat_fwd_ms.json``."""
+from benchmarks.harness.step_phases import read_phase as read  # noqa: F401

@@ -352,17 +352,17 @@ def kda_inputs(cfg: KimiLinearConfig, lp: Params, y):
     b, s, _ = y.shape
     h, d = cfg.kda_heads, cfg.kda_head_dim
     f32 = jnp.float32
-    with jax.named_scope("kda_proj"):
+    with trace.scope("kda_proj"):
         qkv = [y @ lp[name].astype(dt) for name in ("w_q", "w_k", "w_v")]
         decay = (y @ lp["w_f1"].astype(dt)) @ lp["w_f2"].astype(dt)
         gate = ((y @ lp["w_g1"].astype(dt)) @ lp["w_g2"].astype(dt)
                 + lp["b_g2"].astype(dt))
         step = y @ lp["w_b"].astype(dt)
-    with jax.named_scope("kda_conv"):
+    with trace.scope("kda_conv"):
         q, k, v = (
             jax.nn.silu(kda.causal_conv(a, lp[name])).reshape(b, s, h, d)
             for a, name in zip(qkv, ("conv_q", "conv_k", "conv_v")))
-    with jax.named_scope("kda_gate"):
+    with trace.scope("kda_gate"):
         q = (_l2_norm(q.astype(f32)) * d ** -0.5).astype(dt)
         k = _l2_norm(k.astype(f32)).astype(dt)
         g = -jnp.exp(lp["a_log"].astype(f32))[:, None] * jax.nn.softplus(
@@ -374,9 +374,9 @@ def kda_inputs(cfg: KimiLinearConfig, lp: Params, y):
 def kda_attention(cfg: KimiLinearConfig, lp: Params, y, mesh=None):
     b, s, _ = y.shape
     q, k, v, g, beta, gate = kda_inputs(cfg, lp, y)
-    with jax.named_scope("kda_chunk"):
+    with trace.scope("kda_chunk"):
         o = kda.chunk_kda(q, k, v, g, beta, chunk=cfg.kda_chunk, mesh=mesh)
-    with jax.named_scope("kda_out"):
+    with trace.scope("kda_out"):
         o = rms_norm(o, lp["o_norm"], cfg.norm_eps)
         o = (o.astype(jnp.float32)
              * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(cfg.dtype)
@@ -387,17 +387,21 @@ def block(cfg: KimiLinearConfig, mesh, attn: str, ffn: str, lp: Params, x):
     """``h += Attn(RMSNorm(h)); h += FFN(RMSNorm(h))`` for the kinds the
     pattern gives this layer."""
     eps = cfg.norm_eps
-    y = rms_norm(x, lp["attn_norm"], eps)
+    with trace.scope("norm"):
+        y = rms_norm(x, lp["attn_norm"], eps)
     if attn == "kda":
         x = x + kda_attention(cfg, lp, y, mesh=mesh)
     else:
         x = x + xing4.latent_attention(cfg, mesh, None, None, lp, y)
-    y = rms_norm(x, lp["mlp_norm"], eps)
+    with trace.scope("norm"):
+        y = rms_norm(x, lp["mlp_norm"], eps)
     if ffn == "moe":
         x = x + moe.moe_mlp(cfg.as_moe(), lp, y, mesh)[0]
     else:
-        x = x + llama.swiglu(
-            y, lp["w_gate"], lp["w_up"], lp["w_down"], cfg.dtype)
+        with trace.scope("dense_mlp"):
+            y = llama.swiglu(
+                y, lp["w_gate"], lp["w_up"], lp["w_down"], cfg.dtype)
+        x = x + y
     if mesh is not None:
         x = lax.with_sharding_constraint(
             x, NamedSharding(mesh, P(BATCH_AXES, None, None)))
@@ -447,8 +451,9 @@ def forward_hidden(
 ) -> jnp.ndarray:
     """Final-norm hidden states (b, s, dim): the pre-unembed
     factorization the fused cross-entropy takes."""
-    return rms_norm(forward_layers(params, tokens, cfg, mesh),
-                    params["final_norm"], cfg.norm_eps)
+    x = forward_layers(params, tokens, cfg, mesh)
+    with trace.scope("norm"):
+        return rms_norm(x, params["final_norm"], cfg.norm_eps)
 
 
 def loss_fn(

@@ -36,6 +36,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
+from dlrover_tpu.observability import trace
 from dlrover_tpu.ops import (
     apply_rope,
     cross_entropy_sums,
@@ -449,25 +450,32 @@ def _decoder_layer(cfg: LlamaConfig, mesh, inv_freq, positions, lp, x,
             return _tp_region_out(partial, TP)
         return lax.psum(partial, TP)
 
-    y = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    with trace.scope("norm"):
+        y = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     if marker:
         y = _tp_region_in(y, TP)
-    q = (y @ lp["wq"].astype(dt)).reshape(b, s, h, hd)
-    k = (y @ lp["wk"].astype(dt)).reshape(b, s, kvh, hd)
-    v = (y @ lp["wv"].astype(dt)).reshape(b, s, kvh, hd)
-    q = apply_rope(q, positions, inv_freq)
-    k = apply_rope(k, positions, inv_freq)
+    with trace.scope("attn_proj"):
+        q = (y @ lp["wq"].astype(dt)).reshape(b, s, h, hd)
+        k = (y @ lp["wk"].astype(dt)).reshape(b, s, kvh, hd)
+        v = (y @ lp["wv"].astype(dt)).reshape(b, s, kvh, hd)
+        q = apply_rope(q, positions, inv_freq)
+        k = apply_rope(k, positions, inv_freq)
     if attn_fn is None:
         attn = _attention(cfg, mesh, q, k, v).reshape(b, s, h * hd)
     else:
         attn = attn_fn(q, k, v).reshape(b, s, h * hd)
-    x = x + close_row_parallel(attn @ lp["wo"].astype(dt))
+    with trace.scope("attn_proj"):
+        attn = close_row_parallel(attn @ lp["wo"].astype(dt))
+    x = x + attn
 
-    y = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+    with trace.scope("norm"):
+        y = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     if marker:
         y = _tp_region_in(y, TP)
-    x = x + close_row_parallel(
-        swiglu(y, lp["w_gate"], lp["w_up"], lp["w_down"], dt))
+    with trace.scope("dense_mlp"):
+        y = close_row_parallel(
+            swiglu(y, lp["w_gate"], lp["w_up"], lp["w_down"], dt))
+    x = x + y
 
     if mesh is not None:
         from jax.sharding import NamedSharding
@@ -582,7 +590,8 @@ def forward_hidden(
         return layer_fn(lp, x), None
 
     x, _ = lax.scan(scan_body, x, params["layers"])
-    return rms_norm(x, params["final_norm"], cfg.norm_eps)
+    with trace.scope("norm"):
+        return rms_norm(x, params["final_norm"], cfg.norm_eps)
 
 
 def unembed(x: jnp.ndarray, lm_head: jnp.ndarray) -> jnp.ndarray:
@@ -1031,9 +1040,9 @@ def _pp_gpipe(
                 lax.dynamic_index_in_dim(x_mb, mb_in, keepdims=False),
                 recv,
             )
-            with jax.named_scope("stage_fwd"):
+            with trace.scope("stage_fwd"):
                 out = run_slab(inp)
-            with jax.named_scope("pp_send_recv"):
+            with trace.scope("pp_send_recv"):
                 recv_next = lax.ppermute(out, PP, fwd_perm)
             # collect finished microbatches (real only on the last stage;
             # early bubble writes land on index 0 and are overwritten by
@@ -1212,7 +1221,7 @@ def _pp_1f1b_run(static: _PPStatic, layers, x_micro, final_norm, lm_head,
                     lax.dynamic_index_in_dim(x_mb, i_f, keepdims=False),
                     recv_act,
                 )
-                with jax.named_scope("stage_fwd"):
+                with trace.scope("stage_fwd"):
                     out = run_slab(layers_local, inp)
                 act_buf = lax.dynamic_update_index_in_dim(
                     act_buf, inp, i_f % pp_size, 0
@@ -1236,7 +1245,7 @@ def _pp_1f1b_run(static: _PPStatic, layers, x_micro, final_norm, lm_head,
                 (act_buf, gin_buf, nll, nv, g_fn, g_lm),
             )
             # collective OUTSIDE the cond: every rank participates
-            with jax.named_scope("pp_send_recv"):
+            with trace.scope("pp_send_recv"):
                 recv_act = lax.ppermute(out, PP, fwd_perm)
 
             # ---- backward op ------------------------------------------
@@ -1252,7 +1261,7 @@ def _pp_1f1b_run(static: _PPStatic, layers, x_micro, final_norm, lm_head,
                 inp = lax.dynamic_index_in_dim(
                     act_buf, i_b % pp_size, keepdims=False
                 )
-                with jax.named_scope("stage_bwd"):
+                with trace.scope("stage_bwd"):
                     _, pull = jax.vjp(run_slab, layers_local, inp)
                     gl, gx = pull(g_out)
                 g_layers = jax.tree.map(jnp.add, g_layers, gl)
@@ -1271,7 +1280,7 @@ def _pp_1f1b_run(static: _PPStatic, layers, x_micro, final_norm, lm_head,
             (g_layers, g_x), gx = lax.cond(
                 do_bwd, bwd_branch, bwd_skip, (g_layers, g_x)
             )
-            with jax.named_scope("pp_send_recv"):
+            with trace.scope("pp_send_recv"):
                 recv_grad = lax.ppermute(gx, PP, bwd_perm)
 
             return (recv_act, recv_grad, act_buf, gin_buf,
@@ -1460,7 +1469,7 @@ def _pp_interleaved_run(static: _PPStatic, layers, x_micro, final_norm,
              g_layers, g_fn, g_lm, g_x, nll, nv) = carry
 
             # -- ring delivery of the previous tick's outputs ----------
-            with jax.named_scope("pp_send_recv"):
+            with trace.scope("pp_send_recv"):
                 win_f = lax.ppermute(wire_f, PP, ring_fwd)
                 win_b = lax.ppermute(wire_b, PP, ring_bwd)
 
@@ -1491,7 +1500,7 @@ def _pp_interleaved_run(static: _PPStatic, layers, x_micro, final_norm,
                     lax.dynamic_index_in_dim(x_mb, f_i, keepdims=False),
                     b_get(recv_act, f_u, f_i % S),
                 )
-                with jax.named_scope("stage_fwd"):
+                with trace.scope("stage_fwd"):
                     out = run_chunk(chunk_params(f_u), inp)
                 act_saved = b_set(act_saved, inp, f_u, f_i % S)
                 is_lastc = is_last & (f_u == v - 1)
@@ -1522,7 +1531,7 @@ def _pp_interleaved_run(static: _PPStatic, layers, x_micro, final_norm,
                 g_layers, g_x = ops
                 g_out = b_get(recv_grad, b_u, b_i % S)
                 inp = b_get(act_saved, b_u, b_i % S)
-                with jax.named_scope("stage_bwd"):
+                with trace.scope("stage_bwd"):
                     _, pull = jax.vjp(run_chunk, chunk_params(b_u), inp)
                     gl, gx = pull(g_out)
 

@@ -380,7 +380,7 @@ def _route(cfg: MoeConfig, router, yt, token_axes=(), bias=None):
     uniform) over all the tokens of the step: inside a ``shard_map`` the
     counts and the mean probabilities are reduced over ``token_axes``."""
     e = cfg.n_experts
-    with jax.named_scope("moe_route"):
+    with trace.scope("moe_route"):
         probs, top_p, top_e = route(cfg, router, yt, bias)
         counts = jnp.sum(jax.nn.one_hot(top_e, e, dtype=jnp.int32), (0, 1))
         mean_prob = probs.mean(axis=0)
@@ -396,16 +396,16 @@ def _experts(lp: Params, yt, top_p, top_e, n_local: int, first: Any = 0):
     """The ``n_local`` experts ``first ..`` applied to the pairs of
     ``yt (t, d)`` that chose them, weighted and summed: ``(t, d)``."""
     k = top_e.shape[1]
-    with jax.named_scope("moe_dispatch"):
+    with trace.scope("moe_dispatch"):
         order, inverse, group_sizes = sort_pairs(top_e, n_local, first)
         xs = dispatch_rows(yt, order, inverse, k)
-    with jax.named_scope("moe_experts"):
+    with trace.scope("moe_experts"):
         gate = grouped_matmul(xs, lp["w_gate"], group_sizes)
         up = grouped_matmul(xs, lp["w_up"], group_sizes)
         rows = grouped_matmul(
             jax.nn.silu(gate) * up, lp["w_down"], group_sizes
         )
-    with jax.named_scope("moe_combine"):
+    with trace.scope("moe_combine"):
         return combine_rows(rows, top_p, order, inverse)
 
 
@@ -438,7 +438,7 @@ def _moe_tokens_sharded(cfg: MoeConfig, lp: Params, y):
 def _shared_expert(lp: Params, y: jnp.ndarray) -> jnp.ndarray:
     """The always-on expert: the dense SwiGLU of ``models/llama.py`` on
     every token, partitioned as any dense matmul is."""
-    with jax.named_scope("moe_shared"):
+    with trace.scope("moe_shared"):
         return llama.swiglu(
             y, lp["ws_gate"], lp["ws_up"], lp["ws_down"], y.dtype)
 
@@ -493,22 +493,27 @@ def _decoder_layer(cfg: MoeConfig, mesh, inv_freq, positions, lp, x):
     b, s, d = x.shape
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
-    y = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = y @ lp["wq"].astype(dt)
-    k = y @ lp["wk"].astype(dt)
-    if cfg.qk_norm:
-        # over the whole projection, before the split into heads
-        q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
-        k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
-    q = q.reshape(b, s, h, hd)
-    k = k.reshape(b, s, kvh, hd)
-    v = (y @ lp["wv"].astype(dt)).reshape(b, s, kvh, hd)
-    q = apply_rope(q, positions, inv_freq)
-    k = apply_rope(k, positions, inv_freq)
+    with trace.scope("norm"):
+        y = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    with trace.scope("attn_proj"):
+        q = y @ lp["wq"].astype(dt)
+        k = y @ lp["wk"].astype(dt)
+        if cfg.qk_norm:
+            # over the whole projection, before the split into heads
+            q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
+        q = q.reshape(b, s, h, hd)
+        k = k.reshape(b, s, kvh, hd)
+        v = (y @ lp["wv"].astype(dt)).reshape(b, s, kvh, hd)
+        q = apply_rope(q, positions, inv_freq)
+        k = apply_rope(k, positions, inv_freq)
     attn = llama._attention(cfg.as_llama(), mesh, q, k, v).reshape(b, s, h * hd)
-    x = x + attn @ lp["wo"].astype(dt)
+    with trace.scope("attn_proj"):
+        attn = attn @ lp["wo"].astype(dt)
+    x = x + attn
 
-    y = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+    with trace.scope("norm"):
+        y = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     moe_out, aux = moe_mlp(cfg, lp, y, mesh)
     x = x + moe_out
 
@@ -572,7 +577,8 @@ def forward_hidden(
     (x, aux_sum), _ = lax.scan(
         scan_body, (x, jnp.zeros((), jnp.float32)), params["layers"]
     )
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    with trace.scope("norm"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return x, aux_sum / cfg.n_layers
 
 

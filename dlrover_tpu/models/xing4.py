@@ -367,7 +367,7 @@ def hc_coefficients(cfg: Xing4Config, phi, alpha, bias, X):
     Sinkhorn's 40 normalisations)."""
     n = cfg.hc_mult
     lo, hi = cfg.hc_clamp
-    with jax.named_scope("hc_coeff"):
+    with trace.scope("hc_coeff"):
         x32 = X.astype(jnp.float32)
         inv_rms = lax.rsqrt(jnp.mean(x32 * x32, axis=(0, 3)) + cfg.norm_eps)
         raw = jnp.einsum(
@@ -387,7 +387,7 @@ def hc_coefficients(cfg: Xing4Config, phi, alpha, bias, X):
 
 def hc_pre_mix(h_pre, X):
     """``y = sum_i H_pre[i] X[i]``: (b, s, d) in the streams' dtype."""
-    with jax.named_scope("hc_mix"):
+    with trace.scope("hc_mix"):
         y = sum(h_pre[i][..., None] * X[i].astype(jnp.float32)
                 for i in range(X.shape[0]))
         return y.astype(X.dtype)
@@ -396,7 +396,7 @@ def hc_pre_mix(h_pre, X):
 def hc_post_mix(h_post, h_res, X, z):
     """``X'[i] = sum_j H_res[i, j] X[j] + H_post[i] z``."""
     n = X.shape[0]
-    with jax.named_scope("hc_mix"):
+    with trace.scope("hc_mix"):
         x32 = [X[j].astype(jnp.float32) for j in range(n)]
         z32 = z.astype(jnp.float32)
         return jnp.stack([
@@ -433,7 +433,7 @@ def latent_attention(cfg, mesh, positions, inv_freq, lp, y):
     b, s, _ = y.shape
     h, rkv = cfg.n_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-    with jax.named_scope("mla_proj"):
+    with trace.scope("mla_proj"):
         if "w_qa" in lp:
             c_q = rms_norm(y @ lp["w_qa"].astype(dt), lp["q_a_norm"], eps)
             q = c_q @ lp["w_qb"].astype(dt)
@@ -457,7 +457,7 @@ def latent_attention(cfg, mesh, positions, inv_freq, lp, y):
         v = kv[..., dn:]
     out = flash_attention(q, k, v, causal=True, mesh=mesh,
                           scale=cfg.softmax_scale)
-    with jax.named_scope("mla_proj"):
+    with trace.scope("mla_proj"):
         return out.reshape(b, s, h * dv) @ lp["w_o"].astype(dt)
 
 
@@ -468,16 +468,18 @@ def block(cfg: Xing4Config, mesh, positions, inv_freq, lp: Params, X):
     eps = cfg.norm_eps
 
     def attention(y):
-        return latent_attention(
-            cfg, mesh, positions, inv_freq, lp,
-            rms_norm(y, lp["attn_norm"], eps))
+        with trace.scope("norm"):
+            y = rms_norm(y, lp["attn_norm"], eps)
+        return latent_attention(cfg, mesh, positions, inv_freq, lp, y)
 
     def feed_forward(y):
-        y = rms_norm(y, lp["mlp_norm"], eps)
+        with trace.scope("norm"):
+            y = rms_norm(y, lp["mlp_norm"], eps)
         if "router" in lp:
             return moe.moe_mlp(cfg.as_moe(), lp, y, mesh)[0]
-        return llama.swiglu(
-            y, lp["w_gate"], lp["w_up"], lp["w_down"], cfg.dtype)
+        with trace.scope("dense_mlp"):
+            return llama.swiglu(
+                y, lp["w_gate"], lp["w_up"], lp["w_down"], cfg.dtype)
 
     X = hc_sublayer(cfg, lp, "hc_attn", X, attention)
     X = hc_sublayer(cfg, lp, "hc_mlp", X, feed_forward)
@@ -552,16 +554,19 @@ def _mtp_hidden(params: Params, tokens, targets, h, cfg: Xing4Config, mesh):
     ``t_{i+1}`` (-1 past the end, where 0 stands in: causal attention
     keeps what follows out of every position that has a target)."""
     mp, eps = params["mtp"], cfg.norm_eps
-    with jax.named_scope("mtp"):
+    with trace.scope("mtp"):
         e = embed_lookup(
             params["embed"], jnp.maximum(targets, 0), mesh, cfg.dtype)
-        both = jnp.concatenate(
-            [rms_norm(e, mp["enorm"], eps), rms_norm(h, mp["hnorm"], eps)],
-            axis=-1)
+        with trace.scope("norm"):
+            both = jnp.concatenate(
+                [rms_norm(e, mp["enorm"], eps),
+                 rms_norm(h, mp["hnorm"], eps)], axis=-1)
         X = _streams(cfg, both @ mp["w_eh"].astype(cfg.dtype))
         lp = jax.tree.map(lambda a: a[0], mp["block"])
         X = _block_fn(cfg, mesh, tokens)(lp, X)
-        return rms_norm(_sum_streams(X), mp["norm"], eps)
+        h = _sum_streams(X)
+        with trace.scope("norm"):
+            return rms_norm(h, mp["norm"], eps)
 
 
 def loss_terms(
@@ -580,11 +585,13 @@ def loss_terms(
             mesh=mesh)
         return nll_sum / jnp.maximum(n_valid, 1.0)
 
-    main = ce(rms_norm(h, params["final_norm"], cfg.norm_eps), targets)
+    with trace.scope("norm"):
+        x = rms_norm(h, params["final_norm"], cfg.norm_eps)
+    main = ce(x, targets)
     if not cfg.mtp_depth:
         return main, jnp.zeros((), jnp.float32), h
     x = _mtp_hidden(params, tokens, targets, h, cfg, mesh)
-    with jax.named_scope("mtp"):
+    with trace.scope("mtp"):
         return main, ce(x, llama._shift_targets(targets)), h
 
 

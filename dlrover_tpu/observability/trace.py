@@ -35,6 +35,17 @@ Every span closed through ``span()`` reaches
 Back-dated ``record()`` (an emitter that measured its own duration, the
 synthetic resize lane) reaches the ring only.
 
+Device side
+-----------
+``scope(name)`` is ``span()``'s sibling for what runs on the device: it
+returns ``jax.named_scope(name)``, so every instruction traced under it
+carries ``name`` in its ``op_name`` metadata (the compiled step's text,
+``step.hlo``, is where a reader finds it: a device trace has none), and
+it records ``name``. ``scopes()`` hands the names out: a reader that
+asks what *no* scope names needs the list of those that are, and a list
+kept anywhere else goes stale with the next scope. Trace time only:
+nothing of it is on a step's path.
+
 Identity
 --------
 ``id`` is a process-wide counter. ``parent`` is the span that caused
@@ -65,6 +76,7 @@ emitters in ``ElasticTrainer.step``). When ``DLROVER_TPU_TRACE`` is off
 
 from __future__ import annotations
 
+import contextlib
 import contextvars
 import itertools
 import json
@@ -108,6 +120,13 @@ _span_ids = itertools.count(1)
 _current_span: contextvars.ContextVar = contextvars.ContextVar(
     "dlrover_tpu_span", default=None
 )
+
+
+def _named_scope(name: str):
+    """``jax.named_scope(name)`` if the process has loaded JAX, else a
+    context that does nothing. Looked up, never imported."""
+    cls = getattr(sys.modules.get("jax"), "named_scope", None)
+    return contextlib.nullcontext() if cls is None else cls(name)
 
 
 def _profiler_annotation():
@@ -201,6 +220,7 @@ class TraceRing:
         self._counters: Dict[str, List] = {}     # name -> [count, seconds]
         self._gauges: Dict[str, float] = {}
         self._texts: Dict[str, Any] = {}
+        self._scopes: set = set()
 
     # -- recording -----------------------------------------------------
 
@@ -281,6 +301,14 @@ class TraceRing:
             if ev is not None:
                 self._append(ev, counts_for_kind=not sp._nested)
 
+    def scope(self, name: str):
+        """``with trace.scope("dense_mlp"): ...`` around the tracing of
+        device work: ``jax.named_scope(name)``, and ``name`` joins
+        ``scopes()`` (module docstring, "Device side")."""
+        with self._lock:
+            self._scopes.add(name)
+        return _named_scope(name)
+
     def gauge(self, name: str, value: float) -> None:
         """A fact that is not a duration (bytes of the compiled step's
         peak, bytes the last save staged): the last value set wins."""
@@ -300,6 +328,13 @@ class TraceRing:
         with self._lock:
             producer = self._texts.get(name)
         return None if producer is None else producer()
+
+    def scopes(self) -> List[str]:
+        """The names ``scope()`` has been given in this process, sorted.
+        ``clear()`` keeps them: JAX caches what it traced, so a scope
+        inside a cached function is not opened a second time."""
+        with self._lock:
+            return sorted(self._scopes)
 
     def events(self) -> List[Dict]:
         with self._lock:
@@ -385,6 +420,8 @@ def record(kind: str, name: str, start_mono: float, dur_s: float, **attrs):
 
 
 span = trace_ring.span
+scope = trace_ring.scope
+scopes = trace_ring.scopes
 gauge = trace_ring.gauge
 counters = trace_ring.counters
 gauges = trace_ring.gauges

@@ -475,6 +475,7 @@ def _flash_fwd_pallas(q, k, v, causal: bool, block_q: int, block_k: int,
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="attention_fwd",
     )(qt, kt, vt)
     out = out.reshape(b, h, sq, dv).transpose(0, 2, 1, 3)
     return out, lse.reshape(b, h, sq, _LSE_LANES)[..., 0]
@@ -630,6 +631,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
         scratch_shapes=[pltpu.VMEM((group * block_q, d), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="attention_bwd_dq",
     )(
         qt.reshape(b, hkv, group, sq, d), kt, vt,
         dot.reshape(b, hkv, group, sq, dv), lanes8(lse), lanes8(delta),
@@ -682,6 +684,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="attention_bwd_dkv",
     )(qt, kt, vt, dot, lse.reshape(b, h, 1, sq), delta.reshape(b, h, 1, sq))
 
     dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
@@ -714,7 +717,7 @@ def _flash_with_lse_fwd(q, k, v, causal, block_q, block_k, interpret,
                         scale=None):
     # named scope = the kernel ledger's attribution key
     # (profiler/kernel_ledger.py classifies HLO sites by op_name path)
-    with jax.named_scope("attention_fwd"):
+    with trace.scope("attention_fwd"):
         if interpret or _on_tpu():
             tiles = _tiles_for(q, k, v, block_q, block_k)
             if block_q is None:
@@ -730,7 +733,7 @@ def _flash_with_lse_fwd(q, k, v, causal, block_q, block_k, interpret,
 def _flash_with_lse_bwd(causal, block_q, block_k, interpret, scale, res, g):
     q, k, v, o, lse = res
     g_out, g_lse = g
-    with jax.named_scope("attention_bwd"):
+    with trace.scope("attention_bwd"):
         if interpret or _on_tpu():
             tiles = _tiles_for(q, k, v, block_q, block_k)
             return _flash_bwd_pallas(

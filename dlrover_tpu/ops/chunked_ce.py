@@ -38,6 +38,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from dlrover_tpu.observability import trace
+
 #: Default vocab-chunk width: 16 MXU lanes of 128 — wide enough that the
 #: per-chunk [tokens, chunk] matmul stays MXU-bound, narrow enough that
 #: the largest live loss activation is tokens*2048*4 bytes, not tokens*V*4.
@@ -163,13 +165,13 @@ def _ce_forward(chunk: int, x, w, tgt):
 def _chunked_ce(chunk: int, x, w, tgt):
     # named scope = the kernel ledger's attribution key
     # (profiler/kernel_ledger.py classifies HLO sites by op_name path)
-    with jax.named_scope("chunked_ce_fwd"):
+    with trace.scope("chunked_ce_fwd"):
         nll_sum, n_valid, _ = _ce_forward(chunk, x, w, tgt)
     return nll_sum, n_valid
 
 
 def _chunked_ce_fwd(chunk: int, x, w, tgt):
-    with jax.named_scope("chunked_ce_fwd"):
+    with trace.scope("chunked_ce_fwd"):
         nll_sum, n_valid, logz = _ce_forward(chunk, x, w, tgt)
     return (nll_sum, n_valid), (x, w, tgt, logz)
 
@@ -183,7 +185,7 @@ def _chunked_ce_bwd(chunk: int, res, cot):
     (x, w); its cotangent is dropped."""
     x, w, tgt, logz = res
     g_nll, _g_nv = cot
-    with jax.named_scope("chunked_ce_bwd"):
+    with trace.scope("chunked_ce_bwd"):
         return _chunked_ce_bwd_impl(chunk, x, w, tgt, logz, g_nll)
 
 

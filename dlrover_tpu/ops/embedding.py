@@ -72,7 +72,7 @@ def embed_lookup(
     gathers = mesh is None or mesh.shape[TP] == 1
     trace.gauge("embed.gather", int(gathers))
     trace.gauge("embed.vocab", vocab)
-    with jax.named_scope("embed_lookup"):
+    with trace.scope("embed_lookup"):
         if gathers:
             return _lookup(table, tokens, mesh, vocab)
         one_hot = jax.nn.one_hot(tokens, vocab, dtype=dtype)
@@ -123,7 +123,7 @@ def _lookup_bwd(mesh, vocab, tokens, dy):
     def local(tokens, dy):
         return _row_sums(tokens.reshape(-1), dy.reshape(-1, dim), vocab)
 
-    with jax.named_scope("embed_lookup"):
+    with trace.scope("embed_lookup"):
         if _one_device(mesh):
             return local(tokens, dy), None
 

@@ -395,6 +395,7 @@ def _fused_ce_fwd_pallas(x2, w, tgt1, v, bt, bv, interpret, with_dx):
         scratch_shapes=[carry, carry, carry],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name="fused_ce_fwd",
     )(x2, w, _lanes(tgt1))
     return (logz[:, 0], gold[:, 0], *dx)
 
@@ -465,6 +466,7 @@ def _fused_ce_dw_pallas(x2, w, tgt1, logz, row_scale, v, bt, bv,
         scratch_shapes=[pltpu.VMEM((d, bv), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name="fused_ce_bwd_dw",
     )(x2, w, _lanes(tgt1), _lanes(logz), _lanes(row_scale))
 
 
@@ -525,7 +527,7 @@ def _fused_ce_run_fwd(block_t, block_v, interpret, x, w, tgt, with_dx):
     _report_sweeps(False)
     # named scope = the kernel ledger's attribution key
     # (profiler/kernel_ledger.py classifies HLO sites by op_name path)
-    with jax.named_scope("fused_ce_fwd"):
+    with trace.scope("fused_ce_fwd"):
         bt, bv, n, x2p, wp, tgt1p = _padded(
             LOSS_DX if with_dx else LOSS, block_t, block_v, x, w, tgt
         )
@@ -562,7 +564,7 @@ def _fused_ce_bwd(block_t, block_v, interpret, res, cot):
     x, w, tgt, logz, dx_soft = res
     g_nll, _g_nv = cot
     _report_sweeps(True)    # the forward's sweep, and dw's
-    with jax.named_scope("fused_ce_bwd"):
+    with trace.scope("fused_ce_bwd"):
         bt, bv, n, x2p, wp, tgt1p = _padded(
             DW, block_t, block_v, x, w, tgt
         )

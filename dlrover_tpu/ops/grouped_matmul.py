@@ -369,6 +369,6 @@ def grouped_matmul(lhs, rhs, group_sizes, *, interpret: bool = False):
     if tiles is None:
         return lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32),
                               preferred_element_type=lhs.dtype)
-    with jax.named_scope("grouped_matmul"):
+    with trace.scope("grouped_matmul"):
         return _grouped_matmul(lhs, rhs, group_sizes.astype(jnp.int32),
                                tiles, bool(interpret))

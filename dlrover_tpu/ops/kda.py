@@ -724,7 +724,7 @@ def _kda_backward(chunk, interpret, res, do):
     dv = v.shape[-1]
     # a custom_vjp's backward is traced outside the caller's scopes: the
     # device metrics find the op by this one
-    with jax.named_scope("kda_chunk"):
+    with trace.scope("kda_chunk"):
         *arrays, do = _tiles(
             [q, k, v, g.astype(_F32), beta.astype(_F32), do], s)
         padded = do.shape[1]
