@@ -30,8 +30,9 @@ What is this family's own:
       o = the gated delta rule over (q, k, v, g, b)          ops/kda.py
       y = W_o concat_h[RMSNorm_d(o) * sigmoid(x W_g1 W_g2 + b_g2)]
 
-  under the named scopes ``kda_proj``, ``kda_conv``, ``kda_gate``,
-  ``kda_chunk``, ``kda_out``.
+  under the named scopes ``kda_proj``, ``kda_conv`` (the second line
+  too: ``kda.conv_silu_norm``), ``kda_gate`` (``g`` and ``b``),
+  ``kda_chunk``, ``kda_out`` (``kda.norm_gate`` and ``W_o``).
 - every block is ``h += Attn(RMSNorm(h)); h += FFN(RMSNorm(h))``; no aux
   loss (the choice bias balances the load in the published recipe, by an
   update this program does not make, as in ``models/xing4.py``).
@@ -339,15 +340,13 @@ def validate_for_mesh(cfg: KimiLinearConfig, mesh: Mesh, batch: int = 0
 # The KDA layer, the block, the forward
 # ---------------------------------------------------------------------------
 
-def _l2_norm(x32):
-    return x32 * lax.rsqrt(jnp.sum(x32 * x32, axis=-1, keepdims=True) + 1e-6)
-
-
-def kda_inputs(cfg: KimiLinearConfig, lp: Params, y):
+def kda_inputs(cfg: KimiLinearConfig, lp: Params, y, mesh=None,
+               interpret: bool = False):
     """``y (b, s, d)``, pre-normed -> what the delta rule takes (``q, k,
     v (b, s, h, 128)`` in the activation dtype, log-decay ``g (b, s, h,
     128)`` and step ``beta (b, s, h)`` float32) and the output gate's
-    logits ``(b, s, h, 128)``."""
+    logits ``(b, s, h, 128)``. ``interpret`` (tests): the layer's Pallas
+    forms on the CPU."""
     dt = cfg.dtype
     b, s, _ = y.shape
     h, d = cfg.kda_heads, cfg.kda_head_dim
@@ -359,28 +358,27 @@ def kda_inputs(cfg: KimiLinearConfig, lp: Params, y):
                 + lp["b_g2"].astype(dt))
         step = y @ lp["w_b"].astype(dt)
     with trace.scope("kda_conv"):
-        q, k, v = (
-            jax.nn.silu(kda.causal_conv(a, lp[name])).reshape(b, s, h, d)
-            for a, name in zip(qkv, ("conv_q", "conv_k", "conv_v")))
+        q, k, v = kda.conv_silu_norm(
+            qkv, [lp[name] for name in ("conv_q", "conv_k", "conv_v")],
+            heads=h, scales=(d ** -0.5, 1.0, None), interpret=interpret,
+            mesh=mesh)
     with trace.scope("kda_gate"):
-        q = (_l2_norm(q.astype(f32)) * d ** -0.5).astype(dt)
-        k = _l2_norm(k.astype(f32)).astype(dt)
         g = -jnp.exp(lp["a_log"].astype(f32))[:, None] * jax.nn.softplus(
             decay.astype(f32) + lp["dt_bias"].astype(f32)).reshape(b, s, h, d)
         beta = jax.nn.sigmoid(step.astype(f32))
     return q, k, v, g, beta, gate.reshape(b, s, h, d)
 
 
-def kda_attention(cfg: KimiLinearConfig, lp: Params, y, mesh=None):
-    b, s, _ = y.shape
-    q, k, v, g, beta, gate = kda_inputs(cfg, lp, y)
+def kda_attention(cfg: KimiLinearConfig, lp: Params, y, mesh=None,
+                  interpret: bool = False):
+    q, k, v, g, beta, gate = kda_inputs(cfg, lp, y, mesh, interpret)
     with trace.scope("kda_chunk"):
-        o = kda.chunk_kda(q, k, v, g, beta, chunk=cfg.kda_chunk, mesh=mesh)
+        o = kda.chunk_kda(q, k, v, g, beta, chunk=cfg.kda_chunk,
+                          interpret=interpret, mesh=mesh)
     with trace.scope("kda_out"):
-        o = rms_norm(o, lp["o_norm"], cfg.norm_eps)
-        o = (o.astype(jnp.float32)
-             * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(cfg.dtype)
-        return o.reshape(b, s, -1) @ lp["w_o"].astype(cfg.dtype)
+        o = kda.norm_gate(o, gate, lp["o_norm"], cfg.norm_eps,
+                          interpret=interpret, mesh=mesh)
+        return o @ lp["w_o"].astype(cfg.dtype)
 
 
 def block(cfg: KimiLinearConfig, mesh, attn: str, ffn: str, lp: Params, x):

@@ -6,6 +6,12 @@ the same equations in XLA ops (matrix products, one triangular solve a
 chunk, a ``lax.scan`` over the chunks), which are also the kernels'
 oracle.
 
+Beside it what the layer does around the rule, elementwise but for a
+shift of rows and a sum over a head's lanes (``conv_silu_norm``: the
+convolution, SiLU and the L2 norms of the inputs; ``norm_gate``: the
+head norm and the gate of the output): on the TPU one Pallas pass a
+direction each, off it XLA's ops (the section at the end).
+
 The recurrence, one head, ``S`` in R^(dk x dv) float32, ``S_0 = 0``::
 
     S'  = Diag(a_t) S_(t-1)                 a_t = exp(g_t) in (0, 1)^dk
@@ -97,6 +103,7 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dlrover_tpu.observability import trace
+from dlrover_tpu.ops.norms import rms_norm
 from dlrover_tpu.parallel.mesh import BATCH_AXES
 
 SUB = 16          # rows of a sub-block of a chunk
@@ -760,6 +767,24 @@ def _kda_kernels_fwd(q, k, v, g, beta, chunk, interpret):
 _kda_kernels.defvjp(_kda_kernels_fwd, _kda_backward)
 
 
+def _over_batch_rows(fn, mesh, args, replicated, out_specs):
+    """``fn(*args, *replicated)``; over a mesh of more than one device
+    under ``shard_map`` on each device's batch rows (the compiler does
+    not partition a Mosaic kernel), ``replicated`` whole on each."""
+    if mesh is None or mesh.size == 1:
+        return fn(*args, *replicated)
+
+    def rows(tree):
+        return jax.tree.map(
+            lambda a: P(BATCH_AXES, *(None,) * (a.ndim - 1)), tree)
+
+    return shard_map(
+        fn, mesh=mesh,
+        in_specs=(*rows(args), *jax.tree.map(lambda _: P(), replicated)),
+        out_specs=out_specs, check_vma=False,
+    )(*args, *replicated)
+
+
 def chunk_kda(q, k, v, g, beta, *, chunk: int = 64, segment: int = 16,
               interpret: bool = False, mesh: Optional[Mesh] = None):
     """The chunked gated delta rule. ``q, k (b, s, h, dk)`` (``q``
@@ -790,10 +815,408 @@ def chunk_kda(q, k, v, g, beta, *, chunk: int = 64, segment: int = 16,
     def kernels(*args):
         return _kda_kernels(*args, chunk, interpret)
 
-    if mesh is None or mesh.size == 1:
-        return kernels(q, k, v, g, beta)
-    wide, narrow = P(BATCH_AXES, None, None, None), P(BATCH_AXES, None, None)
-    return shard_map(
-        kernels, mesh=mesh, in_specs=(wide, wide, wide, wide, narrow),
-        out_specs=wide, check_vma=False,
-    )(q, k, v, g, beta)
+    return _over_batch_rows(kernels, mesh, (q, k, v, g, beta), (),
+                            P(BATCH_AXES, None, None, None))
+
+
+# ---------------------------------------------------------------------------
+# The elementwise passes around the kernels (docs/design/kernels.md 1e):
+# what the layer does to the projections before the delta rule and to
+# its output after it, each direction one Pallas pass that reads every
+# operand once and writes every result once, float32 from the load to
+# the store, on blocks of the ``(b, s, h d)`` arrays where they lie.
+# ---------------------------------------------------------------------------
+
+IO_ROWS = 256     # tokens a grid step of a pass, of HEADS heads' lanes
+HALO = 16         # rows of the small block before (after) a tile: the
+                  # convolution's w - 1 rows, in whole bf16 sublane tiles
+_L2_EPS = 1e-6
+
+
+def _l2_norm(x32):
+    return x32 * lax.rsqrt(jnp.sum(x32 * x32, axis=-1, keepdims=True)
+                           + _L2_EPS)
+
+
+def _conv_silu_norm_xla(xs, taps, heads, scales):
+    out = []
+    for x, w, scale in zip(xs, taps, scales):
+        b, s, _ = x.shape
+        z = jax.nn.silu(causal_conv(x, w)).reshape(b, s, heads, -1)
+        if scale is not None:
+            z = (_l2_norm(z.astype(_F32)) * scale).astype(x.dtype)
+        out.append(z)
+    return tuple(out)
+
+
+def _norm_gate_xla(o, gate, weight, eps):
+    b, s = o.shape[:2]
+    o = rms_norm(o, weight, eps)
+    o = (o.astype(_F32) * jax.nn.sigmoid(gate.astype(_F32))).astype(o.dtype)
+    return o.reshape(b, s, -1)
+
+
+def _rows_after(x, before, j):
+    """``x (rows, L)``, ``before (HALO, L)`` float32: row ``t`` of the
+    result is row ``t - j`` of ``[before; x]`` (``j <= HALO``)."""
+    if not j:
+        return x
+    rolled = pltpu.roll(x, j, 0)
+    head = jnp.where(_iota(before.shape, 0) < j, pltpu.roll(before, j, 0),
+                     rolled[:HALO])
+    if x.shape[0] == HALO:
+        return head
+    return jnp.concatenate([head, rolled[HALO:]], axis=0)
+
+
+def _rows_before(x, after, j):
+    """Row ``t`` of the result is row ``t + j`` of ``[x; after]``."""
+    if not j:
+        return x
+    rolled = pltpu.roll(x, x.shape[0] - j, 0)
+    tail = jnp.where(_iota(after.shape, 0) >= HALO - j,
+                     pltpu.roll(after, HALO - j, 0), rolled[-HALO:])
+    return jnp.concatenate([rolled[:-HALO], tail], axis=0)
+
+
+def _conv_rows(x, before, w):
+    """The causal convolution of a tile: ``w (taps, L)``, the last tap
+    the token's own -> ``y`` and the shifted tiles it summed, a tap
+    each."""
+    n = w.shape[0]
+    shifted = [_rows_after(x, before, n - 1 - i) for i in range(n)]
+    y = w[0:1] * shifted[0]
+    for i in range(1, n):
+        y = y + w[i:i + 1] * shifted[i]
+    return y, shifted
+
+
+def _by_head(fn, d, *arrays):
+    """``fn`` on the ``d`` lanes of each head of ``(rows, heads d)``
+    arrays."""
+    width = arrays[0].shape[1]
+    return jnp.concatenate(
+        [fn(*(a[:, at:at + d] for a in arrays)) for at in range(0, width, d)],
+        axis=1)
+
+
+def _sigmoid(x):
+    # through tanh: one transcendental and no division, which the vector
+    # unit would do in software (the input pass 14 % shorter on the chip)
+    return 0.5 * jnp.tanh(0.5 * x) + 0.5
+
+
+def _lane_sum(x):
+    return jnp.sum(x, axis=1, keepdims=True)
+
+
+def _silu_norm(y, scale, d):
+    """``SiLU``, then (``scale`` not None) the L2 norm a head times
+    ``scale``."""
+    z = y * _sigmoid(y)
+    if scale is None:
+        return z
+    return _by_head(
+        lambda zh: zh * (lax.rsqrt(_lane_sum(zh * zh) + _L2_EPS) * scale),
+        d, z)
+
+
+def _silu_norm_bwd(y, dout, scale, d):
+    """The cotangent of ``y`` from that of ``_silu_norm``'s result."""
+    sig = _sigmoid(y)
+    if scale is not None:
+        def head(zh, dh):
+            r = lax.rsqrt(_lane_sum(zh * zh) + _L2_EPS)
+            back = _lane_sum(dh * zh) * (r * r)
+            return (scale * r) * (dh - zh * back)
+        dout = _by_head(head, d, y * sig, dout)
+    return dout * (sig * (1.0 + y * (1.0 - sig)))
+
+
+def _in_fwd_kernel(*refs, scales, d):
+    """A tile of each projection: ``refs`` are (tile, the ``HALO`` rows
+    before it) an array, the taps ``(arrays, taps, L)``, then the
+    outputs."""
+    n = len(scales)
+    taps_ref, outs = refs[2 * n], refs[2 * n + 1:]
+    first = pl.program_id(2) == 0
+    for i, scale in enumerate(scales):
+        x = refs[2 * i][...].astype(_F32)
+        # zeros before the sequence
+        before = jnp.where(first, 0.0, refs[2 * i + 1][...].astype(_F32))
+        y, _ = _conv_rows(x, before, taps_ref[i])
+        outs[i][...] = _silu_norm(y, scale, d).astype(outs[i].dtype)
+
+
+def _in_bwd_kernel(*refs, scales, d):
+    """``refs``: an array (its tile, the ``HALO`` rows before and after
+    it, the cotangent's tile and the rows after it), the taps, then the
+    projections' gradients and the taps' (float32, summed over the
+    tiles). The pre-activation is formed again, of the tile and of the
+    rows after it: the convolution's transpose reads the cotangent of
+    ``y`` up to ``taps - 1`` rows past the tile."""
+    n = len(scales)
+    taps_ref, dxs, dtaps_ref = refs[5 * n], refs[5 * n + 1:-1], refs[-1]
+    first = pl.program_id(2) == 0
+    last = pl.program_id(2) == pl.num_programs(2) - 1
+
+    @pl.when(first)
+    def _():
+        dtaps_ref[...] = jnp.zeros_like(dtaps_ref)
+
+    for i, scale in enumerate(scales):
+        x, before, after, dout, dout_after = (
+            r[...].astype(_F32) for r in refs[5 * i:5 * i + 5])
+        before = jnp.where(first, 0.0, before)
+        # nothing past the sequence reads a token
+        dout_after = jnp.where(last, 0.0, dout_after)
+        w = taps_ref[i]
+        taps = w.shape[0]
+        y, shifted = _conv_rows(x, before, w)
+        y_after, _ = _conv_rows(after, x[-HALO:], w)
+        dy = _silu_norm_bwd(y, dout, scale, d)
+        dy_after = _silu_norm_bwd(y_after, dout_after, scale, d)
+        dx = w[taps - 1:taps] * dy
+        for j in range(1, taps):
+            dx = dx + w[taps - 1 - j:taps - j] * _rows_before(dy, dy_after, j)
+        dxs[i][...] = dx.astype(dxs[i].dtype)
+        for k in range(taps):
+            dtaps_ref[i, k:k + 1, :] += jnp.sum(
+                dy * shifted[k], axis=0, keepdims=True)
+
+
+def _io_call(kernel, name, ins, outs, *, heads, interpret):
+    """A pass over ``(b, s, h d)`` arrays of whole tiles. ``ins`` and
+    ``outs``: ``(kind, array or its shape)``, ``kind`` the block a grid
+    step takes: "tile", ``IO_ROWS`` tokens; "before" and "after", the
+    ``HALO`` rows that end where the tile starts and start where it ends
+    (the sequence's own first and last where there are none: the kernel
+    masks them); "lanes", the step's lanes of a small ``(..., h d)``
+    array (taps, a norm's weight); "sums", the same of a ``(b, ..., h
+    d)`` output the kernel adds to tile by tile. Grid (batch, lanes,
+    tiles), the tiles in order."""
+    b, s, width = next(x.shape for kind, x in ins if kind == "tile")
+    lanes = _heads_a_step(heads) * (width // heads)
+    per = IO_ROWS // HALO
+
+    def spec(kind, x):
+        if kind == "tile":
+            return pl.BlockSpec((None, IO_ROWS, lanes),
+                                lambda bi, li, ti: (bi, ti, li))
+        if kind == "before":
+            return pl.BlockSpec(
+                (None, HALO, lanes),
+                lambda bi, li, ti: (bi, jnp.maximum(ti * per - 1, 0), li))
+        if kind == "after":
+            return pl.BlockSpec(
+                (None, HALO, lanes),
+                lambda bi, li, ti: (
+                    bi, jnp.minimum((ti + 1) * per, s // HALO - 1), li))
+        if kind == "lanes":
+            lead = x.shape[:-1]
+            return pl.BlockSpec(
+                lead + (lanes,), lambda bi, li, ti: (0,) * len(lead) + (li,))
+        lead = x.shape[1:-1]
+        return pl.BlockSpec(
+            (None,) + lead + (lanes,),
+            lambda bi, li, ti: (bi,) + (0,) * len(lead) + (li,))
+
+    return pl.pallas_call(
+        kernel,
+        grid=(b, width // lanes, s // IO_ROWS),
+        in_specs=[spec(*x) for x in ins],
+        out_specs=[spec(*x) for x in outs],
+        out_shape=[x for _, x in outs],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name=name,
+    )(*(x for _, x in ins))
+
+
+def _io_tiles(arrays):
+    """``(b, s, ...)`` -> ``(b, s, h d)``, whole tiles of ``IO_ROWS``."""
+    s = arrays[0].shape[1]
+    pad = ((0, 0), (0, -s % IO_ROWS), (0, 0))
+    return [jnp.pad(a.reshape(*a.shape[:2], -1), pad) for a in arrays]
+
+
+def _like(x):
+    return jax.ShapeDtypeStruct(x.shape, x.dtype)
+
+
+def _taps_rows(taps):
+    """``[(h d, taps)]`` -> ``(arrays, taps, h d)`` float32: a tap is a
+    row of lanes."""
+    return jnp.stack([w.astype(_F32).T for w in taps])
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4), inline=True)
+def _in_forward(xs, taps, heads, scales, interpret):
+    b, s, width = xs[0].shape
+    tiled = _io_tiles(xs)
+    out = _io_call(
+        functools.partial(_in_fwd_kernel, scales=scales, d=width // heads),
+        "kda_in_fwd",
+        [(kind, x) for x in tiled for kind in ("tile", "before")]
+        + [("lanes", _taps_rows(taps))],
+        [("tile", _like(x)) for x in tiled],
+        heads=heads, interpret=interpret)
+    return tuple(o[:, :s].reshape(b, s, heads, -1) for o in out)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2), inline=True)
+def _in_backward(heads, scales, interpret, res, cts):
+    xs, taps = res
+    b, s, width = xs[0].shape
+    # a custom_vjp's backward is traced outside the caller's scopes
+    with trace.scope("kda_conv"):
+        rows, tiled = _taps_rows(taps), _io_tiles(xs)
+        ins = []
+        for x, dout in zip(tiled, _io_tiles(cts)):
+            ins += [(kind, x) for kind in ("tile", "before", "after")]
+            ins += [(kind, dout) for kind in ("tile", "after")]
+        *dxs, dtaps = _io_call(
+            functools.partial(_in_bwd_kernel, scales=scales, d=width // heads),
+            "kda_in_bwd", ins + [("lanes", rows)],
+            [("tile", _like(x)) for x in tiled]
+            + [("sums", jax.ShapeDtypeStruct((b,) + rows.shape, _F32))],
+            heads=heads, interpret=interpret)
+        dtaps = jnp.sum(dtaps, axis=0)
+    return (tuple(dx[:, :s] for dx in dxs),
+            tuple(dw.T.astype(w.dtype) for dw, w in zip(dtaps, taps)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _in_pass(xs, taps, heads, scales, interpret):
+    return _in_forward(xs, taps, heads, scales, interpret)
+
+
+def _in_pass_fwd(xs, taps, heads, scales, interpret):
+    return _in_forward(xs, taps, heads, scales, interpret), (xs, taps)
+
+
+_in_pass.defvjp(_in_pass_fwd, _in_backward)
+
+
+def _out_fwd_kernel(o_ref, gate_ref, w_ref, out_ref, *, d, eps):
+    for at in range(0, o_ref.shape[1], d):
+        o, gate, w = (r[:, at:at + d].astype(_F32)
+                      for r in (o_ref, gate_ref, w_ref))
+        r = lax.rsqrt(_lane_sum(o * o) * (1.0 / d) + eps)
+        out_ref[:, at:at + d] = (o * r * w * _sigmoid(gate)).astype(
+            out_ref.dtype)
+
+
+def _out_bwd_kernel(dout_ref, o_ref, gate_ref, w_ref, do_ref, dgate_ref,
+                    dw_ref, *, d, eps):
+    """The norm is formed again from ``o``; the weight's gradient
+    (float32) is summed over the tiles, a head's lanes each."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    for at in range(0, o_ref.shape[1], d):
+        dout, o, gate, w = (r[:, at:at + d].astype(_F32)
+                            for r in (dout_ref, o_ref, gate_ref, w_ref))
+        r = lax.rsqrt(_lane_sum(o * o) * (1.0 / d) + eps)
+        n, sig = o * r, _sigmoid(gate)
+        dn_w = dout * n                    # the cotangent of w sigmoid(gate)
+        dgate_ref[:, at:at + d] = (
+            dn_w * w * (sig * (1.0 - sig))).astype(dgate_ref.dtype)
+        dw_ref[:, at:at + d] += jnp.sum(dn_w * sig, axis=0, keepdims=True)
+        dn = dout * (w * sig)
+        do_ref[:, at:at + d] = (r * (
+            dn - n * (_lane_sum(dn * n) * (1.0 / d)))
+        ).astype(do_ref.dtype)
+
+
+def _weight_row(weight, heads):
+    """``(d,)`` -> ``(1, h d)`` float32: the norm's weight, a head each."""
+    return jnp.tile(weight.astype(_F32), heads)[None]
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4), inline=True)
+def _out_forward(o, gate, weight, eps, interpret):
+    b, s, heads, d = o.shape
+    o, gate = _io_tiles([o, gate])
+    out, = _io_call(
+        functools.partial(_out_fwd_kernel, d=d, eps=eps), "kda_out_fwd",
+        [("tile", o), ("tile", gate), ("lanes", _weight_row(weight, heads))],
+        [("tile", _like(o))], heads=heads, interpret=interpret)
+    return out[:, :s]
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1), inline=True)
+def _out_backward(eps, interpret, res, dout):
+    o, gate, weight = res
+    b, s, heads, d = o.shape
+    with trace.scope("kda_out"):
+        tiled = _io_tiles([dout, o, gate])
+        do, dgate, dw = _io_call(
+            functools.partial(_out_bwd_kernel, d=d, eps=eps), "kda_out_bwd",
+            [("tile", x) for x in tiled]
+            + [("lanes", _weight_row(weight, heads))],
+            [("tile", _like(tiled[1])), ("tile", _like(tiled[2])),
+             ("sums", jax.ShapeDtypeStruct((b, 1, heads * d), _F32))],
+            heads=heads, interpret=interpret)
+        dw = jnp.sum(dw.reshape(b * heads, d), axis=0)
+    return (do[:, :s].reshape(o.shape), dgate[:, :s].reshape(gate.shape),
+            dw.astype(weight.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _out_pass(o, gate, weight, eps, interpret):
+    return _out_forward(o, gate, weight, eps, interpret)
+
+
+def _out_pass_fwd(o, gate, weight, eps, interpret):
+    return _out_forward(o, gate, weight, eps, interpret), (o, gate, weight)
+
+
+_out_pass.defvjp(_out_pass_fwd, _out_backward)
+
+
+def _io_fused(interpret: bool, d: int) -> bool:
+    """Whether the passes run: on the TPU where a head's channels are
+    whole lanes (or in interpret mode, for the CPU's numerics tests)."""
+    fused = interpret or (_on_tpu() and d % 128 == 0)
+    trace.gauge("kda.io_fused", int(fused))
+    return fused
+
+
+def conv_silu_norm(xs, taps, *, heads: int, scales, interpret: bool = False,
+                   mesh: Optional[Mesh] = None):
+    """What a KDA layer does to its projections before the delta rule.
+    ``xs``: the projections, ``(b, s, h d)`` each; ``taps``: each one's
+    depthwise causal convolution, ``(h d, w)``; ``scales``: for each,
+    the factor on its L2 norm a head, or None for no norm -> ``SiLU(conv
+    (x))``, normed a head and scaled, ``(b, s, h, d)`` each in ``x``'s
+    dtype.
+
+    On the TPU with heads of whole lanes (or with ``interpret``) one
+    Pallas pass each way: float32 from the load to the one store, the
+    backward forms the pre-activation again from the projections. Else
+    XLA's ops, which round to ``x``'s dtype after the convolution and
+    after the SiLU. ``mesh`` as in ``chunk_kda``."""
+    xs, taps, scales = tuple(xs), tuple(taps), tuple(scales)
+    if not _io_fused(interpret, xs[0].shape[-1] // heads):
+        return _conv_silu_norm_xla(xs, taps, heads, scales)
+    wide = P(BATCH_AXES, None, None, None)
+    return _over_batch_rows(
+        lambda xs, taps: _in_pass(xs, taps, heads, scales, interpret),
+        mesh, (xs,), (taps,), (wide,) * len(xs))
+
+
+def norm_gate(o, gate, weight, eps: float, *, interpret: bool = False,
+              mesh: Optional[Mesh] = None):
+    """What a KDA layer does to the delta rule's output: ``o, gate (b,
+    s, h, d)``, ``weight (d,)`` -> ``RMSNorm_d(o) weight sigmoid(gate)``
+    as ``(b, s, h d)`` in ``o``'s dtype. The same choice of form as
+    ``conv_silu_norm``; the XLA form rounds the norm to ``o``'s dtype
+    before the gate."""
+    if not _io_fused(interpret, o.shape[-1]):
+        return _norm_gate_xla(o, gate, weight, eps)
+    return _over_batch_rows(
+        lambda o, gate, weight: _out_pass(o, gate, weight, eps, interpret),
+        mesh, (o, gate), (weight,), P(BATCH_AXES, None, None))

@@ -2,7 +2,9 @@
 32, 128) bfloat16 with ``g`` over the init range: the kernels' time,
 forward and forward + backward, against the XLA form's, and their
 output and five gradients against the XLA form's and, at 1024 tokens,
-the float32 recurrence's.
+the float32 recurrence's; and the two elementwise passes around the
+kernels (stages ``inputs``, ``output``), each way, against the XLA ops
+they replace: time, output and every gradient.
 
     chiprun -- python scripts/kda_chip_check.py [--seed N] [--stages a,b]
 
@@ -55,10 +57,67 @@ def rel(got, want):
     return float(jnp.max(jnp.abs(got - want)) / (jnp.max(jnp.abs(want)) + 1e-30))
 
 
+def passes(seed, stages):
+    """The passes against the XLA forms. Every array crosses the jit's
+    boundary as ``(b, s, h d)``: a 4-d parameter or result has another
+    tiled layout on the chip and would cost a copy neither form makes
+    inside the layer. ``*_bwd_ms`` of a pass is its backward alone (its
+    residuals are its inputs); of the XLA form, the backward with what
+    of the forward autodiff needs again."""
+    b, s, h, d = SHAPE
+    bf = jnp.bfloat16
+    ks = jax.random.split(jax.random.key(seed), 13)
+
+    def flat(key, scale=1.0):
+        return (scale * jax.random.normal(key, (b, s, h * d))).astype(bf)
+
+    xs = tuple(flat(k) for k in ks[:3])
+    taps = tuple(jax.random.uniform(
+        k, (h * d, 4), minval=-0.5, maxval=0.5).astype(bf) for k in ks[3:6])
+    cts = tuple(flat(k) for k in ks[6:9])
+    o, gate, ct = flat(ks[9]), flat(ks[10], 2.0), flat(ks[11])
+    weight = (1.0 + 0.3 * jax.random.normal(ks[12], (d,))).astype(bf)
+    scales = (d ** -0.5, 1.0, None)
+
+    def wide(a):
+        return a.reshape(b, s, h, d)
+
+    forms = {
+        "inputs": (
+            lambda xs, taps: tuple(a.reshape(b, s, -1) for a in (
+                kda.conv_silu_norm(xs, taps, heads=h, scales=scales))),
+            lambda xs, taps: tuple(a.reshape(b, s, -1) for a in (
+                kda._conv_silu_norm_xla(xs, taps, h, scales))),
+            (xs, taps), cts),
+        "output": (
+            lambda o, g, w: kda.norm_gate(wide(o), wide(g), w, 1e-5),
+            lambda o, g, w: kda._norm_gate_xla(wide(o), wide(g), w, 1e-5),
+            (o, gate, weight), ct),
+    }
+
+    def backward(fn):
+        return jax.jit(lambda ct, *args: jax.vjp(fn, *args)[1](ct))
+
+    res = {}
+    for name in stages:
+        fused, xla, args, ct = forms[name]
+        r = res[name] = {}
+        r["xla_fwd_ms"], want = timed(jax.jit(xla), *args)
+        r["xla_bwd_ms"], want_grads = timed(backward(xla), ct, *args)
+        r["pass_fwd_ms"], got = timed(jax.jit(fused), *args)
+        r["pass_bwd_ms"], grads = timed(backward(fused), ct, *args)
+        r["out_vs_xla"] = max(
+            rel(x, y) for x, y in zip(*map(jax.tree.leaves, (got, want))))
+        r["grads_vs_xla"] = [rel(x, y) for x, y in zip(
+            *map(jax.tree.leaves, (grads, want_grads)))]
+    return res
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--stages", default="xla,fwd,grad,recurrence")
+    ap.add_argument("--stages",
+                    default="xla,fwd,grad,recurrence,inputs,output")
     a = ap.parse_args()
     stages = a.stages.split(",")
     args, w = inputs(a.seed)
@@ -106,6 +165,8 @@ def main():
                 names, (rel(x, y) for x, y in zip(gk, gw))))
             res["xla_grads_vs_recurrence_1024"] = dict(zip(
                 names, (rel(x, y) for x, y in zip(gx, gw))))
+    res.update(passes(
+        a.seed, [n for n in ("inputs", "output") if n in stages]))
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/kda_chip_check.json", "w") as f:
         json.dump(res, f, indent=1)

@@ -4,7 +4,9 @@ kimi_linear.py ref_delta_rule``), outputs and every gradient, at strong
 and weak decay and small and large steps, in both forms: the XLA ops
 and the Pallas kernels in interpret mode (their hand-written backward
 against the recurrence's autodiff and the XLA form's); the convolution
-against a shifted sum."""
+against a shifted sum; the elementwise passes around the kernels
+(``conv_silu_norm``, ``norm_gate``) in interpret mode against the XLA
+forms they replace, outputs and every gradient."""
 
 import functools
 
@@ -181,6 +183,15 @@ def test_the_gauges_say_which_form_ran():
     assert trace.gauges()["kda.kernel"] == 0
     kda.chunk_kda(*args, chunk=128, interpret=True)   # no kernel admits it
     assert trace.gauges()["kda.kernel"] == 0
+    # the passes around the kernels say theirs, each where it chooses
+    xs, taps, _ = _io_inputs(1, 40, 2, jnp.float32)
+    o, gate, weight = _out_inputs(1, 40, 2, jnp.float32)
+    for fused in (True, False):
+        kda.conv_silu_norm(xs, taps, heads=2, scales=SCALES, interpret=fused)
+        assert trace.gauges()["kda.io_fused"] == int(fused)
+    for fused in (True, False):
+        kda.norm_gate(o, gate, weight, 1e-5, interpret=fused)
+        assert trace.gauges()["kda.io_fused"] == int(fused)
 
 
 def test_chunk_must_be_whole_sub_blocks():
@@ -200,3 +211,157 @@ def test_convolution_is_a_causal_shifted_sum(taps):
     # nothing of a later token reaches an earlier one
     bumped = kda.causal_conv(x.at[:, 7].add(1.0), w)
     np.testing.assert_array_equal(bumped[:, :7], kda.causal_conv(x, w)[:, :7])
+
+
+# ---------------------------------------------------------------------------
+# The elementwise passes around the kernels
+# ---------------------------------------------------------------------------
+
+D = 128                                # a head's channels: whole lanes
+SCALES = (D ** -0.5, 1.0, None)        # q, k, v as the layer asks
+IO_SHAPES = [                          # batch rows, tokens, heads
+    (2, 300, 4),    # two rows; two tiles, the second padded
+    (1, 258, 8),    # two lane blocks of four heads; 2 rows past a tile
+    (1, 100, 4),    # less than a tile: the first tile's halo alone
+]
+IO_DTYPES = [jnp.float32, jnp.bfloat16]
+
+
+def _io_inputs(b, s, h, dtype, d=D, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 9)
+    xs = tuple(jax.random.normal(k, (b, s, h * d)).astype(dtype)
+               for k in ks[:3])
+    taps = tuple(jax.random.uniform(k, (h * d, 4), minval=-0.5, maxval=0.5)
+                 for k in ks[3:6])
+    weights = tuple(jax.random.normal(k, (b, s, h, d)) for k in ks[6:])
+    return xs, taps, weights
+
+
+def _out_inputs(b, s, h, dtype, d=D, seed=1):
+    ks = jax.random.split(jax.random.key(seed), 3)
+    o = jax.random.normal(ks[0], (b, s, h, d)).astype(dtype)
+    gate = (2.0 * jax.random.normal(ks[1], (b, s, h, d))).astype(dtype)
+    return o, gate, 1.0 + 0.3 * jax.random.normal(ks[2], (d,))
+
+
+def _exact(tree):
+    return jax.tree.map(lambda a: a.astype(jnp.float32), tree)
+
+
+def _same(got, want, dtype):
+    """Float32: the two forms to rounding. bfloat16: the pass (float32
+    inside, one rounding at the store) against the XLA form in float32
+    on the same inputs, within bfloat16's step of the largest entry."""
+    got, want = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(got) == len(want)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        _close(a.astype(jnp.float32), b, 2e-6 if dtype == jnp.float32
+               else 1e-2)
+
+
+@pytest.mark.parametrize("dtype", IO_DTYPES)
+@pytest.mark.parametrize("b,s,h", IO_SHAPES)
+def test_input_pass_matches_the_xla_form(b, s, h, dtype):
+    xs, taps, weights = _io_inputs(b, s, h, dtype)
+
+    def form(interpret):
+        def loss(xs, taps):
+            out = kda.conv_silu_norm(xs, taps, heads=h, scales=SCALES,
+                                     interpret=interpret)
+            return sum(jnp.sum(o.astype(jnp.float32) * w)
+                       for o, w in zip(out, weights)), out
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))
+
+    (_, out), (dxs, dtaps) = form(True)(xs, taps)
+    (_, want), (want_dxs, want_dtaps) = form(False)(_exact(xs), taps)
+    for o, dx, x in zip(out, dxs, xs):
+        assert o.shape == (b, s, h, D) and o.dtype == dtype
+        assert dx.shape == x.shape and dx.dtype == dtype
+    assert all(dw.shape == (h * D, 4) for dw in dtaps)
+    _same(out, want, dtype)
+    _same(dxs, want_dxs, dtype)
+    _same(dtaps, want_dtaps, dtype)
+    # q and k leave normed a head, q scaled
+    q, k = (jnp.linalg.norm(o.astype(jnp.float32), axis=-1) for o in out[:2])
+    np.testing.assert_allclose(q, D ** -0.5, rtol=1e-2)
+    np.testing.assert_allclose(k, 1.0, rtol=1e-2)
+
+
+@pytest.mark.parametrize("dtype", IO_DTYPES)
+@pytest.mark.parametrize("b,s,h", IO_SHAPES)
+def test_output_pass_matches_the_xla_form(b, s, h, dtype):
+    o, gate, weight = _out_inputs(b, s, h, dtype)
+    w_out = jax.random.normal(jax.random.key(2), (b, s, h * D))
+
+    def form(interpret):
+        def loss(o, gate, weight):
+            out = kda.norm_gate(o, gate, weight, 1e-5, interpret=interpret)
+            return jnp.sum(out.astype(jnp.float32) * w_out), out
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))
+
+    (_, out), grads = form(True)(o, gate, weight)
+    (_, want), want_grads = form(False)(*_exact((o, gate)), weight)
+    assert out.shape == (b, s, h * D) and out.dtype == dtype
+    assert [g.dtype for g in grads] == [dtype, dtype, weight.dtype]
+    _same(out, want, dtype)
+    _same(grads, want_grads, dtype)
+
+
+@pytest.mark.parametrize("at", [0, 2, 254, 255, 256, 299])
+def test_input_pass_is_causal_across_the_tiles(at):
+    """A bump at token ``at`` moves nothing before it and, forward,
+    nothing past the convolution's reach; its own gradient reads the
+    cotangents of ``at .. at + 3`` and no other token's: the first
+    tile's masked halo, the rows a tile takes of the one before it, and
+    the rows the backward takes of the one after."""
+    xs, taps, weights = _io_inputs(1, 300, 4, jnp.float32, seed=4)
+
+    def out(xs):
+        return kda.conv_silu_norm(xs, taps, heads=4, scales=SCALES,
+                                  interpret=True)
+
+    bumped = out(tuple(x.at[:, at].add(1.0) for x in xs))
+    for a, b in zip(bumped, out(xs)):
+        np.testing.assert_array_equal(a[:, :at], b[:, :at])
+        np.testing.assert_array_equal(a[:, at + 4:], b[:, at + 4:])
+        assert float(jnp.max(jnp.abs(a[:, at] - b[:, at]))) > 0.0
+
+    def dx(weights):
+        return jax.grad(lambda xs: sum(
+            jnp.sum(o * w) for o, w in zip(out(xs), weights)))(xs)
+
+    moved = dx(tuple(
+        w.at[:, :at].add(1.0).at[:, at + 4:].add(1.0) for w in weights))
+    for a, b in zip(moved, dx(weights)):
+        np.testing.assert_array_equal(a[:, at], b[:, at])
+
+
+def test_passes_over_a_mesh_run_on_each_devices_batch_rows():
+    """Under ``shard_map`` on the batch rows the passes give what they
+    give on one device; the taps' and the norm weight's gradients are
+    summed over the devices."""
+    from dlrover_tpu.parallel import MeshConfig, build_mesh
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from dlrover_tpu.parallel.mesh import BATCH_AXES
+
+    mesh = build_mesh(MeshConfig(dp=2, fsdp=2).resolve(4),
+                      devices=jax.devices()[:4])
+    rows = NamedSharding(mesh, P(BATCH_AXES))
+    xs, taps, weights = _io_inputs(4, 260, 2, jnp.float32, d=16)
+    o, gate, weight = _out_inputs(4, 260, 2, jnp.float32, d=16)
+
+    def loss(mesh):
+        def fn(xs, taps, o, gate, weight):
+            q, k, v = kda.conv_silu_norm(
+                xs, taps, heads=2, scales=(0.25, 1.0, None), interpret=True,
+                mesh=mesh)
+            out = kda.norm_gate(o * v, gate, weight, 1e-5, interpret=True,
+                                mesh=mesh)
+            return jnp.sum(out.reshape(q.shape) * q * k * weights[0])
+        return jax.jit(jax.grad(fn, argnums=range(5)))
+
+    sharded = jax.device_put((xs, o, gate), rows)
+    got = loss(mesh)(sharded[0], taps, sharded[1], sharded[2], weight)
+    _same(got, loss(None)(xs, taps, o, gate, weight), jnp.float32)

@@ -6,6 +6,7 @@ experts tied to the uncut layer."""
 
 import copy
 import dataclasses
+import functools
 import json
 import os
 
@@ -77,7 +78,18 @@ def built(config, mesh):
     return fam, params, tokens
 
 
-def test_loss_and_gradients_match_the_plain_form(built, config):
+@pytest.fixture(params=["xla", "kernels"])
+def kda_form(request, monkeypatch):
+    """The KDA layer's two forms: off the TPU it takes XLA's ops; with
+    ``interpret`` its Pallas forms (the passes around the delta rule and
+    the delta rule's kernels), which hold the layer's wiring of them."""
+    if request.param == "kernels":
+        monkeypatch.setattr(kimi_linear, "kda_attention", functools.partial(
+            kimi_linear.kda_attention, interpret=True))
+    return request.param
+
+
+def test_loss_and_gradients_match_the_plain_form(built, config, kda_form):
     fam, params, tokens = built
     loss, grads = jax.jit(jax.value_and_grad(fam.loss_fn))(params, tokens)
     want, want_grads = jax.jit(jax.value_and_grad(
@@ -92,6 +104,9 @@ def test_loss_and_gradients_match_the_plain_form(built, config):
     # top-k's indices give the choice bias no gradient, in either form
     run = grads["runs"][kimi_linear.run_name(1)]
     assert float(jnp.max(jnp.abs(run["router_bias"]))) == 0.0
+    gauges = trace.gauges()
+    assert gauges["kda.io_fused"] == gauges["kda.kernel"] == (
+        kda_form == "kernels")
     # and every KDA parameter weighs
     for name in ("a_log", "dt_bias", "conv_q", "conv_k", "conv_v", "w_f1",
                  "w_g1", "b_g2", "w_b", "o_norm"):
@@ -235,11 +250,12 @@ def test_other_patterns_run_and_match_the_plain_form(config, mesh, pattern):
 # The two attention kinds
 # ---------------------------------------------------------------------------
 
-def test_kda_layer_matches_the_token_by_token_form(built, config):
+def test_kda_layer_matches_the_token_by_token_form(built, config, kda_form):
     fam, params, _ = built
     lp = jax.tree.map(lambda a: a[0], params["runs"][kimi_linear.run_name(1)])
     y = jax.random.normal(jax.random.key(8), (2, 48, fam.cfg.dim))
     got = kimi_linear.kda_attention(fam.cfg, lp, y)
+    assert trace.gauges()["kda.io_fused"] == (kda_form == "kernels")
     np.testing.assert_allclose(
         got, family._ref_kda(y, lp, config), atol=2e-5, rtol=2e-4)
     # the decay really is per channel and inside (0, 1)
