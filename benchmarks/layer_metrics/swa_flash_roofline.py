@@ -1,0 +1,2 @@
+"""``swa_flash_roofline``: see ``swa_flash_roofline.json``."""
+from benchmarks.harness.smallthinker_flops import read_flash_roofline as read  # noqa: F401

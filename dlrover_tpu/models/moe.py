@@ -16,6 +16,13 @@ it is a real compute path, and there is one of it:
   chip's share of an expert-parallel job) computes the pairs that chose
   its experts; the others sort into the tail and add nothing. Under a
   mesh ``ep`` divides the held experts further.
+- **what the router reads** (``route_on``): the tensor the choice is
+  made on is by default the tensor the experts are applied to; a model
+  whose router reads the attention's input (``models/smallthinker.py``)
+  hands that in beside it, and the router's gradient goes where it
+  came from.
+- **the experts' activation** (``expert_act``): ``silu`` (SwiGLU) or
+  ``relu`` (ReGLU) on the gate.
 - **shared expert**: where the layer has ``ws_gate`` / ``ws_up`` /
   ``ws_down``, every token also passes through that dense SwiGLU
   (``moe_shared``), added to the routed result.
@@ -103,6 +110,10 @@ class MoeConfig:
     # experts first_expert .. first_expert + experts_held - 1 (None: all)
     experts_held: Optional[int] = None
     first_expert: int = 0
+    # the gate's activation inside an expert: "silu" or "relu"
+    expert_act: str = "silu"
+    # a head's width where it is not dim / n_heads (None: it is)
+    stated_head_dim: Optional[int] = None
     router_aux_coef: float = 0.01
     max_seq_len: int = 8192
     rope_theta: float = 1000000.0
@@ -117,6 +128,8 @@ class MoeConfig:
 
     @property
     def head_dim(self) -> int:
+        if self.stated_head_dim is not None:
+            return self.stated_head_dim
         return self.dim // self.n_heads
 
     @property
@@ -392,7 +405,11 @@ def _route(cfg: MoeConfig, router, yt, token_axes=(), bias=None):
     return top_p, top_e, aux
 
 
-def _experts(lp: Params, yt, top_p, top_e, n_local: int, first: Any = 0):
+_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _experts(lp: Params, yt, top_p, top_e, n_local: int, first: Any = 0,
+             act: str = "silu"):
     """The ``n_local`` experts ``first ..`` applied to the pairs of
     ``yt (t, d)`` that chose them, weighted and summed: ``(t, d)``."""
     k = top_e.shape[1]
@@ -403,15 +420,16 @@ def _experts(lp: Params, yt, top_p, top_e, n_local: int, first: Any = 0):
         gate = grouped_matmul(xs, lp["w_gate"], group_sizes)
         up = grouped_matmul(xs, lp["w_up"], group_sizes)
         rows = grouped_matmul(
-            jax.nn.silu(gate) * up, lp["w_down"], group_sizes
+            _ACTS[act](gate) * up, lp["w_down"], group_sizes
         )
     with trace.scope("moe_combine"):
         return combine_rows(rows, top_p, order, inverse)
 
 
-def _moe_tokens_sharded(cfg: MoeConfig, lp: Params, y):
+def _moe_tokens_sharded(cfg: MoeConfig, lp: Params, y, route_on=None):
     """The body of ``moe_mlp``'s ``shard_map``: this device's tokens
-    ``y (b, s, d)``, its ``e / ep`` experts at ``1 / tp`` of their width.
+    ``y (b, s, d)`` (and, where the router reads another tensor, that:
+    ``route_on``), its ``e / ep`` experts at ``1 / tp`` of their width.
     Rows, choices and weights are gathered over ep; every rank computes
     the pairs that chose its experts for all of the group's tokens and
     the partial outputs are summed back to their owners. An axis of size
@@ -419,8 +437,9 @@ def _moe_tokens_sharded(cfg: MoeConfig, lp: Params, y):
     b, s, d = y.shape
     e_local = lp["w_gate"].shape[0]
     yt = y.reshape(b * s, d)
-    top_p, top_e, aux = _route(cfg, lp["router"], yt, BATCH_AXES + (SP,),
-                               lp.get("router_bias"))
+    top_p, top_e, aux = _route(
+        cfg, lp["router"], yt if route_on is None else route_on.reshape(
+            b * s, d), BATCH_AXES + (SP,), lp.get("router_bias"))
     yt, top_p, top_e = (
         lax.all_gather(a, EP, axis=0, tiled=True) for a in (yt, top_p, top_e)
     )
@@ -430,7 +449,7 @@ def _moe_tokens_sharded(cfg: MoeConfig, lp: Params, y):
     # custom_vjps, which get no such help by themselves)
     yt, top_p = (lax.pcast(a, TP, to="varying") for a in (yt, top_p))
     first = cfg.first_expert + lax.axis_index(EP) * e_local
-    out = _experts(lp, yt, top_p, top_e, e_local, first)
+    out = _experts(lp, yt, top_p, top_e, e_local, first, cfg.expert_act)
     out = lax.psum_scatter(out, EP, scatter_dimension=0, tiled=True)
     return lax.psum(out, TP).reshape(b, s, d), aux
 
@@ -444,16 +463,26 @@ def _shared_expert(lp: Params, y: jnp.ndarray) -> jnp.ndarray:
 
 
 def moe_mlp(
-    cfg: MoeConfig, lp: Params, y: jnp.ndarray, mesh: Optional[Mesh] = None
+    cfg: MoeConfig, lp: Params, y: jnp.ndarray, mesh: Optional[Mesh] = None,
+    route_on: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """(B, S, D) -> (out (B, S, D), aux_loss scalar)."""
+    """(B, S, D) -> (out (B, S, D), aux_loss scalar). ``route_on (B, S,
+    D)``: the tensor the router reads where that is not ``y``."""
     b, s, d = y.shape
-    _report_shapes(cfg, b * s, "ws_gate" in lp)
+    if cfg.expert_act not in _ACTS:
+        raise ValueError(
+            f"expert_act={cfg.expert_act!r}: one of {sorted(_ACTS)}")
+    _report_shapes(cfg, b * s, "ws_gate" in lp, route_on is not None)
+    # beside y where it is another tensor; else y alone, as it was
+    routed = () if route_on is None else (route_on,)
     if mesh is None or mesh.size == 1:
         yt = y.reshape(b * s, d)
         top_p, top_e, aux = _route(
-            cfg, lp["router"], yt, bias=lp.get("router_bias"))
-        out = _experts(lp, yt, top_p, top_e, cfg.n_held, cfg.first_expert)
+            cfg, lp["router"],
+            yt if route_on is None else route_on.reshape(b * s, d),
+            bias=lp.get("router_bias"))
+        out = _experts(lp, yt, top_p, top_e, cfg.n_held, cfg.first_expert,
+                       cfg.expert_act)
         out = out.reshape(b, s, d)
     else:
         specs = {"router": P(None, None), "w_gate": P(EP, None, TP),
@@ -464,20 +493,25 @@ def moe_mlp(
             functools.partial(_moe_tokens_sharded, cfg),
             mesh=mesh,
             # fsdp's shards of the weights are gathered on the way in
-            in_specs=(specs, P(BATCH_AXES, SP, None)),
+            in_specs=(specs,) + (P(BATCH_AXES, SP, None),) * (
+                1 + len(routed)),
             out_specs=(P(BATCH_AXES, SP, None), P()),
         )
-        out, aux = sharded({k: lp[k] for k in specs}, y)
+        out, aux = sharded({k: lp[k] for k in specs}, y, *routed)
     if "ws_gate" in lp:
         out = out + _shared_expert(lp, y)
     return out, aux
 
 
-def _report_shapes(cfg: MoeConfig, tokens: int, shared: bool = False):
+def _report_shapes(cfg: MoeConfig, tokens: int, shared: bool = False,
+                   route_on: bool = False):
     """The gauges that say what the expert layer of this build is given
     (set while the step is traced, as ``attn.block_q`` is).
     ``moe.rows_held`` is what uniform routing sends the held experts of
-    a layer: the step's own count hangs on the router."""
+    a layer: the step's own count hangs on the router. ``moe.route_on``:
+    1 where the router reads a tensor handed in beside the experts'
+    input (the attention's input), 0 where it reads that input;
+    ``moe.act``: the gate's activation, 0 silu, 1 relu."""
     k, e = cfg.experts_per_token, cfg.n_experts
     trace.gauge("moe.experts", e)
     trace.gauge("moe.top_k", k)
@@ -486,6 +520,8 @@ def _report_shapes(cfg: MoeConfig, tokens: int, shared: bool = False):
     trace.gauge("moe.rows_held", tokens * k * cfg.n_held / e)
     trace.gauge("moe.tail_rows", tokens * k * (e - cfg.n_held) / e)
     trace.gauge("moe.shared_experts", int(shared))
+    trace.gauge("moe.route_on", int(route_on))
+    trace.gauge("moe.act", list(_ACTS).index(cfg.expert_act))
 
 
 def _decoder_layer(cfg: MoeConfig, mesh, inv_freq, positions, lp, x):

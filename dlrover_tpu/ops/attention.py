@@ -31,6 +31,17 @@ How the three kernels walk the (q, k) plane:
   nor DMA: its ``index_map`` names the block the pipeline already
   holds. (Masking only the blocks the diagonal crosses was measured
   and dropped: under 2 % of a kernel, PERF.md section 6, PR 26.)
+- **window** (``window=w``, causal only): query ``i`` sees key ``j`` iff
+  ``0 <= i - j < w``, its own position and the ``w - 1`` before it. The
+  band has a lower edge too, so the walk is over the band alone: the
+  grid's last axis is as long as the most blocks one q block (one k
+  block, for dk/dv) needs, step ``j`` of it is block ``first + j``, and
+  what lies past the block's last is neither computed nor fetched.
+  A row's first fetched block can then be wholly masked for that row:
+  the forward takes ``exp`` against 0 while a row's running max is
+  still the mask's value (docs/design/kernels.md). The window calls are
+  named ``attention_fwd_swa``, ``attention_bwd_dq_swa`` and
+  ``attention_bwd_dkv_swa``. ``window >= seq`` is the causal call.
 - **operands** go to the MXU in the dtype they arrive in (bf16 under
   ``activation_dtype: bfloat16``, f32 in the CPU tests) and accumulate
   in f32; ``P`` and ``dS`` are rounded to that dtype before their
@@ -81,13 +92,17 @@ def mha_reference_with_lse(
     q_offset=0,
     k_offset=0,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
 ):
     """Stable-softmax attention in float32, GQA-aware; returns
     ``(out (b,sq,h,dv), lse (b,h,sq))``. ``q_offset`` / ``k_offset`` are
     *global* positions of element 0 — this is what lets ring-attention
     chunks mask causally against each other. ``v`` may be narrower or
     wider than ``q`` and ``k`` (latent attention: 192 against 128);
-    ``scale`` None is ``1 / sqrt(d)`` of the q/k width."""
+    ``scale`` None is ``1 / sqrt(d)`` of the q/k width. ``window`` (with
+    ``causal``): a query sees its own position and the ``window - 1``
+    before it."""
+    assert window is None or causal, "a window is causal"
     b, sq, h, d = q.shape
     hkv = k.shape[2]
     group = h // hkv
@@ -101,6 +116,8 @@ def mha_reference_with_lse(
         qpos = q_offset + jnp.arange(sq)
         kpos = k_offset + jnp.arange(k.shape[1])
         mask = qpos[:, None] >= kpos[None, :]
+        if window is not None:
+            mask &= qpos[:, None] - kpos[None, :] < window
         logits = jnp.where(mask[None, None], logits, _NEG_INF)
     lse = jax.nn.logsumexp(logits, axis=-1)  # (b, h, sq)
     probs = jnp.exp(logits - lse[..., None])
@@ -109,10 +126,11 @@ def mha_reference_with_lse(
 
 
 def mha_reference(q, k, v, causal: bool = True, q_offset=0, k_offset=0,
-                  scale: Optional[float] = None):
+                  scale: Optional[float] = None,
+                  window: Optional[int] = None):
     return mha_reference_with_lse(
         q, k, v, causal=causal, q_offset=q_offset, k_offset=k_offset,
-        scale=scale,
+        scale=scale, window=window,
     )[0]
 
 
@@ -211,7 +229,11 @@ def choose_tiles(kernel: str, sq: int, sk: int, head_dim: int, group: int,
     sequence with no aligned divisor that is too long to be one block):
     the caller has the reference path. Causal or not does not enter: on
     the chip both want the same tiles. ``head_dim`` is the q/k head's
-    width, ``v_head_dim`` the v head's where it differs.
+    width, ``v_head_dim`` the v head's where it differs. Nor does a
+    window enter: a window call computes and masks away about
+    ``(block_q + block_k) / window`` of its work at the band's two
+    edges, and on the v5e at window 4096 smaller tiles cost as much in
+    grid steps as they saved there (docs/design/kernels.md, PR 37).
 
     Short and awkward sequences come out as before there was a
     chooser: 8 and 64 as one block, 196 and 197 as one block, anything
@@ -285,12 +307,14 @@ def _tiles_for(q, k, v, block_q, block_k):
     return tiles
 
 
-def _report_tiles(block_q: int, block_k: int):
+def _report_tiles(block_q: int, block_k: int, window: Optional[int] = None):
     """The gauges that say which tiling the job runs: the forward's own
-    chosen tiles, and the count of call sites left at 128 or less."""
+    chosen tiles (a window call's under names of their own), and the
+    count of call sites left at 128 or less."""
     global _fallback_sites
-    trace.gauge("attn.block_q", block_q)
-    trace.gauge("attn.block_k", block_k)
+    kind = "" if window is None else "window_"
+    trace.gauge(f"attn.{kind}block_q", block_q)
+    trace.gauge(f"attn.{kind}block_k", block_k)
     if max(block_q, block_k) <= 128:
         _fallback_sites += 1
         trace.gauge("attn.tile_fallback", _fallback_sites)
@@ -337,6 +361,36 @@ def _first_q_block(ki, block_q: int, block_k: int, n_q: int):
     return jnp.minimum((ki * block_k) // block_q, n_q - 1)
 
 
+def _first_k_block(qi, block_q: int, block_k: int, window: int):
+    """First k block the q block ``qi`` needs under a window: the one
+    that holds its first row's oldest key."""
+    return jnp.maximum(qi * block_q - (window - 1), 0) // block_k
+
+
+def _last_q_block(ki, block_q: int, block_k: int, n_q: int, window: int):
+    """Last q block the k block ``ki`` needs under a window: the one
+    that holds the last query to see its last key."""
+    return jnp.minimum(
+        (ki * block_k + block_k - 1 + window - 1) // block_q, n_q - 1)
+
+
+def _band_steps(walk: str, n_q: int, n_k: int, block_q: int, block_k: int,
+                window: int) -> int:
+    """The length of a window call's inner grid axis: the most k blocks
+    one q block needs (``walk="k"``: forward and dq) or the most q
+    blocks one k block needs (``walk="q"``: dk/dv). The four edges above
+    again, on Python ints: a grid's length is static."""
+    if walk == "k":
+        return max(
+            min(((qi + 1) * block_q - 1) // block_k, n_k - 1)
+            - max(qi * block_q - (window - 1), 0) // block_k + 1
+            for qi in range(n_q))
+    return max(
+        min((ki * block_k + block_k - 1 + window - 1) // block_q, n_q - 1)
+        - min(ki * block_k // block_q, n_q - 1) + 1
+        for ki in range(n_k))
+
+
 def _when_needed(causal: bool, qi, ki, block_q: int, block_k: int):
     """Decorator: run the body unless the (qi, ki) block of the score
     plane lies wholly above the causal diagonal."""
@@ -345,14 +399,25 @@ def _when_needed(causal: bool, qi, ki, block_q: int, block_k: int):
     return pl.when(ki * block_k <= qi * block_q + block_q - 1)
 
 
-def _causal_mask(qi, ki, group: int, block_q: int, block_k: int):
-    """(group * block_q, block_k) bool: query position >= key position.
-    The group's heads repeat the q block's positions."""
+def _causal_mask(qi, ki, group: int, block_q: int, block_k: int,
+                 window: Optional[int] = None):
+    """(group * block_q, block_k) bool: query position >= key position
+    and, under a window, less than ``window`` past it. The group's heads
+    repeat the q block's positions."""
     shape = (group, block_q, block_k)
     qpos = qi * block_q + lax.broadcasted_iota(jnp.int32, shape, 1)
     kpos = ki * block_k + lax.broadcasted_iota(jnp.int32, shape, 2)
     rows = (group * block_q, block_k)
-    return qpos.reshape(rows) >= kpos.reshape(rows)
+    qpos, kpos = qpos.reshape(rows), kpos.reshape(rows)
+    if window is None:
+        return qpos >= kpos
+    return (qpos >= kpos) & (qpos - kpos < window)
+
+
+def _name_suffix(window: Optional[int]) -> str:
+    """What a window call's kernels are named by, after the prefix the
+    plain calls have: a trace tells the two kinds of layer apart."""
+    return "" if window is None else "_swa"
 
 
 def _compiler_params():
@@ -369,24 +434,31 @@ def _compiler_params():
 def _flash_fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     *, group: int, block_q: int, block_k: int, n_kblocks: int,
-    causal: bool, scale: float
+    causal: bool, scale: float, window: Optional[int] = None
 ):
     qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    step = pl.program_id(3)
     rows, dv = acc_ref.shape          # out is as wide as a v head
     d = q_ref.shape[-1]               # scores run over the q/k width
     last_k = (_last_k_block(qi, block_q, block_k, n_kblocks) if causal
               else n_kblocks - 1)
+    # under a window the inner axis walks the band: step 0 is the q
+    # block's first k block, not k block 0
+    ki = step if window is None else (
+        _first_k_block(qi, block_q, block_k, window) + step)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # k block 0 is every row's first and holds its position 0, so the
-    # running max is a real score before any wholly masked row of a
-    # later block meets it (exp(-1e30 - m) == 0, never exp(0))
+    # without a window k block 0 is every row's first and holds its
+    # position 0, so the running max is a real score before any wholly
+    # masked row of a later block meets it (exp(-1e30 - m) == 0, never
+    # exp(0)). Under a window a row's first fetched block can lie wholly
+    # before its oldest key: while a row's max is still the mask's value
+    # the exponent is taken against 0, so p, l and acc stay 0
     @_when_needed(causal, qi, ki, block_q, block_k)
     def _compute():
         q = q_ref[0, 0].reshape(rows, d)                     # (G*bq, d)
@@ -394,11 +466,14 @@ def _flash_fwd_kernel(
         v = v_ref[0, 0]
         s = _dot(q, k, _NT) * scale                          # (G*bq, bk) f32
         if causal:
-            s = jnp.where(_causal_mask(qi, ki, group, block_q, block_k),
-                          s, _NEG_INF)
+            s = jnp.where(
+                _causal_mask(qi, ki, group, block_q, block_k, window),
+                s, _NEG_INF)
         m_prev = m_ref[...]                                  # (G*bq, 128)
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - _lane_fill(m_next, block_k))
+        m_exp = m_next if window is None else jnp.where(
+            m_next < 0.5 * _NEG_INF, 0.0, m_next)
+        p = jnp.exp(s - _lane_fill(m_exp, block_k))
         corr = jnp.exp(m_prev - m_next)
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
         m_ref[...] = m_next
@@ -419,11 +494,15 @@ def _flash_fwd_kernel(
 
 
 def _kv_specs(block_k: int, d: int, dv: int, causal: bool, block_q: int,
-              n_k: int):
+              n_k: int, window: Optional[int] = None):
     """K and V BlockSpecs of the (b, hkv, n_q, n_k) grids. Above the
     causal diagonal the index stays on the last block the q block
-    needs: the pipeline sees an unchanged index and issues no DMA."""
+    needs: the pipeline sees an unchanged index and issues no DMA.
+    Under a window the inner axis counts from the q block's first k
+    block (`_band_steps` long)."""
     def index(bi, hi, qi, ki):
+        if window is not None:
+            ki = ki + _first_k_block(qi, block_q, block_k, window)
         if causal:
             ki = jnp.minimum(ki, _last_k_block(qi, block_q, block_k, n_k))
         return (bi, hi, ki, 0)
@@ -434,13 +513,16 @@ def _kv_specs(block_k: int, d: int, dv: int, causal: bool, block_q: int,
 
 def _flash_fwd_pallas(q, k, v, causal: bool, block_q: int, block_k: int,
                       interpret: bool = False,
-                      scale: Optional[float] = None):
+                      scale: Optional[float] = None,
+                      window: Optional[int] = None):
     b, sq, h, d = q.shape
     sk, hkv, dv = k.shape[1], k.shape[2], v.shape[3]
     group = h // hkv
     block_q, block_k = _clip_tiles(sq, sk, block_q, block_k)
     n_q, n_k = sq // block_q, sk // block_k
     rows = group * block_q
+    k_steps = n_k if window is None else _band_steps(
+        "k", n_q, n_k, block_q, block_k, window)
 
     # (b, s, h, d) → (b, h, s, d) so the contiguous minor dims tile
     # cleanly; the query heads of a kv head are adjacent, so splitting
@@ -457,11 +539,11 @@ def _flash_fwd_pallas(q, k, v, causal: bool, block_q: int, block_k: int,
         functools.partial(
             _flash_fwd_kernel, group=group, block_q=block_q,
             block_k=block_k, n_kblocks=n_k, causal=causal,
-            scale=_scale_for(d, scale),
+            scale=_scale_for(d, scale), window=window,
         ),
-        grid=(b, hkv, n_q, n_k),
-        in_specs=[q_rows(d),
-                  *_kv_specs(block_k, d, dv, causal, block_q, n_k)],
+        grid=(b, hkv, n_q, k_steps),
+        in_specs=[q_rows(d), *_kv_specs(block_k, d, dv, causal, block_q,
+                                        n_k, window)],
         out_specs=[q_rows(dv), q_rows(_LSE_LANES)],
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, group, sq, dv), q.dtype),
@@ -475,7 +557,7 @@ def _flash_fwd_pallas(q, k, v, causal: bool, block_q: int, block_k: int,
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
-        name="attention_fwd",
+        name="attention_fwd" + _name_suffix(window),
     )(qt, kt, vt)
     out = out.reshape(b, h, sq, dv).transpose(0, 2, 1, 3)
     return out, lse.reshape(b, h, sq, _LSE_LANES)[..., 0]
@@ -501,15 +583,17 @@ def _flash_fwd_pallas(q, k, v, causal: bool, block_q: int, block_k: int,
 def _flash_bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref,
     *, group: int, block_q: int, block_k: int, n_kblocks: int,
-    causal: bool, scale: float
+    causal: bool, scale: float, window: Optional[int] = None
 ):
     qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    step = pl.program_id(3)
     rows, d = acc_ref.shape
     last_k = (_last_k_block(qi, block_q, block_k, n_kblocks) if causal
               else n_kblocks - 1)
+    ki = step if window is None else (
+        _first_k_block(qi, block_q, block_k, window) + step)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -523,8 +607,9 @@ def _flash_bwd_dq_kernel(
         delta = delta_ref[0, 0].reshape(rows, _LSE_LANES)[:, :1]
         s = _dot(q, k, _NT) * scale
         if causal:
-            s = jnp.where(_causal_mask(qi, ki, group, block_q, block_k),
-                          s, _NEG_INF)
+            s = jnp.where(
+                _causal_mask(qi, ki, group, block_q, block_k, window),
+                s, _NEG_INF)
         p = jnp.exp(s - lse)                                     # (G*bq, bk)
         ds = p * (_dot(do, v, _NT) - delta)
         acc_ref[...] = acc_ref[...] + _dot(ds.astype(k.dtype), k, _NN)
@@ -538,7 +623,8 @@ def _flash_bwd_dq_kernel(
 def _flash_bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_acc, dv_acc,
-    *, block_q: int, block_k: int, n_qblocks: int, causal: bool, scale: float
+    *, block_q: int, block_k: int, n_qblocks: int, causal: bool,
+    scale: float, window: Optional[int] = None, q_steps: int = 0
 ):
     # kv-head-major: grid dim 1 is the KV head; dim 3 sweeps
     # (query_head_in_group, q_block) pairs so the group's contributions
@@ -546,14 +632,26 @@ def _flash_bwd_dkv_kernel(
     # (b, h, sk, d) per-query-head buffers in HBM (round-2 Weak #7).
     ki = pl.program_id(2)
     j = pl.program_id(3)
-    qi = j % n_qblocks
+    if window is None:
+        qi = j % n_qblocks
+    else:
+        # a head's sweep walks the band: q_steps blocks from the first
+        # that sees this k block
+        qi = _first_q_block(ki, block_q, block_k, n_qblocks) + j % q_steps
 
     @pl.when(j == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @_when_needed(causal, qi, ki, block_q, block_k)
+    def needed(body):
+        if window is None:
+            return _when_needed(causal, qi, ki, block_q, block_k)(body)
+        # the blocks of the sweep past the band's last compute nothing
+        return pl.when(qi <= _last_q_block(
+            ki, block_q, block_k, n_qblocks, window))(body)
+
+    @needed
     def _compute():
         q = q_ref[0, 0]                                       # (bq, d)
         do = do_ref[0, 0]
@@ -569,7 +667,10 @@ def _flash_bwd_dkv_kernel(
             qpos = qi * block_q + lax.broadcasted_iota(
                 jnp.int32, (block_k, block_q), 1
             )
-            st = jnp.where(qpos >= kpos, st, _NEG_INF)
+            seen = qpos >= kpos
+            if window is not None:
+                seen &= qpos - kpos < window
+            st = jnp.where(seen, st, _NEG_INF)
         pt = jnp.exp(st - lse)
         # dv += p^T @ do
         dv_acc[...] = dv_acc[...] + _dot(pt.astype(do.dtype), do, _NN)
@@ -584,7 +685,8 @@ def _flash_bwd_dkv_kernel(
 
 
 def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
-                      dq_tiles, dkv_tiles, interpret=False, scale=None):
+                      dq_tiles, dkv_tiles, interpret=False, scale=None,
+                      window: Optional[int] = None):
     b, sq, h, d = q.shape
     sk, hkv, dv = k.shape[1], k.shape[2], v.shape[3]
     group = h // hkv
@@ -605,6 +707,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
     # -- dq: grid (b, hkv, n_q, n_k), the group's q block fixed, k rotates --
     block_q, block_k = _clip_tiles(sq, sk, *dq_tiles)
     n_q, n_k = sq // block_q, sk // block_k
+    k_steps = n_k if window is None else _band_steps(
+        "k", n_q, n_k, block_q, block_k, window)
 
     def q_rows(lanes):
         return pl.BlockSpec((1, 1, group, block_q, lanes),
@@ -620,10 +724,12 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
         functools.partial(
             _flash_bwd_dq_kernel, group=group, block_q=block_q,
             block_k=block_k, n_kblocks=n_k, causal=causal, scale=scale,
+            window=window,
         ),
-        grid=(b, hkv, n_q, n_k),
+        grid=(b, hkv, n_q, k_steps),
         in_specs=[
-            q_rows(d), *_kv_specs(block_k, d, dv, causal, block_q, n_k),
+            q_rows(d),
+            *_kv_specs(block_k, d, dv, causal, block_q, n_k, window),
             q_rows(dv), q_rows(_LSE_LANES), q_rows(_LSE_LANES),
         ],
         out_specs=q_rows(d),
@@ -631,7 +737,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
         scratch_shapes=[pltpu.VMEM((group * block_q, d), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
-        name="attention_bwd_dq",
+        name="attention_bwd_dq" + _name_suffix(window),
     )(
         qt.reshape(b, hkv, group, sq, d), kt, vt,
         dot.reshape(b, hkv, group, sq, dv), lanes8(lse), lanes8(delta),
@@ -643,14 +749,21 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
     # per-query-head form (round-2 Weak #7), which matters at 8:1 GQA.
     block_q, block_k = _clip_tiles(sq, sk, *dkv_tiles)
     n_q, n_k = sq // block_q, sk // block_k
+    q_steps = n_q if window is None else _band_steps(
+        "q", n_q, n_k, block_q, block_k, window)
 
     def q_head(bi, hi, i, j):
         # above the diagonal the index waits on the first q block this
         # k block needs: no DMA for blocks that compute nothing
-        qi = j % n_q
-        if causal:
+        qi = j % q_steps
+        if window is not None:
+            # the band's sweep starts there, and waits on its last
+            qi = jnp.minimum(
+                qi + _first_q_block(i, block_q, block_k, n_q),
+                _last_q_block(i, block_q, block_k, n_q, window))
+        elif causal:
             qi = jnp.maximum(qi, _first_q_block(i, block_q, block_k, n_q))
-        return (bi, hi * group + j // n_q, qi, 0)
+        return (bi, hi * group + j // q_steps, qi, 0)
 
     def q_head_row(bi, hi, i, j):
         bi, head, qi, _ = q_head(bi, hi, i, j)
@@ -663,9 +776,10 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
     dkh, dvh = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
-            n_qblocks=n_q, causal=causal, scale=scale,
+            n_qblocks=n_q, causal=causal, scale=scale, window=window,
+            q_steps=q_steps,
         ),
-        grid=(b, hkv, n_k, group * n_q),
+        grid=(b, hkv, n_k, group * q_steps),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), q_head),
             kv_block(d), kv_block(dv),
@@ -684,7 +798,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
-        name="attention_bwd_dkv",
+        name="attention_bwd_dkv" + _name_suffix(window),
     )(qt, kt, vt, dot, lse.reshape(b, h, 1, sq), delta.reshape(b, h, 1, sq))
 
     dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
@@ -699,50 +813,72 @@ def _on_tpu() -> bool:
 # custom_vjp surfaces
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention_with_lse(q, k, v, causal: bool = True,
                              block_q: Optional[int] = None,
                              block_k: Optional[int] = None,
                              interpret: bool = False,
-                             scale: Optional[float] = None):
+                             scale: Optional[float] = None,
+                             window: Optional[int] = None):
     """(out (b,s,h,dv), lse (b,h,s)) — both differentiable. ``block_q``
     / ``block_k`` None (both): each kernel takes `choose_tiles`' pair;
     a pinned pair goes to all three. ``v`` heads may have another width
-    than q/k heads; ``scale`` None is ``1 / sqrt`` of the q/k width."""
+    than q/k heads; ``scale`` None is ``1 / sqrt`` of the q/k width.
+    ``window`` (static, causal self-attention only): a query sees its
+    own position and the ``window - 1`` before it; None, or a window no
+    shorter than the sequence, is the plain causal call."""
     return _flash_with_lse_fwd(q, k, v, causal, block_q, block_k, interpret,
-                               scale)[0]
+                               scale, window)[0]
+
+
+def _effective_window(q, k, causal, window) -> Optional[int]:
+    """The window the kernels are built for: None where it masks
+    nothing the causal mask leaves."""
+    if window is None:
+        return None
+    if not causal or window < 1 or q.shape[1] != k.shape[1]:
+        raise ValueError(
+            f"window={window}: a window is the last `window` positions of "
+            f"causal self-attention (causal={causal}, seq {q.shape[1]} "
+            f"against {k.shape[1]})")
+    return None if window >= k.shape[1] else int(window)
 
 
 def _flash_with_lse_fwd(q, k, v, causal, block_q, block_k, interpret,
-                        scale=None):
+                        scale=None, window=None):
+    window = _effective_window(q, k, causal, window)
     # named scope = the kernel ledger's attribution key
     # (profiler/kernel_ledger.py classifies HLO sites by op_name path)
     with trace.scope("attention_fwd"):
         if interpret or _on_tpu():
             tiles = _tiles_for(q, k, v, block_q, block_k)
             if block_q is None:
-                _report_tiles(*tiles["fwd"])
+                _report_tiles(*tiles["fwd"], window)
             out, lse = _flash_fwd_pallas(q, k, v, causal, *tiles["fwd"],
-                                         interpret=interpret, scale=scale)
+                                         interpret=interpret, scale=scale,
+                                         window=window)
         else:
             out, lse = mha_reference_with_lse(q, k, v, causal=causal,
-                                              scale=scale)
+                                              scale=scale, window=window)
     return (out, lse), (q, k, v, out, lse)
 
 
-def _flash_with_lse_bwd(causal, block_q, block_k, interpret, scale, res, g):
+def _flash_with_lse_bwd(causal, block_q, block_k, interpret, scale, window,
+                        res, g):
     q, k, v, o, lse = res
     g_out, g_lse = g
+    window = _effective_window(q, k, causal, window)
     with trace.scope("attention_bwd"):
         if interpret or _on_tpu():
             tiles = _tiles_for(q, k, v, block_q, block_k)
             return _flash_bwd_pallas(
                 q, k, v, o, lse, g_out, g_lse, causal,
                 tiles["dq"], tiles["dkv"], interpret=interpret, scale=scale,
+                window=window,
             )
         _, vjp = jax.vjp(
-            lambda q, k, v: mha_reference_with_lse(q, k, v, causal=causal,
-                                                   scale=scale),
+            lambda q, k, v: mha_reference_with_lse(
+                q, k, v, causal=causal, scale=scale, window=window),
             q, k, v,
         )
         return vjp((g_out, g_lse))
@@ -756,7 +892,8 @@ def flash_attention(q, k, v, causal: bool = True,
                     block_k: Optional[int] = None,
                     interpret: bool = False,
                     mesh: Optional[Mesh] = None,
-                    scale: Optional[float] = None):
+                    scale: Optional[float] = None,
+                    window: Optional[int] = None):
     """``mesh``: the mesh the caller's jit partitions over. The compiler
     partitions the reference path itself, but not a Mosaic kernel
     ("cannot be automatically partitioned"), so over more than one
@@ -766,7 +903,7 @@ def flash_attention(q, k, v, causal: bool = True,
     stages) pass no mesh."""
     def attn(q, k, v):
         return flash_attention_with_lse(
-            q, k, v, causal, block_q, block_k, interpret, scale
+            q, k, v, causal, block_q, block_k, interpret, scale, window
         )[0]
 
     if mesh is None or mesh.size == 1 or not (interpret or _on_tpu()):

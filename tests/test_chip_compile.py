@@ -178,6 +178,30 @@ def test_flash_olmoe_cell_compiles_at_chosen_tiles(
     assert hlo.count("tpu_custom_call") == 3  # fwd, dq, dk/dv
 
 
+# smallthinker-ep4-1chip-steady (PR 37): 28 query heads on 4 kv heads of
+# 128 (group 7) at 16384 positions, the full layers' causal kernels and
+# the window layers' (window 4096: the band's walk, kernels named _swa),
+# at the tiles the kernels choose
+@pytest.mark.parametrize("window", [None, 4096])
+def test_flash_smallthinker_cell_compiles_at_chosen_tiles(
+        one_chip, kernels_are_the_path, window):
+    q = jax.ShapeDtypeStruct((1, 16384, 28, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 16384, 4, 128), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        out = attention.flash_attention(q, k, v, window=window)
+        return out.astype(jnp.float32).sum()
+
+    hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert hlo.count("tpu_custom_call") == 3
+    suffix = "_swa" if window else ""
+    for name in ("attention_fwd", "attention_bwd_dq", "attention_bwd_dkv"):
+        assert re.search(rf"%{name}{suffix}(\.\d+)? = ", hlo), name
+    assert ("_swa" in hlo) == bool(window)
+
+
 # The expert layer of that cell: 8192 tokens x 8 choices = 65536 rows
 # through 64 experts of 2048 x 1024, bf16. What the test holds is that
 # the v5e's compiler takes the grouped-matmul kernels at the tiles they

@@ -6,7 +6,7 @@ what JAX writes into every instruction's ``op_name``
 (``rematted_computation``, ``transpose(jvp(``) and find a scope as a
 whole component of that path. The builds below go through
 ``ElasticTrainer`` on the CPU, once with and once without remat for each
-of the four families' tiny configurations: they are what fails when a
+of the five families' tiny configurations: they are what fails when a
 JAX upgrade renames one of those, or a refactor drops a scope.
 """
 
@@ -29,13 +29,15 @@ from dlrover_tpu.train.trainer import ElasticTrainer, TrainConfig
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CONFIGS = ("tiny-cpu", "tiny-cpu-olmoe", "tiny-cpu-xing4",
-           "tiny-cpu-kimi-linear")
+           "tiny-cpu-kimi-linear", "tiny-cpu-smallthinker")
 # the scope that holds a family's dense feed-forward and the one that
 # holds its attention's projections (None: the family has none)
 DENSE = {"tiny-cpu": "dense_mlp", "tiny-cpu-olmoe": None,
-         "tiny-cpu-xing4": "dense_mlp", "tiny-cpu-kimi-linear": "dense_mlp"}
+         "tiny-cpu-xing4": "dense_mlp", "tiny-cpu-kimi-linear": "dense_mlp",
+         "tiny-cpu-smallthinker": None}
 PROJ = {"tiny-cpu": "attn_proj", "tiny-cpu-olmoe": "attn_proj",
-        "tiny-cpu-xing4": "mla_proj", "tiny-cpu-kimi-linear": "kda_proj"}
+        "tiny-cpu-xing4": "mla_proj", "tiny-cpu-kimi-linear": "kda_proj",
+        "tiny-cpu-smallthinker": "attn_proj"}
 
 
 @functools.lru_cache(maxsize=None)
