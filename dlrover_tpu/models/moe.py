@@ -41,11 +41,23 @@ it is a real compute path, and there is one of it:
   the router's probabilities and the ``k`` of a token are summed.
   Dispatch and combine are ``custom_vjp`` pairs whose backward is again a
   gather (by the inverse permutation), never a scatter-add.
+- **the live count** (``ops/moe_rows.py``): where a tail can exist (a
+  held share on one chip), combine, its backward and dispatch's backward
+  are Pallas kernels on the TPU that move the rows below
+  ``sum(group_sizes)`` and no others; the count stays on the device and
+  the arrays keep their ``(t * k, d)``. The zeros the grouped kernels
+  still store past the count are then read by nothing but the
+  elementwise ``act(gate) * up`` between them (and by
+  ``lax.ragged_dot`` where a shape falls back to it, which is why the
+  row kernels run only beside the grouped kernels). Dispatch's forward
+  stays XLA's gather, and so does everything where every expert is held
+  and under a mesh.
 - **under a mesh** the same path runs inside ``shard_map``: tokens stay
   where the batch axes put them, the rows are gathered over ``ep``, each
   rank sorts by its local experts (pairs for other ranks' experts fall
   in a tail past ``sum(group_sizes)`` that the grouped matmul leaves
-  zero) and the result is ``psum_scatter``-ed back; ``tp`` splits the
+  zero and ``combine_rows`` weighs into nothing) and the result is
+  ``psum_scatter``-ed back; ``tp`` splits the
   expert width (a ``psum`` closes the down projection) and ``fsdp``
   shards are gathered on the way in. Correct on the CPU meshes of
   tests/test_moe.py; its speed is nobody's subject until a four-chip
@@ -80,7 +92,8 @@ from dlrover_tpu.ops import (
     rms_norm,
     rope_frequencies,
 )
-from dlrover_tpu.ops.grouped_matmul import grouped_matmul
+from dlrover_tpu.ops import moe_rows
+from dlrover_tpu.ops.grouped_matmul import choose_tiles, grouped_matmul
 from dlrover_tpu.parallel.mesh import BATCH_AXES, EP, FSDP, SP, TP
 
 Params = Dict[str, Any]
@@ -312,10 +325,10 @@ def sort_pairs(top_e: jnp.ndarray, n_groups: int, first: Any = 0):
     flat = top_e.reshape(-1) - first
     key = jnp.where((flat >= 0) & (flat < n_groups), flat, n_groups)
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    n = order.shape[0]
-    inverse = jnp.zeros((n,), jnp.int32).at[order].set(
-        jnp.arange(n, dtype=jnp.int32), unique_indices=True
-    )
+    # the inverse of a permutation is its argsort: a second sort of
+    # int32 keys, a sixth of what the scatter of n scalars took (v5e,
+    # 98304 pairs: 0.08 against 0.45 ms, docs/design/kernels.md 1c)
+    inverse = jnp.argsort(order).astype(jnp.int32)
     group_sizes = jnp.sum(
         key[:, None] == jnp.arange(n_groups, dtype=key.dtype)[None, :],
         axis=0, dtype=jnp.int32,
@@ -324,67 +337,130 @@ def sort_pairs(top_e: jnp.ndarray, n_groups: int, first: Any = 0):
 
 
 def _int_zeros(*arrays):
-    return tuple(np.zeros(a.shape, jax.dtypes.float0) for a in arrays)
+    return tuple(
+        None if a is None else np.zeros(a.shape, jax.dtypes.float0)
+        for a in arrays)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def dispatch_rows(yt, order, inverse, k: int):
+def _permute(to, values):
+    """``values`` with element ``i`` moved to ``to[i]`` (``to`` a
+    permutation): ``values[argsort(to)]`` in one two-operand sort."""
+    return lax.sort((to, values), num_keys=1)[1]
+
+
+def _row_blocks(rows, weights_shape, live, interpret: bool):
+    """The row kernels' blocks for this call (``ops/moe_rows.py``), or
+    None where XLA's gathers run: a call that names no live count has no
+    tail to skip."""
+    if live is None:
+        return None
+    t, k = weights_shape
+    return moe_rows.row_blocks(t, k, rows.shape[1], rows.dtype,
+                               interpret=interpret)
+
+
+def dispatch_rows(yt, order, inverse, k: int, live=None, *,
+                  interpret: bool = False):
     """``yt (t, d)`` -> ``(t * k, d)``: sorted row ``r`` is the token of
-    pair ``order[r]``. The backward gathers by ``inverse`` and sums a
-    token's ``k`` rows, where autodiff would scatter-add."""
+    pair ``order[r]`` (one gather of whole rows: XLA's runs at the HBM's
+    rate). The backward gathers by ``inverse`` and sums a token's ``k``
+    rows, where autodiff would scatter-add. ``live ()``: the sorted rows
+    that any product reads, ``sum(group_sizes)``. Given it, on the TPU
+    (or under ``interpret``) the backward reads the cotangent's rows
+    below it and no others (``ops/moe_rows.py``)."""
+    blocks = _row_blocks(yt, (yt.shape[0], k), live, interpret)
+    return _dispatch(yt, order, inverse, live, k, blocks, interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _dispatch(yt, order, inverse, live, k, blocks, interpret):
     return yt[order // k]
 
 
-def _dispatch_fwd(yt, order, inverse, k):
-    return dispatch_rows.fun(yt, order, inverse, k), (order, inverse)
+def _dispatch_fwd(yt, order, inverse, live, k, blocks, interpret):
+    return (_dispatch.fun(yt, order, inverse, live, k, blocks, interpret),
+            (order, inverse, live))
 
 
-def _dispatch_bwd(k, res, g):
-    order, inverse = res
+def _dispatch_bwd(k, blocks, interpret, res, g):
+    order, inverse, live = res
     n, d = g.shape
-    d_yt = jnp.sum(
-        g[inverse].reshape(n // k, k, d), axis=1, dtype=jnp.float32
-    ).astype(g.dtype)
-    return (d_yt,) + _int_zeros(order, inverse)
+    if blocks is None:
+        d_yt = jnp.sum(
+            g[inverse].reshape(n // k, k, d), axis=1, dtype=jnp.float32
+        ).astype(g.dtype)
+    else:
+        # a custom_vjp's backward is traced outside the caller's scopes
+        with trace.scope("moe_dispatch"):
+            d_yt = moe_rows.token_sums(g, inverse, live, k, block=blocks[1],
+                                       interpret=interpret)
+    return (d_yt,) + _int_zeros(order, inverse, live)
 
 
-dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@jax.custom_vjp
-def combine_rows(rows, weights, order, inverse):
+def combine_rows(rows, weights, order, inverse, live=None, *,
+                 interpret: bool = False):
     """``rows (t * k, d)`` in sorted order, ``weights (t, k)`` float32 ->
     ``(t, d)``: token ``i``'s output is the sum over its ``k`` pairs of
-    weight x row. Forward and backward are gathers."""
+    weight x row. Forward and backward are gathers. ``live``: as
+    `dispatch_rows`. Given it, on the TPU (or under ``interpret``) a
+    pair whose sorted row is at or past it adds nothing and its row is
+    never read; its weight's cotangent is exactly 0, and its row's is
+    not written past the 256-row block that holds row ``live`` (nothing
+    reads it: the grouped products' backward visits no tile there)."""
+    blocks = _row_blocks(rows, weights.shape, live, interpret)
+    return _combine(rows, weights, order, inverse, live, blocks, interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _combine(rows, weights, order, inverse, live, blocks, interpret):
     t, k = weights.shape
-    picked = rows[inverse].reshape(t, k, rows.shape[1])
-    return jnp.sum(
-        picked.astype(jnp.float32) * weights[..., None], axis=1
-    ).astype(rows.dtype)
+    if blocks is None:
+        picked = rows[inverse].reshape(t, k, rows.shape[1])
+        return jnp.sum(
+            picked.astype(jnp.float32) * weights[..., None], axis=1
+        ).astype(rows.dtype)
+    return moe_rows.token_sums(rows, inverse, live, k, block=blocks[1],
+                               weights=weights.reshape(-1),
+                               interpret=interpret)
 
 
-def _combine_fwd(rows, weights, order, inverse):
-    return (combine_rows.fun(rows, weights, order, inverse),
-            (rows, weights, order, inverse))
+def _combine_fwd(rows, weights, order, inverse, live, blocks, interpret):
+    return (_combine.fun(rows, weights, order, inverse, live, blocks,
+                         interpret),
+            (rows, weights, order, inverse, live))
 
 
-def _combine_bwd(res, g):
-    rows, weights, order, inverse = res
+def _combine_bwd(blocks, interpret, res, g):
+    rows, weights, order, inverse, live = res
     k = weights.shape[1]
     # both cotangents from one gather of g, in sorted order; the
     # weights' comes back to (t, k) as a permutation of t * k scalars
-    g_rows = g[order // k]
-    d_rows = (
-        g_rows.astype(jnp.float32) * weights.reshape(-1)[order][:, None]
-    ).astype(rows.dtype)
-    d_weights = jnp.sum(
-        rows.astype(jnp.float32) * g_rows.astype(jnp.float32), axis=1
-    )
-    return (d_rows, d_weights[inverse].reshape(weights.shape)) + _int_zeros(
-        order, inverse)
+    if blocks is None:
+        g_rows = g[order // k]
+        d_rows = (
+            g_rows.astype(jnp.float32) * weights.reshape(-1)[order][:, None]
+        ).astype(rows.dtype)
+        d_weights = jnp.sum(
+            rows.astype(jnp.float32) * g_rows.astype(jnp.float32), axis=1
+        )[inverse]
+    else:
+        with trace.scope("moe_combine"):
+            # a permutation of n scalars as a sort by the inverse
+            # permutation: a gather of scalars runs an element at a time
+            sorted_weights = _permute(inverse, weights.reshape(-1))
+            d_rows, d_weights = moe_rows.sorted_cotangents(
+                g, order // k, rows, sorted_weights, live, block=blocks[0],
+                interpret=interpret)
+            d_weights = jnp.where(
+                inverse < live, _permute(order, d_weights), 0.0)
+    return (d_rows, d_weights.reshape(weights.shape)) + _int_zeros(
+        order, inverse, live)
 
 
-combine_rows.defvjp(_combine_fwd, _combine_bwd)
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def _route(cfg: MoeConfig, router, yt, token_axes=(), bias=None):
@@ -409,13 +485,28 @@ _ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 def _experts(lp: Params, yt, top_p, top_e, n_local: int, first: Any = 0,
-             act: str = "silu"):
+             act: str = "silu", tail: bool = False):
     """The ``n_local`` experts ``first ..`` applied to the pairs of
-    ``yt (t, d)`` that chose them, weighted and summed: ``(t, d)``."""
-    k = top_e.shape[1]
+    ``yt (t, d)`` that chose them, weighted and summed: ``(t, d)``.
+    ``tail``: the router scores experts that are not among them, so
+    pairs can sort past ``sum(group_sizes)``; combine, its backward and
+    dispatch's backward then stop at that count (``ops/moe_rows.py``),
+    and the rows' cotangent past it is left unwritten. The grouped
+    kernels visit no tile there in their backward; ``lax.ragged_dot``,
+    where a shape falls back to it, would read it, so the row kernels
+    run only where the grouped kernels do. Without a tail every row is
+    live and XLA's gathers, which move a row without the seven beside
+    it, are the faster (docs/design/kernels.md 1c)."""
+    t, k = top_e.shape
+    d, f = lp["w_gate"].shape[1:]
+    blocks = (tail and choose_tiles(t * k, d, f, yt.dtype)
+              and moe_rows.row_blocks(t, k, d, yt.dtype))
+    trace.gauge("moe.rows_kernel", int(bool(blocks)))
+    trace.gauge("moe.row_block", blocks[0] if blocks else 0)
     with trace.scope("moe_dispatch"):
         order, inverse, group_sizes = sort_pairs(top_e, n_local, first)
-        xs = dispatch_rows(yt, order, inverse, k)
+        live = jnp.sum(group_sizes) if blocks else None
+        xs = dispatch_rows(yt, order, inverse, k, live)
     with trace.scope("moe_experts"):
         gate = grouped_matmul(xs, lp["w_gate"], group_sizes)
         up = grouped_matmul(xs, lp["w_up"], group_sizes)
@@ -423,7 +514,7 @@ def _experts(lp: Params, yt, top_p, top_e, n_local: int, first: Any = 0,
             _ACTS[act](gate) * up, lp["w_down"], group_sizes
         )
     with trace.scope("moe_combine"):
-        return combine_rows(rows, top_p, order, inverse)
+        return combine_rows(rows, top_p, order, inverse, live)
 
 
 def _moe_tokens_sharded(cfg: MoeConfig, lp: Params, y, route_on=None):
@@ -449,6 +540,10 @@ def _moe_tokens_sharded(cfg: MoeConfig, lp: Params, y, route_on=None):
     # custom_vjps, which get no such help by themselves)
     yt, top_p = (lax.pcast(a, TP, to="varying") for a in (yt, top_p))
     first = cfg.first_expert + lax.axis_index(EP) * e_local
+    # XLA's gathers over every row here, tail or no tail: this
+    # shard_map checks how values vary over the mesh (tp's psum hangs
+    # on it), which puts a `pvary` into a kernel's body that Mosaic
+    # does not lower (the grouped kernels meet the same wall)
     out = _experts(lp, yt, top_p, top_e, e_local, first, cfg.expert_act)
     out = lax.psum_scatter(out, EP, scatter_dimension=0, tiled=True)
     return lax.psum(out, TP).reshape(b, s, d), aux
@@ -482,7 +577,7 @@ def moe_mlp(
             yt if route_on is None else route_on.reshape(b * s, d),
             bias=lp.get("router_bias"))
         out = _experts(lp, yt, top_p, top_e, cfg.n_held, cfg.first_expert,
-                       cfg.expert_act)
+                       cfg.expert_act, cfg.n_held < cfg.n_experts)
         out = out.reshape(b, s, d)
     else:
         specs = {"router": P(None, None), "w_gate": P(EP, None, TP),

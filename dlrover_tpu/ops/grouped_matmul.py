@@ -6,9 +6,15 @@ the rows of ``lhs`` come sorted by group, group ``i`` has
 matrix. Rows past ``sum(group_sizes)`` belong to no group and come out
 zero, in the forward and in d-lhs (models/moe.py sorts there the pairs
 that chose an expert this rank does not hold, (ep - 1) / ep of all
-pairs, and ``combine_rows`` gathers them with non-zero weights: the
-zeros are load-bearing). It is a ``jax.custom_vjp`` over three products
-of the same FLOPs:
+pairs). Who reads those zeros: XLA's gathers in ``combine_rows`` and
+``dispatch_rows``' backward, with non-zero weights, wherever
+``ops/moe_rows.py``'s kernels do not run (off the TPU, under a mesh,
+where a shape falls back); where they do run, only the elementwise
+``act(gate) * up`` between the products, and dropping the stores is
+the next step (``ROADMAP.md`` Queue 1 item 4). An operand's rows past
+the tile that holds row ``sum(group_sizes)`` are never fetched, so
+they may be unwritten memory. It is a ``jax.custom_vjp`` over three
+products of the same FLOPs:
 
 - **forward**  ``out[rows_i] = lhs[rows_i] @ rhs[i]``;
 - **d-lhs**    ``d_lhs[rows_i] = d_out[rows_i] @ rhs[i]^T``: the same

@@ -26,7 +26,8 @@ from jax.sharding import NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
 from dlrover_tpu.observability import trace
-from dlrover_tpu.ops import attention, fused_ce, grouped_matmul, kda
+from dlrover_tpu.ops import (
+    attention, fused_ce, grouped_matmul, kda, moe_rows)
 from dlrover_tpu.parallel import MeshConfig, build_mesh
 from dlrover_tpu.parallel.mesh import BATCH_AXES
 
@@ -65,6 +66,7 @@ def kernels_are_the_path(monkeypatch):
     monkeypatch.setattr(fused_ce, "_on_tpu", lambda: True)
     monkeypatch.setattr(grouped_matmul, "_on_tpu", lambda: True)
     monkeypatch.setattr(kda, "_on_tpu", lambda: True)
+    monkeypatch.setattr(moe_rows, "_on_tpu", lambda: True)
     monkeypatch.setenv("DLROVER_TPU_FUSED_CE", "1")
 
 
@@ -508,6 +510,98 @@ def test_kimi_linear_expert_block_fwd_bwd_compiles(
         3 if flash else 2.3) * 2**30
     assert trace.gauges()["moe.rows_held"] == 8192
     assert trace.gauges()["moe.tail_rows"] == 57344
+
+
+# The expert layer of the four expert cells, forward and backward under
+# remat as the cells run it: (tokens, choices, experts, held, width,
+# expert width, activation), and the temporaries the parent's program
+# needed for the same block (XLA's gathers over all t x k rows). Where
+# pairs can sort into a tail the row movements run ops/moe_rows.py's
+# kernels, bound by the live count: combine's forward and dispatch's
+# backward (`moe_rows_summed`) and combine's backward
+# (`moe_rows_cotangents`); OLMoE, which holds every expert, keeps XLA's
+# gathers.
+EXPERT_CELLS = {
+    "smallthinker": ((16384, 6, 64, 16, 2560, 768, "relu"), 1971133440),
+    "xing4": ((8192, 4, 64, 8, 3584, 1024, "silu"), 910812160),
+    "kimi": ((8192, 8, 256, 32, 2304, 1024, "silu"), 1054416896),
+    "olmoe": ((8192, 8, 64, None, 2048, 1024, "silu"), 675513856),
+}
+
+
+def _expert_layer(cell, sharding, mesh=None, batch=1):
+    from dlrover_tpu.models import moe
+
+    (t, k, e, held, d, f, act), _ = EXPERT_CELLS[cell]
+    cfg = moe.MoeConfig(
+        dim=d, ffn_dim=f, n_experts=e, experts_per_token=k,
+        experts_held=held, expert_act=act, n_layers=1, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16)
+    layers = moe.abstract_params(cfg)["layers"]
+    specs = moe.param_specs(cfg)["layers"]
+    lp = {
+        name: jax.ShapeDtypeStruct(
+            layers[name].shape[1:], layers[name].dtype,
+            sharding=sharding if mesh is None else NamedSharding(
+                mesh, P(*specs[name][1:])))
+        for name in ("router", "w_gate", "w_up", "w_down")
+    }
+    y = jax.ShapeDtypeStruct(
+        (batch, t // batch, d), jnp.bfloat16,
+        sharding=sharding if mesh is None else NamedSharding(
+            mesh, P(BATCH_AXES, None, None)))
+
+    def loss(lp, y):
+        fn = jax.checkpoint(
+            lambda lp, y: moe.moe_mlp(cfg, lp, y, mesh)[0],
+            policy=jax.checkpoint_policies.nothing_saveable)
+        return fn(lp, y).astype(jnp.float32).sum()
+
+    return jax.jit(
+        jax.value_and_grad(loss, argnums=(0, 1))).lower(lp, y).compile()
+
+
+@pytest.mark.parametrize("cell", sorted(EXPERT_CELLS))
+def test_expert_rows_fwd_bwd_compile_in_the_parents_memory(
+        one_chip, kernels_are_the_path, cell):
+    compiled = _expert_layer(cell, one_chip)
+    hlo = compiled.as_text()
+    tail = EXPERT_CELLS[cell][0][3] is not None
+    # combine's forward (the backward reads no output of it, so the
+    # remat forward has none) and dispatch's backward, and combine's
+    # backward; the three products forward, again under remat, d-lhs
+    # and d-rhs
+    assert _kernel_calls(hlo, "moe_rows_summed") == (2 if tail else 0)
+    assert _kernel_calls(hlo, "moe_rows_cotangents") == (1 if tail else 0)
+    assert _kernel_calls(hlo, "grouped_matmul") == 12
+    assert trace.gauges()["moe.rows_kernel"] == int(tail)
+    assert trace.gauges()["moe.row_block"] == (256 if tail else 0)
+    # every kernel under the scope the device metrics select by
+    for name in _op_names(hlo):
+        if "moe_rows_" in name:
+            assert _in_scope(name, "moe_combine") or _in_scope(
+                name, "moe_dispatch"), name
+    # no (t x k, d) array beside the parent's: the kernels' lists of
+    # int32 and float32 scalars (the live pairs, the sorted weights and
+    # their cotangent) are 0.4 MB each at 98304 pairs
+    parent = EXPERT_CELLS[cell][1]
+    assert compiled.memory_analysis().temp_size_in_bytes < parent + 2 * 2**20
+
+
+def test_expert_layer_over_four_chips_keeps_xlas_gathers(topo, monkeypatch):
+    """One program across the 2 x 2 mesh, ep 2: inside ``moe_mlp``'s
+    ``shard_map`` each rank holds half of the held experts and the other
+    half's pairs are its tail. That ``shard_map`` checks how values vary
+    over the mesh (tp's psum hangs on it), and the check writes a
+    ``pvary`` into a kernel's body, which Mosaic does not lower: no
+    Pallas kernel compiles inside it, the grouped products' neither. So
+    under a mesh the rows move by XLA's gathers and the products by
+    ``lax.ragged_dot``, as on the CPU meshes, and the program compiles."""
+    monkeypatch.setattr(moe_rows, "_on_tpu", lambda: True)
+    mesh = build_mesh(MeshConfig(dp=-1, ep=2), devices=list(topo.devices))
+    hlo = _expert_layer("xing4", None, mesh, batch=4).as_text()
+    assert "moe_rows_" not in hlo
+    assert trace.gauges()["moe.rows_kernel"] == 0
 
 
 def test_grouped_matmul_compiles_at_xing4_shape(
