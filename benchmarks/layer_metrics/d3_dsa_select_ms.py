@@ -1,0 +1,2 @@
+"""``d3_dsa_select_ms``: see ``d3_dsa_select_ms.json``."""
+from benchmarks.harness.hlo_scopes import scoped_ms_per_step as read  # noqa: F401

@@ -418,30 +418,48 @@ def hc_sublayer(cfg: Xing4Config, lp: Params, name: str, X, fn):
 # Latent attention, the block, the forward
 # ---------------------------------------------------------------------------
 
-def latent_attention(cfg, mesh, positions, inv_freq, lp, y):
+def latent_attention(cfg, mesh, positions, inv_freq, lp, y, *,
+                     window: Optional[int] = None, attend=None):
     """Latent attention of ``y (b, s, d)``, already pre-normed: q heads
     of ``qk_nope_dim + qk_rope_dim``, k and v through one bottleneck of
     rank ``kv_lora_rank``, the last ``qk_rope_dim`` of a key one vector
     for all heads. The one function of its kind: ``cfg`` is any config
-    with those widths, ``dtype``, ``norm_eps``, ``softmax_scale`` and,
-    where there is rotary, ``rope_magnitude`` (this family's,
-    ``models/kimi_linear.py``'s). q
+    (or a layer kind's view of one) with those widths, ``n_heads``,
+    ``dtype``, ``norm_eps``, ``softmax_scale`` and, where there is
+    rotary, ``rope_magnitude`` (this family's,
+    ``models/kimi_linear.py``'s, each layer kind of
+    ``models/dots3.py``'s, whose ranks, head counts, widths and theta
+    differ a call). q
     goes through a rank bottleneck with its own norm where ``lp`` has
     ``w_qa`` / ``w_qb`` and through one matrix ``w_q`` where not;
-    ``inv_freq`` None leaves the ``qk_rope_dim`` part without rotary."""
+    ``inv_freq`` None leaves the ``qk_rope_dim`` part without rotary.
+    Where ``cfg`` has ``latent_rescale = (a_q, a_kv)`` the two normed
+    latents are multiplied by them. ``window``: the flash kernels'
+    (a query sees its own position and the ``window - 1`` before it).
+    ``attend(q, k, v, c_q) -> (b, s, h, v_head_dim)`` stands in for the
+    causal flash call where the caller's attention is its own (a learned
+    selection: it is handed the normed q latent, which an indexer
+    reads). Where ``lp`` has ``w_g`` the heads' outputs are gated,
+    ``sigmoid(y w_g)`` a head, before ``w_o``."""
     dt, eps = cfg.dtype, cfg.norm_eps
     b, s, _ = y.shape
     h, rkv = cfg.n_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    a_q, a_kv = getattr(cfg, "latent_rescale", (1.0, 1.0))
+    c_q = None
     with trace.scope("mla_proj"):
         if "w_qa" in lp:
             c_q = rms_norm(y @ lp["w_qa"].astype(dt), lp["q_a_norm"], eps)
+            if a_q != 1.0:
+                c_q = c_q * a_q
             q = c_q @ lp["w_qb"].astype(dt)
         else:
             q = y @ lp["w_q"].astype(dt)
         q = q.reshape(b, s, h, dn + dr)
         kva = y @ lp["w_kva"].astype(dt)
         c_kv = rms_norm(kva[..., :rkv], lp["kv_a_norm"], eps)
+        if a_kv != 1.0:
+            c_kv = c_kv * a_kv
         kv = (c_kv @ lp["w_kvb"].astype(dt)).reshape(b, s, h, dn + dv)
         k_rope = kva[:, :, None, rkv:]
         if inv_freq is not None:
@@ -455,8 +473,15 @@ def latent_attention(cfg, mesh, positions, inv_freq, lp, y):
         k = jnp.concatenate(
             [kv[..., :dn], jnp.broadcast_to(k_rope, (b, s, h, dr))], axis=-1)
         v = kv[..., dn:]
-    out = flash_attention(q, k, v, causal=True, mesh=mesh,
-                          scale=cfg.softmax_scale)
+    if attend is not None:
+        out = attend(q, k, v, c_q)
+    else:
+        out = flash_attention(q, k, v, causal=True, mesh=mesh,
+                              scale=cfg.softmax_scale, window=window)
+    if "w_g" in lp:
+        with trace.scope("attn_gate"):
+            gate = jax.nn.sigmoid(y @ lp["w_g"].astype(dt))
+            out = out * gate[..., None]
     with trace.scope("mla_proj"):
         return out.reshape(b, s, h * dv) @ lp["w_o"].astype(dt)
 

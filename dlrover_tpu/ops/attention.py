@@ -42,6 +42,19 @@ How the three kernels walk the (q, k) plane:
   still the mask's value (docs/design/kernels.md). The window calls are
   named ``attention_fwd_swa``, ``attention_bwd_dq_swa`` and
   ``attention_bwd_dkv_swa``. ``window >= seq`` is the causal call.
+- **select** (``select=mask``, causal only): the mask is data. ``mask
+  (b, sq, sk)`` int8 is nonzero where the query sees the key, *already
+  under the causal mask* (``ops/dsa.py selection_mask`` makes it from
+  the indexer's scores: a model that chooses its own keys). The three
+  kernels read it tile by tile beside K and V (dk/dv its transpose,
+  made once a call) in place of the positions' comparison; the walk is
+  the causal walk, since a query's chosen keys lie in any block at or
+  under the diagonal, and ``causal`` only says which blocks cannot hold
+  a chosen pair. A row may choose nothing of a block it fetches, its
+  first included: the forward guards its exponent as under a window.
+  The calls are named ``attention_fwd_sel``, ``attention_bwd_dq_sel``
+  and ``attention_bwd_dkv_sel``. A call without ``select`` has no such
+  operand (it is not passed as "all").
 - **operands** go to the MXU in the dtype they arrive in (bf16 under
   ``activation_dtype: bfloat16``, f32 in the CPU tests) and accumulate
   in f32; ``P`` and ``dS`` are rounded to that dtype before their
@@ -67,6 +80,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax, shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -93,6 +107,7 @@ def mha_reference_with_lse(
     k_offset=0,
     scale: Optional[float] = None,
     window: Optional[int] = None,
+    select=None,
 ):
     """Stable-softmax attention in float32, GQA-aware; returns
     ``(out (b,sq,h,dv), lse (b,h,sq))``. ``q_offset`` / ``k_offset`` are
@@ -101,7 +116,8 @@ def mha_reference_with_lse(
     wider than ``q`` and ``k`` (latent attention: 192 against 128);
     ``scale`` None is ``1 / sqrt(d)`` of the q/k width. ``window`` (with
     ``causal``): a query sees its own position and the ``window - 1``
-    before it."""
+    before it. ``select (b, sq, sk)``: nonzero where the query sees
+    the key, the whole mask (it replaces the positions' comparison)."""
     assert window is None or causal, "a window is causal"
     b, sq, h, d = q.shape
     hkv = k.shape[2]
@@ -112,7 +128,9 @@ def mha_reference_with_lse(
     scale = _scale_for(d, scale)
     qf = q.astype(jnp.float32) * scale
     logits = jnp.einsum("bqhd,bkhd->bhqk", qf, k.astype(jnp.float32))
-    if causal:
+    if select is not None:
+        logits = jnp.where((select != 0)[:, None], logits, _NEG_INF)
+    elif causal:
         qpos = q_offset + jnp.arange(sq)
         kpos = k_offset + jnp.arange(k.shape[1])
         mask = qpos[:, None] >= kpos[None, :]
@@ -414,10 +432,30 @@ def _causal_mask(qi, ki, group: int, block_q: int, block_k: int,
     return (qpos >= kpos) & (qpos - kpos < window)
 
 
-def _name_suffix(window: Optional[int]) -> str:
-    """What a window call's kernels are named by, after the prefix the
-    plain calls have: a trace tells the two kinds of layer apart."""
+def _name_suffix(window: Optional[int], select: bool = False) -> str:
+    """What a window call's or a selection call's kernels are named by,
+    after the prefix the plain calls have: a trace tells the kinds of
+    layer apart."""
+    if select:
+        return "_sel"
     return "" if window is None else "_swa"
+
+
+def _split_select(refs, select: bool):
+    """A kernel's refs after K and V: ``(the selection's tile or None,
+    the rest)``."""
+    return (refs[0], refs[1:]) if select else (None, refs)
+
+
+def _selected(sel_ref, group: int):
+    """(group * rows, cols) bool from the selection's (1, rows, cols)
+    int8 tile; the group's heads repeat the q block's rows."""
+    seen = sel_ref[0].astype(jnp.int32) != 0
+    if group == 1:
+        return seen
+    rows, cols = seen.shape
+    return jnp.broadcast_to(
+        seen[None], (group, rows, cols)).reshape(group * rows, cols)
 
 
 def _compiler_params():
@@ -432,10 +470,13 @@ def _compiler_params():
 # ---------------------------------------------------------------------------
 
 def _flash_fwd_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-    *, group: int, block_q: int, block_k: int, n_kblocks: int,
-    causal: bool, scale: float, window: Optional[int] = None
+    q_ref, k_ref, v_ref, *rest,
+    group: int, block_q: int, block_k: int, n_kblocks: int,
+    causal: bool, scale: float, window: Optional[int] = None,
+    select: bool = False
 ):
+    sel_ref, (o_ref, lse_ref, acc_ref, m_ref, l_ref) = _split_select(
+        rest, select)
     qi = pl.program_id(2)
     step = pl.program_id(3)
     rows, dv = acc_ref.shape          # out is as wide as a v head
@@ -458,20 +499,23 @@ def _flash_fwd_kernel(
     # masked row of a later block meets it (exp(-1e30 - m) == 0, never
     # exp(0)). Under a window a row's first fetched block can lie wholly
     # before its oldest key: while a row's max is still the mask's value
-    # the exponent is taken against 0, so p, l and acc stay 0
+    # the exponent is taken against 0, so p, l and acc stay 0. A row
+    # under a selection may have chosen nothing of block 0 either
     @_when_needed(causal, qi, ki, block_q, block_k)
     def _compute():
         q = q_ref[0, 0].reshape(rows, d)                     # (G*bq, d)
         k = k_ref[0, 0]                                      # (bk, d)
         v = v_ref[0, 0]
         s = _dot(q, k, _NT) * scale                          # (G*bq, bk) f32
-        if causal:
+        if select:
+            s = jnp.where(_selected(sel_ref, group), s, _NEG_INF)
+        elif causal:
             s = jnp.where(
                 _causal_mask(qi, ki, group, block_q, block_k, window),
                 s, _NEG_INF)
         m_prev = m_ref[...]                                  # (G*bq, 128)
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        m_exp = m_next if window is None else jnp.where(
+        m_exp = m_next if window is None and not select else jnp.where(
             m_next < 0.5 * _NEG_INF, 0.0, m_next)
         p = jnp.exp(s - _lane_fill(m_exp, block_k))
         corr = jnp.exp(m_prev - m_next)
@@ -511,10 +555,31 @@ def _kv_specs(block_k: int, d: int, dv: int, causal: bool, block_q: int,
             pl.BlockSpec((1, 1, block_k, dv), index))
 
 
+def _select_specs(select, block_q: int, block_k: int, causal: bool,
+                  n_k: int):
+    """The selection's BlockSpec of the (b, hkv, n_q, n_k) grids, as a
+    list (empty without one): the (block_q, block_k) tile of the K / V
+    block's step, every head's the same."""
+    if select is None:
+        return []
+
+    def index(bi, hi, qi, ki):
+        if causal:
+            ki = jnp.minimum(ki, _last_k_block(qi, block_q, block_k, n_k))
+        return (bi, qi, ki)
+
+    return [pl.BlockSpec((1, block_q, block_k), index)]
+
+
+def _operands(select):
+    """A call's selection as its kernels' extra operand, if it has one."""
+    return () if select is None else (select,)
+
+
 def _flash_fwd_pallas(q, k, v, causal: bool, block_q: int, block_k: int,
                       interpret: bool = False,
                       scale: Optional[float] = None,
-                      window: Optional[int] = None):
+                      window: Optional[int] = None, select=None):
     b, sq, h, d = q.shape
     sk, hkv, dv = k.shape[1], k.shape[2], v.shape[3]
     group = h // hkv
@@ -540,10 +605,12 @@ def _flash_fwd_pallas(q, k, v, causal: bool, block_q: int, block_k: int,
             _flash_fwd_kernel, group=group, block_q=block_q,
             block_k=block_k, n_kblocks=n_k, causal=causal,
             scale=_scale_for(d, scale), window=window,
+            select=select is not None,
         ),
         grid=(b, hkv, n_q, k_steps),
         in_specs=[q_rows(d), *_kv_specs(block_k, d, dv, causal, block_q,
-                                        n_k, window)],
+                                        n_k, window),
+                  *_select_specs(select, block_q, block_k, causal, n_k)],
         out_specs=[q_rows(dv), q_rows(_LSE_LANES)],
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, group, sq, dv), q.dtype),
@@ -557,8 +624,8 @@ def _flash_fwd_pallas(q, k, v, causal: bool, block_q: int, block_k: int,
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
-        name="attention_fwd" + _name_suffix(window),
-    )(qt, kt, vt)
+        name="attention_fwd" + _name_suffix(window, select is not None),
+    )(qt, kt, vt, *_operands(select))
     out = out.reshape(b, h, sq, dv).transpose(0, 2, 1, 3)
     return out, lse.reshape(b, h, sq, _LSE_LANES)[..., 0]
 
@@ -581,10 +648,13 @@ def _flash_fwd_pallas(q, k, v, causal: bool, block_q: int, block_k: int,
 
 
 def _flash_bwd_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref,
-    *, group: int, block_q: int, block_k: int, n_kblocks: int,
-    causal: bool, scale: float, window: Optional[int] = None
+    q_ref, k_ref, v_ref, *rest,
+    group: int, block_q: int, block_k: int, n_kblocks: int,
+    causal: bool, scale: float, window: Optional[int] = None,
+    select: bool = False
 ):
+    sel_ref, (do_ref, lse_ref, delta_ref, dq_ref, acc_ref) = _split_select(
+        rest, select)
     qi = pl.program_id(2)
     step = pl.program_id(3)
     rows, d = acc_ref.shape
@@ -606,7 +676,9 @@ def _flash_bwd_dq_kernel(
         lse = lse_ref[0, 0].reshape(rows, _LSE_LANES)[:, :1]     # (G*bq, 1)
         delta = delta_ref[0, 0].reshape(rows, _LSE_LANES)[:, :1]
         s = _dot(q, k, _NT) * scale
-        if causal:
+        if select:
+            s = jnp.where(_selected(sel_ref, group), s, _NEG_INF)
+        elif causal:
             s = jnp.where(
                 _causal_mask(qi, ki, group, block_q, block_k, window),
                 s, _NEG_INF)
@@ -621,11 +693,14 @@ def _flash_bwd_dq_kernel(
 
 
 def _flash_bwd_dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    dk_acc, dv_acc,
-    *, block_q: int, block_k: int, n_qblocks: int, causal: bool,
-    scale: float, window: Optional[int] = None, q_steps: int = 0
+    q_ref, k_ref, v_ref, *rest,
+    block_q: int, block_k: int, n_qblocks: int, causal: bool,
+    scale: float, window: Optional[int] = None, q_steps: int = 0,
+    select: bool = False
 ):
+    # with a selection, its transposed (block_k, block_q) tile
+    sel_ref, (do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+              dk_acc, dv_acc) = _split_select(rest, select)
     # kv-head-major: grid dim 1 is the KV head; dim 3 sweeps
     # (query_head_in_group, q_block) pairs so the group's contributions
     # accumulate in VMEM and dk/dv are written once per kv head — no
@@ -660,7 +735,9 @@ def _flash_bwd_dkv_kernel(
         lse = lse_ref[0, 0]                                   # (1, bq)
         delta = delta_ref[0, 0]
         st = _dot(k, q, _NT) * scale                          # (bk, bq)
-        if causal:
+        if select:
+            st = jnp.where(_selected(sel_ref, 1), st, _NEG_INF)
+        elif causal:
             kpos = ki * block_k + lax.broadcasted_iota(
                 jnp.int32, (block_k, block_q), 0
             )
@@ -686,7 +763,7 @@ def _flash_bwd_dkv_kernel(
 
 def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
                       dq_tiles, dkv_tiles, interpret=False, scale=None,
-                      window: Optional[int] = None):
+                      window: Optional[int] = None, select=None):
     b, sq, h, d = q.shape
     sk, hkv, dv = k.shape[1], k.shape[2], v.shape[3]
     group = h // hkv
@@ -724,12 +801,13 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
         functools.partial(
             _flash_bwd_dq_kernel, group=group, block_q=block_q,
             block_k=block_k, n_kblocks=n_k, causal=causal, scale=scale,
-            window=window,
+            window=window, select=select is not None,
         ),
         grid=(b, hkv, n_q, k_steps),
         in_specs=[
             q_rows(d),
             *_kv_specs(block_k, d, dv, causal, block_q, n_k, window),
+            *_select_specs(select, block_q, block_k, causal, n_k),
             q_rows(dv), q_rows(_LSE_LANES), q_rows(_LSE_LANES),
         ],
         out_specs=q_rows(d),
@@ -737,9 +815,9 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
         scratch_shapes=[pltpu.VMEM((group * block_q, d), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
-        name="attention_bwd_dq" + _name_suffix(window),
+        name="attention_bwd_dq" + _name_suffix(window, select is not None),
     )(
-        qt.reshape(b, hkv, group, sq, d), kt, vt,
+        qt.reshape(b, hkv, group, sq, d), kt, vt, *_operands(select),
         dot.reshape(b, hkv, group, sq, dv), lanes8(lse), lanes8(delta),
     )
 
@@ -777,12 +855,15 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
         functools.partial(
             _flash_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
             n_qblocks=n_q, causal=causal, scale=scale, window=window,
-            q_steps=q_steps,
+            q_steps=q_steps, select=select is not None,
         ),
         grid=(b, hkv, n_k, group * q_steps),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), q_head),
             kv_block(d), kv_block(dv),
+            *([] if select is None else [pl.BlockSpec(
+                (1, block_k, block_q),
+                lambda bi, hi, i, j: (bi, i, q_head(bi, hi, i, j)[2]))]),
             pl.BlockSpec((1, 1, block_q, dv), q_head),
             pl.BlockSpec((1, 1, 1, block_q), q_head_row),
             pl.BlockSpec((1, 1, 1, block_q), q_head_row),
@@ -798,8 +879,11 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
-        name="attention_bwd_dkv" + _name_suffix(window),
-    )(qt, kt, vt, dot, lse.reshape(b, h, 1, sq), delta.reshape(b, h, 1, sq))
+        name="attention_bwd_dkv" + _name_suffix(window, select is not None),
+    )(qt, kt, vt,
+      # dk/dv computes transposed tiles: the selection transposed, once
+      *(() if select is None else (jnp.swapaxes(select, 1, 2),)),
+      dot, lse.reshape(b, h, 1, sq), delta.reshape(b, h, 1, sq))
 
     dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
     return dq, dkh.transpose(0, 2, 1, 3), dvh.transpose(0, 2, 1, 3)
@@ -887,29 +971,113 @@ def _flash_with_lse_bwd(causal, block_q, block_k, interpret, scale, window,
 flash_attention_with_lse.defvjp(_flash_with_lse_fwd, _flash_with_lse_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def flash_attention_select_with_lse(q, k, v, select,
+                                    block_q: Optional[int] = None,
+                                    block_k: Optional[int] = None,
+                                    interpret: bool = False,
+                                    scale: Optional[float] = None):
+    """Causal self-attention over the keys ``select`` names: ``select
+    (b, s, s)`` int8, nonzero where the query sees the key, already under
+    the causal mask (every row sees at least one key). ``(out, lse)`` as
+    `flash_attention_with_lse`, differentiable in q, k and v; the
+    selection gets no gradient. On the TPU (and under ``interpret``) the
+    ``_sel`` kernels, off it the jnp reference under the same mask."""
+    return _flash_select_fwd(q, k, v, select, block_q, block_k, interpret,
+                             scale)[0]
+
+
+def _check_select(q, k, select):
+    b, sq = q.shape[:2]
+    if (q.shape[1] != k.shape[1] or select.shape != (b, sq, sq)
+            or select.dtype != jnp.int8):
+        raise ValueError(
+            f"select {select.shape} {select.dtype}: a selection is one int8 "
+            f"(batch, seq, seq) mask of causal self-attention (q "
+            f"{q.shape}, k {k.shape})")
+
+
+def _flash_select_fwd(q, k, v, select, block_q, block_k, interpret, scale):
+    _check_select(q, k, select)
+    with trace.scope("attention_fwd"):
+        if interpret or _on_tpu():
+            tiles = _tiles_for(q, k, v, block_q, block_k)
+            if block_q is None:
+                trace.gauge("attn.select_block_q", tiles["fwd"][0])
+                trace.gauge("attn.select_block_k", tiles["fwd"][1])
+            out, lse = _flash_fwd_pallas(
+                q, k, v, True, *tiles["fwd"], interpret=interpret,
+                scale=scale, select=select)
+        else:
+            out, lse = mha_reference_with_lse(
+                q, k, v, causal=True, scale=scale, select=select)
+    return (out, lse), (q, k, v, select, out, lse)
+
+
+def _flash_select_bwd(block_q, block_k, interpret, scale, res, g):
+    q, k, v, select, o, lse = res
+    g_out, g_lse = g
+    no_grad = np.zeros(select.shape, jax.dtypes.float0)
+    with trace.scope("attention_bwd"):
+        if interpret or _on_tpu():
+            tiles = _tiles_for(q, k, v, block_q, block_k)
+            return _flash_bwd_pallas(
+                q, k, v, o, lse, g_out, g_lse, True, tiles["dq"],
+                tiles["dkv"], interpret=interpret, scale=scale,
+                select=select) + (no_grad,)
+        _, vjp = jax.vjp(
+            lambda q, k, v: mha_reference_with_lse(
+                q, k, v, causal=True, scale=scale, select=select),
+            q, k, v)
+        return vjp((g_out, g_lse)) + (no_grad,)
+
+
+flash_attention_select_with_lse.defvjp(_flash_select_fwd, _flash_select_bwd)
+
+
 def flash_attention(q, k, v, causal: bool = True,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: bool = False,
                     mesh: Optional[Mesh] = None,
                     scale: Optional[float] = None,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None,
+                    select=None, return_lse: bool = False):
     """``mesh``: the mesh the caller's jit partitions over. The compiler
     partitions the reference path itself, but not a Mosaic kernel
     ("cannot be automatically partitioned"), so over more than one
     device the kernels run under ``shard_map`` on each device's batch
     rows (data axes) and heads (tp), every sequence whole. Callers
     already inside a manual ``shard_map`` (ring, ulysses, the pp
-    stages) pass no mesh."""
-    def attn(q, k, v):
-        return flash_attention_with_lse(
-            q, k, v, causal, block_q, block_k, interpret, scale, window
-        )[0]
+    stages) pass no mesh. ``select``: see
+    `flash_attention_select_with_lse` (causal, no window; every head of
+    a batch row reads the one mask). ``return_lse``: ``(out, lse (b, h,
+    s))`` instead of ``out``."""
+    both = (lambda r: r) if return_lse else (lambda r: r[0])
+    if select is not None:
+        if not causal or window is not None:
+            raise ValueError(
+                "select= is the whole mask of causal self-attention: no "
+                f"window beside it (causal={causal}, window={window})")
 
+        def attn(q, k, v, select):
+            return both(flash_attention_select_with_lse(
+                q, k, v, select, block_q, block_k, interpret, scale))
+
+        operands = (q, k, v, select)
+    else:
+        def attn(q, k, v):
+            return both(flash_attention_with_lse(
+                q, k, v, causal, block_q, block_k, interpret, scale, window
+            ))
+
+        operands = (q, k, v)
     if mesh is None or mesh.size == 1 or not (interpret or _on_tpu()):
-        return attn(q, k, v)
+        return attn(*operands)
     spec = P(BATCH_AXES, None, TP, None)
+    specs = (spec,) * 3 + (P(BATCH_AXES, None, None),) * (select is not None)
     return shard_map(
-        attn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        attn, mesh=mesh, in_specs=specs,
+        out_specs=(spec, P(BATCH_AXES, TP, None)) if return_lse else spec,
         check_vma=False,
-    )(q, k, v)
+    )(*operands)

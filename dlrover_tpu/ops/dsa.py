@@ -1,0 +1,519 @@
+"""Learned sparse attention's own pieces: the indexer's scores, the exact
+top-k threshold of a causal row, the selection as a mask, and the
+head-summed attention probabilities the indexer learns from.
+
+A layer with an indexer (``models/dots3.py``'s full-attention layers)
+lets each query attend to the ``topk`` keys at or before it that a small
+scorer ranks highest::
+
+    I[t, s] = sum_j w[t, j] * relu(q_j[t] . k[s])      j over index heads
+    S_t     = the topk keys s <= t of largest I[t, s]  (all while t < topk)
+
+and trains the scorer towards the main attention's own distribution,
+``KL(p^_t || softmax_{s in S_t} I[t, s])`` with ``p`` the attention
+probabilities summed over heads. What this file computes:
+
+- `index_scores`: ``I (b, s, s)`` float32. On the TPU three Pallas
+  kernels under one ``custom_vjp`` (``dsa_index_fwd``; ``dsa_index_bwd_dq``
+  for d``q`` and d``w``; ``dsa_index_bwd_dk``): a grid step is a
+  (query block, key block) tile, the index heads a loop inside it, so
+  the ``(s, s, heads)`` products live in VMEM a head at a time and HBM
+  holds ``I`` alone. Blocks wholly above the diagonal are zeros, neither
+  computed nor fetched. bf16 operands, float32 products and sums.
+- `select_threshold`: per row the ``topk``-th largest of the causal
+  entries, exactly, and where its ties are cut: **bisection on the
+  float's bits** (32 counting passes over an order-preserving uint32
+  key build the threshold bit by bit, ``log2 s`` more find the position
+  of the last tie kept). Ties are broken to the lower ``s``, as a
+  stable descending sort and ``lax.top_k`` break them. XLA ops: every
+  pass is one fused compare-and-count over ``(s, s)``; ``lax.top_k`` at
+  k = 2048 and a full sort are the slow paths on a TPU.
+- `selection_mask`: the int8 ``(b, s, s)`` mask the flash kernels read
+  (``ops/attention.py`` ``select=``), causal included.
+- `head_summed_probs`: ``p[t, s] = sum_h exp(scale q_h[t] . k_h[s] -
+  lse[t, h])`` over the selected pairs, from q, k, the flash forward's
+  ``lse`` and the mask; a Pallas kernel on the TPU (``dsa_probs``: heads
+  innermost, the ``(block_q, block_k)`` float32 tile accumulated in its
+  output block), not differentiated.
+- `indexer_loss`: the KL, summed over the rows (XLA ops over ``(s, s)``).
+
+Off the TPU the XLA forms, blocked over query rows, which are the
+kernels' oracles; ``interpret=True`` runs the kernels on the CPU. No
+``(s, s, heads)`` array exists in HBM on the TPU path. A gather-by-index
+form (a query's ``topk`` keys gathered into a dense block) is not here:
+at 8192 positions the masked causal walk does less work a pair.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import Mesh, PartitionSpec as P
+
+from dlrover_tpu.observability import trace
+from dlrover_tpu.ops.attention import (
+    _LSE_LANES as _LANES,   # a per-row scalar's broadcast minor dim
+    _NN,
+    _NT,
+    _STAT_LANES,
+    _VMEM_LIMIT,
+    _dot,
+    _first_q_block as _first_q,
+    _last_k_block as _last_k,
+)
+from dlrover_tpu.ops.kda import _over_batch_rows
+from dlrover_tpu.parallel.mesh import BATCH_AXES
+
+#: largest (block_q, block_k) of the four kernels: the forward and the
+#: probabilities keep one float32 tile, the backwards also a float32
+#: accumulator an index head
+_MAX_TILE = {"fwd": (256, 512), "dq": (128, 512), "dk": (128, 512),
+             "probs": (512, 512)}
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _side(s: int, cap: int) -> int:
+    """The largest divisor of ``s`` up to ``cap`` that is a multiple of
+    128; a sequence 128 does not divide goes as one block."""
+    if s % 128:
+        return s
+    return next(t for t in range(min(cap, s) // 128 * 128, 0, -128)
+                if s % t == 0)
+
+
+def _tiles(kernel: str, s: int) -> Tuple[int, int]:
+    bq, bk = _MAX_TILE[kernel]
+    return _side(s, bq), _side(s, bk)
+
+
+def _params(*semantics):
+    return pltpu.CompilerParams(
+        dimension_semantics=semantics, vmem_limit_bytes=_VMEM_LIMIT)
+
+
+def _over_batch(fn, mesh: Optional[Mesh], *arrays):
+    """``fn`` on each device's batch rows (a Mosaic kernel is not
+    partitioned by the compiler); every operand leads with the batch and
+    the one result is ``(b, s, s)``."""
+    return _over_batch_rows(fn, mesh, arrays, (), P(BATCH_AXES, None, None))
+
+
+# ---------------------------------------------------------------------------
+# The indexer's scores
+# ---------------------------------------------------------------------------
+
+def _index_scores_xla(q, k, w):
+    """The definition, a block of query rows at a time (the block's
+    ``(rows, heads, s)`` products are all that exists at once; a block
+    is recomputed in a backward pass). Above the diagonal too."""
+    b, s, h, d = q.shape
+    block = 128 if s % 128 == 0 else s
+
+    @jax.checkpoint
+    def one(args):
+        qb, wb = args                                   # (b, block, h, d)
+        dots = jnp.einsum("bqhd,bkd->bqhk", qb, k,
+                          preferred_element_type=jnp.float32)
+        return jnp.sum(wb[..., None] * jnp.maximum(dots, 0.0), axis=2)
+
+    out = lax.map(one, (
+        jnp.moveaxis(q.reshape(b, s // block, block, h, d), 1, 0),
+        jnp.moveaxis(w.reshape(b, s // block, block, h), 1, 0)))
+    return jnp.moveaxis(out, 0, 1).reshape(b, s, s)
+
+
+def _index_fwd_kernel(q_ref, k_ref, w_ref, o_ref, *, bq: int, bk: int):
+    qi, ki = pl.program_id(1), pl.program_id(2)
+    heads = q_ref.shape[1]
+
+    @pl.when(ki * bk <= qi * bq + bq - 1)
+    def _compute():
+        k = k_ref[0]                                         # (bk, d)
+
+        def head(j, acc):
+            dots = _dot(q_ref[0, j], k, _NT)                 # (bq, bk) f32
+            return acc + w_ref[0, j][:, :1] * jnp.maximum(dots, 0.0)
+
+        o_ref[0] = lax.fori_loop(
+            0, heads, head, jnp.zeros((bq, bk), jnp.float32))
+
+    @pl.when(ki * bk > qi * bq + bq - 1)
+    def _above():
+        o_ref[0] = jnp.zeros((bq, bk), jnp.float32)
+
+
+def _lanes(x):
+    """``(b, h, s)`` -> ``(b, h, s, 8)``: a row's scalar with a minor dim
+    a TPU block can tile."""
+    return jnp.broadcast_to(x[..., None], x.shape + (_LANES,))
+
+
+def _index_fwd_pallas(q, k, w, interpret: bool):
+    b, s, h, d = q.shape
+    bq, bk = _tiles("fwd", s)
+    n_q, n_k = s // bq, s // bk
+    return pl.pallas_call(
+        functools.partial(_index_fwd_kernel, bq=bq, bk=bk),
+        grid=(b, n_q, n_k),
+        in_specs=[
+            pl.BlockSpec((1, h, bq, d), lambda bi, qi, ki: (bi, 0, qi, 0)),
+            pl.BlockSpec((1, bk, d), lambda bi, qi, ki: (
+                bi, jnp.minimum(ki, _last_k(qi, bq, bk, n_k)), 0)),
+            pl.BlockSpec((1, h, bq, _LANES),
+                         lambda bi, qi, ki: (bi, 0, qi, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, bq, bk), lambda bi, qi, ki: (bi, qi, ki)),
+        out_shape=jax.ShapeDtypeStruct((b, s, s), jnp.float32),
+        compiler_params=_params("parallel", "parallel", "parallel"),
+        interpret=interpret,
+        name="dsa_index_fwd",
+    )(q.transpose(0, 2, 1, 3), k, _lanes(w.transpose(0, 2, 1)))
+
+
+def _index_bwd_dq_kernel(q_ref, k_ref, w_ref, g_ref, dq_ref, dw_ref,
+                         dq_acc, dw_acc, *, bq: int, bk: int, n_k: int):
+    qi, ki = pl.program_id(1), pl.program_id(2)
+    heads = q_ref.shape[1]
+
+    @pl.when(ki == 0)
+    def _init():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+        dw_acc[...] = jnp.zeros_like(dw_acc)
+
+    @pl.when(ki * bk <= qi * bq + bq - 1)
+    def _compute():
+        k = k_ref[0]
+        g = g_ref[0]                                         # (bq, bk) f32
+
+        def head(j, carry):
+            dots = _dot(q_ref[0, j], k, _NT)
+            dw_acc[j] = dw_acc[j] + jnp.sum(
+                g * jnp.maximum(dots, 0.0), axis=1, keepdims=True)
+            gw = jnp.where(dots > 0.0, g * w_ref[0, j][:, :1], 0.0)
+            dq_acc[j] = dq_acc[j] + _dot(gw.astype(k.dtype), k, _NN)
+            return carry
+
+        lax.fori_loop(0, heads, head, 0)
+
+    @pl.when(ki == _last_k(qi, bq, bk, n_k))
+    def _finalize():
+        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+        dw_ref[0] = dw_acc[...][:, :, :_LANES]
+
+
+def _index_bwd_dk_kernel(q_ref, k_ref, w_ref, gt_ref, dk_ref, dk_acc,
+                         *, bq: int, bk: int, n_q: int):
+    ki, qi = pl.program_id(1), pl.program_id(2)
+    heads = q_ref.shape[1]
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+
+    @pl.when(ki * bk <= qi * bq + bq - 1)
+    def _compute():
+        k = k_ref[0]
+        gt = gt_ref[0]                                       # (bk, bq) f32
+
+        def head(j, carry):
+            q = q_ref[0, j]                                  # (bq, d)
+            dots_t = _dot(k, q, _NT)                         # (bk, bq)
+            gw = jnp.where(dots_t > 0.0, gt * w_ref[0, j], 0.0)
+            dk_acc[...] = dk_acc[...] + _dot(gw.astype(q.dtype), q, _NN)
+            return carry
+
+        lax.fori_loop(0, heads, head, 0)
+
+    @pl.when(qi == n_q - 1)
+    def _finalize():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+
+
+def _index_bwd_pallas(q, k, w, g, interpret: bool):
+    b, s, h, d = q.shape
+    qt = q.transpose(0, 2, 1, 3)
+    wt = w.transpose(0, 2, 1)                                # (b, h, s)
+    bq, bk = _tiles("dq", s)
+    n_q, n_k = s // bq, s // bk
+
+    def q_rows(lanes):
+        return pl.BlockSpec((1, h, bq, lanes),
+                            lambda bi, qi, ki: (bi, 0, qi, 0))
+
+    def k_index(bi, qi, ki):
+        return jnp.minimum(ki, _last_k(qi, bq, bk, n_k))
+
+    dq, dw = pl.pallas_call(
+        functools.partial(_index_bwd_dq_kernel, bq=bq, bk=bk, n_k=n_k),
+        grid=(b, n_q, n_k),
+        in_specs=[
+            q_rows(d),
+            pl.BlockSpec((1, bk, d),
+                         lambda bi, qi, ki: (bi, k_index(bi, qi, ki), 0)),
+            q_rows(_LANES),
+            pl.BlockSpec((1, bq, bk),
+                         lambda bi, qi, ki: (bi, qi, k_index(bi, qi, ki))),
+        ],
+        out_specs=[q_rows(d), q_rows(_LANES)],
+        out_shape=[jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
+                   jax.ShapeDtypeStruct((b, h, s, _LANES), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((h, bq, d), jnp.float32),
+                        pltpu.VMEM((h, bq, _STAT_LANES), jnp.float32)],
+        compiler_params=_params("parallel", "parallel", "arbitrary"),
+        interpret=interpret,
+        name="dsa_index_bwd_dq",
+    )(qt, k, _lanes(wt), g)
+
+    bq, bk = _tiles("dk", s)
+    n_q, n_k = s // bq, s // bk
+
+    def q_index(bi, ki, qi):
+        # above the diagonal the index waits on the first block needed
+        return jnp.maximum(qi, _first_q(ki, bq, bk, n_q))
+
+    dk = pl.pallas_call(
+        functools.partial(_index_bwd_dk_kernel, bq=bq, bk=bk, n_q=n_q),
+        grid=(b, n_k, n_q),
+        in_specs=[
+            pl.BlockSpec((1, h, bq, d),
+                         lambda bi, ki, qi: (bi, 0, q_index(bi, ki, qi), 0)),
+            pl.BlockSpec((1, bk, d), lambda bi, ki, qi: (bi, ki, 0)),
+            pl.BlockSpec((1, h, 1, bq),
+                         lambda bi, ki, qi: (bi, 0, 0, q_index(bi, ki, qi))),
+            pl.BlockSpec((1, bk, bq),
+                         lambda bi, ki, qi: (bi, ki, q_index(bi, ki, qi))),
+        ],
+        out_specs=pl.BlockSpec((1, bk, d), lambda bi, ki, qi: (bi, ki, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, s, d), k.dtype),
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32)],
+        compiler_params=_params("parallel", "parallel", "arbitrary"),
+        interpret=interpret,
+        name="dsa_index_bwd_dk",
+    )(qt, k, wt[:, :, None, :], g.swapaxes(1, 2))
+    return (dq.transpose(0, 2, 1, 3), dk,
+            dw[..., 0].transpose(0, 2, 1).astype(w.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _index_scores_kernels(q, k, w, interpret):
+    return _index_fwd_pallas(q, k, w, interpret)
+
+
+def _index_scores_fwd(q, k, w, interpret):
+    return _index_fwd_pallas(q, k, w, interpret), (q, k, w)
+
+
+def _index_scores_bwd(interpret, res, g):
+    with trace.scope("dsa_index"):
+        return _index_bwd_pallas(*res, g, interpret)
+
+
+_index_scores_kernels.defvjp(_index_scores_fwd, _index_scores_bwd)
+
+
+def index_scores(q, k, w, *, interpret: bool = False,
+                 mesh: Optional[Mesh] = None):
+    """``I[t, s] = sum_j w[t, j] relu(q[t, j] . k[s])``: ``q (b, s, h,
+    d)`` the index heads' queries, ``k (b, s, d)`` the one index key a
+    position, ``w (b, s, h)`` float32 the heads' weights -> ``(b, s, s)``
+    float32, differentiable in all three. Entries above the diagonal
+    are unspecified (the kernels write zeros, the XLA form the value):
+    everything downstream reads the causal part alone."""
+    kernels = interpret or _on_tpu()
+    trace.gauge("dsa.kernel", 1 if kernels else 0)
+    w = w.astype(jnp.float32)
+    if not kernels:
+        return _index_scores_xla(q, k, w)
+    return _over_batch(
+        lambda q, k, w: _index_scores_kernels(q, k, w, interpret),
+        mesh, q, k, w)
+
+
+# ---------------------------------------------------------------------------
+# The selection
+# ---------------------------------------------------------------------------
+
+def _ordered_bits(x):
+    """float32 -> uint32 whose unsigned order is the floats' order
+    (-0.0 below +0.0)."""
+    bits = lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+    negative = bits >> 31 == 1
+    return jnp.where(negative, ~bits, bits | jnp.uint32(0x80000000))
+
+
+def _causal(s: int):
+    pos = jnp.arange(s, dtype=jnp.int32)
+    return pos[None, :] <= pos[:, None]                      # (query, key)
+
+
+def _causal_bits(scores):
+    """`_ordered_bits` of the causal entries; what the causal mask hides
+    becomes 0 and sorts below every score."""
+    return jnp.where(_causal(scores.shape[-1]), _ordered_bits(scores),
+                     jnp.uint32(0))
+
+
+def _threshold(bits, topk: int):
+    """`select_threshold` on a row's `_causal_bits`."""
+    s = bits.shape[-1]
+
+    def count(seen):
+        return jnp.sum(seen, axis=-1, dtype=jnp.int32)       # (b, s)
+
+    def grow(i, tau):
+        cand = tau | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        return jnp.where(count(bits >= cand[..., None]) >= topk, cand, tau)
+
+    tau = lax.fori_loop(0, 32, grow, jnp.zeros(bits.shape[:-1], jnp.uint32))
+    # ties at tau: keep the `need` of lowest position
+    need = topk - count(bits > tau[..., None])
+    ties = bits == tau[..., None]
+    pos = jnp.arange(s, dtype=jnp.int32)
+    n_bits = max(s - 1, 1).bit_length()
+
+    def reach(i, cut):
+        cand = cut | (jnp.int32(1) << (n_bits - 1 - i))
+        below = count(ties & (pos < cand[..., None]))
+        return jnp.where(below < need, cand, cut)
+
+    cut = lax.fori_loop(0, n_bits, reach, jnp.zeros(bits.shape[:-1], jnp.int32))
+    return tau, cut
+
+
+def select_threshold(scores, topk: int):
+    """Per row ``t`` of ``scores (b, s, s)``, over its causal entries ``s
+    <= t``: ``(tau, cut)`` with ``tau (b, s)`` uint32 the ordered bits
+    (`_ordered_bits`) of the ``topk``-th largest and ``cut (b, s)`` int32
+    the position of the last entry equal to it that is kept, ties broken
+    to the lower ``s``: the row's selection is ``bits > tau``, or ``bits
+    == tau`` at ``s <= cut``. A row with no more than ``topk`` causal
+    entries gets ``tau`` 0 (everything). Exact: 32 counting passes build
+    ``tau`` from its highest bit down, ``s.bit_length()`` more find
+    ``cut``."""
+    return _threshold(_causal_bits(scores), topk)
+
+
+def selection_mask(scores, topk: int):
+    """``(b, s, s)`` int8, 1 where query ``t`` attends to key ``s``: the
+    ``topk`` causal keys of largest ``scores[t, s]`` (all of them while
+    ``t < topk``), ties to the lower ``s``. What ``flash_attention(
+    select=)`` reads."""
+    s = scores.shape[-1]
+    causal = _causal(s)
+    if topk >= s:
+        return jnp.broadcast_to(causal.astype(jnp.int8), scores.shape)
+    bits = _causal_bits(scores)
+    tau, cut = (a[..., None] for a in _threshold(bits, topk))
+    pos = jnp.arange(s, dtype=jnp.int32)
+    chosen = (bits > tau) | ((bits == tau) & (pos <= cut))
+    return (chosen & causal).astype(jnp.int8)
+
+
+# ---------------------------------------------------------------------------
+# What the indexer learns from
+# ---------------------------------------------------------------------------
+
+def _probs_xla(q, k, lse, mask, scale: float):
+    b, s, h, d = q.shape
+    block = 128 if s % 128 == 0 else s
+
+    def one(args):
+        qb, lb, mb = args                 # (b, block, h, d), (b, h, block)
+        logits = jnp.einsum("bqhd,bkhd->bhqk", qb, k,
+                            preferred_element_type=jnp.float32) * scale
+        p = jnp.exp(logits - lb[..., None])
+        return jnp.sum(jnp.where((mb != 0)[:, None], p, 0.0), axis=1)
+
+    out = lax.map(one, (
+        jnp.moveaxis(q.reshape(b, s // block, block, h, d), 1, 0),
+        jnp.moveaxis(lse.reshape(b, h, s // block, block), 2, 0),
+        jnp.moveaxis(mask.reshape(b, s // block, block, s), 1, 0)))
+    return jnp.moveaxis(out, 0, 1).reshape(b, s, s)
+
+
+def _probs_kernel(q_ref, k_ref, lse_ref, m_ref, o_ref, *, bq: int, bk: int,
+                  scale: float):
+    qi, ki, hi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+
+    @pl.when(hi == 0)
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(ki * bk <= qi * bq + bq - 1)
+    def _compute():
+        logits = _dot(q_ref[0, 0], k_ref[0, 0], _NT) * scale
+        p = jnp.exp(logits - lse_ref[0, 0][:, :1])
+        seen = m_ref[0].astype(jnp.int32) != 0
+        o_ref[0] = o_ref[0] + jnp.where(seen, p, 0.0)
+
+
+def _probs_pallas(q, k, lse, mask, scale: float, interpret: bool):
+    b, s, h, d = q.shape
+    bq, bk = _tiles("probs", s)
+    n_q, n_k = s // bq, s // bk
+
+    def k_index(qi, ki):
+        return jnp.minimum(ki, _last_k(qi, bq, bk, n_k))
+
+    return pl.pallas_call(
+        functools.partial(_probs_kernel, bq=bq, bk=bk, scale=scale),
+        grid=(b, n_q, n_k, h),
+        in_specs=[
+            pl.BlockSpec((1, 1, bq, d),
+                         lambda bi, qi, ki, hi: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, bk, d),
+                         lambda bi, qi, ki, hi: (bi, hi, k_index(qi, ki), 0)),
+            pl.BlockSpec((1, 1, bq, _LANES),
+                         lambda bi, qi, ki, hi: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, bq, bk),
+                         lambda bi, qi, ki, hi: (bi, qi, k_index(qi, ki))),
+        ],
+        out_specs=pl.BlockSpec(
+            (1, bq, bk), lambda bi, qi, ki, hi: (bi, qi, ki)),
+        out_shape=jax.ShapeDtypeStruct((b, s, s), jnp.float32),
+        compiler_params=_params(
+            "parallel", "parallel", "parallel", "arbitrary"),
+        interpret=interpret,
+        name="dsa_probs",
+    )(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), _lanes(lse), mask)
+
+
+def head_summed_probs(q, k, lse, mask, scale: float, *,
+                      interpret: bool = False, mesh: Optional[Mesh] = None):
+    """``p[t, s] = sum_h exp(scale q[t, h] . k[s, h] - lse[h, t])`` where
+    ``mask[t, s]`` is set, 0 elsewhere: the main attention's
+    probabilities summed over its heads, ``(b, s, s)`` float32. ``q, k
+    (b, s, h, d)`` and ``lse (b, h, s)`` are the flash forward's
+    operands and result under the same mask. Not differentiated."""
+    q, k, lse = (lax.stop_gradient(a) for a in (q, k, lse))
+    scale = float(scale)
+    if not (interpret or _on_tpu()):
+        return _probs_xla(q, k, lse, mask, scale)
+    return _over_batch(
+        lambda q, k, lse, mask: _probs_pallas(
+            q, k, lse, mask, scale, interpret),
+        mesh, q, k, lse, mask)
+
+
+def indexer_loss(scores, probs, mask):
+    """``sum_t KL(p^_t || softmax_{s in S_t} scores[t, s])`` over every
+    row of the batch, ``p^ = probs / sum_{s in S_t} probs`` a constant:
+    a float32 scalar whose gradient reaches ``scores`` alone."""
+    seen = mask != 0
+    target = lax.stop_gradient(probs)
+    target = target / jnp.sum(target, axis=-1, keepdims=True)
+    logq = jax.nn.log_softmax(
+        jnp.where(seen, scores, -jnp.inf), axis=-1)
+    terms = jnp.where(
+        seen & (target > 0.0),
+        target * (jnp.log(jnp.where(target > 0.0, target, 1.0)) - logq), 0.0)
+    return jnp.sum(terms)

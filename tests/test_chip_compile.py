@@ -27,7 +27,7 @@ from jax.sharding import PartitionSpec as P
 
 from dlrover_tpu.observability import trace
 from dlrover_tpu.ops import (
-    attention, fused_ce, grouped_matmul, kda, moe_rows)
+    attention, dsa, fused_ce, grouped_matmul, kda, moe_rows)
 from dlrover_tpu.parallel import MeshConfig, build_mesh
 from dlrover_tpu.parallel.mesh import BATCH_AXES
 
@@ -67,6 +67,7 @@ def kernels_are_the_path(monkeypatch):
     monkeypatch.setattr(grouped_matmul, "_on_tpu", lambda: True)
     monkeypatch.setattr(kda, "_on_tpu", lambda: True)
     monkeypatch.setattr(moe_rows, "_on_tpu", lambda: True)
+    monkeypatch.setattr(dsa, "_on_tpu", lambda: True)
     monkeypatch.setenv("DLROVER_TPU_FUSED_CE", "1")
 
 
@@ -202,6 +203,72 @@ def test_flash_smallthinker_cell_compiles_at_chosen_tiles(
     for name in ("attention_fwd", "attention_bwd_dq", "attention_bwd_dkv"):
         assert re.search(rf"%{name}{suffix}(\.\d+)? = ", hlo), name
     assert ("_swa" in hlo) == bool(window)
+
+
+# dots3-ep32-1chip-steady (PR 40): b1, s8192. A full layer's 32 held
+# heads of 192 / 128 read the selection as an int8 operand, tile by tile,
+# in all three kernels; a window layer's 16 held heads of 256 / 128 walk
+# a band of 513, which no tile divides.
+@pytest.mark.parametrize("kind", ["select", "window"])
+def test_flash_dots3_cell_compiles_at_chosen_tiles(
+        one_chip, kernels_are_the_path, kind):
+    heads, d = (32, 192) if kind == "select" else (16, 256)
+    q = jax.ShapeDtypeStruct((1, 8192, heads, d), jnp.bfloat16,
+                             sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 8192, heads, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((1, 8192, 8192), jnp.int8,
+                                sharding=one_chip)
+
+    def loss(q, k, v, mask):
+        kw = dict(select=mask) if kind == "select" else dict(window=513)
+        return attention.flash_attention(q, k, v, **kw).astype(
+            jnp.float32).sum()
+
+    hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, v, mask)
+    assert hlo.count("tpu_custom_call") == 3
+    suffix = "_sel" if kind == "select" else "_swa"
+    for name in ("attention_fwd", "attention_bwd_dq", "attention_bwd_dkv"):
+        assert re.search(rf"%{name}{suffix}(\.\d+)? = ", hlo), name
+    # the selection is one byte a pair: never widened to a tensor a head
+    assert "s8[1,8192,8192]" in hlo or kind == "window"
+    assert not re.search(r"\[1,32,8192,8192\]|\[1,8192,8192,32\]", hlo)
+
+
+# The indexer at the same cell: 64 index heads of 128 against one index
+# key a position; forward and the backward L_I needs; and the kernel
+# that sums the main attention's probabilities over the 32 held heads.
+# No (8192, 8192, 64) array is in either program.
+def test_dsa_index_kernels_compile_at_the_cell_shape(
+        one_chip, kernels_are_the_path):
+    q = jax.ShapeDtypeStruct((1, 8192, 64, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, 8192, 128), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((1, 8192, 64), jnp.float32, sharding=one_chip)
+
+    def loss(q, k, w):
+        return jnp.sum(dsa.index_scores(q, k, w) ** 2)
+
+    hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, k, w)
+    # (alone under grad XLA names the forward's call after its jvp scope)
+    for name in ("dsa_index_fwd", "dsa_index_bwd_dq", "dsa_index_bwd_dk"):
+        assert sum("custom-call(" in line and name in line.split(" = ")[0]
+                   for line in hlo.splitlines()) == 1, name
+    assert not re.search(r"8192,8192,64\]|8192,64,8192\]|64,8192,8192\]", hlo)
+
+
+def test_dsa_probs_kernel_compiles_at_the_cell_shape(
+        one_chip, kernels_are_the_path):
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 192), jnp.bfloat16,
+                             sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((1, 32, 8192), jnp.float32, sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((1, 8192, 8192), jnp.int8,
+                                sharding=one_chip)
+    hlo = _compile(
+        lambda q, k, lse, mask: dsa.head_summed_probs(
+            q, k, lse, mask, 192 ** -0.5), q, q, lse, mask)
+    assert hlo.count("tpu_custom_call") == 1 and "dsa_probs" in hlo
+    assert not re.search(r"32,8192,8192\]|8192,8192,32\]", hlo)
 
 
 # The expert layer of that cell: 8192 tokens x 8 choices = 65536 rows
@@ -598,6 +665,7 @@ def test_expert_layer_over_four_chips_keeps_xlas_gathers(topo, monkeypatch):
     under a mesh the rows move by XLA's gathers and the products by
     ``lax.ragged_dot``, as on the CPU meshes, and the program compiles."""
     monkeypatch.setattr(moe_rows, "_on_tpu", lambda: True)
+    monkeypatch.setattr(dsa, "_on_tpu", lambda: True)
     mesh = build_mesh(MeshConfig(dp=-1, ep=2), devices=list(topo.devices))
     hlo = _expert_layer("xing4", None, mesh, batch=4).as_text()
     assert "moe_rows_" not in hlo
@@ -681,6 +749,24 @@ def test_fused_ce_fwd_bwd_compiles(one_chip, n, d, v):
     # a vocabulary some multiple of 128 up to the tile divides is not
     # padded to the tile into a copy of the head (50304 -> 50688 at 512)
     assert f",{-(-v // 512) * 512}]" not in hlo or v % 512 == 0
+
+
+# dots3-ep32-1chip-steady's head (PR 40): the first cell past d = 4096.
+# At 5120 the three kernels keep the default tiles (256 tokens x 512
+# columns: dw's blocks are 36.7 MiB of the 48 MiB budget; they halve from
+# d = 8192); 19008 columns are no multiple of 128 and pad to 19456.
+def test_fused_ce_compiles_at_dots3_width(one_chip):
+    n, d, v = 8192, 5120, 19008
+    for kernel in (fused_ce.LOSS, fused_ce.LOSS_DX, fused_ce.DW):
+        assert fused_ce._tile_geometry(
+            n, v, d, jnp.bfloat16, jnp.bfloat16, fused_ce.DEFAULT_BLOCK_T,
+            fused_ce.DEFAULT_BLOCK_V, kernel) == (256, 512, 8192, 19456)
+    args = _ce_args(n, d, v, one_chip)
+    assert _compile(_fused_nll, *args).count("tpu_custom_call") == 1
+    hlo = _compile(jax.grad(_fused_nll, argnums=(0, 1)), *args)
+    assert hlo.count("tpu_custom_call") == 2
+    for name in ("fused_ce_fwd", "fused_ce_bwd_dw"):
+        assert _kernel_calls(hlo, name) == 1, name
 
 
 # Over more than one device the kernels run per shard under shard_map:

@@ -1,0 +1,214 @@
+"""``ops/dsa.py``: the indexer's score kernels in interpret mode against
+the XLA form and against the definition written apart, forward and the
+backward ``L_I`` needs; the exact top-k threshold against ``lax.top_k``,
+ties included; the head-summed probabilities; the KL and where its
+gradient goes."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+
+from dlrover_tpu.observability import trace
+from dlrover_tpu.ops import dsa
+from dlrover_tpu.ops.attention import mha_reference_with_lse
+
+S = 256
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """Several blocks a side at 256 positions."""
+    monkeypatch.setattr(dsa, "_MAX_TILE", {
+        "fwd": (128, 128), "dq": (128, 128), "dk": (128, 128),
+        "probs": (128, 128)})
+
+
+def _operands(b=2, s=S, h=4, d=32, seed=0):
+    kq, kk, kw = jax.random.split(jax.random.key(seed), 3)
+    return (jax.random.normal(kq, (b, s, h, d), jnp.float32),
+            jax.random.normal(kk, (b, s, d), jnp.float32),
+            jax.random.normal(kw, (b, s, h), jnp.float32))
+
+
+def _causal(s=S):
+    return jnp.tril(jnp.ones((s, s), bool))
+
+
+def _plain_scores(q, k, w):
+    """The definition in one expression."""
+    dots = jnp.einsum("bthd,bsd->bths", q, k)
+    return jnp.sum(w[..., None] * jnp.maximum(dots, 0.0), axis=2)
+
+
+@pytest.mark.parametrize("interpret", [False, True])
+def test_index_scores_match_the_definition(interpret, small_tiles):
+    q, k, w = _operands()
+    got = dsa.index_scores(q, k, w, interpret=interpret)
+    want = _plain_scores(q, k, w)
+    np.testing.assert_allclose(
+        jnp.where(_causal(), got, 0.0), jnp.where(_causal(), want, 0.0),
+        atol=2e-4, rtol=2e-5)
+    assert trace.gauges()["dsa.kernel"] == float(interpret)
+
+
+@pytest.mark.parametrize("interpret", [False, True])
+def test_index_scores_gradients(interpret, small_tiles):
+    """The backward ``L_I`` needs: a cotangent that lives on causal
+    entries only, as the KL's does."""
+    q, k, w = _operands(seed=1)
+    g = jax.random.normal(jax.random.key(9), (2, S, S)) * _causal()
+
+    def grads(fn):
+        return jax.grad(
+            lambda q, k, w: jnp.sum(fn(q, k, w) * g), argnums=(0, 1, 2))(
+                q, k, w)
+
+    want = grads(_plain_scores)
+    got = grads(lambda q, k, w: dsa.index_scores(
+        q, k, w, interpret=interpret))
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=2e-3, rtol=2e-4)
+
+
+def test_kernels_write_zeros_above_the_diagonal_blocks(small_tiles):
+    q, k, w = _operands(b=1)
+    got = dsa.index_scores(q, k, w, interpret=True)
+    assert not np.asarray(got[0, :128, 128:]).any()
+
+
+def test_the_kernels_are_three_and_named(monkeypatch, small_tiles):
+    names = []
+    real = dsa.pl.pallas_call
+
+    def spy(*a, **kw):
+        names.append(kw.get("name"))
+        return real(*a, **kw)
+
+    monkeypatch.setattr(dsa.pl, "pallas_call", spy)
+    q, k, w = _operands(b=1)
+    jax.grad(lambda q: jnp.sum(dsa.index_scores(q, k, w, interpret=True)))(q)
+    assert names == ["dsa_index_fwd", "dsa_index_bwd_dq", "dsa_index_bwd_dk"]
+
+
+# -- the selection ---------------------------------------------------------
+
+def _top_k_mask(scores, topk):
+    """``lax.top_k`` over the causal entries, scattered to a mask."""
+    b, s, _ = scores.shape
+    causal = np.asarray(_causal(s))
+    _, idx = lax.top_k(jnp.where(causal, scores, -jnp.inf), topk)
+    want = np.zeros((b, s, s), bool)
+    np.put_along_axis(want, np.asarray(idx), True, axis=-1)
+    return want & causal
+
+
+SCORES = {
+    "normal": lambda key: jax.random.normal(key, (2, S, S)),
+    # thirds: a dozen ties at every threshold
+    "ties": lambda key: jnp.round(jax.random.normal(key, (2, S, S)) * 3) / 3,
+    "all equal": lambda key: jnp.zeros((2, S, S)),
+    "signed zeros and extremes": lambda key: jnp.where(
+        jax.random.bernoulli(key, 0.5, (2, S, S)), -0.0, 0.0).at[
+            :, :, ::7].set(3e38).at[:, :, 3::11].set(-3e38),
+    "tiny and denormal": lambda key: jax.random.normal(
+        key, (2, S, S)) * 1e-40,
+}
+
+
+@pytest.mark.parametrize("topk", [1, 32, 100, 255])
+@pytest.mark.parametrize("kind", sorted(SCORES))
+def test_selection_is_lax_top_k_ties_to_the_lower_position(kind, topk):
+    scores = SCORES[kind](jax.random.key(2))
+    got = np.asarray(dsa.selection_mask(scores, topk))
+    assert got.dtype == np.int8
+    np.testing.assert_array_equal(got != 0, _top_k_mask(scores, topk))
+    # exactly min(t + 1, topk) keys a row: the count the roofline credits
+    np.testing.assert_array_equal(
+        got.sum(-1)[0], np.minimum(np.arange(S) + 1, topk))
+
+
+def test_a_topk_no_shorter_than_the_sequence_selects_every_causal_key():
+    scores = jax.random.normal(jax.random.key(3), (1, 64, 64))
+    for topk in (64, 2048):
+        np.testing.assert_array_equal(
+            np.asarray(dsa.selection_mask(scores, topk))[0] != 0,
+            np.asarray(_causal(64)))
+
+
+def test_threshold_is_the_kth_largest_of_the_causal_row():
+    scores = jax.random.normal(jax.random.key(4), (1, S, S))
+    tau, _ = dsa.select_threshold(scores, 16)
+    row = 200
+    want = np.sort(np.asarray(scores)[0, row, :row + 1])[-16]
+    assert np.asarray(tau)[0, row] == np.asarray(
+        dsa._ordered_bits(jnp.float32(want)))
+    assert np.asarray(tau)[0, 10] == 0      # 11 causal entries: all
+
+
+def test_ordered_bits_order_as_the_floats_do():
+    x = jnp.asarray([-3e38, -1.0, -1e-40, -0.0, 0.0, 1e-40, 1.0, 3e38],
+                    jnp.float32)
+    bits = np.asarray(dsa._ordered_bits(x)).astype(np.uint64)
+    assert (np.diff(bits.astype(np.int64)) > 0).all()
+
+
+# -- what the indexer learns from ----------------------------------------------
+
+def _attention(seed=5, h=3, d=24):
+    kq, kk, kv, ks = jax.random.split(jax.random.key(seed), 4)
+    q = jax.random.normal(kq, (2, S, h, d))
+    k = jax.random.normal(kk, (2, S, h, d))
+    v = jax.random.normal(kv, (2, S, h, 16))
+    mask = dsa.selection_mask(jax.random.normal(ks, (2, S, S)), 40)
+    _, lse = mha_reference_with_lse(q, k, v, select=mask)
+    return q, k, lse, mask, d ** -0.5
+
+
+@pytest.mark.parametrize("interpret", [False, True])
+def test_head_summed_probs(interpret, small_tiles):
+    q, k, lse, mask, scale = _attention()
+    got = dsa.head_summed_probs(q, k, lse, mask, scale, interpret=interpret)
+    logits = jnp.einsum("bthd,bshd->bhts", q, k) * scale
+    want = jnp.sum(jnp.where(
+        (mask != 0)[:, None], jnp.exp(logits - lse[..., None]), 0.0), axis=1)
+    np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-5)
+    # a head's probabilities sum to one over the selection
+    np.testing.assert_allclose(got.sum(-1), 3.0, rtol=1e-5)
+
+
+def test_head_summed_probs_are_constants(small_tiles):
+    q, k, lse, mask, scale = _attention()
+    g = jax.grad(lambda q: jnp.sum(
+        dsa.head_summed_probs(q, k, lse, mask, scale) ** 2))(q)
+    assert not np.asarray(g).any()
+
+
+def test_indexer_loss_is_the_kl_and_moves_the_scores_alone():
+    q, k, lse, mask, scale = _attention()
+    probs = dsa.head_summed_probs(q, k, lse, mask, scale)
+    scores = jax.random.normal(jax.random.key(6), (2, S, S))
+    seen = np.asarray(mask) != 0
+    target = np.asarray(probs) / np.asarray(probs).sum(-1, keepdims=True)
+    logits = np.where(seen, np.asarray(scores), -np.inf)
+    logq = logits - np.log(np.exp(logits).sum(-1, keepdims=True))
+    with np.errstate(divide="ignore", invalid="ignore"):
+        kl = np.where(seen & (target > 0),
+                      target * (np.log(target) - logq), 0.0)
+    np.testing.assert_allclose(
+        dsa.indexer_loss(scores, probs, mask), kl.sum(), rtol=1e-4)
+    d_scores, d_probs = jax.grad(
+        lambda s, p: dsa.indexer_loss(s, p, mask), argnums=(0, 1))(
+            scores, probs)
+    assert not np.asarray(d_probs).any()
+    # softmax over the selection less the target, nothing outside it
+    want = np.where(seen, np.exp(logq) - target, 0.0)
+    np.testing.assert_allclose(d_scores, want, atol=1e-6)
+
+
+def test_the_kl_of_a_distribution_with_itself_is_zero():
+    q, k, lse, mask, scale = _attention(h=1)
+    probs = dsa.head_summed_probs(q, k, lse, mask, scale)
+    scores = jnp.where(mask != 0, jnp.log(jnp.maximum(probs, 1e-30)), 0.0)
+    assert abs(float(dsa.indexer_loss(scores, probs, mask))) < 1e-2
