@@ -33,25 +33,27 @@ it is a real compute path, and there is one of it:
   the load, shapes are static, cost is linear in ``t``. No tensor of
   size ``t x e x capacity`` exists.
 - **grouped matmul** (``moe_experts``): gate, up and down are each one
-  ``lax.ragged_dot`` over the ragged groups. The TPU compiler lowers it
-  to its own grouped-matmul kernel (forward, d-lhs and d-rhs alike:
-  ``ragged-dot-none`` in a device trace), bf16 operands under f32
+  grouped product over the ragged groups (``ops/grouped_matmul.py``'s
+  kernels on the TPU, up's d-lhs adding onto gate's; ``lax.ragged_dot``
+  off it and where a shape does not tile), bf16 operands under f32
   accumulation; docs/design/kernels.md has what was measured.
 - **combine** (``moe_combine``): rows go back to token order weighted by
   the router's probabilities and the ``k`` of a token are summed.
   Dispatch and combine are ``custom_vjp`` pairs whose backward is again a
   gather (by the inverse permutation), never a scatter-add.
 - **the live count** (``ops/moe_rows.py``): where a tail can exist (a
-  held share on one chip), combine, its backward and dispatch's backward
-  are Pallas kernels on the TPU that move the rows below
-  ``sum(group_sizes)`` and no others; the count stays on the device and
-  the arrays keep their ``(t * k, d)``. The zeros the grouped kernels
-  still store past the count are then read by nothing but the
-  elementwise ``act(gate) * up`` between them (and by
-  ``lax.ragged_dot`` where a shape falls back to it, which is why the
-  row kernels run only beside the grouped kernels). Dispatch's forward
-  stays XLA's gather, and so does everything where every expert is held
-  and under a mesh.
+  held share on one chip), nothing between dispatch's gather and the
+  layer's result touches a row at or past ``sum(group_sizes)``: combine,
+  its backward and dispatch's backward are Pallas kernels on the TPU
+  that move the rows below it and no others, ``act(gate) x up`` and its
+  backward are passes over the blocks below it, and the grouped
+  products walk no tile of the tail, forward or backward
+  (``tail_unread``; the gauge ``moe.tail_skipped``). The count stays on
+  the device and the arrays keep their ``(t * k, .)``; past the count
+  they are unwritten memory (``lax.ragged_dot``, where a shape falls
+  back to it, would read it, which is why all of this runs only beside
+  the grouped kernels). Dispatch's forward stays XLA's gather, and so
+  does everything where every expert is held and under a mesh.
 - **under a mesh** the same path runs inside ``shard_map``: tokens stay
   where the batch axes put them, the rows are gathered over ``ep``, each
   rank sorts by its local experts (pairs for other ranks' experts fall
@@ -93,7 +95,7 @@ from dlrover_tpu.ops import (
     rope_frequencies,
 )
 from dlrover_tpu.ops import moe_rows
-from dlrover_tpu.ops.grouped_matmul import choose_tiles, grouped_matmul
+from dlrover_tpu.ops.grouped_matmul import choose_tiles, grouped_matmuls
 from dlrover_tpu.parallel.mesh import BATCH_AXES, EP, FSDP, SP, TP
 
 Params = Dict[str, Any]
@@ -484,37 +486,83 @@ def _route(cfg: MoeConfig, router, yt, token_axes=(), bias=None):
 _ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
+def gated_rows(gate, up, act: str, live=None, *, interpret: bool = False):
+    """``act(gate) x up`` of two ``(t * k, f)`` arrays in sorted order.
+    ``live``: as `dispatch_rows`. Given it, on the TPU (or under
+    ``interpret``) the pass and its backward read and write the rows
+    below it and no others, to the end of the block that holds row
+    ``live`` (zeros from ``live`` on), in float32 from the load to the
+    one store (``ops/moe_rows.py``)."""
+    blocks = _row_blocks(gate, (gate.shape[0], 1), live, interpret)
+    if blocks is None:
+        return _ACTS[act](gate) * up
+    return _gated(gate, up, live, act, blocks[0], interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _gated(gate, up, live, act, block, interpret):
+    return moe_rows.gated_rows(gate, up, live, act=act, block=block,
+                               interpret=interpret)
+
+
+def _gated_fwd(gate, up, live, act, block, interpret):
+    hidden = _gated.fun(gate, up, live, act, block, interpret)
+    return hidden, (gate, up, live)
+
+
+def _gated_bwd(act, block, interpret, res, g):
+    gate, up, live = res
+    with trace.scope("moe_experts"):
+        d_gate, d_up = moe_rows.gated_rows_cotangents(
+            gate, up, g, live, act=act, block=block, interpret=interpret)
+    return (d_gate, d_up) + _int_zeros(live)
+
+
+_gated.defvjp(_gated_fwd, _gated_bwd)
+
+
 def _experts(lp: Params, yt, top_p, top_e, n_local: int, first: Any = 0,
-             act: str = "silu", tail: bool = False):
+             act: str = "silu", tail: bool = False, *,
+             interpret: bool = False):
     """The ``n_local`` experts ``first ..`` applied to the pairs of
     ``yt (t, d)`` that chose them, weighted and summed: ``(t, d)``.
     ``tail``: the router scores experts that are not among them, so
-    pairs can sort past ``sum(group_sizes)``; combine, its backward and
-    dispatch's backward then stop at that count (``ops/moe_rows.py``),
-    and the rows' cotangent past it is left unwritten. The grouped
-    kernels visit no tile there in their backward; ``lax.ragged_dot``,
-    where a shape falls back to it, would read it, so the row kernels
-    run only where the grouped kernels do. Without a tail every row is
-    live and XLA's gathers, which move a row without the seven beside
-    it, are the faster (docs/design/kernels.md 1c)."""
+    pairs can sort past ``live = sum(group_sizes)``. Nothing between
+    `dispatch_rows`' gather and the result then touches a row at or past
+    that count: the grouped products walk no tile of the tail, forward
+    or backward (``tail_unread``), ``act(gate) x up`` and its backward
+    stop at the block that holds row ``live``, and combine, its backward
+    and dispatch's backward read below it (``ops/moe_rows.py``); what
+    lies past it in every ``(t * k, .)`` array but the gathered rows is
+    unwritten memory. ``lax.ragged_dot``, where a shape falls back to
+    it, would read it, so all of this runs only where the three grouped
+    products tile. Without a tail every row is live and XLA's ops, which
+    move a row without the seven beside it, are the faster
+    (docs/design/kernels.md 1c). ``interpret`` runs every kernel in
+    interpreter mode (the CPU tests)."""
     t, k = top_e.shape
     d, f = lp["w_gate"].shape[1:]
     blocks = (tail and choose_tiles(t * k, d, f, yt.dtype)
-              and moe_rows.row_blocks(t, k, d, yt.dtype))
+              and choose_tiles(t * k, f, d, yt.dtype)
+              and moe_rows.row_blocks(t, k, d, yt.dtype,
+                                      interpret=interpret))
     trace.gauge("moe.rows_kernel", int(bool(blocks)))
     trace.gauge("moe.row_block", blocks[0] if blocks else 0)
     with trace.scope("moe_dispatch"):
         order, inverse, group_sizes = sort_pairs(top_e, n_local, first)
         live = jnp.sum(group_sizes) if blocks else None
-        xs = dispatch_rows(yt, order, inverse, k, live)
+        xs = dispatch_rows(yt, order, inverse, k, live, interpret=interpret)
     with trace.scope("moe_experts"):
-        gate = grouped_matmul(xs, lp["w_gate"], group_sizes)
-        up = grouped_matmul(xs, lp["w_up"], group_sizes)
-        rows = grouped_matmul(
-            _ACTS[act](gate) * up, lp["w_down"], group_sizes
-        )
+        products = functools.partial(
+            grouped_matmuls, group_sizes=group_sizes,
+            tail_unread=bool(blocks), interpret=interpret)
+        gate, up = products(xs, (lp["w_gate"], lp["w_up"]))
+        rows, = products(
+            gated_rows(gate, up, act, live, interpret=interpret),
+            (lp["w_down"],))
     with trace.scope("moe_combine"):
-        return combine_rows(rows, top_p, order, inverse, live)
+        return combine_rows(rows, top_p, order, inverse, live,
+                            interpret=interpret)
 
 
 def _moe_tokens_sharded(cfg: MoeConfig, lp: Params, y, route_on=None):

@@ -1,6 +1,7 @@
 """The expert layer's row movements, bound by the live count: Pallas TPU
 kernels for ``models/moe.py``'s ``combine_rows`` (forward and backward)
-and ``dispatch_rows``' backward.
+and ``dispatch_rows``' backward, and the pass between the grouped
+products, ``act(gate) x up`` (`gated_rows`), forward and backward.
 
 ``models/moe.py`` sorts the ``n = t * k`` (token, choice) pairs by expert
 and keeps every array at its worst-case ``(n, d)``; a chip that holds
@@ -29,6 +30,12 @@ operand, so shapes stay static and nothing is dropped.
   unwritten row (it masks them by position, so it would not need it).
   One fetch of the cotangent's row gives ``d_rows = g_row x weight`` and
   ``d_weights = <rows, g_row>`` in float32.
+- **the pass between the products** (`gated_rows`,
+  `gated_rows_cotangents`: PR 42): blocks of sorted rows of the ``(n,
+  f)`` arrays up to the one that holds row ``live``, on a grid as long
+  (a traced grid dimension), float32 from the load to the one store,
+  zeros from ``live`` to the block's end; ``silu`` and ``relu``. The
+  backward writes its two results over two of its operands.
 - **a row fetch.** Mosaic slices a tiled HBM operand by whole tiles of 8
   rows, so a row comes with the 7 beside it: one contiguous DMA of ``(8,
   d)`` from the operand left in ``pl.ANY``, ``_WINDOW`` of them in
@@ -226,6 +233,100 @@ def sorted_cotangents(g, tok, rows, weights, live, *, block: int,
     )(live, tok.reshape(blocks, 1, block),
       weights.reshape(blocks, 1, block), g, rows)
     return d_rows, d_weights.reshape(n)
+
+
+# ---------------------------------------------------------------------------
+# between the products: act(gate) x up and its backward over the live blocks
+# ---------------------------------------------------------------------------
+
+def _act(name: str, g):
+    """``(act(g), act'(g))`` of a float32 tile: ``silu`` or ``relu``
+    (the sigmoid through tanh, as ``ops/kda.py``'s passes form it)."""
+    if name == "relu":
+        return jnp.maximum(g, 0.0), (g > 0.0).astype(_F32)
+    sig = 0.5 * jnp.tanh(0.5 * g) + 0.5
+    return g * sig, sig * (1.0 + g * (1.0 - sig))
+
+
+def _below(live_ref, shape):
+    """Which rows of this grid step's block are below the live count."""
+    rows = pl.program_id(0) * shape[0] + lax.broadcasted_iota(
+        jnp.int32, shape, 0)
+    return rows < live_ref[0]
+
+
+def _gated_kernel(live_ref, gate_ref, up_ref, out_ref, *, act: str):
+    hidden, _ = _act(act, gate_ref[...].astype(_F32))
+    out_ref[...] = jnp.where(
+        _below(live_ref, out_ref.shape), hidden * up_ref[...].astype(_F32),
+        0.0).astype(out_ref.dtype)
+
+
+def _gated_bwd_kernel(live_ref, gate_ref, up_ref, g_ref, d_gate_ref,
+                      d_up_ref, *, act: str):
+    below = _below(live_ref, g_ref.shape)
+    hidden, slope = _act(act, gate_ref[...].astype(_F32))
+    g = g_ref[...].astype(_F32)
+    d_gate_ref[...] = jnp.where(
+        below, g * up_ref[...].astype(_F32) * slope, 0.0).astype(
+            d_gate_ref.dtype)
+    d_up_ref[...] = jnp.where(below, g * hidden, 0.0).astype(d_up_ref.dtype)
+
+
+def _live_blocks_call(kernel, name, live, operands, n_out, *, block: int,
+                      interpret: bool, in_place: bool = False):
+    """``kernel`` over the blocks of ``block`` rows of the ``(n, f)``
+    ``operands`` up to the one that holds row ``live``: the grid's length
+    is counted on the device, so a later block is neither read nor
+    written. ``in_place``: the results are written over the last
+    ``n_out`` operands (``input_output_aliases``; a block is read whole
+    before it is stored)."""
+    n, f = operands[0].shape
+    live = live.reshape(1).astype(jnp.int32)
+    tile = pl.BlockSpec((block, f), lambda b, live: (b, 0))
+    out = jax.ShapeDtypeStruct((n, f), operands[0].dtype)
+    first = 1 + len(operands) - n_out    # the count is operand 0
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(jnp.minimum(live[0] // block + 1, n // block),),
+            in_specs=[tile] * len(operands),
+            out_specs=[tile] * n_out),
+        out_shape=[out] * n_out,
+        input_output_aliases={first + i: i for i in range(n_out)}
+        if in_place else {},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name=name,
+    )(live, *operands)
+
+
+@functools.partial(jax.jit, static_argnames=("act", "block", "interpret"))
+def gated_rows(gate, up, live, *, act: str, block: int,
+               interpret: bool = False):
+    """``act(gate) x up`` of two ``(n, f)`` arrays in sorted order, for
+    the rows below ``live ()`` int32: float32 from the load to the one
+    store. Zero from ``live`` to the end of the block that holds row
+    ``live``; later blocks are neither read nor written."""
+    return _live_blocks_call(
+        functools.partial(_gated_kernel, act=act), "moe_rows_gated", live,
+        (gate, up), 1, block=block, interpret=interpret)[0]
+
+
+@functools.partial(jax.jit, static_argnames=("act", "block", "interpret"))
+def gated_rows_cotangents(gate, up, g, live, *, act: str, block: int,
+                          interpret: bool = False):
+    """`gated_rows`' backward: ``(d_gate, d_up) = (g x up x act'(gate),
+    g x act(gate))`` below ``live``, written as `gated_rows` writes, and
+    over ``up`` and ``g``, as XLA's fusion wrote in place: no ``(n, f)``
+    buffer beside the operands'."""
+    return _live_blocks_call(
+        functools.partial(_gated_bwd_kernel, act=act), "moe_rows_gated_bwd",
+        live, (gate, up, g), 2, block=block, interpret=interpret,
+        in_place=True)
 
 
 # ---------------------------------------------------------------------------

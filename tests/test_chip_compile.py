@@ -317,7 +317,8 @@ def test_olmoe_expert_layer_compiles(one_chip, kernels_are_the_path):
 def test_olmoe_expert_layer_fwd_bwd_compiles(one_chip, kernels_are_the_path):
     loss, lp, y = _olmoe_expert_layer(one_chip)
     hlo = _compile(jax.grad(loss, argnums=(0, 1)), lp, y)
-    # forward, d-lhs and d-rhs of each of the three products
+    # forward, d-lhs and d-rhs of each of the three products (up's
+    # d-lhs adds onto gate's in place: no add of the two outside)
     assert _kernel_calls(hlo, "grouped_matmul_dlhs") == 3
     assert _kernel_calls(hlo, "grouped_matmul_drhs") == 3
     assert _kernel_calls(hlo, "grouped_matmul") == 9
@@ -374,12 +375,15 @@ def test_xing4_expert_block_fwd_bwd_compiles(one_chip, kernels_are_the_path):
     hlo = compiled.as_text()
     # the remat forward is the only forward here (nothing else wants the
     # block's output): 1 + 2 of attention, and forward, d-lhs and d-rhs
-    # of each of the three grouped products
+    # of each of the three grouped products; act(gate) x up and its
+    # backward as passes
     assert _kernel_calls(hlo, "attention_fwd") == 1
     assert _kernel_calls(hlo, "attention_bwd") == 2
     assert _kernel_calls(hlo, "grouped_matmul_dlhs") == 3
     assert _kernel_calls(hlo, "grouped_matmul_drhs") == 3
     assert _kernel_calls(hlo, "grouped_matmul") == 9
+    assert _kernel_calls(hlo, "moe_rows_gated") == 2
+    assert trace.gauges()["moe.tail_skipped"] == 1
     assert "[8192,64,8" not in hlo  # no (tokens, experts, ...) dispatch tensor
     # a block's own temporaries fit beside the cell's state and carries
     assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2**30
@@ -549,6 +553,7 @@ def test_kimi_linear_expert_block_fwd_bwd_compiles(
     assert _kernel_calls(hlo, "attention_fwd") == flash
     assert _kernel_calls(hlo, "attention_bwd") == 2 * flash
     assert _kernel_calls(hlo, "grouped_matmul") == 9
+    assert _kernel_calls(hlo, "moe_rows_gated") == 2
     delta = [n for n in _op_names(hlo) if "/kda_" in n]
     if flash:
         assert not delta
@@ -586,12 +591,15 @@ def test_kimi_linear_expert_block_fwd_bwd_compiles(
 # pairs can sort into a tail the row movements run ops/moe_rows.py's
 # kernels, bound by the live count: combine's forward and dispatch's
 # backward (`moe_rows_summed`) and combine's backward
-# (`moe_rows_cotangents`); OLMoE, which holds every expert, keeps XLA's
-# gathers.
+# (`moe_rows_cotangents`), and since PR 42 `act(gate) x up` and its
+# backward (`moe_rows_gated`, `moe_rows_gated_bwd`) while the grouped
+# products walk no tile of the tail; OLMoE, which holds every expert,
+# keeps XLA's gathers and fusion and the walk it had.
 EXPERT_CELLS = {
     "smallthinker": ((16384, 6, 64, 16, 2560, 768, "relu"), 1971133440),
     "xing4": ((8192, 4, 64, 8, 3584, 1024, "silu"), 910812160),
     "kimi": ((8192, 8, 256, 32, 2304, 1024, "silu"), 1054416896),
+    "dots3": ((8192, 8, 256, 8, 5120, 1536, "silu"), 2630225408),
     "olmoe": ((8192, 8, 64, None, 2048, 1024, "silu"), 675513856),
 }
 
@@ -637,20 +645,29 @@ def test_expert_rows_fwd_bwd_compile_in_the_parents_memory(
     # combine's forward (the backward reads no output of it, so the
     # remat forward has none) and dispatch's backward, and combine's
     # backward; the three products forward, again under remat, d-lhs
-    # and d-rhs
+    # and d-rhs; the pass between the products forward, again under
+    # remat, and backward
     assert _kernel_calls(hlo, "moe_rows_summed") == (2 if tail else 0)
     assert _kernel_calls(hlo, "moe_rows_cotangents") == (1 if tail else 0)
+    assert _kernel_calls(hlo, "moe_rows_gated_bwd") == (1 if tail else 0)
+    assert _kernel_calls(hlo, "moe_rows_gated") == (3 if tail else 0)
+    assert _kernel_calls(hlo, "grouped_matmul_dlhs") == 3
     assert _kernel_calls(hlo, "grouped_matmul") == 12
     assert trace.gauges()["moe.rows_kernel"] == int(tail)
+    assert trace.gauges()["moe.tail_skipped"] == int(tail)
     assert trace.gauges()["moe.row_block"] == (256 if tail else 0)
     # every kernel under the scope the device metrics select by
     for name in _op_names(hlo):
-        if "moe_rows_" in name:
+        if "moe_rows_gated" in name or "grouped_matmul" in name:
+            assert _in_scope(name, "moe_experts"), name
+        elif "moe_rows_" in name:
             assert _in_scope(name, "moe_combine") or _in_scope(
                 name, "moe_dispatch"), name
     # no (t x k, d) array beside the parent's: the kernels' lists of
     # int32 and float32 scalars (the live pairs, the sorted weights and
-    # their cotangent) are 0.4 MB each at 98304 pairs
+    # their cotangent) are 0.4 MB each at 98304 pairs. The pass's
+    # backward writes over two of its operands, as XLA's fusion did, and
+    # up's d-lhs over gate's
     parent = EXPERT_CELLS[cell][1]
     assert compiled.memory_analysis().temp_size_in_bytes < parent + 2 * 2**20
 

@@ -2,7 +2,8 @@
 interpreter mode against ``lax.ragged_dot`` and its autodiff: forward,
 d-lhs and d-rhs, over group sizes that straddle row tiles, leave groups
 empty, pile every row on one group, or leave a tail of rows in none
-(which the forward and d-lhs walks zero without reading or multiplying)."""
+(which the forward and d-lhs walks zero without reading or multiplying,
+or, on the caller's word that nothing reads them, do not visit at all)."""
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +23,7 @@ GROUPS = {
     "all_tail": [0, 0, 0, 0, 0, 0],
     "tail_on_a_tile_edge": [100, 28, 0, 64, 60, 4],   # 256 live rows
     "tiny_groups": [1, 2, 3, 500, 5, 1],
+    "tail_inside_a_tile": [120, 0, 70, 30, 80, 30],   # 330 = 2 tiles + 74
 }
 
 
@@ -99,7 +101,7 @@ def test_visits_cover_every_row_once(name):
     assert set(groups[: int(num[0])]) == set(range(G))
 
 
-TAILS = ["tail", "all_tail", "tail_on_a_tile_edge"]
+TAILS = ["tail", "all_tail", "tail_on_a_tile_edge", "tail_inside_a_tile"]
 NO_TAIL = {   # the (group, tile) visits at 128 rows a tile, as PR 27 walked
     "even": [(0, 0), (1, 0), (1, 1), (2, 1), (2, 2), (3, 2), (4, 2),
              (4, 3), (5, 3)],
@@ -181,3 +183,112 @@ def test_off_tpu_the_path_is_ragged_dot():
     out = gm.grouped_matmul(lhs, rhs, sizes)     # CPU, no interpret
     np.testing.assert_allclose(out, _reference(lhs, rhs, sizes),
                                rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the walk bound by the live count (``tail_unread``): no visit to a tile
+# of the tail, whose rows of the results stay unwritten (NaN in
+# interpreter mode)
+# ---------------------------------------------------------------------------
+
+def _vjps(fn, sizes, lhs, rhs, ct):
+    out, vjp = jax.vjp(lambda a, b: fn(a, b, sizes), lhs, rhs)
+    return (out,) + vjp(ct)
+
+
+def _bounded(lhs, rhs, sizes):
+    return gm.grouped_matmul(lhs, rhs, sizes, tail_unread=True,
+                             interpret=True)
+
+
+@pytest.mark.parametrize("poisoned", [False, True])
+@pytest.mark.parametrize("name", TAILS)
+def test_bounded_walk_matches_ragged_dot_below_the_count(name, poisoned):
+    """Forward and d-lhs below the count and the whole of d-rhs; with
+    the operands' and the cotangent's rows past the count NaN, which is
+    what an unwritten row of a producer may hold, the same."""
+    lhs, rhs = _operands(seed=5)
+    sizes = jnp.asarray(GROUPS[name], jnp.int32)
+    total = int(sizes.sum())
+    ct = jax.random.normal(jax.random.key(6), (M, N))
+    poison = (lambda a: a.at[total:].set(jnp.nan)) if poisoned else (
+        lambda a: a)
+    with jax.default_matmul_precision("highest"):
+        got = _vjps(_bounded, sizes, poison(lhs), rhs, poison(ct))
+        want = _vjps(_reference, sizes, lhs, rhs, ct)
+    for g, w in zip(got[:2], (want[0], want[1])):
+        assert np.isfinite(np.asarray(g[:total])).all()
+        np.testing.assert_allclose(g[:total], w[:total],
+                                   rtol=1e-5, atol=1e-5)
+    assert np.isfinite(np.asarray(got[2])).all()
+    np.testing.assert_allclose(got[2], want[2], rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("name", sorted(GROUPS))
+def test_bounded_walk_names_no_tile_past_the_live_rows(name):
+    sizes = jnp.asarray(GROUPS[name], jnp.int32)
+    block = 128
+    total = int(sizes.sum())
+    offsets, groups, tiles, num = (np.asarray(a) for a in gm._visits(
+        sizes, M, block, tail=False, empty=False))
+    num = int(num[0])
+    assert (num == 0) == (total == 0)
+    assert (groups[:num] < G).all()
+    assert (tiles[:num] <= max(total - 1, 0) // block).all()
+    # every entry, real visit or not, names a block that exists
+    assert ((0 <= groups) & (groups < G)).all()
+    assert ((0 <= tiles) & (tiles < M // block)).all()
+    # the live rows are covered once, as the zeroing walk covers them
+    seen = np.zeros(M, int)
+    for grp, tile in zip(groups[:num], tiles[:num]):
+        seen[max(offsets[grp], tile * block):
+             min(offsets[grp + 1], (tile + 1) * block)] += 1
+    assert (seen[:total] == 1).all() and not seen[total:].any()
+    assert int(gm._grid_steps((offsets, groups, tiles,
+                               jnp.asarray([num])))) == max(num, 1)
+
+
+@pytest.mark.parametrize("tail_unread", [False, True])
+@pytest.mark.parametrize("name", ["even", "tail", "all_tail",
+                                  "tail_inside_a_tile"])
+def test_products_that_share_their_rows_sum_d_lhs_in_place(
+        name, tail_unread):
+    lhs, rhs = _operands(seed=7)
+    _, other = _operands(seed=8)
+    sizes = jnp.asarray(GROUPS[name], jnp.int32)
+    total = int(sizes.sum())
+    cts = [jax.random.normal(jax.random.key(9 + i), (M, N)) for i in (0, 1)]
+
+    def loss(fn):
+        def inner(lhs, a, b):
+            outs = fn(lhs, (a, b), sizes)
+            below = (jnp.arange(M) < total)[:, None]
+            return sum(jnp.sum(jnp.where(below, o * ct, 0.0))
+                       for o, ct in zip(outs, cts))
+        return inner
+
+    pair = lambda l, ws, s: gm.grouped_matmuls(
+        l, ws, s, tail_unread=tail_unread, interpret=True)
+    ref = lambda l, ws, s: tuple(_reference(l, w, s) for w in ws)
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(loss(pair), argnums=(0, 1, 2))(lhs, rhs, other)
+        want = jax.grad(loss(ref), argnums=(0, 1, 2))(lhs, rhs, other)
+    edge = M if not tail_unread else total
+    np.testing.assert_allclose(got[0][:edge], want[0][:edge],
+                               rtol=1e-5, atol=1e-5)
+    for g, w in zip(got[1:], want[1:]):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4)
+
+
+def test_tiles_count_the_tile_a_d_lhs_adds_onto():
+    # up's d-lhs fetches gate's result, one more (block_m, block_k) tile
+    # a visit: counted, it leaves the cells the tiles they had (PR 40's)
+    for (m, k, n), want in (((98304, 2560, 768), (256, 768, 640)),
+                            ((65536, 5120, 1536), (256, 768, 1024)),
+                            ((65536, 2304, 1024), (256, 1024, 768)),
+                            ((32768, 3584, 1024), (256, 1024, 896)),
+                            ((65536, 2048, 1024), (256, 1024, 1024))):
+        assert gm.choose_tiles(m, k, n, jnp.bfloat16) == want
+        bm, _, bk = want
+        walk = 2 * (bm * n + bk * n + 2 * bm * bk) * 2 + bm * bk * 4
+        assert walk + n * bk * 4 <= gm._VMEM_BUDGET

@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 
 from dlrover_tpu.models import moe
+from dlrover_tpu.observability import trace
 from dlrover_tpu.ops import grouped_matmul as gm
 from dlrover_tpu.ops import moe_rows
 
@@ -233,8 +234,10 @@ def _expert_layer(params, yt, top_p, top_e, held, kernels):
     k = top_e.shape[1]
     order, inverse, sizes = moe.sort_pairs(top_e, held)
     live = jnp.sum(sizes) if kernels else None
+    # with the row kernels the products walk no tile of the tail: what
+    # they leave unwritten there is NaN in interpreter mode
     products = functools.partial(gm.grouped_matmul, group_sizes=sizes,
-                                 interpret=True)
+                                 tail_unread=kernels, interpret=True)
     xs = moe.dispatch_rows(yt, order, inverse, k, live, interpret=kernels)
     if kernels:
         xs = _poison_cotangent_tail(xs, live)
@@ -277,6 +280,92 @@ def test_nan_in_unvisited_rows_reaches_nothing(live):
     # kernel adds in another order
     assert np.isfinite(_f32(grads[2])).all()
     np.testing.assert_allclose(grads[2], want[2], rtol=1e-5, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# act(gate) x up as a pass bound by the count, alone and in the layer
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("act", ["silu", "relu"])
+@pytest.mark.parametrize("count", sorted(LIVE))
+def test_gated_pass_matches_jnp_below_the_count(count, act):
+    """Forward and both cotangents against autodiff of the ``jnp``
+    expression, float32; NaN in every operand's rows from the count on,
+    which the pass turns into zeros up to its block's end and leaves
+    unwritten after it."""
+    n, f = 6 * SORTED_BLOCK, 2 * D
+    live = LIVE[count](n)
+    ks = jax.random.split(jax.random.key(11), 3)
+    gate, up, ct = (jax.random.normal(k, (n, f)) for k in ks)
+    poison = lambda a: jnp.where(_below(n, live), a, jnp.nan)
+    got, vjp = jax.vjp(
+        lambda g, u: moe.gated_rows(g, u, act, jnp.int32(live),
+                                    interpret=True), poison(gate), poison(up))
+    want, want_vjp = jax.vjp(
+        lambda g, u: moe.gated_rows(g, u, act), gate, up)
+    edge = min(n, (live // SORTED_BLOCK + 1) * SORTED_BLOCK)
+    for g, w in zip((got,) + vjp(poison(ct)), (want,) + want_vjp(ct)):
+        np.testing.assert_allclose(g[:live], w[:live], rtol=2e-6, atol=2e-6)
+        assert not _f32(g)[live:edge].any()
+        assert np.isnan(_f32(g)[edge:]).all()      # never written
+
+
+def _int_layer(t, k, held, f, seed=5):
+    ks = jax.random.split(jax.random.key(seed), 5)
+    bf = jnp.bfloat16
+    lp = {"w_gate": _integers(ks[0], (held, D, f), bf, top=1),
+          "w_up": _integers(ks[1], (held, D, f), bf, top=1),
+          "w_down": _integers(ks[2], (held, f, D), bf, top=1)}
+    yt = _integers(ks[3], (t, D), bf, top=2)
+    top_p = _integers(ks[4], (t, k), jnp.float32, top=4) / 4
+    return lp, yt, top_p
+
+
+@pytest.mark.parametrize("act", ["silu", "relu"])
+@pytest.mark.parametrize("count", ["none", "over_an_edge", "all"])
+def test_experts_that_skip_the_tail_match_the_zeroing_walk(
+        monkeypatch, count, act):
+    """`moe._experts` with a tail (the products walk no tile of it, the
+    pass between them and the row movements stop at the count) against
+    the same layer without that word (`blocks` None: the walk that
+    zeroes, XLA's gathers, the ``jnp`` expression): the output and every
+    gradient. Integer-valued operands: with ``relu`` every product is
+    exact and a sum rounds once, in either form."""
+    monkeypatch.setattr(gm, "_MAX_BLOCK_M", SORTED_BLOCK)
+    t, k, e, held, f = 64, 4, 32, 4, 128
+    live = LIVE[count](t * k)
+    top_e = _routing(t, k, e, held, live, seed=6)
+    lp, yt, top_p = _int_layer(t, k, held, f)
+
+    def loss(tail):
+        def fn(lp, yt, top_p):
+            out = moe._experts(lp, yt, top_p, top_e, held, 0, act, tail,
+                               interpret=True)
+            return jnp.sum(out.astype(jnp.float32) * (1 + jnp.arange(D) % 3)
+                           ), out
+        return jax.jit(jax.value_and_grad(fn, argnums=(0, 1, 2),
+                                          has_aux=True))
+
+    (_, out), grads = loss(True)(lp, yt, top_p)
+    gauges = dict(trace.gauges())
+    (_, want_out), want = loss(False)(lp, yt, top_p)
+    assert gauges["moe.tail_skipped"] == gauges["moe.rows_kernel"] == 1
+    assert trace.gauges()["moe.tail_skipped"] == 0
+    same = np.testing.assert_array_equal if act == "relu" else (
+        lambda a, b: np.testing.assert_allclose(
+            a, b, rtol=2e-2, atol=2e-2 * (1 + np.abs(b).max())))
+    same(_f32(out), _f32(want_out))
+    for got_leaf, want_leaf in zip(jax.tree.leaves(grads[:2]),
+                                   jax.tree.leaves(want[:2])):
+        assert np.isfinite(_f32(got_leaf)).all()
+        same(_f32(got_leaf), _f32(want_leaf))
+    # d top_p: a sum of 128 products a pair, added in another order
+    np.testing.assert_allclose(
+        grads[2], want[2], rtol=1e-5 if act == "relu" else 2e-2,
+        atol=1e-5 if act == "relu" else 2e-2 * float(
+            1 + jnp.abs(want[2]).max()))
+    if count == "none":
+        assert not _f32(out).any()
 
 
 def test_unrounded_operands_agree_to_rounding():
