@@ -655,6 +655,9 @@ def _report_shapes(cfg: Dots3Config):
     full = cfg.latent(FULL)
     trace.gauge("dsa.topk", cfg.index_topk)
     trace.gauge("dsa.index_heads", cfg.index_n_heads)
+    # 1 once a full block's checkpoint has met the loss's gradient and
+    # kept it (`_block_fn`): in a differentiated build under remat
+    trace.gauge("dsa.loss_grad_kept", 0)
     trace.gauge("attn.heads_held", cfg.n_held_heads(FULL))
     trace.gauge("attn.heads", cfg.n_heads)
     trace.gauge("attn.swa_heads_held", cfg.n_held_heads(WINDOW))
@@ -676,12 +679,24 @@ def _block_fn(cfg: Dots3Config, mesh, kind: str, positions):
     fn = functools.partial(block, cfg, mesh, kind, positions)
     if not cfg.remat:
         return fn
-    # a block is recomputed whole in the backward pass, but for a full
-    # layer's selection: the mask is 1 byte a pair, the threshold 45
-    # passes over the scores
-    policy = (jax.checkpoint_policies.save_only_these_names("dsa_select")
-              if kind == FULL else
-              jax.checkpoint_policies.nothing_saveable)
+    # a block is recomputed whole in the backward pass, but for the two
+    # arrays a full layer names. The selection's mask, 1 byte a pair,
+    # spares the threshold's 45 passes over the scores. d L_I / d scores,
+    # 4 bytes a pair (`dsa.indexer_loss` forms it in the forward: its
+    # target is a constant), spares the indexer's score kernel,
+    # `dsa_probs` and the KL, which nothing else in the backward reads.
+    if kind != FULL:
+        return jax.checkpoint(
+            fn, policy=jax.checkpoint_policies.nothing_saveable)
+    named = jax.checkpoint_policies.save_only_these_names(
+        "dsa_select", dsa.LOSS_GRAD)
+
+    def policy(prim, *avals, **params):
+        keep = named(prim, *avals, **params)
+        if keep and params["name"] == dsa.LOSS_GRAD:
+            trace.gauge("dsa.loss_grad_kept", 1)
+        return keep
+
     return jax.checkpoint(fn, policy=policy)
 
 

@@ -35,7 +35,9 @@ probabilities summed over heads. What this file computes:
   ``lse`` and the mask; a Pallas kernel on the TPU (``dsa_probs``: heads
   innermost, the ``(block_q, block_k)`` float32 tile accumulated in its
   output block), not differentiated.
-- `indexer_loss`: the KL, summed over the rows (XLA ops over ``(s, s)``).
+- `indexer_loss`: the KL, summed over the rows (XLA ops over ``(s, s)``);
+  its forward also forms the gradient with respect to the scores, the
+  one array its backward reads (a ``custom_vjp``).
 
 Off the TPU the XLA forms, blocked over query rows, which are the
 kernels' oracles; ``interpret=True`` runs the kernels on the CPU. No
@@ -52,6 +54,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
@@ -504,16 +507,52 @@ def head_summed_probs(q, k, lse, mask, scale: float, *,
         mesh, q, k, lse, mask)
 
 
+#: the name `indexer_loss`'s forward gives ``d loss / d scores``: a
+#: checkpoint policy that keeps it spares the recomputed forward the
+#: score kernel, ``dsa_probs`` and the KL (``models/dots3.py _block_fn``)
+LOSS_GRAD = "dsa_loss_grad"
+
+
+def _kl_and_grad(scores, probs, mask):
+    """The KL and its gradient with respect to ``scores`` from one set
+    of passes: over a row's selection ``softmax(scores) x (the sum of
+    the row's positive targets) - target``, zero elsewhere."""
+    seen = mask != 0
+    target = probs / jnp.sum(probs, axis=-1, keepdims=True)
+    logq = jax.nn.log_softmax(
+        jnp.where(seen, scores, -jnp.inf), axis=-1)
+    live = seen & (target > 0.0)
+    terms = jnp.where(
+        live,
+        target * (jnp.log(jnp.where(target > 0.0, target, 1.0)) - logq), 0.0)
+    held = jnp.where(live, target, 0.0)
+    grad = jnp.where(
+        seen,
+        jnp.exp(logq) * jnp.sum(held, axis=-1, keepdims=True) - held, 0.0)
+    return jnp.sum(terms), grad
+
+
+@jax.custom_vjp
 def indexer_loss(scores, probs, mask):
     """``sum_t KL(p^_t || softmax_{s in S_t} scores[t, s])`` over every
     row of the batch, ``p^ = probs / sum_{s in S_t} probs`` a constant:
-    a float32 scalar whose gradient reaches ``scores`` alone."""
-    seen = mask != 0
-    target = lax.stop_gradient(probs)
-    target = target / jnp.sum(target, axis=-1, keepdims=True)
-    logq = jax.nn.log_softmax(
-        jnp.where(seen, scores, -jnp.inf), axis=-1)
-    terms = jnp.where(
-        seen & (target > 0.0),
-        target * (jnp.log(jnp.where(target > 0.0, target, 1.0)) - logq), 0.0)
-    return jnp.sum(terms)
+    a float32 scalar whose gradient reaches ``scores`` alone. Because
+    ``p^`` is a constant that gradient is known in the forward, which
+    forms it beside the KL and names it `LOSS_GRAD`; the backward scales
+    it and reads nothing else."""
+    return _kl_and_grad(scores, probs, mask)[0]
+
+
+def _indexer_loss_fwd(scores, probs, mask):
+    loss, grad = _kl_and_grad(scores, probs, mask)
+    return loss, checkpoint_name(grad, LOSS_GRAD)
+
+
+def _indexer_loss_bwd(grad, ct):
+    with trace.scope("dsa_loss"):
+        # formed once: without the barrier XLA forms the product again
+        # inside the transpose the key-side score kernel's operand needs
+        return lax.optimization_barrier(ct * grad), None, None
+
+
+indexer_loss.defvjp(_indexer_loss_fwd, _indexer_loss_bwd)

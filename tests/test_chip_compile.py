@@ -271,6 +271,67 @@ def test_dsa_probs_kernel_compiles_at_the_cell_shape(
     assert not re.search(r"32,8192,8192\]|8192,8192,32\]", hlo)
 
 
+# That cell's whole step, built as benchmarks/jobs/finetune_loop.py
+# builds it (the family, its TrainConfig, ElasticTrainer.lower_step) on
+# one described chip: `step.hbm_peak_bytes` here is the chip's
+# `d3_hbm_peak_gib` to the byte. Since PR 43 a full block keeps d L_I / d
+# scores beside the selection's mask (256 MiB a layer, float32), and the
+# recomputed forward runs neither the indexer's score kernel nor
+# `dsa_probs`: one call a full layer a step where the parent made two.
+# The parent's step peaks at 15,186,436,096 bytes (14.143 GiB); the two
+# kept arrays and some slack may be added to it, no more.
+DOTS3_PARENT_STEP_PEAK = 15186436096
+
+
+def test_dots3_step_keeps_the_loss_gradient_in_the_memory_it_has(
+        topo, kernels_are_the_path):
+    import json
+
+    from benchmarks.families import dots3 as family
+    from dlrover_tpu.lint import memcheck
+    from dlrover_tpu.train.trainer import ElasticTrainer, TrainConfig
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "dots3-note-prev-ep32-1chip.json")) as f:
+        config = json.load(f)
+    mc = MeshConfig(dp=-1, **config.get("mesh", {})).resolve(1)
+    mesh = build_mesh(mc, devices=topo.devices[:1])
+    fam = family.build(config, mesh)
+    tc = TrainConfig(global_batch_size=1, micro_batch_size=1,
+                     **fam.train_config)
+    trainer = ElasticTrainer(fam.loss_fn, fam.param_specs, mesh, mc, tc)
+    params = jax.eval_shape(fam.init_params, jax.random.key(0))
+    state = {"params": params,
+             "opt": jax.eval_shape(trainer.optimizer.init, params),
+             "step": jax.ShapeDtypeStruct((), jnp.int32),
+             "lr_scale": jax.ShapeDtypeStruct((), jnp.float32)}
+    accum, per = trainer.step_batch_shape
+    trainer.record_avatars(
+        state, jax.ShapeDtypeStruct((accum, per, 8192), jnp.int32))
+    compiled, _ = trainer.lower_step(mesh, mc)
+
+    hlo = compiled.as_text()
+    assert fam.cfg.layer_kinds.count("F") == 2
+    for name, calls in (("dsa_index_fwd", 2), ("dsa_probs", 2),
+                        ("dsa_index_bwd_dq", 2), ("dsa_index_bwd_dk", 2),
+                        ("attention_fwd_sel", 4)):
+        assert _kernel_calls(hlo, name) == calls, name
+    assert trace.gauges()["dsa.loss_grad_kept"] == 1
+    # the backward scales the kept array once a layer: the transpose the
+    # key-side score kernel reads is a copy of that product, not a second
+    # product (`indexer_loss`'s barrier)
+    scaled = [line for line in _wide_f32(hlo, "fusion", 8192 * 8192)
+              if "transpose(jvp" in line]
+    assert len(scaled) == 2 and all(
+        _in_scope(re.search(r'op_name="([^"]*)"', line).group(1), "dsa_loss")
+        for line in scaled), scaled
+    peak = memcheck.read_memory_analysis(compiled)["peak_bytes"]
+    print(f"dots3 step.hbm_peak_bytes {peak} = {peak / 2**30:.4f} GiB")
+    assert peak <= DOTS3_PARENT_STEP_PEAK + 560 * 2**20
+    assert peak <= 15.75 * 2**30
+
+
 # The expert layer of that cell: 8192 tokens x 8 choices = 65536 rows
 # through 64 experts of 2048 x 1024, bf16. What the test holds is that
 # the v5e's compiler takes the grouped-matmul kernels at the tiles they

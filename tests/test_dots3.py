@@ -6,6 +6,7 @@ of the loss and where each one's gradient goes; the layout read from
 ``layer_types``; the shares of heads and experts tied to the uncut
 layer; the meshes."""
 
+import collections
 import dataclasses
 import json
 import os
@@ -18,7 +19,7 @@ import pytest
 from benchmarks.families import dots3 as family
 from dlrover_tpu.models import dots3, moe
 from dlrover_tpu.observability import trace
-from dlrover_tpu.ops import dsa
+from dlrover_tpu.ops import attention, dsa
 from dlrover_tpu.parallel import MeshConfig, build_mesh, named_shardings
 from dlrover_tpu.train.trainer import ElasticTrainer, TrainConfig
 
@@ -382,6 +383,76 @@ def test_gauges_say_what_the_build_is(built):
     assert trace.text("layers.pattern") == "fFSSS"
     assert {"dsa_index", "dsa_select", "dsa_loss", "attn_gate",
             "mla_proj"} <= set(trace.scopes())
+
+
+def _kernel_calls(jaxpr, found=None):
+    """The Pallas calls of a jaxpr and of every jaxpr inside it, by the
+    kernel's ``name=``."""
+    found = collections.Counter() if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _kernel_calls(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("kind,layer,want", [
+    ("F", 1, {"dsa_index_fwd": 1, "dsa_probs": 1, "dsa_index_bwd_dq": 1,
+              "dsa_index_bwd_dk": 1, "attention_fwd_sel": 2,
+              "attention_bwd_dq_sel": 1, "attention_bwd_dkv_sel": 1}),
+    ("S", 2, {"attention_fwd_swa": 2, "attention_bwd_dq_swa": 1,
+              "attention_bwd_dkv_swa": 1})])
+def test_a_recomputed_full_block_runs_the_loss_and_the_scores_once(
+        built, monkeypatch, kind, layer, want):
+    """The kernels a block's gradient calls under remat, checkpoint's
+    partial evaluation done (it has dropped from the recomputed forward
+    what the backward does not read). Until PR 43 a full block called
+    ``dsa_index_fwd`` 2 and ``dsa_probs`` 2 times: the KL's autodiff read
+    ``log_softmax(scores)`` and ``probs`` again. The flash forward is
+    still called twice (its ``lse`` and output are the backward's), and
+    a window block's three kernels 2, 1, 1 as before."""
+    fam, params, _ = built
+    cfg = dataclasses.replace(fam.cfg, remat=True)
+    monkeypatch.setattr(dsa, "_on_tpu", lambda: True)
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    positions = jnp.broadcast_to(jnp.arange(128, dtype=jnp.int32), (2, 128))
+    x = jax.random.normal(jax.random.key(2), (2, 128, cfg.dim))
+    fn = dots3._block_fn(cfg, None, kind, positions)
+    trace.gauge("dsa.loss_grad_kept", 0)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda lp, x: sum(jnp.sum(out) for out in fn(lp, x)),
+        argnums=(0, 1)))(dots3.layer_params(cfg, params, layer), x)
+    assert dict(_kernel_calls(jaxpr.jaxpr)) == want
+    assert trace.gauges()["dsa.loss_grad_kept"] == (kind == "F")
+
+
+def test_remat_changes_no_gradient_and_the_gauge_says_what_was_kept(built):
+    """Every leaf's gradient, the indexer's three included, with the full
+    blocks keeping the mask and ``d L_I / d scores`` against the same
+    model with nothing recomputed."""
+    fam, params, tokens = built
+
+    def value_and_grads(remat):
+        cfg = dataclasses.replace(fam.cfg, remat=remat)
+        out = jax.jit(jax.value_and_grad(
+            lambda p: dots3.loss_fn(p, tokens, cfg, None)))(params)
+        return out, trace.gauges()["dsa.loss_grad_kept"]
+
+    (want, want_grads), kept = value_and_grads(False)
+    assert kept == 0
+    (got, grads), kept = value_and_grads(True)
+    assert kept == 1
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    _assert_grads_agree(grads, want_grads, tol=1e-5)
+    moved = [jax.tree_util.keystr(path) for path, leaf
+             in jax.tree_util.tree_flatten_with_path(grads)[0]
+             if _is_indexer(path) and np.asarray(leaf).any()]
+    assert len(moved) == 2 * len(dots3.INDEXER), moved
+    # a forward alone keeps nothing
+    jax.eval_shape(lambda p: dots3.loss_fn(
+        p, tokens, dataclasses.replace(fam.cfg, remat=True), None), params)
+    assert trace.gauges()["dsa.loss_grad_kept"] == 0
 
 
 @pytest.mark.parametrize("axis,why", [

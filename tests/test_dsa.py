@@ -212,3 +212,82 @@ def test_the_kl_of_a_distribution_with_itself_is_zero():
     probs = dsa.head_summed_probs(q, k, lse, mask, scale)
     scores = jnp.where(mask != 0, jnp.log(jnp.maximum(probs, 1e-30)), 0.0)
     assert abs(float(dsa.indexer_loss(scores, probs, mask))) < 1e-2
+
+
+# -- the loss's own backward rule ---------------------------------------------
+
+def _parents_indexer_loss(scores, probs, mask):
+    """``indexer_loss`` as it stood before it formed its gradient in the
+    forward (PR 40's body, under plain autodiff): the oracle."""
+    seen = mask != 0
+    target = lax.stop_gradient(probs)
+    target = target / jnp.sum(target, axis=-1, keepdims=True)
+    logq = jax.nn.log_softmax(
+        jnp.where(seen, scores, -jnp.inf), axis=-1)
+    terms = jnp.where(
+        seen & (target > 0.0),
+        target * (jnp.log(jnp.where(target > 0.0, target, 1.0)) - logq), 0.0)
+    return jnp.sum(terms)
+
+
+def _loss_operands(kind):
+    """``(scores, probs, mask)``: the file's attention, or 64 positions
+    under a top-40 (so 39 rows hold fewer than ``topk`` causal keys) with
+    every third selected pair's target zero and some rows' targets zero
+    on all but the diagonal."""
+    if kind == "attention":
+        q, k, lse, mask, scale = _attention()
+        return (jax.random.normal(jax.random.key(6), (2, S, S)),
+                dsa.head_summed_probs(q, k, lse, mask, scale), mask)
+    s = 64
+    ks, kp = jax.random.split(jax.random.key(7))
+    scores = 3.0 * jax.random.normal(ks, (2, s, s))
+    mask = dsa.selection_mask(scores, 40)
+    rows, cols = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+    probs = jnp.where(
+        (mask != 0) & ((rows + cols) % 3 != 0),
+        jax.random.uniform(kp, (2, s, s), minval=0.1), 0.0)
+    probs = jnp.where((rows % 5 == 0) & (rows != cols), 0.0, probs)
+    return scores, probs.at[:, jnp.arange(s), jnp.arange(s)].set(0.5), mask
+
+
+@pytest.mark.parametrize("kind", ["attention", "partly_zero_short_rows"])
+def test_the_loss_forms_in_its_forward_the_gradient_autodiff_gave(kind):
+    scores, probs, mask = _loss_operands(kind)
+    if kind != "attention":
+        held = np.asarray((probs > 0) & (mask != 0)).sum(-1)
+        seen = np.asarray(mask != 0).sum(-1)
+        assert (seen < 40).any() and (held < seen).any() and (held == 1).any()
+    got, d_scores = jax.value_and_grad(dsa.indexer_loss)(scores, probs, mask)
+    want, want_d = jax.value_and_grad(_parents_indexer_loss)(
+        scores, probs, mask)
+    assert float(want) > 1.0
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    assert np.isfinite(np.asarray(d_scores)).all()
+    largest = float(jnp.max(jnp.abs(want_d)))
+    assert float(jnp.max(jnp.abs(d_scores - want_d))) <= 1e-5 * largest
+    assert not np.asarray(jnp.where(mask != 0, 0.0, d_scores)).any()
+    d_probs = jax.grad(dsa.indexer_loss, argnums=1)(scores, probs, mask)
+    assert d_probs.shape == probs.shape and not np.asarray(d_probs).any()
+
+
+@pytest.mark.parametrize("cotangent", [1.0, -0.37, 2.5e-4])
+def test_a_cotangent_scales_the_kept_gradient(cotangent):
+    scores, probs, mask = _loss_operands("partly_zero_short_rows")
+    loss, kept = dsa._indexer_loss_fwd(scores, probs, mask)
+    np.testing.assert_allclose(
+        loss, dsa.indexer_loss(scores, probs, mask), rtol=1e-6)
+    _, vjp = jax.vjp(lambda s: dsa.indexer_loss(s, probs, mask), scores)
+    got, = vjp(jnp.float32(cotangent))
+    np.testing.assert_array_equal(got, jnp.float32(cotangent) * kept)
+    want = jax.grad(lambda s: cotangent * _parents_indexer_loss(
+        s, probs, mask))(scores)
+    np.testing.assert_allclose(
+        got, want, atol=1e-5 * float(jnp.max(jnp.abs(want))))
+
+
+def test_the_kept_gradient_carries_its_name():
+    scores, probs, mask = _loss_operands("partly_zero_short_rows")
+    text = str(jax.make_jaxpr(jax.grad(dsa.indexer_loss))(
+        scores, probs, mask))
+    assert text.count(f"name[name={dsa.LOSS_GRAD}]") == 1
