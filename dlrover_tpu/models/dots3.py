@@ -22,9 +22,9 @@ What is this family's own:
   of the period** as ``models/smallthinker.py`` stacks its kinds, each
   position's slab with its own shapes. The leading
   ``first_k_dense_replace`` layers (dense feed-forward) come before the
-  scan, each with its own tree; the expert layers are one ``lax.scan``
-  over their shortest period, and what the depth leaves past whole
-  periods (the published layout ends on one more ``F``) follows it.
+  scan, each with its own tree; the expert layers are one scan over
+  their shortest period (``stack.walk``), and what the depth leaves past
+  whole periods (the published layout ends on one more ``F``) follows it.
 - **the full layer**, ``y = RMSNorm(x)``::
 
       c_q  = a_q RMSNorm(y W_qa);  q_h = c_q W_qb,h      (rotary on 64)
@@ -66,12 +66,11 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dlrover_tpu.models import llama, moe
+from dlrover_tpu.models import llama, moe, stack
 from dlrover_tpu.models.xing4 import latent_attention
 from dlrover_tpu.observability import trace
 from dlrover_tpu.ops import (
     apply_rope,
-    cross_entropy_sums,
     dsa,
     embed_lookup,
     flash_attention,
@@ -248,16 +247,18 @@ class Dots3Config:
         return self.layer_kinds[self.n_dense_layers:]
 
     @property
+    def layout(self) -> Tuple[stack.Part, ...]:
+        """The dense layers, each a part; the expert layers' shortest
+        period, stacked; what the depth leaves past whole periods."""
+        return stack.periodic(self.layer_kinds, head=self.n_dense_layers)
+
+    @property
     def period(self) -> int:
-        """The expert layers' shortest period (the depth need not be a
-        whole number of them: what is left follows the scan)."""
-        kinds = self.moe_kinds
-        return next((p for p in range(1, len(kinds) + 1) if all(
-            kinds[i] == kinds[i % p] for i in range(len(kinds)))), 1)
+        return max((len(p.kinds) for p in self.layout if p.repeats), default=1)
 
     @property
     def n_periods(self) -> int:
-        return len(self.moe_kinds) // self.period
+        return sum(p.repeats or 0 for p in self.layout)
 
     @property
     def tail_kinds(self) -> Tuple[str, ...]:
@@ -486,25 +487,23 @@ def param_specs(cfg: Dots3Config) -> Params:
     }
 
 
-def abstract_params(cfg: Dots3Config) -> Params:
-    return jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
+abstract_params = functools.partial(stack.abstract_params, init_params)
+param_count = functools.partial(stack.param_count, init_params)
 
 
-def param_count(cfg: Dots3Config) -> int:
-    return sum(
-        math.prod(l.shape) for l in jax.tree.leaves(abstract_params(cfg)))
+def _trees(params: Params):
+    """``params``' layers as the layout's parts take them."""
+    def own(group):
+        return [group[layer_name(i)] for i in range(len(group))]
+
+    period = tuple(params["layers"][pos_name(i)]
+                   for i in range(len(params["layers"])))
+    return own(params["dense"]) + [period] * bool(period) + own(params["tail"])
 
 
 def layer_params(cfg: Dots3Config, params: Params, layer: int) -> Params:
     """Layer ``layer``'s own leaves, wherever the layout keeps them."""
-    n = cfg.n_dense_layers
-    if layer < n:
-        return params["dense"][layer_name(layer)]
-    row, pos = divmod(layer - n, cfg.period)
-    if row < cfg.n_periods:
-        return jax.tree.map(
-            lambda a: a[row], params["layers"][pos_name(pos)])
-    return params["tail"][layer_name(layer - n - cfg.n_periods * cfg.period)]
+    return stack.layer_params(cfg.layout, _trees(params), layer)
 
 
 def validate_for_mesh(cfg: Dots3Config, mesh: Mesh, batch: int = 0) -> None:
@@ -676,57 +675,19 @@ def _report_shapes(cfg: Dots3Config):
 
 
 def _block_fn(cfg: Dots3Config, mesh, kind: str, positions):
-    fn = functools.partial(block, cfg, mesh, kind, positions)
-    if not cfg.remat:
-        return fn
-    # a block is recomputed whole in the backward pass, but for the two
-    # arrays a full layer names. The selection's mask, 1 byte a pair,
-    # spares the threshold's 45 passes over the scores. d L_I / d scores,
-    # 4 bytes a pair (`dsa.indexer_loss` forms it in the forward: its
-    # target is a constant), spares the indexer's score kernel,
-    # `dsa_probs` and the KL, which nothing else in the backward reads.
-    if kind != FULL:
-        return jax.checkpoint(
-            fn, policy=jax.checkpoint_policies.nothing_saveable)
-    named = jax.checkpoint_policies.save_only_these_names(
-        "dsa_select", dsa.LOSS_GRAD)
-
-    def policy(prim, *avals, **params):
-        keep = named(prim, *avals, **params)
-        if keep and params["name"] == dsa.LOSS_GRAD:
+    """A block is recomputed whole in the backward pass, but for the two
+    arrays a full layer names. The selection's mask, 1 byte a pair,
+    spares the threshold's 45 passes over the scores. d L_I / d scores,
+    4 bytes a pair (`dsa.indexer_loss` forms it in the forward: its
+    target is a constant), spares the indexer's score kernel,
+    `dsa_probs` and the KL, which nothing else in the backward reads."""
+    def kept(name):
+        if name == dsa.LOSS_GRAD:
             trace.gauge("dsa.loss_grad_kept", 1)
-        return keep
 
-    return jax.checkpoint(fn, policy=policy)
-
-
-def _walk(params: Params, tokens, cfg: Dots3Config, mesh, each):
-    """The layers first to last: ``each(kind, lp, x) -> (x, out)`` on
-    every block, the period's under one ``lax.scan``. Returns ``(x, the
-    outs: dense layers', the scan's stacked a position, the tail's)``."""
-    x = embed_lookup(params["embed"], tokens, mesh, cfg.dtype)
-    dense_out, tail_out = [], []
-    for i, kind in enumerate(cfg.layer_kinds[:cfg.n_dense_layers]):
-        x, out = each(kind, params["dense"][layer_name(i)], x)
-        dense_out.append(out)
-    period_kinds = cfg.moe_kinds[:cfg.period]
-
-    def one_period(x, layers):
-        outs = []
-        for i, kind in enumerate(period_kinds):
-            x, out = each(kind, layers[pos_name(i)], x)
-            outs.append(out)
-        return x, jnp.stack(outs)
-
-    scan_out = jnp.zeros((0,))
-    if cfg.n_periods:
-        x, scan_out = lax.scan(one_period, x, params["layers"])
-    for i, kind in enumerate(cfg.tail_kinds):
-        x, out = each(kind, params["tail"][layer_name(i)], x)
-        tail_out.append(out)
-    return x, jnp.concatenate([
-        jnp.asarray(part, jnp.float32).reshape(-1)
-        for part in (dense_out, scan_out, tail_out)])
+    return stack.recompute(
+        functools.partial(block, cfg, mesh, kind, positions), cfg.remat,
+        ("dsa_select", dsa.LOSS_GRAD) if kind == FULL else (), kept)
 
 
 def _positions(tokens):
@@ -747,8 +708,9 @@ def forward_layers(
     positions = _positions(tokens)
     fns = {kind: _block_fn(cfg, mesh, kind, positions)
            for kind in (FULL, WINDOW)}
-    return _walk(params, tokens, cfg, mesh,
-                 lambda kind, lp, x: fns[kind](lp, x))
+    x = embed_lookup(params["embed"], tokens, mesh, cfg.dtype)
+    return stack.walk(x, cfg.layout, _trees(params),
+                      lambda kind, lp, x: fns[kind](lp, x))
 
 
 def live_rows(
@@ -775,7 +737,8 @@ def live_rows(
                 dtype=jnp.float32)
         return feed_forward_half(cfg, mesh, lp, x, u), held
 
-    _, counts = _walk(params, tokens, cfg, mesh, each)
+    x = embed_lookup(params["embed"], tokens, mesh, cfg.dtype)
+    _, counts = stack.walk(x, cfg.layout, _trees(params), each)
     return counts[cfg.n_dense_layers:].astype(jnp.int32)
 
 
@@ -789,12 +752,10 @@ def loss_terms(
     x, l_i = forward_layers(params, tokens, cfg, mesh)
     with trace.scope("norm"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    nll_sum, n_valid = cross_entropy_sums(
-        x, params["lm_head"], llama._shift_targets(tokens),
-        chunk_size=cfg.ce_chunk_size, mesh=mesh)
+    ce = stack.next_token_loss(
+        x, params["lm_head"], tokens, cfg.ce_chunk_size, mesh)
     n_full = max(cfg.layer_kinds.count(FULL), 1)
-    return (nll_sum / jnp.maximum(n_valid, 1.0),
-            jnp.sum(l_i) / (n_full * tokens.size))
+    return ce, jnp.sum(l_i) / (n_full * tokens.size)
 
 
 def loss_fn(

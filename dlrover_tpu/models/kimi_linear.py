@@ -18,9 +18,9 @@ What is this family's own:
   1 as published) and which feed-forward (``"dense"`` for the first
   ``n_dense_layers``, ``"moe"`` after). Consecutive layers of one kind
   are a *run*: the parameters are stacked a run (``params["runs"]``)
-  and the forward is one ``lax.scan`` a run over the one ``block``
-  function, so the published 27 layers compile as 15 loops over three
-  bodies, not as 27 inlined blocks, and no slab is ever sliced.
+  and ``stack.walk`` scans each run over the one ``block`` function, so
+  the published 27 layers compile as 15 loops over three bodies, not as
+  27 inlined blocks, and no slab is ever sliced.
 - **the KDA layer** (``kda_attention``), ``h`` heads of ``d`` = 128::
 
       q~, k~, v~ = SiLU(Conv4(x W_q)), SiLU(Conv4(x W_k)), SiLU(Conv4(x W_v))
@@ -42,7 +42,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import itertools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -51,9 +50,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dlrover_tpu.models import llama, moe, xing4
+from dlrover_tpu.models import llama, moe, stack, xing4
 from dlrover_tpu.observability import trace
-from dlrover_tpu.ops import cross_entropy_sums, embed_lookup, kda, rms_norm
+from dlrover_tpu.ops import embed_lookup, kda, rms_norm
 from dlrover_tpu.parallel.mesh import BATCH_AXES, EP, FSDP, PP, SP, TP
 
 Params = Dict[str, Any]
@@ -122,12 +121,14 @@ class KimiLinearConfig:
         return "".join("K" if a == "kda" else "L" for a, _ in self.pattern)
 
     @property
+    def layout(self) -> Tuple[stack.Part, ...]:
+        """The pattern as runs of like layers, each a stacked part."""
+        return stack.runs(self.pattern)
+
+    @property
     def runs(self) -> Tuple[Tuple[str, str, int], ...]:
-        """The pattern as runs of like layers: ``(attention,
-        feed-forward, how many)``."""
-        return tuple(
-            (attn, ffn, len(list(layers)))
-            for (attn, ffn), layers in itertools.groupby(self.pattern))
+        """``(attention, feed-forward, how many)`` of each run."""
+        return tuple((*part.kinds[0], part.repeats) for part in self.layout)
 
     # what xing4.latent_attention reads of a config
     @property
@@ -302,14 +303,8 @@ def param_specs(cfg: KimiLinearConfig) -> Params:
     }
 
 
-def abstract_params(cfg: KimiLinearConfig) -> Params:
-    return jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
-
-
-def param_count(cfg: KimiLinearConfig) -> int:
-    return sum(
-        math.prod(l.shape) for l in jax.tree.leaves(abstract_params(cfg))
-    )
+abstract_params = functools.partial(stack.abstract_params, init_params)
+param_count = functools.partial(stack.param_count, init_params)
 
 
 def validate_for_mesh(cfg: KimiLinearConfig, mesh: Mesh, batch: int = 0
@@ -428,30 +423,17 @@ def forward_layers(
     mesh: Optional[Mesh] = None,
 ) -> jnp.ndarray:
     """The residual after the last block, before the final norm:
-    (b, s, dim). One ``lax.scan`` a run of like layers."""
+    (b, s, dim). One scan a run of like layers."""
     if mesh is not None:
         validate_for_mesh(cfg, mesh, batch=tokens.shape[0])
     _report_shapes(cfg)
     x = embed_lookup(params["embed"], tokens, mesh, cfg.dtype)
-    for i, (attn, ffn, _) in enumerate(cfg.runs):
-        fn = functools.partial(block, cfg, mesh, attn, ffn)
-        if cfg.remat:
-            fn = jax.checkpoint(
-                fn, policy=jax.checkpoint_policies.nothing_saveable)
-        x, _ = lax.scan(lambda x, lp, fn=fn: (fn(lp, x), None), x,
-                        params["runs"][run_name(i)])
-    return x
-
-
-def forward_hidden(
-    params: Params, tokens: jnp.ndarray, cfg: KimiLinearConfig,
-    mesh: Optional[Mesh] = None,
-) -> jnp.ndarray:
-    """Final-norm hidden states (b, s, dim): the pre-unembed
-    factorization the fused cross-entropy takes."""
-    x = forward_layers(params, tokens, cfg, mesh)
-    with trace.scope("norm"):
-        return rms_norm(x, params["final_norm"], cfg.norm_eps)
+    fns = {kind: stack.recompute(
+        functools.partial(block, cfg, mesh, *kind), cfg.remat)
+        for kind in set(cfg.pattern)}
+    trees = [(params["runs"][run_name(i)],) for i in range(len(cfg.layout))]
+    return stack.walk(x, cfg.layout, trees,
+                      lambda kind, lp, x: (fns[kind](lp, x), None))[0]
 
 
 def loss_fn(
@@ -459,9 +441,8 @@ def loss_fn(
     mesh: Optional[Mesh] = None,
 ) -> jnp.ndarray:
     """Mean next-token cross-entropy (pad tokens < 0 ignored)."""
-    x = forward_hidden(params, tokens, cfg, mesh)
-    nll_sum, n_valid = cross_entropy_sums(
-        x, params["lm_head"], llama._shift_targets(tokens),
-        chunk_size=cfg.ce_chunk_size, mesh=mesh,
-    )
-    return nll_sum / jnp.maximum(n_valid, 1.0)
+    x = forward_layers(params, tokens, cfg, mesh)
+    with trace.scope("norm"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    return stack.next_token_loss(
+        x, params["lm_head"], tokens, cfg.ce_chunk_size, mesh)

@@ -36,6 +36,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
+from dlrover_tpu.models import stack
 from dlrover_tpu.observability import trace
 from dlrover_tpu.ops import (
     apply_rope,
@@ -251,16 +252,8 @@ def deinterleave_layers(params: Params, pp: int, v: int) -> Params:
     }
 
 
-def abstract_params(cfg: LlamaConfig) -> Params:
-    return jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
-
-
-def param_count(cfg: LlamaConfig) -> int:
-    import math
-
-    return sum(
-        math.prod(l.shape) for l in jax.tree.leaves(abstract_params(cfg))
-    )
+abstract_params = functools.partial(stack.abstract_params, init_params)
+param_count = functools.partial(stack.param_count, init_params)
 
 
 # ---------------------------------------------------------------------------
@@ -489,15 +482,8 @@ def _decoder_layer(cfg: LlamaConfig, mesh, inv_freq, positions, lp, x,
 def _maybe_remat(cfg: LlamaConfig, layer_fn):
     """Apply the configured rematerialization policy (one place for the
     policy ladder: forward() and the pp schedule must never diverge)."""
-    if not cfg.remat:
-        return layer_fn
-    if cfg.remat_policy == "mlp":
-        policy = jax.checkpoint_policies.save_only_these_names(
-            "ffn_gate", "ffn_up"
-        )
-    else:
-        policy = jax.checkpoint_policies.nothing_saveable
-    return jax.checkpoint(layer_fn, policy=policy)
+    keep = ("ffn_gate", "ffn_up") if cfg.remat_policy == "mlp" else ()
+    return stack.recompute(layer_fn, cfg.remat, keep)
 
 
 def validate_for_mesh(cfg: LlamaConfig, mesh: Mesh, seq_len: int = 0) -> None:
@@ -585,11 +571,8 @@ def forward_hidden(
     layer_fn = _maybe_remat(
         cfg, functools.partial(_decoder_layer, cfg, mesh, inv_freq, positions)
     )
-
-    def scan_body(x, lp):
-        return layer_fn(lp, x), None
-
-    x, _ = lax.scan(scan_body, x, params["layers"])
+    x, _ = lax.scan(
+        lambda x, lp: (layer_fn(lp, x), None), x, params["layers"])
     with trace.scope("norm"):
         return rms_norm(x, params["final_norm"], cfg.norm_eps)
 
@@ -634,23 +617,8 @@ def _ce_sums_shifted(logits: jnp.ndarray, targets: jnp.ndarray):
     return jnp.sum((logz - gold) * valid), jnp.sum(valid)
 
 
-def _shift_targets(tokens: jnp.ndarray) -> jnp.ndarray:
-    """targets[i] = tokens[i+1], last position padded invalid (-1).
-
-    Implemented as slice + ``lax.pad`` — NOT ``jnp.concatenate`` — on
-    purpose: when this runs inside jit on a mesh with BOTH a data axis
-    and sp > 1, this jaxlib's (0.4.36) GSPMD partitioner miscompiles a
-    concatenate along the sp-sharded axis into an unreduced replica
-    sum, returning every target id multiplied by the data-axis size
-    (123 -> 246, the pad -1 -> -2). Wrong gold columns made the ring
-    configs of test_sharded_loss read ~0.25% off — not a tolerance
-    problem, a wrong-targets problem. ``lax.pad`` partitions cleanly.
-    """
-    return lax.pad(
-        tokens[..., 1:],
-        jnp.asarray(-1, tokens.dtype),
-        [(0, 0, 0)] * (tokens.ndim - 1) + [(0, 1, 0)],
-    )
+#: the pp stages' name for it (and `benchmarks/families/`'s)
+_shift_targets = stack.shift_targets
 
 
 def _record_sp_comm(cfg: LlamaConfig, mesh: Mesh, batch: int, seq: int,
@@ -747,17 +715,10 @@ def loss_fn(
     if mesh is not None:
         _record_sp_comm(cfg, mesh, tokens.shape[0], tokens.shape[1])
         _record_tp_comm(cfg, mesh, tokens.shape[0], tokens.shape[1])
-    # fused lm-head + CE: never materializes [b, s, vocab] logits.
-    # Shifted-target form (last position's target is the -1 sentinel)
-    # computes the head on all b*s positions, as `forward` + `_ce_sums`
-    # does. cross_entropy_sums dispatches: Pallas fused-CE kernel on TPU
-    # (ops/fused_ce.py), the chunked scan everywhere else.
+    # the head on all b*s positions, as `forward` + `_ce_sums` does
     x = forward_hidden(params, tokens, cfg, mesh)
-    nll_sum, n_valid = cross_entropy_sums(
-        x, params["lm_head"], _shift_targets(tokens),
-        chunk_size=cfg.ce_chunk_size, mesh=mesh,
-    )
-    return nll_sum / jnp.maximum(n_valid, 1.0)
+    return stack.next_token_loss(
+        x, params["lm_head"], tokens, cfg.ce_chunk_size, mesh)
 
 
 def _pp_loss(

@@ -85,11 +85,10 @@ import numpy as np
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dlrover_tpu.models import llama
+from dlrover_tpu.models import llama, stack
 from dlrover_tpu.observability import trace
 from dlrover_tpu.ops import (
     apply_rope,
-    cross_entropy_sums,
     embed_lookup,
     rms_norm,
     rope_frequencies,
@@ -264,14 +263,8 @@ def param_specs(cfg: MoeConfig) -> Params:
     }
 
 
-def abstract_params(cfg: MoeConfig) -> Params:
-    return jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
-
-
-def param_count(cfg: MoeConfig) -> int:
-    return sum(
-        math.prod(l.shape) for l in jax.tree.leaves(abstract_params(cfg))
-    )
+abstract_params = functools.partial(stack.abstract_params, init_params)
+param_count = functools.partial(stack.param_count, init_params)
 
 
 def active_param_count(cfg: MoeConfig) -> int:
@@ -742,11 +735,9 @@ def forward_hidden(
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta)
 
-    layer_fn = functools.partial(_decoder_layer, cfg, mesh, inv_freq, positions)
-    if cfg.remat:
-        layer_fn = jax.checkpoint(
-            layer_fn, policy=jax.checkpoint_policies.nothing_saveable
-        )
+    layer_fn = stack.recompute(
+        functools.partial(_decoder_layer, cfg, mesh, inv_freq, positions),
+        cfg.remat)
 
     def scan_body(carry, lp):
         x, aux_sum = carry
@@ -782,9 +773,6 @@ def loss_fn(
     head runs as models/llama.py runs it: operands in the dtype they
     arrive in, f32 accumulation, the fused-CE kernel on the TPU."""
     x, aux = forward_hidden(params, tokens, cfg, mesh)
-    nll_sum, n_valid = cross_entropy_sums(
-        x, params["lm_head"], llama._shift_targets(tokens),
-        chunk_size=cfg.ce_chunk_size, mesh=mesh,
-    )
-    ce = nll_sum / jnp.maximum(n_valid, 1.0)
+    ce = stack.next_token_loss(
+        x, params["lm_head"], tokens, cfg.ce_chunk_size, mesh)
     return ce + cfg.router_aux_coef * aux

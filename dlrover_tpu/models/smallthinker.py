@@ -18,9 +18,9 @@ What is this family's own:
   kernels. The layout's shortest period divides the depth (published:
   ``F W W W`` thirteen times), the layers' parameters are stacked **a
   position of the period** (``params["layers"]["pos0"]`` holds layers 0,
-  4, 8, ..), and the forward is **one ``lax.scan`` over the periods**
-  whose body is the period's blocks, each with its own kind and its own
-  slab: 52 layers compile as one loop over four blocks, and a kind is
+  4, 8, ..), and the forward is **one scan over the periods**
+  (``stack.walk``) whose body is the period's blocks, each with its own
+  kind and its own slab: 52 layers are one loop over four blocks, a kind is
   never a ``lax.cond`` that builds both. (Runs of like layers, as
   ``KimiLinearConfig`` makes them, would be 26 loops at the published
   depth over the same two bodies. One slab of all the layers, split
@@ -50,11 +50,10 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dlrover_tpu.models import llama, moe
+from dlrover_tpu.models import moe, stack
 from dlrover_tpu.observability import trace
 from dlrover_tpu.ops import (
     apply_rope,
-    cross_entropy_sums,
     embed_lookup,
     rms_norm,
     rope_frequencies,
@@ -110,11 +109,13 @@ class SmallThinkerConfig:
             for r, w in zip(self.rope_layout, self.window_layout))
 
     @property
+    def layout(self) -> Tuple[stack.Part, ...]:
+        """One stacked part: the shortest period that divides the depth."""
+        return stack.periodic(self.kinds, whole=True)
+
+    @property
     def period(self) -> int:
-        """The layout's shortest period that divides the depth."""
-        kinds, n = self.kinds, self.n_layers
-        return next(p for p in range(1, n + 1) if n % p == 0 and all(
-            kinds[i] == kinds[i % p] for i in range(n)))
+        return len(self.layout[0].kinds)
 
     @property
     def pattern_string(self) -> str:
@@ -178,11 +179,16 @@ def param_specs(cfg: SmallThinkerConfig) -> Params:
     return specs
 
 
+def _trees(params: Params):
+    """``params``' layers as the layout's one part takes them."""
+    positions = params["layers"]
+    return [tuple(positions[pos_name(i)] for i in range(len(positions)))]
+
+
 def layer_params(cfg: SmallThinkerConfig, params: Params, layer: int
                  ) -> Params:
     """Layer ``layer``'s own leaves."""
-    row, pos = divmod(layer, cfg.period)
-    return jax.tree.map(lambda a: a[row], params["layers"][pos_name(pos)])
+    return stack.layer_params(cfg.layout, _trees(params), layer)
 
 
 def param_count(cfg: SmallThinkerConfig) -> int:
@@ -267,29 +273,17 @@ def forward_layers(
     mesh: Optional[Mesh] = None,
 ) -> jnp.ndarray:
     """The residual after the last block, before the final norm:
-    (b, s, dim). One ``lax.scan`` over the layout's periods."""
+    (b, s, dim). One scan over the layout's periods."""
     b, s = tokens.shape
     if mesh is not None:
         validate_for_mesh(cfg, mesh, seq_len=s, batch=b)
     _report_shapes(cfg)
     x = embed_lookup(params["embed"], tokens, mesh, cfg.dtype)
-
-    def block_fn(kind):
-        fn = functools.partial(block, cfg, mesh, *kind)
-        if cfg.remat:
-            fn = jax.checkpoint(
-                fn, policy=jax.checkpoint_policies.nothing_saveable)
-        return fn
-
-    fns = [block_fn(kind) for kind in cfg.kinds[:cfg.period]]
-
-    def one_period(x, layers):
-        for i, fn in enumerate(fns):
-            x = fn(layers[pos_name(i)], x)
-        return x, None
-
-    x, _ = lax.scan(one_period, x, params["layers"])
-    return x
+    fns = {kind: stack.recompute(
+        functools.partial(block, cfg, mesh, *kind), cfg.remat)
+        for kind in set(cfg.kinds)}
+    return stack.walk(x, cfg.layout, _trees(params),
+                      lambda kind, lp, x: (fns[kind](lp, x), None))[0]
 
 
 def live_rows(
@@ -302,36 +296,17 @@ def live_rows(
     of its own beside the step, which has no output but the loss: the
     gauge ``moe.rows_held`` is what uniform routing *would* send, this
     is what the router sends. (n_layers,) int32."""
-    mcfg = cfg.as_moe()
-    first, kinds = cfg.first_expert, cfg.kinds[:cfg.period]
+    mcfg, first = cfg.as_moe(), cfg.first_expert
     x = embed_lookup(params["embed"], tokens, mesh, cfg.dtype)
 
-    def one_period(x, layers):
-        counts = []
-        for i, kind in enumerate(kinds):
-            lp = layers[pos_name(i)]
-            y = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-            _, _, top_e = moe.route(
-                mcfg, lp["router"], y.reshape(-1, cfg.dim))
-            counts.append(jnp.sum(
-                (top_e >= first) & (top_e < first + mcfg.n_held),
-                dtype=jnp.int32))
-            x = block(cfg, mesh, *kind, lp, x)
-        return x, jnp.stack(counts)
+    def each(kind, lp, x):
+        y = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        _, _, top_e = moe.route(mcfg, lp["router"], y.reshape(-1, cfg.dim))
+        held = jnp.sum((top_e >= first) & (top_e < first + mcfg.n_held),
+                       dtype=jnp.int32)
+        return block(cfg, mesh, *kind, lp, x), held
 
-    _, counts = lax.scan(one_period, x, params["layers"])
-    return counts.reshape(-1)
-
-
-def forward_hidden(
-    params: Params, tokens: jnp.ndarray, cfg: SmallThinkerConfig,
-    mesh: Optional[Mesh] = None,
-) -> jnp.ndarray:
-    """Final-norm hidden states (b, s, dim): the pre-unembed
-    factorization the fused cross-entropy takes."""
-    x = forward_layers(params, tokens, cfg, mesh)
-    with trace.scope("norm"):
-        return rms_norm(x, params["final_norm"], cfg.norm_eps)
+    return stack.walk(x, cfg.layout, _trees(params), each)[1]
 
 
 def loss_fn(
@@ -339,9 +314,8 @@ def loss_fn(
     mesh: Optional[Mesh] = None,
 ) -> jnp.ndarray:
     """Mean next-token cross-entropy (pad tokens < 0 ignored)."""
-    x = forward_hidden(params, tokens, cfg, mesh)
-    nll_sum, n_valid = cross_entropy_sums(
-        x, params["lm_head"], llama._shift_targets(tokens),
-        chunk_size=cfg.ce_chunk_size, mesh=mesh,
-    )
-    return nll_sum / jnp.maximum(n_valid, 1.0)
+    x = forward_layers(params, tokens, cfg, mesh)
+    with trace.scope("norm"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    return stack.next_token_loss(
+        x, params["lm_head"], tokens, cfg.ce_chunk_size, mesh)

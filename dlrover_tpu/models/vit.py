@@ -15,6 +15,7 @@ pre-norm encoder blocks with gelu MLP, mean-pool head.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict
 
@@ -23,6 +24,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from dlrover_tpu.models import stack
 from dlrover_tpu.ops.attention import (
     flash_attention,
     flash_tiles,
@@ -132,14 +134,8 @@ def param_specs(cfg: ViTConfig) -> Params:
     }
 
 
-def abstract_params(cfg: ViTConfig) -> Params:
-    return jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
-
-
-def param_count(cfg: ViTConfig) -> int:
-    return sum(
-        math.prod(l.shape) for l in jax.tree.leaves(abstract_params(cfg))
-    )
+abstract_params = functools.partial(stack.abstract_params, init_params)
+param_count = functools.partial(stack.param_count, init_params)
 
 
 def patchify(cfg: ViTConfig, images: jnp.ndarray) -> jnp.ndarray:
@@ -187,16 +183,10 @@ def forward_pooled(params: Params, images: jnp.ndarray, cfg: ViTConfig,
     x = patchify(cfg, images.astype(dt)) @ params["patch_embed"].astype(dt)
     x = x + params["pos_embed"].astype(dt)[None]
 
-    layer_fn = lambda lp, x: _encoder_layer(cfg, mesh, lp, x)  # noqa: E731
-    if cfg.remat:
-        layer_fn = jax.checkpoint(
-            layer_fn, policy=jax.checkpoint_policies.nothing_saveable
-        )
-
-    def scan_body(x, lp):
-        return layer_fn(lp, x), None
-
-    x, _ = lax.scan(scan_body, x, params["layers"])
+    layer_fn = stack.recompute(
+        functools.partial(_encoder_layer, cfg, mesh), cfg.remat)
+    x, _ = lax.scan(
+        lambda x, lp: (layer_fn(lp, x), None), x, params["layers"])
     if mesh is not None:
         from jax.sharding import NamedSharding
 

@@ -57,11 +57,10 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dlrover_tpu.models import llama, moe
+from dlrover_tpu.models import llama, moe, stack
 from dlrover_tpu.observability import trace
 from dlrover_tpu.ops import (
     apply_rope,
-    cross_entropy_sums,
     embed_lookup,
     flash_attention,
     rms_norm,
@@ -315,14 +314,8 @@ def param_specs(cfg: Xing4Config) -> Params:
     return specs
 
 
-def abstract_params(cfg: Xing4Config) -> Params:
-    return jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
-
-
-def param_count(cfg: Xing4Config) -> int:
-    return sum(
-        math.prod(l.shape) for l in jax.tree.leaves(abstract_params(cfg))
-    )
+abstract_params = functools.partial(stack.abstract_params, init_params)
+param_count = functools.partial(stack.param_count, init_params)
 
 
 def validate_for_mesh(cfg: Xing4Config, mesh: Mesh, batch: int = 0) -> None:
@@ -538,11 +531,9 @@ def rotary_tables(cfg: Xing4Config, tokens):
 
 
 def _block_fn(cfg: Xing4Config, mesh, tokens):
-    fn = functools.partial(block, cfg, mesh, *rotary_tables(cfg, tokens))
-    if cfg.remat:
-        fn = jax.checkpoint(
-            fn, policy=jax.checkpoint_policies.nothing_saveable)
-    return fn
+    return stack.recompute(
+        functools.partial(block, cfg, mesh, *rotary_tables(cfg, tokens)),
+        cfg.remat)
 
 
 def _streams(cfg: Xing4Config, x):
@@ -602,22 +593,17 @@ def loss_terms(
     cross-entropy through the one head (pad tokens < 0 ignored), and the
     summed streams both heads start from."""
     h = forward_streams(params, tokens, cfg, mesh)
-    targets = llama._shift_targets(tokens)
-
-    def ce(x, tgt):
-        nll_sum, n_valid = cross_entropy_sums(
-            x, params["lm_head"], tgt, chunk_size=cfg.ce_chunk_size,
-            mesh=mesh)
-        return nll_sum / jnp.maximum(n_valid, 1.0)
-
+    ce = functools.partial(
+        stack.next_token_loss, chunk_size=cfg.ce_chunk_size, mesh=mesh)
     with trace.scope("norm"):
         x = rms_norm(h, params["final_norm"], cfg.norm_eps)
-    main = ce(x, targets)
+    main = ce(x, params["lm_head"], tokens)
     if not cfg.mtp_depth:
         return main, jnp.zeros((), jnp.float32), h
+    targets = stack.shift_targets(tokens)   # the second head's tokens
     x = _mtp_hidden(params, tokens, targets, h, cfg, mesh)
     with trace.scope("mtp"):
-        return main, ce(x, llama._shift_targets(targets)), h
+        return main, ce(x, params["lm_head"], targets), h
 
 
 def loss_fn(

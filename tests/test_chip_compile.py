@@ -7,73 +7,28 @@ VMEM, a misaligned slice, a Mosaic kernel left to the automatic
 partitioner — and interpret mode shows none of that. Nothing runs, so
 these say nothing about results or times.
 
-The topology is described inside a fixture, never at import, and the
-compiles run in the test's own process: only one process may load the
-TPU's library, and under xdist only the worker given this file does.
+The topology is described inside a fixture, never at import
+(``tests/chip_compile.py``, with the readers of a compiled program's text
+that this file shares with ``test_chip_compile_steps.py``, a cell's whole
+step and the families' blocks, and ``test_chip_compile_experts.py``, the
+expert layer).
 """
-
-import os
-
-os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
 
 import re
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
-from jax.sharding import NamedSharding, SingleDeviceSharding
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from dlrover_tpu.observability import trace
-from dlrover_tpu.ops import (
-    attention, dsa, fused_ce, grouped_matmul, kda, moe_rows)
+from dlrover_tpu.ops import attention, dsa, fused_ce, kda
 from dlrover_tpu.parallel import MeshConfig, build_mesh
 from dlrover_tpu.parallel.mesh import BATCH_AXES
-
-
-@pytest.fixture(scope="module")
-def topo():
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-
-    try:
-        desc = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2"
-        )
-    except Exception as e:
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    # a compile for a described chip is written to the persistent cache
-    # but cannot be read back without the chip: keep the cache out of it
-    enabled = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield desc
-    jax.config.update("jax_enable_compilation_cache", enabled)
-    compilation_cache.reset_cache()
-
-
-@pytest.fixture(scope="module")
-def one_chip(topo):
-    return SingleDeviceSharding(topo.devices[0])
-
-
-@pytest.fixture
-def kernels_are_the_path(monkeypatch):
-    """The public wrappers ask ``jax.default_backend()``, which is the
-    CPU here, and would take their reference branch."""
-    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    monkeypatch.setattr(fused_ce, "_on_tpu", lambda: True)
-    monkeypatch.setattr(grouped_matmul, "_on_tpu", lambda: True)
-    monkeypatch.setattr(kda, "_on_tpu", lambda: True)
-    monkeypatch.setattr(moe_rows, "_on_tpu", lambda: True)
-    monkeypatch.setattr(dsa, "_on_tpu", lambda: True)
-    monkeypatch.setenv("DLROVER_TPU_FUSED_CE", "1")
-
-
-def _compile(fn, *args):
-    compiled = jax.jit(fn).lower(*args).compile()
-    return compiled.as_text()
+from tests.chip_compile import (  # noqa: F401  (fixtures by import)
+    _compile, _in_scope, _kernel_calls, _op_names, kernels_are_the_path,
+    one_chip, topo)
 
 
 # Llama-3-8B attention: b1, s2048, 32 q / 8 kv heads, head_dim 128
@@ -271,126 +226,9 @@ def test_dsa_probs_kernel_compiles_at_the_cell_shape(
     assert not re.search(r"32,8192,8192\]|8192,8192,32\]", hlo)
 
 
-# That cell's whole step, built as benchmarks/jobs/finetune_loop.py
-# builds it (the family, its TrainConfig, ElasticTrainer.lower_step) on
-# one described chip: `step.hbm_peak_bytes` here is the chip's
-# `d3_hbm_peak_gib` to the byte. Since PR 43 a full block keeps d L_I / d
-# scores beside the selection's mask (256 MiB a layer, float32), and the
-# recomputed forward runs neither the indexer's score kernel nor
-# `dsa_probs`: one call a full layer a step where the parent made two.
-# The parent's step peaks at 15,186,436,096 bytes (14.143 GiB); the two
-# kept arrays and some slack may be added to it, no more.
-DOTS3_PARENT_STEP_PEAK = 15186436096
-
-
-def test_dots3_step_keeps_the_loss_gradient_in_the_memory_it_has(
-        topo, kernels_are_the_path):
-    import json
-
-    from benchmarks.families import dots3 as family
-    from dlrover_tpu.lint import memcheck
-    from dlrover_tpu.train.trainer import ElasticTrainer, TrainConfig
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmarks", "configs",
-                           "dots3-note-prev-ep32-1chip.json")) as f:
-        config = json.load(f)
-    mc = MeshConfig(dp=-1, **config.get("mesh", {})).resolve(1)
-    mesh = build_mesh(mc, devices=topo.devices[:1])
-    fam = family.build(config, mesh)
-    tc = TrainConfig(global_batch_size=1, micro_batch_size=1,
-                     **fam.train_config)
-    trainer = ElasticTrainer(fam.loss_fn, fam.param_specs, mesh, mc, tc)
-    params = jax.eval_shape(fam.init_params, jax.random.key(0))
-    state = {"params": params,
-             "opt": jax.eval_shape(trainer.optimizer.init, params),
-             "step": jax.ShapeDtypeStruct((), jnp.int32),
-             "lr_scale": jax.ShapeDtypeStruct((), jnp.float32)}
-    accum, per = trainer.step_batch_shape
-    trainer.record_avatars(
-        state, jax.ShapeDtypeStruct((accum, per, 8192), jnp.int32))
-    compiled, _ = trainer.lower_step(mesh, mc)
-
-    hlo = compiled.as_text()
-    assert fam.cfg.layer_kinds.count("F") == 2
-    for name, calls in (("dsa_index_fwd", 2), ("dsa_probs", 2),
-                        ("dsa_index_bwd_dq", 2), ("dsa_index_bwd_dk", 2),
-                        ("attention_fwd_sel", 4)):
-        assert _kernel_calls(hlo, name) == calls, name
-    assert trace.gauges()["dsa.loss_grad_kept"] == 1
-    # the backward scales the kept array once a layer: the transpose the
-    # key-side score kernel reads is a copy of that product, not a second
-    # product (`indexer_loss`'s barrier)
-    scaled = [line for line in _wide_f32(hlo, "fusion", 8192 * 8192)
-              if "transpose(jvp" in line]
-    assert len(scaled) == 2 and all(
-        _in_scope(re.search(r'op_name="([^"]*)"', line).group(1), "dsa_loss")
-        for line in scaled), scaled
-    peak = memcheck.read_memory_analysis(compiled)["peak_bytes"]
-    print(f"dots3 step.hbm_peak_bytes {peak} = {peak / 2**30:.4f} GiB")
-    assert peak <= DOTS3_PARENT_STEP_PEAK + 560 * 2**20
-    assert peak <= 15.75 * 2**30
-
-
-# The expert layer of that cell: 8192 tokens x 8 choices = 65536 rows
-# through 64 experts of 2048 x 1024, bf16. What the test holds is that
-# the v5e's compiler takes the grouped-matmul kernels at the tiles they
-# choose (ops/grouped_matmul.py), forward, d-lhs and d-rhs, and that no
-# tensor of (tokens, experts, capacity) is in the program.
-def _olmoe_expert_layer(sharding):
-    import dataclasses
-
-    from dlrover_tpu.models import moe
-
-    cfg = dataclasses.replace(
-        moe.MoeConfig.olmoe_1b_7b(), n_layers=1, dtype=jnp.bfloat16,
-        param_dtype=jnp.bfloat16)
-    layers = moe.abstract_params(cfg)["layers"]
-    lp = {
-        k: jax.ShapeDtypeStruct(layers[k].shape[1:], layers[k].dtype,
-                                sharding=sharding)
-        for k in ("router", "w_gate", "w_up", "w_down")
-    }
-    y = jax.ShapeDtypeStruct((2, 4096, cfg.dim), jnp.bfloat16,
-                             sharding=sharding)
-
-    def loss(lp, y):
-        out, aux = moe.moe_mlp(cfg, lp, y)
-        return out.astype(jnp.float32).sum() + aux
-
-    return loss, lp, y
-
-
-def _kernel_calls(hlo, name):
-    return sum("custom-call(" in line
-               and line.split(" = ")[0].strip().lstrip("%").startswith(name)
-               for line in hlo.splitlines())
-
-
-def test_olmoe_expert_layer_compiles(one_chip, kernels_are_the_path):
-    loss, lp, y = _olmoe_expert_layer(one_chip)
-    hlo = _compile(loss, lp, y)
-    assert _kernel_calls(hlo, "grouped_matmul") == 3  # gate, up, down
-    assert "ragged-dot" not in hlo
-    assert "[8192,64," not in hlo  # no (tokens, experts, ...) dispatch tensor
-
-
-def test_olmoe_expert_layer_fwd_bwd_compiles(one_chip, kernels_are_the_path):
-    loss, lp, y = _olmoe_expert_layer(one_chip)
-    hlo = _compile(jax.grad(loss, argnums=(0, 1)), lp, y)
-    # forward, d-lhs and d-rhs of each of the three products (up's
-    # d-lhs adds onto gate's in place: no add of the two outside)
-    assert _kernel_calls(hlo, "grouped_matmul_dlhs") == 3
-    assert _kernel_calls(hlo, "grouped_matmul_drhs") == 3
-    assert _kernel_calls(hlo, "grouped_matmul") == 9
-    assert "[8192,64," not in hlo
-
-
 # xing4-ep8-1chip-steady (PR 31): latent attention's kernels take q/k
-# heads of 192 against v heads of 128 and the scale yarn states; and one
-# whole expert block of the step at the published widths (four streams
-# of 2 x 4096 x 3584, ranks 768 / 512, 8 held experts of 64, the shared
-# expert), forward and backward, remat as the cell runs it.
+# heads of 192 against v heads of 128 and the scale yarn states (the
+# cell's whole expert block: test_chip_compile_steps.py).
 def test_flash_two_widths_compiles_at_chosen_tiles(
         one_chip, kernels_are_the_path):
     tiles = attention.flash_tiles(4096, 4096, 192, 1, jnp.bfloat16, 128)
@@ -407,50 +245,6 @@ def test_flash_two_widths_compiles_at_chosen_tiles(
 
     hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), qk, qk, v)
     assert hlo.count("tpu_custom_call") == 3  # fwd, dq, dk/dv
-
-
-def test_xing4_expert_block_fwd_bwd_compiles(one_chip, kernels_are_the_path):
-    from dlrover_tpu.models import xing4
-    from dlrover_tpu.ops import yarn_frequencies
-
-    cfg = xing4.Xing4Config(
-        vocab_size=16384, n_dense_layers=1, n_moe_layers=1, experts_held=8,
-        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
-    layers = xing4.abstract_params(cfg)["layers"]
-    lp = jax.tree.map(
-        lambda l: jax.ShapeDtypeStruct(l.shape[1:], l.dtype,
-                                       sharding=one_chip), layers)
-    X = jax.ShapeDtypeStruct((4, 2, 4096, cfg.dim), jnp.bfloat16,
-                             sharding=one_chip)
-
-    def loss(lp, X):
-        positions = jnp.broadcast_to(jnp.arange(4096, dtype=jnp.int32),
-                                     (2, 4096))
-        inv_freq = yarn_frequencies(64, 10000.0, 64.0, 4096)
-        fn = jax.checkpoint(
-            lambda lp, X: xing4.block(cfg, None, positions, inv_freq, lp, X),
-            policy=jax.checkpoint_policies.nothing_saveable)
-        return fn(lp, X).astype(jnp.float32).sum()
-
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(lp, X).compile()
-    hlo = compiled.as_text()
-    # the remat forward is the only forward here (nothing else wants the
-    # block's output): 1 + 2 of attention, and forward, d-lhs and d-rhs
-    # of each of the three grouped products; act(gate) x up and its
-    # backward as passes
-    assert _kernel_calls(hlo, "attention_fwd") == 1
-    assert _kernel_calls(hlo, "attention_bwd") == 2
-    assert _kernel_calls(hlo, "grouped_matmul_dlhs") == 3
-    assert _kernel_calls(hlo, "grouped_matmul_drhs") == 3
-    assert _kernel_calls(hlo, "grouped_matmul") == 9
-    assert _kernel_calls(hlo, "moe_rows_gated") == 2
-    assert trace.gauges()["moe.tail_skipped"] == 1
-    assert "[8192,64,8" not in hlo  # no (tokens, experts, ...) dispatch tensor
-    # a block's own temporaries fit beside the cell's state and carries
-    assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2**30
-    assert trace.gauges()["moe.rows_held"] == 4096
-    assert trace.gauges()["moe.tail_rows"] == 28672
-    assert cfg.softmax_scale == pytest.approx(0.14468, rel=1e-4)
 
 
 def _kda_args(sharding, batch=1):
@@ -470,29 +264,6 @@ def _kda_loss(mesh=None):
     return loss
 
 
-def _op_names(hlo, target="tpu_custom_call"):
-    """The ``op_name`` of every custom call to ``target``."""
-    return [re.search(r'op_name="([^"]*)"', line).group(1)
-            for line in hlo.splitlines()
-            if f'custom_call_target="{target}"' in line]
-
-
-def _wide_f32(hlo, op, at_least=8192 * 4096):
-    """The lines of ``hlo`` where ``op`` makes a float32 array of
-    ``at_least`` elements."""
-    found = []
-    for line in hlo.splitlines():
-        shape = re.search(r"= f32\[([0-9,]+)\]\S* " + op + r"\(", line)
-        if shape and np.prod(
-                [int(d) for d in shape.group(1).split(",")]) >= at_least:
-            found.append(line)
-    return found
-
-
-def _in_scope(op_name, scope):
-    # as benchmarks/harness/hlo_scopes.py reads it: a whole component,
-    # bare or wrapped by a transform
-    return scope in re.split(r"[/()]", op_name)
 
 
 def test_chunked_delta_rule_fwd_bwd_compiles_in_its_memory(one_chip):
@@ -580,174 +351,6 @@ def test_kda_elementwise_passes_compile(one_chip, kernels_are_the_path, scope):
     assert trace.gauges()["kda.io_fused"] == 1
 
 
-@pytest.mark.parametrize("attn", ["kda", "mla"])
-def test_kimi_linear_expert_block_fwd_bwd_compiles(
-        one_chip, kernels_are_the_path, attn):
-    from dlrover_tpu.models import kimi_linear
-
-    cfg = kimi_linear.KimiLinearConfig(
-        vocab_size=20480, n_layers=5, kda_layers=(1, 2, 3, 5),
-        full_attn_layers=(4,), experts_held=32, dtype=jnp.bfloat16,
-        param_dtype=jnp.bfloat16)
-    lp = {
-        name: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
-        for name, (shape, _, _) in kimi_linear._block_shapes(
-            cfg, attn, "moe").items()
-    }
-    x = jax.ShapeDtypeStruct((1, 8192, cfg.dim), jnp.bfloat16,
-                             sharding=one_chip)
-
-    def loss(lp, x):
-        fn = jax.checkpoint(
-            lambda lp, x: kimi_linear.block(cfg, None, attn, "moe", lp, x),
-            policy=jax.checkpoint_policies.nothing_saveable)
-        return fn(lp, x).astype(jnp.float32).sum()
-
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(lp, x).compile()
-    hlo = compiled.as_text()
-    # latent attention without rotary still runs the 192 / 128 kernels;
-    # a KDA block runs none of them and its own two instead: the remat
-    # forward and the backward (the loss's value is not asked for, so
-    # the first forward is gone), both under the scope the device
-    # metrics select by
-    flash = 1 if attn == "mla" else 0
-    assert _kernel_calls(hlo, "attention_fwd") == flash
-    assert _kernel_calls(hlo, "attention_bwd") == 2 * flash
-    assert _kernel_calls(hlo, "grouped_matmul") == 9
-    assert _kernel_calls(hlo, "moe_rows_gated") == 2
-    delta = [n for n in _op_names(hlo) if "/kda_" in n]
-    if flash:
-        assert not delta
-    else:
-        # each kernel once, under its layer's scope, the backward's too
-        # (the first forward is gone, so a forward kernel runs once)
-        assert sorted((n.split("/")[-2], next(
-            s for s in ("kda_conv", "kda_chunk", "kda_out")
-            if _in_scope(n, s))) for n in delta) == [
-            ("kda_bwd", "kda_chunk"), ("kda_fwd", "kda_chunk"),
-            ("kda_in_bwd", "kda_conv"), ("kda_in_fwd", "kda_conv"),
-            ("kda_out_bwd", "kda_out"), ("kda_out_fwd", "kda_out")]
-        assert trace.gauges()["kda.io_fused"] == 1
-        # the XLA form of the passes took float32 copies of every
-        # activation into another layout and back: none is left
-        assert not _wide_f32(hlo, "copy")
-        # what the XLA form of the rule cost beside its loops: the solves
-        # and the float32 moves of (8192, 4096) into chunk-major order
-        assert "riangular" not in hlo
-        assert not [line for line in _wide_f32(hlo, "transpose")
-                    if _in_scope(line, "kda_chunk")]
-    # a block's own temporaries fit beside the cell's 7.16 GiB of state
-    # and 4.78 of float32 gradients; a KDA block's are under what they
-    # were with the passes in XLA ops (2.857 GiB; 2.10 now)
-    assert compiled.memory_analysis().temp_size_in_bytes < (
-        3 if flash else 2.3) * 2**30
-    assert trace.gauges()["moe.rows_held"] == 8192
-    assert trace.gauges()["moe.tail_rows"] == 57344
-
-
-# The expert layer of the four expert cells, forward and backward under
-# remat as the cells run it: (tokens, choices, experts, held, width,
-# expert width, activation), and the temporaries the parent's program
-# needed for the same block (XLA's gathers over all t x k rows). Where
-# pairs can sort into a tail the row movements run ops/moe_rows.py's
-# kernels, bound by the live count: combine's forward and dispatch's
-# backward (`moe_rows_summed`) and combine's backward
-# (`moe_rows_cotangents`), and since PR 42 `act(gate) x up` and its
-# backward (`moe_rows_gated`, `moe_rows_gated_bwd`) while the grouped
-# products walk no tile of the tail; OLMoE, which holds every expert,
-# keeps XLA's gathers and fusion and the walk it had.
-EXPERT_CELLS = {
-    "smallthinker": ((16384, 6, 64, 16, 2560, 768, "relu"), 1971133440),
-    "xing4": ((8192, 4, 64, 8, 3584, 1024, "silu"), 910812160),
-    "kimi": ((8192, 8, 256, 32, 2304, 1024, "silu"), 1054416896),
-    "dots3": ((8192, 8, 256, 8, 5120, 1536, "silu"), 2630225408),
-    "olmoe": ((8192, 8, 64, None, 2048, 1024, "silu"), 675513856),
-}
-
-
-def _expert_layer(cell, sharding, mesh=None, batch=1):
-    from dlrover_tpu.models import moe
-
-    (t, k, e, held, d, f, act), _ = EXPERT_CELLS[cell]
-    cfg = moe.MoeConfig(
-        dim=d, ffn_dim=f, n_experts=e, experts_per_token=k,
-        experts_held=held, expert_act=act, n_layers=1, dtype=jnp.bfloat16,
-        param_dtype=jnp.bfloat16)
-    layers = moe.abstract_params(cfg)["layers"]
-    specs = moe.param_specs(cfg)["layers"]
-    lp = {
-        name: jax.ShapeDtypeStruct(
-            layers[name].shape[1:], layers[name].dtype,
-            sharding=sharding if mesh is None else NamedSharding(
-                mesh, P(*specs[name][1:])))
-        for name in ("router", "w_gate", "w_up", "w_down")
-    }
-    y = jax.ShapeDtypeStruct(
-        (batch, t // batch, d), jnp.bfloat16,
-        sharding=sharding if mesh is None else NamedSharding(
-            mesh, P(BATCH_AXES, None, None)))
-
-    def loss(lp, y):
-        fn = jax.checkpoint(
-            lambda lp, y: moe.moe_mlp(cfg, lp, y, mesh)[0],
-            policy=jax.checkpoint_policies.nothing_saveable)
-        return fn(lp, y).astype(jnp.float32).sum()
-
-    return jax.jit(
-        jax.value_and_grad(loss, argnums=(0, 1))).lower(lp, y).compile()
-
-
-@pytest.mark.parametrize("cell", sorted(EXPERT_CELLS))
-def test_expert_rows_fwd_bwd_compile_in_the_parents_memory(
-        one_chip, kernels_are_the_path, cell):
-    compiled = _expert_layer(cell, one_chip)
-    hlo = compiled.as_text()
-    tail = EXPERT_CELLS[cell][0][3] is not None
-    # combine's forward (the backward reads no output of it, so the
-    # remat forward has none) and dispatch's backward, and combine's
-    # backward; the three products forward, again under remat, d-lhs
-    # and d-rhs; the pass between the products forward, again under
-    # remat, and backward
-    assert _kernel_calls(hlo, "moe_rows_summed") == (2 if tail else 0)
-    assert _kernel_calls(hlo, "moe_rows_cotangents") == (1 if tail else 0)
-    assert _kernel_calls(hlo, "moe_rows_gated_bwd") == (1 if tail else 0)
-    assert _kernel_calls(hlo, "moe_rows_gated") == (3 if tail else 0)
-    assert _kernel_calls(hlo, "grouped_matmul_dlhs") == 3
-    assert _kernel_calls(hlo, "grouped_matmul") == 12
-    assert trace.gauges()["moe.rows_kernel"] == int(tail)
-    assert trace.gauges()["moe.tail_skipped"] == int(tail)
-    assert trace.gauges()["moe.row_block"] == (256 if tail else 0)
-    # every kernel under the scope the device metrics select by
-    for name in _op_names(hlo):
-        if "moe_rows_gated" in name or "grouped_matmul" in name:
-            assert _in_scope(name, "moe_experts"), name
-        elif "moe_rows_" in name:
-            assert _in_scope(name, "moe_combine") or _in_scope(
-                name, "moe_dispatch"), name
-    # no (t x k, d) array beside the parent's: the kernels' lists of
-    # int32 and float32 scalars (the live pairs, the sorted weights and
-    # their cotangent) are 0.4 MB each at 98304 pairs. The pass's
-    # backward writes over two of its operands, as XLA's fusion did, and
-    # up's d-lhs over gate's
-    parent = EXPERT_CELLS[cell][1]
-    assert compiled.memory_analysis().temp_size_in_bytes < parent + 2 * 2**20
-
-
-def test_expert_layer_over_four_chips_keeps_xlas_gathers(topo, monkeypatch):
-    """One program across the 2 x 2 mesh, ep 2: inside ``moe_mlp``'s
-    ``shard_map`` each rank holds half of the held experts and the other
-    half's pairs are its tail. That ``shard_map`` checks how values vary
-    over the mesh (tp's psum hangs on it), and the check writes a
-    ``pvary`` into a kernel's body, which Mosaic does not lower: no
-    Pallas kernel compiles inside it, the grouped products' neither. So
-    under a mesh the rows move by XLA's gathers and the products by
-    ``lax.ragged_dot``, as on the CPU meshes, and the program compiles."""
-    monkeypatch.setattr(moe_rows, "_on_tpu", lambda: True)
-    monkeypatch.setattr(dsa, "_on_tpu", lambda: True)
-    mesh = build_mesh(MeshConfig(dp=-1, ep=2), devices=list(topo.devices))
-    hlo = _expert_layer("xing4", None, mesh, batch=4).as_text()
-    assert "moe_rows_" not in hlo
-    assert trace.gauges()["moe.rows_kernel"] == 0
 
 
 def test_grouped_matmul_compiles_at_xing4_shape(
