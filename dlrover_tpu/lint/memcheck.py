@@ -158,7 +158,13 @@ def read_memory_analysis(compiled, label: str = "step") -> Dict[str, int]:
     nothing was measurable. When at least the argument/temp side is
     present a ``peak_bytes`` estimate is added: arguments + outputs +
     temp + generated code − aliased bytes (donated inputs whose buffer
-    the output reuses would otherwise be counted twice).
+    the output reuses would otherwise be counted twice). Where the
+    backend also states the peak of its own heap simulation
+    (``peak_memory_in_bytes``; absent on older jaxlib, and then simply
+    absent here), ``planned_peak_bytes`` is that peak + generated code:
+    what the compiler holds against the device's memory. The sum above
+    can overcount it (a step whose temporaries reuse donated arguments:
+    18.4 GB summed for 15.5 GB planned, PERF.md section 7, PR 45).
     """
     try:
         ma = compiled.memory_analysis()
@@ -186,6 +192,10 @@ def read_memory_analysis(compiled, label: str = "step") -> Dict[str, int]:
                        "degrading")
     if out:
         out["peak_bytes"] = measured_peak_bytes(out)
+        planned = getattr(ma, "peak_memory_in_bytes", None)
+        if isinstance(planned, int) and planned > 0:
+            out["planned_peak_bytes"] = planned + out.get(
+                "generated_code_bytes", 0)
     return out
 
 

@@ -25,7 +25,8 @@ it is a real compute path, and there is one of it:
   ``relu`` (ReGLU) on the gate.
 - **shared expert**: where the layer has ``ws_gate`` / ``ws_up`` /
   ``ws_down``, every token also passes through that dense SwiGLU
-  (``moe_shared``), added to the routed result.
+  (``moe_shared``), added to the routed result; where it also has
+  ``w_s``, under a sigmoid gate a token (Qwen3-Next).
 - **sorted dispatch** (``moe_dispatch``): the ``t * k`` (token, choice)
   pairs are ordered by expert (one stable sort of int32 keys);
   ``group_sizes (e,)`` counts each expert's pairs and the rows are
@@ -592,10 +593,15 @@ def _moe_tokens_sharded(cfg: MoeConfig, lp: Params, y, route_on=None):
 
 def _shared_expert(lp: Params, y: jnp.ndarray) -> jnp.ndarray:
     """The always-on expert: the dense SwiGLU of ``models/llama.py`` on
-    every token, partitioned as any dense matmul is."""
+    every token, partitioned as any dense matmul is; where the layer has
+    ``w_s (d, 1)``, times a gate a token, ``sigmoid(y w_s)``."""
+    trace.gauge("moe.shared_gate", int("w_s" in lp))
     with trace.scope("moe_shared"):
-        return llama.swiglu(
+        out = llama.swiglu(
             y, lp["ws_gate"], lp["ws_up"], lp["ws_down"], y.dtype)
+        if "w_s" in lp:
+            out = out * jax.nn.sigmoid(y @ lp["w_s"].astype(y.dtype))
+        return out
 
 
 def moe_mlp(

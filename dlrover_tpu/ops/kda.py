@@ -87,6 +87,33 @@ inside the chunk). The XLA form's backward is JAX's own, through
 rematerialised *segments* of 16 chunks (what autodiff would keep of the
 whole sequence, 3.4 GiB a layer at 8192 tokens, is the step's peak
 otherwise).
+
+**The second form: one decay a head** (``chunk_gdn``; Gated DeltaNet,
+Qwen3-Next). ``g_t`` is a number a value head, ``hv`` value heads in
+groups of ``hv / hk`` over ``hk`` key heads: value head ``j`` reads
+``q`` and ``k`` of key head ``j // (hv / hk)``. The decay no longer has
+to be split into factors: it is a mask on a plain product,
+
+    A_kk[i, j] = (k_i . k_j) e^(G_i - G_j)        j <  i
+    A_qk[i, j] = (q_i . k_j) e^(G_i - G_j)        j <= i
+    W_k = T Diag(b e^G) K,    T = (I + Diag(b) A_kk)^-1
+
+with ``K K^T`` and ``Q K^T`` formed once a key head and masked a value
+head, and ``q`` and ``k`` never repeated over the group. No sub-blocks
+and no reference row: ``e^(G_i - G_j) <= 1`` for ``j <= i`` whatever
+``g`` is (the exponent is never formed where it would be positive), so
+**this form is exact (to float32 rounding) for any ``g <= 0``**, where
+the channel form above holds to ``|g| <= 9`` a token; ``tests/
+test_kda.py`` holds both of its forms at ``g`` down to -21 a token (the
+public initialisation's reach) against the token-by-token recurrence.
+Its kernels (``gdn_fwd``, ``gdn_bwd``) are the kernels above but for the
+state-free part and its derivative (``_gdn_part``, ``_gdn_solve``,
+``_gdn_prep_bwd``): the inverse (``_inverse``), the walk through the
+state (``_walk_fwd``, ``_walk_bwd``), tiles and grid are shared; a grid
+step takes ``HEADS / (hv / hk)`` key heads and their value heads' chains,
+``G`` is summed in XLA before the call (``dg`` after it), ``dg`` is a
+number a row, and a key head's ``dq, dk`` are summed over its value
+heads before the products that apply them.
 """
 
 from __future__ import annotations
@@ -169,6 +196,21 @@ def _decay_products(x, k, G, dtype, strict: bool):
     return jnp.concatenate(rows, axis=-2)
 
 
+def _whole_segments(arrays, chunk: int, segment: int):
+    """``(b, s, ...)`` arrays padded to whole segments of ``segment``
+    chunks (one shorter segment where the sequence has fewer chunks):
+    the rows past the end have no decay and no step, and nothing of them
+    is read back -> ``(arrays, chunks a segment, rows added)``."""
+    s = arrays[0].shape[1]
+    seg = min(segment, -(-s // chunk))
+    pad = -s % (chunk * seg)
+    if pad:
+        arrays = tuple(
+            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+            for a in arrays)
+    return arrays, seg, pad
+
+
 def _chunk_kda_xla(q, k, v, g, beta, *, chunk: int = 64, segment: int = 16):
     """The chunked gated delta rule in XLA ops. ``q, k (b, s, h, dk)`` (``q``
     already scaled, both already normalised), ``v (b, s, h, dv)``,
@@ -185,13 +227,8 @@ def _chunk_kda_xla(q, k, v, g, beta, *, chunk: int = 64, segment: int = 16):
     dv = v.shape[-1]
     if chunk % SUB:
         raise ValueError(f"chunk_kda: chunk {chunk} is no multiple of {SUB}")
-    seg = min(segment, -(-s // chunk))
-    pad = -s % (chunk * seg)
-    if pad:
-        # rows past the end: no decay, no step, nothing read back
-        q, k, v, g, beta = (
-            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
-            for a in (q, k, v, g, beta))
+    (q, k, v, g, beta), seg, pad = _whole_segments(
+        (q, k, v, g, beta), chunk, segment)
     n_seg = (s + pad) // (chunk * seg)
     f32 = jnp.float32
 
@@ -244,6 +281,93 @@ def _chunk_kda_xla(q, k, v, g, beta, *, chunk: int = 64, segment: int = 16):
     # (n_seg, seg, b, h, C, dv) -> (b, s, h, dv)
     o = jnp.moveaxis(o, (0, 1, 3), (1, 2, 4))
     return o.reshape(b, s + pad, h, dv)[:, :s]
+
+
+GDN_SEGMENT = 16    # chunks a rematerialised segment of the per-head XLA form
+
+
+def _chunk_gdn_xla(q, k, v, g, beta, *, chunk: int = 64):
+    """The chunked gated delta rule **with one decay a head** in XLA ops
+    (the module docstring's second form). ``q, k (b, s, hk, dk)`` (``q``
+    already scaled, both already normalised), ``v (b, s, hv, dv)``, ``g,
+    beta (b, s, hv)`` float32 (``g <= 0``, any size) -> ``o (b, s, hv,
+    dv)`` in ``v``'s dtype, which is also the matmul operands'. Value
+    head ``j`` reads key head ``j // (hv / hk)``: the two ``(C, C)``
+    products are formed a key head and masked a value head, and ``q``
+    and ``k`` enter the scan as they are, never repeated. Segments (of
+    ``GDN_SEGMENT`` chunks), padding and the state as in
+    ``_chunk_kda_xla``."""
+    dtype = v.dtype
+    b, s, hk, dk = q.shape
+    hv, dv = v.shape[2:]
+    r = hv // hk
+    (q, k, v, g, beta), seg, pad = _whole_segments(
+        (q, k, v, g, beta), chunk, GDN_SEGMENT)
+    n_seg = (s + pad) // (chunk * seg)
+    f32 = jnp.float32
+
+    def chunks(a, head_axes):
+        """(b, s, heads.., ...) -> (n_seg, seg, b, heads.., C, ...)."""
+        a = a.reshape(b, n_seg, seg, chunk, *a.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(a, 0, 2), 3, 3 + head_axes)
+
+    i, j = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
+    eye = jnp.eye(chunk, dtype=f32)
+
+    def step(S, xs):
+        qc, kc, w_v, t_k, a_qk, grown, to_end, keep = xs
+        Sd = S.astype(dtype)
+        ks = jnp.einsum("bhcd,bhrde->bhrce", kc, Sd,
+                        preferred_element_type=f32)
+        qs = jnp.einsum("bhcd,bhrde->bhrce", qc, Sd,
+                        preferred_element_type=f32)
+        u = w_v - jnp.einsum("bhrcj,bhrje->bhrce", t_k, ks.astype(dtype),
+                             preferred_element_type=f32)
+        o = grown * qs + jnp.einsum("bhrcj,bhrje->bhrce", a_qk,
+                                    u.astype(dtype),
+                                    preferred_element_type=f32)
+        S = keep * S + jnp.einsum("bhcd,bhrce->bhrde", kc,
+                                  (to_end * u).astype(dtype),
+                                  preferred_element_type=f32)
+        return S, o.astype(dtype)
+
+    @jax.checkpoint
+    def one_segment(S, xs):
+        qc, kc, vc, gc, bc = xs          # (seg, b, hk, [r,] C[, d])
+        G = jnp.cumsum(gc, axis=-1)                    # (seg, b, hk, r, C)
+        # e^(G_i - G_j) for j <= i: at most 1 whatever g is; the
+        # exponent is never formed where it would be positive
+        diff = G[..., :, None] - G[..., None, :]
+        decay = jnp.where(j <= i, jnp.exp(jnp.where(j <= i, diff, 0.0)), 0.0)
+        kk = jnp.einsum("...id,...jd->...ij", kc, kc,
+                        preferred_element_type=f32)[..., None, :, :]
+        qk = jnp.einsum("...id,...jd->...ij", qc, kc,
+                        preferred_element_type=f32)[..., None, :, :]
+        bc = bc[..., None]                             # (.., C, 1)
+        grown = jnp.exp(G)[..., None]
+        # (I + Diag(b) A_kk) [W_v | T] = [Diag(b) V | I]: W_k S is
+        # T Diag(b e^G) (K S), so no W_k a value head is ever formed
+        w = lax.linalg.triangular_solve(
+            eye + bc * jnp.where(j < i, kk * decay, 0.0),
+            jnp.concatenate([bc * vc.astype(f32),
+                             jnp.broadcast_to(eye, decay.shape)], axis=-1),
+            left_side=True, lower=True, unit_diagonal=True)
+        end = G[..., -1:]
+        t_k = w[..., dv:] * jnp.swapaxes(bc * grown, -1, -2)
+        return lax.scan(step, S, (
+            qc, kc, w[..., :dv], t_k.astype(dtype),
+            (qk * decay).astype(dtype), grown,
+            jnp.exp(end - G)[..., None], jnp.exp(end)[..., None]))
+
+    _, o = lax.scan(
+        one_segment, jnp.zeros((b, hk, r, dk, dv), f32),
+        (chunks(q, 1), chunks(k, 1),
+         chunks(v.reshape(*v.shape[:2], hk, r, dv), 2),
+         chunks(g.astype(f32).reshape(*g.shape[:2], hk, r), 2),
+         chunks(beta.astype(f32).reshape(*beta.shape[:2], hk, r), 2)))
+    # (n_seg, seg, b, hk, r, C, dv) -> (b, s, hv, dv)
+    o = jnp.moveaxis(jnp.moveaxis(o, 5, 3), 2, 0)
+    return o.reshape(b, s + pad, hv, dv)[:, :s]
 
 
 # ---------------------------------------------------------------------------
@@ -378,20 +502,13 @@ def _decay_part(q, k, v, g, b_col, *, chunk: int):
             "b_row": b_row}
 
 
-@functools.partial(jax.jit, static_argnames=("chunk",), inline=True)
-def _solve_part(q, k, v, b_col, part, coef, *, chunk: int):
-    """The solve and what the chunks read. ``part``: ``_decay_part``'s;
-    ``coef (SUB, (SUB - 1) TILE)``: ``part["ltp"]`` spread by
-    ``_sel_matrix``, ``coef[j, (i - 1, s, .)] = L_s[i, j]``.
-
-    Returns ``part`` and: ``w_v`` float32, ``w_k``, ``a_qk``, ``q_in``,
-    ``k_out`` in the operands' dtype, ``keep (TILE / chunk, dk)``
-    float32, and for the backward the inverse ``t = (I + Diag(b)
-    A_kk)^-1`` and ``w_k32``, ``W_k`` before its cast."""
-    dt = v.dtype
+def _inverse(coef, under, b_col, chunk: int):
+    """``t = (I + Diag(b) A_kk)^-1`` of a tile, ``(TILE, TILE)`` float32
+    with the chunks' inverses on its diagonal. ``coef (SUB, (SUB - 1)
+    TILE)``: the diagonal sub-blocks' entries spread by ``_sel_matrix``,
+    ``coef[j, (i - 1, s, .)] = b_i A_kk[i, j]`` of sub-block ``s``;
+    ``under``: ``A_kk`` under the diagonal sub-blocks (zeros in them)."""
     n = chunk // SUB
-    G, decay = part["G"], part["decay"]
-    k32 = k.astype(_F32)
     # the inverse of the diagonal sub-blocks of I + Diag(b) A_kk, row by
     # row (forward substitution), all TILE / SUB of them at once: x[j,
     # (s, c)] is entry (j, c) of sub-block s's inverse; row i is e_i -
@@ -409,9 +526,26 @@ def _solve_part(q, k, v, b_col, part, coef, *, chunk: int):
     # (I + D N)^-1 D with D N nilpotent of index n, in Horner's form
     if n > 1:
         d = t
-        p_mat = _dot(d, b_col * part["a_kk"], _NN, exact=True)
+        p_mat = _dot(d, b_col * under, _NN, exact=True)
         for _ in range(n - 1):
             t = d - _dot(p_mat, t, _NN, exact=True)
+    return t
+
+
+@functools.partial(jax.jit, static_argnames=("chunk",), inline=True)
+def _solve_part(q, k, v, b_col, part, coef, *, chunk: int):
+    """The solve and what the chunks read. ``part``: ``_decay_part``'s;
+    ``coef (SUB, (SUB - 1) TILE)``: ``part["ltp"]`` spread by
+    ``_sel_matrix``, ``coef[j, (i - 1, s, .)] = L_s[i, j]``.
+
+    Returns ``part`` and: ``w_v`` float32, ``w_k``, ``a_qk``, ``q_in``,
+    ``k_out`` in the operands' dtype, ``keep (TILE / chunk, dk)``
+    float32, and for the backward the inverse ``t = (I + Diag(b)
+    A_kk)^-1`` and ``w_k32``, ``W_k`` before its cast."""
+    dt = v.dtype
+    G, decay = part["G"], part["decay"]
+    k32 = k.astype(_F32)
+    t = _inverse(coef, part["a_kk"], b_col, chunk)
     if dt == jnp.bfloat16:
         # T Diag(b) V with V as it is: exact in three passes
         w_v = _dot_pieces(t * part["b_row"], v, _NN)
@@ -553,29 +687,18 @@ def _head_inputs(refs, beta_ref, heads, dk, dv):
     return out
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, sel_ref, o_ref, *rest,
-                chunk, heads, dk, dv):
-    """One tile of ``heads`` heads a grid step: the state-free part,
-    then the tile's chunks through the state, the heads' chains side by
-    side for the scheduler to interleave. The state is held transposed,
-    ``(dv, dk)`` (a chunk's decay scales its lanes), in scratch through
-    a head's tiles. ``rest``: the output of a state a chunk (what each
-    chunk started from) where the backward will want it, then the
-    scratch."""
-    states_ref = rest[0] if len(rest) == 2 else None
-    s_ref = rest[-1]
+def _walk_fwd(p, s_ref, states_ref, o_ref, *, chunk, heads, dv):
+    """A tile's chunks through the state: ``p[hd]`` is what head ``hd``'s
+    state-free part gave (``_SCAN_IN`` and ``keep``, a row a chunk), the
+    state is held transposed, ``(dv, dk)`` (a chunk's decay scales its
+    lanes, or all of it), in the scratch ``s_ref`` through a head's
+    tiles; the heads' chains side by side for the scheduler to
+    interleave. ``states_ref``: where each chunk writes the state it
+    started from, or None."""
     dt = o_ref.dtype
-    per = TILE // chunk
-
-    @pl.when(pl.program_id(2) == 0)
-    def _():
-        s_ref[...] = jnp.zeros_like(s_ref)
-
-    ins = _head_inputs((q_ref, k_ref, v_ref, g_ref), beta_ref, heads, dk, dv)
-    p = _prep_heads(ins, sel_ref[...], chunk=chunk)
     st = [s_ref[hd] for hd in range(heads)]
     out = [[] for _ in range(heads)]
-    for j in range(per):
+    for j in range(TILE // chunk):
         for hd in range(heads):
             if states_ref is not None:
                 states_ref[hd, j] = st[hd]
@@ -592,25 +715,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, sel_ref, o_ref, *rest,
         s_ref[hd] = st[hd]
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, sel_ref, do_ref,
-                states_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, ds_ref,
-                *, chunk, heads, dk, dv):
-    """The forward kernel backwards: the grid walks the tiles from the
-    last. A tile: its state-free part again (with the inverse), its
-    chunks from the last through the cotangent of the (transposed)
-    state, each reading the state it started from and forming ``U``
-    again, then the state-free part's derivative."""
+def _walk_bwd(p, do_ref, states_ref, ds_ref, *, chunk, heads, dv):
+    """``_walk_fwd`` backwards: a tile's chunks from the last through
+    the cotangent of the (transposed) state, each reading the state it
+    started from and forming ``U`` again. Returns, a head, the float32
+    cotangents of what the chunks read of the state-free part (by name,
+    a list of the chunks' rows; ``keep``'s a row of ``dk`` lanes a chunk,
+    not yet summed where the decay is one number), and the state's
+    cotangent before the tile, which the caller stores."""
     dt = do_ref.dtype
     per = TILE // chunk
-
-    @pl.when(pl.program_id(2) == 0)
-    def _():
-        ds_ref[...] = jnp.zeros_like(ds_ref)
-
-    ins = _head_inputs((q_ref, k_ref, v_ref, g_ref), beta_ref, heads, dk, dv)
-    p = _prep_heads(ins, sel_ref[...], chunk=chunk)
     ds = [ds_ref[hd] for hd in range(heads)]
-    # the cotangents of what the chunks read of the state-free part
     cts = [{n: [None] * per for n in _SCAN_IN + ("keep",)}
            for _ in range(heads)]
     for j in reversed(range(per)):
@@ -633,6 +748,48 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, sel_ref, do_ref,
             ct["keep"][j] = jnp.sum(ds[hd] * st, axis=0, keepdims=True)
             ds[hd] = (p[hd]["keep"][j:j + 1] * ds[hd]
                       + _dot(do, q_in, _TN) - _dot(dud, w_k, _TN))
+    return cts, ds
+
+
+def _row(col):
+    """``col (TILE, 1)`` as a row ``(1, TILE)``: lanes are its tokens."""
+    eye = _iota((TILE, TILE), 0) == _iota((TILE, TILE), 1)
+    return jnp.sum(jnp.where(eye, col, 0.0), axis=0, keepdims=True)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, sel_ref, o_ref, *rest,
+                chunk, heads, dk, dv):
+    """One tile of ``heads`` heads a grid step: the state-free part,
+    then the tile's chunks through the state (``_walk_fwd``). ``rest``:
+    the output of a state a chunk (what each chunk started from) where
+    the backward will want it, then the scratch."""
+    states_ref = rest[0] if len(rest) == 2 else None
+    s_ref = rest[-1]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    ins = _head_inputs((q_ref, k_ref, v_ref, g_ref), beta_ref, heads, dk, dv)
+    p = _prep_heads(ins, sel_ref[...], chunk=chunk)
+    _walk_fwd(p, s_ref, states_ref, o_ref, chunk=chunk, heads=heads, dv=dv)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, sel_ref, do_ref,
+                states_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, ds_ref,
+                *, chunk, heads, dk, dv):
+    """The forward kernel backwards: the grid walks the tiles from the
+    last. A tile: its state-free part again (with the inverse), its
+    chunks from the last (``_walk_bwd``), then the state-free part's
+    derivative."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        ds_ref[...] = jnp.zeros_like(ds_ref)
+
+    ins = _head_inputs((q_ref, k_ref, v_ref, g_ref), beta_ref, heads, dk, dv)
+    p = _prep_heads(ins, sel_ref[...], chunk=chunk)
+    cts, ds = _walk_bwd(p, do_ref, states_ref, ds_ref, chunk=chunk,
+                        heads=heads, dv=dv)
     eye = _iota((TILE, TILE), 0) == _iota((TILE, TILE), 1)
     for hd in range(heads):
         ct = {n: jnp.concatenate(x, axis=0) for n, x in cts[hd].items()}
@@ -650,46 +807,55 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, sel_ref, do_ref,
         ds_ref[hd] = ds[hd]
 
 
-def _heads_a_step(h: int) -> int:
-    return max(d for d in range(1, HEADS + 1) if h % d == 0)
+def _heads_a_step(hk: int, r: int = 1) -> int:
+    """Key heads a grid step: their ``r`` value heads each (one in the
+    channel form) are the step's chains, ``HEADS`` of them where ``r``
+    divides that."""
+    return max(d for d in range(1, hk + 1)
+               if hk % d == 0 and d * r <= max(HEADS, r))
 
 
-def _call(kernel, name, arrays, more_in, out, *, chunk, backwards, interpret):
-    """``arrays``: q, k, v, g as ``(b, s, h d)`` (free reshapes: a
-    head's channels are the lanes of a block, its tokens lie where they
-    lay, nothing is transposed in HBM) and beta ``(b, s, h)``, whole
-    tiles. ``more_in`` and ``out``: ``(kind, array or its shape)``,
-    ``kind`` the lanes a head has of a ``(b, s, h kind)`` array, or the
-    block of a ``(b, h, s / TILE, ., .)`` one. The grid is (batch, heads
-    / heads a step, tiles), the last axis in order (from the end where
+def _call(kernel, name, arrays, more_in, out, *, hk, chunk, backwards,
+          interpret):
+    """``arrays``: q, k ``(b, s, hk dk)``, v ``(b, s, hv dv)`` (free
+    reshapes: a head's channels are the lanes of a block, its tokens lie
+    where they lay, nothing is transposed in HBM), the decays (``g (b,
+    s, hv dk)`` in the channel form, where ``hv = hk``; ``G (b, s, hv)``
+    in the per-head form) and beta ``(b, s, hv)``, whole tiles.
+    ``more_in`` and ``out``: ``(kind, array or its shape)``, ``kind``
+    ``("k", d)`` or ``("v", d)`` for the ``d`` lanes a key or a value
+    head has of a ``(b, s, .)`` array, or the block of a ``(b, hv, s /
+    TILE, ., .)`` one. The grid is (batch, key heads / key heads a
+    step, tiles), the last axis in order (from the end where
     ``backwards``): it carries the state."""
-    b, s, h = arrays[4].shape
+    b, s, hv = arrays[4].shape
     steps = s // TILE
-    dk, dv = arrays[0].shape[-1] // h, arrays[2].shape[-1] // h
-    heads = _heads_a_step(h)
+    dk, dv, r = arrays[0].shape[-1] // hk, arrays[2].shape[-1] // hv, hv // hk
+    heads = _heads_a_step(hk, r)
     sel = jnp.asarray(_sel_matrix(), jnp.bfloat16)
 
     def at(ti):
         return steps - 1 - ti if backwards else ti
 
     def spec(kind):
-        if isinstance(kind, int):
-            return pl.BlockSpec((None, TILE, heads * kind),
+        if isinstance(kind[0], str):
+            lanes = heads * kind[1] * (r if kind[0] == "v" else 1)
+            return pl.BlockSpec((None, TILE, lanes),
                                 lambda bi, hi, ti: (bi, at(ti), hi))
-        return pl.BlockSpec((None, heads) + kind,
+        return pl.BlockSpec((None, heads * r) + kind,
                             lambda bi, hi, ti: (bi, hi, at(ti), 0, 0))
 
-    in_specs = [spec(dk), spec(dk), spec(dv), spec(dk),
-                pl.BlockSpec((None, TILE, h),
-                             lambda bi, hi, ti: (bi, at(ti), 0)),
-                pl.BlockSpec(sel.shape, lambda bi, hi, ti: (0, 0))]
+    whole = pl.BlockSpec((None, TILE, hv), lambda bi, hi, ti: (bi, at(ti), 0))
+    decays = whole if arrays[3].shape[-1] == hv else spec(("k", dk))
+    in_specs = [spec(("k", dk)), spec(("k", dk)), spec(("v", dv)), decays,
+                whole, pl.BlockSpec(sel.shape, lambda bi, hi, ti: (0, 0))]
     return pl.pallas_call(
         functools.partial(kernel, chunk=chunk, heads=heads, dk=dk, dv=dv),
-        grid=(b, h // heads, steps),
+        grid=(b, hk // heads, steps),
         in_specs=in_specs + [spec(kind) for kind, _ in more_in],
         out_specs=[spec(kind) for kind, _ in out],
         out_shape=[shape for _, shape in out],
-        scratch_shapes=[pltpu.VMEM((heads, dv, dk), _F32)],
+        scratch_shapes=[pltpu.VMEM((heads * r, dv, dk), _F32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
@@ -708,46 +874,82 @@ def _tiles(arrays, s):
 # Both calls are jitted (and inlined where they are called): a kernel's
 # body is some thousand operations to trace, the step calls each kernel
 # once a run of like layers, and jit's cache traces it once a shape.
+# They serve both forms of the rule, which ``g`` tells apart: a decay a
+# channel ``(b, s, h, dk)`` (``kda_fwd``, ``kda_bwd`` under the scope
+# ``kda_chunk``), or a decay a head ``(b, s, hv)`` over ``hv / hk`` value
+# heads a key head (``gdn_fwd``, ``gdn_bwd`` under ``gdn_chunk``), which
+# the kernels read summed inside each chunk (``_chunk_sums``; their
+# ``dG`` is summed back the other way).
+
+def _form(g):
+    """``(forward kernel, backward kernel, their names' stem)``."""
+    if g.ndim == 3:
+        return _gdn_fwd_kernel, _gdn_bwd_kernel, "gdn"
+    return _fwd_kernel, _bwd_kernel, "kda"
+
+
+def _chunk_sums(x, chunk: int, reverse: bool = False):
+    """``x (b, s, h)``: the cumulative sums inside each chunk of rows."""
+    b, s, h = x.shape
+    return lax.cumsum(x.reshape(b, s // chunk, chunk, h), axis=2,
+                      reverse=reverse).reshape(b, s, h)
+
+
+def _kernel_operands(arrays, s, chunk):
+    """``[q, k, v, g, beta, ...]`` as the kernels read them (``_tiles``)."""
+    per_head = arrays[3].ndim == 3
+    arrays = _tiles([a.astype(_F32) if i in (3, 4) else a
+                     for i, a in enumerate(arrays)], s)
+    if per_head:
+        arrays[3] = _chunk_sums(arrays[3], chunk)
+    return arrays
+
 
 @functools.partial(jax.jit, static_argnums=(5, 6, 7), inline=True)
-def _kda_forward(q, k, v, g, beta, chunk, interpret, states: bool):
-    b, s, h, dk = q.shape
-    dv = v.shape[-1]
-    arrays = _tiles([q, k, v, g.astype(_F32), beta.astype(_F32)], s)
+def _rule_forward(q, k, v, g, beta, chunk, interpret, states: bool):
+    b, s, hk, dk = q.shape
+    hv, dv = v.shape[2:]
+    kernel, _, stem = _form(g)
+    arrays = _kernel_operands([q, k, v, g, beta], s, chunk)
     padded = arrays[0].shape[1]
-    out = [(dv, jax.ShapeDtypeStruct((b, padded, h * dv), v.dtype))]
+    out = [(("v", dv), jax.ShapeDtypeStruct((b, padded, hv * dv), v.dtype))]
     if states:
         out.append(((TILE // chunk, dv, dk), jax.ShapeDtypeStruct(
-            (b, h, padded // chunk, dv, dk), _F32)))
-    o, *kept = _call(_fwd_kernel, "kda_fwd", arrays, (), out, chunk=chunk,
-                     backwards=False, interpret=interpret)
-    return (o[:, :s].reshape(b, s, h, dv), *kept)
+            (b, hv, padded // chunk, dv, dk), _F32)))
+    o, *kept = _call(kernel, stem + "_fwd", arrays, (), out, hk=hk,
+                     chunk=chunk, backwards=False, interpret=interpret)
+    return (o[:, :s].reshape(b, s, hv, dv), *kept)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1), inline=True)
-def _kda_backward(chunk, interpret, res, do):
+def _rule_backward(chunk, interpret, res, do):
     q, k, v, g, beta, states = res
-    b, s, h, dk = q.shape
-    dv = v.shape[-1]
+    b, s, hk, dk = q.shape
+    hv, dv = v.shape[2:]
+    _, kernel, stem = _form(g)
     # a custom_vjp's backward is traced outside the caller's scopes: the
     # device metrics find the op by this one
-    with trace.scope("kda_chunk"):
-        *arrays, do = _tiles(
-            [q, k, v, g.astype(_F32), beta.astype(_F32), do], s)
+    with trace.scope(stem + "_chunk"):
+        *arrays, do = _kernel_operands([q, k, v, g, beta, do], s, chunk)
         padded = do.shape[1]
 
-        def flat(d, dtype):
-            return d, jax.ShapeDtypeStruct((b, padded, h * d), dtype)
+        def flat(side, d, heads, dtype):
+            return (side, d), jax.ShapeDtypeStruct((b, padded, heads * d),
+                                                   dtype)
 
+        row = ((1, 1, TILE), jax.ShapeDtypeStruct(
+            (b, hv, padded // TILE, 1, TILE), _F32))
         dq, dk_, dv_, dg, dbeta = _call(
-            _bwd_kernel, "kda_bwd", arrays,
-            [(dv, do), ((TILE // chunk, dv, dk), states)],
-            [flat(dk, q.dtype), flat(dk, k.dtype), flat(dv, v.dtype),
-             flat(dk, _F32),
-             ((1, 1, TILE), jax.ShapeDtypeStruct(
-                 (b, h, padded // TILE, 1, TILE), _F32))],
-            chunk=chunk, backwards=True, interpret=interpret)
-        dbeta = jnp.swapaxes(dbeta.reshape(b, h, padded), 1, 2)
+            kernel, stem + "_bwd", arrays,
+            [(("v", dv), do), ((TILE // chunk, dv, dk), states)],
+            [flat("k", dk, hk, q.dtype), flat("k", dk, hk, k.dtype),
+             flat("v", dv, hv, v.dtype),
+             row if g.ndim == 3 else flat("k", dk, hk, _F32), row],
+            hk=hk, chunk=chunk, backwards=True, interpret=interpret)
+        dbeta = jnp.swapaxes(dbeta.reshape(b, hv, padded), 1, 2)
+        if g.ndim == 3:
+            dg = _chunk_sums(jnp.swapaxes(dg.reshape(b, hv, padded), 1, 2),
+                             chunk, reverse=True)
     return (dq[:, :s].reshape(q.shape), dk_[:, :s].reshape(k.shape),
             dv_[:, :s].reshape(v.shape),
             dg[:, :s].reshape(g.shape).astype(g.dtype),
@@ -755,16 +957,16 @@ def _kda_backward(chunk, interpret, res, do):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _kda_kernels(q, k, v, g, beta, chunk, interpret):
-    return _kda_forward(q, k, v, g, beta, chunk, interpret, False)[0]
+def _rule_kernels(q, k, v, g, beta, chunk, interpret):
+    return _rule_forward(q, k, v, g, beta, chunk, interpret, False)[0]
 
 
-def _kda_kernels_fwd(q, k, v, g, beta, chunk, interpret):
-    o, states = _kda_forward(q, k, v, g, beta, chunk, interpret, True)
+def _rule_kernels_fwd(q, k, v, g, beta, chunk, interpret):
+    o, states = _rule_forward(q, k, v, g, beta, chunk, interpret, True)
     return o, (q, k, v, g, beta, states)
 
 
-_kda_kernels.defvjp(_kda_kernels_fwd, _kda_backward)
+_rule_kernels.defvjp(_rule_kernels_fwd, _rule_backward)
 
 
 def _over_batch_rows(fn, mesh, args, replicated, out_specs):
@@ -813,7 +1015,227 @@ def chunk_kda(q, k, v, g, beta, *, chunk: int = 64, segment: int = 16,
     trace.gauge("kda.chunks_per_step", TILE // chunk)
 
     def kernels(*args):
-        return _kda_kernels(*args, chunk, interpret)
+        return _rule_kernels(*args, chunk, interpret)
+
+    return _over_batch_rows(kernels, mesh, (q, k, v, g, beta), (),
+                            P(BATCH_AXES, None, None, None))
+
+
+# ---------------------------------------------------------------------------
+# The same rule with one decay a head (Gated DeltaNet): the module
+# docstring's second form. The walk through the state, the inverse, the
+# grid and the calls' wrappers (``_rule_forward``, ``_rule_backward``)
+# are the kernels' above; the state-free part and its derivative are
+# these.
+# ---------------------------------------------------------------------------
+
+def _gdn_part(products, G, b_col, *, chunk: int):
+    """A value head's state-free part up to the solve. ``products``:
+    ``kk = K K^T`` and ``qk = Q K^T (TILE, TILE)`` float32 of its key
+    head, formed once a key head; ``G (TILE, 1)`` float32: the
+    cumulative log-decay inside each chunk; ``b_col (TILE, 1)``. Here
+    the products meet the mask ``e^(G_i - G_j)``, at most 1 whatever
+    ``g`` is."""
+    same_sub, same_chunk, _, _, r, c = _masks(chunk)
+    kk, qk = products["kk"], products["qk"]
+    G_row, b_row = _row(G), _row(b_col)
+    decay = jnp.exp(G)
+    mask = jnp.where(same_chunk & (c <= r),
+                     jnp.exp(jnp.minimum(G - G_row, 0.0)), 0.0)
+    a_kk = jnp.where(c < r, kk * mask, 0.0)
+    # transposed, as the substitution takes its rows: [j, i] = b_i
+    # A_kk[i, j], i's sub-block = j's, j < i (kk is symmetric)
+    lt = jnp.where(same_sub & (c > r),
+                   kk * jnp.exp(jnp.minimum(G_row - G, 0.0)), 0.0) * b_row
+    ltp = lt[:SUB]
+    for s in range(1, TILE // SUB):
+        ltp = ltp + lt[s * SUB:(s + 1) * SUB]
+    lasts = range(chunk - 1, TILE, chunk)
+    return {"a_kk": a_kk, "a_qk32": qk * mask, "mask": mask, "ltp": ltp,
+            "G": G, "decay": decay, "b_row": b_row,
+            "decay_row": jnp.exp(G_row),
+            "to_end": jnp.exp(_rows_of(G, lasts, chunk) - G)}
+
+
+def _gdn_solve(q, k, v, b_col, part, coef, *, chunk: int):
+    """The solve and what the chunks read, as ``_solve_part`` returns
+    them: ``W_k = T Diag(b e^G) K``, the decays a number a row."""
+    dt = v.dtype
+    same_sub = _masks(chunk)[0]
+    q32, k32 = q.astype(_F32), k.astype(_F32)
+    t = _inverse(coef, jnp.where(same_sub, 0.0, part["a_kk"]), b_col, chunk)
+    t_v, t_k = t * part["b_row"], t * (part["b_row"] * part["decay_row"])
+    if dt == jnp.bfloat16:
+        # the float32 factor in three pieces, V and K as they are
+        w_v, w_k = _dot_pieces(t_v, v, _NN), _dot_pieces(t_k, k, _NN)
+    else:
+        w_v = _dot(t_v, v.astype(_F32), _NN, exact=True)
+        w_k = _dot(t_k, k32, _NN, exact=True)
+    return dict(
+        part, t=t, w_v=w_v, w_k32=w_k, w_k=w_k.astype(dt),
+        a_qk=part["a_qk32"].astype(dt),
+        q_in=(q32 * part["decay"]).astype(dt),
+        k_out=(k32 * part["to_end"]).astype(dt),
+        # a chunk's whole decay, over the state's lanes
+        keep=jnp.concatenate([
+            jnp.broadcast_to(part["decay"][at:at + 1], (1, k.shape[1]))
+            for at in range(chunk - 1, TILE, chunk)], axis=0))
+
+
+def _gdn_prep(q_ref, k_ref, v_ref, G_ref, beta_ref, sel, *, chunk, heads, r,
+              dk, dv):
+    """A grid step's state-free parts: ``heads`` key heads, ``r`` value
+    heads each, in the value heads' order. Returns ``(ins, p)``: a value
+    head's ``(q, k, v, G, b_col, products)`` and what ``_walk_fwd``
+    reads of it."""
+    first = pl.program_id(1) * heads * r
+    ins = []
+    for kh in range(heads):
+        q, k = (ref[:, kh * dk:(kh + 1) * dk] for ref in (q_ref, k_ref))
+        products = {"kk": _dot(k, k, _NT), "qk": _dot(q, k, _NT)}
+        for j in range(kh * r, (kh + 1) * r):
+            ins.append((q, k, v_ref[:, j * dv:(j + 1) * dv],
+                        _head_column(G_ref, first + j),
+                        _head_column(beta_ref, first + j), products))
+    parts = [_gdn_part(products, G, b_col, chunk=chunk)
+             for _, _, _, G, b_col, products in ins]
+    coef = _dot_pieces(
+        jnp.concatenate([p["ltp"] for p in parts], axis=0), sel, _NN)
+    return ins, [
+        _gdn_solve(q, k, v, b_col, part, coef[j * SUB:(j + 1) * SUB],
+                   chunk=chunk)
+        for j, ((q, k, v, _, b_col, _), part) in enumerate(zip(ins, parts))]
+
+
+def _gdn_prep_bwd(q, k, v, b_col, p, ct, *, chunk: int):
+    """The derivative of a value head's state-free part: its inputs,
+    ``p`` (``_gdn_solve``'s), ``ct`` as ``_prep_bwd_tile`` takes them ->
+    ``dv`` float32, the rows ``dG, dbeta (1, TILE)``, and what its key
+    head sums over its value heads before one set of products: the
+    cotangents of ``kk`` and ``qk`` and the direct parts of ``dq, dk``.
+
+    The solve as in ``_prep_bwd_tile``. The mask ``D[i, j] = e^(G_i -
+    G_j)`` gives ``dG_i += sum_j M[i, j]`` and ``dG_j -= sum_i M[i,
+    j]``, ``M = dA_kk A_kk + dA_qk A_qk``."""
+    _, same_chunk, _, _, r, c = _masks(chunk)
+    q32, k32, v32 = (a.astype(_F32) for a in (q, k, v))
+    dw_v, dw_k, da_qk, dq_in, dk_out, dkeep = (
+        ct[n] for n in ("w_v", "w_k", "a_qk", "q_in", "k_out", "keep"))
+    G, decay, to_end, mask = (p[n] for n in ("G", "decay", "to_end", "mask"))
+    t, w_v, w_k, a_kk = p["t"], p["w_v"], p["w_k32"], p["a_kk"]
+
+    def lanes(x):
+        return jnp.sum(x, axis=1, keepdims=True)
+
+    # the solve
+    d_rv = _dot(t, dw_v, _TN, exact=True)
+    d_rk = _dot(t, dw_k, _TN, exact=True)
+    dl = jnp.where(
+        same_chunk & (c < r),
+        -(_dot(d_rv, w_v, _NT, exact=True) + _dot(d_rk, w_k, _NT, exact=True)),
+        0.0)
+    rk = lanes(d_rk * k32) * decay
+    db = lanes(dl * a_kk) + lanes(d_rv * v32) + rk
+    da_kk = b_col * dl
+    dv32 = b_col * d_rv
+    dk32 = (b_col * decay) * d_rk
+    dG = b_col * rk
+
+    # what the scan reads: Q e^G, K e^(G_C - G), e^G_C
+    dq32 = dq_in * decay
+    dG = dG + lanes(dq_in * q32) * decay
+    e = lanes(dk_out * k32) * to_end
+    dk32 = dk32 + dk_out * to_end
+    dG = dG - e
+    row = _iota(G.shape, 0)
+    for i, last in enumerate(range(chunk - 1, TILE, chunk)):
+        total = (jnp.sum(e[last + 1 - chunk:last + 1], axis=0, keepdims=True)
+                 + lanes(dkeep[i:i + 1]) * jnp.exp(G[last:last + 1]))
+        dG = dG + jnp.where(row == last, total, 0.0)
+
+    # the masked products
+    m = da_kk * a_kk + da_qk * p["a_qk32"]
+    dG_row = _row(dG + lanes(m)) - jnp.sum(m, axis=0, keepdims=True)
+    return (dq32, dk32, dv32, dG_row, _row(db), da_kk * mask, da_qk * mask)
+
+
+def _gdn_fwd_kernel(q_ref, k_ref, v_ref, G_ref, beta_ref, sel_ref, o_ref,
+                    *rest, chunk, heads, dk, dv):
+    """``_fwd_kernel`` for the per-head form: a tile of ``heads`` key
+    heads and their ``heads r`` value heads' chains a grid step."""
+    states_ref = rest[0] if len(rest) == 2 else None
+    s_ref = rest[-1]
+    r = s_ref.shape[0] // heads
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    _, p = _gdn_prep(q_ref, k_ref, v_ref, G_ref, beta_ref, sel_ref[...],
+                     chunk=chunk, heads=heads, r=r, dk=dk, dv=dv)
+    _walk_fwd(p, s_ref, states_ref, o_ref, chunk=chunk, heads=heads * r,
+              dv=dv)
+
+
+def _gdn_bwd_kernel(q_ref, k_ref, v_ref, G_ref, beta_ref, sel_ref, do_ref,
+                    states_ref, dq_ref, dk_ref, dv_ref, dG_ref, dbeta_ref,
+                    ds_ref, *, chunk, heads, dk, dv):
+    """``_bwd_kernel`` for the per-head form. A key head's ``dq, dk``
+    are the sums over its value heads: the cotangents of its two
+    products are summed first, then applied once."""
+    dt = do_ref.dtype
+    r = ds_ref.shape[0] // heads
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        ds_ref[...] = jnp.zeros_like(ds_ref)
+
+    ins, p = _gdn_prep(q_ref, k_ref, v_ref, G_ref, beta_ref, sel_ref[...],
+                       chunk=chunk, heads=heads, r=r, dk=dk, dv=dv)
+    cts, ds = _walk_bwd(p, do_ref, states_ref, ds_ref, chunk=chunk,
+                        heads=heads * r, dv=dv)
+    for kh in range(heads):
+        total = None
+        for j in range(kh * r, (kh + 1) * r):
+            ct = {n: jnp.concatenate(x, axis=0) for n, x in cts[j].items()}
+            q, k, v, _, b_col, _ = ins[j]
+            dq, dk_, dv_, dG, db, dkk, dqk = _gdn_prep_bwd(
+                q, k, v, b_col, p[j], ct, chunk=chunk)
+            dv_ref[:, j * dv:(j + 1) * dv] = dv_.astype(dv_ref.dtype)
+            dG_ref[j, 0] = dG
+            dbeta_ref[j, 0] = db
+            ds_ref[j] = ds[j]
+            mine = (dq, dk_, dkk, dqk)
+            total = mine if total is None else tuple(
+                a + b for a, b in zip(total, mine))
+        dq, dk_, dkk, dqk = total
+        dkk, dqk = dkk.astype(dt), dqk.astype(dt)
+        kc = slice(kh * dk, (kh + 1) * dk)
+        dq_ref[:, kc] = (dq + _dot(dqk, k, _NN)).astype(dq_ref.dtype)
+        dk_ref[:, kc] = (dk_ + _dot(dkk, k, _NN) + _dot(dkk, k, _TN)
+                         + _dot(dqk, q, _TN)).astype(dk_ref.dtype)
+
+
+def chunk_gdn(q, k, v, g, beta, *, chunk: int = 64,
+              interpret: bool = False, mesh: Optional[Mesh] = None):
+    """The chunked gated delta rule with **one decay a head** over
+    grouped value heads (Gated DeltaNet): ``q, k (b, s, hk, dk)`` (``q``
+    already scaled, both already normalised), ``v (b, s, hv, dv)``, ``g,
+    beta (b, s, hv)`` float32 -> ``o (b, s, hv, dv)`` in ``v``'s dtype;
+    value head ``j`` reads key head ``j // (hv / hk)``. Exact for any
+    ``g <= 0``. The choice of form, ``interpret`` and
+    ``mesh`` as in ``chunk_kda`` (kernels ``gdn_fwd``, ``gdn_bwd``); the
+    gauge ``attn.gdn_kernel`` says which form the traced step took."""
+    if v.shape[2] % q.shape[2]:
+        raise ValueError(f"chunk_gdn: {v.shape[2]} value heads over "
+                         f"{q.shape[2]} key heads")
+    if not ((interpret or _on_tpu()) and chunk in KERNEL_CHUNKS):
+        trace.gauge("attn.gdn_kernel", 0)
+        return _chunk_gdn_xla(q, k, v, g, beta, chunk=chunk)
+    trace.gauge("attn.gdn_kernel", 1)
+
+    def kernels(*args):
+        return _rule_kernels(*args, chunk, interpret)
 
     return _over_batch_rows(kernels, mesh, (q, k, v, g, beta), (),
                             P(BATCH_AXES, None, None, None))
@@ -849,10 +1271,13 @@ def _conv_silu_norm_xla(xs, taps, heads, scales):
     return tuple(out)
 
 
-def _norm_gate_xla(o, gate, weight, eps):
+_GATES = {"sigmoid": jax.nn.sigmoid, "silu": jax.nn.silu}
+
+
+def _norm_gate_xla(o, gate, weight, eps, act="sigmoid"):
     b, s = o.shape[:2]
     o = rms_norm(o, weight, eps)
-    o = (o.astype(_F32) * jax.nn.sigmoid(gate.astype(_F32))).astype(o.dtype)
+    o = (o.astype(_F32) * _GATES[act](gate.astype(_F32))).astype(o.dtype)
     return o.reshape(b, s, -1)
 
 
@@ -1065,12 +1490,12 @@ def _in_forward(xs, taps, heads, scales, interpret):
     return tuple(o[:, :s].reshape(b, s, heads, -1) for o in out)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2), inline=True)
-def _in_backward(heads, scales, interpret, res, cts):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3), inline=True)
+def _in_backward(heads, scales, interpret, scope, res, cts):
     xs, taps = res
     b, s, width = xs[0].shape
     # a custom_vjp's backward is traced outside the caller's scopes
-    with trace.scope("kda_conv"):
+    with trace.scope(scope):
         rows, tiled = _taps_rows(taps), _io_tiles(xs)
         ins = []
         for x, dout in zip(tiled, _io_tiles(cts)):
@@ -1087,29 +1512,43 @@ def _in_backward(heads, scales, interpret, res, cts):
             tuple(dw.T.astype(w.dtype) for dw, w in zip(dtaps, taps)))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
-def _in_pass(xs, taps, heads, scales, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
+def _in_pass(xs, taps, heads, scales, interpret, scope):
     return _in_forward(xs, taps, heads, scales, interpret)
 
 
-def _in_pass_fwd(xs, taps, heads, scales, interpret):
+def _in_pass_fwd(xs, taps, heads, scales, interpret, scope):
     return _in_forward(xs, taps, heads, scales, interpret), (xs, taps)
 
 
 _in_pass.defvjp(_in_pass_fwd, _in_backward)
 
 
-def _out_fwd_kernel(o_ref, gate_ref, w_ref, out_ref, *, d, eps):
+def _gate_act(gate, sig, act: str):
+    """The output gate from ``sig = sigmoid(gate)``: ``sigmoid`` (KDA)
+    or ``silu`` (Gated DeltaNet)."""
+    return sig if act == "sigmoid" else gate * sig
+
+
+def _gate_slope(gate, sig, act: str):
+    """Its derivative."""
+    if act == "sigmoid":
+        return sig * (1.0 - sig)
+    return sig * (1.0 + gate * (1.0 - sig))
+
+
+def _out_fwd_kernel(o_ref, gate_ref, w_ref, out_ref, *, d, eps, act):
     for at in range(0, o_ref.shape[1], d):
         o, gate, w = (r[:, at:at + d].astype(_F32)
                       for r in (o_ref, gate_ref, w_ref))
         r = lax.rsqrt(_lane_sum(o * o) * (1.0 / d) + eps)
-        out_ref[:, at:at + d] = (o * r * w * _sigmoid(gate)).astype(
-            out_ref.dtype)
+        out_ref[:, at:at + d] = (
+            o * r * w * _gate_act(gate, _sigmoid(gate), act)).astype(
+                out_ref.dtype)
 
 
 def _out_bwd_kernel(dout_ref, o_ref, gate_ref, w_ref, do_ref, dgate_ref,
-                    dw_ref, *, d, eps):
+                    dw_ref, *, d, eps, act):
     """The norm is formed again from ``o``; the weight's gradient
     (float32) is summed over the tiles, a head's lanes each."""
     @pl.when(pl.program_id(2) == 0)
@@ -1121,11 +1560,12 @@ def _out_bwd_kernel(dout_ref, o_ref, gate_ref, w_ref, do_ref, dgate_ref,
                             for r in (dout_ref, o_ref, gate_ref, w_ref))
         r = lax.rsqrt(_lane_sum(o * o) * (1.0 / d) + eps)
         n, sig = o * r, _sigmoid(gate)
-        dn_w = dout * n                    # the cotangent of w sigmoid(gate)
+        gated = _gate_act(gate, sig, act)
+        dn_w = dout * n                    # the cotangent of w act(gate)
         dgate_ref[:, at:at + d] = (
-            dn_w * w * (sig * (1.0 - sig))).astype(dgate_ref.dtype)
-        dw_ref[:, at:at + d] += jnp.sum(dn_w * sig, axis=0, keepdims=True)
-        dn = dout * (w * sig)
+            dn_w * w * _gate_slope(gate, sig, act)).astype(dgate_ref.dtype)
+        dw_ref[:, at:at + d] += jnp.sum(dn_w * gated, axis=0, keepdims=True)
+        dn = dout * (w * gated)
         do_ref[:, at:at + d] = (r * (
             dn - n * (_lane_sum(dn * n) * (1.0 / d)))
         ).astype(do_ref.dtype)
@@ -1136,25 +1576,27 @@ def _weight_row(weight, heads):
     return jnp.tile(weight.astype(_F32), heads)[None]
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4), inline=True)
-def _out_forward(o, gate, weight, eps, interpret):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5), inline=True)
+def _out_forward(o, gate, weight, eps, interpret, act):
     b, s, heads, d = o.shape
     o, gate = _io_tiles([o, gate])
     out, = _io_call(
-        functools.partial(_out_fwd_kernel, d=d, eps=eps), "kda_out_fwd",
+        functools.partial(_out_fwd_kernel, d=d, eps=eps, act=act),
+        "kda_out_fwd",
         [("tile", o), ("tile", gate), ("lanes", _weight_row(weight, heads))],
         [("tile", _like(o))], heads=heads, interpret=interpret)
     return out[:, :s]
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1), inline=True)
-def _out_backward(eps, interpret, res, dout):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3), inline=True)
+def _out_backward(eps, interpret, act, scope, res, dout):
     o, gate, weight = res
     b, s, heads, d = o.shape
-    with trace.scope("kda_out"):
+    with trace.scope(scope):
         tiled = _io_tiles([dout, o, gate])
         do, dgate, dw = _io_call(
-            functools.partial(_out_bwd_kernel, d=d, eps=eps), "kda_out_bwd",
+            functools.partial(_out_bwd_kernel, d=d, eps=eps, act=act),
+            "kda_out_bwd",
             [("tile", x) for x in tiled]
             + [("lanes", _weight_row(weight, heads))],
             [("tile", _like(tiled[1])), ("tile", _like(tiled[2])),
@@ -1165,13 +1607,14 @@ def _out_backward(eps, interpret, res, dout):
             dw.astype(weight.dtype))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _out_pass(o, gate, weight, eps, interpret):
-    return _out_forward(o, gate, weight, eps, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _out_pass(o, gate, weight, eps, interpret, act, scope):
+    return _out_forward(o, gate, weight, eps, interpret, act)
 
 
-def _out_pass_fwd(o, gate, weight, eps, interpret):
-    return _out_forward(o, gate, weight, eps, interpret), (o, gate, weight)
+def _out_pass_fwd(o, gate, weight, eps, interpret, act, scope):
+    return (_out_forward(o, gate, weight, eps, interpret, act),
+            (o, gate, weight))
 
 
 _out_pass.defvjp(_out_pass_fwd, _out_backward)
@@ -1185,7 +1628,8 @@ def _io_fused(interpret: bool, d: int) -> bool:
     return fused
 
 
-def conv_silu_norm(xs, taps, *, heads: int, scales, interpret: bool = False,
+def conv_silu_norm(xs, taps, *, heads: int, scales,
+                   scope: str = "kda_conv", interpret: bool = False,
                    mesh: Optional[Mesh] = None):
     """What a KDA layer does to its projections before the delta rule.
     ``xs``: the projections, ``(b, s, h d)`` each; ``taps``: each one's
@@ -1198,25 +1642,35 @@ def conv_silu_norm(xs, taps, *, heads: int, scales, interpret: bool = False,
     Pallas pass each way: float32 from the load to the one store, the
     backward forms the pre-activation again from the projections. Else
     XLA's ops, which round to ``x``'s dtype after the convolution and
-    after the SiLU. ``mesh`` as in ``chunk_kda``."""
+    after the SiLU. ``mesh`` as in ``chunk_kda``. ``scope``: the named
+    scope the caller holds this under, which the hand-written backward
+    opens again (it is traced outside the caller's). Arrays of one call
+    have one width: a layer whose v has more heads than its q and k (a
+    Gated DeltaNet) makes two calls, v's with ``scales=(None,)``."""
     xs, taps, scales = tuple(xs), tuple(taps), tuple(scales)
     if not _io_fused(interpret, xs[0].shape[-1] // heads):
         return _conv_silu_norm_xla(xs, taps, heads, scales)
     wide = P(BATCH_AXES, None, None, None)
     return _over_batch_rows(
-        lambda xs, taps: _in_pass(xs, taps, heads, scales, interpret),
+        lambda xs, taps: _in_pass(xs, taps, heads, scales, interpret,
+                                  scope),
         mesh, (xs,), (taps,), (wide,) * len(xs))
 
 
-def norm_gate(o, gate, weight, eps: float, *, interpret: bool = False,
+def norm_gate(o, gate, weight, eps: float, *, act: str = "sigmoid",
+              scope: str = "kda_out", interpret: bool = False,
               mesh: Optional[Mesh] = None):
-    """What a KDA layer does to the delta rule's output: ``o, gate (b,
-    s, h, d)``, ``weight (d,)`` -> ``RMSNorm_d(o) weight sigmoid(gate)``
-    as ``(b, s, h d)`` in ``o``'s dtype. The same choice of form as
-    ``conv_silu_norm``; the XLA form rounds the norm to ``o``'s dtype
-    before the gate."""
+    """What the layer does to the delta rule's output: ``o, gate (b, s,
+    h, d)``, ``weight (d,)`` -> ``RMSNorm_d(o) weight act(gate)`` as
+    ``(b, s, h d)`` in ``o``'s dtype; ``act`` is ``"sigmoid"`` (KDA) or
+    ``"silu"`` (Gated DeltaNet); ``scope`` as in ``conv_silu_norm``. The
+    same choice of form as ``conv_silu_norm``; the XLA form rounds the
+    norm to ``o``'s dtype before the gate."""
+    if act not in _GATES:
+        raise ValueError(f"norm_gate: act={act!r}: one of {sorted(_GATES)}")
     if not _io_fused(interpret, o.shape[-1]):
-        return _norm_gate_xla(o, gate, weight, eps)
+        return _norm_gate_xla(o, gate, weight, eps, act)
     return _over_batch_rows(
-        lambda o, gate, weight: _out_pass(o, gate, weight, eps, interpret),
+        lambda o, gate, weight: _out_pass(
+            o, gate, weight, eps, interpret, act, scope),
         mesh, (o, gate), (weight,), P(BATCH_AXES, None, None))

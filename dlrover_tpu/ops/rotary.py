@@ -50,10 +50,18 @@ def yarn_mscale(factor: float, mscale: float) -> float:
 
 
 def apply_rope(
-    x: jnp.ndarray,          # (..., seq, n_heads, head_dim): rotated whole
+    x: jnp.ndarray,          # (..., seq, n_heads, head_dim)
     positions: jnp.ndarray,  # (..., seq) int32 global positions
-    inv_freq: jnp.ndarray,   # (head_dim // 2,)
+    inv_freq: jnp.ndarray,   # (rotary_dim // 2,)
 ) -> jnp.ndarray:
+    """Rotary on a head's first ``2 len(inv_freq)`` channels, their first
+    half against their second; the whole head where that is its width,
+    the channels past it unrotated (a partial rotary: Qwen3-Next turns a
+    quarter of a 256-wide head)."""
+    rotary_dim = 2 * inv_freq.shape[0]
+    if rotary_dim < x.shape[-1]:
+        turned = apply_rope(x[..., :rotary_dim], positions, inv_freq)
+        return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     angles = positions[..., :, None].astype(jnp.float32) * inv_freq  # (...,s,d/2)
     cos = jnp.cos(angles)[..., :, None, :]  # broadcast over heads
     sin = jnp.sin(angles)[..., :, None, :]

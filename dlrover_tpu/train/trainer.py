@@ -1395,7 +1395,8 @@ class ElasticTrainer:
         from dlrover_tpu.lint import memcheck
 
         measured = memcheck.read_memory_analysis(fn)
-        for key in ("peak_bytes", "temp_bytes", "argument_bytes"):
+        for key in ("peak_bytes", "planned_peak_bytes", "temp_bytes",
+                    "argument_bytes"):
             if key in measured:
                 trace.gauge(f"step.hbm_{key}", measured[key])
         # a device trace names instructions, not the scopes they came

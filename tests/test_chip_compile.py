@@ -301,6 +301,42 @@ def test_chunked_delta_rule_kernels_compile_in_the_same_memory(
     assert trace.gauges()["kda.chunks_per_step"] == 2
 
 
+def _gdn_args(sharding, seq=16384):
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    return [arg((1, seq, 16, 128), jnp.bfloat16)] * 2 + [
+        arg((1, seq, 32, 128), jnp.bfloat16),
+        arg((1, seq, 32), jnp.float32), arg((1, seq, 32), jnp.float32)]
+
+
+def _gdn_loss(*a):
+    with jax.named_scope("gdn_chunk"):          # as gdn_attention calls it
+        o = kda.chunk_gdn(*a, chunk=64)
+    return o.astype(jnp.float32).sum()
+
+
+def test_per_head_delta_rule_kernels_compile_at_the_cells_shapes(
+        one_chip, kernels_are_the_path):
+    """The per-head form (one decay a head, 32 value heads over 16 key
+    heads, 16384 tokens: the qwen3next cell's layer): one call forward,
+    under differentiation the forward with a state a chunk (512 MiB) and
+    the hand-written backward; no triangular solve and no scan is left."""
+    args = _gdn_args(one_chip)
+    names = _op_names(_compile(_gdn_loss, *args))
+    assert len(names) == 1 and _in_scope(names[0], "gdn_chunk")
+    compiled = jax.jit(jax.grad(_gdn_loss, argnums=range(5))).lower(
+        *args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0 * 2**30
+    hlo = compiled.as_text()
+    names = _op_names(hlo)
+    assert len(names) == 2 and all(_in_scope(n, "gdn_chunk") for n in names)
+    assert sum("gdn_fwd" in n for n in names) == 1
+    assert sum("gdn_bwd" in n for n in names) == 1
+    assert "riangular" not in hlo
+    assert trace.gauges()["attn.gdn_kernel"] == 1
+
+
 def _kda_io_losses(mesh=None):
     """The KDA layer's two elementwise passes at the kimi-linear cell's
     shapes, under the scopes ``kimi_linear.kda_attention`` opens."""

@@ -84,6 +84,10 @@ EXPERT_CELLS = {
     "kimi": ((8192, 8, 256, 32, 2304, 1024, "silu"), 1054416896),
     "dots3": ((8192, 8, 256, 8, 5120, 1536, "silu"), 2630225408),
     "olmoe": ((8192, 8, 64, None, 2048, 1024, "silu"), 675513856),
+    # PR 45, many small experts: 320 rows an expert of width 512, a
+    # router 512 wide, 163840 pairs through the sort; no parent, so the
+    # bytes are this layer's own at the PR that listed the cell
+    "qwen3next": ((16384, 10, 512, 32, 2048, 512, "silu"), 2558014464),
 }
 
 

@@ -191,3 +191,60 @@ def test_kimi_linear_expert_block_fwd_bwd_compiles(
         3 if flash else 2.3) * 2**30
     assert trace.gauges()["moe.rows_held"] == 8192
     assert trace.gauges()["moe.tail_rows"] == 57344
+
+
+@pytest.mark.parametrize("kind", ["G", "F"])
+def test_qwen3_next_block_fwd_bwd_compiles(
+        one_chip, kernels_are_the_path, kind):
+    """A block of the qwen3next cell at its shapes (16384 tokens, 32 of
+    512 experts held): a Gated DeltaNet block runs the per-head rule's
+    two kernels and the passes around them under the layer's scopes and
+    no flash kernel; a gated attention block the flash kernels at 256 /
+    256 and group 8 at the tiles the shapes choose."""
+    from dlrover_tpu.models import qwen3_next
+
+    cfg = qwen3_next.Qwen3NextConfig(
+        vocab_size=18992, n_layers=8, experts_held=32, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16)
+    lp = {
+        name: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+        for name, (shape, _, _) in qwen3_next._block_shapes(cfg, kind).items()
+    }
+    x = jax.ShapeDtypeStruct((1, 16384, cfg.dim), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(lp, x):
+        fn = jax.checkpoint(
+            lambda lp, x: qwen3_next.block(cfg, None, kind, lp, x)[0],
+            policy=jax.checkpoint_policies.nothing_saveable)
+        return fn(lp, x).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(lp, x).compile()
+    hlo = compiled.as_text()
+    flash = 1 if kind == "F" else 0
+    assert _kernel_calls(hlo, "attention_fwd") == flash
+    assert _kernel_calls(hlo, "attention_bwd") == 2 * flash
+    assert _kernel_calls(hlo, "grouped_matmul") == 9
+    delta = [n for n in _op_names(hlo) if "/gdn_" in n or "/kda_" in n]
+    if flash:
+        assert not delta
+        assert (trace.gauges()["attn.block_q"],
+                trace.gauges()["attn.block_k"]) == (256, 512)
+    else:
+        # the remat forward and the backward of each (the first forward
+        # is gone with the loss's value); the input pass runs twice a
+        # direction: q and k over 16 heads, v over 32
+        assert sorted((n.split("/")[-2], next(
+            s for s in ("gdn_conv", "gdn_chunk", "gdn_out")
+            if _in_scope(n, s))) for n in delta) == [
+            ("gdn_bwd", "gdn_chunk"), ("gdn_fwd", "gdn_chunk"),
+            ("kda_in_bwd", "gdn_conv"), ("kda_in_bwd", "gdn_conv"),
+            ("kda_in_fwd", "gdn_conv"), ("kda_in_fwd", "gdn_conv"),
+            ("kda_out_bwd", "gdn_out"), ("kda_out_fwd", "gdn_out")]
+        assert trace.gauges()["attn.gdn_kernel"] == 1
+        assert trace.gauges()["kda.io_fused"] == 1
+        assert "riangular" not in hlo
+    # a block's temporaries fit beside the cell's 6.56 GiB of state
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
+    assert trace.gauges()["moe.rows_held"] == 10240
+    assert trace.gauges()["moe.shared_gate"] == 1

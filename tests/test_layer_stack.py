@@ -12,7 +12,8 @@ import numpy as np
 import pytest
 from jax.ad_checkpoint import checkpoint_name
 
-from dlrover_tpu.models import dots3, kimi_linear, smallthinker, stack
+from dlrover_tpu.models import (
+    dots3, kimi_linear, qwen3_next, smallthinker, stack)
 from dlrover_tpu.models.stack import Part
 
 MODELS = os.path.join(os.path.dirname(os.path.dirname(
@@ -70,7 +71,9 @@ def test_runs_are_stacked_parts_of_one_position():
     (kimi_linear.KimiLinearConfig().layout,
      kimi_linear.KimiLinearConfig().pattern),
     (stack.periodic("ffFSFSF", head=2), tuple("ffFSFSF")),
-], ids=["smallthinker", "dots3", "kimi_linear", "head_and_tail"])
+    (qwen3_next.Qwen3NextConfig().layout, qwen3_next.Qwen3NextConfig().kinds),
+], ids=["smallthinker", "dots3", "kimi_linear", "head_and_tail",
+        "qwen3_next"])
 def test_locate_finds_every_layer_once_and_in_order(parts, kinds):
     seen = [stack.locate(parts, layer) for layer in range(len(kinds))]
     assert len(set(seen)) == len(kinds) and seen == sorted(
@@ -246,7 +249,8 @@ def test_one_file_under_models_calls_jax_checkpoint():
 
 def test_no_family_walks_its_layers_or_shifts_its_targets_itself():
     sources = _sources()
-    for name in ("kimi_linear.py", "smallthinker.py", "dots3.py"):
+    for name in ("kimi_linear.py", "smallthinker.py", "dots3.py",
+                 "qwen3_next.py"):
         assert "lax.scan(" not in sources[name], name
     assert {name for name, text in sources.items()
             if "_shift_targets" in text} == {"llama.py"}

@@ -457,3 +457,17 @@ def test_speculation_filter_drops_oom_worlds(dp4, monkeypatch):
     # armed with room to spare: every neighbor kept
     monkeypatch.setenv("DLROVER_TPU_MEMCHECK_BUDGET_GB", "1000")
     assert trainer._filter_speculation_targets(targets) == targets
+
+
+def test_read_memory_analysis_planned_peak_where_the_backend_states_it():
+    # the compiler's own heap simulation + generated code, beside the
+    # sum (which can overcount a step whose temporaries reuse donated
+    # arguments); absent, not zero, where the backend has no such field
+    out = memcheck.read_memory_analysis(
+        _Compiled(_full_ma(peak_memory_in_bytes=120)), label="t-plan")
+    assert out["planned_peak_bytes"] == 120 + 10
+    assert out["peak_bytes"] == 100 + 50 + 30 + 10 - 40
+    for absent in (_full_ma(), _full_ma(peak_memory_in_bytes=0),
+                   _full_ma(peak_memory_in_bytes=None)):
+        assert "planned_peak_bytes" not in memcheck.read_memory_analysis(
+            _Compiled(absent), label="t-noplan")
