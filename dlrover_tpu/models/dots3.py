@@ -71,6 +71,7 @@ from dlrover_tpu.models.xing4 import latent_attention
 from dlrover_tpu.observability import trace
 from dlrover_tpu.ops import (
     apply_rope,
+    attention as attn_ops,
     dsa,
     embed_lookup,
     flash_attention,
@@ -657,6 +658,7 @@ def _report_shapes(cfg: Dots3Config):
     # 1 once a full block's checkpoint has met the loss's gradient and
     # kept it (`_block_fn`): in a differentiated build under remat
     trace.gauge("dsa.loss_grad_kept", 0)
+    trace.gauge("attn.out_kept", 0)  # as above, of a flash forward's output
     trace.gauge("attn.heads_held", cfg.n_held_heads(FULL))
     trace.gauge("attn.heads", cfg.n_heads)
     trace.gauge("attn.swa_heads_held", cfg.n_held_heads(WINDOW))
@@ -675,19 +677,23 @@ def _report_shapes(cfg: Dots3Config):
 
 
 def _block_fn(cfg: Dots3Config, mesh, kind: str, positions):
-    """A block is recomputed whole in the backward pass, but for the two
-    arrays a full layer names. The selection's mask, 1 byte a pair,
-    spares the threshold's 45 passes over the scores. d L_I / d scores,
-    4 bytes a pair (`dsa.indexer_loss` forms it in the forward: its
-    target is a constant), spares the indexer's score kernel,
-    `dsa_probs` and the KL, which nothing else in the backward reads."""
+    """A block is recomputed whole in the backward pass, but for the
+    flash forward's output and ``lse`` (its backward's residuals: the
+    kernel runs once a step) and the two arrays a full layer names. The
+    selection's mask, 1 byte a pair, spares the threshold's 45 passes
+    over the scores. d L_I / d scores, 4 bytes a pair
+    (`dsa.indexer_loss` forms it in the forward: its target is a
+    constant), spares the indexer's score kernel, `dsa_probs` and the
+    KL, which nothing else in the backward reads."""
     def kept(name):
+        attn_ops.report_kept(name)
         if name == dsa.LOSS_GRAD:
             trace.gauge("dsa.loss_grad_kept", 1)
 
     return stack.recompute(
         functools.partial(block, cfg, mesh, kind, positions), cfg.remat,
-        ("dsa_select", dsa.LOSS_GRAD) if kind == FULL else (), kept)
+        attn_ops.KEPT + (("dsa_select", dsa.LOSS_GRAD) if kind == FULL
+                         else ()), kept)
 
 
 def _positions(tokens):

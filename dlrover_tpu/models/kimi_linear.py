@@ -52,7 +52,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dlrover_tpu.models import llama, moe, stack, xing4
 from dlrover_tpu.observability import trace
-from dlrover_tpu.ops import embed_lookup, kda, rms_norm
+from dlrover_tpu.ops import attention, embed_lookup, kda, rms_norm
 from dlrover_tpu.parallel.mesh import BATCH_AXES, EP, FSDP, PP, SP, TP
 
 Params = Dict[str, Any]
@@ -414,8 +414,20 @@ def _report_shapes(cfg: KimiLinearConfig):
     trace.gauge("mla.kv_lora_rank", cfg.kv_lora_rank)
     trace.gauge("mla.q_rank", 0)
     trace.gauge("mla.rotary", 0)
+    trace.gauge("attn.out_kept", 0)  # 1 once a block keeps one (`_block_fn`)
     trace.gauge("attn.scale", cfg.softmax_scale)
     trace.provide_text("layers.pattern", lambda: cfg.pattern_string)
+
+
+def _block_fn(cfg: KimiLinearConfig, mesh, attn: str, ffn: str):
+    """A block is recomputed whole in the backward pass, but for the
+    flash forward's output and ``lse``, its backward's residuals (65 MiB
+    a latent layer at 8192 tokens): the kernel runs once a step. The
+    delta rule's residual is a float32 state a chunk, 256 MiB a layer,
+    and stays recomputed."""
+    return stack.recompute(
+        functools.partial(block, cfg, mesh, attn, ffn), cfg.remat,
+        attention.KEPT, attention.report_kept)
 
 
 def forward_layers(
@@ -428,9 +440,7 @@ def forward_layers(
         validate_for_mesh(cfg, mesh, batch=tokens.shape[0])
     _report_shapes(cfg)
     x = embed_lookup(params["embed"], tokens, mesh, cfg.dtype)
-    fns = {kind: stack.recompute(
-        functools.partial(block, cfg, mesh, *kind), cfg.remat)
-        for kind in set(cfg.pattern)}
+    fns = {kind: _block_fn(cfg, mesh, *kind) for kind in set(cfg.pattern)}
     trees = [(params["runs"][run_name(i)],) for i in range(len(cfg.layout))]
     return stack.walk(x, cfg.layout, trees,
                       lambda kind, lp, x: (fns[kind](lp, x), None))[0]

@@ -67,12 +67,13 @@ from dlrover_tpu.models import moe, stack
 from dlrover_tpu.observability import trace
 from dlrover_tpu.ops import (
     apply_rope,
+    attention,
     embed_lookup,
+    flash_attention,
     kda,
     rms_norm,
     rope_frequencies,
 )
-from dlrover_tpu.ops.attention import flash_attention
 from dlrover_tpu.parallel.mesh import BATCH_AXES, EP, FSDP, PP, SP, TP
 
 Params = Dict[str, Any]
@@ -453,8 +454,20 @@ def _report_shapes(cfg: Qwen3NextConfig):
     trace.gauge("attn.gdn_chunk", cfg.gdn_chunk)
     trace.gauge("attn.group", cfg.n_heads // cfg.n_kv_heads)
     trace.gauge("attn.rotary_dim", cfg.rotary_dim)
+    trace.gauge("attn.out_kept", 0)  # 1 once a block keeps one (`_block_fn`)
     trace.gauge("layers.period", cfg.period)
     trace.provide_text("layers.pattern", lambda: cfg.pattern_string)
+
+
+def _block_fn(cfg: Qwen3NextConfig, mesh, kind: str):
+    """A block is recomputed whole in the backward pass, but for the
+    flash forward's output and ``lse``, its backward's residuals (129
+    MiB a gated attention layer at 16384 tokens): the kernel runs once a
+    step. The delta rule's residual is a float32 state a chunk, 512 MiB
+    a layer, and stays recomputed."""
+    return stack.recompute(
+        functools.partial(block, cfg, mesh, kind), cfg.remat,
+        attention.KEPT, attention.report_kept)
 
 
 def forward_layers(
@@ -468,9 +481,7 @@ def forward_layers(
         validate_for_mesh(cfg, mesh, batch=tokens.shape[0])
     _report_shapes(cfg)
     x = embed_lookup(params["embed"], tokens, mesh, cfg.dtype)
-    fns = {kind: stack.recompute(
-        functools.partial(block, cfg, mesh, kind), cfg.remat)
-        for kind in set(cfg.kinds)}
+    fns = {kind: _block_fn(cfg, mesh, kind) for kind in set(cfg.kinds)}
     x, aux = stack.walk(x, cfg.layout, _trees(params),
                         lambda kind, lp, x: fns[kind](lp, x))
     return x, jnp.mean(aux)

@@ -61,6 +61,7 @@ from dlrover_tpu.models import llama, moe, stack
 from dlrover_tpu.observability import trace
 from dlrover_tpu.ops import (
     apply_rope,
+    attention,
     embed_lookup,
     flash_attention,
     rms_norm,
@@ -515,6 +516,7 @@ def _report_shapes(cfg: Xing4Config):
     trace.gauge("mla.q_lora_rank", cfg.q_lora_rank)
     trace.gauge("mla.kv_lora_rank", cfg.kv_lora_rank)
     trace.gauge("attn.scale", cfg.softmax_scale)
+    trace.gauge("attn.out_kept", 0)  # 1 once a block keeps one (`_block_fn`)
     trace.gauge("hc.streams", cfg.hc_mult)
     trace.gauge("hc.sinkhorn_iters", cfg.hc_sinkhorn_iters)
     trace.gauge("mtp.depth", cfg.mtp_depth)
@@ -531,9 +533,12 @@ def rotary_tables(cfg: Xing4Config, tokens):
 
 
 def _block_fn(cfg: Xing4Config, mesh, tokens):
+    """A block is recomputed whole in the backward pass, but for the
+    flash forward's output and ``lse``, its backward's residuals (65 MiB
+    a layer at 2 x 4096 tokens): the kernel runs once a step."""
     return stack.recompute(
         functools.partial(block, cfg, mesh, *rotary_tables(cfg, tokens)),
-        cfg.remat)
+        cfg.remat, attention.KEPT, attention.report_kept)
 
 
 def _streams(cfg: Xing4Config, x):

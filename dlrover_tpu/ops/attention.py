@@ -61,6 +61,11 @@ How the three kernels walk the (q, k) plane:
   matmuls; the scale is applied to f32 values. Max, sum, ``lse``,
   ``delta`` and every accumulator stay f32.
 
+- **residuals**: the backward reads ``(q, k, v[, select], out, lse)``.
+  The forward rules name ``out`` and ``lse`` (`KEPT`): a block whose
+  checkpoint keeps the two (``models/stack.py recompute(keep=KEPT)``)
+  recomputes q, k, v in its backward pass and never the forward kernel.
+
 On the TPU backend the Pallas kernels are the path: one the chip's
 compiler refuses fails the step. Off TPU both directions run the jnp
 reference, so the same model code runs in CPU tests; ``interpret=True``
@@ -82,6 +87,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax, shard_map
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
@@ -893,6 +899,25 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+#: the names both forwards give their output and its ``lse``, which with
+#: q, k, v (and the selection) are all the backward kernels read: a
+#: checkpoint policy that keeps the two spares the recomputed forward the
+#: forward kernel (``models/stack.py recompute(keep=KEPT)``). Under
+#: ``nothing_saveable``, and outside a checkpoint, a name is an identity.
+KEPT = ("attn_out", "attn_lse")
+
+
+def _named(out, lse):
+    return checkpoint_name(out, KEPT[0]), checkpoint_name(lse, KEPT[1])
+
+
+def report_kept(name: str):
+    """A ``recompute(kept=)`` callback: the gauge ``attn.out_kept`` reads
+    1 once a block's checkpoint has met a forward's output and kept it."""
+    if name == KEPT[0]:
+        trace.gauge("attn.out_kept", 1)
+
+
 # ---------------------------------------------------------------------------
 # custom_vjp surfaces
 # ---------------------------------------------------------------------------
@@ -944,6 +969,7 @@ def _flash_with_lse_fwd(q, k, v, causal, block_q, block_k, interpret,
         else:
             out, lse = mha_reference_with_lse(q, k, v, causal=causal,
                                               scale=scale, window=window)
+    out, lse = _named(out, lse)
     return (out, lse), (q, k, v, out, lse)
 
 
@@ -1011,6 +1037,7 @@ def _flash_select_fwd(q, k, v, select, block_q, block_k, interpret, scale):
         else:
             out, lse = mha_reference_with_lse(
                 q, k, v, causal=True, scale=scale, select=select)
+    out, lse = _named(out, lse)
     return (out, lse), (q, k, v, select, out, lse)
 
 

@@ -4,6 +4,7 @@ own checks, and ``tests/chip_compile.py`` for what the files share). These
 are the long compiles, a file of their own so that no one file sets the
 pace of a ``--dist loadfile`` run."""
 
+import collections
 import os
 import re
 
@@ -26,9 +27,12 @@ from tests.chip_compile import (  # noqa: F401  (fixtures by import)
 # scores beside the selection's mask (256 MiB a layer, float32), and the
 # recomputed forward runs neither the indexer's score kernel nor
 # `dsa_probs`: one call a full layer a step where the parent made two.
-# The parent's step peaks at 15,186,436,096 bytes (14.143 GiB); the two
-# kept arrays and some slack may be added to it, no more.
-DOTS3_PARENT_STEP_PEAK = 15186436096
+# Since PR 46 every block also keeps its flash forward's output and lse
+# (64 + 1 MiB a full layer, 32 + 0.5 a window layer), so a forward kernel
+# runs once a layer a step where the parent ran it twice. That step
+# peaks at 15,454,193,152 bytes (14.393 GiB; 15,256,935,424 before the
+# five pairs were kept); some slack may be added to it, no more.
+DOTS3_STEP_PEAK = 15454193152
 
 
 def test_dots3_step_keeps_the_loss_gradient_in_the_memory_it_has(
@@ -60,12 +64,16 @@ def test_dots3_step_keeps_the_loss_gradient_in_the_memory_it_has(
     compiled, _ = trainer.lower_step(mesh, mc)
 
     hlo = compiled.as_text()
-    assert fam.cfg.layer_kinds.count("F") == 2
+    assert (fam.cfg.layer_kinds.count("F"),
+            fam.cfg.layer_kinds.count("S")) == (2, 3)
     for name, calls in (("dsa_index_fwd", 2), ("dsa_probs", 2),
                         ("dsa_index_bwd_dq", 2), ("dsa_index_bwd_dk", 2),
-                        ("attention_fwd_sel", 4)):
+                        ("attention_fwd_sel", 2), ("attention_fwd_swa", 3),
+                        ("attention_bwd_dq_sel", 2),
+                        ("attention_bwd_dq_swa", 3)):
         assert _kernel_calls(hlo, name) == calls, name
     assert trace.gauges()["dsa.loss_grad_kept"] == 1
+    assert trace.gauges()["attn.out_kept"] == 1
     # the backward scales the kept array once a layer: the transpose the
     # key-side score kernel reads is a copy of that product, not a second
     # product (`indexer_loss`'s barrier)
@@ -76,17 +84,25 @@ def test_dots3_step_keeps_the_loss_gradient_in_the_memory_it_has(
         for line in scaled), scaled
     peak = memcheck.read_memory_analysis(compiled)["peak_bytes"]
     print(f"dots3 step.hbm_peak_bytes {peak} = {peak / 2**30:.4f} GiB")
-    assert peak <= DOTS3_PARENT_STEP_PEAK + 560 * 2**20
-    assert peak <= 15.75 * 2**30
+    assert peak <= DOTS3_STEP_PEAK + 64 * 2**20 <= 15.75 * 2**30
 
 
-# xing4-ep8-1chip-steady (PR 31): one whole expert block of the step at
-# the published widths (four streams of 2 x 4096 x 3584, ranks 768 / 512,
-# 8 held experts of 64, the shared expert), forward and backward, remat
-# as the cell runs it.
+def _two_in_line(fn):
+    """The gradient's function of two blocks in line, built as the family
+    builds them: the first one's output is wanted, so its forward runs;
+    the second's is not (the loss's value is not asked for), so its first
+    forward runs only for what its checkpoint keeps."""
+    return jax.grad(
+        lambda lp, x: fn(lp, fn(lp, x)).astype(jnp.float32).sum(),
+        argnums=(0, 1))
+
+
+# xing4-ep8-1chip-steady (PR 31): two whole expert blocks of the step in
+# line at the published widths (four streams of 2 x 4096 x 3584, ranks
+# 768 / 512, 8 held experts of 64, the shared expert), forward and
+# backward, recomputed as the family's own factory has it.
 def test_xing4_expert_block_fwd_bwd_compiles(one_chip, kernels_are_the_path):
     from dlrover_tpu.models import xing4
-    from dlrover_tpu.ops import yarn_frequencies
 
     cfg = xing4.Xing4Config(
         vocab_size=16384, n_dense_layers=1, n_moe_layers=1, experts_held=8,
@@ -97,31 +113,29 @@ def test_xing4_expert_block_fwd_bwd_compiles(one_chip, kernels_are_the_path):
                                        sharding=one_chip), layers)
     X = jax.ShapeDtypeStruct((4, 2, 4096, cfg.dim), jnp.bfloat16,
                              sharding=one_chip)
+    tokens = jax.ShapeDtypeStruct((2, 4096), jnp.int32)
+    trace.gauge("attn.out_kept", 0)
 
-    def loss(lp, X):
-        positions = jnp.broadcast_to(jnp.arange(4096, dtype=jnp.int32),
-                                     (2, 4096))
-        inv_freq = yarn_frequencies(64, 10000.0, 64.0, 4096)
-        fn = jax.checkpoint(
-            lambda lp, X: xing4.block(cfg, None, positions, inv_freq, lp, X),
-            policy=jax.checkpoint_policies.nothing_saveable)
-        return fn(lp, X).astype(jnp.float32).sum()
-
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(lp, X).compile()
+    compiled = jax.jit(_two_in_line(
+        xing4._block_fn(cfg, None, tokens))).lower(lp, X).compile()
     hlo = compiled.as_text()
-    # the remat forward is the only forward here (nothing else wants the
-    # block's output): 1 + 2 of attention, and forward, d-lhs and d-rhs
-    # of each of the three grouped products; act(gate) x up and its
-    # backward as passes
-    assert _kernel_calls(hlo, "attention_fwd") == 1
-    assert _kernel_calls(hlo, "attention_bwd") == 2
-    assert _kernel_calls(hlo, "grouped_matmul_dlhs") == 3
-    assert _kernel_calls(hlo, "grouped_matmul_drhs") == 3
-    assert _kernel_calls(hlo, "grouped_matmul") == 9
-    assert _kernel_calls(hlo, "moe_rows_gated") == 2
+    # since PR 46 a block keeps the flash forward's output and lse: one
+    # forward call a block (the first block's own forward; the second's,
+    # which runs for the kept pair alone) where `nothing_saveable` made
+    # 2 + 1; a block's 2 of attention's backward, and forward, d-lhs and
+    # d-rhs of each of the three grouped products (the first block's
+    # forward products run twice); act(gate) x up and its backward as
+    # passes
+    assert _kernel_calls(hlo, "attention_fwd") == 2
+    assert trace.gauges()["attn.out_kept"] == 1
+    assert _kernel_calls(hlo, "attention_bwd") == 4
+    assert _kernel_calls(hlo, "grouped_matmul_dlhs") == 6
+    assert _kernel_calls(hlo, "grouped_matmul_drhs") == 6
+    assert _kernel_calls(hlo, "grouped_matmul") == 21
+    assert _kernel_calls(hlo, "moe_rows_gated") == 5
     assert trace.gauges()["moe.tail_skipped"] == 1
     assert "[8192,64,8" not in hlo  # no (tokens, experts, ...) dispatch tensor
-    # a block's own temporaries fit beside the cell's state and carries
+    # two blocks' own temporaries fit beside the cell's state and carries
     assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2**30
     assert trace.gauges()["moe.rows_held"] == 4096
     assert trace.gauges()["moe.tail_rows"] == 28672
@@ -145,36 +159,32 @@ def test_kimi_linear_expert_block_fwd_bwd_compiles(
     x = jax.ShapeDtypeStruct((1, 8192, cfg.dim), jnp.bfloat16,
                              sharding=one_chip)
 
-    def loss(lp, x):
-        fn = jax.checkpoint(
-            lambda lp, x: kimi_linear.block(cfg, None, attn, "moe", lp, x),
-            policy=jax.checkpoint_policies.nothing_saveable)
-        return fn(lp, x).astype(jnp.float32).sum()
-
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(lp, x).compile()
+    trace.gauge("attn.out_kept", 0)
+    compiled = jax.jit(_two_in_line(
+        kimi_linear._block_fn(cfg, None, attn, "moe"))).lower(lp, x).compile()
     hlo = compiled.as_text()
-    # latent attention without rotary still runs the 192 / 128 kernels;
-    # a KDA block runs none of them and its own two instead: the remat
-    # forward and the backward (the loss's value is not asked for, so
-    # the first forward is gone), both under the scope the device
-    # metrics select by
+    # latent attention without rotary still runs the 192 / 128 kernels,
+    # the forward once a block since PR 46 (the block keeps its output
+    # and lse; `nothing_saveable` made 2 + 1 of it); a KDA block runs
+    # none of them and keeps nothing: its own forward kernels run in the
+    # first block's forward and in both recomputed ones, the backward's
+    # once a block, all under the scope the device metrics select by
     flash = 1 if attn == "mla" else 0
-    assert _kernel_calls(hlo, "attention_fwd") == flash
-    assert _kernel_calls(hlo, "attention_bwd") == 2 * flash
-    assert _kernel_calls(hlo, "grouped_matmul") == 9
-    assert _kernel_calls(hlo, "moe_rows_gated") == 2
+    assert _kernel_calls(hlo, "attention_fwd") == 2 * flash
+    assert trace.gauges()["attn.out_kept"] == flash
+    assert _kernel_calls(hlo, "attention_bwd") == 4 * flash
+    assert _kernel_calls(hlo, "grouped_matmul") == 21
+    assert _kernel_calls(hlo, "moe_rows_gated") == 5
     delta = [n for n in _op_names(hlo) if "/kda_" in n]
     if flash:
         assert not delta
     else:
-        # each kernel once, under its layer's scope, the backward's too
-        # (the first forward is gone, so a forward kernel runs once)
-        assert sorted((n.split("/")[-2], next(
+        assert collections.Counter((n.split("/")[-2], next(
             s for s in ("kda_conv", "kda_chunk", "kda_out")
-            if _in_scope(n, s))) for n in delta) == [
-            ("kda_bwd", "kda_chunk"), ("kda_fwd", "kda_chunk"),
-            ("kda_in_bwd", "kda_conv"), ("kda_in_fwd", "kda_conv"),
-            ("kda_out_bwd", "kda_out"), ("kda_out_fwd", "kda_out")]
+            if _in_scope(n, s))) for n in delta) == {
+            ("kda_bwd", "kda_chunk"): 2, ("kda_fwd", "kda_chunk"): 3,
+            ("kda_in_bwd", "kda_conv"): 2, ("kda_in_fwd", "kda_conv"): 3,
+            ("kda_out_bwd", "kda_out"): 2, ("kda_out_fwd", "kda_out"): 3}
         assert trace.gauges()["kda.io_fused"] == 1
         # the XLA form of the passes took float32 copies of every
         # activation into another layout and back: none is left
@@ -184,11 +194,12 @@ def test_kimi_linear_expert_block_fwd_bwd_compiles(
         assert "riangular" not in hlo
         assert not [line for line in _wide_f32(hlo, "transpose")
                     if _in_scope(line, "kda_chunk")]
-    # a block's own temporaries fit beside the cell's 7.16 GiB of state
-    # and 4.78 of float32 gradients; a KDA block's are under what they
-    # were with the passes in XLA ops (2.857 GiB; 2.10 now)
+    # two blocks' own temporaries fit beside the cell's 7.16 GiB of state
+    # and 4.78 of float32 gradients (2.302 GiB the latent pair with its
+    # kept 65 MiB, 2.976 the KDA pair; one KDA block alone took 2.10,
+    # and 2.857 with the passes in XLA ops)
     assert compiled.memory_analysis().temp_size_in_bytes < (
-        3 if flash else 2.3) * 2**30
+        2.5 if flash else 3.1) * 2**30
     assert trace.gauges()["moe.rows_held"] == 8192
     assert trace.gauges()["moe.tail_rows"] == 57344
 
@@ -213,38 +224,42 @@ def test_qwen3_next_block_fwd_bwd_compiles(
     x = jax.ShapeDtypeStruct((1, 16384, cfg.dim), jnp.bfloat16,
                              sharding=one_chip)
 
-    def loss(lp, x):
-        fn = jax.checkpoint(
-            lambda lp, x: qwen3_next.block(cfg, None, kind, lp, x)[0],
-            policy=jax.checkpoint_policies.nothing_saveable)
-        return fn(lp, x).astype(jnp.float32).sum()
-
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(lp, x).compile()
+    trace.gauge("attn.out_kept", 0)
+    fn = qwen3_next._block_fn(cfg, None, kind)
+    compiled = jax.jit(_two_in_line(
+        lambda lp, x: fn(lp, x)[0])).lower(lp, x).compile()
     hlo = compiled.as_text()
+    # since PR 46 a gated attention block keeps the flash forward's
+    # output and lse: one forward call a block where `nothing_saveable`
+    # made 2 + 1 for the pair
     flash = 1 if kind == "F" else 0
-    assert _kernel_calls(hlo, "attention_fwd") == flash
-    assert _kernel_calls(hlo, "attention_bwd") == 2 * flash
-    assert _kernel_calls(hlo, "grouped_matmul") == 9
+    assert _kernel_calls(hlo, "attention_fwd") == 2 * flash
+    assert trace.gauges()["attn.out_kept"] == flash
+    assert _kernel_calls(hlo, "attention_bwd") == 4 * flash
+    assert _kernel_calls(hlo, "grouped_matmul") == 21
     delta = [n for n in _op_names(hlo) if "/gdn_" in n or "/kda_" in n]
     if flash:
         assert not delta
         assert (trace.gauges()["attn.block_q"],
                 trace.gauges()["attn.block_k"]) == (256, 512)
     else:
-        # the remat forward and the backward of each (the first forward
-        # is gone with the loss's value); the input pass runs twice a
-        # direction: q and k over 16 heads, v over 32
-        assert sorted((n.split("/")[-2], next(
+        # the first block's forward, both recomputed forwards and both
+        # backwards (the rule's state is not kept: 512 MiB a layer); the
+        # input pass runs twice a direction: q and k over 16 heads, v
+        # over 32
+        assert collections.Counter((n.split("/")[-2], next(
             s for s in ("gdn_conv", "gdn_chunk", "gdn_out")
-            if _in_scope(n, s))) for n in delta) == [
-            ("gdn_bwd", "gdn_chunk"), ("gdn_fwd", "gdn_chunk"),
-            ("kda_in_bwd", "gdn_conv"), ("kda_in_bwd", "gdn_conv"),
-            ("kda_in_fwd", "gdn_conv"), ("kda_in_fwd", "gdn_conv"),
-            ("kda_out_bwd", "gdn_out"), ("kda_out_fwd", "gdn_out")]
+            if _in_scope(n, s))) for n in delta) == {
+            ("gdn_bwd", "gdn_chunk"): 2, ("gdn_fwd", "gdn_chunk"): 3,
+            ("kda_in_bwd", "gdn_conv"): 4, ("kda_in_fwd", "gdn_conv"): 6,
+            ("kda_out_bwd", "gdn_out"): 2, ("kda_out_fwd", "gdn_out"): 3}
         assert trace.gauges()["attn.gdn_kernel"] == 1
         assert trace.gauges()["kda.io_fused"] == 1
         assert "riangular" not in hlo
-    # a block's temporaries fit beside the cell's 6.56 GiB of state
-    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
+    # two blocks' temporaries fit beside the cell's 6.56 GiB of state
+    # (4.850 GiB the Gated DeltaNet pair, 4.371 the attention pair with
+    # its kept 129 MiB)
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        4.6 if flash else 5.0) * 2**30
     assert trace.gauges()["moe.rows_held"] == 10240
     assert trace.gauges()["moe.shared_gate"] == 1

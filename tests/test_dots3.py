@@ -399,9 +399,9 @@ def _kernel_calls(jaxpr, found=None):
 
 @pytest.mark.parametrize("kind,layer,want", [
     ("F", 1, {"dsa_index_fwd": 1, "dsa_probs": 1, "dsa_index_bwd_dq": 1,
-              "dsa_index_bwd_dk": 1, "attention_fwd_sel": 2,
+              "dsa_index_bwd_dk": 1, "attention_fwd_sel": 1,
               "attention_bwd_dq_sel": 1, "attention_bwd_dkv_sel": 1}),
-    ("S", 2, {"attention_fwd_swa": 2, "attention_bwd_dq_swa": 1,
+    ("S", 2, {"attention_fwd_swa": 1, "attention_bwd_dq_swa": 1,
               "attention_bwd_dkv_swa": 1})])
 def test_a_recomputed_full_block_runs_the_loss_and_the_scores_once(
         built, monkeypatch, kind, layer, want):
@@ -409,9 +409,9 @@ def test_a_recomputed_full_block_runs_the_loss_and_the_scores_once(
     partial evaluation done (it has dropped from the recomputed forward
     what the backward does not read). Until PR 43 a full block called
     ``dsa_index_fwd`` 2 and ``dsa_probs`` 2 times: the KL's autodiff read
-    ``log_softmax(scores)`` and ``probs`` again. The flash forward is
-    still called twice (its ``lse`` and output are the backward's), and
-    a window block's three kernels 2, 1, 1 as before."""
+    ``log_softmax(scores)`` and ``probs`` again. Until PR 46 the flash
+    forward was called twice in either kind of block: its output and
+    ``lse`` are the backward's, and the block keeps them now."""
     fam, params, _ = built
     cfg = dataclasses.replace(fam.cfg, remat=True)
     monkeypatch.setattr(dsa, "_on_tpu", lambda: True)
@@ -420,11 +420,13 @@ def test_a_recomputed_full_block_runs_the_loss_and_the_scores_once(
     x = jax.random.normal(jax.random.key(2), (2, 128, cfg.dim))
     fn = dots3._block_fn(cfg, None, kind, positions)
     trace.gauge("dsa.loss_grad_kept", 0)
+    trace.gauge("attn.out_kept", 0)
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda lp, x: sum(jnp.sum(out) for out in fn(lp, x)),
         argnums=(0, 1)))(dots3.layer_params(cfg, params, layer), x)
     assert dict(_kernel_calls(jaxpr.jaxpr)) == want
     assert trace.gauges()["dsa.loss_grad_kept"] == (kind == "F")
+    assert trace.gauges()["attn.out_kept"] == 1
 
 
 def test_remat_changes_no_gradient_and_the_gauge_says_what_was_kept(built):

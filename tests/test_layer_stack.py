@@ -247,6 +247,17 @@ def test_one_file_under_models_calls_jax_checkpoint():
     assert calls == {"stack.py"}
 
 
+def test_four_families_keep_the_flash_forwards_output_at_their_call_site():
+    """The keep is each family's own choice where it calls `recompute`
+    (its cell has the memory), not a rule of `stack.py` or of the
+    kernels: the families whose cells have no room name nothing."""
+    sources = _sources()
+    assert {name for name, text in sources.items()
+            if re.search(r"\bKEPT\b", text)} == {
+        "dots3.py", "qwen3_next.py", "xing4.py", "kimi_linear.py"}
+    assert "KEPT" not in sources["stack.py"]
+
+
 def test_no_family_walks_its_layers_or_shifts_its_targets_itself():
     sources = _sources()
     for name in ("kimi_linear.py", "smallthinker.py", "dots3.py",
