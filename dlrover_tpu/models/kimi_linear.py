@@ -409,6 +409,7 @@ def _report_shapes(cfg: KimiLinearConfig):
     trace.gauge("kda.head_dim", cfg.kda_head_dim)
     trace.gauge("kda.chunk", cfg.kda_chunk)
     trace.gauge("kda.conv", cfg.conv_size)
+    trace.gauge("kda.state_kept", 0)  # as `attn.out_kept`, of the rule's states
     trace.gauge("mla.qk_head_dim", cfg.qk_nope_dim + cfg.qk_rope_dim)
     trace.gauge("mla.v_head_dim", cfg.v_head_dim)
     trace.gauge("mla.kv_lora_rank", cfg.kv_lora_rank)
@@ -419,15 +420,21 @@ def _report_shapes(cfg: KimiLinearConfig):
     trace.provide_text("layers.pattern", lambda: cfg.pattern_string)
 
 
+def _report_kept(name: str):
+    """``recompute``'s one callback feeds both kernels' gauges."""
+    attention.report_kept(name)
+    kda.report_kept(name)
+
+
 def _block_fn(cfg: KimiLinearConfig, mesh, attn: str, ffn: str):
-    """A block is recomputed whole in the backward pass, but for the
-    flash forward's output and ``lse``, its backward's residuals (65 MiB
-    a latent layer at 8192 tokens): the kernel runs once a step. The
-    delta rule's residual is a float32 state a chunk, 256 MiB a layer,
-    and stays recomputed."""
+    """A block is recomputed whole in the backward pass, but for what its
+    attention's forward kernel leaves its backward: the flash forward's
+    output and ``lse`` (65 MiB a latent layer at 8192 tokens), the delta
+    rule's output and the float32 state every chunk started from (64 +
+    256 MiB a KDA layer). Either kernel runs once a step."""
     return stack.recompute(
         functools.partial(block, cfg, mesh, attn, ffn), cfg.remat,
-        attention.KEPT, attention.report_kept)
+        attention.KEPT + kda.KEPT, _report_kept)
 
 
 def forward_layers(

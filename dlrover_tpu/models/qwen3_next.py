@@ -463,8 +463,10 @@ def _block_fn(cfg: Qwen3NextConfig, mesh, kind: str):
     """A block is recomputed whole in the backward pass, but for the
     flash forward's output and ``lse``, its backward's residuals (129
     MiB a gated attention layer at 16384 tokens): the kernel runs once a
-    step. The delta rule's residual is a float32 state a chunk, 512 MiB
-    a layer, and stays recomputed."""
+    step. The delta rule's forward names its output and states too
+    (``ops/kda.py``'s own ``KEPT``): 128 + 512 MiB a layer, 3.75 GiB for
+    six layers where the step's plan has 1.1 free, so this block does
+    not keep them and the rule stays recomputed."""
     return stack.recompute(
         functools.partial(block, cfg, mesh, kind), cfg.remat,
         attention.KEPT, attention.report_kept)

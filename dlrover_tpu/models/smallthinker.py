@@ -54,11 +54,11 @@ from dlrover_tpu.models import moe, stack
 from dlrover_tpu.observability import trace
 from dlrover_tpu.ops import (
     apply_rope,
+    attention as attn_ops,
     embed_lookup,
     rms_norm,
     rope_frequencies,
 )
-from dlrover_tpu.ops.attention import flash_attention
 from dlrover_tpu.parallel.mesh import BATCH_AXES, SP
 
 Params = Dict[str, Any]
@@ -232,7 +232,8 @@ def attention(cfg: SmallThinkerConfig, mesh, lp: Params, y, rotary: bool,
             inv_freq = rope_frequencies(hd, cfg.rope_theta)
             q = apply_rope(q, positions, inv_freq)
             k = apply_rope(k, positions, inv_freq)
-    out = flash_attention(q, k, v, causal=True, mesh=mesh, window=window)
+    out = attn_ops.flash_attention(
+        q, k, v, causal=True, mesh=mesh, window=window)
     with trace.scope("attn_proj"):
         return out.reshape(b, s, h * hd) @ lp["wo"].astype(dt)
 
@@ -264,8 +265,20 @@ def _report_shapes(cfg: SmallThinkerConfig):
     trace.gauge("attn.full_layers", len(kinds) - windows)
     trace.gauge("attn.rotary_layers", sum(r for r, _ in kinds))
     trace.gauge("attn.group", cfg.n_heads // cfg.n_kv_heads)
+    trace.gauge("attn.out_kept", 0)  # 1 once a block keeps one (`_block_fn`)
     trace.gauge("layers.period", cfg.period)
     trace.provide_text("layers.pattern", lambda: cfg.pattern_string)
+
+
+def _block_fn(cfg: SmallThinkerConfig, mesh, rotary: bool,
+              window: Optional[int]):
+    """A block is recomputed whole in the backward pass, but for the
+    flash forward's output and ``lse``, its backward's residuals (114
+    MiB a layer at 16384 tokens, full or window): the kernel runs once a
+    step. q, k and v are recomputed."""
+    return stack.recompute(
+        functools.partial(block, cfg, mesh, rotary, window), cfg.remat,
+        attn_ops.KEPT, attn_ops.report_kept)
 
 
 def forward_layers(
@@ -279,9 +292,7 @@ def forward_layers(
         validate_for_mesh(cfg, mesh, seq_len=s, batch=b)
     _report_shapes(cfg)
     x = embed_lookup(params["embed"], tokens, mesh, cfg.dtype)
-    fns = {kind: stack.recompute(
-        functools.partial(block, cfg, mesh, *kind), cfg.remat)
-        for kind in set(cfg.kinds)}
+    fns = {kind: _block_fn(cfg, mesh, *kind) for kind in set(cfg.kinds)}
     return stack.walk(x, cfg.layout, _trees(params),
                       lambda kind, lp, x: (fns[kind](lp, x), None))[0]
 

@@ -83,10 +83,14 @@ forms a tile's state-free part again, takes its chunks in reverse, and
 differentiates the state-free part (the solve through ``dM = -T^T dW
 W^T`` under the diagonal; every decay factor ``y = x e^E`` through ``dx
 = dy e^E``, ``dE = dy y``; ``dg`` the reverse cumulative sum of ``dG``
-inside the chunk). The XLA form's backward is JAX's own, through
-rematerialised *segments* of 16 chunks (what autodiff would keep of the
-whole sequence, 3.4 GiB a layer at 8192 tokens, is the step's peak
-otherwise).
+inside the chunk). The forward rule names its output and those states
+(`KEPT`, both forms): a block whose checkpoint keeps the two
+(``models/stack.py recompute(keep=KEPT)``; ``kimi_linear``'s does,
+``qwen3_next``'s has not the memory) recomputes the rule's inputs in its
+backward pass and never the forward kernel. The XLA form's backward is
+JAX's own, through rematerialised *segments* of 16 chunks (what autodiff
+would keep of the whole sequence, 3.4 GiB a layer at 8192 tokens, is the
+step's peak otherwise).
 
 **The second form: one decay a head** (``chunk_gdn``; Gated DeltaNet,
 Qwen3-Next). ``g_t`` is a number a value head, ``hv`` value heads in
@@ -125,6 +129,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax, shard_map
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
@@ -961,8 +966,26 @@ def _rule_kernels(q, k, v, g, beta, chunk, interpret):
     return _rule_forward(q, k, v, g, beta, chunk, interpret, False)[0]
 
 
+#: the names the forward rule gives the rule's output and the states its
+#: chunks started from (both forms). With q, k, v, g, beta they are all
+#: the backward kernel reads, and the output is what ``norm_gate``'s
+#: backward reads: a checkpoint policy that keeps the two spares the
+#: recomputed forward the forward kernel (``models/stack.py
+#: recompute(keep=KEPT)``). Under ``nothing_saveable``, and outside a
+#: checkpoint, a name is an identity.
+KEPT = ("delta_out", "delta_states")
+
+
+def report_kept(name: str):
+    """A ``recompute(kept=)`` callback: the gauge ``kda.state_kept`` reads
+    1 once a block's checkpoint has met a forward's states and kept them."""
+    if name == KEPT[1]:
+        trace.gauge("kda.state_kept", 1)
+
+
 def _rule_kernels_fwd(q, k, v, g, beta, chunk, interpret):
     o, states = _rule_forward(q, k, v, g, beta, chunk, interpret, True)
+    o, states = checkpoint_name(o, KEPT[0]), checkpoint_name(states, KEPT[1])
     return o, (q, k, v, g, beta, states)
 
 

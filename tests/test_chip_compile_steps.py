@@ -160,18 +160,24 @@ def test_kimi_linear_expert_block_fwd_bwd_compiles(
                              sharding=one_chip)
 
     trace.gauge("attn.out_kept", 0)
+    trace.gauge("kda.state_kept", 0)
     compiled = jax.jit(_two_in_line(
         kimi_linear._block_fn(cfg, None, attn, "moe"))).lower(lp, x).compile()
     hlo = compiled.as_text()
     # latent attention without rotary still runs the 192 / 128 kernels,
     # the forward once a block since PR 46 (the block keeps its output
     # and lse; `nothing_saveable` made 2 + 1 of it); a KDA block runs
-    # none of them and keeps nothing: its own forward kernels run in the
-    # first block's forward and in both recomputed ones, the backward's
-    # once a block, all under the scope the device metrics select by
+    # none of them and, since PR 47, keeps the delta rule's output and
+    # states (64 + 256 MiB): the rule's forward kernel runs once a block
+    # where it made 2 + 1 (the second block's first forward now runs, up
+    # to that kernel, for the kept pair alone: the input pass 2 + 2), the
+    # output pass in the first block's forward and in both recomputed
+    # ones, the backwards once a block, all under the scope the device
+    # metrics select by
     flash = 1 if attn == "mla" else 0
     assert _kernel_calls(hlo, "attention_fwd") == 2 * flash
     assert trace.gauges()["attn.out_kept"] == flash
+    assert trace.gauges()["kda.state_kept"] == 1 - flash
     assert _kernel_calls(hlo, "attention_bwd") == 4 * flash
     assert _kernel_calls(hlo, "grouped_matmul") == 21
     assert _kernel_calls(hlo, "moe_rows_gated") == 5
@@ -182,8 +188,8 @@ def test_kimi_linear_expert_block_fwd_bwd_compiles(
         assert collections.Counter((n.split("/")[-2], next(
             s for s in ("kda_conv", "kda_chunk", "kda_out")
             if _in_scope(n, s))) for n in delta) == {
-            ("kda_bwd", "kda_chunk"): 2, ("kda_fwd", "kda_chunk"): 3,
-            ("kda_in_bwd", "kda_conv"): 2, ("kda_in_fwd", "kda_conv"): 3,
+            ("kda_bwd", "kda_chunk"): 2, ("kda_fwd", "kda_chunk"): 2,
+            ("kda_in_bwd", "kda_conv"): 2, ("kda_in_fwd", "kda_conv"): 4,
             ("kda_out_bwd", "kda_out"): 2, ("kda_out_fwd", "kda_out"): 3}
         assert trace.gauges()["kda.io_fused"] == 1
         # the XLA form of the passes took float32 copies of every
@@ -196,10 +202,11 @@ def test_kimi_linear_expert_block_fwd_bwd_compiles(
                     if _in_scope(line, "kda_chunk")]
     # two blocks' own temporaries fit beside the cell's 7.16 GiB of state
     # and 4.78 of float32 gradients (2.302 GiB the latent pair with its
-    # kept 65 MiB, 2.976 the KDA pair; one KDA block alone took 2.10,
-    # and 2.857 with the passes in XLA ops)
+    # kept 65 MiB; 2.960 the KDA pair with its kept 2 x 320 MiB, 2.976
+    # when it kept nothing; one KDA block alone took 2.10, and 2.857
+    # with the passes in XLA ops)
     assert compiled.memory_analysis().temp_size_in_bytes < (
-        2.5 if flash else 3.1) * 2**30
+        2.5 if flash else 3.0) * 2**30
     assert trace.gauges()["moe.rows_held"] == 8192
     assert trace.gauges()["moe.tail_rows"] == 57344
 

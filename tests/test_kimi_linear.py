@@ -265,6 +265,32 @@ def test_kda_layer_matches_the_token_by_token_form(built, config, kda_form):
     assert float(jnp.std(g[0, 0, 0])) > 0.0
 
 
+def test_a_recomputed_kda_block_keeps_the_rules_output_and_states(
+        built, kda_form):
+    """The family's own recompute keeps what the rule's forward kernel
+    leaves its backward, the float32 state a chunk among it (gauge
+    ``kda.state_kept``), where the kernels run; the XLA form names
+    nothing and is recomputed whole. (That loss and gradients are the
+    un-kept block's bit for bit: ``tests/test_kda_kept.py``.)"""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    fam, params, _ = built
+    cfg = dataclasses.replace(fam.cfg, remat=True)
+    assert cfg.pattern[1] == ("kda", "moe")
+    lp = jax.tree.map(lambda a: a[0], params["runs"][kimi_linear.run_name(1)])
+    x = jax.random.normal(jax.random.key(8), (1, 32, cfg.dim))
+    trace.gauge("kda.state_kept", 0)
+    states = [aval for aval, why in saved_residuals(
+        kimi_linear._block_fn(cfg, None, "kda", "moe"), lp, x)
+        if "named 'delta_states'" in why]
+    kernels = kda_form == "kernels"
+    assert len(states) == kernels
+    assert trace.gauges()["kda.state_kept"] == kernels
+    if kernels:  # (b, h, chunks of a padded tile, dv, dk)
+        assert states[0].dtype == jnp.float32 and states[0].shape[-2:] == (
+            cfg.kda_head_dim, cfg.kda_head_dim)
+
+
 def test_latent_attention_without_q_rank_or_rotary(built, config):
     """``xing4.latent_attention`` with one q matrix and no rotary is the
     function given a rotary of angle zero, and the plain form."""
@@ -356,6 +382,7 @@ def test_gauges_say_what_the_build_is(built):
     assert g["kda.layers"] == 4 and g["kda.heads"] == 4
     assert g["kda.head_dim"] == 16 and g["kda.chunk"] == 16
     assert g["kda.conv"] == 4
+    assert g["kda.state_kept"] == 0  # the tiny build recomputes nothing
     assert g["mla.rotary"] == 0 and g["mla.q_rank"] == 0
     assert g["mla.qk_head_dim"] == 24 and g["mla.kv_lora_rank"] == 16
     assert g["attn.scale"] == pytest.approx(24 ** -0.5)

@@ -247,14 +247,26 @@ def test_one_file_under_models_calls_jax_checkpoint():
     assert calls == {"stack.py"}
 
 
-def test_four_families_keep_the_flash_forwards_output_at_their_call_site():
+@pytest.mark.parametrize("names,keepers", [
+    (r"attention\.KEPT|attn_ops\.KEPT", {
+        "dots3.py", "qwen3_next.py", "xing4.py", "kimi_linear.py",
+        "smallthinker.py"}),
+    (r"kda\.KEPT", {"kimi_linear.py"}),
+])
+def test_families_keep_a_forward_kernels_residuals_at_their_call_site(
+        names, keepers):
     """The keep is each family's own choice where it calls `recompute`
-    (its cell has the memory), not a rule of `stack.py` or of the
-    kernels: the families whose cells have no room name nothing."""
+    (its cell's planned peak has the room), not a rule of `stack.py` or
+    of the kernels: the flash forward's pair in five files, the delta
+    rule's in kimi's alone (qwen3next's step has not the room); Llama,
+    `moe.py`'s layer and ViT name nothing."""
     sources = _sources()
     assert {name for name, text in sources.items()
+            if re.search(names, text)} == keepers
+    assert {name for name, text in sources.items()
             if re.search(r"\bKEPT\b", text)} == {
-        "dots3.py", "qwen3_next.py", "xing4.py", "kimi_linear.py"}
+        "dots3.py", "qwen3_next.py", "xing4.py", "kimi_linear.py",
+        "smallthinker.py"}
     assert "KEPT" not in sources["stack.py"]
 
 

@@ -5,6 +5,7 @@ the layout read from the config's two lists; what the router reads;
 ReGLU; the share of the experts tied to the uncut layer; the meshes."""
 
 import dataclasses
+import functools
 import json
 import os
 
@@ -229,6 +230,37 @@ def test_the_experts_are_reglu(built, config):
         moe.moe_mlp(dataclasses.replace(mcfg, expert_act="gelu"), lp, y)
 
 
+@pytest.mark.parametrize("layer,kind", [(0, (False, None)), (1, (True, 16))])
+def test_a_recomputed_block_keeps_the_flash_forwards_pair(built, layer, kind):
+    """A full and a window block under the family's own recompute keep
+    the flash forward's output and ``lse`` and none of q, k, v (gauge
+    ``attn.out_kept``); loss and gradients are the block's own."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    fam, params, tokens = built
+    cfg = dataclasses.replace(fam.cfg, remat=True)
+    assert cfg.kinds[layer] == kind
+    lp = smallthinker.layer_params(cfg, params, layer)
+    x = params["embed"][tokens]
+    b, s, _ = x.shape
+    h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    trace.gauge("attn.out_kept", 0)
+    fn = smallthinker._block_fn(cfg, None, *kind)
+    saved = [tuple(aval.shape) for aval, _ in saved_residuals(fn, lp, x)]
+    assert (b, s, h, hd) in saved and (b, h, s) in saved
+    assert (b, s, kvh, hd) not in saved and saved.count((b, s, h, hd)) == 1
+
+    def grads(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda lp, x: jnp.sum(fn(lp, x) ** 2), argnums=(0, 1)))(lp, x)
+
+    got = grads(fn)
+    assert trace.gauges()["attn.out_kept"] == 1
+    want = grads(functools.partial(smallthinker.block, cfg, None, *kind))
+    for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
 def test_the_four_shares_add_up(config, mesh):
     """Four chips share a layer's 16 experts, four each. The routed parts
     the four shares compute are the uncut layer of the plain form."""
@@ -319,6 +351,7 @@ def test_gauges_say_what_the_build_is(built):
     assert g["attn.window"] == 16 and g["attn.window_layers"] == 6
     assert g["attn.full_layers"] == 2 and g["attn.rotary_layers"] == 6
     assert g["attn.group"] == 2 and g["layers.period"] == 4
+    assert g["attn.out_kept"] == 0  # the tiny build recomputes nothing
     assert g["moe.route_on"] == 1 and g["moe.act"] == 1
     assert g["moe.experts"] == 8 and g["moe.experts_held"] == 4
     assert g["moe.rows_held"] == 2 * 48 * 2 * 4 / 8
