@@ -1,0 +1,2 @@
+"""``sala_attn_proj_ms``: see ``sala_attn_proj_ms.json``."""
+from benchmarks.harness.hlo_scopes import scoped_ms_per_step as read  # noqa: F401

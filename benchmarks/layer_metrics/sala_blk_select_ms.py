@@ -1,0 +1,2 @@
+"""``sala_blk_select_ms``: see ``sala_blk_select_ms.json``."""
+from benchmarks.harness.hlo_scopes import scoped_ms_per_step as read  # noqa: F401

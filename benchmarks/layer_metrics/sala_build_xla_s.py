@@ -1,0 +1,2 @@
+"""``sala_build_xla_s``: see ``sala_build_xla_s.json``."""
+from benchmarks.harness.program_spans import counter_seconds_mean as read  # noqa: F401

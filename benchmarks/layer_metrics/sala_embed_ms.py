@@ -1,0 +1,2 @@
+"""``sala_embed_ms``: see ``sala_embed_ms.json``."""
+from benchmarks.harness.hlo_scopes import scoped_ms_per_step as read  # noqa: F401

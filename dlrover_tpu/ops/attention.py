@@ -55,6 +55,21 @@ How the three kernels walk the (q, k) plane:
   The calls are named ``attention_fwd_sel``, ``attention_bwd_dq_sel``
   and ``attention_bwd_dkv_sel``. A call without ``select`` has no such
   operand (it is not passed as "all").
+- **select by blocks** (``select=``, ``select_block=n``): ``select (b,
+  hkv, s, s / n)`` int8 is nonzero where the queries of a key-value
+  group see a *block* of ``n`` keys (``ops/blocksel.py pick_blocks``: a
+  model whose groups choose their own blocks); within a chosen block a
+  query sees the keys at or before it. A key-level mask a group would be
+  ``n`` times the bytes (512 MiB a layer at 16384 positions and two
+  groups, against 8), so the kernels widen a tile themselves: forward
+  and dq hold a q block's whole strip of blocks and spread it over a k
+  block's lanes by one small product with a 0 / 1 matrix (a group's
+  heads share it), dk/dv reads the transposed selection's ``block_k /
+  n`` rows of its k block and repeats each over its keys' sublanes. The
+  walk is the causal walk here too: the queries of a tile choose apart,
+  and their union leaves next to no tile out. The calls are named
+  ``attention_fwd_blk``, ``attention_bwd_dq_blk`` and
+  ``attention_bwd_dkv_blk``.
 - **operands** go to the MXU in the dtype they arrive in (bf16 under
   ``activation_dtype: bfloat16``, f32 in the CPU tests) and accumulate
   in f32; ``P`` and ``dS`` are rounded to that dtype before their
@@ -122,8 +137,9 @@ def mha_reference_with_lse(
     wider than ``q`` and ``k`` (latent attention: 192 against 128);
     ``scale`` None is ``1 / sqrt(d)`` of the q/k width. ``window`` (with
     ``causal``): a query sees its own position and the ``window - 1``
-    before it. ``select (b, sq, sk)``: nonzero where the query sees
-    the key, the whole mask (it replaces the positions' comparison)."""
+    before it. ``select (b, sq, sk)``, or ``(b, hkv, sq, sk)`` for one a
+    key-value head: nonzero where the query sees the key, the whole mask
+    (it replaces the positions' comparison)."""
     assert window is None or causal, "a window is causal"
     b, sq, h, d = q.shape
     hkv = k.shape[2]
@@ -135,7 +151,9 @@ def mha_reference_with_lse(
     qf = q.astype(jnp.float32) * scale
     logits = jnp.einsum("bqhd,bkhd->bhqk", qf, k.astype(jnp.float32))
     if select is not None:
-        logits = jnp.where((select != 0)[:, None], logits, _NEG_INF)
+        seen = (select != 0)[:, None] if select.ndim == 3 else jnp.repeat(
+            select != 0, group, axis=1)
+        logits = jnp.where(seen, logits, _NEG_INF)
     elif causal:
         qpos = q_offset + jnp.arange(sq)
         kpos = k_offset + jnp.arange(k.shape[1])
@@ -438,12 +456,13 @@ def _causal_mask(qi, ki, group: int, block_q: int, block_k: int,
     return (qpos >= kpos) & (qpos - kpos < window)
 
 
-def _name_suffix(window: Optional[int], select: bool = False) -> str:
+def _name_suffix(window: Optional[int], select: bool = False,
+                 select_block: int = 0) -> str:
     """What a window call's or a selection call's kernels are named by,
     after the prefix the plain calls have: a trace tells the kinds of
     layer apart."""
     if select:
-        return "_sel"
+        return "_blk" if select_block else "_sel"
     return "" if window is None else "_swa"
 
 
@@ -456,12 +475,52 @@ def _split_select(refs, select: bool):
 def _selected(sel_ref, group: int):
     """(group * rows, cols) bool from the selection's (1, rows, cols)
     int8 tile; the group's heads repeat the q block's rows."""
-    seen = sel_ref[0].astype(jnp.int32) != 0
+    return _over_group(sel_ref[0].astype(jnp.int32) != 0, group)
+
+
+def _over_group(seen, group: int):
+    """(rows, cols) bool -> (group * rows, cols): the group's heads
+    repeat the q block's rows."""
     if group == 1:
         return seen
     rows, cols = seen.shape
     return jnp.broadcast_to(
         seen[None], (group, rows, cols)).reshape(group * rows, cols)
+
+
+def _selected_blocks(sel_ref, qi, ki, group: int, block_q: int,
+                     block_k: int, select_block: int):
+    """(group * block_q, block_k) bool from a q block's strip of the
+    selection by blocks, ``(1, 1, block_q, s / select_block)`` int8: key
+    ``t`` of the k block takes the column of its block (a product with a
+    0 / 1 matrix spreads the strip over the lanes), under the causal
+    mask."""
+    strip = sel_ref[0, 0].astype(jnp.int32).astype(jnp.float32)
+    n = strip.shape[1]
+    shape = (n, block_k)
+    key = ki * block_k + lax.broadcasted_iota(jnp.int32, shape, 1)
+    spread = (lax.broadcasted_iota(jnp.int32, shape, 0)
+              == key // select_block).astype(jnp.bfloat16)
+    seen = _dot(strip.astype(jnp.bfloat16), spread, _NN) > 0.5
+    return _over_group(seen & _causal_mask(qi, ki, 1, block_q, block_k),
+                       group)
+
+
+def _selected_blocks_t(sel_ref, qi, ki, block_q: int, block_k: int,
+                       select_block: int):
+    """(block_k, block_q) bool from the transposed selection's rows of
+    this k block, ``(1, 1, 1, block_k / select_block, block_q)`` int8:
+    each row repeated over its block's keys (sublanes), under the causal
+    mask."""
+    rows = sel_ref[0, 0, 0].astype(jnp.int32) != 0
+    seen = jnp.concatenate([
+        jnp.broadcast_to(rows[r:r + 1], (select_block, block_q))
+        for r in range(block_k // select_block)])
+    kpos = ki * block_k + lax.broadcasted_iota(
+        jnp.int32, (block_k, block_q), 0)
+    qpos = qi * block_q + lax.broadcasted_iota(
+        jnp.int32, (block_k, block_q), 1)
+    return seen & (qpos >= kpos)
 
 
 def _compiler_params():
@@ -479,7 +538,7 @@ def _flash_fwd_kernel(
     q_ref, k_ref, v_ref, *rest,
     group: int, block_q: int, block_k: int, n_kblocks: int,
     causal: bool, scale: float, window: Optional[int] = None,
-    select: bool = False
+    select: bool = False, select_block: int = 0
 ):
     sel_ref, (o_ref, lse_ref, acc_ref, m_ref, l_ref) = _split_select(
         rest, select)
@@ -513,7 +572,11 @@ def _flash_fwd_kernel(
         k = k_ref[0, 0]                                      # (bk, d)
         v = v_ref[0, 0]
         s = _dot(q, k, _NT) * scale                          # (G*bq, bk) f32
-        if select:
+        if select_block:
+            s = jnp.where(_selected_blocks(
+                sel_ref, qi, ki, group, block_q, block_k, select_block),
+                s, _NEG_INF)
+        elif select:
             s = jnp.where(_selected(sel_ref, group), s, _NEG_INF)
         elif causal:
             s = jnp.where(
@@ -562,12 +625,17 @@ def _kv_specs(block_k: int, d: int, dv: int, causal: bool, block_q: int,
 
 
 def _select_specs(select, block_q: int, block_k: int, causal: bool,
-                  n_k: int):
+                  n_k: int, select_block: int = 0):
     """The selection's BlockSpec of the (b, hkv, n_q, n_k) grids, as a
     list (empty without one): the (block_q, block_k) tile of the K / V
-    block's step, every head's the same."""
+    block's step, every head's the same; of a selection by blocks the
+    key-value head's own strip of the q block, whole (fetched once a q
+    block)."""
     if select is None:
         return []
+    if select_block:
+        return [pl.BlockSpec((1, 1, block_q, select.shape[-1]),
+                             lambda bi, hi, qi, ki: (bi, hi, qi, 0))]
 
     def index(bi, hi, qi, ki):
         if causal:
@@ -585,7 +653,8 @@ def _operands(select):
 def _flash_fwd_pallas(q, k, v, causal: bool, block_q: int, block_k: int,
                       interpret: bool = False,
                       scale: Optional[float] = None,
-                      window: Optional[int] = None, select=None):
+                      window: Optional[int] = None, select=None,
+                      select_block: int = 0):
     b, sq, h, d = q.shape
     sk, hkv, dv = k.shape[1], k.shape[2], v.shape[3]
     group = h // hkv
@@ -611,12 +680,13 @@ def _flash_fwd_pallas(q, k, v, causal: bool, block_q: int, block_k: int,
             _flash_fwd_kernel, group=group, block_q=block_q,
             block_k=block_k, n_kblocks=n_k, causal=causal,
             scale=_scale_for(d, scale), window=window,
-            select=select is not None,
+            select=select is not None, select_block=select_block,
         ),
         grid=(b, hkv, n_q, k_steps),
         in_specs=[q_rows(d), *_kv_specs(block_k, d, dv, causal, block_q,
                                         n_k, window),
-                  *_select_specs(select, block_q, block_k, causal, n_k)],
+                  *_select_specs(select, block_q, block_k, causal, n_k,
+                                 select_block)],
         out_specs=[q_rows(dv), q_rows(_LSE_LANES)],
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, group, sq, dv), q.dtype),
@@ -630,7 +700,8 @@ def _flash_fwd_pallas(q, k, v, causal: bool, block_q: int, block_k: int,
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
-        name="attention_fwd" + _name_suffix(window, select is not None),
+        name="attention_fwd" + _name_suffix(window, select is not None,
+                                            select_block),
     )(qt, kt, vt, *_operands(select))
     out = out.reshape(b, h, sq, dv).transpose(0, 2, 1, 3)
     return out, lse.reshape(b, h, sq, _LSE_LANES)[..., 0]
@@ -657,7 +728,7 @@ def _flash_bwd_dq_kernel(
     q_ref, k_ref, v_ref, *rest,
     group: int, block_q: int, block_k: int, n_kblocks: int,
     causal: bool, scale: float, window: Optional[int] = None,
-    select: bool = False
+    select: bool = False, select_block: int = 0
 ):
     sel_ref, (do_ref, lse_ref, delta_ref, dq_ref, acc_ref) = _split_select(
         rest, select)
@@ -682,7 +753,11 @@ def _flash_bwd_dq_kernel(
         lse = lse_ref[0, 0].reshape(rows, _LSE_LANES)[:, :1]     # (G*bq, 1)
         delta = delta_ref[0, 0].reshape(rows, _LSE_LANES)[:, :1]
         s = _dot(q, k, _NT) * scale
-        if select:
+        if select_block:
+            s = jnp.where(_selected_blocks(
+                sel_ref, qi, ki, group, block_q, block_k, select_block),
+                s, _NEG_INF)
+        elif select:
             s = jnp.where(_selected(sel_ref, group), s, _NEG_INF)
         elif causal:
             s = jnp.where(
@@ -702,7 +777,7 @@ def _flash_bwd_dkv_kernel(
     q_ref, k_ref, v_ref, *rest,
     block_q: int, block_k: int, n_qblocks: int, causal: bool,
     scale: float, window: Optional[int] = None, q_steps: int = 0,
-    select: bool = False
+    select: bool = False, select_block: int = 0
 ):
     # with a selection, its transposed (block_k, block_q) tile
     sel_ref, (do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
@@ -741,7 +816,11 @@ def _flash_bwd_dkv_kernel(
         lse = lse_ref[0, 0]                                   # (1, bq)
         delta = delta_ref[0, 0]
         st = _dot(k, q, _NT) * scale                          # (bk, bq)
-        if select:
+        if select_block:
+            st = jnp.where(_selected_blocks_t(
+                sel_ref, qi, ki, block_q, block_k, select_block),
+                st, _NEG_INF)
+        elif select:
             st = jnp.where(_selected(sel_ref, 1), st, _NEG_INF)
         elif causal:
             kpos = ki * block_k + lax.broadcasted_iota(
@@ -769,7 +848,8 @@ def _flash_bwd_dkv_kernel(
 
 def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
                       dq_tiles, dkv_tiles, interpret=False, scale=None,
-                      window: Optional[int] = None, select=None):
+                      window: Optional[int] = None, select=None,
+                      select_block: int = 0):
     b, sq, h, d = q.shape
     sk, hkv, dv = k.shape[1], k.shape[2], v.shape[3]
     group = h // hkv
@@ -808,12 +888,14 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
             _flash_bwd_dq_kernel, group=group, block_q=block_q,
             block_k=block_k, n_kblocks=n_k, causal=causal, scale=scale,
             window=window, select=select is not None,
+            select_block=select_block,
         ),
         grid=(b, hkv, n_q, k_steps),
         in_specs=[
             q_rows(d),
             *_kv_specs(block_k, d, dv, causal, block_q, n_k, window),
-            *_select_specs(select, block_q, block_k, causal, n_k),
+            *_select_specs(select, block_q, block_k, causal, n_k,
+                           select_block),
             q_rows(dv), q_rows(_LSE_LANES), q_rows(_LSE_LANES),
         ],
         out_specs=q_rows(d),
@@ -821,7 +903,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
         scratch_shapes=[pltpu.VMEM((group * block_q, d), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
-        name="attention_bwd_dq" + _name_suffix(window, select is not None),
+        name="attention_bwd_dq" + _name_suffix(window, select is not None,
+                                               select_block),
     )(
         qt.reshape(b, hkv, group, sq, d), kt, vt, *_operands(select),
         dot.reshape(b, hkv, group, sq, dv), lanes8(lse), lanes8(delta),
@@ -857,19 +940,37 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
         return pl.BlockSpec((1, 1, block_k, lanes),
                             lambda bi, hi, i, j: (bi, hi, i, 0))
 
+    # dk/dv computes transposed tiles: the selection transposed, once
+    # (by blocks: its rows a k block, ``block_k / select_block`` of them)
+    if select is None:
+        select_t, select_t_specs = (), []
+    elif select_block:
+        if block_k % select_block:
+            raise ValueError(f"flash attention: a k block of {block_k} "
+                             f"keys is no whole blocks of {select_block}")
+        select_t = (jnp.swapaxes(select, 2, 3).reshape(
+            b, hkv, n_k, block_k // select_block, sq),)
+        select_t_specs = [pl.BlockSpec(
+            (1, 1, 1, block_k // select_block, block_q),
+            lambda bi, hi, i, j: (bi, hi, i, 0, q_head(bi, hi, i, j)[2]))]
+    else:
+        select_t = (jnp.swapaxes(select, 1, 2),)
+        select_t_specs = [pl.BlockSpec(
+            (1, block_k, block_q),
+            lambda bi, hi, i, j: (bi, i, q_head(bi, hi, i, j)[2]))]
+
     dkh, dvh = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
             n_qblocks=n_q, causal=causal, scale=scale, window=window,
             q_steps=q_steps, select=select is not None,
+            select_block=select_block,
         ),
         grid=(b, hkv, n_k, group * q_steps),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), q_head),
             kv_block(d), kv_block(dv),
-            *([] if select is None else [pl.BlockSpec(
-                (1, block_k, block_q),
-                lambda bi, hi, i, j: (bi, i, q_head(bi, hi, i, j)[2]))]),
+            *select_t_specs,
             pl.BlockSpec((1, 1, block_q, dv), q_head),
             pl.BlockSpec((1, 1, 1, block_q), q_head_row),
             pl.BlockSpec((1, 1, 1, block_q), q_head_row),
@@ -885,11 +986,9 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, causal,
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
-        name="attention_bwd_dkv" + _name_suffix(window, select is not None),
-    )(qt, kt, vt,
-      # dk/dv computes transposed tiles: the selection transposed, once
-      *(() if select is None else (jnp.swapaxes(select, 1, 2),)),
-      dot, lse.reshape(b, h, 1, sq), delta.reshape(b, h, 1, sq))
+        name="attention_bwd_dkv" + _name_suffix(window, select is not None,
+                                                select_block),
+    )(qt, kt, vt, *select_t, dot, lse.reshape(b, h, 1, sq), delta.reshape(b, h, 1, sq))
 
     dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
     return dq, dkh.transpose(0, 2, 1, 3), dvh.transpose(0, 2, 1, 3)
@@ -997,51 +1096,83 @@ def _flash_with_lse_bwd(causal, block_q, block_k, interpret, scale, window,
 flash_attention_with_lse.defvjp(_flash_with_lse_fwd, _flash_with_lse_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def flash_attention_select_with_lse(q, k, v, select,
                                     block_q: Optional[int] = None,
                                     block_k: Optional[int] = None,
                                     interpret: bool = False,
-                                    scale: Optional[float] = None):
-    """Causal self-attention over the keys ``select`` names: ``select
-    (b, s, s)`` int8, nonzero where the query sees the key, already under
-    the causal mask (every row sees at least one key). ``(out, lse)`` as
-    `flash_attention_with_lse`, differentiable in q, k and v; the
-    selection gets no gradient. On the TPU (and under ``interpret``) the
-    ``_sel`` kernels, off it the jnp reference under the same mask."""
+                                    scale: Optional[float] = None,
+                                    select_block: int = 0):
+    """Causal self-attention over the keys ``select`` names, int8, in one
+    of two forms. ``select_block`` 0: ``(b, s, s)``, nonzero where the
+    query sees the key, already under the causal mask, one mask for every
+    head of a batch row. ``select_block`` ``n``: ``(b, hkv, s, s / n)``,
+    nonzero where the queries of a key-value head's group see a block of
+    ``n`` keys, of which a query sees those at or before it. Every row
+    sees at least one key. ``(out, lse)`` as `flash_attention_with_lse`,
+    differentiable in q, k and v; the selection gets no gradient. On the
+    TPU (and under ``interpret``) the ``_sel`` (``_blk``) kernels, off it
+    the jnp reference under the same mask."""
     return _flash_select_fwd(q, k, v, select, block_q, block_k, interpret,
-                             scale)[0]
+                             scale, select_block)[0]
 
 
-def _check_select(q, k, select):
+def _check_select(q, k, select, select_block: int = 0):
     b, sq = q.shape[:2]
-    if (q.shape[1] != k.shape[1] or select.shape != (b, sq, sq)
-            or select.dtype != jnp.int8):
+    want = (b, k.shape[2], sq, sq // select_block) if select_block else (
+        b, sq, sq)
+    if (sq != k.shape[1] or select.shape != want
+            or select.dtype != jnp.int8
+            or (select_block and sq % select_block)):
         raise ValueError(
-            f"select {select.shape} {select.dtype}: a selection is one int8 "
-            f"(batch, seq, seq) mask of causal self-attention (q "
-            f"{q.shape}, k {k.shape})")
+            f"select {select.shape} {select.dtype}: a selection of causal "
+            "self-attention is int8, one (batch, seq, seq) mask for every "
+            "head of a row or, with select_block, one (batch, kv heads, "
+            f"seq, seq / select_block) choice of blocks a key-value head "
+            f"(wanted {want}: q {q.shape}, k {k.shape}, select_block "
+            f"{select_block})")
 
 
-def _flash_select_fwd(q, k, v, select, block_q, block_k, interpret, scale):
-    _check_select(q, k, select)
+def select_by_keys(select, select_block: int):
+    """A selection by blocks as the key-level mask a key-value head, ``(b,
+    hkv, s, s)`` bool under the causal mask: what the jnp reference (and
+    a test) reads. ``s / select_block`` times the bytes."""
+    s = select.shape[2]
+    pos = jnp.arange(s, dtype=jnp.int32)
+    return (jnp.repeat(select != 0, select_block, axis=3)
+            & (pos[None, :] <= pos[:, None]))
+
+
+def _reference_select(q, k, v, select, scale, select_block):
+    if select_block:
+        select = select_by_keys(select, select_block)
+    return mha_reference_with_lse(q, k, v, causal=True, scale=scale,
+                                  select=select)
+
+
+def _flash_select_fwd(q, k, v, select, block_q, block_k, interpret, scale,
+                      select_block=0):
+    _check_select(q, k, select, select_block)
     with trace.scope("attention_fwd"):
         if interpret or _on_tpu():
             tiles = _tiles_for(q, k, v, block_q, block_k)
-            if block_q is None:
+            if block_q is None and select_block:
+                _report_tiles(*tiles["fwd"])
+            elif block_q is None:
                 trace.gauge("attn.select_block_q", tiles["fwd"][0])
                 trace.gauge("attn.select_block_k", tiles["fwd"][1])
             out, lse = _flash_fwd_pallas(
                 q, k, v, True, *tiles["fwd"], interpret=interpret,
-                scale=scale, select=select)
+                scale=scale, select=select, select_block=select_block)
         else:
-            out, lse = mha_reference_with_lse(
-                q, k, v, causal=True, scale=scale, select=select)
+            out, lse = _reference_select(q, k, v, select, scale,
+                                         select_block)
     out, lse = _named(out, lse)
     return (out, lse), (q, k, v, select, out, lse)
 
 
-def _flash_select_bwd(block_q, block_k, interpret, scale, res, g):
+def _flash_select_bwd(block_q, block_k, interpret, scale, select_block,
+                      res, g):
     q, k, v, select, o, lse = res
     g_out, g_lse = g
     no_grad = np.zeros(select.shape, jax.dtypes.float0)
@@ -1051,10 +1182,10 @@ def _flash_select_bwd(block_q, block_k, interpret, scale, res, g):
             return _flash_bwd_pallas(
                 q, k, v, o, lse, g_out, g_lse, True, tiles["dq"],
                 tiles["dkv"], interpret=interpret, scale=scale,
-                select=select) + (no_grad,)
+                select=select, select_block=select_block) + (no_grad,)
         _, vjp = jax.vjp(
-            lambda q, k, v: mha_reference_with_lse(
-                q, k, v, causal=True, scale=scale, select=select),
+            lambda q, k, v: _reference_select(q, k, v, select, scale,
+                                              select_block),
             q, k, v)
         return vjp((g_out, g_lse)) + (no_grad,)
 
@@ -1069,16 +1200,18 @@ def flash_attention(q, k, v, causal: bool = True,
                     mesh: Optional[Mesh] = None,
                     scale: Optional[float] = None,
                     window: Optional[int] = None,
-                    select=None, return_lse: bool = False):
+                    select=None, return_lse: bool = False,
+                    select_block: int = 0):
     """``mesh``: the mesh the caller's jit partitions over. The compiler
     partitions the reference path itself, but not a Mosaic kernel
     ("cannot be automatically partitioned"), so over more than one
     device the kernels run under ``shard_map`` on each device's batch
     rows (data axes) and heads (tp), every sequence whole. Callers
     already inside a manual ``shard_map`` (ring, ulysses, the pp
-    stages) pass no mesh. ``select``: see
-    `flash_attention_select_with_lse` (causal, no window; every head of
-    a batch row reads the one mask). ``return_lse``: ``(out, lse (b, h,
+    stages) pass no mesh. ``select``, ``select_block``: see
+    `flash_attention_select_with_lse` (causal, no window; one key-level
+    mask for every head of a batch row or, with ``select_block``, a
+    choice of blocks a key-value head). ``return_lse``: ``(out, lse (b, h,
     s))`` instead of ``out``."""
     both = (lambda r: r) if return_lse else (lambda r: r[0])
     if select is not None:
@@ -1089,7 +1222,8 @@ def flash_attention(q, k, v, causal: bool = True,
 
         def attn(q, k, v, select):
             return both(flash_attention_select_with_lse(
-                q, k, v, select, block_q, block_k, interpret, scale))
+                q, k, v, select, block_q, block_k, interpret, scale,
+                select_block))
 
         operands = (q, k, v, select)
     else:
@@ -1102,7 +1236,9 @@ def flash_attention(q, k, v, causal: bool = True,
     if mesh is None or mesh.size == 1 or not (interpret or _on_tpu()):
         return attn(*operands)
     spec = P(BATCH_AXES, None, TP, None)
-    specs = (spec,) * 3 + (P(BATCH_AXES, None, None),) * (select is not None)
+    specs = (spec,) * 3 + (
+        P(BATCH_AXES, TP, None, None) if select_block
+        else P(BATCH_AXES, None, None),) * (select is not None)
     return shard_map(
         attn, mesh=mesh, in_specs=specs,
         out_specs=(spec, P(BATCH_AXES, TP, None)) if return_lse else spec,

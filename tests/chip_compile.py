@@ -23,7 +23,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from dlrover_tpu.ops import (
-    attention, dsa, fused_ce, grouped_matmul, kda, moe_rows)
+    attention, blocksel, dsa, fused_ce, grouped_matmul, kda, lightning,
+    moe_rows)
 
 
 @pytest.fixture(scope="module")
@@ -62,6 +63,8 @@ def kernels_are_the_path(monkeypatch):
     monkeypatch.setattr(kda, "_on_tpu", lambda: True)
     monkeypatch.setattr(moe_rows, "_on_tpu", lambda: True)
     monkeypatch.setattr(dsa, "_on_tpu", lambda: True)
+    monkeypatch.setattr(lightning, "_on_tpu", lambda: True)
+    monkeypatch.setattr(blocksel, "_on_tpu", lambda: True)
     monkeypatch.setenv("DLROVER_TPU_FUSED_CE", "1")
 
 

@@ -23,7 +23,8 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from dlrover_tpu.observability import trace
-from dlrover_tpu.ops import attention, dsa, fused_ce, kda
+from dlrover_tpu.ops import (
+    attention, blocksel, dsa, fused_ce, kda, lightning)
 from dlrover_tpu.parallel import MeshConfig, build_mesh
 from dlrover_tpu.parallel.mesh import BATCH_AXES
 from tests.chip_compile import (  # noqa: F401  (fixtures by import)
@@ -224,6 +225,90 @@ def test_dsa_probs_kernel_compiles_at_the_cell_shape(
             q, k, lse, mask, 192 ** -0.5), q, q, lse, mask)
     assert hlo.count("tpu_custom_call") == 1 and "dsa_probs" in hlo
     assert not re.search(r"32,8192,8192\]|8192,8192,32\]", hlo)
+
+
+# minicpm-sala-d4-1chip-steady (PR 48): b1, s16384. The minicpm4 layer's
+# 32 query heads on 2 key heads of 128 (group 16) read a choice of blocks a
+# key-value head, (1, 2, 16384, 256) int8, in all three kernels: forward
+# and dq a q block's whole strip, spread over a k block's lanes by a
+# product with a 0 / 1 matrix; dk/dv the transposed choice's 16 rows of
+# its k block, each repeated over 64 sublanes. No key-level mask exists.
+def test_flash_minicpm_sala_cell_compiles_at_chosen_tiles(
+        one_chip, kernels_are_the_path):
+    q = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, 16384, 2, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    chosen = jax.ShapeDtypeStruct((1, 2, 16384, 256), jnp.int8,
+                                  sharding=one_chip)
+
+    def loss(q, k, v, chosen):
+        return attention.flash_attention(
+            q, k, v, select=chosen, select_block=64).astype(jnp.float32).sum()
+
+    assert attention.flash_tiles(16384, 16384, 128, 16, jnp.bfloat16) == {
+        "fwd": (128, 512), "dq": (128, 512), "dkv": (1024, 1024)}
+    hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, k, k, chosen)
+    assert hlo.count("tpu_custom_call") == 3
+    for name in ("attention_fwd", "attention_bwd_dq", "attention_bwd_dkv"):
+        assert re.search(rf"%{name}_blk(\.\d+)? = ", hlo), name
+    assert "s8[1,2,16384,256]" in hlo
+    assert not re.search(r"\[1,(2|32),16384,16384\]", hlo)
+
+
+# The choice at the same cell: one call of the scoring kernel holds a
+# group's 1023 pooled keys whole and stores (2, 16384, 256) float32; the
+# threshold is XLA's passes over that. No (32, 16384, 1023) array.
+def test_block_score_kernel_compiles_at_the_cell_shape(
+        one_chip, kernels_are_the_path):
+    q = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, 16384, 2, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def choose(q, k):
+        scores = blocksel.block_scores(
+            q, blocksel.pooled_keys(k, 32, 16), block=64, kernel=32,
+            stride=16, scale=128 ** -0.5)
+        return blocksel.pick_blocks(scores, block=64, topk=64,
+                                    init_blocks=1, window=2048)
+
+    compiled = jax.jit(choose).lower(q, k).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 1
+    assert re.search(r"%blk_score(\.\d+)? = ", hlo)
+    assert "s8[1,2,16384,256]" in hlo
+    assert not re.search(r"16384,10(23|24)\]|10(23|24),16384\]", hlo)
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2**20
+    assert trace.gauges()["attn.blk_score_kernel"] == 1
+
+
+# The lightning rule at the same cell: 32 heads of 128, 64 chunks of 256.
+# One call forward; under differentiation the forward with a float32
+# state a chunk (128 MiB) and the hand-written backward.
+def test_lightning_kernels_compile_at_the_cells_shape(
+        one_chip, kernels_are_the_path):
+    x = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    slopes = jax.ShapeDtypeStruct((32,), jnp.float32, sharding=one_chip)
+
+    def loss(q, k, v, slopes):
+        with jax.named_scope("la_chunk"):
+            return lightning.lightning_attention(
+                q, k, v, slopes, chunk=256).astype(jnp.float32).sum()
+
+    names = _op_names(_compile(loss, x, x, x, slopes))
+    assert len(names) == 1 and _in_scope(names[0], "la_chunk")
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x, slopes).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0 * 2**30
+    hlo = compiled.as_text()
+    names = _op_names(hlo)
+    assert len(names) == 2 and all(_in_scope(n, "la_chunk") for n in names)
+    assert _kernel_calls(hlo, "lightning_fwd") == 1
+    assert _kernel_calls(hlo, "lightning_bwd") == 1
+    assert "f32[1,32,64,128,128]" in hlo        # a state a chunk
+    assert trace.gauges()["la.kernel"] == 1
 
 
 # xing4-ep8-1chip-steady (PR 31): latent attention's kernels take q/k

@@ -87,6 +87,66 @@ def test_dots3_step_keeps_the_loss_gradient_in_the_memory_it_has(
     assert peak <= DOTS3_STEP_PEAK + 64 * 2**20 <= 15.75 * 2**30
 
 
+# minicpm-sala-d4-1chip-steady's whole step (PR 48), built the same way:
+# `step.hbm_planned_peak_bytes` here is the chip's `sala_hbm_peak_gib` to
+# the byte (15,213,162,496: 14.168 GiB of 15.75). The sparse block keeps
+# its choice of blocks and the flash pair, the three lightning blocks the
+# rule's output and states, so every forward kernel runs once a step.
+SALA_STEP_PLANNED_PEAK = 15213162496
+
+
+def test_minicpm_sala_step_fits_the_chip_with_every_forward_kernel_once(
+        topo, kernels_are_the_path):
+    import json
+
+    from benchmarks.families import minicpm_sala as family
+    from dlrover_tpu.lint import memcheck
+    from dlrover_tpu.train.trainer import ElasticTrainer, TrainConfig
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "minicpm-sala-9b-d4-1chip.json")) as f:
+        config = json.load(f)
+    mc = MeshConfig(dp=-1, **config.get("mesh", {})).resolve(1)
+    mesh = build_mesh(mc, devices=topo.devices[:1])
+    fam = family.build(config, mesh)
+    tc = TrainConfig(global_batch_size=1, micro_batch_size=1,
+                     **fam.train_config)
+    trainer = ElasticTrainer(fam.loss_fn, fam.param_specs, mesh, mc, tc)
+    params = jax.eval_shape(fam.init_params, jax.random.key(0))
+    state = {"params": params,
+             "opt": jax.eval_shape(trainer.optimizer.init, params),
+             "step": jax.ShapeDtypeStruct((), jnp.int32),
+             "lr_scale": jax.ShapeDtypeStruct((), jnp.float32)}
+    accum, per = trainer.step_batch_shape
+    trainer.record_avatars(
+        state, jax.ShapeDtypeStruct((accum, per, 16384), jnp.int32))
+    compiled, _ = trainer.lower_step(mesh, mc)
+
+    hlo = compiled.as_text()
+    assert fam.cfg.pattern_string == "SLLL"
+    # the lightning layers are one scan: a kernel of theirs is one call
+    # site of three trips
+    for name, calls in (("blk_score", 1), ("attention_fwd_blk", 1),
+                        ("attention_bwd_dq_blk", 1),
+                        ("attention_bwd_dkv_blk", 1), ("lightning_fwd", 1),
+                        ("lightning_bwd", 1), ("kda_out_fwd", 2),
+                        ("kda_out_bwd", 1)):
+        assert _kernel_calls(hlo, name) == calls, name
+    gauges = trace.gauges()
+    assert gauges["attn.out_kept"] == 1 and gauges["la.state_kept"] == 1
+    assert gauges["attn.blk_sparse"] == 1 and gauges["la.kernel"] == 1
+    assert (gauges["attn.block_q"], gauges["attn.block_k"]) == (128, 512)
+    assert "s8[1,2,16384,256]" in hlo
+    assert not re.search(r"\[1,(2|32),16384,16384\]", hlo)
+    read = memcheck.read_memory_analysis(compiled)
+    print(f"minicpm_sala step planned {read['planned_peak_bytes']} = "
+          f"{read['planned_peak_bytes'] / 2**30:.4f} GiB, summed "
+          f"{read['peak_bytes'] / 2**30:.4f}")
+    assert read["planned_peak_bytes"] <= (
+        SALA_STEP_PLANNED_PEAK + 64 * 2**20) <= 15.75 * 2**30
+
+
 def _two_in_line(fn):
     """The gradient's function of two blocks in line, built as the family
     builds them: the first one's output is wanted, so its forward runs;

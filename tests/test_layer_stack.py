@@ -13,7 +13,7 @@ import pytest
 from jax.ad_checkpoint import checkpoint_name
 
 from dlrover_tpu.models import (
-    dots3, kimi_linear, qwen3_next, smallthinker, stack)
+    dots3, kimi_linear, minicpm_sala, qwen3_next, smallthinker, stack)
 from dlrover_tpu.models.stack import Part
 
 MODELS = os.path.join(os.path.dirname(os.path.dirname(
@@ -72,8 +72,10 @@ def test_runs_are_stacked_parts_of_one_position():
      kimi_linear.KimiLinearConfig().pattern),
     (stack.periodic("ffFSFSF", head=2), tuple("ffFSFSF")),
     (qwen3_next.Qwen3NextConfig().layout, qwen3_next.Qwen3NextConfig().kinds),
+    (minicpm_sala.MiniCPMSalaConfig().layout,
+     minicpm_sala.MiniCPMSalaConfig().kinds),
 ], ids=["smallthinker", "dots3", "kimi_linear", "head_and_tail",
-        "qwen3_next"])
+        "qwen3_next", "minicpm_sala"])
 def test_locate_finds_every_layer_once_and_in_order(parts, kinds):
     seen = [stack.locate(parts, layer) for layer in range(len(kinds))]
     assert len(set(seen)) == len(kinds) and seen == sorted(
@@ -250,30 +252,32 @@ def test_one_file_under_models_calls_jax_checkpoint():
 @pytest.mark.parametrize("names,keepers", [
     (r"attention\.KEPT|attn_ops\.KEPT", {
         "dots3.py", "qwen3_next.py", "xing4.py", "kimi_linear.py",
-        "smallthinker.py"}),
+        "smallthinker.py", "minicpm_sala.py"}),
     (r"kda\.KEPT", {"kimi_linear.py"}),
+    (r"lightning\.KEPT", {"minicpm_sala.py"}),
 ])
 def test_families_keep_a_forward_kernels_residuals_at_their_call_site(
         names, keepers):
     """The keep is each family's own choice where it calls `recompute`
     (its cell's planned peak has the room), not a rule of `stack.py` or
-    of the kernels: the flash forward's pair in five files, the delta
-    rule's in kimi's alone (qwen3next's step has not the room); Llama,
-    `moe.py`'s layer and ViT name nothing."""
+    of the kernels: the flash forward's pair in six files, the delta
+    rule's in kimi's alone (qwen3next's step has not the room), the
+    lightning rule's in minicpm_sala's; Llama, `moe.py`'s layer and ViT
+    name nothing."""
     sources = _sources()
     assert {name for name, text in sources.items()
             if re.search(names, text)} == keepers
     assert {name for name, text in sources.items()
             if re.search(r"\bKEPT\b", text)} == {
         "dots3.py", "qwen3_next.py", "xing4.py", "kimi_linear.py",
-        "smallthinker.py"}
+        "smallthinker.py", "minicpm_sala.py"}
     assert "KEPT" not in sources["stack.py"]
 
 
 def test_no_family_walks_its_layers_or_shifts_its_targets_itself():
     sources = _sources()
     for name in ("kimi_linear.py", "smallthinker.py", "dots3.py",
-                 "qwen3_next.py"):
+                 "qwen3_next.py", "minicpm_sala.py"):
         assert "lax.scan(" not in sources[name], name
     assert {name for name, text in sources.items()
             if "_shift_targets" in text} == {"llama.py"}

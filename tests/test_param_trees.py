@@ -2,7 +2,8 @@
 with: the paths, shapes, dtypes and partition specs of each tiny
 configuration, and what ``init_params`` draws under one key, against a
 record taken at the commit before ``models/stack.py`` (PR 44's parent;
-``qwen3_next``'s at the PR that added it, 45) and kept in
+``qwen3_next``'s and ``minicpm_sala``'s at the PRs that added them, 45
+and 48) and kept in
 ``tests/golden/param_trees.json``. A saved state restores by path
 and shape, so a family that moves onto shared layout code must leave every
 line of its record as it is.
@@ -19,7 +20,8 @@ import numpy as np
 import pytest
 
 from dlrover_tpu.models import (
-    dots3, kimi_linear, llama, moe, qwen3_next, smallthinker, vit, xing4)
+    dots3, kimi_linear, llama, minicpm_sala, moe, qwen3_next, smallthinker,
+    vit, xing4)
 
 GOLDEN = os.path.join(os.path.dirname(__file__), "golden", "param_trees.json")
 
@@ -36,6 +38,7 @@ FAMILIES = {
     "dots3_head_and_tail": (dots3, dots3.Dots3Config.tiny(
         layer_kinds=(F, S, F, S, F, S, F), n_dense_layers=2)),
     "qwen3_next": (qwen3_next, qwen3_next.Qwen3NextConfig.tiny()),
+    "minicpm_sala": (minicpm_sala, minicpm_sala.MiniCPMSalaConfig.tiny()),
 }
 
 
