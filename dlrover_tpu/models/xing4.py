@@ -28,7 +28,11 @@ What is this family's own:
   Coefficients are formed in float32 (``hc_coeff``); the streams stay in
   the activation dtype and are mixed in float32 (``hc_mix``). ``u phi``
   is ``(vec(X) phi) / rms``: the product takes the streams as they are
-  stored and accumulates in float32, so nothing is rounded twice.
+  stored and accumulates in float32, so nothing is rounded twice. On
+  the TPU, where a stream's channels are whole lanes, the four places
+  where a sublayer touches the streams are ``ops/hc_mix.py``'s Pallas
+  passes (each slab read once a direction); anywhere else the ``jnp``
+  below, which is also their oracle (``hc_sublayer`` chooses).
 - **latent attention** (as ``deepseek_v3``): q through a rank
   ``q_lora_rank`` bottleneck with its own norm; k and v through one of
   rank ``kv_lora_rank``; ``qk_rope_dim`` rotary dims per head, the key's
@@ -64,6 +68,7 @@ from dlrover_tpu.ops import (
     attention,
     embed_lookup,
     flash_attention,
+    hc_mix,
     rms_norm,
     yarn_frequencies,
     yarn_mscale,
@@ -345,22 +350,11 @@ def validate_for_mesh(cfg: Xing4Config, mesh: Mesh, batch: int = 0) -> None:
 # Residual streams
 # ---------------------------------------------------------------------------
 
-def sinkhorn(m: jnp.ndarray, iters: int, eps: float) -> jnp.ndarray:
-    """``m (n, n, ...)`` positive -> doubly stochastic over its first two
-    axes: ``iters`` times, columns to sum one, then rows."""
-    for _ in range(iters):
-        m = m / (jnp.sum(m, axis=0, keepdims=True) + eps)
-        m = m / (jnp.sum(m, axis=1, keepdims=True) + eps)
-    return m
-
-
 def hc_coefficients(cfg: Xing4Config, phi, alpha, bias, X):
     """``X (n, b, s, d)`` -> ``H_pre (n, b, s)``, ``H_post (n, b, s)``,
     ``H_res (n, n, b, s)``, float32, tokens minor (a ``(.., n, n)``
     layout would leave 124 of a register's 128 lanes empty through the
     Sinkhorn's 40 normalisations)."""
-    n = cfg.hc_mult
-    lo, hi = cfg.hc_clamp
     with trace.scope("hc_coeff"):
         x32 = X.astype(jnp.float32)
         inv_rms = lax.rsqrt(jnp.mean(x32 * x32, axis=(0, 3)) + cfg.norm_eps)
@@ -368,15 +362,9 @@ def hc_coefficients(cfg: Xing4Config, phi, alpha, bias, X):
             "nbsd,ndk->kbs", X, phi.astype(X.dtype),
             preferred_element_type=jnp.float32,
         ) * inv_rms
-        alpha = alpha.astype(jnp.float32)
-        bias = bias.astype(jnp.float32)[:, None, None]
-        pre = alpha[0] * raw[:n] + bias[:n]
-        post = alpha[1] * raw[n:2 * n] + bias[n:2 * n]
-        res = (alpha[2] * raw[2 * n:] + bias[2 * n:]).reshape(
-            (n, n) + raw.shape[1:])
-        h_res = sinkhorn(jnp.exp(jnp.clip(res, lo, hi)),
-                         cfg.hc_sinkhorn_iters, cfg.hc_eps)
-        return jax.nn.sigmoid(pre), 2.0 * jax.nn.sigmoid(post), h_res
+        return hc_mix.coefficients(
+            raw, alpha, bias, cfg.hc_mult, cfg.hc_clamp,
+            cfg.hc_sinkhorn_iters, cfg.hc_eps)
 
 
 def hc_pre_mix(h_pre, X):
@@ -400,11 +388,20 @@ def hc_post_mix(h_post, h_res, X, z):
         ])
 
 
-def hc_sublayer(cfg: Xing4Config, lp: Params, name: str, X, fn):
+def hc_sublayer(cfg: Xing4Config, lp: Params, name: str, X, fn, *,
+                mesh: Optional[Mesh] = None, interpret: bool = False):
     """One sublayer ``fn`` (b, s, d) -> (b, s, d) between its pre-mix
-    and its post + res-mix, with the coefficients ``lp[name_*]``."""
-    h_pre, h_post, h_res = hc_coefficients(
-        cfg, lp[f"{name}_phi"], lp[f"{name}_alpha"], lp[f"{name}_bias"], X)
+    and its post + res-mix, with the coefficients ``lp[name_*]``: on the
+    TPU with streams of whole lanes (or with ``interpret``)
+    ``ops/hc_mix.py``'s four passes, anywhere else the ``jnp`` form
+    above; the gauge ``layers.hc_fused`` says which."""
+    phi, alpha, bias = (lp[f"{name}_{k}"] for k in ("phi", "alpha", "bias"))
+    if hc_mix.fused(interpret, X.shape[-1]):
+        return hc_mix.sublayer(
+            X, phi, alpha, bias, fn, norm_eps=cfg.norm_eps,
+            clamp=cfg.hc_clamp, iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps,
+            interpret=interpret, mesh=mesh)
+    h_pre, h_post, h_res = hc_coefficients(cfg, phi, alpha, bias, X)
     return hc_post_mix(h_post, h_res, X, fn(hc_pre_mix(h_pre, X)))
 
 
@@ -500,8 +497,8 @@ def block(cfg: Xing4Config, mesh, positions, inv_freq, lp: Params, X):
             return llama.swiglu(
                 y, lp["w_gate"], lp["w_up"], lp["w_down"], cfg.dtype)
 
-    X = hc_sublayer(cfg, lp, "hc_attn", X, attention)
-    X = hc_sublayer(cfg, lp, "hc_mlp", X, feed_forward)
+    X = hc_sublayer(cfg, lp, "hc_attn", X, attention, mesh=mesh)
+    X = hc_sublayer(cfg, lp, "hc_mlp", X, feed_forward, mesh=mesh)
     if mesh is not None:
         X = lax.with_sharding_constraint(
             X, NamedSharding(mesh, P(None, BATCH_AXES, None, None)))

@@ -23,8 +23,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from dlrover_tpu.ops import (
-    attention, blocksel, dsa, fused_ce, grouped_matmul, kda, lightning,
-    moe_rows)
+    attention, blocksel, dsa, fused_ce, grouped_matmul, hc_mix, kda,
+    lightning, moe_rows)
 
 
 @pytest.fixture(scope="module")
@@ -65,6 +65,7 @@ def kernels_are_the_path(monkeypatch):
     monkeypatch.setattr(dsa, "_on_tpu", lambda: True)
     monkeypatch.setattr(lightning, "_on_tpu", lambda: True)
     monkeypatch.setattr(blocksel, "_on_tpu", lambda: True)
+    monkeypatch.setattr(hc_mix, "_on_tpu", lambda: True)
     monkeypatch.setenv("DLROVER_TPU_FUSED_CE", "1")
 
 

@@ -474,6 +474,47 @@ def test_kda_elementwise_passes_compile(one_chip, kernels_are_the_path, scope):
 
 
 
+def _hc_sublayer(mesh, streams, replicated, batch=2):
+    """One sublayer's stream mixing at the xing4 cell's widths, ``fn``
+    the identity: ``(loss, its arguments)``."""
+    from dlrover_tpu.models import xing4
+
+    cfg = xing4.Xing4Config()
+
+    def loss(X, phi, alpha, bias):
+        lp = {"hc_phi": phi, "hc_alpha": alpha, "hc_bias": bias}
+        out = xing4.hc_sublayer(cfg, lp, "hc", X, lambda y: y, mesh=mesh)
+        return out.astype(jnp.float32).sum()
+
+    def arg(shape, at=replicated):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=at)
+
+    return loss, (arg((4, batch, 4096, cfg.dim), streams),
+                  arg((4, cfg.dim, cfg.hc_width)), arg((3,)),
+                  arg((cfg.hc_width,)))
+
+
+def _assert_the_hc_passes(hlo_fwd, hlo_grad):
+    """Two passes forward; under differentiation (no value asked for)
+    the pre-mix's forward and both backwards, which open the scope
+    themselves. Every call lies in ``hc_mix``."""
+    for hlo, kernels in ((hlo_fwd, ["hc_pre_fwd", "hc_post_fwd"]),
+                         (hlo_grad, ["hc_pre_fwd", "hc_post_bwd",
+                                     "hc_pre_bwd"])):
+        names = _op_names(hlo)
+        assert sorted(part for name in names for part in name.split("/")
+                      if part.startswith("hc_p")) == sorted(kernels)
+        assert all(_in_scope(name, "hc_mix") for name in names)
+    assert trace.gauges()["layers.hc_fused"] == 1
+
+
+def test_hc_mix_passes_compile(one_chip, kernels_are_the_path):
+    loss, args = _hc_sublayer(None, one_chip, one_chip)
+    _assert_the_hc_passes(
+        _compile(loss, *args),
+        _compile(jax.grad(loss, argnums=(0, 1, 2, 3)), *args))
+
+
 def test_grouped_matmul_compiles_at_xing4_shape(
         one_chip, kernels_are_the_path):
     # one grouped product of that block alone, forward and backward:
@@ -646,3 +687,14 @@ def test_kda_elementwise_passes_compile_over_four_chips(
     grad = jax.grad(loss, argnums=tuple(range(len(args))))
     _assert_one_pass_each_way(
         scope, _compile(loss, *args), _compile(grad, *args), kernel)
+
+
+def test_hc_mix_passes_compile_over_four_chips(mesh4, kernels_are_the_path):
+    """Under ``shard_map`` on each chip's batch rows, ``phi``, ``alpha``
+    and the bias replicated (their gradients summed over the chips)."""
+    loss, args = _hc_sublayer(
+        mesh4, NamedSharding(mesh4, P(None, BATCH_AXES)),
+        NamedSharding(mesh4, P()), batch=4)
+    hlo = _compile(jax.grad(loss, argnums=(0, 1, 2, 3)), *args)
+    _assert_the_hc_passes(_compile(loss, *args), hlo)
+    assert "all-reduce" in hlo

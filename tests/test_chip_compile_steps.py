@@ -195,6 +195,21 @@ def test_xing4_expert_block_fwd_bwd_compiles(one_chip, kernels_are_the_path):
     assert _kernel_calls(hlo, "moe_rows_gated") == 5
     assert trace.gauges()["moe.tail_skipped"] == 1
     assert "[8192,64,8" not in hlo  # no (tokens, experts, ...) dispatch tensor
+    # since PR 49 the stream mixing is ops/hc_mix.py's four passes, two
+    # sublayers a block. The pre-mix runs with the first block's forward
+    # (2), with the second's as far as attention's kept pair needs it
+    # (1) and in both recomputed forwards (4); the post + res-mix with
+    # the first block's forward (2) and once a recomputed one (a block's
+    # last X' is its result, which nothing reads again); each backward
+    # once a sublayer
+    assert trace.gauges()["layers.hc_fused"] == 1
+    assert {name: _kernel_calls(hlo, name) for name in (
+        "hc_pre_fwd", "hc_post_fwd", "hc_post_bwd", "hc_pre_bwd")} == {
+            "hc_pre_fwd": 7, "hc_post_fwd": 4, "hc_post_bwd": 4,
+            "hc_pre_bwd": 4}
+    # the streams are mixed in float32 inside the passes alone: no
+    # float32 copy of a whole (2, 4096, 3584) slab in HBM
+    assert not _wide_f32(hlo, "copy", at_least=2 * 4096 * 3584)
     # two blocks' own temporaries fit beside the cell's state and carries
     assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2**30
     assert trace.gauges()["moe.rows_held"] == 4096
