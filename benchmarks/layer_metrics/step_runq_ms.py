@@ -1,0 +1,2 @@
+"""``step_runq_ms``: see ``step_runq_ms.json``."""
+from benchmarks.harness.step_rows import read  # noqa: F401

@@ -311,16 +311,6 @@ TRACE_RING_CAP = _define(
     "Bound on the trace spine's span ring; the oldest half is dropped "
     "on overflow (per-kind seconds totals keep counting).",
 )
-PY_TRACING = _define(
-    "DLROVER_TPU_PY_TRACING", False, "bool",
-    "Host-side PyTracer (profiler/py_tracing.py): GC pauses + user "
-    "spans into the chrome-trace ring (and, when DLROVER_TPU_TRACE is "
-    "on, into the trace spine as gc_pause/input_wait spans).",
-)
-PY_TRACING_CAP = _define(
-    "DLROVER_TPU_PY_TRACING_CAP", 100_000, "int",
-    "PyTracer ring capacity (events; oldest half dropped on overflow).",
-)
 STRAGGLER_RATIO = _define(
     "DLROVER_TPU_STRAGGLER_RATIO", 1.5, "float",
     "Straggler policy (master/monitor/straggler.py): a rank is slow "

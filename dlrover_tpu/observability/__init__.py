@@ -2,10 +2,11 @@
 instrument the repo grew separately.
 
 - :mod:`dlrover_tpu.observability.trace` — the typed spans every
-  emitter (trainer, live reshard, checkpoint tiers, rendezvous,
-  PyTracer) records: always-on per-name counters and gauges, the JAX
-  profiler's host plane, and the ring, exportable as chrome-trace JSON
-  mergeable with the interposer ``/timeline`` dump.
+  emitter (trainer, live reshard, checkpoint tiers, rendezvous, the
+  dataloader, the garbage collector) records: always-on per-name
+  counters, per-kind seconds, gauges and step rows, the JAX profiler's
+  host plane, and the ring, exportable as chrome-trace JSON mergeable
+  with the interposer ``/timeline`` dump.
 - :mod:`dlrover_tpu.observability.digest` — windowed per-rank
   step-time digests (count/mean/p50/p95/max) that ride the step RPC to
   the master, feeding straggler detection

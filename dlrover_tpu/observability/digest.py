@@ -2,9 +2,12 @@
 
 The master used to learn only a per-chief step *count*
 (``GlobalStepReport``); every per-rank timing signal died in the worker
-process. Workers now fold each step's wall seconds into this digest and
-the (already throttled, ~15 s) step report drains one window —
-count/mean/p50/p95/max plus the window's input-wait seconds — so the
+process. Workers now fold each step's wall seconds (a step row's
+``interval_s``: dispatch to dispatch of the training step, which is the
+device's step for a loop that fetches its loss and for one that runs
+ahead alike) into this digest and the (already throttled, ~15 s) step
+report drains one window — count/mean/p50/p95/max, the late steps and
+their excess, plus the window's input-wait and GC seconds — so the
 master's straggler detector and lost-time attribution get per-rank
 distributions with ZERO extra RPCs (ROADMAP item 5's backpressure
 concern: one batched message, not per-step chatter).
@@ -58,12 +61,19 @@ class StepTimeDigest:
         self._count = 0
         self._sum = 0.0
         self._max = 0.0
+        self._late_n = 0
+        self._late_s = 0.0
 
-    def add(self, dur_s: float) -> None:
+    def add(self, dur_s: float, late_s: float = 0.0) -> None:
+        """One step of ``dur_s`` seconds, ``late_s`` of them over what
+        the trace spine's running median allows a step (0: on time)."""
         dur = max(0.0, float(dur_s))
         with self._lock:
             self._count += 1
             self._sum += dur
+            if late_s > 0.0:
+                self._late_n += 1
+                self._late_s += late_s
             if dur > self._max:
                 self._max = dur
             if len(self._samples) < self._max_samples:
@@ -79,10 +89,14 @@ class StepTimeDigest:
             d["count"] = self._count
             d["mean_s"] = round(self._sum / self._count, 6)
             d["max_s"] = round(self._max, 6)
+            d["late_n"] = self._late_n
+            d["late_s"] = round(self._late_s, 6)
             self._samples = []
             self._count = 0
             self._sum = 0.0
             self._max = 0.0
+            self._late_n = 0
+            self._late_s = 0.0
             return d
 
 
@@ -92,7 +106,7 @@ def merge_windows(a: Optional[Dict], b: Optional[Dict]) -> Optional[Dict]:
     must not erase its productive/input-wait seconds from the
     attribution). count/mean fold exactly; the order statistics take
     the max of the two windows (conservative toward straggler
-    detection); input-wait deltas sum."""
+    detection); input-wait, GC and late-step deltas sum."""
     if not a:
         return dict(b) if b else None
     if not b:
@@ -112,10 +126,10 @@ def merge_windows(a: Optional[Dict], b: Optional[Dict]) -> Optional[Dict]:
         out[key] = round(
             max(float(a.get(key, 0.0)), float(b.get(key, 0.0))), 6
         )
-    out["input_wait_s"] = round(
-        float(a.get("input_wait_s", 0.0)) + float(b.get("input_wait_s", 0.0)),
-        6,
-    )
+    for key in ("input_wait_s", "gc_pause_s", "late_s"):
+        out[key] = round(
+            float(a.get(key, 0.0)) + float(b.get(key, 0.0)), 6)
+    out["late_n"] = int(a.get("late_n", 0)) + int(b.get("late_n", 0))
     return out
 
 

@@ -2,9 +2,9 @@
 
 The repo's instruments grew as disjoint ledgers — the compile ledger
 (train/warm_compile.py), ResizeLedger (train/live_reshard.py), the comm
-ledger (profiler/comm.py), checkpoint restore stats, the PyTracer ring
-and the native interposer timeline — each with its own format and its
-own clock. This module is the join: every instrument records *typed
+ledger (profiler/comm.py), checkpoint restore stats and the native
+interposer timeline — each with its own format and its own clock. This
+module is the join: every instrument records *typed
 spans* into one ring with one clock basis, and the ring exports
 chrome-trace JSON that merges with every other rank's (and the
 interposer's ``/timeline`` dump) into a single perfetto-loadable job
@@ -34,6 +34,25 @@ Every span closed through ``span()`` reaches
 
 Back-dated ``record()`` (an emitter that measured its own duration, the
 synthetic resize lane) reaches the ring only.
+
+Garbage collections are spans too (``install_gc_hook()``: kind
+``gc_pause``, named ``gc.gen<n>``), in all three sinks, but through no
+``Span`` and no lock: a collection can begin while this thread holds
+the ring's lock, so the hook keeps its sums to itself and the readers
+(``counters()``, ``kind_seconds()``, ``events()``) merge them in.
+
+Step rows
+---------
+``StepAccount`` is the stepping thread's account of one interval between
+two dispatches of the training step: what the wall clock, the thread's
+and the process's CPU clocks, the kernel's run queue, the collector and
+the program's own spans say happened in it (``StepAccount.close`` lists
+the fields). ``step_row()`` keeps the last ``STEP_ROWS_CAP`` rows always
+(``step_rows()`` hands out a copy) and, with ``DLROVER_TPU_TRACE`` on,
+one ring event of kind ``step`` named ``step_row`` a row. A row much
+longer than the running median is *late*, and ``late_account()`` puts
+its excess down to a cause (``LATE_CAUSES``); the sums are the counters
+``late.<cause>``.
 
 Device side
 -----------
@@ -70,29 +89,40 @@ Hot-path contract
 -----------------
 A span is two clock reads, an inert profiler check and one locked table
 update — never a device sync (graftlint JG002 stays green for the
-emitters in ``ElasticTrainer.step``). When ``DLROVER_TPU_TRACE`` is off
-(the default) nothing is appended to the ring.
+emitters in ``ElasticTrainer.step``). A step row is eight host reads
+(two clocks, ``getrusage``, one 64-byte ``pread`` of the thread's
+``schedstat`` on a descriptor kept open, the collector's sums, the
+thread's named seconds, the profiler check) and one locked append, all
+of it after the step's dispatch has returned, when the device has its
+work and the host waits; a collection costs two clock reads and four
+list updates. When
+``DLROVER_TPU_TRACE`` is off (the default) nothing is appended to the
+ring. Measured: docs/design/observability.md, "Hot-path contract".
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import contextvars
+import gc
 import itertools
 import json
 import os
+import resource
+import statistics
 import sys
 import tempfile
 import threading
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 from dlrover_tpu.common import flags
 from dlrover_tpu.common.log import logger
 
 #: the span classification (docs/design/observability.md). ``downtime`` is
 #: master-side only (the SpeedMonitor's bracket spans); ``host`` is the
-#: catch-all PyTracer user spans map onto.
+#: catch-all for user spans of no other kind.
 SPAN_KINDS = (
     "step",
     "compile",
@@ -109,6 +139,18 @@ SPAN_KINDS = (
 
 #: what the profiler's host plane calls a span of this module
 PROFILER_PREFIX = "dlrover/"
+
+#: the kinds whose seconds a step row names (``named_s``): the program's
+#: own work between two steps, which is no late step
+NAMED_KINDS = frozenset((
+    "ckpt_save", "ckpt_restore", "eval", "compile", "state_transfer",
+    "rendezvous", "input_wait",
+))
+#: rows the spine keeps (the oldest goes first)
+STEP_ROWS_CAP = 4096
+#: what a late row's excess is put down to, in this order
+LATE_CAUSES = ("named", "gc", "runq", "cpu", "blocked")
+_GC_NAMES = ("gc.gen0", "gc.gen1", "gc.gen2")
 
 
 def enabled() -> bool:
@@ -146,7 +188,8 @@ class Span:
     seconds once the block has closed."""
 
     __slots__ = ("_ring", "kind", "name", "attrs", "id", "parent", "step",
-                 "dur", "_nested", "_t0", "_token", "_annotation")
+                 "dur", "_nested", "_in_named", "_named", "_t0", "_token",
+                 "_annotation")
 
     def __init__(self, ring, kind, name, cause, step, attrs):
         self._ring = ring
@@ -166,6 +209,11 @@ class Span:
         # a span inside one of its own kind decomposes it: it adds
         # nothing to the kind's total
         self._nested = outer is not None and outer.kind == self.kind
+        # the outermost span of a named kind carries the named seconds
+        # of all it encloses
+        inside = outer is not None and outer._in_named
+        self._in_named = inside or self.kind in NAMED_KINDS
+        self._named = self._in_named and not inside
         if outer is not None:
             if self.parent is None:
                 self.parent = outer.id
@@ -200,14 +248,20 @@ def _clean(attrs: Dict) -> Dict:
     return {k: v for k, v in attrs.items() if v not in (None, "")}
 
 
+class _PerThread(threading.local):
+    #: seconds of the spans of ``NAMED_KINDS`` closed on this thread
+    named = 0.0
+
+
 class TraceRing:
     """Process-wide span recorder (thread-safe): the always-on counters
     and gauges, and the bounded ring behind ``DLROVER_TPU_TRACE``.
 
     Ring spans: ``{"kind", "name", "t" (monotonic start, s), "dur" (s),
-    "tid", "attrs"?}``. Per-kind cumulative seconds survive ring
-    overflow — the attribution consumers read those, the timeline
-    consumers read the (windowed) spans.
+    "tid", "attrs"?}``. Per-kind cumulative seconds are kept whether or
+    not the ring is on and survive its overflow — the attribution
+    consumers read those, the timeline consumers read the (windowed)
+    spans.
     """
 
     def __init__(self, capacity: Optional[int] = None):
@@ -221,6 +275,17 @@ class TraceRing:
         self._gauges: Dict[str, float] = {}
         self._texts: Dict[str, Any] = {}
         self._scopes: set = set()
+        self._thread = _PerThread()
+        # the collector's hook writes these and takes no lock (module
+        # docstring); collections never overlap, so it is one writer
+        self._gc_n = [0, 0, 0]
+        self._gc_s = [0.0, 0.0, 0.0]
+        self._gc_open = None                # (start, annotation)
+        self._gc_events: List[Dict] = []    # not yet in the ring
+        self._rows: collections.deque = collections.deque(
+            maxlen=STEP_ROWS_CAP)
+        self._rows_seen = 0                 # rows that are no edge
+        self._base: Optional[Dict] = None   # the running median row
 
     # -- recording -----------------------------------------------------
 
@@ -267,15 +332,22 @@ class TraceRing:
 
     def _append(self, ev: Dict, counts_for_kind: bool):
         """Under ``self._lock``."""
+        self._drain_gc_events()
         self._events.append(ev)
         if counts_for_kind:
-            kind = ev["kind"]
-            self._kind_seconds[kind] = (
-                self._kind_seconds.get(kind, 0.0) + ev["dur"]
-            )
+            self._add_kind_seconds(ev["kind"], ev["dur"])
         cap = self.capacity
         if len(self._events) > cap:
             del self._events[: len(self._events) // 2]
+
+    def _add_kind_seconds(self, kind: str, dur: float):
+        """Under ``self._lock``."""
+        self._kind_seconds[kind] = self._kind_seconds.get(kind, 0.0) + dur
+
+    def _drain_gc_events(self):
+        """Under ``self._lock``: what the collector's hook left."""
+        while self._gc_events:
+            self._events.append(self._gc_events.pop(0))
 
     def span(self, kind: str, name: Optional[str] = None, *,
              cause: Optional[int] = None, step: Optional[int] = None,
@@ -291,15 +363,109 @@ class TraceRing:
                 sp.kind, sp.name, sp._t0, sp.dur, None,
                 dict(sp.attrs, id=sp.id, parent=sp.parent, step=sp.step),
             )
+        if sp._named:
+            self._thread.named += sp.dur
         with self._lock:
-            row = self._counters.get(sp.name)
-            if row is None:
-                self._counters[sp.name] = [1, sp.dur]
-            else:
-                row[0] += 1
-                row[1] += sp.dur
+            self._count(sp.name, 1, sp.dur)
             if ev is not None:
-                self._append(ev, counts_for_kind=not sp._nested)
+                self._append(ev, counts_for_kind=False)
+            if not sp._nested:
+                self._add_kind_seconds(sp.kind, sp.dur)
+
+    def _count(self, name: str, times: int, seconds: float):
+        """Under ``self._lock``."""
+        row = self._counters.get(name)
+        if row is None:
+            self._counters[name] = [times, seconds]
+        else:
+            row[0] += times
+            row[1] += seconds
+
+    def named_seconds(self) -> float:
+        """Seconds of the spans of ``NAMED_KINDS`` that have closed on
+        the calling thread, since it started."""
+        return self._thread.named
+
+    # -- the collector -------------------------------------------------
+
+    def on_gc(self, phase: str, info: Dict):
+        """A ``gc.callbacks`` entry (``install_gc_hook``): one span of
+        kind ``gc_pause`` a collection, from ``start`` to ``stop``.
+        Takes no lock and opens no ``Span`` (module docstring)."""
+        gen = min(info["generation"], len(_GC_NAMES) - 1)
+        if phase == "start":
+            annotation = None
+            cls = _profiler_annotation()
+            if cls is not None:
+                annotation = cls(PROFILER_PREFIX + _GC_NAMES[gen],
+                                 kind="gc_pause")
+                annotation.__enter__()
+            self._gc_open = (time.monotonic(), annotation)
+            return
+        opened, self._gc_open = self._gc_open, None
+        if opened is None:      # installed between the two phases
+            return
+        t0, annotation = opened
+        dur = time.monotonic() - t0
+        self._gc_n[gen] += 1
+        self._gc_s[gen] += dur
+        if annotation is not None:
+            annotation.set_metadata(collected=info.get("collected", 0))
+            annotation.__exit__(None, None, None)
+        if enabled():
+            self._gc_events.append(self._event(
+                "gc_pause", _GC_NAMES[gen], t0, dur, None,
+                {"collected": info.get("collected", 0)}))
+
+    def gc_totals(self) -> Tuple[Tuple[int, ...], Tuple[float, ...]]:
+        """Collections and their seconds by generation, process-wide (a
+        collection stops every thread that wants the interpreter)."""
+        return tuple(self._gc_n), tuple(self._gc_s)
+
+    # -- step rows -----------------------------------------------------
+
+    def step_row(self, row: Dict) -> Dict:
+        """Keep one closed interval of the stepping thread
+        (``StepAccount.close`` makes it). Sets the row's ``late_s``:
+        its excess over the running median row if it is late by
+        ``late_account``'s rule, else 0; a late row's excess folds into
+        the counters ``late.<cause>`` (times: late rows; seconds: the
+        cause's part). An ``edge`` row folds into nothing."""
+        ev = None
+        if enabled():
+            attrs = {k: v for k, v in row.items()
+                     if k not in ("t", "interval_s")}
+            ev = self._event("step", "step_row", row["t"],
+                             row["interval_s"], None, attrs)
+        with self._lock:
+            self._rows.append(row)
+            row["late_s"] = 0.0 if row["edge"] else self._fold_late(row)
+            if ev is not None:
+                ev.setdefault("attrs", {})["late_s"] = row["late_s"]
+                self._append(ev, counts_for_kind=False)
+        return row
+
+    #: the running median row is taken over this many of the newest rows
+    #: and taken again every ``BASE_EVERY`` rows; no row is judged before
+    #: ``BASE_MIN`` have been seen
+    BASE_ROWS, BASE_EVERY, BASE_MIN = 128, 64, 8
+
+    def _fold_late(self, row: Dict) -> float:
+        """Under ``self._lock``."""
+        self._rows_seen += 1
+        seen = self._rows_seen
+        if seen < self.BASE_MIN:
+            return 0.0
+        if (self._base is None or seen <= self.BASE_EVERY
+                or seen % self.BASE_EVERY == 0):
+            self._base = baseline(itertools.islice(
+                reversed(self._rows), self.BASE_ROWS))
+        account = late_account((row,), self._base)
+        if not account["n"]:
+            return 0.0
+        for cause in LATE_CAUSES:
+            self._count("late." + cause, 1, account[cause])
+        return sum(account[cause] for cause in LATE_CAUSES)
 
     def scope(self, name: str):
         """``with trace.scope("dense_mlp"): ...`` around the tracing of
@@ -338,17 +504,30 @@ class TraceRing:
 
     def events(self) -> List[Dict]:
         with self._lock:
+            self._drain_gc_events()
             return [dict(e) for e in self._events]
 
     def kind_seconds(self) -> Dict[str, float]:
-        """Cumulative seconds per span kind (ring-overflow-proof)."""
+        """Cumulative seconds per span kind, ring on or off."""
         with self._lock:
-            return dict(self._kind_seconds)
+            out = dict(self._kind_seconds)
+        if any(self._gc_n):
+            out["gc_pause"] = out.get("gc_pause", 0.0) + sum(self._gc_s)
+        return out
 
     def counters(self) -> Dict[str, Tuple[int, float]]:
         """``{span name: (times closed, seconds in all)}``, a copy."""
         with self._lock:
-            return {k: (v[0], v[1]) for k, v in self._counters.items()}
+            out = {k: (v[0], v[1]) for k, v in self._counters.items()}
+        for name, n, s in zip(_GC_NAMES, self._gc_n, self._gc_s):
+            if n:
+                out[name] = (n, s)
+        return out
+
+    def step_rows(self) -> List[Dict]:
+        """The newest ``STEP_ROWS_CAP`` step rows, oldest first, a copy."""
+        with self._lock:
+            return [dict(r) for r in self._rows]
 
     def gauges(self) -> Dict[str, float]:
         with self._lock:
@@ -361,6 +540,12 @@ class TraceRing:
             self._counters.clear()
             self._gauges.clear()
             self._texts.clear()
+            self._rows.clear()
+            self._rows_seen = 0
+            self._base = None
+            self._gc_events.clear()
+            self._gc_n[:] = [0, 0, 0]
+            self._gc_s[:] = [0.0, 0.0, 0.0]
 
     # -- export --------------------------------------------------------
 
@@ -411,8 +596,225 @@ class TraceRing:
         return path
 
 
+def _row_seconds(row: Dict, field: str) -> Optional[float]:
+    value = row[field]
+    return sum(value) if field == "gc_s" else value
+
+
+#: a late row's causes that a field of the row measures (what is left
+#: is ``blocked``), in ``LATE_CAUSES``' order
+_CAUSE_FIELDS = (("named", "named_s"), ("gc", "gc_s"), ("runq", "runq_s"),
+                 ("cpu", "cpu_s"))
+
+
+def _tick(values: List[float]) -> float:
+    """The step of a CPU clock that ticks, from its readings: what
+    every reading is a whole multiple of, if that is a millisecond or
+    more (a sandboxed kernel's thread clock steps by 10 ms); 0 for a
+    clock that does not."""
+    distinct = sorted({round(v, 6) for v in values})
+    if len(distinct) < 2:
+        return 0.0
+    step = min(b - a for a, b in zip(distinct, distinct[1:]))
+    if step < 1e-3:
+        return 0.0
+    whole = all(abs(v / step - round(v / step)) < 1e-3 for v in distinct)
+    return step if whole else 0.0
+
+
+def baseline(rows: Iterable[Dict]) -> Optional[Dict]:
+    """The median row of ``rows``: the median, field by field, of
+    ``interval_s``, ``named_s``, ``gc_s`` (all generations), ``runq_s``
+    and ``cpu_s`` over the rows that are no ``edge``, and
+    ``cpu_tick_s``, the step of the thread's CPU clock where it ticks
+    (``_tick``). None without such rows; a field no row measures
+    (``runq_s`` without ``schedstat``) reads None."""
+    columns: Dict[str, List[float]] = {"interval_s": []}
+    columns.update((field, []) for _, field in _CAUSE_FIELDS)
+    for row in rows:
+        if row["edge"]:
+            continue
+        for field, column in columns.items():
+            value = _row_seconds(row, field)
+            if value is not None:
+                column.append(value)
+    if not columns["interval_s"]:
+        return None
+    out = {field: statistics.median(column) if column else None
+           for field, column in columns.items()}
+    out["cpu_tick_s"] = _tick(columns["cpu_s"])
+    return out
+
+
+def late_account(rows: Iterable[Dict], base: Dict) -> Dict:
+    """The rule of a late step: rows and their median row
+    (``baseline``) in, ``{"n": late rows, <cause>: seconds}`` out.
+
+    With ``m`` the median interval, a row that is no ``edge`` is late
+    where ``interval_s - m > max(10 ms, 0.02 m)``. Its excess goes, in
+    ``LATE_CAUSES``' order and never more than is left of it, to
+    ``named`` (its ``named_s`` over the median row's: a save, an
+    evaluation, a wait for input), ``gc``, ``runq`` (runnable, and no
+    CPU to run on), ``cpu`` (running: the host's own work; where the
+    thread's CPU clock ticks, only what is over the median by more than
+    one tick, which is what a reading of such a clock is uncertain by)
+    and ``blocked``, which is the rest: the thread neither ran nor
+    waited for a CPU, it waited for a completion that came late, and
+    that is the device's doing or the runtime's."""
+    out: Dict[str, Any] = {"n": 0}
+    out.update((cause, 0.0) for cause in LATE_CAUSES)
+    m = base["interval_s"]
+    for row in rows:
+        left = row["interval_s"] - m
+        if row["edge"] or left <= max(0.010, 0.02 * m):
+            continue
+        out["n"] += 1
+        for cause, field in _CAUSE_FIELDS:
+            mine, usual = _row_seconds(row, field), base[field]
+            if mine is None or usual is None:
+                continue
+            if field == "cpu_s":
+                usual += base.get("cpu_tick_s", 0.0)
+            part = min(left, max(0.0, mine - usual))
+            out[cause] += part
+            left -= part
+        out["blocked"] += left
+    return out
+
+
 #: the process singleton every emitter records into
 trace_ring = TraceRing()
+
+
+class StepAccount:
+    """The stepping thread's account of the interval between two
+    dispatches of the training step. ``ElasticTrainer.step`` calls
+    ``close`` once its dispatch has returned, inside the ``train_step``
+    span: the device has its work by then, so for a loop that fetches
+    every loss, as for one that runs ahead, what the account costs is
+    not on the step's path. Host reads only, microseconds in all; the
+    one file is the thread's ``schedstat``, opened once and read with
+    ``pread``. Where the kernel has none, ``runq_s`` is None."""
+
+    _SCHEDSTAT = "/proc/thread-self/schedstat"
+
+    def __init__(self, ring: TraceRing = trace_ring):
+        self._ring = ring
+        self._fd: Optional[int] = None      # -1: the kernel has no file
+        self._fd_thread = None
+        self._open: Optional[tuple] = None  # the probes at the last close
+
+    def __del__(self):
+        self._close_fd()
+
+    def _close_fd(self):
+        if self._fd is not None and self._fd >= 0:
+            try:
+                os.close(self._fd)
+            except Exception:   # the interpreter is shutting down
+                pass
+        self._fd = None
+
+    def reset(self):
+        """Forget the open interval: the next ``close`` opens one and
+        closes none (a step build is no step)."""
+        self._open = None
+
+    def _runq_s(self) -> Optional[float]:
+        """Seconds this thread has been runnable with no CPU to run on
+        (``schedstat``'s second field), since it started."""
+        thread = threading.get_ident()
+        if self._fd_thread != thread:
+            # the path names the thread that opens it
+            self._close_fd()
+            self._fd_thread = thread
+            try:
+                self._fd = os.open(self._SCHEDSTAT, os.O_RDONLY)
+            except OSError:
+                self._fd = -1
+        if self._fd < 0:
+            return None
+        try:
+            return int(os.pread(self._fd, 64, 0).split()[1]) * 1e-9
+        except (OSError, IndexError, ValueError):
+            self._close_fd()
+            self._fd = -1
+            return None
+
+    def _probe(self) -> tuple:
+        usage = resource.getrusage(resource.RUSAGE_THREAD)
+        return (
+            time.monotonic(), usage.ru_utime + usage.ru_stime,
+            time.process_time(),
+            self._runq_s(), usage.ru_nivcsw, usage.ru_majflt,
+            self._ring.gc_totals(), self._ring.named_seconds(),
+            profiling(),
+        )
+
+    def close(self, step: int, dispatched: Span) -> Optional[Dict]:
+        """Close the interval that the last ``close`` opened, open the
+        next, and hand the closed one to the spine as a row (None at the
+        first call after ``reset``). ``dispatched`` is the open
+        ``train_step`` span whose dispatch has just returned. The row:
+
+        - ``step``: the host step whose dispatch closed the interval
+          (the device work waited for inside it was the step before);
+          ``t``: the interval's start (monotonic); ``interval_s``: from
+          one dispatch's return to the next, the whole loop;
+          ``dispatch_s``: the closing dispatch, from its span's start;
+        - ``named_s``: seconds of the spans of ``NAMED_KINDS`` that
+          closed on this thread inside it;
+        - ``gc_n``, ``gc_s``: collections and their seconds by
+          generation, process-wide;
+        - ``cpu_s``: this thread's CPU seconds (``getrusage``'s user
+          and system time); ``proc_cpu_s``: the
+          process's, every runtime thread with it; ``runq_s``: seconds
+          this thread was runnable and had no CPU; ``nivcsw``,
+          ``majflt``: its involuntary context switches and major faults;
+        - ``traced``: a profiler session was on at both ends; ``edge``:
+          at one end only (the interval holds ``start_trace`` or
+          ``stop_trace`` and is no step);
+        - ``late_s``: ``TraceRing.step_row`` sets it."""
+        now = self._probe()
+        before, self._open = self._open, now
+        if before is None:
+            return None
+        (t0, cpu0, proc0, runq0, nivcsw0, majflt0, (gc_n0, gc_s0), named0,
+         on0) = before
+        (t1, cpu1, proc1, runq1, nivcsw1, majflt1, (gc_n1, gc_s1), named1,
+         on1) = now
+        return self._ring.step_row({
+            "step": step,
+            "t": t0,
+            "interval_s": t1 - t0,
+            "dispatch_s": t1 - dispatched._t0,
+            "named_s": named1 - named0,
+            "gc_n": tuple(b - a for a, b in zip(gc_n0, gc_n1)),
+            "gc_s": tuple(b - a for a, b in zip(gc_s0, gc_s1)),
+            "cpu_s": cpu1 - cpu0,
+            "proc_cpu_s": proc1 - proc0,
+            "runq_s": (None if runq0 is None or runq1 is None
+                       else runq1 - runq0),
+            "nivcsw": nivcsw1 - nivcsw0,
+            "majflt": majflt1 - majflt0,
+            "traced": int(on0 and on1),
+            "edge": int(on0 != on1),
+        })
+
+
+def profiling() -> bool:
+    """Whether a profiler session is on (and JAX loaded)."""
+    return _profiler_annotation() is not None
+
+
+def install_gc_hook() -> bool:
+    """Make every garbage collection of this process a span of kind
+    ``gc_pause`` named ``gc.gen<n>`` (``TraceRing.on_gc``). Idempotent;
+    the first ``ElasticTrainer`` and ``bootstrap.init`` call it."""
+    if trace_ring.on_gc in gc.callbacks:
+        return False
+    gc.callbacks.append(trace_ring.on_gc)
+    return True
 
 
 def record(kind: str, name: str, start_mono: float, dur_s: float, **attrs):
@@ -425,6 +827,7 @@ scopes = trace_ring.scopes
 gauge = trace_ring.gauge
 counters = trace_ring.counters
 gauges = trace_ring.gauges
+step_rows = trace_ring.step_rows
 provide_text = trace_ring.provide_text
 text = trace_ring.text
 
@@ -564,8 +967,9 @@ def attribution_from_kind_seconds(
 def prometheus_lines() -> List[str]:
     """Spine rows for the worker ``/metrics`` endpoint
     (profiler/comm.py): times closed and cumulative seconds per span
-    name, the gauges, cumulative seconds per span kind (ring on) plus
-    the last drained step-time digest window."""
+    name (the ``gc.gen<n>`` collections and the ``late.<cause>`` sums
+    among them), the gauges, cumulative seconds per span kind plus the
+    last drained step-time digest window."""
     lines: List[str] = []
     spans = trace_ring.counters()
     if spans:

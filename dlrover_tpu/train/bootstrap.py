@@ -72,9 +72,9 @@ class WorkerContext:
         self._last_reported_step = 0
         self._last_report_ts = 0.0
         self.step_report_interval = 15.0
-        # input-wait seconds already shipped with earlier digests (the
-        # spine counter is cumulative; reports carry the delta)
-        self._input_wait_mark = 0.0
+        # input-wait and GC seconds already shipped with earlier digests
+        # (the spine's per-kind totals only grow; reports carry the delta)
+        self._kind_marks = {"input_wait": 0.0, "gc_pause": 0.0}
         # whether this worker has ever shipped a comm_links split with
         # a dcn row: after a resize REMOVES the slow link (slice loss →
         # single-slice world) one more report must replace the master's
@@ -194,8 +194,9 @@ class WorkerContext:
         ``digest``: a :class:`~dlrover_tpu.observability.digest.
         StepTimeDigest` the caller folds per-step wall times into; the
         report DRAINS one window from it (count/mean/p50/p95/max) and
-        attaches the worker's input-wait seconds since the last report
-        (trace spine ``input_wait`` counter) — per-rank step-time
+        attaches the worker's input-wait and GC-pause seconds since the
+        last report (the trace spine's per-kind seconds, kept whether or
+        not its ring is on) — per-rank step-time
         distributions ride the existing throttled RPC, so the master's
         straggler detector and attribution cost no extra chatter."""
         if self.client is None:
@@ -214,11 +215,11 @@ class WorkerContext:
             from dlrover_tpu.observability import digest as digest_mod
             from dlrover_tpu.observability import trace
 
-            total_iw = trace.trace_ring.kind_seconds().get("input_wait", 0.0)
-            payload["input_wait_s"] = round(
-                max(0.0, total_iw - self._input_wait_mark), 6
-            )
-            self._input_wait_mark = total_iw
+            kinds = trace.trace_ring.kind_seconds()
+            for kind, mark in self._kind_marks.items():
+                total = kinds.get(kind, 0.0)
+                payload[kind + "_s"] = round(max(0.0, total - mark), 6)
+                self._kind_marks[kind] = total
             digest_mod.set_last_window(payload)  # worker /metrics gauge
         if self._unreported_digest:
             # a window whose report failed (master relaunch gap) rides
@@ -304,21 +305,15 @@ def init(
         except Exception:
             logger.exception("stack-dump handler install failed; continuing")
     from dlrover_tpu.common import flags as _flags
+    from dlrover_tpu.observability import trace as _trace
 
-    if _flags.PY_TRACING.get() or _flags.TRACE.get():
-        # GC pauses + user spans into the host timeline; the trace
-        # spine needs the same emitters (gc_pause/input_wait spans), so
-        # either flag turns the tracer on (typed registry, was a raw
-        # DLROVER_TPU_PY_TRACING env read)
-        from dlrover_tpu.profiler.py_tracing import py_tracer
-
-        py_tracer.start()
+    # GC pauses as gc_pause spans of the spine (counters always, the
+    # profiler's host plane in a session, the ring behind its flag)
+    _trace.install_gc_hook()
     if _flags.TRACE.get():
         # dump this process's span ring at exit so the job-timeline CLI
         # (profiler/analysis.py) can merge every rank + the master into
         # one perfetto-loadable trace
-        from dlrover_tpu.observability import trace as _trace
-
         _trace.dump_at_exit(
             role="worker", node_id=env.node_id, process_id=env.process_id
         )

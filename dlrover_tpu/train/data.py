@@ -20,6 +20,7 @@ import numpy as np
 
 from dlrover_tpu.common.log import logger
 from dlrover_tpu.common.messages import DatasetShardParams, Task
+from dlrover_tpu.observability import trace
 
 
 def _broadcast_tuple(values: Tuple[int, ...], is_source: bool) -> Tuple[int, ...]:
@@ -408,19 +409,13 @@ class ElasticDataLoader:
         return True
 
     def __iter__(self):
-        from dlrover_tpu.profiler.py_tracing import py_tracer
-
-        # flag-registry enablement (DLROVER_TPU_PY_TRACING / _TRACE):
-        # entry scripts that never call bootstrap.init still get their
-        # input-wait spans into the spine
-        py_tracer.maybe_start()
         self.update_batch_size_from_config()
         for indices in self.sampler:
-            # span only when tracing is on: fetch+collate stalls explain
-            # device-idle gaps in the merged timeline (reference
-            # py_tracing's dataloader interception); cat="dataloader"
-            # maps onto the spine's `input_wait` span kind
-            with py_tracer.span("dataloader.next", cat="dataloader"):
+            # fetch+collate stalls explain device-idle gaps: the span
+            # reaches the spine's counters and per-kind seconds always
+            # (the master's `input_stall`, a step row's `named_s`), the
+            # profiler's host plane in a session
+            with trace.span("input_wait", "dataloader.next"):
                 batch = self._collate([self.dataset[i] for i in indices])
             yield batch
         # next epoch may pick up a new config (never mid-epoch)

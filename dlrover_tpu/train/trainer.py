@@ -191,6 +191,11 @@ class ElasticTrainer:
         # drains one window to the master's straggler detector and
         # lost-time attribution
         self.step_digest = StepTimeDigest()
+        # the stepping thread's account of every interval between two
+        # entries to step() (observability/trace.py StepAccount): one
+        # row a step into the spine, and the digest's sample
+        self._account = trace.StepAccount()
+        trace.install_gc_hook()
         self._maybe_serve_comm_metrics()
 
     def _maybe_serve_comm_metrics(self):
@@ -1719,9 +1724,10 @@ class ElasticTrainer:
         build_t0 = time.perf_counter()
         if first_build:
             # the first call is build-dominated: it has a span of its
-            # own and stays out of `train_step` and of the digest, or
-            # every (re)start would feed the straggler detector one
-            # giant sample per rank
+            # own and stays out of `train_step`, of the step rows and of
+            # the digest, or every (re)start would feed the straggler
+            # detector one giant sample per rank
+            self._account.reset()
             with trace.span("host", "first_step", step=self._host_step + 1):
                 with trace.span("compile", "build",
                                 world=self.mesh.size) as built:
@@ -1735,14 +1741,36 @@ class ElasticTrainer:
         else:
             if self.worker_ctx is not None:
                 state = self.poll_runtime_config(state)
-            # step wall clock, measured WITHOUT a device sync: dispatch
-            # of step N blocks on donation until step N-1's buffers
-            # free, so in steady state this converges to the device step
-            # time. Feeds the per-rank digest.
             with trace.span("step", "train_step", step=self._host_step + 1,
                             host_step=self._host_step + 1) as dispatched:
                 new_state, loss = self._dispatch(state, batch)
-            self.step_digest.add(dispatched.dur)
+                # step wall clock, measured WITHOUT a device sync: from
+                # the last dispatch's return to this one's is the whole
+                # loop, which is the device's step for a caller that
+                # fetches every loss (it waits for it in between) and
+                # for one that runs ahead (dispatch of step N blocks on
+                # donation until step N-1's buffers free). The dispatch
+                # span alone is neither: microseconds for a caller that
+                # fetches. The account closes here, after the dispatch:
+                # the device has its work, so the probes cost the step
+                # nothing. The row feeds the per-rank digest; none
+                # closes at the first call after a build, whose interval
+                # would hold the build.
+                row = self._account.close(self._host_step + 1, dispatched)
+                if row is not None:
+                    if not row["edge"]:
+                        self.step_digest.add(row["interval_s"],
+                                             row["late_s"])
+                    # on the profiler's host plane this event lies beside
+                    # the end of the device work that the interval it
+                    # closes waited for
+                    dispatched.set(
+                        prev_interval_ms=row["interval_s"] * 1e3,
+                        prev_gc_ms=sum(row["gc_s"]) * 1e3,
+                        prev_runq_ms=(row["runq_s"] or 0.0) * 1e3,
+                        prev_cpu_ms=row["cpu_s"] * 1e3,
+                        prev_named_ms=row["named_s"] * 1e3,
+                    )
         if first_build and self._pending_resize is not None:
             self._finalize_resize(loss, build_t0)
         # host-side step counter: reading new_state["step"] would block on

@@ -12,7 +12,24 @@ from dlrover_tpu.common import flags
 
 
 @pytest.fixture
-def traced(monkeypatch):
+def no_gc_hook():
+    """The collector's hook off for the test: a collection may begin
+    anywhere, and a test that counts events or counters exactly cannot
+    have its span among them. An earlier test's trainer installed it."""
+    import gc
+
+    from dlrover_tpu.observability.trace import trace_ring
+
+    was_on = trace_ring.on_gc in gc.callbacks
+    if was_on:
+        gc.callbacks.remove(trace_ring.on_gc)
+    yield
+    if was_on and trace_ring.on_gc not in gc.callbacks:
+        gc.callbacks.append(trace_ring.on_gc)
+
+
+@pytest.fixture
+def traced(monkeypatch, no_gc_hook):
     """Spine on, recording into a clean ring."""
     from dlrover_tpu.observability.trace import trace_ring
 
@@ -33,10 +50,11 @@ def test_trace_ring_off_by_default(monkeypatch):
     monkeypatch.delenv("DLROVER_TPU_TRACE", raising=False)
     r = TraceRing()
     r.record("step", "train_step", time.monotonic(), 0.01)
-    with r.span("compile"):
+    with r.span("compile") as sp:
         pass
     assert r.events() == []
-    assert r.kind_seconds() == {}
+    # per-kind seconds are kept with the ring off, as the counters are
+    assert r.kind_seconds() == {"compile": sp.dur}
 
 
 def test_trace_ring_records_spans_and_kind_totals(traced):
@@ -68,7 +86,7 @@ def test_trace_ring_bounded_but_totals_survive(traced, monkeypatch):
 
 
 @pytest.fixture
-def untraced(monkeypatch):
+def untraced(monkeypatch, no_gc_hook):
     """Spine off (the default), clean counters."""
     from dlrover_tpu.observability.trace import trace_ring
 
@@ -88,7 +106,8 @@ def test_counters_count_with_the_ring_off(untraced, times):
         assert sp.dur >= 0.002
     count, seconds = trace.counters()["d2h.wait"]
     assert count == times and seconds >= 0.002 * times
-    assert untraced.events() == [] and untraced.kind_seconds() == {}
+    assert untraced.events() == []
+    assert untraced.kind_seconds() == {"ckpt_save": seconds}
     # what counters() hands out is a copy
     trace.counters().clear()
     assert "d2h.wait" in trace.counters()
@@ -191,7 +210,7 @@ def test_span_closes_and_unwinds_on_an_exception(untraced):
 
 @pytest.mark.parametrize("spine", ["0", "1"])
 def test_span_lands_in_the_profilers_host_plane(monkeypatch, tmp_path,
-                                                spine):
+                                                spine, no_gc_hook):
     """Under a profiler session a span is a host event named
     ``dlrover/<name>`` with its identity and attributes as stats, on the
     profiler's clock; whether the ring is on makes no difference."""
@@ -274,46 +293,66 @@ def test_chrome_export_epoch_clock_and_dump(traced, tmp_path):
     assert len(doc["traceEvents"]) == 1
 
 
-def test_pytracer_mirrors_into_spine(traced, monkeypatch):
-    """GC + user spans adopt the spine's span classification: gc -> gc_pause,
-    dataloader -> input_wait, other cats -> host."""
+@pytest.fixture
+def gc_hook(no_gc_hook):
+    """The collector's hook on for this test alone."""
     import gc
 
-    from dlrover_tpu.profiler.py_tracing import PyTracer
+    from dlrover_tpu.observability import trace
 
-    tracer = PyTracer()
-    tracer.start()
-    try:
-        with tracer.span("dataloader.next", cat="dataloader"):
-            pass
-        with tracer.span("preprocess", cat="user"):
-            pass
-        gc.collect()
-    finally:
-        tracer.stop()
-    kinds = {e["kind"] for e in traced.events()}
-    assert "input_wait" in kinds
-    assert "host" in kinds
-    assert "gc_pause" in kinds
-    # the tracer's own chrome ring still works (back-compat consumers)
-    names = [e["name"] for e in tracer.events()]
-    assert "dataloader.next" in names
+    assert trace.install_gc_hook() is True
+    assert trace.install_gc_hook() is False      # idempotent
+    yield
+    gc.callbacks.remove(trace.trace_ring.on_gc)
 
 
-def test_pytracer_capacity_and_enablement_from_flags(monkeypatch):
-    from dlrover_tpu.profiler.py_tracing import PyTracer
+def test_gc_and_user_spans_reach_the_spine(traced, gc_hook):
+    """GC + user spans adopt the spine's span classification: gc ->
+    gc_pause (named by generation), dataloader -> input_wait, other
+    cats -> host; all three through the one ring."""
+    import gc
 
-    monkeypatch.setenv("DLROVER_TPU_PY_TRACING_CAP", "32")
-    monkeypatch.delenv("DLROVER_TPU_TRACE", raising=False)
-    tracer = PyTracer()
-    assert tracer._cap == 32
-    monkeypatch.setenv("DLROVER_TPU_PY_TRACING", "0")
-    assert tracer.maybe_start() is False
-    monkeypatch.setenv("DLROVER_TPU_PY_TRACING", "1")
-    assert tracer.maybe_start() is True
-    tracer.stop()
-    # explicit constructor capacity still wins
-    assert PyTracer(capacity=7)._cap == 7
+    from dlrover_tpu.observability import trace
+    from dlrover_tpu.profiler.py_tracing import py_tracer
+
+    with py_tracer.span("dataloader.next", cat="dataloader"):
+        pass
+    with py_tracer.span("preprocess", cat="user"):
+        pass
+    gc.collect()
+    by_name = {e["name"]: e for e in traced.events()}
+    assert by_name["dataloader.next"]["kind"] == "input_wait"
+    assert by_name["preprocess"]["kind"] == "host"
+    assert by_name["gc.gen2"]["kind"] == "gc_pause"
+    assert by_name["gc.gen2"]["dur"] > 0
+    assert "collected" in by_name["gc.gen2"]["attrs"]
+    counters = trace.counters()
+    assert counters["dataloader.next"][0] == 1
+    assert counters["gc.gen2"][0] >= 1
+
+
+def test_gc_pause_reaches_the_counters_with_every_flag_unset(
+        untraced, gc_hook):
+    """The flags that used to turn host tracing on are gone: a
+    collection is counted always, and reaches the ring only behind
+    ``DLROVER_TPU_TRACE``."""
+    import gc
+
+    from dlrover_tpu.observability import trace
+
+    assert not hasattr(flags, "PY_TRACING")
+    assert not hasattr(flags, "PY_TRACING_CAP")
+    gc.collect()
+    gc.collect(0)
+    counters = trace.counters()
+    assert counters["gc.gen2"][0] == 1 and counters["gc.gen0"][0] >= 1
+    assert counters["gc.gen2"][1] > 0
+    assert untraced.kind_seconds()["gc_pause"] == pytest.approx(
+        sum(s for name, (_, s) in counters.items()
+            if name.startswith("gc.gen")))
+    assert untraced.events() == []
+    untraced.clear()
+    assert "gc.gen2" not in trace.counters()
 
 
 def test_attribution_from_kind_seconds():
@@ -367,7 +406,8 @@ def test_spine_prometheus_lines_carry_counters_and_gauges(untraced):
     assert 'dlrover_tpu_span_seconds_total{name="d2h.wait"} 0.0' in text
     assert ('dlrover_tpu_trace_gauge{name="ckpt.staged_bytes"} 8.154e+09'
             in text)
-    assert "dlrover_tpu_trace_seconds_total" not in text
+    # ... and the seconds a kind, which no longer wait for the ring
+    assert 'dlrover_tpu_trace_seconds_total{kind="ckpt_save"}' in text
 
 
 # ---------------------------------------------------------------------------
@@ -626,7 +666,7 @@ def test_failed_step_report_retries_digest_window(monkeypatch):
 @pytest.fixture(scope="module")
 def three_trainer_steps():
     """Three steps of a tiny trainer with the ring on: (ring events,
-    counters, gauges, the digest's window, kind seconds)."""
+    counters, gauges, the digest's window, kind seconds, step rows)."""
     import jax
 
     from dlrover_tpu.models import llama
@@ -660,26 +700,41 @@ def three_trainer_steps():
         jax.block_until_ready(loss)
         yield (trace.trace_ring.events(), trace.counters(), trace.gauges(),
                trainer.step_digest.snapshot_and_reset(),
-               trace.trace_ring.kind_seconds())
+               trace.trace_ring.kind_seconds(), trace.step_rows())
     finally:
         trace.trace_ring.clear()
         mp.undo()
 
 
 def test_trainer_emits_step_compile_spans_and_digest(three_trainer_steps):
-    events, counters, _, window, kind_seconds = three_trainer_steps
+    events, counters, _, window, kind_seconds, rows = three_trainer_steps
     kinds = [e["kind"] for e in events]
     # warm-compile default on: the AOT build recorded compile spans
     assert "compile" in kinds
     # steps after the first (build) call recorded step spans
-    assert kinds.count("step") == 2
     steps = [e["attrs"] for e in events if e["name"] == "train_step"]
     assert [(a["step"], a["host_step"]) for a in steps] == [(2, 2), (3, 3)]
-    # the digest folded the same steps
-    assert window is not None and window["count"] == 2
-    # ... from the span's own seconds (the digest rounds to microseconds)
-    assert window["mean_s"] * 2 == pytest.approx(
-        counters["train_step"][1], abs=2e-6)
+    # the build call opens no interval: the one row runs from the
+    # second call's dispatch to the third's, and it is a ring event too
+    (row,) = rows
+    assert (row["step"], row["edge"], row["traced"]) == (3, 0, 0)
+    (row_event,) = [e for e in events if e["name"] == "step_row"]
+    assert row_event["kind"] == "step"
+    assert row_event["dur"] == pytest.approx(row["interval_s"])
+    assert row_event["attrs"]["step"] == 3
+    # the digest folded the interval, dispatch to dispatch (it rounds
+    # to microseconds), not the dispatch inside it
+    assert window is not None and window["count"] == 1
+    assert window["mean_s"] == pytest.approx(row["interval_s"], abs=2e-6)
+    assert row["dispatch_s"] < row["interval_s"]
+    # ... which is step 3's, up to where the account closed inside it
+    assert 0 < row["dispatch_s"] <= [
+        e for e in events if e["name"] == "train_step"][1]["dur"]
+    # step 3's span carries the account of the interval it closed
+    assert "prev_interval_ms" not in steps[0]
+    assert steps[1]["prev_interval_ms"] == pytest.approx(
+        row["interval_s"] * 1e3)
+    assert {"prev_cpu_ms", "prev_named_ms"} <= set(steps[1])
     # the build's children decompose it: the kind counts the build once
     (build,) = [e for e in events if e["name"] == "build"]
     assert kind_seconds["compile"] == pytest.approx(build["dur"])
@@ -695,7 +750,7 @@ def test_trainer_emits_step_compile_spans_and_digest(three_trainer_steps):
     ("build.speculate", "build"),
 ])
 def test_trainer_first_step_spans(three_trainer_steps, name, parent):
-    events, counters, _, _, _ = three_trainer_steps
+    events, counters, _, _, _, _ = three_trainer_steps
     by_name = {e["name"]: e for e in events}
     attrs = by_name[name]["attrs"]
     assert counters[name][0] == 1
@@ -718,9 +773,452 @@ def test_trainer_first_step_spans(three_trainer_steps, name, parent):
     "step.hbm_peak_bytes", "step.hbm_temp_bytes", "step.hbm_argument_bytes",
 ])
 def test_trainer_sets_hbm_gauges_at_build(three_trainer_steps, gauge):
-    _, _, gauges, _, _ = three_trainer_steps
+    _, _, gauges, _, _, _ = three_trainer_steps
     assert gauges[gauge] > 0
     assert gauges["step.hbm_peak_bytes"] >= gauges[gauge]
+
+
+# ---------------------------------------------------------------------------
+# the step's account: one row an interval between two entries to step()
+# ---------------------------------------------------------------------------
+
+
+class _Stepper:
+    """A tiny built trainer whose every step fetches its loss, as the
+    benchmark's jobs and any loop that logs its loss do."""
+
+    def __init__(self):
+        import jax
+
+        from dlrover_tpu.models import llama
+        from dlrover_tpu.parallel import (
+            MeshConfig, build_mesh, named_shardings,
+        )
+        from dlrover_tpu.train.trainer import ElasticTrainer, TrainConfig
+
+        cfg = llama.LlamaConfig.tiny()
+        mc = MeshConfig(dp=1, fsdp=1, sp=1, tp=1).resolve(1)
+        mesh = build_mesh(mc, devices=jax.devices()[:1])
+        specs = llama.param_specs(cfg)
+        params = jax.device_put(
+            llama.init_params(cfg, jax.random.key(0)),
+            named_shardings(mesh, specs),
+        )
+        tc = TrainConfig(global_batch_size=2, micro_batch_size=2,
+                         warmup_steps=0, total_steps=100000)
+        self.trainer = ElasticTrainer(
+            lambda p, t: llama.loss_fn(p, t, cfg, None), specs, mesh, mc, tc
+        )
+        self.state = self.trainer.init_state(params)
+        self.batch = jax.random.randint(
+            jax.random.key(1), (1, 2, 16), 0, cfg.vocab_size
+        )
+        self.step()     # the build
+
+    def step(self, times: int = 1):
+        for _ in range(times):
+            self.state, loss = self.trainer.step(self.state, self.batch)
+            float(loss)
+
+    def settle(self, rows: int = 12):
+        """A clean spine, an open interval and ``rows`` ordinary rows:
+        enough for the running median to judge the next."""
+        from dlrover_tpu.observability import trace
+
+        self.trainer._account.reset()
+        trace.trace_ring.clear()
+        self.trainer.step_digest.snapshot_and_reset()
+        self.step(rows + 1)
+
+
+@pytest.fixture(scope="module")
+def stepper_built():
+    return _Stepper()
+
+
+@pytest.fixture
+def stepper(stepper_built, monkeypatch):
+    from dlrover_tpu.observability import trace
+
+    monkeypatch.delenv("DLROVER_TPU_TRACE", raising=False)
+    stepper_built.settle()
+    yield stepper_built
+    trace.trace_ring.clear()
+
+
+def _late(trace):
+    """The ``late.<cause>`` counters: ({cause: seconds}, late rows)."""
+    rows = {name[len("late."):]: v for name, v in trace.counters().items()
+            if name.startswith("late.")}
+    counts = {n for n, _ in rows.values()}
+    assert len(counts) <= 1     # every cause counts every late row
+    return ({cause: rows.get(cause, (0, 0.0))[1]
+             for cause in trace.LATE_CAUSES},
+            counts.pop() if counts else 0)
+
+
+def _next_row(stepper):
+    """One more step: (the row its entry closed, what that row alone
+    added to the ``late.<cause>`` counters, the late rows it added)."""
+    from dlrover_tpu.observability import trace
+
+    before, n_before = _late(trace)
+    stepper.step()
+    after, n_after = _late(trace)
+    return (trace.step_rows()[-1],
+            {cause: after[cause] - before[cause] for cause in after},
+            n_after - n_before)
+
+
+ROW_FIELDS = {
+    "step", "t", "interval_s", "dispatch_s", "named_s", "gc_n", "gc_s",
+    "cpu_s", "proc_cpu_s", "runq_s", "nivcsw", "majflt", "traced", "edge",
+    "late_s",
+}
+
+
+def test_step_row_a_step_with_every_field(stepper):
+    from dlrover_tpu.observability import trace
+
+    rows = trace.step_rows()
+    assert len(rows) == 12      # 13 calls: the first opens, closes none
+    first = stepper.trainer._host_step - 11
+    assert [r["step"] for r in rows] == list(range(first, first + 12))
+    for r in rows:
+        assert set(r) == ROW_FIELDS
+        assert 0 < r["dispatch_s"] < r["interval_s"]
+        assert 0 < r["cpu_s"] <= r["proc_cpu_s"] + 1e-3
+        assert len(r["gc_n"]) == len(r["gc_s"]) == 3
+        assert (r["traced"], r["edge"]) == (0, 0)
+        assert r["runq_s"] is None or r["runq_s"] >= 0
+    # with every flag unset nothing reached the ring
+    assert trace.trace_ring.events() == []
+    # what step_rows() hands out is a copy
+    trace.step_rows().clear()
+    rows[0]["step"] = -1
+    assert trace.step_rows()[0]["step"] == first
+
+
+def test_step_row_intervals_tile_the_wall(stepper):
+    """Dispatch to dispatch: the rows' intervals sum to the wall between
+    the first dispatch and the last, whatever the loop did in between."""
+    from dlrover_tpu.observability import trace
+
+    before = len(trace.step_rows())
+    t0 = time.monotonic()
+    stepper.step()              # its dispatch closes the interval open now
+    time.sleep(0.01)
+    stepper.step(3)
+    t1 = time.monotonic()
+    rows = trace.step_rows()[before + 1:]
+    assert len(rows) == 3
+    assert rows[0]["t"] >= t0
+    for a, b in zip(rows, rows[1:]):
+        assert b["t"] == pytest.approx(a["t"] + a["interval_s"], abs=1e-9)
+    assert rows[-1]["t"] + rows[-1]["interval_s"] <= t1
+    assert sum(r["interval_s"] for r in rows) >= 0.01
+
+
+def test_a_save_between_two_steps_is_named_not_blocked(stepper):
+    from dlrover_tpu.observability import trace
+
+    with trace.span("ckpt_save", "save.blocking") as save:
+        with trace.span("ckpt_save", "d2h.wait"):   # counted once
+            time.sleep(0.05)
+    row, late, n = _next_row(stepper)
+    assert row["named_s"] == pytest.approx(save.dur)
+    assert row["late_s"] >= 0.04 and n == 1
+    assert sum(late.values()) == pytest.approx(row["late_s"])
+    # the save's seconds are named whatever else the host was doing
+    assert late["named"] >= 0.045 and late["blocked"] < late["named"]
+    # a span of another thread is not this thread's work
+    import threading
+
+    def elsewhere():
+        with trace.span("ckpt_save", "stage.background"):
+            time.sleep(0.02)
+
+    t = threading.Thread(target=elsewhere)
+    t.start()
+    t.join()
+    row, _, _ = _next_row(stepper)
+    assert row["named_s"] == 0.0
+
+
+def test_a_wait_for_input_is_named(stepper):
+    """The dataloader's fetch opens its own ``input_wait`` span: it is
+    the row's ``named_s``, the kind's seconds with the ring off, and
+    what bootstrap reports to the master."""
+    import numpy as np
+
+    from dlrover_tpu.observability import trace
+    from dlrover_tpu.train.bootstrap import WorkerContext, WorkerEnv
+    from dlrover_tpu.train.data import ElasticDataLoader
+
+    class Slow:
+        def __len__(self):
+            return 4
+
+        def __getitem__(self, i):
+            time.sleep(0.01)
+            return np.zeros((2,), np.float32)
+
+    sent = []
+
+    class Client:
+        def report_global_step(self, step, **kw):
+            sent.append(kw)
+
+    ctx = WorkerContext(WorkerEnv(), Client())
+    ctx.step_report_interval = 0.0
+    assert trace.trace_ring.kind_seconds().get("input_wait", 0.0) == 0.0
+    for _ in ElasticDataLoader(Slow(), batch_size=2, shuffle=False):
+        stepper.step()
+    waited = trace.trace_ring.kind_seconds()["input_wait"]
+    assert waited >= 0.04
+    assert trace.counters()["dataloader.next"][0] == 2
+    rows = trace.step_rows()[-2:]
+    assert sum(r["named_s"] for r in rows) == pytest.approx(waited, abs=1e-6)
+    ctx.report_step(5, force=True, digest=stepper.trainer.step_digest)
+    (kw,) = sent
+    assert kw["digest"]["input_wait_s"] == pytest.approx(waited, abs=1e-5)
+    assert "gc_pause_s" in kw["digest"]
+    assert kw["digest"]["late_n"] >= 2
+
+
+def test_a_collection_between_two_steps_is_gc(stepper, gc_hook):
+    import gc
+
+    from dlrover_tpu.observability import trace
+
+    # something for the collector to walk: 10 ms and more of a pause
+    gc.disable()
+    try:
+        ballast = [[i] for i in range(400000)]
+        stepper.step()          # building it is a row of its own
+        gen2_before = trace.counters().get("gc.gen2", (0, 0.0))
+        gc.collect()
+        row, late, n = _next_row(stepper)
+    finally:
+        gc.enable()
+    del ballast
+    assert row["gc_n"] == (0, 0, 1)
+    assert row["gc_s"][2] > 0.010
+    gen2 = trace.counters()["gc.gen2"]
+    assert gen2[0] == gen2_before[0] + 1
+    assert gen2[1] - gen2_before[1] == pytest.approx(row["gc_s"][2])
+    assert n == 1 and late["named"] == 0.0
+    assert late["gc"] == pytest.approx(row["gc_s"][2], rel=0.05)
+    assert late["gc"] > late["blocked"]
+    assert trace.trace_ring.events() == []      # the ring is off
+
+
+class _StubAnnotation:
+    """What ``jax.profiler.TraceAnnotation`` is to the spine."""
+
+    on = False
+    closed = []
+
+    def __init__(self, name, **stats):
+        self.name, self.stats = name, dict(stats)
+
+    @classmethod
+    def is_enabled(cls):
+        return cls.on
+
+    def __enter__(self):
+        return self
+
+    def set_metadata(self, **stats):
+        self.stats.update(stats)
+
+    def __exit__(self, *exc):
+        type(self).closed.append(self)
+
+
+@pytest.fixture
+def session(monkeypatch):
+    """A profiler session that a test turns on and off."""
+    from dlrover_tpu.observability import trace
+
+    monkeypatch.setattr(_StubAnnotation, "on", False)
+    monkeypatch.setattr(_StubAnnotation, "closed", [])
+    monkeypatch.setattr(
+        trace, "_profiler_annotation",
+        lambda: _StubAnnotation if _StubAnnotation.on else None)
+    return _StubAnnotation
+
+
+def test_a_session_names_the_collection_and_the_step_carries_the_account(
+        stepper, gc_hook, session):
+    import gc
+
+    from dlrover_tpu.observability import trace
+
+    session.on = True
+    stepper.step(2)
+    gc.collect()
+    stepper.step()
+    session.on = False
+    by_name = {}
+    for a in session.closed:
+        by_name.setdefault(a.name, []).append(a)
+    (pause,) = by_name["dlrover/gc.gen2"]
+    assert pause.stats["kind"] == "gc_pause" and "collected" in pause.stats
+    steps = by_name["dlrover/train_step"]
+    assert len(steps) == 3
+    for a in steps:
+        assert {"prev_interval_ms", "prev_gc_ms", "prev_runq_ms",
+                "prev_cpu_ms", "prev_named_ms"} <= set(a.stats)
+    row = trace.step_rows()[-1]
+    assert row["traced"] == 1
+    assert steps[-1].stats["prev_gc_ms"] == pytest.approx(
+        sum(row["gc_s"]) * 1e3)
+    assert steps[-1].stats["prev_interval_ms"] == pytest.approx(
+        row["interval_s"] * 1e3)
+
+
+def test_a_bare_sleep_is_blocked(stepper):
+    time.sleep(0.05)
+    row, late, n = _next_row(stepper)
+    assert row["late_s"] >= 0.04 and n == 1
+    # asleep, the thread neither ran nor waited for a CPU
+    assert late["blocked"] >= 0.045
+    assert late["named"] == 0.0 and late["cpu"] < late["blocked"]
+
+
+def test_a_busy_loop_is_cpu(stepper):
+    until = time.thread_time() + 0.05
+    while time.thread_time() < until:
+        pass
+    row, late, n = _next_row(stepper)
+    assert row["cpu_s"] >= 0.05
+    assert row["late_s"] >= 0.04 and n == 1
+    assert late["cpu"] >= 0.045 and late["named"] == 0.0
+
+
+def test_an_interval_across_a_profiler_start_is_an_edge(stepper, session):
+    stepper.trainer.step_digest.snapshot_and_reset()
+    seen = []
+    session.on = True
+    time.sleep(0.1)             # start_trace's seconds
+    seen.append(_next_row(stepper))
+    seen.append(_next_row(stepper))
+    session.on = False
+    time.sleep(0.1)             # stop_trace's
+    seen.append(_next_row(stepper))
+    seen.append(_next_row(stepper))
+    assert [(row["traced"], row["edge"]) for row, _, _ in seen] == [
+        (0, 1), (1, 0), (0, 1), (0, 0)]
+    for row, late, n in (seen[0], seen[2]):
+        # an edge holds no step: it is late by no rule, folds into no
+        # counter and stays out of the digest
+        assert row["interval_s"] > 0.1 and row["late_s"] == 0.0
+        assert n == 0 and not any(late.values())
+    window = stepper.trainer.step_digest.snapshot_and_reset()
+    assert window["count"] == 2 and window["max_s"] < 0.1
+
+
+def test_the_table_of_rows_is_bounded(untraced):
+    from dlrover_tpu.observability import trace
+
+    account = trace.StepAccount()
+    for step in range(trace.STEP_ROWS_CAP + 10):
+        with trace.span("step", "train_step") as dispatched:
+            account.close(step, dispatched)
+    rows = trace.step_rows()
+    assert len(rows) == trace.STEP_ROWS_CAP == 4096
+    assert rows[-1]["step"] == trace.STEP_ROWS_CAP + 9
+    assert rows[0]["step"] == 10
+    untraced.clear()
+    assert trace.step_rows() == []
+
+
+def test_the_digest_holds_intervals_for_a_loop_that_fetches(stepper):
+    """A loop that fetches every loss waits for the device between two
+    entries: the digest's window is those intervals, not the dispatches,
+    which return before the device has run the step."""
+    from dlrover_tpu.observability import trace
+
+    rows = trace.step_rows()
+    window = stepper.trainer.step_digest.snapshot_and_reset()
+    assert window["count"] == len(rows) == 12
+    total = sum(r["interval_s"] for r in rows)
+    assert window["mean_s"] == pytest.approx(total / 12, abs=2e-6)
+    assert window["max_s"] == pytest.approx(
+        max(r["interval_s"] for r in rows), abs=2e-6)
+    assert trace.counters()["train_step"][1] < total
+    assert window["late_s"] == pytest.approx(
+        sum(r["late_s"] for r in rows), abs=2e-6)
+    # a late step is in the window it ended in
+    time.sleep(0.05)
+    row, _, _ = _next_row(stepper)
+    window = stepper.trainer.step_digest.snapshot_and_reset()
+    assert (window["count"], window["late_n"]) == (1, 1)
+    assert window["late_s"] == pytest.approx(row["late_s"], abs=2e-6)
+
+
+def test_late_account_is_the_counters_rule():
+    """Rows and a median row in, the late rows' excess by cause out, in
+    the order named, gc, runq, cpu, blocked and never more than is
+    left."""
+    from dlrover_tpu.observability.trace import baseline, late_account
+
+    def row(interval, named=0.0, gc=0.0, runq=0.0, cpu=0.002, edge=0):
+        return {"interval_s": interval, "named_s": named,
+                "gc_s": (0.0, 0.0, gc), "runq_s": runq, "cpu_s": cpu,
+                "edge": edge}
+
+    rows = [row(0.300)] * 9 + [
+        row(0.305),                             # under 10 ms: on time
+        row(0.400, named=0.060, gc=0.030),      # 60 named, 30 gc, 10 left
+        row(0.350, gc=0.080),                   # gc capped at the 50
+        row(0.330, runq=0.010, cpu=0.012),      # 10 runq, 10 cpu, 10 left
+        row(9.000, edge=1),
+    ]
+    base = baseline(rows)
+    assert base == {"interval_s": 0.300, "named_s": 0.0, "gc_s": 0.0,
+                    "runq_s": 0.0, "cpu_s": 0.002, "cpu_tick_s": 0.0}
+    got = late_account(rows, base)
+    assert got["n"] == 3
+    assert got["named"] == pytest.approx(0.060)
+    assert got["gc"] == pytest.approx(0.030 + 0.050)
+    assert got["runq"] == pytest.approx(0.010)
+    assert got["cpu"] == pytest.approx(0.010)
+    assert got["blocked"] == pytest.approx(0.010 + 0.010)
+    # 2 % of a long step is more than 10 ms
+    slow = [row(1.000)] * 5 + [row(1.015), row(1.030)]
+    assert late_account(slow, baseline(slow))["n"] == 1
+    # without schedstat runq is None and takes nothing
+    blind = [dict(r, runq_s=None) for r in rows]
+    got = late_account(blind, baseline(blind))
+    assert got["runq"] == 0.0 and got["cpu"] == pytest.approx(0.010)
+    assert got["blocked"] == pytest.approx(0.010 + 0.020)
+    assert baseline([row(1.0, edge=1)]) is None
+    # a thread clock that ticks by 10 ms (a sandboxed kernel's) reads 0
+    # or 10 for a step that costs 3: one tick over the median is no
+    # reading of more work, two are one tick's worth
+    ticking = ([row(0.300, cpu=0.0)] * 6 + [row(0.300, cpu=0.010000000002)] * 3
+               + [row(0.320, cpu=0.010), row(0.340, cpu=0.020)])
+    base = baseline(ticking)
+    assert base["cpu_s"] == 0.0
+    assert base["cpu_tick_s"] == pytest.approx(0.010)
+    got = late_account(ticking, base)
+    assert got["n"] == 2 and got["cpu"] == pytest.approx(0.010)
+    assert got["blocked"] == pytest.approx(0.020 + 0.030)
+
+
+def test_step_account_without_schedstat(untraced, monkeypatch):
+    from dlrover_tpu.observability import trace
+
+    monkeypatch.setattr(trace.StepAccount, "_SCHEDSTAT",
+                        "/proc/thread-self/no-such-file")
+    account = trace.StepAccount()
+    with trace.span("step", "train_step") as dispatched:
+        assert account.close(1, dispatched) is None
+        row = account.close(2, dispatched)
+    assert row["runq_s"] is None and row["cpu_s"] >= 0
+    assert account._fd == -1
 
 
 # ---------------------------------------------------------------------------

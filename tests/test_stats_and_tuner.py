@@ -167,23 +167,35 @@ def test_prefetch_to_device_applies_sharding():
     assert all(b.sharding == sh for b in out)
 
 
-def test_dataloader_fetch_traced_when_tracer_on():
-    """Dataloader fetch spans land in the host timeline (reference
-    py_tracing dataloader interception)."""
+def test_dataloader_fetch_traced_with_every_flag_unset(monkeypatch):
+    """Dataloader fetch spans land in the spine (reference py_tracing
+    dataloader interception): its counters and per-kind seconds always,
+    its ring behind DLROVER_TPU_TRACE."""
     import numpy as np
 
-    from dlrover_tpu.profiler.py_tracing import py_tracer
+    from dlrover_tpu.observability import trace
     from dlrover_tpu.train.data import ElasticDataLoader
 
     ds = [np.zeros((2,), np.float32) for _ in range(8)]
     loader = ElasticDataLoader(ds, batch_size=4, shuffle=False)
-    py_tracer.start()
+    monkeypatch.delenv("DLROVER_TPU_TRACE", raising=False)
+    fetched = trace.counters().get("dataloader.next", (0, 0.0))
+    waited = trace.trace_ring.kind_seconds().get("input_wait", 0.0)
+    list(loader)
+    count, seconds = trace.counters()["dataloader.next"]
+    assert count == fetched[0] + 2 and seconds > fetched[1]
+    assert trace.trace_ring.kind_seconds()["input_wait"] == pytest.approx(
+        waited + seconds - fetched[1])
+    assert "dataloader.next" not in [
+        e["name"] for e in trace.trace_ring.events()]
+    monkeypatch.setenv("DLROVER_TPU_TRACE", "1")
     try:
         list(loader)
+        mine = [e for e in trace.trace_ring.events()
+                if e["name"] == "dataloader.next"]
+        assert len(mine) == 2 and {e["kind"] for e in mine} == {"input_wait"}
     finally:
-        py_tracer.stop()
-    names = [e["name"] for e in py_tracer.events()]
-    assert names.count("dataloader.next") >= 2
+        trace.trace_ring.clear()
 
 
 def test_prefetch_pytree_sharding():

@@ -332,34 +332,45 @@ def test_http_get_bounded_timeout_and_retry_with_warning(monkeypatch):
     assert "error" in HangDumper._fetch_pending(9999)
 
 
-def test_py_tracer_records_gc_and_spans():
+def test_py_tracer_records_gc_and_spans(monkeypatch):
     """Host-side tracing tier (reference py_tracing_manager.cc): GC pauses
-    and user spans land in the chrome-trace ring."""
+    and user spans land in the trace spine, whose ring exports them as
+    chrome-trace events."""
     import gc
 
-    from dlrover_tpu.profiler.py_tracing import PyTracer
+    from dlrover_tpu.observability import trace
+    from dlrover_tpu.profiler.py_tracing import py_tracer
 
-    tracer = PyTracer()
-    tracer.start()
+    monkeypatch.setenv("DLROVER_TPU_TRACE", "1")
+    installed = trace.install_gc_hook()
+    trace.trace_ring.clear()
     try:
-        with tracer.span("dataloader.next"):
+        with py_tracer.span("dataloader.next"):
             pass
         gc.collect()
+        names = {e["name"] for e in trace.trace_ring.events()}
+        assert "dataloader.next" in names
+        assert "gc.gen2" in names
+        assert trace.counters()["gc.gen2"][0] >= 1
+        events = trace.trace_ring.chrome_trace()["traceEvents"]
+        assert all(
+            {"name", "cat", "ph", "ts", "dur"} <= set(e) for e in events
+        )
+        assert {"gc_pause", "host"} <= {e["cat"] for e in events}
+        # with the ring's flag off the spans are counted and not kept
+        kept = len(trace.trace_ring.events())
+        monkeypatch.setenv("DLROVER_TPU_TRACE", "0")
+        with py_tracer.span("after.off"):
+            pass
+        gc.collect()
+        assert "after.off" not in {
+            e["name"] for e in trace.trace_ring.events()}
+        assert trace.counters()["after.off"][0] == 1
+        assert len(trace.trace_ring.events()) == kept
     finally:
-        tracer.stop()
-    events = tracer.events()
-    names = {e["name"] for e in events}
-    assert "dataloader.next" in names
-    assert any(n.startswith("gc.collect") for n in names)
-    trace = json.loads(tracer.chrome_trace())
-    assert all(
-        {"name", "cat", "ph", "ts", "dur"} <= set(e) for e in
-        trace["traceEvents"]
-    )
-    # stopped tracer records nothing
-    with tracer.span("after.stop"):
-        pass
-    assert "after.stop" not in {e["name"] for e in tracer.events()}
+        if installed:
+            gc.callbacks.remove(trace.trace_ring.on_gc)
+        trace.trace_ring.clear()
 
 
 def test_cost_attribution_and_live_mfu_gauge(native):
