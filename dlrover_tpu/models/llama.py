@@ -38,6 +38,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from dlrover_tpu.models import stack
 from dlrover_tpu.observability import trace
+from dlrover_tpu.ops import attention as attn_ops
 from dlrover_tpu.ops import (
     apply_rope,
     cross_entropy_sums,
@@ -67,11 +68,19 @@ class LlamaConfig:
     dtype: Any = jnp.bfloat16          # activation/compute dtype
     param_dtype: Any = jnp.float32     # master params
     remat: bool = True
-    # "all": recompute the whole layer in bwd (min memory);
-    # "mlp": save the ffn gate/up activations — ~75% of a layer's
+    # "all": the layer is recomputed in bwd but for what its attention
+    # backward kernels read, which stays (`_maybe_remat`): q, k, v after
+    # rotary and the flash forward's output and lse, tokens a chip x
+    # (2 h hd + 2 kvh hd) x 2 B + the lse's tokens x h x 4 B a layer
+    # (161 MiB at 8192 tokens and 32 x 128 q, 8 x 128 k and v heads)
+    # beside the layer's input, so the recomputed forward runs neither
+    # the flash kernel nor the three projections;
+    # "mlp": also save the ffn gate/up activations — ~75% of a layer's
     # recompute FLOPs are the two d×ffn matmuls, so saving their outputs
     # (2*b*s*ffn elements/layer) buys most of no-remat's speed at a
-    # fraction of its memory
+    # fraction of its memory (`ffn_gate` names silu's output and its
+    # backward reads its input, so the gate's product is still formed
+    # again: about half of that, PERF.md section 7)
     remat_policy: str = "all"
     attn_impl: str = "auto"   # auto | flash | reference | ring | ulysses
     # chunked fused cross-entropy (ops/chunked_ce.py): vocab columns per
@@ -409,6 +418,32 @@ def _gather_lm_head(lm_head, fsdp_size, tp_size, marker=False):
     return lm_head
 
 
+#: the names a block gives q, k and v where its attention's backward
+#: begins to read them (after rotary; `moe._decoder_layer` with
+#: ``qk_norm`` names q and k before their norm, whose backward reads its
+#: input). With `attention.KEPT` they are all the flash backward kernels
+#: read: a block that keeps both sets (`_maybe_remat`) recomputes neither
+#: the forward kernel nor the three projections.
+QKV_KEPT = ("attn_q", "attn_k", "attn_v")
+
+
+def name_qkv(q, k, v):
+    """q, k, v under `QKV_KEPT`. A name keeps the named copy and nothing
+    else: what is to be spared must read what this returns."""
+    return tuple(checkpoint_name(a, name)
+                 for a, name in zip((q, k, v), QKV_KEPT))
+
+
+def report_kept(name: str):
+    """The ``recompute(kept=)`` callback of a block that keeps `QKV_KEPT`
+    and `attention.KEPT`: the gauges ``attn.qkv_kept`` and
+    ``attn.out_kept`` read 1 once a block's checkpoint has kept a q and
+    a flash forward's output."""
+    attn_ops.report_kept(name)
+    if name == QKV_KEPT[0]:
+        trace.gauge("attn.qkv_kept", 1)
+
+
 def swiglu(y, w_gate, w_up, w_down, dt):
     """The dense feed-forward every family shares: ``(silu(y Wg) * (y
     Wu)) Wd``, its two wide activations named for the "mlp" remat
@@ -453,6 +488,8 @@ def _decoder_layer(cfg: LlamaConfig, mesh, inv_freq, positions, lp, x,
         v = (y @ lp["wv"].astype(dt)).reshape(b, s, kvh, hd)
         q = apply_rope(q, positions, inv_freq)
         k = apply_rope(k, positions, inv_freq)
+        # rotary is linear: its backward reads the positions alone
+        q, k, v = name_qkv(q, k, v)
     if attn_fn is None:
         attn = _attention(cfg, mesh, q, k, v).reshape(b, s, h * hd)
     else:
@@ -481,9 +518,17 @@ def _decoder_layer(cfg: LlamaConfig, mesh, inv_freq, positions, lp, x,
 
 def _maybe_remat(cfg: LlamaConfig, layer_fn):
     """Apply the configured rematerialization policy (one place for the
-    policy ladder: forward() and the pp schedule must never diverge)."""
-    keep = ("ffn_gate", "ffn_up") if cfg.remat_policy == "mlp" else ()
-    return stack.recompute(layer_fn, cfg.remat, keep)
+    policy ladder: forward(), the pp schedule and ``models/moe.py`` must
+    never diverge). The block is recomputed; what its attention backward
+    reads stays: `QKV_KEPT`, and the flash pair where the attention is
+    the flash op (the sp forms name none), tokens a chip x (2 h hd + 2
+    kvh hd) x 2 B + tokens x h x 4 B a layer. Under "mlp" the
+    feed-forward's two wide activations stay too."""
+    keep = attn_ops.KEPT + QKV_KEPT + (
+        ("ffn_gate", "ffn_up") if cfg.remat_policy == "mlp" else ())
+    trace.gauge("attn.out_kept", 0)  # 1 once a block keeps one
+    trace.gauge("attn.qkv_kept", 0)
+    return stack.recompute(layer_fn, cfg.remat, keep, report_kept)
 
 
 def validate_for_mesh(cfg: LlamaConfig, mesh: Mesh, seq_len: int = 0) -> None:

@@ -676,15 +676,20 @@ def _decoder_layer(cfg: MoeConfig, mesh, inv_freq, positions, lp, x):
     with trace.scope("attn_proj"):
         q = y @ lp["wq"].astype(dt)
         k = y @ lp["wk"].astype(dt)
+        v = (y @ lp["wv"].astype(dt)).reshape(b, s, kvh, hd)
         if cfg.qk_norm:
+            # a norm's backward reads its input: q and k are named (and
+            # kept, `forward_hidden`) where the backward's reads begin
+            q, k, v = llama.name_qkv(q, k, v)
             # over the whole projection, before the split into heads
             q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
             k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
         q = q.reshape(b, s, h, hd)
         k = k.reshape(b, s, kvh, hd)
-        v = (y @ lp["wv"].astype(dt)).reshape(b, s, kvh, hd)
         q = apply_rope(q, positions, inv_freq)
         k = apply_rope(k, positions, inv_freq)
+        if not cfg.qk_norm:
+            q, k, v = llama.name_qkv(q, k, v)
     attn = llama._attention(cfg.as_llama(), mesh, q, k, v).reshape(b, s, h * hd)
     with trace.scope("attn_proj"):
         attn = attn @ lp["wo"].astype(dt)
@@ -741,9 +746,10 @@ def forward_hidden(
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta)
 
-    layer_fn = stack.recompute(
-        functools.partial(_decoder_layer, cfg, mesh, inv_freq, positions),
-        cfg.remat)
+    # recomputed, but for what the attention's backward reads
+    layer_fn = llama._maybe_remat(
+        cfg.as_llama(),
+        functools.partial(_decoder_layer, cfg, mesh, inv_freq, positions))
 
     def scan_body(carry, lp):
         x, aux_sum = carry

@@ -252,7 +252,7 @@ def test_one_file_under_models_calls_jax_checkpoint():
 @pytest.mark.parametrize("names,keepers", [
     (r"attention\.KEPT|attn_ops\.KEPT", {
         "dots3.py", "qwen3_next.py", "xing4.py", "kimi_linear.py",
-        "smallthinker.py", "minicpm_sala.py"}),
+        "smallthinker.py", "minicpm_sala.py", "llama.py"}),
     (r"kda\.KEPT", {"kimi_linear.py"}),
     (r"lightning\.KEPT", {"minicpm_sala.py"}),
 ])
@@ -260,17 +260,18 @@ def test_families_keep_a_forward_kernels_residuals_at_their_call_site(
         names, keepers):
     """The keep is each family's own choice where it calls `recompute`
     (its cell's planned peak has the room), not a rule of `stack.py` or
-    of the kernels: the flash forward's pair in six files, the delta
-    rule's in kimi's alone (qwen3next's step has not the room), the
-    lightning rule's in minicpm_sala's; Llama, `moe.py`'s layer and ViT
-    name nothing."""
+    of the kernels: the flash forward's pair in seven files (Llama's
+    `_maybe_remat`, which `moe.py`'s layer goes through, keeps q, k, v
+    beside it), the delta rule's in kimi's alone (qwen3next's step has
+    not the room), the lightning rule's in minicpm_sala's; ViT names
+    nothing."""
     sources = _sources()
     assert {name for name, text in sources.items()
             if re.search(names, text)} == keepers
     assert {name for name, text in sources.items()
             if re.search(r"\bKEPT\b", text)} == {
         "dots3.py", "qwen3_next.py", "xing4.py", "kimi_linear.py",
-        "smallthinker.py", "minicpm_sala.py"}
+        "smallthinker.py", "minicpm_sala.py", "llama.py"}
     assert "KEPT" not in sources["stack.py"]
 
 
