@@ -1,0 +1,2 @@
+"""``g4h_attn_proj_ms``: see ``g4h_attn_proj_ms.json``."""
+from benchmarks.harness.hlo_scopes import scoped_ms_per_step as read  # noqa: F401

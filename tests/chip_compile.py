@@ -24,7 +24,7 @@ from jax.sharding import SingleDeviceSharding
 
 from dlrover_tpu.ops import (
     attention, blocksel, dsa, fused_ce, grouped_matmul, hc_mix, kda,
-    lightning, moe_rows)
+    lightning, moe_rows, ssd)
 
 
 @pytest.fixture(scope="module")
@@ -66,6 +66,7 @@ def kernels_are_the_path(monkeypatch):
     monkeypatch.setattr(lightning, "_on_tpu", lambda: True)
     monkeypatch.setattr(blocksel, "_on_tpu", lambda: True)
     monkeypatch.setattr(hc_mix, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ssd, "_on_tpu", lambda: True)
     monkeypatch.setenv("DLROVER_TPU_FUSED_CE", "1")
 
 

@@ -698,3 +698,37 @@ def test_hc_mix_passes_compile_over_four_chips(mesh4, kernels_are_the_path):
     hlo = _compile(jax.grad(loss, argnums=(0, 1, 2, 3)), *args)
     _assert_the_hc_passes(_compile(loss, *args), hlo)
     assert "all-reduce" in hlo
+
+
+# granite4h-ep8-1chip-steady (PR 52): the state-space scan at the cell's
+# shape, 32 heads of 64 (a pair a lane tile), one group of state 128, 64
+# chunks of 256. One call forward; under differentiation the forward with
+# a float32 state a chunk (64 MiB) and the hand-written backward, whose
+# sums of the per-head cotangents XLA closes under the same scope.
+def test_ssd_kernels_compile_at_the_cells_shape(one_chip,
+                                                kernels_are_the_path):
+    from dlrover_tpu.ops import ssd
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sds((1, 16384, 32, 64)), sds((1, 16384, 32), jnp.float32),
+            sds((32,), jnp.float32), sds((1, 16384, 128)),
+            sds((1, 16384, 128)), sds((32,), jnp.float32))
+
+    def loss(*operands):
+        with jax.named_scope("ssm_chunk"):
+            return ssd.ssd(*operands, chunk=256).astype(jnp.float32).sum()
+
+    names = _op_names(_compile(loss, *args))
+    assert len(names) == 1 and _in_scope(names[0], "ssm_chunk")
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        *args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * 2**30
+    hlo = compiled.as_text()
+    names = _op_names(hlo)
+    assert len(names) == 2 and all(_in_scope(n, "ssm_chunk") for n in names)
+    assert _kernel_calls(hlo, "ssd_bwd") == 1
+    assert "f32[1,64,2048,128]" in hlo          # a state a chunk
+    assert trace.gauges()["ssm.kernel"] == 1
+    assert ssd._heads_a_step(32, 64) == 32      # C B^T once a chunk

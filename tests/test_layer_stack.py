@@ -13,7 +13,8 @@ import pytest
 from jax.ad_checkpoint import checkpoint_name
 
 from dlrover_tpu.models import (
-    dots3, kimi_linear, minicpm_sala, qwen3_next, smallthinker, stack)
+    dots3, granite_hybrid, kimi_linear, minicpm_sala, qwen3_next,
+    smallthinker, stack)
 from dlrover_tpu.models.stack import Part
 
 MODELS = os.path.join(os.path.dirname(os.path.dirname(
@@ -74,8 +75,10 @@ def test_runs_are_stacked_parts_of_one_position():
     (qwen3_next.Qwen3NextConfig().layout, qwen3_next.Qwen3NextConfig().kinds),
     (minicpm_sala.MiniCPMSalaConfig().layout,
      minicpm_sala.MiniCPMSalaConfig().kinds),
+    (granite_hybrid.GraniteHybridConfig().layout,
+     granite_hybrid.GraniteHybridConfig().kinds),
 ], ids=["smallthinker", "dots3", "kimi_linear", "head_and_tail",
-        "qwen3_next", "minicpm_sala"])
+        "qwen3_next", "minicpm_sala", "granite_hybrid"])
 def test_locate_finds_every_layer_once_and_in_order(parts, kinds):
     seen = [stack.locate(parts, layer) for layer in range(len(kinds))]
     assert len(set(seen)) == len(kinds) and seen == sorted(
@@ -252,33 +255,37 @@ def test_one_file_under_models_calls_jax_checkpoint():
 @pytest.mark.parametrize("names,keepers", [
     (r"attention\.KEPT|attn_ops\.KEPT", {
         "dots3.py", "qwen3_next.py", "xing4.py", "kimi_linear.py",
-        "smallthinker.py", "minicpm_sala.py", "llama.py"}),
+        "smallthinker.py", "minicpm_sala.py", "llama.py",
+        "granite_hybrid.py"}),
     (r"kda\.KEPT", {"kimi_linear.py"}),
     (r"lightning\.KEPT", {"minicpm_sala.py"}),
+    (r"ssd\.KEPT", {"granite_hybrid.py"}),
 ])
 def test_families_keep_a_forward_kernels_residuals_at_their_call_site(
         names, keepers):
     """The keep is each family's own choice where it calls `recompute`
     (its cell's planned peak has the room), not a rule of `stack.py` or
-    of the kernels: the flash forward's pair in seven files (Llama's
+    of the kernels: the flash forward's pair in eight files (Llama's
     `_maybe_remat`, which `moe.py`'s layer goes through, keeps q, k, v
     beside it), the delta rule's in kimi's alone (qwen3next's step has
-    not the room), the lightning rule's in minicpm_sala's; ViT names
-    nothing."""
+    not the room), the lightning rule's in minicpm_sala's, the
+    state-space scan's in granite_hybrid's (where its configuration says
+    so); ViT names nothing."""
     sources = _sources()
     assert {name for name, text in sources.items()
             if re.search(names, text)} == keepers
     assert {name for name, text in sources.items()
             if re.search(r"\bKEPT\b", text)} == {
         "dots3.py", "qwen3_next.py", "xing4.py", "kimi_linear.py",
-        "smallthinker.py", "minicpm_sala.py", "llama.py"}
+        "smallthinker.py", "minicpm_sala.py", "llama.py",
+        "granite_hybrid.py"}
     assert "KEPT" not in sources["stack.py"]
 
 
 def test_no_family_walks_its_layers_or_shifts_its_targets_itself():
     sources = _sources()
     for name in ("kimi_linear.py", "smallthinker.py", "dots3.py",
-                 "qwen3_next.py", "minicpm_sala.py"):
+                 "qwen3_next.py", "minicpm_sala.py", "granite_hybrid.py"):
         assert "lax.scan(" not in sources[name], name
     assert {name for name, text in sources.items()
             if "_shift_targets" in text} == {"llama.py"}

@@ -20,8 +20,8 @@ import numpy as np
 import pytest
 
 from dlrover_tpu.models import (
-    dots3, kimi_linear, llama, minicpm_sala, moe, qwen3_next, smallthinker,
-    vit, xing4)
+    dots3, granite_hybrid, kimi_linear, llama, minicpm_sala, moe, qwen3_next,
+    smallthinker, vit, xing4)
 
 GOLDEN = os.path.join(os.path.dirname(__file__), "golden", "param_trees.json")
 
@@ -39,6 +39,8 @@ FAMILIES = {
         layer_kinds=(F, S, F, S, F, S, F), n_dense_layers=2)),
     "qwen3_next": (qwen3_next, qwen3_next.Qwen3NextConfig.tiny()),
     "minicpm_sala": (minicpm_sala, minicpm_sala.MiniCPMSalaConfig.tiny()),
+    "granite_hybrid": (granite_hybrid,
+                       granite_hybrid.GraniteHybridConfig.tiny()),
 }
 
 
