@@ -53,8 +53,11 @@ it is a real compute path, and there is one of it:
   the device and the arrays keep their ``(t * k, .)``; past the count
   they are unwritten memory (``lax.ragged_dot``, where a shape falls
   back to it, would read it, which is why all of this runs only beside
-  the grouped kernels). Dispatch's forward stays XLA's gather, and so
-  does everything where every expert is held and under a mesh.
+  the grouped kernels). Dispatch's forward gathers the live rows and no
+  others where the held share and the tokens' size make that the faster
+  (`moe_rows.gather_pays`; the gauge ``moe.dispatch_bounded``) and is
+  XLA's gather of every row elsewhere, as is everything where every
+  expert is held and under a mesh.
 - **under a mesh** the same path runs inside ``shard_map``: tokens stay
   where the batch axes put them, the rows are gathered over ``ep``, each
   rank sorts by its local experts (pairs for other ranks' experts fall
@@ -356,29 +359,36 @@ def _row_blocks(rows, weights_shape, live, interpret: bool):
 
 
 def dispatch_rows(yt, order, inverse, k: int, live=None, *,
-                  interpret: bool = False):
+                  whole: bool = False, interpret: bool = False):
     """``yt (t, d)`` -> ``(t * k, d)``: sorted row ``r`` is the token of
     pair ``order[r]`` (one gather of whole rows: XLA's runs at the HBM's
     rate). The backward gathers by ``inverse`` and sums a token's ``k``
     rows, where autodiff would scatter-add. ``live ()``: the sorted rows
     that any product reads, ``sum(group_sizes)``. Given it, on the TPU
-    (or under ``interpret``) the backward reads the cotangent's rows
-    below it and no others (``ops/moe_rows.py``)."""
+    (or under ``interpret``) the forward gathers the rows below it, to
+    the end of the block that holds row ``live``, and writes no other
+    (the same rows bit for bit; ``whole``: every row all the same, by
+    XLA's gather, where that is the faster), and the backward reads the
+    cotangent's rows below it and no others (``ops/moe_rows.py``)."""
     blocks = _row_blocks(yt, (yt.shape[0], k), live, interpret)
-    return _dispatch(yt, order, inverse, live, k, blocks, interpret)
+    return _dispatch(yt, order, inverse, live, k, blocks, whole, interpret)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _dispatch(yt, order, inverse, live, k, blocks, interpret):
-    return yt[order // k]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _dispatch(yt, order, inverse, live, k, blocks, whole, interpret):
+    if blocks is None or whole:
+        return yt[order // k]
+    return moe_rows.gathered_rows(yt, order // k, live, block=blocks[0],
+                                  interpret=interpret)
 
 
-def _dispatch_fwd(yt, order, inverse, live, k, blocks, interpret):
-    return (_dispatch.fun(yt, order, inverse, live, k, blocks, interpret),
+def _dispatch_fwd(yt, order, inverse, live, k, blocks, whole, interpret):
+    return (_dispatch.fun(yt, order, inverse, live, k, blocks, whole,
+                          interpret),
             (order, inverse, live))
 
 
-def _dispatch_bwd(k, blocks, interpret, res, g):
+def _dispatch_bwd(k, blocks, whole, interpret, res, g):
     order, inverse, live = res
     n, d = g.shape
     if blocks is None:
@@ -517,21 +527,26 @@ _gated.defvjp(_gated_fwd, _gated_bwd)
 
 def _experts(lp: Params, yt, top_p, top_e, n_local: int, first: Any = 0,
              act: str = "silu", tail: bool = False, *,
-             interpret: bool = False):
+             share: Optional[float] = None, interpret: bool = False):
     """The ``n_local`` experts ``first ..`` applied to the pairs of
     ``yt (t, d)`` that chose them, weighted and summed: ``(t, d)``.
     ``tail``: the router scores experts that are not among them, so
     pairs can sort past ``live = sum(group_sizes)``. Nothing between
     `dispatch_rows`' gather and the result then touches a row at or past
-    that count: the grouped products walk no tile of the tail, forward
-    or backward (``tail_unread``), ``act(gate) x up`` and its backward
-    stop at the block that holds row ``live``, and combine, its backward
-    and dispatch's backward read below it (``ops/moe_rows.py``); what
-    lies past it in every ``(t * k, .)`` array but the gathered rows is
-    unwritten memory. ``lax.ragged_dot``, where a shape falls back to
-    it, would read it, so all of this runs only where the three grouped
-    products tile. Without a tail every row is live and XLA's ops, which
-    move a row without the seven beside it, are the faster
+    that count: ``act(gate) x up`` and its backward stop at the block
+    that holds row ``live``, the grouped products walk no tile of the
+    tail, forward or backward (``tail_unread``), and combine, its
+    backward and dispatch's backward read below it
+    (``ops/moe_rows.py``); what lies past it in every ``(t * k, .)``
+    array but the gathered rows is unwritten memory. The gather stops at
+    that block too where that is the faster (``share``: the held
+    experts' share of the router's, which `moe_rows.gather_pays` weighs
+    with the tokens' size; None: it stops); the gathered rows are then
+    no residual of gate's and up's products, whose backward gathers the
+    live rows again. ``lax.ragged_dot``, where a shape falls back to it,
+    would read unwritten rows, so all of this runs only where the three
+    grouped products tile. Without a tail every row is live and XLA's
+    ops, which move a row without the seven beside it, are the faster
     (docs/design/kernels.md 1c). ``interpret`` runs every kernel in
     interpreter mode (the CPU tests)."""
     t, k = top_e.shape
@@ -540,20 +555,40 @@ def _experts(lp: Params, yt, top_p, top_e, n_local: int, first: Any = 0,
               and choose_tiles(t * k, f, d, yt.dtype)
               and moe_rows.row_blocks(t, k, d, yt.dtype,
                                       interpret=interpret))
+    bounded = bool(blocks) and (
+        share is None or moe_rows.gather_pays(t, d, yt.dtype, share))
     trace.gauge("moe.rows_kernel", int(bool(blocks)))
+    trace.gauge("moe.dispatch_bounded", int(bounded))
     trace.gauge("moe.row_block", blocks[0] if blocks else 0)
     with trace.scope("moe_dispatch"):
         order, inverse, group_sizes = sort_pairs(top_e, n_local, first)
         live = jnp.sum(group_sizes) if blocks else None
-        xs = dispatch_rows(yt, order, inverse, k, live, interpret=interpret)
+    products = functools.partial(
+        grouped_matmuls, group_sizes=group_sizes,
+        tail_unread=bool(blocks), interpret=interpret)
+
+    def gate_and_up(yt, w_gate, w_up):
+        with trace.scope("moe_dispatch"):
+            xs = dispatch_rows(yt, order, inverse, k, live,
+                               whole=not bounded, interpret=interpret)
+        with trace.scope("moe_experts"):
+            return products(xs, (w_gate, w_up))
+
+    # the gathered rows are the products' residual, (t k, d) whatever the
+    # count. XLA gathers every row a second time by itself where a step
+    # does not fit with them kept (granite's); a kernel it cannot repeat,
+    # so the backward is told to gather the live rows again, and gate's
+    # and up's cotangents wait for down's d-rhs (a barrier's transpose is
+    # a barrier), or the scheduler holds combine's d_rows across them
+    # (PERF.md section 6, PR 53)
+    gate, up = stack.recompute(gate_and_up, bounded)(
+        yt, lp["w_gate"], lp["w_up"])
+    w_down = lp["w_down"]
+    if bounded:
+        gate, up, w_down = lax.optimization_barrier((gate, up, w_down))
     with trace.scope("moe_experts"):
-        products = functools.partial(
-            grouped_matmuls, group_sizes=group_sizes,
-            tail_unread=bool(blocks), interpret=interpret)
-        gate, up = products(xs, (lp["w_gate"], lp["w_up"]))
         rows, = products(
-            gated_rows(gate, up, act, live, interpret=interpret),
-            (lp["w_down"],))
+            gated_rows(gate, up, act, live, interpret=interpret), (w_down,))
     with trace.scope("moe_combine"):
         return combine_rows(rows, top_p, order, inverse, live,
                             interpret=interpret)
@@ -624,7 +659,8 @@ def moe_mlp(
             yt if route_on is None else route_on.reshape(b * s, d),
             bias=lp.get("router_bias"))
         out = _experts(lp, yt, top_p, top_e, cfg.n_held, cfg.first_expert,
-                       cfg.expert_act, cfg.n_held < cfg.n_experts)
+                       cfg.expert_act, cfg.n_held < cfg.n_experts,
+                       share=cfg.n_held / cfg.n_experts)
         out = out.reshape(b, s, d)
     else:
         specs = {"router": P(None, None), "w_gate": P(EP, None, TP),

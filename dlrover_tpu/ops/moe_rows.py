@@ -1,7 +1,7 @@
 """The expert layer's row movements, bound by the live count: Pallas TPU
-kernels for ``models/moe.py``'s ``combine_rows`` (forward and backward)
-and ``dispatch_rows``' backward, and the pass between the grouped
-products, ``act(gate) x up`` (`gated_rows`), forward and backward.
+kernels for ``models/moe.py``'s ``dispatch_rows`` and ``combine_rows``
+(forward and backward) and the pass between the grouped products,
+``act(gate) x up`` (`gated_rows`), forward and backward.
 
 ``models/moe.py`` sorts the ``n = t * k`` (token, choice) pairs by expert
 and keeps every array at its worst-case ``(n, d)``; a chip that holds
@@ -45,8 +45,14 @@ operand, so shapes stay static and nothing is dropped.
   is 50-85 ns a row on the v5e by width (the 32-56 KB of a fetch at
   about 690 GB/s), slower than XLA's gather of whole rows (8-13 ns) and
   a little faster than its weighted sums (70-90 ns over every row):
-  the kernels win by the rows they skip, and dispatch's forward, a
-  plain gather, stays XLA's (docs/design/kernels.md 1c).
+  the kernels win by the rows they skip.
+- **dispatch's forward** (`gathered_rows`: PR 53): blocks of sorted
+  rows up to the one that holds row ``live``, on a grid as long; a row
+  is fetched as above and stored as it is (exact). XLA's gather of
+  whole rows runs at the HBM's rate while its operand can be staged in
+  VMEM, so the kernel wins by the rows it skips and only past a share
+  of dead rows that `gather_pays` reads off the shapes
+  (docs/design/kernels.md 1c).
 
 Off the TPU, for a dtype or shape the kernels do not take, and as their
 oracle in tests/test_moe_rows.py, ``models/moe.py`` keeps XLA's ops.
@@ -103,6 +109,25 @@ def row_blocks(t: int, k: int, d: int, dtype, *, interpret: bool = False
     if d % 128 or not rows or not tokens:
         return None
     return rows[0], tokens[0]
+
+
+#: What `gather_pays` weighs, GB/s on the v5e (docs/design/kernels.md
+#: 1c): the row fetches' rate over the 8 rows a fetch moves, and XLA's
+#: whole gather while its operand can be staged in VMEM and from the
+#: size on where it cannot (128 MiB gathers from HBM; 80 is staged).
+_FETCH_RATE, _STAGED_RATE, _UNSTAGED_RATE = 630.0, 650.0, 130.0
+_UNSTAGED_BYTES = 128 * 2**20
+
+
+def gather_pays(t: int, d: int, dtype, share: float) -> bool:
+    """Whether `gathered_rows` over the held ``share`` of the sorted rows
+    of ``t`` tokens of width ``d`` is faster than XLA's gather of every
+    row: three calls a layer (the rows are not kept for gate's and up's
+    d-rhs: ``models/moe.py`` `_experts`) of 8-row fetches against two at
+    the rate the tokens' size allows."""
+    staged = t * d * jnp.dtype(dtype).itemsize < _UNSTAGED_BYTES
+    whole = _STAGED_RATE if staged else _UNSTAGED_RATE
+    return 3 * share * _GROUP / _FETCH_RATE < 2 / whole
 
 
 def _row_f32(stage, slot, sub):
@@ -233,6 +258,54 @@ def sorted_cotangents(g, tok, rows, weights, live, *, block: int,
     )(live, tok.reshape(blocks, 1, block),
       weights.reshape(blocks, 1, block), g, rows)
     return d_rows, d_weights.reshape(n)
+
+
+# ---------------------------------------------------------------------------
+# sorted order: dispatch's forward, the live blocks' rows and no others
+# ---------------------------------------------------------------------------
+
+def _gathered_kernel(live_ref, tok_ref, src_ref, out_ref, stage, picked,
+                     sems):
+    def use(i, row):
+        picked[pl.ds(i, 1), :] = row
+
+    _walk(out_ref.shape[0], lambda i: tok_ref[0, i], src_ref, stage, sems,
+          use)
+    out_ref[...] = picked[...].astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def gathered_rows(yt, tok, live, *, block: int, interpret: bool = False):
+    """Dispatch's forward in sorted order. ``yt (t, d)``, ``tok (n,)``
+    int32 (the token of each sorted row), ``live ()`` int32 -> ``(n,
+    d)``: row ``r`` is ``yt[tok[r]]``, exactly, for every ``r`` up to the
+    end of the block that holds row ``live`` (so a tile the grouped
+    products straddle there holds real rows, which they mask by
+    position); later blocks are neither read nor written. The grid's
+    length is counted on the device."""
+    n, d = tok.shape[0], yt.shape[1]
+    blocks = n // block
+    live = live.reshape(1).astype(jnp.int32)
+    return pl.pallas_call(
+        _gathered_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(jnp.minimum(live[0] // block + 1, blocks),),
+            in_specs=[pl.BlockSpec((None, 1, block),
+                                   lambda b, live: (b, 0, 0),
+                                   memory_space=pltpu.SMEM),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((block, d), lambda b, live: (b, 0)),
+            scratch_shapes=[pltpu.VMEM((_WINDOW, _GROUP, d), yt.dtype),
+                            pltpu.VMEM((block, d), _F32),
+                            pltpu.SemaphoreType.DMA((_WINDOW,))]),
+        out_shape=jax.ShapeDtypeStruct((n, d), yt.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name="moe_rows_gathered",
+    )(live, tok.reshape(blocks, 1, block), yt)
 
 
 # ---------------------------------------------------------------------------

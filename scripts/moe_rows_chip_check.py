@@ -1,7 +1,8 @@
 """``ops/moe_rows.py`` on the chip at the expert cells' shapes: the four
 row movements of ``models/moe.py`` (dispatch and combine, forward and
-backward) through the kernels against XLA's gathers: time, and every
-output and gradient element for element.
+backward) bound by the live count against XLA's gathers over every row:
+time, and every output and gradient element for element (dispatch's
+forward below the count and to the end of its block).
 
     chiprun -- python scripts/moe_rows_chip_check.py [--seed N]
         [--cells a,b] [--window 8,16] [--tokens 64,128]
@@ -32,6 +33,9 @@ CELLS = {
     "smallthinker": (16384, 6, 64, 16, 2560),
     "xing4": (8192, 4, 64, 8, 3584),
     "kimi": (8192, 8, 256, 32, 2304),
+    "qwen3next": (16384, 10, 512, 32, 2048),
+    "dots3": (8192, 8, 256, 8, 5120),
+    "granite": (16384, 10, 72, 9, 4096),
     "olmoe": (8192, 8, 64, 64, 2048),
 }
 
@@ -45,14 +49,20 @@ def timed(fn, *args, reps=10):
     return (time.perf_counter() - t0) / reps * 1e3, out
 
 
-def differing(got, want, keep=None):
-    """Elements that differ (NaN counts), among the rows ``keep``."""
-    got, want = (jnp.asarray(a, jnp.float32) for a in (got, want))
+@jax.jit
+def _differing(got, want, keep):
+    # one fused pass: a (163840, 4096) pair has no room for float32 copies
     bad = ~((got == want) & ~jnp.isnan(got))
     if keep is not None:
         bad = bad & keep.reshape(keep.shape + (1,) * (bad.ndim - keep.ndim))
-    worst = jnp.max(jnp.where(bad, jnp.abs(got - want), 0.0))
-    return {"differ": int(jnp.sum(bad)), "max_abs": float(worst)}
+    gap = jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32))
+    return jnp.sum(bad), jnp.max(jnp.where(bad, gap, 0.0))
+
+
+def differing(got, want, keep=None):
+    """Elements that differ (NaN counts), among the rows ``keep``."""
+    count, worst = _differing(got, want, keep)
+    return {"differ": int(count), "max_abs": float(worst)}
 
 
 def check(seed, t, k, e, held, d, interpret=False, profile=False):
@@ -66,12 +76,13 @@ def check(seed, t, k, e, held, d, interpret=False, profile=False):
     below = jnp.arange(n) < live
     yt = jax.random.normal(ks[1], (t, d)).astype(bf)
     weights = jax.random.uniform(ks[2], (t, k), jnp.float32)
-    # as the grouped products leave them: zeros past the live count
-    rows = jnp.where(below[:, None], jax.random.normal(ks[3], (n, d)), 0
-                     ).astype(bf)
+    # as the grouped products leave them: zeros past the live count (in
+    # one fused pass: float32 normals of granite's shape are 2.5 GiB)
+    sorted_rows = jax.jit(lambda key: jnp.where(
+        below[:, None], jax.random.normal(key, (n, d)), 0).astype(bf))
+    rows = sorted_rows(ks[3])
     g_tokens = jax.random.normal(ks[4], (t, d)).astype(bf)
-    g_rows = jnp.where(below[:, None], jax.random.normal(ks[5], (n, d)), 0
-                       ).astype(bf)
+    g_rows = sorted_rows(ks[5])
 
     def forms(count):
         dispatch = lambda y: moe.dispatch_rows(
@@ -111,8 +122,11 @@ def check(seed, t, k, e, held, d, interpret=False, profile=False):
         r = res[name] = {}
         r["xla_ms"], want = timed(xla[name][0], *xla[name][1])
         r["kernel_ms"], got = timed(kernels[name][0], *kernels[name][1])
-        if name == "dispatch_fwd":      # XLA's gather in both forms
-            r.update(differing(got, want))
+        if name == "dispatch_fwd":
+            # the rows gathered past the count, to its block's end, are real
+            block = res["row_blocks"][0]
+            r.update(differing(got, want, jnp.arange(n) < jnp.minimum(
+                n, (live // block + 1) * block)))
         elif name == "combine_bwd":
             r["d_rows"] = differing(got[0], want[0], below)
             r["d_weights"] = differing(got[1], want[1], live_pair)
@@ -131,6 +145,7 @@ def check(seed, t, k, e, held, d, interpret=False, profile=False):
         res["device_ops_ms"] = device_ops(
             [("sort_pairs", jax.jit(lambda te: moe.sort_pairs(te, held, 0)),
               (top_e.astype(jnp.int32),))]
+            + [("dispatch_fwd_every_row",) + xla["dispatch_fwd"]]
             + [(name,) + kernels[name] for name in kernels])
     return res
 

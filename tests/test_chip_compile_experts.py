@@ -78,17 +78,26 @@ def test_olmoe_expert_layer_fwd_bwd_compiles(one_chip, kernels_are_the_path):
 # backward (`moe_rows_gated`, `moe_rows_gated_bwd`) while the grouped
 # products walk no tile of the tail; OLMoE, which holds every expert,
 # keeps XLA's gathers and fusion and the walk it had.
+# Since PR 53, where the dead rows pay for it (`moe_rows.gather_pays`:
+# the three cells of `BOUNDED`), dispatch's forward is a kernel too
+# (`moe_rows_gathered`) and the gathered rows are no residual of gate's
+# and up's products, whose backward gathers the live rows again: such a
+# layer holds one `(t k, d)` array less than its parent's (PR 52's bytes
+# were 2630160896, 2558014464 and, for granite's layer, 4786955776).
 EXPERT_CELLS = {
     "smallthinker": ((16384, 6, 64, 16, 2560, 768, "relu"), 1971133440),
     "xing4": ((8192, 4, 64, 8, 3584, 1024, "silu"), 910812160),
     "kimi": ((8192, 8, 256, 32, 2304, 1024, "silu"), 1054416896),
-    "dots3": ((8192, 8, 256, 8, 5120, 1536, "silu"), 2630225408),
+    "dots3": ((8192, 8, 256, 8, 5120, 1536, "silu"), 1948318208),
     "olmoe": ((8192, 8, 64, None, 2048, 1024, "silu"), 675513856),
     # PR 45, many small experts: 320 rows an expert of width 512, a
-    # router 512 wide, 163840 pairs through the sort; no parent, so the
-    # bytes are this layer's own at the PR that listed the cell
-    "qwen3next": ((16384, 10, 512, 32, 2048, 512, "silu"), 2558014464),
+    # router 512 wide, 163840 pairs through the sort
+    "qwen3next": ((16384, 10, 512, 32, 2048, 512, "silu"), 1850138624),
+    # PR 52's cell, listed by PR 53: 163840 pairs of width 4096, an
+    # eighth of them live, tokens XLA's gather cannot stage (128 MiB)
+    "granite": ((16384, 10, 72, 9, 4096, 768, "silu"), 3441587200),
 }
+BOUNDED = {"dots3", "qwen3next", "granite"}
 
 
 def _expert_layer(cell, sharding, mesh=None, batch=1):
@@ -141,6 +150,17 @@ def test_expert_rows_fwd_bwd_compile_in_the_parents_memory(
     assert _kernel_calls(hlo, "grouped_matmul_dlhs") == 3
     assert _kernel_calls(hlo, "grouped_matmul") == 12
     assert trace.gauges()["moe.rows_kernel"] == int(tail)
+    # dispatch's forward: the kernel in the forward, under remat and once
+    # more for gate's and up's d-rhs, and no gather of every row; or
+    # XLA's whole gather, forward and under remat
+    (t, k, _, _, d, _, _), _ = EXPERT_CELLS[cell]
+    whole = sum(f"bf16[{t * k},{d}]" in line.split(" gather(")[0]
+                for line in hlo.splitlines() if " gather(" in line)
+    bounded = cell in BOUNDED
+    assert trace.gauges()["moe.dispatch_bounded"] == int(bounded)
+    assert _kernel_calls(hlo, "moe_rows_gathered") == (3 if bounded else 0)
+    if tail:
+        assert whole == (0 if bounded else 2)
     assert trace.gauges()["moe.tail_skipped"] == int(tail)
     assert trace.gauges()["moe.row_block"] == (256 if tail else 0)
     # every kernel under the scope the device metrics select by

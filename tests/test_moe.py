@@ -8,6 +8,7 @@ but the parameter tree.
 """
 
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +17,8 @@ import optax
 import pytest
 
 from dlrover_tpu.models import moe
+from dlrover_tpu.observability import trace
+from dlrover_tpu.ops import grouped_matmul, moe_rows
 from dlrover_tpu.parallel import MeshConfig, build_mesh
 from dlrover_tpu.parallel.sharding import shard_pytree
 
@@ -213,6 +216,40 @@ def test_dropless_under_skew(convention):
     used = {k: grads[0][k] for k in ("router", "w_gate", "w_up", "w_down")}
     _assert_grads_close((used, grads[1]),
                         ({k: want_grads[0][k] for k in used}, want_grads[1]))
+
+
+@pytest.mark.parametrize("held,bounded", [(2, 1), (8, 0), (None, 0)])
+def test_dispatch_is_bounded_where_the_dead_rows_pay_for_it(
+        monkeypatch, held, bounded):
+    """The gradient program of a held share of 32 experts, traced as it
+    is on the TPU (nothing is lowered). A sixteenth held: the gauge says
+    dispatch's forward stops at the count, no gather makes a ``(t k, d)``
+    array, and the kernel is there twice, the forward's call and the one
+    the backward makes again. A quarter held: the row kernels run and
+    the forward's gather is XLA's, over every row, once. Every expert
+    held: the program names no count and keeps XLA's gathers."""
+    monkeypatch.setattr(moe_rows, "_on_tpu", lambda: True)
+    monkeypatch.setattr(grouped_matmul, "_on_tpu", lambda: True)
+    d = 128
+    cfg = moe.MoeConfig.tiny(dim=d, ffn_dim=d, n_experts=32,
+                             experts_held=held)
+    layers = moe.init_params(cfg, jax.random.key(0))["layers"]
+    lp = {name: layers[name][0]
+          for name in ("router", "w_gate", "w_up", "w_down")}
+    y = jnp.zeros((2, 64, d), cfg.dtype)
+    pairs = 2 * 64 * cfg.experts_per_token
+
+    def loss(lp, y):
+        out, aux = moe.moe_mlp(cfg, lp, y)
+        return jnp.sum(out) + aux
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(lp, y))
+    gauges = trace.gauges()
+    assert gauges["moe.dispatch_bounded"] == bounded
+    assert gauges["moe.rows_kernel"] == int(held is not None)
+    whole = len(re.findall(rf":f32\[{pairs},{d}\] = gather\[", text))
+    kernels = text.count("name=moe_rows_gathered")
+    assert (whole, kernels) == {2: (0, 2), 8: (1, 0), None: (4, 0)}[held]
 
 
 @pytest.mark.parametrize("t,k,e", [(16, 2, 4), (40, 8, 64), (7, 3, 5)])

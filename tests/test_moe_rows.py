@@ -1,10 +1,10 @@
 """The expert layer's row movements bound by the live count
 (ops/moe_rows.py through models/moe.py's ``dispatch_rows`` and
 ``combine_rows``), the kernels in interpreter mode against XLA's gathers:
-combine's forward, dispatch's backward and combine's two cotangents, at
-live counts on and around a block's edge, under skewed routing, and
-with everything the kernels must not read, and everything they do not
-write, poisoned with NaN.
+dispatch's forward, combine's forward, dispatch's backward and combine's
+two cotangents, at live counts on and around a block's edge, under
+skewed routing, and with everything the kernels must not read, and
+everything they do not write, poisoned with NaN.
 
 Rows, weights and cotangents are small integers (or bfloat16-valued),
 so every product and every sum is exact in float32: XLA's CPU backend
@@ -103,17 +103,27 @@ def _f32(x):
     return np.asarray(jnp.asarray(x, jnp.float32))
 
 
+def _edge(n, live, block):
+    """The end of the block of ``block`` rows that holds row ``live``."""
+    return min(n, (live // block + 1) * block)
+
+
 def _assert_same(got, want, live, inverse, k):
-    """Bit for bit wherever a result is defined; ``d_rows`` is defined
-    below the live count and zero to the end of the block that holds
-    row ``live``; a tail pair's ``d_weights`` is exactly 0."""
+    """Bit for bit wherever a result is defined; ``xs`` and ``d_rows``
+    are defined below the live count, ``xs`` finite (real rows) and
+    ``d_rows`` zero to the end of the block that holds row ``live``,
+    and nothing is said of either past it; a tail pair's ``d_weights``
+    is exactly 0."""
     n = inverse.shape[0]
-    for name in ("xs", "d_yt", "out"):
+    for name in ("d_yt", "out"):
         np.testing.assert_array_equal(_f32(got[name]), _f32(want[name]), name)
+    for name in ("xs", "d_rows"):
+        np.testing.assert_array_equal(
+            _f32(got[name])[:live], _f32(want[name])[:live], name)
+    written = _edge(n, live, SORTED_BLOCK)
     np.testing.assert_array_equal(
-        _f32(got["d_rows"])[:live], _f32(want["d_rows"])[:live])
-    edge = min(n, (live // SORTED_BLOCK + 1) * SORTED_BLOCK)
-    assert not _f32(got["d_rows"])[live:edge].any()
+        _f32(got["xs"])[live:written], _f32(want["xs"])[live:written])
+    assert not _f32(got["d_rows"])[live:written].any()
     live_pair = np.asarray(inverse < live).reshape(-1, k)
     np.testing.assert_array_equal(
         _f32(got["d_weights"])[live_pair], _f32(want["d_weights"])[live_pair])
@@ -157,6 +167,40 @@ def test_movements_match_xla_over_several_lane_blocks(dtype):
     t, k, e, held = SHAPES[3]
     live = LIVE["over_an_edge"](t * k)
     _check(_routing(t, k, e, held, live), held, dtype, d=3 * D)
+
+
+GATHER_LIVE = {
+    "none": lambda n: 0,
+    "one": lambda n: 1,
+    "under_a_block": lambda n: SORTED_BLOCK - 1,
+    "a_block": lambda n: SORTED_BLOCK,
+    "all_but_one": lambda n: n - 1,
+    "all": lambda n: n,
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("count", list(GATHER_LIVE))
+def test_dispatch_gathers_to_the_end_of_the_block_that_holds_the_count(
+        count, dtype):
+    """Dispatch's forward alone: XLA's gather bit for bit below the count
+    and on to the end of the block that holds row ``live`` (real rows,
+    which the products mask by position); what is left keeps what the
+    buffer held, NaN in interpreter mode."""
+    t, k, e, held = SHAPES[1]
+    n = t * k
+    live = GATHER_LIVE[count](n)
+    order, inverse, sizes = moe.sort_pairs(_routing(t, k, e, held, live),
+                                           held)
+    assert int(jnp.sum(sizes)) == live
+    yt = jax.random.normal(jax.random.key(2), (t, D)).astype(dtype)
+    got = moe.dispatch_rows(yt, order, inverse, k, jnp.int32(live),
+                            interpret=True)
+    written = _edge(n, live, SORTED_BLOCK)
+    assert got.shape == (n, D) and got.dtype == dtype
+    np.testing.assert_array_equal(
+        _f32(got)[:written], _f32(yt[order // k])[:written])
+    assert np.isnan(_f32(got)[written:]).all()      # never written
 
 
 def test_one_expert_takes_every_live_pair():
@@ -210,12 +254,19 @@ def _poison_bwd(live, g):
 _poison_cotangent_tail.defvjp(_poison_fwd, _poison_bwd)
 
 
+def _poison_tail(x, live):
+    """Rows from ``live`` on are NaN: what `dispatch_rows` gathers past
+    the count (to its block's end) no product may use, and what it does
+    not write nothing may read."""
+    return jnp.where(_below(x.shape[0], live), x, jnp.nan)
+
+
 @jax.custom_vjp
 def _poison_both_tails(x, live):
     """Forward: rows from ``live`` on are NaN (what `combine_rows` must
     not read). Backward: the cotangent's rows past the block that holds
     row ``live`` are NaN (what the cotangent kernel does not write)."""
-    return jnp.where(_below(x.shape[0], live), x, jnp.nan)
+    return _poison_tail(x, live)
 
 
 def _poison_both_fwd(x, live):
@@ -240,7 +291,9 @@ def _expert_layer(params, yt, top_p, top_e, held, kernels):
                                  tail_unread=kernels, interpret=True)
     xs = moe.dispatch_rows(yt, order, inverse, k, live, interpret=kernels)
     if kernels:
-        xs = _poison_cotangent_tail(xs, live)
+        # the value's tail after the cotangent's: a `where`'s own
+        # backward would zero the NaN on its way to dispatch's
+        xs = _poison_tail(_poison_cotangent_tail(xs, live), live)
     hidden = jax.nn.silu(products(xs, params["w_gate"])) * products(
         xs, params["w_up"])
     rows = products(hidden, params["w_down"])
@@ -303,7 +356,7 @@ def test_gated_pass_matches_jnp_below_the_count(count, act):
                                     interpret=True), poison(gate), poison(up))
     want, want_vjp = jax.vjp(
         lambda g, u: moe.gated_rows(g, u, act), gate, up)
-    edge = min(n, (live // SORTED_BLOCK + 1) * SORTED_BLOCK)
+    edge = _edge(n, live, SORTED_BLOCK)
     for g, w in zip((got,) + vjp(poison(ct)), (want,) + want_vjp(ct)):
         np.testing.assert_allclose(g[:live], w[:live], rtol=2e-6, atol=2e-6)
         assert not _f32(g)[live:edge].any()
@@ -389,3 +442,45 @@ def test_unrounded_operands_agree_to_rounding():
 
     for got, want in zip(both(live, True), both(None, False)):
         np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
+
+
+def test_moe_mlp_with_a_tail_matches_the_path_that_names_no_count(
+        monkeypatch):
+    """`moe.moe_mlp` over a held share, the bounded forms (the gather
+    that stops at the count and is made again for the backward, the row
+    kernels, the products that walk no tile of the tail: what they do
+    not write reads NaN here) against XLA's ops over every
+    row: the loss and every gradient. Float32, ReGLU: the forms differ
+    in the order of a token's ``k`` and of a product's sum. Two of 32
+    experts held: a share at which the gather stops at the count too."""
+    monkeypatch.setattr(gm, "_MAX_BLOCK_M", SORTED_BLOCK)
+    cfg = moe.MoeConfig.tiny(dim=D, ffn_dim=D, n_experts=32,
+                             experts_per_token=4, experts_held=2,
+                             first_expert=3, expert_act="relu")
+    layers = moe.init_params(cfg, jax.random.key(12))["layers"]
+    lp = {name: layers[name][0]
+          for name in ("router", "w_gate", "w_up", "w_down")}
+    # weights large enough for outputs that a wrong row would move
+    lp = {name: w * (1 if name == "router" else 20) for name, w in lp.items()}
+    y = jax.random.normal(jax.random.key(13), (2, 48, D))
+
+    def loss(lp, y):
+        out, aux = moe.moe_mlp(cfg, lp, y)
+        return jnp.sum(out ** 2) + aux
+
+    grad = lambda: jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(lp, y)
+    want_loss, want = grad()
+    assert trace.gauges()["moe.dispatch_bounded"] == 0
+    monkeypatch.setattr(moe, "_experts", functools.partial(
+        moe._experts, interpret=True))
+    got_loss, got = grad()
+    gauges = trace.gauges()
+    assert gauges["moe.dispatch_bounded"] == gauges["moe.rows_kernel"] == 1
+    assert float(want_loss) > 100.0     # the aux term alone is 1
+    np.testing.assert_allclose(got_loss, want_loss, rtol=1e-5)
+    for got_leaf, want_leaf in zip(jax.tree.leaves(got),
+                                   jax.tree.leaves(want)):
+        assert np.isfinite(_f32(got_leaf)).all()
+        np.testing.assert_allclose(
+            got_leaf, want_leaf, rtol=1e-4,
+            atol=1e-5 * float(jnp.abs(want_leaf).max()))
