@@ -63,7 +63,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dlrover_tpu.models import llama, moe, stack
@@ -74,10 +73,10 @@ from dlrover_tpu.ops import (
     attention as attn_ops,
     dsa,
     embed_lookup,
-    flash_attention,
     rms_norm,
     rope_frequencies,
 )
+from dlrover_tpu.ops.norms import layer_norm
 from dlrover_tpu.parallel.mesh import BATCH_AXES, EP, FSDP, PP, SP, TP
 
 Params = Dict[str, Any]
@@ -539,26 +538,17 @@ def validate_for_mesh(cfg: Dots3Config, mesh: Mesh, batch: int = 0) -> None:
 # The indexer, the block, the forward
 # ---------------------------------------------------------------------------
 
-def _layer_norm(x, weight, bias, eps: float):
-    x32 = x.astype(jnp.float32)
-    mean = jnp.mean(x32, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(x32 - mean), axis=-1, keepdims=True)
-    out = (x32 - mean) * lax.rsqrt(var + eps)
-    return (out * weight.astype(jnp.float32)
-            + bias.astype(jnp.float32)).astype(x.dtype)
-
-
-def index_scores(cfg: Dots3Config, mesh, positions, inv_freq, lp, y, c_q,
-                 interpret: bool = False):
-    """The indexer's ``I (b, s, s)`` float32 from the layer's normed input
-    ``y`` and q latent ``c_q``, neither of which its gradient reaches."""
+def index_inputs(cfg: Dots3Config, positions, inv_freq, lp, y, c_q):
+    """The indexer's ``(q (b, s, hi, di), k (b, s, di), w (b, s, hi)
+    float32)`` from the layer's normed input ``y`` and q latent ``c_q``,
+    neither of which its gradient reaches."""
     dt = cfg.dtype
     b, s, _ = y.shape
     hi, di, dr = cfg.index_n_heads, cfg.index_head_dim, cfg.qk_rope_dim
     with trace.scope("dsa_index"):
         y, c_q = lax.stop_gradient(y), lax.stop_gradient(c_q)
         q = (c_q @ lp["idx_wq"].astype(dt)).reshape(b, s, hi, di)
-        k = _layer_norm(y @ lp["idx_wk"].astype(dt), lp["idx_k_norm"],
+        k = layer_norm(y @ lp["idx_wk"].astype(dt), lp["idx_k_norm"],
                         lp["idx_k_bias"], cfg.norm_eps)[:, :, None, :]
         # rotary on the first qk_rope_dim of each
         q = jnp.concatenate(
@@ -567,33 +557,23 @@ def index_scores(cfg: Dots3Config, mesh, positions, inv_freq, lp, y, c_q,
             [apply_rope(k[..., :dr], positions, inv_freq), k[..., dr:]], -1)
         w = (y @ lp["idx_ww"].astype(dt)).astype(jnp.float32) * (
             hi ** -0.5 * di ** -0.5)
-        return dsa.index_scores(q, k[:, :, 0], w, interpret=interpret,
-                                mesh=mesh)
+        return q, k[:, :, 0], w
 
 
 def selected_attention(cfg: Dots3Config, mesh, positions, inv_freq, lp, y,
                        interpret: bool = False):
     """``attend`` of a full layer for ``latent_attention``, and the dict
     it leaves ``l_i`` in (the layer's KL summed over its rows) with the
-    selection ``mask`` and the indexer's ``scores``."""
+    selection ``mask`` and the indexer's ``scores``: the indexer's
+    inputs are this family's, the sequence after them
+    `dsa.selected_attention`'s."""
     scale = cfg.latent(FULL).softmax_scale
     aux = {}
 
     def attend(q, k, v, c_q):
-        scores = index_scores(
-            cfg, mesh, positions, inv_freq, lp, y, c_q, interpret)
-        with trace.scope("dsa_select"):
-            mask = checkpoint_name(
-                dsa.selection_mask(lax.stop_gradient(scores), cfg.index_topk),
-                "dsa_select")
-        out, lse = flash_attention(
-            q, k, v, causal=True, mesh=mesh, scale=scale, select=mask,
-            interpret=interpret, return_lse=True)
-        with trace.scope("dsa_loss"):
-            probs = dsa.head_summed_probs(
-                q, k, lse, mask, scale, interpret=interpret, mesh=mesh)
-            aux["l_i"] = dsa.indexer_loss(scores, probs, mask)
-        aux["mask"], aux["scores"] = mask, scores
+        out, aux["l_i"], aux["mask"], aux["scores"] = dsa.selected_attention(
+            q, k, v, *index_inputs(cfg, positions, inv_freq, lp, y, c_q),
+            cfg.index_topk, scale, interpret=interpret, mesh=mesh)
         return out
 
     return attend, aux
@@ -692,7 +672,7 @@ def _block_fn(cfg: Dots3Config, mesh, kind: str, positions):
 
     return stack.recompute(
         functools.partial(block, cfg, mesh, kind, positions), cfg.remat,
-        attn_ops.KEPT + (("dsa_select", dsa.LOSS_GRAD) if kind == FULL
+        attn_ops.KEPT + ((dsa.SELECT, dsa.LOSS_GRAD) if kind == FULL
                          else ()), kept)
 
 

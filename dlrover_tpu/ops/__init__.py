@@ -18,7 +18,9 @@ from dlrover_tpu.ops.norms import rms_norm  # noqa: F401
 from dlrover_tpu.ops.ring_attention import ring_attention  # noqa: F401
 from dlrover_tpu.ops.ulysses import ulysses_attention  # noqa: F401
 from dlrover_tpu.ops.rotary import (  # noqa: F401
+    apply_mrope,
     apply_rope,
+    mrope_tables,
     rope_frequencies,
     yarn_frequencies,
     yarn_mscale,

@@ -69,6 +69,7 @@ from dlrover_tpu.ops.attention import (
     _dot,
     _first_q_block as _first_q,
     _last_k_block as _last_k,
+    flash_attention,
 )
 from dlrover_tpu.ops.kda import _over_batch_rows
 from dlrover_tpu.parallel.mesh import BATCH_AXES
@@ -427,12 +428,16 @@ def selection_mask(scores, topk: int):
 
 def _probs_xla(q, k, lse, mask, scale: float):
     b, s, h, d = q.shape
+    hkv = k.shape[2]
     block = 128 if s % 128 == 0 else s
 
     def one(args):
         qb, lb, mb = args                 # (b, block, h, d), (b, h, block)
-        logits = jnp.einsum("bqhd,bkhd->bhqk", qb, k,
-                            preferred_element_type=jnp.float32) * scale
+        # query head j reads key head j // (h // hkv)
+        logits = jnp.einsum(
+            "bqngd,bknd->bngqk", qb.reshape(b, block, hkv, h // hkv, d), k,
+            preferred_element_type=jnp.float32).reshape(b, h, block, s)
+        logits = logits * scale
         p = jnp.exp(logits - lb[..., None])
         return jnp.sum(jnp.where((mb != 0)[:, None], p, 0.0), axis=1)
 
@@ -461,11 +466,17 @@ def _probs_kernel(q_ref, k_ref, lse_ref, m_ref, o_ref, *, bq: int, bk: int,
 
 def _probs_pallas(q, k, lse, mask, scale: float, interpret: bool):
     b, s, h, d = q.shape
+    group = h // k.shape[2]
     bq, bk = _tiles("probs", s)
     n_q, n_k = s // bq, s // bk
 
     def k_index(qi, ki):
         return jnp.minimum(ki, _last_k(qi, bq, bk, n_k))
+
+    def k_head(hi):
+        # the heads are the innermost grid axis: a group's query heads
+        # follow one another and their key block is fetched once
+        return hi if group == 1 else hi // group
 
     return pl.pallas_call(
         functools.partial(_probs_kernel, bq=bq, bk=bk, scale=scale),
@@ -473,8 +484,8 @@ def _probs_pallas(q, k, lse, mask, scale: float, interpret: bool):
         in_specs=[
             pl.BlockSpec((1, 1, bq, d),
                          lambda bi, qi, ki, hi: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda bi, qi, ki, hi: (bi, hi, k_index(qi, ki), 0)),
+            pl.BlockSpec((1, 1, bk, d), lambda bi, qi, ki, hi: (
+                bi, k_head(hi), k_index(qi, ki), 0)),
             pl.BlockSpec((1, 1, bq, _LANES),
                          lambda bi, qi, ki, hi: (bi, hi, qi, 0)),
             pl.BlockSpec((1, bq, bk),
@@ -492,11 +503,17 @@ def _probs_pallas(q, k, lse, mask, scale: float, interpret: bool):
 
 def head_summed_probs(q, k, lse, mask, scale: float, *,
                       interpret: bool = False, mesh: Optional[Mesh] = None):
-    """``p[t, s] = sum_h exp(scale q[t, h] . k[s, h] - lse[h, t])`` where
-    ``mask[t, s]`` is set, 0 elsewhere: the main attention's
-    probabilities summed over its heads, ``(b, s, s)`` float32. ``q, k
-    (b, s, h, d)`` and ``lse (b, h, s)`` are the flash forward's
-    operands and result under the same mask. Not differentiated."""
+    """``p[t, s] = sum_h exp(scale q[t, h] . k[s, h // group] - lse[h,
+    t])`` where ``mask[t, s]`` is set, 0 elsewhere: the main attention's
+    probabilities summed over its heads, ``(b, s, s)`` float32. ``q (b,
+    s, h, d)``, ``k (b, s, hkv, d)`` with ``group = h / hkv`` query
+    heads on a key head (the key is read where it lies, never repeated)
+    and ``lse (b, h, s)`` are the flash forward's operands and result
+    under the same mask. Not differentiated."""
+    if q.shape[2] % k.shape[2]:
+        raise ValueError(
+            f"{q.shape[2]} query heads do not share {k.shape[2]} key heads "
+            "evenly")
     q, k, lse = (lax.stop_gradient(a) for a in (q, k, lse))
     scale = float(scale)
     if not (interpret or _on_tpu()):
@@ -506,6 +523,11 @@ def head_summed_probs(q, k, lse, mask, scale: float, *,
             q, k, lse, mask, scale, interpret),
         mesh, q, k, lse, mask)
 
+
+#: the name `selected_attention` gives the selection's mask: a checkpoint
+#: policy that keeps it spares the recomputed forward the threshold's
+#: counting passes
+SELECT = "dsa_select"
 
 #: the name `indexer_loss`'s forward gives ``d loss / d scores``: a
 #: checkpoint policy that keeps it spares the recomputed forward the
@@ -556,3 +578,36 @@ def _indexer_loss_bwd(grad, ct):
 
 
 indexer_loss.defvjp(_indexer_loss_fwd, _indexer_loss_bwd)
+
+
+# ---------------------------------------------------------------------------
+# The whole sequence, for every family with an indexer
+# ---------------------------------------------------------------------------
+
+def selected_attention(q, k, v, index_q, index_k, index_w, topk: int,
+                       scale: float, *, interpret: bool = False,
+                       mesh: Optional[Mesh] = None):
+    """Attention over the keys an indexer selects, and what the indexer
+    learns from it: `index_scores` of ``index_q (b, s, hi, di)``,
+    ``index_k (b, s, di)``, ``index_w (b, s, hi)`` (the family's own
+    projections, already turned, under its stop-gradients) ->
+    `selection_mask` at ``topk`` (named `SELECT`) ->
+    ``flash_attention(select=)`` of ``q (b, s, h, d)`` on ``k, v (b, s,
+    hkv, .)`` at ``scale`` -> `head_summed_probs` -> `indexer_loss`.
+    Returns ``(out (b, s, h, dv), the KL summed over the rows, mask,
+    scores)``. The one copy: latent attention (``models/dots3.py``) and
+    grouped heads (``models/keye_vl.py``) both call it."""
+    with trace.scope("dsa_index"):
+        scores = index_scores(index_q, index_k, index_w,
+                              interpret=interpret, mesh=mesh)
+    with trace.scope("dsa_select"):
+        mask = checkpoint_name(
+            selection_mask(lax.stop_gradient(scores), topk), SELECT)
+    out, lse = flash_attention(
+        q, k, v, causal=True, mesh=mesh, scale=scale, select=mask,
+        interpret=interpret, return_lse=True)
+    with trace.scope("dsa_loss"):
+        probs = head_summed_probs(
+            q, k, lse, mask, scale, interpret=interpret, mesh=mesh)
+        l_i = indexer_loss(scores, probs, mask)
+    return out, l_i, mask, scores

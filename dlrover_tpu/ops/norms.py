@@ -19,3 +19,13 @@ def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-5):
     var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
     y = x32 * lax.rsqrt(var + eps)
     return (y * weight.astype(jnp.float32)).astype(dtype)
+
+
+def layer_norm(x: jnp.ndarray, weight: jnp.ndarray, bias: jnp.ndarray,
+               eps: float = 1e-5):
+    x32 = x.astype(jnp.float32)
+    mean = jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x32 - mean), axis=-1, keepdims=True)
+    out = (x32 - mean) * lax.rsqrt(var + eps)
+    return (out * weight.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(x.dtype)

@@ -4,11 +4,18 @@ Frequencies are computed once per (seq_len, head_dim) and closed over by the
 jitted step — static shapes, no per-step host work. ``positions`` is passed
 explicitly so sequence-parallel shards (ring attention) can rotate with
 their *global* positions.
+
+A token of an interleaved document has **three** positions (time, height,
+width: the Qwen2-VL rule): `mrope_tables` forms the angles' cosines and
+sines once from the three rows and ``mrope_section`` (pair ``i`` turns
+by row ``c(i)``'s position), `turn` applies them, `apply_mrope` is the
+two in one call.
 """
 
 from __future__ import annotations
 
 import math
+from typing import Sequence, Tuple
 
 import jax.numpy as jnp
 
@@ -63,8 +70,49 @@ def apply_rope(
         turned = apply_rope(x[..., :rotary_dim], positions, inv_freq)
         return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     angles = positions[..., :, None].astype(jnp.float32) * inv_freq  # (...,s,d/2)
-    cos = jnp.cos(angles)[..., :, None, :]  # broadcast over heads
-    sin = jnp.sin(angles)[..., :, None, :]
+    return turn(x, jnp.cos(angles), jnp.sin(angles))
+
+
+def turn(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
+    """``x (..., seq, n_heads, d)`` turned by the angles whose ``cos``,
+    ``sin`` are ``(..., seq, d / 2)``: the first half of the channels
+    against the second, in float32."""
+    cos = cos[..., :, None, :]  # broadcast over heads
+    sin = sin[..., :, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def mrope_tables(
+    positions: jnp.ndarray,     # (3, ..., seq) int32: time, height, width
+    inv_freq: jnp.ndarray,      # (d / 2,)
+    sections: Sequence[int],    # pairs a row, in order; sums to d / 2
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``(cos, sin)``, each ``(..., seq, d / 2)`` float32, of the angles
+    ``positions[c(i)] * inv_freq[i]``: pair ``i`` turns by the row whose
+    section holds it (``mrope_section``, chunked: the first
+    ``sections[0]`` pairs by row 0, the next ``sections[1]`` by row 1,
+    the rest by row 2)."""
+    if len(sections) != positions.shape[0] or sum(sections) != (
+            inv_freq.shape[0]):
+        raise ValueError(
+            f"sections {tuple(sections)} do not deal the {inv_freq.shape[0]} "
+            f"pairs out to the {positions.shape[0]} rows of positions")
+    angles = positions[..., None].astype(jnp.float32) * inv_freq
+    edges = [sum(sections[:i]) for i in range(len(sections) + 1)]
+    angles = jnp.concatenate(
+        [angles[row, ..., lo:hi]
+         for row, (lo, hi) in enumerate(zip(edges, edges[1:]))], axis=-1)
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+def apply_mrope(
+    x: jnp.ndarray,          # (..., seq, n_heads, head_dim)
+    positions: jnp.ndarray,  # (3, ..., seq) int32
+    inv_freq: jnp.ndarray,   # (head_dim // 2,)
+    sections: Sequence[int],
+) -> jnp.ndarray:
+    """Rotary on the whole head with a position a row of ``positions``
+    (`mrope_tables`); three equal rows give `apply_rope`'s result."""
+    return turn(x, *mrope_tables(positions, inv_freq, sections))

@@ -227,6 +227,71 @@ def test_dsa_probs_kernel_compiles_at_the_cell_shape(
     assert not re.search(r"32,8192,8192\]|8192,8192,32\]", hlo)
 
 
+# keye-vl-ep8-1chip-steady (PR 54): b1, s16384. 32 query heads on 4 key
+# heads of 128 (group 8) read the selection tile by tile in all three
+# `_sel` kernels; the indexer's 16 heads of 64 (half a lane tile) against
+# one key of 64; `dsa_probs` reads the key head where it lies. No array
+# a head over (16384, 16384) and no key repeated eight times is in a
+# program.
+def test_flash_keye_vl_cell_compiles_over_the_selection_at_group_8(
+        one_chip, kernels_are_the_path):
+    q = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, 16384, 4, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((1, 16384, 16384), jnp.int8,
+                                sharding=one_chip)
+
+    def loss(q, k, v, mask):
+        return attention.flash_attention(q, k, v, select=mask).astype(
+            jnp.float32).sum()
+
+    hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, k, k, mask)
+    assert hlo.count("tpu_custom_call") == 3
+    for name in ("attention_fwd", "attention_bwd_dq", "attention_bwd_dkv"):
+        assert re.search(rf"%{name}_sel(\.\d+)? = ", hlo), name
+    assert "s8[1,16384,16384]" in hlo
+    assert not re.search(r"\[1,(4|32),16384,16384\]", hlo)
+
+
+def test_dsa_index_kernels_compile_at_sixteen_heads_of_64(
+        one_chip, kernels_are_the_path):
+    q = jax.ShapeDtypeStruct((1, 16384, 16, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, 16384, 64), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((1, 16384, 16), jnp.float32, sharding=one_chip)
+
+    def loss(q, k, w):
+        return jnp.sum(dsa.index_scores(q, k, w) ** 2)
+
+    hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, k, w)
+    for name in ("dsa_index_fwd", "dsa_index_bwd_dq", "dsa_index_bwd_dk"):
+        assert sum("custom-call(" in line and name in line.split(" = ")[0]
+                   for line in hlo.splitlines()) == 1, name
+    assert not re.search(
+        r"16384,16384,16\]|16384,16,16384\]|16,16384,16384\]", hlo)
+
+
+def test_dsa_probs_kernel_reads_grouped_keys_where_they_lie(
+        one_chip, kernels_are_the_path):
+    q = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, 16384, 4, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((1, 32, 16384), jnp.float32,
+                               sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((1, 16384, 16384), jnp.int8,
+                                sharding=one_chip)
+    hlo = _compile(
+        lambda q, k, lse, mask: dsa.head_summed_probs(
+            q, k, lse, mask, 128 ** -0.5), q, k, lse, mask)
+    assert hlo.count("tpu_custom_call") == 1 and "dsa_probs" in hlo
+    # the key goes in at its 4 heads: nothing of it at 32
+    assert not re.search(r"bf16\[1,(32,16384|16384,32),128\][^\n]*broadcast",
+                         hlo)
+    assert not re.search(r"32,16384,16384\]|16384,16384,32\]", hlo)
+
+
 # minicpm-sala-d4-1chip-steady (PR 48): b1, s16384. The minicpm4 layer's
 # 32 query heads on 2 key heads of 128 (group 16) read a choice of blocks a
 # key-value head, (1, 2, 16384, 256) int8, in all three kernels: forward

@@ -291,3 +291,86 @@ def test_the_kept_gradient_carries_its_name():
     text = str(jax.make_jaxpr(jax.grad(dsa.indexer_loss))(
         scores, probs, mask))
     assert text.count(f"name[name={dsa.LOSS_GRAD}]") == 1
+
+
+# -- grouped heads: 32 query heads on 4 key heads, 16 index heads of 64 ---------
+
+def _grouped_attention(h=8, hkv=2, d=24, seed=6):
+    kq, kk, kv, ks = jax.random.split(jax.random.key(seed), 4)
+    q = jax.random.normal(kq, (2, S, h, d))
+    k = jax.random.normal(kk, (2, S, hkv, d))
+    v = jax.random.normal(kv, (2, S, hkv, 16))
+    mask = dsa.selection_mask(jax.random.normal(ks, (2, S, S)), 40)
+    _, lse = mha_reference_with_lse(q, k, v, select=mask)
+    return q, k, lse, mask, d ** -0.5
+
+
+@pytest.mark.parametrize("interpret", [False, True])
+def test_grouped_head_summed_probs_are_the_repeated_keys(
+        interpret, small_tiles):
+    """Query head ``j`` reads key head ``j // group`` where it lies: the
+    same numbers as the call with ``k`` repeated a group's times."""
+    q, k, lse, mask, scale = _grouped_attention()
+    got = dsa.head_summed_probs(q, k, lse, mask, scale, interpret=interpret)
+    want = dsa.head_summed_probs(
+        q, jnp.repeat(k, q.shape[2] // k.shape[2], axis=2), lse, mask, scale,
+        interpret=interpret)
+    np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-5)
+    np.testing.assert_allclose(got.sum(-1), q.shape[2], rtol=1e-5)
+
+
+def test_heads_that_do_not_share_the_key_heads_evenly_are_refused():
+    q, k, lse, mask, scale = _grouped_attention()
+    with pytest.raises(ValueError, match="evenly"):
+        dsa.head_summed_probs(q[:, :, :7], k, lse[:, :7], mask, scale)
+
+
+def test_index_scores_at_sixteen_heads_of_64(small_tiles):
+    """The published indexer's shape a head (half a lane tile, half the
+    MXU's depth): the kernels against the XLA form, values and the three
+    gradients."""
+    q, k, w = _operands(b=1, h=16, d=64, seed=2)
+    g = jax.random.normal(jax.random.key(8), (1, S, S)) * _causal()
+
+    def both(interpret):
+        return jax.value_and_grad(
+            lambda q, k, w: jnp.sum(dsa.index_scores(
+                q, k, w, interpret=interpret) * g), argnums=(0, 1, 2))(
+                    q, k, w)
+
+    (got, got_grads), (want, want_grads) = both(True), both(False)
+    np.testing.assert_allclose(got, want, rtol=2e-5)
+    np.testing.assert_allclose(
+        jnp.where(_causal(), dsa.index_scores(q, k, w, interpret=True), 0.0),
+        jnp.where(_causal(), _plain_scores(q, k, w), 0.0),
+        atol=2e-3, rtol=2e-5)
+    for a, b in zip(got_grads, want_grads):
+        np.testing.assert_allclose(a, b, atol=5e-3, rtol=2e-4)
+
+
+@pytest.mark.parametrize("interpret", [False, True])
+def test_selected_attention_is_its_pieces_in_order(interpret, small_tiles):
+    """The one function both families call: index scores -> mask ->
+    flash over the selection -> probabilities -> KL."""
+    from dlrover_tpu.ops.attention import flash_attention
+
+    q, k, _, _, scale = _grouped_attention()
+    v = jax.random.normal(jax.random.key(3), k.shape)
+    iq, ik, iw = _operands(h=4, d=32, seed=4)
+    out, l_i, mask, scores = dsa.selected_attention(
+        q, k, v, iq, ik, iw, 40, scale, interpret=interpret)
+    want_scores = dsa.index_scores(iq, ik, iw, interpret=interpret)
+    want_mask = dsa.selection_mask(want_scores, 40)
+    want_out, lse = flash_attention(
+        q, k, v, scale=scale, select=want_mask, interpret=interpret,
+        return_lse=True)
+    np.testing.assert_array_equal(mask, want_mask)
+    np.testing.assert_allclose(out, want_out, atol=1e-6)
+    np.testing.assert_allclose(l_i, dsa.indexer_loss(
+        want_scores, dsa.head_summed_probs(
+            q, k, lse, want_mask, scale, interpret=interpret), want_mask),
+        rtol=1e-6)
+    np.testing.assert_array_equal(scores, want_scores)
+    text = str(jax.make_jaxpr(lambda *a: dsa.selected_attention(
+        *a, 40, scale)[0])(q, k, v, iq, ik, iw))
+    assert text.count(f"name[name={dsa.SELECT}]") == 1

@@ -13,7 +13,7 @@ import pytest
 from jax.ad_checkpoint import checkpoint_name
 
 from dlrover_tpu.models import (
-    dots3, granite_hybrid, kimi_linear, minicpm_sala, qwen3_next,
+    dots3, granite_hybrid, keye_vl, kimi_linear, minicpm_sala, qwen3_next,
     smallthinker, stack)
 from dlrover_tpu.models.stack import Part
 
@@ -77,8 +77,9 @@ def test_runs_are_stacked_parts_of_one_position():
      minicpm_sala.MiniCPMSalaConfig().kinds),
     (granite_hybrid.GraniteHybridConfig().layout,
      granite_hybrid.GraniteHybridConfig().kinds),
+    (keye_vl.KeyeVLConfig().layout, keye_vl.KeyeVLConfig().pattern_string),
 ], ids=["smallthinker", "dots3", "kimi_linear", "head_and_tail",
-        "qwen3_next", "minicpm_sala", "granite_hybrid"])
+        "qwen3_next", "minicpm_sala", "granite_hybrid", "keye_vl"])
 def test_locate_finds_every_layer_once_and_in_order(parts, kinds):
     seen = [stack.locate(parts, layer) for layer in range(len(kinds))]
     assert len(set(seen)) == len(kinds) and seen == sorted(
@@ -256,7 +257,7 @@ def test_one_file_under_models_calls_jax_checkpoint():
     (r"attention\.KEPT|attn_ops\.KEPT", {
         "dots3.py", "qwen3_next.py", "xing4.py", "kimi_linear.py",
         "smallthinker.py", "minicpm_sala.py", "llama.py",
-        "granite_hybrid.py"}),
+        "granite_hybrid.py", "keye_vl.py"}),
     (r"kda\.KEPT", {"kimi_linear.py"}),
     (r"lightning\.KEPT", {"minicpm_sala.py"}),
     (r"ssd\.KEPT", {"granite_hybrid.py"}),
@@ -265,7 +266,7 @@ def test_families_keep_a_forward_kernels_residuals_at_their_call_site(
         names, keepers):
     """The keep is each family's own choice where it calls `recompute`
     (its cell's planned peak has the room), not a rule of `stack.py` or
-    of the kernels: the flash forward's pair in eight files (Llama's
+    of the kernels: the flash forward's pair in nine files (Llama's
     `_maybe_remat`, which `moe.py`'s layer goes through, keeps q, k, v
     beside it), the delta rule's in kimi's alone (qwen3next's step has
     not the room), the lightning rule's in minicpm_sala's, the
@@ -278,14 +279,15 @@ def test_families_keep_a_forward_kernels_residuals_at_their_call_site(
             if re.search(r"\bKEPT\b", text)} == {
         "dots3.py", "qwen3_next.py", "xing4.py", "kimi_linear.py",
         "smallthinker.py", "minicpm_sala.py", "llama.py",
-        "granite_hybrid.py"}
+        "granite_hybrid.py", "keye_vl.py"}
     assert "KEPT" not in sources["stack.py"]
 
 
 def test_no_family_walks_its_layers_or_shifts_its_targets_itself():
     sources = _sources()
     for name in ("kimi_linear.py", "smallthinker.py", "dots3.py",
-                 "qwen3_next.py", "minicpm_sala.py", "granite_hybrid.py"):
+                 "qwen3_next.py", "minicpm_sala.py", "granite_hybrid.py",
+                 "keye_vl.py"):
         assert "lax.scan(" not in sources[name], name
     assert {name for name, text in sources.items()
             if "_shift_targets" in text} == {"llama.py"}

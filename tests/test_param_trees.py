@@ -20,7 +20,8 @@ import numpy as np
 import pytest
 
 from dlrover_tpu.models import (
-    dots3, granite_hybrid, kimi_linear, llama, minicpm_sala, moe, qwen3_next,
+    dots3, granite_hybrid, keye_vl, kimi_linear, llama, minicpm_sala, moe,
+    qwen3_next,
     smallthinker, vit, xing4)
 
 GOLDEN = os.path.join(os.path.dirname(__file__), "golden", "param_trees.json")
@@ -41,6 +42,7 @@ FAMILIES = {
     "minicpm_sala": (minicpm_sala, minicpm_sala.MiniCPMSalaConfig.tiny()),
     "granite_hybrid": (granite_hybrid,
                        granite_hybrid.GraniteHybridConfig.tiny()),
+    "keye_vl": (keye_vl, keye_vl.KeyeVLConfig.tiny(experts_held=4)),
 }
 
 
