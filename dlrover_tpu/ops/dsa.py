@@ -25,11 +25,21 @@ probabilities summed over heads. What this file computes:
   float's bits** (32 counting passes over an order-preserving uint32
   key build the threshold bit by bit, ``log2 s`` more find the position
   of the last tie kept). Ties are broken to the lower ``s``, as a
-  stable descending sort and ``lax.top_k`` break them. XLA ops: every
-  pass is one fused compare-and-count over ``(s, s)``; ``lax.top_k`` at
-  k = 2048 and a full sort are the slow paths on a TPU.
+  stable descending sort and ``lax.top_k`` break them. XLA ops, every
+  pass one fused compare-and-count over ``(s, s)`` in HBM: the
+  selection's form off the TPU, the kernel's oracle, and what
+  ``ops/blocksel.py pick_blocks`` calls on its ``s / block`` blocks.
+  ``lax.top_k`` at k = 2048 and a full sort are the slow paths on a TPU.
 - `selection_mask`: the int8 ``(b, s, s)`` mask the flash kernels read
-  (``ops/attention.py`` ``select=``), causal included.
+  (``ops/attention.py`` ``select=``), causal included. On the TPU one
+  Pallas kernel, ``dsa_select``: a grid step holds a block of whole
+  query rows (`_select_rows`: 128 at 16384 positions, 256 at 8192) as
+  int32 keys in VMEM, runs the same passes there over the key tiles at
+  or under the block's diagonal, and writes the rows' mask; HBM sees
+  the scores once and the mask once, and no ``(s, s)`` array of bits
+  exists. The ``cut`` passes run only in a block one of whose rows
+  holds more keys at its threshold than it needs. Off the TPU
+  `select_threshold`'s passes as XLA ops; the same mask bit for bit.
 - `head_summed_probs`: ``p[t, s] = sum_h exp(scale q_h[t] . k_h[s] -
   lse[t, h])`` over the selected pairs, from q, k, the flash forward's
   ``lse`` and the mask; a Pallas kernel on the TPU (``dsa_probs``: heads
@@ -39,9 +49,10 @@ probabilities summed over heads. What this file computes:
   its forward also forms the gradient with respect to the scores, the
   one array its backward reads (a ``custom_vjp``).
 
-Off the TPU the XLA forms, blocked over query rows, which are the
-kernels' oracles; ``interpret=True`` runs the kernels on the CPU. No
-``(s, s, heads)`` array exists in HBM on the TPU path. A gather-by-index
+Off the TPU the XLA forms (the scores and the probabilities blocked
+over query rows), which are the kernels' oracles; ``interpret=True``
+runs the kernels on the CPU. No ``(s, s, heads)`` array exists in HBM
+on the TPU path. A gather-by-index
 form (a query's ``topk`` keys gathered into a dense block) is not here:
 at 8192 positions the masked causal walk does less work a pair.
 """
@@ -65,6 +76,7 @@ from dlrover_tpu.ops.attention import (
     _NN,
     _NT,
     _STAT_LANES,
+    _VMEM_BUDGET,
     _VMEM_LIMIT,
     _dot,
     _first_q_block as _first_q,
@@ -406,20 +418,223 @@ def select_threshold(scores, topk: int):
     return _threshold(_causal_bits(scores), topk)
 
 
-def selection_mask(scores, topk: int):
-    """``(b, s, s)`` int8, 1 where query ``t`` attends to key ``s``: the
-    ``topk`` causal keys of largest ``scores[t, s]`` (all of them while
-    ``t < topk``), ties to the lower ``s``. What ``flash_attention(
-    select=)`` reads."""
+def _xla_selection_mask(scores, topk: int):
+    """`selection_mask` from `select_threshold`'s passes, XLA ops."""
     s = scores.shape[-1]
-    causal = _causal(s)
-    if topk >= s:
-        return jnp.broadcast_to(causal.astype(jnp.int8), scores.shape)
     bits = _causal_bits(scores)
     tau, cut = (a[..., None] for a in _threshold(bits, topk))
     pos = jnp.arange(s, dtype=jnp.int32)
     chosen = (bits > tau) | ((bits == tau) & (pos <= cut))
-    return (chosen & causal).astype(jnp.int8)
+    return (chosen & _causal(s)).astype(jnp.int8)
+
+
+#: What `_select_rows` lets a grid step of `dsa_select` fill: a block of
+#: rows' float32 scores and int8 mask, both double-buffered, and the key
+#: copy (`choose_tiles`' margin under `_VMEM_LIMIT`)
+_SELECT_BUDGET = _VMEM_BUDGET
+
+#: rows whose counts a run of passes carries in registers, and the
+#: columns one trip of a counting loop covers. Measured on the v5e at
+#: 16384 positions (PERF.md section 6, PR 55): 4.76 ms a layer here, 5.6
+#: at (64, 512), 7.5 at (32, 512)
+_SELECT_SUB = 128
+_SELECT_TRIP = 1024
+
+_INT_MIN, _INT_MAX = -2**31, 2**31 - 1
+
+
+def _select_rows(s: int) -> Optional[int]:
+    """Query rows a grid step of `dsa_select` holds (whole rows: a count
+    is over a row): the largest multiple of 32, the int8 mask's sublane
+    tiling, that divides ``s`` and fits `_SELECT_BUDGET`; a sequence 128
+    does not divide goes as one block. ``None`` where nothing fits: the
+    caller has the XLA form."""
+    # a key of a row: the float32 block and the int8 block twice (the
+    # pipeline's two buffers) and the int32 copy
+    per_row = s * (2 * 4 + 4 + 2 * 1)
+    if s % 128:
+        return s if s * per_row <= _SELECT_BUDGET else None
+    cap = min(s, _SELECT_BUDGET // per_row)
+    return next(
+        (r for r in range(cap // 32 * 32, 0, -32) if s % r == 0), None)
+
+
+def _select_kernel(s_ref, o_ref, key_ref, tau_ref, cut_ref, *,
+                   rows: int, sub: int, width: int, per_trip: int,
+                   topk: int):
+    """One block of ``rows`` query rows, whole: `_threshold`'s passes on
+    a copy of the rows' keys in VMEM, then the rows' mask. The key is
+    `_ordered_bits` with its top bit turned, an int32 whose signed order
+    is the floats' (``tau`` here is `select_threshold`'s ``^ 2**31``);
+    what the causal mask hides is ``_INT_MIN``, below every candidate.
+    A row's scalar is held on every lane of a ``width``-wide tile."""
+    i = pl.program_id(1)
+    s = s_ref.shape[-1]
+    n_tiles = s // width
+    row0 = i * rows
+    # column tiles that hold a causal entry, in whole trips
+    trips = _last_k(i, rows, width, n_tiles) // per_trip + 1
+    live = trips * per_trip
+
+    def cols(c):
+        return pl.ds(pl.multiple_of(c * width, width), width)
+
+    def pos(c, n):
+        return c * width + lax.broadcasted_iota(jnp.int32, (n, width), 1)
+
+    def row(r0, n):
+        return row0 + r0 + lax.broadcasted_iota(jnp.int32, (n, width), 0)
+
+    def write(chosen):
+        def tile(c, carry):
+            o_ref[0, :, cols(c)] = (
+                chosen(c) & (pos(c, rows) <= row(0, rows))).astype(jnp.int8)
+            return carry
+
+        def above(c, carry):
+            o_ref[0, :, cols(c)] = jnp.zeros((rows, width), jnp.int8)
+            return carry
+
+        lax.fori_loop(0, live, tile, 0)
+        lax.fori_loop(live, n_tiles, above, 0)
+
+    # every row of the block holds no more than topk causal keys
+    all_kept = row0 + rows <= topk
+
+    @pl.when(all_kept)
+    def _causal_alone():
+        write(lambda c: jnp.full((rows, width), True))
+
+    def count(rs, n_trips, seen):
+        """Per row of the sub-block ``rs``, how many of its keys in the
+        first ``n_trips`` trips ``seen(keys, tile)`` holds for."""
+        def trip(t, acc):
+            for u in range(per_trip):
+                c = t * per_trip + u
+                acc = acc + jnp.where(seen(key_ref[rs, cols(c)], c), 1, 0)
+            return acc
+
+        acc = lax.fori_loop(0, n_trips, trip,
+                            jnp.zeros((sub, width), jnp.int32))
+        return jnp.broadcast_to(
+            jnp.sum(acc, axis=1, keepdims=True), (sub, width))
+
+    def sub_block(j):
+        r0 = pl.multiple_of(j * sub, sub)
+        # the trips that hold a causal entry of the sub-block's rows
+        return r0, pl.ds(r0, sub), (
+            (row0 + r0 + sub - 1) // (width * per_trip) + 1)
+
+    @pl.when(jnp.logical_not(all_kept))
+    def _threshold_and_mask():
+        def to_keys(c, carry):
+            bits = lax.bitcast_convert_type(s_ref[0, :, cols(c)], jnp.int32)
+            key = jnp.where(bits < 0, bits ^ jnp.int32(_INT_MAX), bits)
+            key_ref[:, cols(c)] = jnp.where(
+                pos(c, rows) <= row(0, rows), key, jnp.int32(_INT_MIN))
+            return carry
+
+        lax.fori_loop(0, live, to_keys, 0)
+
+        def find_tau(j, most):
+            r0, rs, n_trips = sub_block(j)
+
+            def grow(p, carry):
+                tau, held = carry
+                # p = 0 turns the sign: _INT_MIN -> 0
+                cand = tau ^ (jnp.int32(1) << (31 - p))
+                n = count(rs, n_trips, lambda key, c: key >= cand)
+                take = n >= topk
+                return jnp.where(take, cand, tau), jnp.where(take, n, held)
+
+            # held: a row's causal keys at or above its tau
+            tau, held = lax.fori_loop(0, 32, grow, (
+                jnp.full((sub, width), _INT_MIN, jnp.int32),
+                row(r0, sub) + 1))
+            tau_ref[rs] = tau
+            return jnp.maximum(most, jnp.max(held))
+
+        most = lax.fori_loop(0, rows // sub, find_tau, jnp.int32(0))
+
+        # where no row holds more keys at its tau than it needs every
+        # causal tie is kept, and no cut is looked for
+        cut_ref[...] = jnp.full_like(cut_ref, _INT_MAX)
+
+        @pl.when(most > topk)
+        def _find_cut():
+            n_bits = max(s - 1, 1).bit_length()
+
+            def one(j, carry):
+                r0, rs, n_trips = sub_block(j)
+                tau = tau_ref[rs]
+                need = topk - count(rs, n_trips, lambda key, c: key > tau)
+
+                def reach(p, cut):
+                    cand = cut | (jnp.int32(1) << (n_bits - 1 - p))
+                    below = count(rs, n_trips, lambda key, c: (
+                        (key == tau) & (pos(c, sub) < cand)))
+                    return jnp.where(below < need, cand, cut)
+
+                cut_ref[rs] = lax.fori_loop(
+                    0, n_bits, reach, jnp.zeros((sub, width), jnp.int32))
+                return carry
+
+            lax.fori_loop(0, rows // sub, one, 0)
+
+        def chosen(c):
+            key, tau = key_ref[:, cols(c)], tau_ref[...]
+            return (key > tau) | (
+                (key == tau) & (pos(c, rows) <= cut_ref[...]))
+
+        write(chosen)
+
+
+def _select_pallas(scores, topk: int, rows: int, interpret: bool):
+    b, s, _ = scores.shape
+    width = 128 if s % 128 == 0 else s
+    sub = next((n for n in range(_SELECT_SUB, 0, -32) if rows % n == 0), rows)
+    n_tiles = s // width
+    per_trip = next(n for n in range(max(_SELECT_TRIP // width, 1), 0, -1)
+                    if n_tiles % n == 0)
+
+    def rows_of(bi, i):
+        return bi, i, 0
+
+    return pl.pallas_call(
+        functools.partial(_select_kernel, rows=rows, sub=sub, width=width,
+                          per_trip=per_trip, topk=topk),
+        grid=(b, s // rows),
+        in_specs=[pl.BlockSpec((1, rows, s), rows_of)],
+        out_specs=pl.BlockSpec((1, rows, s), rows_of),
+        out_shape=jax.ShapeDtypeStruct((b, s, s), jnp.int8),
+        scratch_shapes=[pltpu.VMEM((rows, s), jnp.int32)] + [
+            pltpu.VMEM((rows, width), jnp.int32)] * 2,
+        compiler_params=_params("parallel", "parallel"),
+        interpret=interpret,
+        name="dsa_select",
+    )(scores)
+
+
+def selection_mask(scores, topk: int, *, interpret: bool = False,
+                   mesh: Optional[Mesh] = None):
+    """``(b, s, s)`` int8, 1 where query ``t`` attends to key ``s``: the
+    ``topk`` causal keys of largest ``scores[t, s]`` (all of them while
+    ``t < topk``), ties to the lower ``s``. What ``flash_attention(
+    select=)`` reads. On the TPU (or with ``interpret``) the kernel
+    `dsa_select`, which reads the scores once; off it `select_threshold`'s
+    passes as XLA ops, the kernel's oracle. The same mask bit for bit.
+    Entries of ``scores`` above the diagonal are never counted."""
+    s = scores.shape[-1]
+    rows = _select_rows(s)
+    kernels = (interpret or _on_tpu()) and topk < s and rows is not None
+    trace.gauge("dsa.select_kernel", 1 if kernels else 0)
+    if topk >= s:
+        return jnp.broadcast_to(_causal(s).astype(jnp.int8), scores.shape)
+    if not kernels:
+        return _xla_selection_mask(scores, topk)
+    return _over_batch(
+        lambda x: _select_pallas(x, topk, rows, interpret),
+        mesh, scores.astype(jnp.float32))
 
 
 # ---------------------------------------------------------------------------
@@ -602,7 +817,8 @@ def selected_attention(q, k, v, index_q, index_k, index_w, topk: int,
                               interpret=interpret, mesh=mesh)
     with trace.scope("dsa_select"):
         mask = checkpoint_name(
-            selection_mask(lax.stop_gradient(scores), topk), SELECT)
+            selection_mask(lax.stop_gradient(scores), topk,
+                           interpret=interpret, mesh=mesh), SELECT)
     out, lse = flash_attention(
         q, k, v, causal=True, mesh=mesh, scale=scale, select=mask,
         interpret=interpret, return_lse=True)

@@ -227,6 +227,21 @@ def test_dsa_probs_kernel_compiles_at_the_cell_shape(
     assert not re.search(r"32,8192,8192\]|8192,8192,32\]", hlo)
 
 
+# The threshold of both cells that select (PR 55): one kernel whose grid
+# step holds 256 whole rows at 8192 positions and 128 at 16384 (8 MiB of
+# scores a block, twice for the pipeline, and the int32 keys), the mask
+# its one result; no array of ordered bits and no XLA pass over (s, s).
+@pytest.mark.parametrize("s,rows", [(8192, 256), (16384, 128)])
+def test_dsa_select_kernel_compiles_at_the_cells_shapes(
+        one_chip, kernels_are_the_path, s, rows):
+    scores = jax.ShapeDtypeStruct((1, s, s), jnp.float32, sharding=one_chip)
+    assert dsa._select_rows(s) == rows
+    hlo = _compile(lambda x: dsa.selection_mask(x, 2048), scores)
+    assert hlo.count("tpu_custom_call") == 1
+    assert re.search(r"%dsa_select(\.\d+)? = ", hlo)
+    assert f"u32[1,{s},{s}]" not in hlo and "while(" not in hlo
+
+
 # keye-vl-ep8-1chip-steady (PR 54): b1, s16384. 32 query heads on 4 key
 # heads of 128 (group 8) read the selection tile by tile in all three
 # `_sel` kernels; the indexer's 16 heads of 64 (half a lane tile) against

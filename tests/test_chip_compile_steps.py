@@ -29,7 +29,8 @@ from tests.chip_compile import (  # noqa: F401  (fixtures by import)
 # `dsa_probs`: one call a full layer a step where the parent made two.
 # Since PR 46 every block also keeps its flash forward's output and lse
 # (64 + 1 MiB a full layer, 32 + 0.5 a window layer), so a forward kernel
-# runs once a layer a step where the parent ran it twice. That step
+# runs once a layer a step where the parent ran it twice. Since PR 55 the
+# selection's threshold is one kernel a full layer (`dsa_select`). That step
 # peaks at 15,454,193,152 bytes (14.393 GiB; 15,256,935,424 before the
 # five pairs were kept); some slack may be added to it, no more.
 DOTS3_STEP_PEAK = 15454193152
@@ -67,11 +68,14 @@ def test_dots3_step_keeps_the_loss_gradient_in_the_memory_it_has(
     assert (fam.cfg.layer_kinds.count("F"),
             fam.cfg.layer_kinds.count("S")) == (2, 3)
     for name, calls in (("dsa_index_fwd", 2), ("dsa_probs", 2),
+                        ("dsa_select", 2),
                         ("dsa_index_bwd_dq", 2), ("dsa_index_bwd_dk", 2),
                         ("attention_fwd_sel", 2), ("attention_fwd_swa", 3),
                         ("attention_bwd_dq_sel", 2),
                         ("attention_bwd_dq_swa", 3)):
         assert _kernel_calls(hlo, name) == calls, name
+    # the threshold is that kernel (PR 55): no array of ordered bits
+    assert "u32[1,8192,8192]" not in hlo
     assert trace.gauges()["dsa.loss_grad_kept"] == 1
     assert trace.gauges()["attn.out_kept"] == 1
     # the backward scales the kept array once a layer: the transpose the

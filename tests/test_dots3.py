@@ -351,6 +351,32 @@ def test_a_full_layer_through_the_kernels_in_interpret_mode(built):
     _assert_grads_agree(got_grads, want_grads, tol=1e-3)
 
 
+def test_the_select_kernel_changes_nothing_of_a_full_layer(
+        built, monkeypatch):
+    """The same layer through the same kernels, the selection by
+    `dsa_select` and by the XLA passes: one mask, so the same outputs and
+    the same gradients."""
+    fam, params, tokens = built
+    cfg = fam.cfg
+    lp = dots3.layer_params(cfg, params, 1)
+    y = jax.random.normal(jax.random.key(2), (2, 128, cfg.dim))
+    positions = jnp.broadcast_to(jnp.arange(128, dtype=jnp.int32), (2, 128))
+
+    def run():
+        return jax.value_and_grad(lambda lp: sum(
+            jnp.sum(x) for x in dots3.attention(
+                cfg, None, "F", positions, lp, y, interpret=True)))(lp)
+
+    got, got_grads = run()
+    assert trace.gauges()["dsa.select_kernel"] == 1
+    monkeypatch.setattr(dsa, "_select_rows", lambda s: None)
+    want, want_grads = run()
+    assert trace.gauges()["dsa.select_kernel"] == 0
+    np.testing.assert_array_equal(got, want)
+    for name in want_grads:
+        np.testing.assert_array_equal(got_grads[name], want_grads[name])
+
+
 def test_live_rows_count_the_pairs_that_chose_a_held_expert(built, config):
     fam, params, tokens = built
     rows = np.asarray(fam.live_rows(params, tokens))
@@ -398,8 +424,9 @@ def _kernel_calls(jaxpr, found=None):
 
 
 @pytest.mark.parametrize("kind,layer,want", [
-    ("F", 1, {"dsa_index_fwd": 1, "dsa_probs": 1, "dsa_index_bwd_dq": 1,
-              "dsa_index_bwd_dk": 1, "attention_fwd_sel": 1,
+    ("F", 1, {"dsa_index_fwd": 1, "dsa_select": 1, "dsa_probs": 1,
+              "dsa_index_bwd_dq": 1, "dsa_index_bwd_dk": 1,
+              "attention_fwd_sel": 1,
               "attention_bwd_dq_sel": 1, "attention_bwd_dkv_sel": 1}),
     ("S", 2, {"attention_fwd_swa": 1, "attention_bwd_dq_swa": 1,
               "attention_bwd_dkv_swa": 1})])
@@ -411,7 +438,8 @@ def test_a_recomputed_full_block_runs_the_loss_and_the_scores_once(
     ``dsa_index_fwd`` 2 and ``dsa_probs`` 2 times: the KL's autodiff read
     ``log_softmax(scores)`` and ``probs`` again. Until PR 46 the flash
     forward was called twice in either kind of block: its output and
-    ``lse`` are the backward's, and the block keeps them now."""
+    ``lse`` are the backward's, and the block keeps them now. The
+    threshold's kernel (PR 55) runs once: the block keeps its mask."""
     fam, params, _ = built
     cfg = dataclasses.replace(fam.cfg, remat=True)
     monkeypatch.setattr(dsa, "_on_tpu", lambda: True)

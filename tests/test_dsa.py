@@ -117,16 +117,128 @@ SCORES = {
 }
 
 
+@pytest.fixture
+def small_select(monkeypatch):
+    """`dsa_select` at 256 positions as it stands at 16384: four row
+    blocks of 64, two sub-blocks a block, two trips a row of keys."""
+    monkeypatch.setattr(dsa, "_SELECT_BUDGET", 64 * S * 14)
+    monkeypatch.setattr(dsa, "_SELECT_SUB", 32)
+    monkeypatch.setattr(dsa, "_SELECT_TRIP", 128)
+
+
+@pytest.mark.parametrize("kernel", [False, True])
 @pytest.mark.parametrize("topk", [1, 32, 100, 255])
 @pytest.mark.parametrize("kind", sorted(SCORES))
-def test_selection_is_lax_top_k_ties_to_the_lower_position(kind, topk):
+def test_selection_is_lax_top_k_ties_to_the_lower_position(
+        kind, topk, kernel, small_select):
     scores = SCORES[kind](jax.random.key(2))
-    got = np.asarray(dsa.selection_mask(scores, topk))
+    got = np.asarray(dsa.selection_mask(scores, topk, interpret=kernel))
+    assert trace.gauges()["dsa.select_kernel"] == float(kernel)
     assert got.dtype == np.int8
     np.testing.assert_array_equal(got != 0, _top_k_mask(scores, topk))
     # exactly min(t + 1, topk) keys a row: the count the roofline credits
     np.testing.assert_array_equal(
         got.sum(-1)[0], np.minimum(np.arange(S) + 1, topk))
+
+
+@pytest.mark.parametrize("shape,topk", [
+    # topk inside the second row block of four: the first writes the
+    # causal mask and runs no pass, the second holds rows on both sides
+    ((1, S, S), 90),
+    # the last row block alone holds a row with more than topk keys
+    ((2, S, S), 200),
+    ((2, S, S), 64),
+    # 128 does not divide the sequence: one block, one tile
+    ((2, 96, 96), 20),
+    ((1, 200, 200), 77),
+    # 128 divides it and 256 does not: three tiles of keys a row
+    ((1, 384, 384), 130),
+])
+@pytest.mark.parametrize("kind", ["normal", "ties"])
+def test_the_kernel_is_the_xla_form_byte_for_byte(
+        shape, topk, kind, small_select):
+    scores = jax.random.normal(jax.random.key(11), shape)
+    if kind == "ties":
+        scores = jnp.round(scores * 2) / 2
+    want = np.asarray(dsa.selection_mask(scores, topk))
+    got = np.asarray(dsa.selection_mask(scores, topk, interpret=True))
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(
+        want != 0, _top_k_mask(scores, topk))
+
+
+ABOVE = {
+    "nan": lambda key, shape: jnp.full(shape, jnp.nan),
+    "inf": lambda key, shape: jnp.full(shape, jnp.inf),
+    "garbage": lambda key, shape: lax.bitcast_convert_type(
+        jax.random.bits(key, shape, jnp.uint32), jnp.float32),
+}
+
+
+@pytest.mark.parametrize("kernel", [False, True])
+@pytest.mark.parametrize("above", sorted(ABOVE))
+def test_what_lies_above_the_diagonal_is_never_counted(
+        above, kernel, small_select):
+    """`index_scores` leaves those entries unspecified."""
+    scores = jnp.round(jax.random.normal(jax.random.key(12), (2, S, S)) * 4)
+    want = dsa.selection_mask(jnp.where(_causal(), scores, 0.0), 70)
+    got = dsa.selection_mask(
+        jnp.where(_causal(), scores,
+                  ABOVE[above](jax.random.key(13), scores.shape)),
+        70, interpret=kernel)
+    np.testing.assert_array_equal(got, want)
+
+
+def test_a_block_of_rows_is_sized_from_the_sequence():
+    # 14 bytes a key a row: the float32 block and the int8 block twice
+    # (the pipeline's two buffers) and the int32 key copy
+    assert dsa._select_rows(16384) == 128
+    assert dsa._select_rows(8192) == 256
+    assert dsa._select_rows(4096) == 512
+    assert dsa._select_rows(128) == 128
+    assert dsa._select_rows(96) == 96
+    # 6144 = 48 x 128: the largest multiple of 32 that divides it and fits
+    assert dsa._select_rows(6144) == 384
+    # nothing fits: the XLA form
+    assert dsa._select_rows(2**20) is None
+    assert dsa._select_rows(2**11 * 3 + 8) is None
+
+
+def test_a_sequence_no_block_fits_takes_the_xla_form(monkeypatch):
+    monkeypatch.setattr(dsa, "_SELECT_BUDGET", 1024)
+    scores = jax.random.normal(jax.random.key(3), (1, 64, 64))
+    got = dsa.selection_mask(scores, 10, interpret=True)
+    assert trace.gauges()["dsa.select_kernel"] == 0
+    np.testing.assert_array_equal(got != 0, _top_k_mask(scores, 10))
+
+
+def test_the_selection_is_one_kernel_and_no_array_of_bits(
+        monkeypatch, small_tiles, small_select):
+    """`selected_attention`'s lowered step: the threshold is the kernel
+    `dsa_select`, and no ``(s, s)`` uint32 array is left of the XLA
+    form."""
+    names = []
+    real = dsa.pl.pallas_call
+
+    def spy(*a, **kw):
+        names.append(kw.get("name"))
+        return real(*a, **kw)
+
+    monkeypatch.setattr(dsa.pl, "pallas_call", spy)
+    q, k, _, _, scale = _grouped_attention()
+    v = jax.random.normal(jax.random.key(3), k.shape)
+    iq, ik, iw = _operands(h=4, d=32, seed=4)
+
+    def step(interpret):
+        return jax.jit(lambda *a: dsa.selected_attention(
+            *a, 40, scale, interpret=interpret)[:2]).lower(
+                q, k, v, iq, ik, iw).as_text()
+
+    text = step(True)
+    assert names.count("dsa_select") == 1
+    assert f"tensor<2x{S}x{S}xui32>" not in text
+    assert f"tensor<2x{S}x{S}xui32>" in step(False)
+    assert names.count("dsa_select") == 1
 
 
 def test_a_topk_no_shorter_than_the_sequence_selects_every_causal_key():

@@ -18,6 +18,7 @@ import pytest
 from benchmarks.families import keye_vl as family
 from dlrover_tpu.models import keye_vl, moe
 from dlrover_tpu.observability import trace
+from dlrover_tpu.ops import dsa
 from dlrover_tpu.parallel import MeshConfig, build_mesh
 from dlrover_tpu.train.trainer import ElasticTrainer, TrainConfig
 
@@ -332,6 +333,39 @@ def test_a_layers_gradients_through_the_kernels_in_interpret_mode(
     assert set(moved) == {"wq", "wk", "wv", "wo", "q_norm", "k_norm",
                           *keye_vl.INDEXER}
     _assert_grads_agree(got, want, tol=1e-4)
+
+
+def test_the_select_kernel_changes_nothing_of_a_layer(
+        built, config, monkeypatch):
+    """The same layer through the same kernels, the selection by
+    `dsa_select` and by the XLA passes: one mask, so the same outputs and
+    the same gradients."""
+    fam, params, _ = built
+    cfg = fam.cfg
+    lp = keye_vl.layer_params(cfg, params, 1)
+    y = jax.random.normal(jax.random.key(2), (1, 128, cfg.dim))
+    g = jax.random.normal(jax.random.key(3), y.shape)
+    tables = keye_vl.rotary_tables(cfg, family.positions_for(config, 1, 128))
+
+    def run():
+        def scalar(lp):
+            out, l_i, mask, _ = keye_vl.attention(
+                cfg, None, tables, lp, y, interpret=True)
+            return jnp.sum(out * g) + l_i, (out, l_i, mask)
+
+        return jax.jit(jax.value_and_grad(scalar, has_aux=True))(lp)
+
+    (_, got), got_grads = run()
+    assert trace.gauges()["dsa.select_kernel"] == 1
+    assert trace.gauges()["attn.select_kernel"] == 1
+    monkeypatch.setattr(dsa, "_select_rows", lambda s: None)
+    (_, want), want_grads = run()
+    assert trace.gauges()["dsa.select_kernel"] == 0
+    assert trace.gauges()["attn.select_kernel"] == 0
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+    for name in want_grads:
+        np.testing.assert_array_equal(got_grads[name], want_grads[name])
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["off", "on"])
