@@ -82,7 +82,10 @@ def parse_faulthandler(text: str, main_only: bool = False) -> List[List[str]]:
 
 
 #: leaf frames of threads that are parked, not working: thread-pool
-#: workers waiting on their queue, threading waits, selector polls.
+#: workers waiting on their queue, threading waits, selector polls, and a
+#: receiver blocked in a read of its socket, pipe or file (the C call has
+#: no Python frame, so the leaf is the wrapper that made it, whose name
+#: is the rule: an RPC library's is in no file known here).
 #: Leaf-only on purpose — an executor thread actively running a task has
 #: deeper frames (``_worker -> run -> fn``) and must stay visible; a
 #: parked one is blocked in the C-level queue get, so its deepest
@@ -90,6 +93,7 @@ def parse_faulthandler(text: str, main_only: bool = False) -> List[List[str]]:
 _IDLE_LEAF_RE = re.compile(
     r"^(wait|_wait_for_tstate_lock|_recv_bytes|poll|select|accept|"
     r"get|_get_block) \((threading|queue|selectors|socket|connection)\.py:"
+    r"|^(read|readinto|readline|recv|recv_into) \("
     r"|^_worker \(thread\.py:"
     r"|^worker \(pool\.py:"
 )

@@ -5,6 +5,7 @@ accelerators (SURVEY.md §4): JAX runs on 8 virtual CPU devices so sharding
 and collectives are exercised for real.
 """
 
+import collections
 import os
 
 # Must be set before jax initializes its backend; the config update
@@ -16,11 +17,38 @@ if "--xla_force_host_platform_device_count" not in flags:
         flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 
+# A test's program runs once on a batch of a hundred tokens, and most of
+# a model file's seconds were XLA's CPU backend compiling it. No test
+# reads what LLVM's expensive passes make of it, so the session compiles
+# at backend level 0 (the HLO passes, buffer assignment and ``op_name``
+# metadata stay): a constant of the session, in the environment for the
+# processes the e2e tests start. A file whose tests assert on optimised
+# output puts the optimiser back in a module fixture of its own.
+os.environ["JAX_DISABLE_MOST_OPTIMIZATIONS"] = "1"
+
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
+jax.config.update("jax_disable_most_optimizations", True)
 
 import pytest  # noqa: E402
+
+
+def pytest_terminal_summary(terminalreporter, config):
+    """Where the run's seconds went: the total and the ten dearest files,
+    from the reports the session (under xdist, its controller) was
+    handed. ``tests/README.md`` has the budget they are read against."""
+    if hasattr(config, "workerinput"):
+        return
+    seconds = collections.Counter()
+    for reports in terminalreporter.stats.values():
+        for report in reports:
+            if hasattr(report, "duration") and hasattr(report, "nodeid"):
+                seconds[report.nodeid.split("::")[0]] += report.duration
+    terminalreporter.write_line(
+        f"test-seconds: {sum(seconds.values()):.0f} in {len(seconds)} files")
+    for name, spent in seconds.most_common(10):
+        terminalreporter.write_line(f"  {spent:7.1f} s  {name}")
 
 
 @pytest.fixture(autouse=True)

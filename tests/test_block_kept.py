@@ -121,13 +121,17 @@ def test_the_pp_stages_keep_what_the_scan_keeps(policy, toks, monkeypatch):
     moe_cfg = moe.MoeConfig.tiny(remat=True)
     jax.make_jaxpr(lambda p: moe.forward_hidden(p, toks, moe_cfg))(
         moe.init_params(moe_cfg, jax.random.key(0)))
-    scan, pp, experts = asked
+    scan, pp, experts, gathered = asked
     assert scan == pp
     wide = ("ffn_gate", "ffn_up") if policy == "mlp" else ()
     assert scan == (True, attention.KEPT + llama.QKV_KEPT + wide,
                     llama.report_kept)
     assert experts == (True, attention.KEPT + llama.QKV_KEPT,
                        llama.report_kept)
+    # ``moe._experts``' own call, inside the layer's: the live rows are
+    # gathered again where a row kernel gathered them, and none runs at
+    # the tiny configuration, where every expert is held
+    assert gathered == (False, (), None)
     # the stage's block is the scan's: the same gradient a layer
     lp = jax.tree.map(lambda a: a[0], params["layers"])
     x = jax.random.normal(jax.random.key(2), (2, 32, cfg.dim))

@@ -1,0 +1,20 @@
+"""The expert layer of the cells that hold a share of their experts and
+leave dispatch's forward to XLA's gather, forward and backward under
+remat, compiled at real widths for a described v5e in its parent's memory
+(the cells whose dispatch is bounded too:
+``test_chip_compile_expert_rows_bounded.py``; OLMoE's:
+``test_chip_compile_experts.py``; the cells' table and what each is held
+to: ``tests/chip_compile.py``)."""
+
+import pytest
+
+from tests.chip_compile import (  # noqa: F401  (fixtures by import)
+    BOUNDED, EXPERT_CELLS, expert_rows_compile_in_the_parents_memory,
+    kernels_are_the_path, one_chip, topo)
+
+
+@pytest.mark.parametrize(
+    "cell", sorted(set(EXPERT_CELLS) - BOUNDED - {"olmoe"}))
+def test_expert_rows_fwd_bwd_compile_in_the_parents_memory(
+        one_chip, kernels_are_the_path, cell):
+    expert_rows_compile_in_the_parents_memory(one_chip, cell)

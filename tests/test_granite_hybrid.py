@@ -5,6 +5,7 @@ tied table's gradient, Granite's multipliers, and what
 ``validate_for_mesh`` refuses."""
 
 import dataclasses
+import functools
 import json
 import os
 
@@ -85,7 +86,8 @@ def test_the_first_loss_has_the_tied_term(fam, config):
     want = granite_hybrid_flops.expected_first_loss(loud)
     untied = np.log(256) + 64 * (0.02 / 0.5) ** 2 / 2
     assert 0.03 < want - untied < 0.06
-    losses = [float(jax.jit(lambda p, t: family.plain_loss(p, t, loud))(
+    plain_loss = jax.jit(functools.partial(family.plain_loss, config=loud))
+    losses = [float(plain_loss(
         fam.init_params(jax.random.key(seed)), _tokens(fam.cfg, 64, seed, 8)))
         for seed in range(4)]
     assert abs(np.mean(losses) - want) < 0.015
@@ -107,8 +109,9 @@ def test_the_tied_tables_gradient_is_the_sum_of_its_two_uses(fam, params):
                                         tokens, cfg)
 
     table = params["embed"]
-    by_lookup, by_head = jax.grad(loss, argnums=(0, 1))(table, table)
-    whole = jax.grad(lambda p: fam.loss_fn(p, tokens))(params)["embed"]
+    by_lookup, by_head = jax.jit(jax.grad(loss, argnums=(0, 1)))(table, table)
+    whole = jax.jit(jax.grad(lambda p: fam.loss_fn(p, tokens)))(
+        params)["embed"]
     assert float(jnp.abs(by_lookup).max()) > 0
     assert float(jnp.abs(by_head).max()) > 0
     _close(whole, by_lookup + by_head, 1e-5)
@@ -122,13 +125,14 @@ def test_the_loss_is_the_head_over_the_last_residual(fam, params):
     """``loss_fn`` is ``head_loss`` of ``forward_layers`` (the reference
     hook reads both from one program); a target below zero is no target."""
     cfg, tokens = fam.cfg, _tokens(fam.cfg, 32)
-    x = granite_hybrid.forward_layers(params, tokens, cfg)
-    whole = granite_hybrid.head_loss(params, x, tokens, cfg)
+    x = jax.jit(functools.partial(
+        granite_hybrid.forward_layers, cfg=cfg))(params, tokens)
+    head_loss = jax.jit(functools.partial(granite_hybrid.head_loss, cfg=cfg))
+    whole = head_loss(params, x, tokens)
     assert float(whole) == pytest.approx(
-        float(fam.loss_fn(params, tokens)), rel=1e-6)
-    padded = granite_hybrid.head_loss(
-        params, x, tokens.at[:, -8:].set(-1), cfg)
-    short = granite_hybrid.head_loss(params, x[:, :-8], tokens[:, :-8], cfg)
+        float(jax.jit(fam.loss_fn)(params, tokens)), rel=1e-6)
+    padded = head_loss(params, x, tokens.at[:, -8:].set(-1))
+    short = head_loss(params, x[:, :-8], tokens[:, :-8])
     assert float(padded) == pytest.approx(float(short), rel=1e-6)
     assert abs(float(padded) - float(whole)) > 1e-4
 
@@ -178,19 +182,22 @@ def test_a_head_share_is_the_uncut_mixers_slice_up_to_the_norm(uncut):
     count, which is **not** the whole's."""
     cfg, params, y = uncut
     lp = granite_hybrid.layer_params(cfg, params, 0)
-    operands, z = granite_hybrid.mamba_operands(cfg, lp, y)
-    whole = ssd.ssd(*operands, chunk=16).reshape(2, 32, -1)
-    g, stat = granite_hybrid.gated(whole, z)
+
+    @functools.partial(jax.jit, static_argnums=0)
+    def scanned(cfg, lp):
+        operands, z = granite_hybrid.mamba_operands(cfg, lp, y)
+        scan = ssd.ssd(*operands, chunk=16).reshape(2, 32, -1)
+        return (scan, *granite_hybrid.gated(scan, z))
+
+    whole, g, stat = scanned(cfg, lp)
     total, p = 0.0, cfg.mamba_head_dim
     assert dataclasses.replace(cfg, mamba_heads_held=2).inner == 2 * p
     for first in range(0, 8, 2):
         share = dataclasses.replace(cfg, mamba_heads_held=2,
                                     first_mamba_head=first)
         mine = _mamba_share(lp, cfg, first, 2)
-        ops, z_mine = granite_hybrid.mamba_operands(share, mine, y)
-        scan = ssd.ssd(*ops, chunk=16).reshape(2, 32, -1)
+        scan, g_mine, stat_mine = scanned(share, mine)
         _close(scan, whole[..., first * p:(first + 2) * p], 1e-5)
-        g_mine, stat_mine = granite_hybrid.gated(scan, z_mine)
         _close(stat_mine * 2 * p, jnp.sum(
             g[..., first * p:(first + 2) * p] ** 2, -1, keepdims=True), 1e-5)
         total = total + stat_mine * 2 * p
@@ -198,7 +205,8 @@ def test_a_head_share_is_the_uncut_mixers_slice_up_to_the_norm(uncut):
         # and the share's mixer is its own norm over its own channels
         want = (g_mine * jax.lax.rsqrt(stat_mine + cfg.norm_eps)
                 * mine["m_norm"]) @ mine["w_out"]
-        _close(granite_hybrid.mamba_mixer(share, mine, y), want, 1e-5)
+        _close(jax.jit(functools.partial(
+            granite_hybrid.mamba_mixer, share))(mine, y), want, 1e-5)
     _close(total, stat * cfg.inner, 1e-5)
 
 
@@ -209,7 +217,8 @@ def test_the_attention_shares_add_up_to_the_uncut_layer(uncut):
     cfg, params, y = uncut
     lp = granite_hybrid.layer_params(cfg, params, 2)
     assert cfg.kinds[2] == "A"
-    whole = granite_hybrid.attention_mixer(cfg, lp, y)
+    mixer = jax.jit(granite_hybrid.attention_mixer, static_argnums=0)
+    whole = mixer(cfg, lp, y)
     hd, total = cfg.head_dim, 0.0
     for first in (0, 2):
         share = dataclasses.replace(cfg, heads_held=2, first_head=first)
@@ -217,7 +226,7 @@ def test_the_attention_shares_add_up_to_the_uncut_layer(uncut):
         kv = slice(first // 2 * hd, (first // 2 + 1) * hd)
         mine = {**lp, "w_q": lp["w_q"][:, q], "w_o": lp["w_o"][q],
                 "w_k": lp["w_k"][:, kv], "w_v": lp["w_v"][:, kv]}
-        total = total + granite_hybrid.attention_mixer(share, mine, y)
+        total = total + mixer(share, mine, y)
     _close(total, whole, 1e-5)
 
 
@@ -226,14 +235,15 @@ def test_the_expert_shares_and_the_shared_expert_once_are_the_layer(uncut):
     expert counted once, are the uncut expert layer."""
     cfg, params, y = uncut
     lp = granite_hybrid.layer_params(cfg, params, 1)
-    whole = moe.moe_mlp(cfg.as_moe(), lp, y)[0]
+    layer = jax.jit(moe.moe_mlp, static_argnums=0)
+    whole = layer(cfg.as_moe(), lp, y)[0]
     routed = {k: v for k, v in lp.items() if not k.startswith("ws_")}
-    total = moe._shared_expert(lp, y)
+    total = jax.jit(moe._shared_expert)(lp, y)
     for first in range(0, 8, 2):
         share = dataclasses.replace(cfg, experts_held=2, first_expert=first)
         mine = {**routed, **{k: routed[k][first:first + 2]
                              for k in ("w_gate", "w_up", "w_down")}}
-        total = total + moe.moe_mlp(share.as_moe(), mine, y)[0]
+        total = total + layer(share.as_moe(), mine, y)[0]
     _close(total, whole, 1e-5)
 
 
@@ -243,13 +253,18 @@ def test_the_vocabulary_slice_is_a_smaller_vocabulary(fam, params):
     cfg = dataclasses.replace(fam.cfg, vocab_size=128)
     sliced = {**params, "embed": params["embed"][:128]}
     tokens = _tokens(cfg, 32)
-    x = granite_hybrid.head_input(cfg, sliced, granite_hybrid.forward_layers(
-        sliced, tokens, cfg))
-    logp = jax.nn.log_softmax(x[:, :-1] @ sliced["embed"].T, axis=-1)
-    want = -jnp.mean(jnp.take_along_axis(
-        logp, tokens[:, 1:, None], axis=-1))
-    assert float(granite_hybrid.loss_fn(sliced, tokens, cfg)
-                 ) == pytest.approx(float(want), abs=2e-6)
+
+    @jax.jit
+    def plain(sliced, tokens):
+        x = granite_hybrid.head_input(
+            cfg, sliced, granite_hybrid.forward_layers(sliced, tokens, cfg))
+        logp = jax.nn.log_softmax(x[:, :-1] @ sliced["embed"].T, axis=-1)
+        return -jnp.mean(jnp.take_along_axis(
+            logp, tokens[:, 1:, None], axis=-1))
+
+    want = plain(sliced, tokens)
+    assert float(jax.jit(functools.partial(granite_hybrid.loss_fn, cfg=cfg))(
+        sliced, tokens)) == pytest.approx(float(want), abs=2e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -262,9 +277,9 @@ def test_the_pallas_form_of_the_mamba_mixer_is_the_xla_form(fam, params):
     y = jax.random.normal(jax.random.key(8), (2, 40, cfg.dim))
 
     def both(interpret):
-        return jax.value_and_grad(lambda lp, y: jnp.sum(
+        return jax.jit(jax.value_and_grad(lambda lp, y: jnp.sum(
             granite_hybrid.mamba_mixer(cfg, lp, y, interpret=interpret) ** 2),
-            argnums=(0, 1))(lp, y)
+            argnums=(0, 1)))(lp, y)
 
     (want, want_grads), (got, got_grads) = both(False), both(True)
     assert float(got) == pytest.approx(float(want), rel=1e-5)
@@ -300,8 +315,9 @@ def test_remat_keeps_the_flash_pair_and_the_scan(fam, params):
     cfg = dataclasses.replace(fam.cfg, remat=True)
     tokens = _tokens(cfg, 32)
     trace.gauge("ssm.state_kept", 0)
-    want = jax.grad(lambda p: fam.loss_fn(p, tokens))(params)
-    got = jax.grad(lambda p: granite_hybrid.loss_fn(p, tokens, cfg))(params)
+    want = jax.jit(jax.grad(lambda p: fam.loss_fn(p, tokens)))(params)
+    got = jax.jit(jax.grad(
+        lambda p: granite_hybrid.loss_fn(p, tokens, cfg)))(params)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         _close(a, b, 1e-5)
     assert trace.gauges()["ssm.state_kept"] == 1
@@ -347,8 +363,9 @@ def test_each_multiplier_is_in_the_forward(fam, params, field, value):
     loud = granite_hybrid.init_params(
         dataclasses.replace(cfg, out_proj_std=None), jax.random.key(3))
     def read(cfg):
-        x = granite_hybrid.forward_layers(loud, tokens, cfg)
-        return x, granite_hybrid.loss_fn(loud, tokens, cfg)
+        return tuple(jax.jit(functools.partial(fn, cfg=cfg))(loud, tokens)
+                     for fn in (granite_hybrid.forward_layers,
+                                granite_hybrid.loss_fn))
 
     (x, loss), (x_moved, loss_moved) = read(cfg), read(
         dataclasses.replace(cfg, **{field: value}))

@@ -7,6 +7,7 @@ of experts and ids tied to the uncut layer; what a block keeps; the
 meshes the family refuses."""
 
 import dataclasses
+import functools
 import json
 import os
 
@@ -236,14 +237,15 @@ def test_the_expert_shares_routed_parts_add_up_to_the_uncut_layer(
     params = _weighty(whole.init_params(jax.random.key(1)))
     lp = keye_vl.layer_params(whole.cfg, params, 1)
     u = jax.random.normal(jax.random.key(3), (1, 24, whole.cfg.dim))
-    want, _ = family._ref_expert_layer(u, lp, whole_cfg)
+    want, _ = jax.jit(functools.partial(
+        family._ref_expert_layer, config=whole_cfg))(u, lp)
     total = 0.0
     for first in range(0, 8, 4):
         share = dict(lp, **{name: lp[name][first:first + 4]
                             for name in ("w_gate", "w_up", "w_down")})
         cfg = dataclasses.replace(
             whole.cfg, experts_held=4, first_expert=first).as_moe()
-        out, _ = moe.moe_mlp(cfg, share, u)
+        out, _ = jax.jit(functools.partial(moe.moe_mlp, cfg))(share, u)
         assert float(jnp.max(jnp.abs(out))) > 1e-3
         total = total + out
     np.testing.assert_allclose(total, want, atol=5e-5, rtol=5e-5)

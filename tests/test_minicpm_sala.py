@@ -3,6 +3,7 @@ against the plain reference (``benchmarks/families/minicpm_sala.py``) on
 seeded weights, its layout, MiniCPM's scalings, the two branches of the
 sparse layer, and what ``validate_for_mesh`` refuses."""
 
+import functools
 import json
 import os
 
@@ -137,11 +138,15 @@ def test_within_dense_len_the_sparse_layer_is_causal_attention(fam, params):
     cfg = fam.cfg
     lp, y = _layer_input(cfg, params, 0, seq=32)
     assert not cfg.sparse_at(32)
-    got = minicpm_sala.sparse_layer(cfg, lp, y)
-    q, k, v, gate = minicpm_sala.sparse_operands(cfg, lp, y)
-    out = attention.mha_reference(q, k, v, causal=True)
-    want = (out * jax.nn.sigmoid(gate)).reshape(2, 32, -1) @ lp["w_o"]
-    np.testing.assert_allclose(got, want, atol=1e-6)
+    got = jax.jit(functools.partial(minicpm_sala.sparse_layer, cfg))(lp, y)
+
+    @jax.jit
+    def causal(lp, y):
+        q, k, v, gate = minicpm_sala.sparse_operands(cfg, lp, y)
+        out = attention.mha_reference(q, k, v, causal=True)
+        return (out * jax.nn.sigmoid(gate)).reshape(2, 32, -1) @ lp["w_o"]
+
+    np.testing.assert_allclose(got, causal(lp, y), atol=1e-6)
 
 
 def test_the_choice_takes_no_gradient(fam, params):
@@ -149,14 +154,15 @@ def test_the_choice_takes_no_gradient(fam, params):
     attention under a fixed choice."""
     cfg = fam.cfg
     lp, y = _layer_input(cfg, params, 0)
-    q, k, _, _ = minicpm_sala.sparse_operands(cfg, lp, y)
-    chosen = minicpm_sala.choose_blocks(cfg, q, k)
+    q, k, _, _ = jax.jit(functools.partial(
+        minicpm_sala.sparse_operands, cfg))(lp, y)
+    choose = functools.partial(minicpm_sala.choose_blocks, cfg)
+    chosen = jax.jit(choose)(q, k)
     assert chosen.dtype == jnp.int8 and chosen.shape == (2, 2, 64, 8)
     assert (np.asarray(chosen).sum(-1) == np.minimum(
         np.arange(64) // 8 + 1, 4)).all()
-    grads = jax.grad(lambda q, k: jnp.sum(
-        minicpm_sala.choose_blocks(cfg, q, k).astype(jnp.float32)),
-        (0, 1))(q, k)
+    grads = jax.jit(jax.grad(lambda q, k: jnp.sum(
+        choose(q, k).astype(jnp.float32)), (0, 1)))(q, k)
     assert all(float(jnp.abs(g).max()) == 0.0 for g in grads)
 
 
@@ -239,13 +245,15 @@ def test_each_scaling_is_in_the_forward(fam, params, field, value):
     cfg = fam.cfg
     other = minicpm_sala.MiniCPMSalaConfig(**{**cfg.__dict__, field: value})
     tokens = _tokens(cfg, 64)
-    a = minicpm_sala.forward_layers(params, tokens, cfg)
-    b = minicpm_sala.forward_layers(params, tokens, other)
+    forward = jax.jit(minicpm_sala.forward_layers, static_argnums=2)
+    loss = jax.jit(minicpm_sala.loss_fn, static_argnums=2)
+    a = forward(params, tokens, cfg)
+    b = forward(params, tokens, other)
     if field == "dim_model_base":       # the head's divisor only
         np.testing.assert_array_equal(a, b)
         assert other.head_divisor == 1.0 and cfg.head_divisor == 4.0
-        assert float(minicpm_sala.loss_fn(params, tokens, cfg)) != float(
-            minicpm_sala.loss_fn(params, tokens, other))
+        assert float(loss(params, tokens, cfg)) != float(
+            loss(params, tokens, other))
     else:
         assert float(jnp.abs(a - b).max()) > 1e-4
 
@@ -257,7 +265,8 @@ def test_the_embedding_is_scaled_and_the_branches_by_the_published_depth(
     none = minicpm_sala.MiniCPMSalaConfig(**{
         **cfg.__dict__, "scale_depth": 0.0})
     np.testing.assert_allclose(
-        minicpm_sala.forward_layers(params, tokens, none),
+        jax.jit(minicpm_sala.forward_layers, static_argnums=2)(
+            params, tokens, none),
         12.0 * params["embed"][tokens], rtol=1e-6)
     assert cfg.residual_scale == pytest.approx(1.4 / 2.0)
 

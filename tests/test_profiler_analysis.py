@@ -170,6 +170,40 @@ def test_stack_sampler_ignores_parked_pool_threads():
         pool.shutdown(wait=False)
 
 
+def test_stack_sampler_ignores_a_thread_blocked_in_a_read():
+    """A receiver thread parked in a blocking read of its pipe (as an
+    RPC library's is: the whole of its stack the same in every sample)
+    is not the hotspot."""
+    import os
+    import threading
+    import time
+
+    from dlrover_tpu.profiler.stack_sampler import StackSampler
+
+    def hot_spin(until):
+        while time.time() < until:
+            sum(range(200))
+
+    r, w = os.pipe()
+
+    def read():
+        os.read(r, 1)
+
+    receiver = threading.Thread(target=read, daemon=True)
+    receiver.start()
+    try:
+        with StackSampler(interval=0.002) as s:
+            hot_spin(time.time() + 0.4)
+        hot = s.hot_path()
+        assert any("hot_spin" in fr for fr in hot), hot
+        assert " read (" not in s.render(min_share=0.0)
+    finally:
+        os.write(w, b"x")
+        receiver.join()
+        os.close(r)
+        os.close(w)
+
+
 def test_hang_trie_main_thread_only():
     """ADVICE r3: hang-dump summarization weights only the 'Current
     thread' section so stuck_at names the hung collective, not an idle
