@@ -574,6 +574,9 @@ def selected_attention(cfg: Dots3Config, mesh, positions, inv_freq, lp, y,
         out, aux["l_i"], aux["mask"], aux["scores"] = dsa.selected_attention(
             q, k, v, *index_inputs(cfg, positions, inv_freq, lp, y, c_q),
             cfg.index_topk, scale, interpret=interpret, mesh=mesh)
+        # under the prefix the jobs' `gauges:` line prints
+        trace.gauge("attn.index_bwd_kernels",
+                    trace.gauges().get("dsa.index_bwd_kernels", 0))
         return out
 
     return attend, aux

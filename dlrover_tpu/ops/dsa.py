@@ -13,13 +13,14 @@ and trains the scorer towards the main attention's own distribution,
 ``KL(p^_t || softmax_{s in S_t} I[t, s])`` with ``p`` the attention
 probabilities summed over heads. What this file computes:
 
-- `index_scores`: ``I (b, s, s)`` float32. On the TPU three Pallas
-  kernels under one ``custom_vjp`` (``dsa_index_fwd``; ``dsa_index_bwd_dq``
-  for d``q`` and d``w``; ``dsa_index_bwd_dk``): a grid step is a
-  (query block, key block) tile, the index heads a loop inside it, so
-  the ``(s, s, heads)`` products live in VMEM a head at a time and HBM
-  holds ``I`` alone. Blocks wholly above the diagonal are zeros, neither
-  computed nor fetched. bf16 operands, float32 products and sums.
+- `index_scores`: ``I (b, s, s)`` float32. On the TPU two Pallas
+  kernels under one ``custom_vjp`` (``dsa_index_fwd``; ``dsa_index_bwd``
+  for d``q``, d``w`` and d``k``, the one index key's gradient held in
+  VMEM for a whole batch row): a grid step is a (query block, key block)
+  tile, the index heads a loop inside it, so the ``(s, s, heads)``
+  products live in VMEM a head at a time and HBM holds ``I`` alone.
+  Blocks wholly above the diagonal are zeros, neither computed nor
+  fetched. bf16 operands, float32 products and sums.
 - `select_threshold`: per row the ``topk``-th largest of the causal
   entries, exactly, and where its ties are cut: **bisection on the
   float's bits** (32 counting passes over an order-preserving uint32
@@ -60,6 +61,7 @@ at 8192 positions the masked causal walk does less work a pair.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -79,18 +81,23 @@ from dlrover_tpu.ops.attention import (
     _VMEM_BUDGET,
     _VMEM_LIMIT,
     _dot,
-    _first_q_block as _first_q,
     _last_k_block as _last_k,
     flash_attention,
 )
 from dlrover_tpu.ops.kda import _over_batch_rows
 from dlrover_tpu.parallel.mesh import BATCH_AXES
 
-#: largest (block_q, block_k) of the four kernels: the forward and the
-#: probabilities keep one float32 tile, the backwards also a float32
-#: accumulator an index head
-_MAX_TILE = {"fwd": (256, 512), "dq": (128, 512), "dk": (128, 512),
-             "probs": (512, 512)}
+#: largest (block_q, block_k) of the three kernels: the forward and the
+#: probabilities keep one float32 tile, the backward also a float32
+#: accumulator an index head (its tiles and trips tried on the v5e:
+#: PERF.md section 6, PR 57)
+_MAX_TILE = {"fwd": (256, 512), "dq": (256, 512), "probs": (512, 512)}
+
+#: index heads a trip of the backward's head loop (Mosaic unrolls a loop
+#: wholly or not at all): a head's chain of product, vector passes and
+#: two products leaves the units idle in turn, and four give the
+#: scheduler independent work to lay between (a fifth faster than one)
+_HEADS_A_TRIP = 4
 
 
 def _on_tpu() -> bool:
@@ -195,128 +202,117 @@ def _index_fwd_pallas(q, k, w, interpret: bool):
     )(q.transpose(0, 2, 1, 3), k, _lanes(w.transpose(0, 2, 1)))
 
 
-def _index_bwd_dq_kernel(q_ref, k_ref, w_ref, g_ref, dq_ref, dw_ref,
-                         dq_acc, dw_acc, *, bq: int, bk: int, n_k: int):
+def _index_bwd_kernel(q_ref, k_ref, w_ref, g_ref, dq_ref, dw_ref, dkt_ref,
+                      dq_acc, dw_acc, dkt_acc, qt_ref, wl_ref, *, bq: int,
+                      bk: int, n_q: int, n_k: int):
     qi, ki = pl.program_id(1), pl.program_id(2)
     heads = q_ref.shape[1]
+
+    def of_head(j):
+        return lax.broadcasted_iota(jnp.int32, (bq, heads), 1) == j
+
+    @pl.when((qi == 0) & (ki == 0))
+    def _init_row():
+        dkt_acc[...] = jnp.zeros_like(dkt_acc)
 
     @pl.when(ki == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
         dw_acc[...] = jnp.zeros_like(dw_acc)
 
+        # once a q block, so that no tile's product transposes anything
+        # and a head's weights lie a row's on every lane
+        def turn(j, carry):
+            qt_ref[j] = q_ref[0, j].T
+            wl_ref[j] = jnp.broadcast_to(jnp.sum(
+                jnp.where(of_head(j), w_ref[0], 0.0), axis=1, keepdims=True),
+                wl_ref.shape[1:])
+            return carry
+
+        lax.fori_loop(0, heads, turn, 0)
+
     @pl.when(ki * bk <= qi * bq + bq - 1)
     def _compute():
         k = k_ref[0]
         g = g_ref[0]                                         # (bq, bk) f32
+        keys = pl.ds(pl.multiple_of(ki * bk, bk), bk)
 
-        def head(j, carry):
+        def head(j):
             dots = _dot(q_ref[0, j], k, _NT)
-            dw_acc[j] = dw_acc[j] + jnp.sum(
-                g * jnp.maximum(dots, 0.0), axis=1, keepdims=True)
-            gw = jnp.where(dots > 0.0, g * w_ref[0, j][:, :1], 0.0)
-            dq_acc[j] = dq_acc[j] + _dot(gw.astype(k.dtype), k, _NN)
+            dw_acc[...] = dw_acc[...] + jnp.where(of_head(j), jnp.sum(
+                g * jnp.maximum(dots, 0.0), axis=1, keepdims=True), 0.0)
+            gw = jnp.where(
+                dots > 0.0, g * wl_ref[j][:, :1], 0.0).astype(k.dtype)
+            dq_acc[j] = dq_acc[j] + _dot(gw, k, _NN)
+            dkt_acc[:, keys] = dkt_acc[:, keys] + _dot(qt_ref[j], gw, _NN)
+
+        a_trip = math.gcd(heads, _HEADS_A_TRIP)
+
+        def trip(i, carry):
+            for u in range(a_trip):
+                head(i * a_trip + u)
             return carry
 
-        lax.fori_loop(0, heads, head, 0)
+        lax.fori_loop(0, heads // a_trip, trip, 0)
 
     @pl.when(ki == _last_k(qi, bq, bk, n_k))
     def _finalize():
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
-        dw_ref[0] = dw_acc[...][:, :, :_LANES]
+        dw_ref[0] = dw_acc[...]
 
-
-def _index_bwd_dk_kernel(q_ref, k_ref, w_ref, gt_ref, dk_ref, dk_acc,
-                         *, bq: int, bk: int, n_q: int):
-    ki, qi = pl.program_id(1), pl.program_id(2)
-    heads = q_ref.shape[1]
-
-    @pl.when(qi == 0)
-    def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-
-    @pl.when(ki * bk <= qi * bq + bq - 1)
-    def _compute():
-        k = k_ref[0]
-        gt = gt_ref[0]                                       # (bk, bq) f32
-
-        def head(j, carry):
-            q = q_ref[0, j]                                  # (bq, d)
-            dots_t = _dot(k, q, _NT)                         # (bk, bq)
-            gw = jnp.where(dots_t > 0.0, gt * w_ref[0, j], 0.0)
-            dk_acc[...] = dk_acc[...] + _dot(gw.astype(q.dtype), q, _NN)
-            return carry
-
-        lax.fori_loop(0, heads, head, 0)
-
-    @pl.when(qi == n_q - 1)
-    def _finalize():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+    @pl.when((qi == n_q - 1) & (ki == n_k - 1))
+    def _finalize_row():
+        dkt_ref[0] = dkt_acc[...].astype(dkt_ref.dtype)
 
 
 def _index_bwd_pallas(q, k, w, g, interpret: bool):
+    """d``q``, d``k``, d``w`` from one kernel: a tile's scores are formed
+    once a head and feed all three. The flash backward is two kernels
+    because dk and dv a head fit no VMEM; the indexer's one key a
+    position has a gradient of ``(d, s)`` float32, which stays in VMEM
+    while a batch row's q blocks walk by. Kept transposed, ``dk^T +=
+    q_j^T gw`` on a q block turned once: the other way round every head
+    and tile would turn ``gw``."""
     b, s, h, d = q.shape
-    qt = q.transpose(0, 2, 1, 3)
-    wt = w.transpose(0, 2, 1)                                # (b, h, s)
     bq, bk = _tiles("dq", s)
     n_q, n_k = s // bq, s // bk
-
-    def q_rows(lanes):
-        return pl.BlockSpec((1, h, bq, lanes),
-                            lambda bi, qi, ki: (bi, 0, qi, 0))
+    # whatever holds the float32 (s, s) scores in HBM (s under 64 k on a
+    # 16 GiB chip) holds this: 32 MiB at 64 k x 128
+    assert s * d * 4 <= _VMEM_BUDGET, (s, d)
+    heads_q_rows = pl.BlockSpec(
+        (1, h, bq, d), lambda bi, qi, ki: (bi, 0, qi, 0))
+    q_rows = pl.BlockSpec((1, bq, h), lambda bi, qi, ki: (bi, qi, 0))
 
     def k_index(bi, qi, ki):
         return jnp.minimum(ki, _last_k(qi, bq, bk, n_k))
 
-    dq, dw = pl.pallas_call(
-        functools.partial(_index_bwd_dq_kernel, bq=bq, bk=bk, n_k=n_k),
+    dq, dw, dkt = pl.pallas_call(
+        functools.partial(_index_bwd_kernel, bq=bq, bk=bk, n_q=n_q, n_k=n_k),
         grid=(b, n_q, n_k),
         in_specs=[
-            q_rows(d),
+            heads_q_rows,
             pl.BlockSpec((1, bk, d),
                          lambda bi, qi, ki: (bi, k_index(bi, qi, ki), 0)),
-            q_rows(_LANES),
+            q_rows,
             pl.BlockSpec((1, bq, bk),
                          lambda bi, qi, ki: (bi, qi, k_index(bi, qi, ki))),
         ],
-        out_specs=[q_rows(d), q_rows(_LANES)],
+        out_specs=[heads_q_rows, q_rows,
+                   pl.BlockSpec((1, d, s), lambda bi, qi, ki: (bi, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
-                   jax.ShapeDtypeStruct((b, h, s, _LANES), jnp.float32)],
+                   jax.ShapeDtypeStruct((b, s, h), jnp.float32),
+                   jax.ShapeDtypeStruct((b, d, s), k.dtype)],
         scratch_shapes=[pltpu.VMEM((h, bq, d), jnp.float32),
+                        pltpu.VMEM((bq, h), jnp.float32),
+                        pltpu.VMEM((d, s), jnp.float32),
+                        pltpu.VMEM((h, d, bq), q.dtype),
                         pltpu.VMEM((h, bq, _STAT_LANES), jnp.float32)],
-        compiler_params=_params("parallel", "parallel", "arbitrary"),
+        # dk sums over the q blocks too
+        compiler_params=_params("parallel", "arbitrary", "arbitrary"),
         interpret=interpret,
-        name="dsa_index_bwd_dq",
-    )(qt, k, _lanes(wt), g)
-
-    bq, bk = _tiles("dk", s)
-    n_q, n_k = s // bq, s // bk
-
-    def q_index(bi, ki, qi):
-        # above the diagonal the index waits on the first block needed
-        return jnp.maximum(qi, _first_q(ki, bq, bk, n_q))
-
-    dk = pl.pallas_call(
-        functools.partial(_index_bwd_dk_kernel, bq=bq, bk=bk, n_q=n_q),
-        grid=(b, n_k, n_q),
-        in_specs=[
-            pl.BlockSpec((1, h, bq, d),
-                         lambda bi, ki, qi: (bi, 0, q_index(bi, ki, qi), 0)),
-            pl.BlockSpec((1, bk, d), lambda bi, ki, qi: (bi, ki, 0)),
-            pl.BlockSpec((1, h, 1, bq),
-                         lambda bi, ki, qi: (bi, 0, 0, q_index(bi, ki, qi))),
-            pl.BlockSpec((1, bk, bq),
-                         lambda bi, ki, qi: (bi, ki, q_index(bi, ki, qi))),
-        ],
-        out_specs=pl.BlockSpec((1, bk, d), lambda bi, ki, qi: (bi, ki, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, s, d), k.dtype),
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=_params("parallel", "parallel", "arbitrary"),
-        interpret=interpret,
-        name="dsa_index_bwd_dk",
-    )(qt, k, wt[:, :, None, :], g.swapaxes(1, 2))
-    return (dq.transpose(0, 2, 1, 3), dk,
-            dw[..., 0].transpose(0, 2, 1).astype(w.dtype))
+        name="dsa_index_bwd",
+    )(q.transpose(0, 2, 1, 3), k, w, g)
+    return dq.transpose(0, 2, 1, 3), dkt.swapaxes(1, 2), dw.astype(w.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -346,6 +342,8 @@ def index_scores(q, k, w, *, interpret: bool = False,
     everything downstream reads the causal part alone."""
     kernels = interpret or _on_tpu()
     trace.gauge("dsa.kernel", 1 if kernels else 0)
+    # the `pallas_call`s of the backward
+    trace.gauge("dsa.index_bwd_kernels", 1 if kernels else 0)
     w = w.astype(jnp.float32)
     if not kernels:
         return _index_scores_xla(q, k, w)

@@ -192,6 +192,7 @@ def test_the_select_kernel_changes_nothing_of_a_full_layer(
 
     got, got_grads = run()
     assert trace.gauges()["dsa.select_kernel"] == 1
+    assert trace.gauges()["attn.index_bwd_kernels"] == 1
     monkeypatch.setattr(dsa, "_select_rows", lambda s: None)
     want, want_grads = run()
     assert trace.gauges()["dsa.select_kernel"] == 0
@@ -253,7 +254,7 @@ def _kernel_calls(jaxpr, found=None):
 
 @pytest.mark.parametrize("kind,layer,want", [
     ("F", 1, {"dsa_index_fwd": 1, "dsa_select": 1, "dsa_probs": 1,
-              "dsa_index_bwd_dq": 1, "dsa_index_bwd_dk": 1,
+              "dsa_index_bwd": 1,
               "attention_fwd_sel": 1,
               "attention_bwd_dq_sel": 1, "attention_bwd_dkv_sel": 1}),
     ("S", 2, {"attention_fwd_swa": 1, "attention_bwd_dq_swa": 1,

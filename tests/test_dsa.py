@@ -21,7 +21,7 @@ S = 256
 def small_tiles(monkeypatch):
     """Several blocks a side at 256 positions."""
     monkeypatch.setattr(dsa, "_MAX_TILE", {
-        "fwd": (128, 128), "dq": (128, 128), "dk": (128, 128),
+        "fwd": (128, 128), "dq": (128, 128),
         "probs": (128, 128)})
 
 
@@ -53,21 +53,20 @@ def test_index_scores_match_the_definition(interpret, small_tiles):
     assert trace.gauges()["dsa.kernel"] == float(interpret)
 
 
+def _index_grads(fn, q, k, w, g):
+    return jax.grad(
+        lambda q, k, w: jnp.sum(fn(q, k, w) * g), argnums=(0, 1, 2))(q, k, w)
+
+
 @pytest.mark.parametrize("interpret", [False, True])
 def test_index_scores_gradients(interpret, small_tiles):
     """The backward ``L_I`` needs: a cotangent that lives on causal
     entries only, as the KL's does."""
     q, k, w = _operands(seed=1)
     g = jax.random.normal(jax.random.key(9), (2, S, S)) * _causal()
-
-    def grads(fn):
-        return jax.grad(
-            lambda q, k, w: jnp.sum(fn(q, k, w) * g), argnums=(0, 1, 2))(
-                q, k, w)
-
-    want = grads(_plain_scores)
-    got = grads(lambda q, k, w: dsa.index_scores(
-        q, k, w, interpret=interpret))
+    want = _index_grads(_plain_scores, q, k, w, g)
+    got = _index_grads(lambda q, k, w: dsa.index_scores(
+        q, k, w, interpret=interpret), q, k, w, g)
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, atol=2e-3, rtol=2e-4)
 
@@ -78,7 +77,7 @@ def test_kernels_write_zeros_above_the_diagonal_blocks(small_tiles):
     assert not np.asarray(got[0, :128, 128:]).any()
 
 
-def test_the_kernels_are_three_and_named(monkeypatch, small_tiles):
+def test_the_kernels_are_two_and_named(monkeypatch, small_tiles):
     names = []
     real = dsa.pl.pallas_call
 
@@ -89,7 +88,44 @@ def test_the_kernels_are_three_and_named(monkeypatch, small_tiles):
     monkeypatch.setattr(dsa.pl, "pallas_call", spy)
     q, k, w = _operands(b=1)
     jax.grad(lambda q: jnp.sum(dsa.index_scores(q, k, w, interpret=True)))(q)
-    assert names == ["dsa_index_fwd", "dsa_index_bwd_dq", "dsa_index_bwd_dk"]
+    assert names == ["dsa_index_fwd", "dsa_index_bwd"]
+
+
+@pytest.mark.parametrize("interpret", [False, True])
+def test_the_gauge_counts_the_backward_kernels(interpret, small_tiles):
+    dsa.index_scores(*_operands(b=1), interpret=interpret)
+    assert trace.gauges()["dsa.index_bwd_kernels"] == float(interpret)
+
+
+def test_dk_sums_over_the_q_blocks_of_each_batch_row(monkeypatch):
+    """Four q blocks feed the first key block and two the second, in two
+    batch rows: the key's gradient is summed across a row's q blocks in
+    the kernel's scratch and zeroed where the next row begins."""
+    monkeypatch.setattr(dsa, "_MAX_TILE", dict(dsa._MAX_TILE, dq=(128, 256)))
+    s = 512
+    q, k, w = _operands(b=2, s=s, seed=3)
+    g = jax.random.normal(jax.random.key(4), (2, s, s)) * _causal(s)
+    # the second row's cotangent alone must not see the first row's sums
+    g = g.at[1].multiply(1e-3)
+    want = _index_grads(_plain_scores, q, k, w, g)[1]
+    got = _index_grads(
+        lambda *a: dsa.index_scores(*a, interpret=True), q, k, w, g)[1]
+    np.testing.assert_allclose(got[0], want[0], atol=2e-3, rtol=2e-4)
+    np.testing.assert_allclose(got[1], want[1], atol=2e-6, rtol=2e-4)
+
+
+@pytest.mark.parametrize("h,d", [(16, 64), (4, 128)])
+def test_the_fused_backward_at_the_cells_head_shapes(h, d, small_tiles):
+    """d``q``, d``k``, d``w`` at sixteen heads of 64 (keye-vl: half a
+    lane tile) and at heads of 128 (dots3) against the definition's
+    autodiff."""
+    q, k, w = _operands(b=1, h=h, d=d, seed=5)
+    g = jax.random.normal(jax.random.key(6), (1, S, S)) * _causal()
+    want = _index_grads(_plain_scores, q, k, w, g)
+    got = _index_grads(
+        lambda *a: dsa.index_scores(*a, interpret=True), q, k, w, g)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=2e-3, rtol=2e-4)
 
 
 # -- the selection ---------------------------------------------------------
