@@ -503,7 +503,7 @@ LIMITS = {
     # the program's selection and the reference's p: dq, dk, dv of the
     # `_sel` kernels at group 8 for one seeded cotangent, and the
     # gradient of L_I in the indexer's q, k, w through `indexer_loss` and
-    # the three `dsa_index_bwd_*` kernels at 64-wide heads, against the
+    # the one `dsa_index_bwd` kernel at 64-wide heads, against the
     # blocked float32 reference's: the 99th percentile over a gradient's
     # rows, the largest of each three. bf16 kernels, eight seeds:
     # 0.00284-0.00285 and 0.00271-0.00273; the reference on the seven

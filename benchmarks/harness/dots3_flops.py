@@ -104,11 +104,12 @@ def attention_flops_per_call(*, batch: int, n_heads: int, qk_dim: int,
 
 def index_flops_per_call(*, batch: int, seq: int, heads: int, dim: int
                          ) -> dict:
-    """FLOPs of the indexer's score kernels over the causal pairs: the
-    forward one product a head; d``q`` / d``w`` the scores again and dQ;
-    d``k`` the scores again and dK."""
+    """FLOPs of the indexer's two score kernels over the causal pairs:
+    ``dsa_index_fwd`` one product a head; ``dsa_index_bwd`` (one kernel
+    since PR 57) three: a tile's scores once, then dQ and dK from them
+    (d``w`` is a weighted row sum on the VPU and is not credited)."""
     unit = 2.0 * batch * heads * causal_pairs(seq) * dim
-    return {"fwd": unit, "bwd_dq": 2 * unit, "bwd_dk": 2 * unit}
+    return {"fwd": unit, "bwd": 3 * unit}
 
 
 def probs_flops_per_call(*, batch: int, n_heads: int, qk_dim: int,
@@ -158,8 +159,7 @@ def _suffixed(names, suffix: str) -> dict:
 
 _FLASH = {"fwd": "attention_fwd", "dq": "attention_bwd_dq",
           "dkv": "attention_bwd_dkv"}
-_INDEX = {"fwd": "dsa_index_fwd", "bwd_dq": "dsa_index_bwd_dq",
-          "bwd_dk": "dsa_index_bwd_dk"}
+_INDEX = {"fwd": "dsa_index_fwd", "bwd": "dsa_index_bwd"}
 
 
 def flash_patterns(kind: str) -> dict:
@@ -189,6 +189,16 @@ def _share_of_peak(ctx, patterns: dict, flops: dict):
     return 100.0 * needed / peak / seconds
 
 
+def log_index_kernels(ctx, flops: dict) -> None:
+    """Log each traced index kernel's own share of the bf16 peak (both
+    families' index rooflines read the two kernels together)."""
+    for k, pattern in _suffixed(_INDEX, "").items():
+        alone = _share_of_peak(ctx, {k: pattern}, flops)
+        if alone is not None:
+            ctx.log(f"indexer {pattern}: {alone:.2f} % of the bf16 peak "
+                    f"({flops[k] / 1e9:.1f} GFLOP a call)")
+
+
 def _is_ours(ctx) -> bool:
     return (ctx.devices[0].platform == "tpu"
             and ctx.config.get("family") == "dots3")
@@ -209,13 +219,17 @@ def read_flash_roofline(spec, ctx):
 
 
 def read_index_roofline(spec, ctx):
-    """``d3_dsa_index_roofline``: the indexer's three score kernels over
-    the causal pairs."""
+    """``d3_dsa_index_roofline``: the indexer's two score kernels
+    (``dsa_index_fwd``, ``dsa_index_bwd``) over the causal pairs; the log
+    has each kernel's own share."""
     if not _is_ours(ctx):
         return None
     params, c = ctx.cell["params"], ctx.config
-    return _share_of_peak(
-        ctx, _suffixed(_INDEX, ""), index_flops_per_call(
-            batch=int(params["batch"]) // len(ctx.devices),
-            seq=int(params["seq"]), heads=c["index_n_heads"],
-            dim=c["index_head_dim"]))
+    flops = index_flops_per_call(
+        batch=int(params["batch"]) // len(ctx.devices),
+        seq=int(params["seq"]), heads=c["index_n_heads"],
+        dim=c["index_head_dim"])
+    value = _share_of_peak(ctx, _suffixed(_INDEX, ""), flops)
+    if value is not None:
+        log_index_kernels(ctx, flops)
+    return value

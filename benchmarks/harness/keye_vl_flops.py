@@ -76,14 +76,14 @@ def flops_per_token(config: dict, seq: int) -> float:
 
 def index_bytes_per_call(*, batch: int, seq: int, heads: int, dim: int
                          ) -> dict:
-    """HBM bytes the indexer's score kernels have to move at the least:
-    the float32 ``(s, s)`` array's causal half once (the forward writes
-    it, each backward reads its cotangent) and the bf16 q, k and float32
-    w once."""
+    """HBM bytes the indexer's two score kernels have to move at the
+    least: the float32 ``(s, s)`` array's causal half once (the forward
+    writes it, the one backward kernel reads its cotangent once), the
+    bf16 q, k and float32 w once, and for the backward dq, dk and dw,
+    as large, once."""
     half = 4.0 * batch * causal_pairs(seq)
     rows = batch * seq * (2.0 * heads * dim + 2.0 * dim + 4.0 * heads)
-    return {"fwd": half + rows, "bwd_dq": half + 2 * rows,
-            "bwd_dk": half + 2 * rows}
+    return {"fwd": half + rows, "bwd": half + 2 * rows}
 
 
 def probs_bytes_per_call(*, batch: int, seq: int, heads: int, kv_heads: int,
@@ -122,9 +122,10 @@ def _any_call(name: str) -> str:
 
 def _log_breakdown(ctx) -> None:
     """Log the step's device milliseconds scope by scope, with the
-    selection's machinery as a share of their sum (the operator's; the
-    line does not carry them: the benchmark's 128 per-layer metrics leave
-    this cell five)."""
+    selection's machinery as a share of their sum (the operator's; since
+    PR 58 the line carries the selection's four, the expert layer's and
+    the lookup's as ``dsa_*_ms``, ``moe_*_ms`` and ``embed_ms``, which
+    read the same scopes through the same function)."""
     from benchmarks.harness import hlo_scopes
 
     by_scope = {
@@ -141,9 +142,11 @@ def _log_breakdown(ctx) -> None:
 
 
 def read_index_roofline(spec, ctx):
-    """``kvl_dsa_index_roofline``: the indexer's three score kernels by
-    name over the causal pairs at 16 heads of 64. Also logs the step's
-    breakdown by scope (`_log_breakdown`)."""
+    """``kvl_dsa_index_roofline``: the indexer's two score kernels
+    (``dsa_index_fwd``, ``dsa_index_bwd``) by name over the causal pairs
+    at 16 heads of 64; the log has each kernel's calls, work and binding
+    side (FLOPs, at these sizes) and its own share of the bf16 peak. Also
+    logs the step's breakdown by scope (`_log_breakdown`)."""
     if not _is_ours(ctx):
         return None
     z = sizes(ctx.config)
@@ -154,6 +157,7 @@ def read_index_roofline(spec, ctx):
     value = _kernel_roofline(ctx, "indexer", {
         _any_call("dsa_index_" + k): (flops[k], moved[k]) for k in flops})
     if value is not None:
+        dots3_flops.log_index_kernels(ctx, flops)
         _log_breakdown(ctx)
     return value
 

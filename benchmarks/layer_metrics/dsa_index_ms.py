@@ -1,2 +1,2 @@
-"""``q3n_moe_share_ms``: see ``q3n_moe_share_ms.json``."""
+"""``dsa_index_ms``: see ``dsa_index_ms.json``."""
 from benchmarks.harness.hlo_scopes import scoped_ms_per_step as read  # noqa: F401

@@ -1,2 +1,2 @@
-"""``q3n_embed_ms``: see ``q3n_embed_ms.json``."""
+"""``dsa_loss_ms``: see ``dsa_loss_ms.json``."""
 from benchmarks.harness.hlo_scopes import scoped_ms_per_step as read  # noqa: F401

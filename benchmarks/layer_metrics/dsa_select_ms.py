@@ -1,2 +1,2 @@
-"""``g4h_moe_experts_ms``: see ``g4h_moe_experts_ms.json``."""
+"""``dsa_select_ms``: see ``dsa_select_ms.json``."""
 from benchmarks.harness.hlo_scopes import scoped_ms_per_step as read  # noqa: F401

@@ -27,6 +27,35 @@ def load_json(*parts):
         return json.load(f)
 
 
+def cell_metrics(cell: str):
+    """The per-layer metrics ``BENCHMARK.json`` gives ``cell``, in its
+    order: those without a ``workloads`` list and those that list it."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        per_layer = json.load(f)["per_layer"]
+    return [m["name"] for m in per_layer
+            if cell in m.get("workloads", (cell,))]
+
+
+def made_up_v5e_ctx(cell_name: str, calls):
+    """What a reader gets after a traced run of ``cell_name`` on one v5e
+    whose device ran ``calls`` (``[(instruction name, seconds)]``) back
+    to back in one step; the reader's log lines collect in ``.logged``."""
+    cell = load_json("workloads", cell_name + ".json")
+    ops, t = [], 0.0
+    for name, seconds in calls:
+        ops.append((t, t + seconds * 1e9, name, ""))
+        t += seconds * 1e9
+    logged = []
+    return types.SimpleNamespace(
+        cell=cell, config=load_json("configs", cell["config"] + ".json"),
+        log=logged.append, logged=logged, step_op_names={}, counters={},
+        devices=[types.SimpleNamespace(platform="tpu",
+                                       device_kind="TPU v5 lite")],
+        trace=types.SimpleNamespace(devices={"d0": ops},
+                                    spans=[(0, t, "step")],
+                                    window_ns=(0.0, t)))
+
+
 def one_device_mesh():
     from dlrover_tpu.parallel import MeshConfig, build_mesh
     import jax

@@ -10,7 +10,8 @@ import types
 
 import pytest
 
-from conftest import BENCH, load_json, one_device_mesh
+from conftest import (BENCH, cell_metrics, load_json, made_up_v5e_ctx,
+                      one_device_mesh)
 
 from benchmarks.families import dots3 as family
 from benchmarks.harness import dots3_flops
@@ -278,8 +279,12 @@ def test_flops_of_the_listed_configuration():
     assert sel["dkv"] == 2 * 32 * 14_681_088 * 640
     index = dots3_flops.index_flops_per_call(
         batch=1, seq=8192, heads=64, dim=128)
-    assert index["fwd"] == 2 * 64 * 33_558_528 * 128
-    assert index["bwd_dq"] == index["bwd_dk"] == 2 * index["fwd"]
+    # the two kernels that run: the forward one product, the one backward
+    # kernel (PR 57) the scores again, dQ and dK
+    assert index == {"fwd": 2 * 64 * 33_558_528 * 128,
+                     "bwd": 3 * 2 * 64 * 33_558_528 * 128}
+    assert dots3_flops._suffixed(dots3_flops._INDEX, "") == {
+        "fwd": r"^dsa_index_fwd(\.\d+)?$", "bwd": r"^dsa_index_bwd(\.\d+)?$"}
     per_token = dots3_flops.flops_per_token(config, 8192)
     swa = dots3_flops.attention_flops_per_call(
         batch=1, n_heads=16, qk_dim=256, v_dim=128, pairs=4_071_168)
@@ -307,21 +312,37 @@ def test_kernel_patterns_tell_the_kinds_apart():
                          "attention_bwd_dkv_sel.7"]
     assert hits("S") == ["attention_fwd_swa.4", "attention_bwd_dq_swa.12",
                          "attention_bwd_dkv_swa"]
-    sel = load_json("layer_metrics", "d3_dsa_flash_ms.json")["patterns"]
-    swa = load_json("layer_metrics", "d3_swa_flash_ms.json")["patterns"]
+    sel = load_json("layer_metrics", "dsa_flash_ms.json")["patterns"]
+    swa = load_json("layer_metrics", "swa_flash_ms.json")["patterns"]
     assert [n for n in names if any(re.search(p, n) for p in sel)] == [
         n for n in names if "_sel" in n]
     assert [n for n in names if any(re.search(p, n) for p in swa)] == [
         n for n in names if "_swa" in n]
-    # the older readers under this cell's names
-    ours = load_json("layer_metrics", "d3_moe_share_ms.json")
-    theirs = load_json("layer_metrics", "moe_share_ms.json")
-    assert (ours["scopes"], ours["patterns"]) == (
-        theirs["scopes"], theirs["patterns"])
-    assert load_json("layer_metrics", "d3_mla_proj_ms.json")["scopes"] == (
-        load_json("layer_metrics", "mla_proj_ms.json")["scopes"])
-    assert load_json("layer_metrics", "d3_swa_flash_ms.json")["patterns"] == (
-        load_json("layer_metrics", "swa_flash_ms.json")["patterns"])
+    # the index kernels by name: the forward and the one backward, and no
+    # longer the pair it replaced
+    index = dots3_flops._suffixed(dots3_flops._INDEX, "")
+    ran = ["dsa_index_fwd.1", "dsa_index_bwd", "dsa_index_bwd.3",
+           "dsa_index_bwd_dq.2", "dsa_index_bwd_dk"]
+    assert [n for n in ran if any(
+        re.search(p, n) for p in index.values())] == ran[:3]
+
+
+def test_the_index_roofline_reads_the_forward_and_the_one_backward():
+    """``d3_dsa_index_roofline`` on a made-up trace of the listed cell on
+    a v5e: two forward calls and one backward at exactly twice their
+    least time read 50 %; the pair PR 57 replaced is not matched, so its
+    seconds neither count nor dilute."""
+    unit_s = 2.0 * 64 * 33_558_528 * 128 / 197e12
+    ctx = made_up_v5e_ctx("dots3-ep32-1chip-steady", [
+        ("dsa_index_fwd.1", 2 * unit_s), ("dsa_index_fwd.7", 2 * unit_s),
+        ("dsa_index_bwd.3", 6 * unit_s), ("dsa_index_bwd_dq.4", 9 * unit_s),
+        ("fusion.2", unit_s)])
+    assert dots3_flops.read_index_roofline({}, ctx) == pytest.approx(50.0)
+    assert len(ctx.logged) == 2 and all(
+        "50.00 %" in line for line in ctx.logged)
+    # the forward alone, as a program without the backward kernel has it
+    ctx.trace.devices["d0"] = ctx.trace.devices["d0"][:2]
+    assert dots3_flops.read_index_roofline({}, ctx) == pytest.approx(50.0)
 
 
 def test_the_listed_metrics_are_this_cells_alone():
@@ -331,16 +352,23 @@ def test_the_listed_metrics_are_this_cells_alone():
     with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
         benchmark = json.load(f)
     ours = [m for m in benchmark["per_layer"] if m["name"].startswith("d3_")]
-    assert len(ours) == 14
+    assert [m["name"] for m in ours] == [
+        "d3_dsa_index_roofline", "d3_dsa_flash_roofline",
+        "d3_swa_flash_roofline"]
+    # shared readers that list this cell (or every cell) since PR 58, where
+    # they were copies under names of this cell's; the selection's four
+    # are keye-vl's too
+    assert {"dsa_index_ms", "dsa_select_ms", "dsa_flash_ms", "dsa_loss_ms",
+            "swa_flash_ms", "mla_proj_ms", "dense_mlp_ms", "moe_share_ms",
+            "moe_experts_ms", "moe_dispatch_ms", "embed_ms", "hbm_peak_gib",
+            "moe_live_rows", "live_rows_drift"} <= set(
+                cell_metrics("dots3-ep32-1chip-steady"))
     for m in ours:
         assert m["workloads"] == ["dots3-ep32-1chip-steady"], m["name"]
         spec = load_json("layer_metrics", m["name"] + ".json")
         assert (spec["unit"], spec["better"], spec["source"], spec["layer"],
                 spec["moves"]) == (m["unit"], m["better"], m["source"],
                                    m["layer"], m["moves"])
-    for m in benchmark["per_layer"]:
-        if not m["name"].startswith("d3_"):
-            assert "dots3-ep32-1chip-steady" not in m.get("workloads", ())
 
 
 def test_new_readers_report_nothing_without_their_kernels():
@@ -358,15 +386,15 @@ def test_new_readers_report_nothing_without_their_kernels():
     ctx.step_op_names = {"fusion.1": "jit(step)/add"}
     ctx.counters = {}
     for name in ("d3_dsa_flash_roofline", "d3_swa_flash_roofline",
-                 "d3_dsa_index_roofline", "d3_dsa_index_ms",
-                 "d3_dsa_select_ms", "d3_dsa_loss_ms", "d3_mla_proj_ms",
-                 "d3_moe_share_ms"):
+                 "d3_dsa_index_roofline", "dsa_index_ms",
+                 "dsa_select_ms", "dsa_loss_ms", "mla_proj_ms",
+                 "moe_share_ms"):
         spec = importlib.util.spec_from_file_location(
             name, os.path.join(BENCH, "layer_metrics", name + ".py"))
         module = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(module)
         assert module.read(
             load_json("layer_metrics", name + ".json"), ctx) is None
-    for name in ("d3_dsa_flash_ms", "d3_swa_flash_ms"):
+    for name in ("dsa_flash_ms", "swa_flash_ms"):
         assert readers.trace_ms_per_step(
             load_json("layer_metrics", name + ".json"), ctx) is None

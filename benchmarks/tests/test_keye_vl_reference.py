@@ -13,7 +13,8 @@ import types
 
 import pytest
 
-from conftest import BENCH, load_json, one_device_mesh
+from conftest import (BENCH, cell_metrics, load_json, made_up_v5e_ctx,
+                      one_device_mesh)
 
 from benchmarks.families import keye_vl as family
 from benchmarks.harness import dots3_flops, keye_vl_flops
@@ -23,8 +24,15 @@ LISTED = "keye-vl-2.0-30b-a3b-ep8-1chip.json"
 CELL = "keye-vl-ep8-1chip-steady"
 #: the cell's own per-layer metrics, in BENCHMARK.json's order
 READERS = ("kvl_dsa_index_roofline", "kvl_dsa_probs_roofline",
-           "kvl_dsa_flash_roofline", "kvl_moe_experts_roofline",
-           "kvl_hbm_peak_gib")
+           "kvl_dsa_flash_roofline", "kvl_moe_experts_roofline")
+#: shared readers that list this cell (or every cell) since PR 58: the
+#: memory metric, which was a copy under this cell's name, and what the
+#: full list of PR 54 had left to the traced run's log
+SHARED = ("hbm_peak_gib", "dsa_index_ms", "dsa_select_ms", "dsa_flash_ms",
+          "dsa_loss_ms", "attn_proj_ms", "moe_experts_ms", "moe_dispatch_ms",
+          "embed_ms", "moe_live_rows", "live_rows_drift", "build_lower_s",
+          "build_xla_s", "first_step_host_s", "step_dispatch_ms",
+          "trainer_idle_ms")
 
 
 def _ctx(cell_name="tiny-cpu-keye-vl-steady", seconds=0.5, seed=7):
@@ -265,7 +273,10 @@ def test_flops_of_the_listed_configuration():
         batch=1, seq=16384, heads=16, dim=64)
     probs = dots3_flops.probs_flops_per_call(
         batch=1, n_heads=32, qk_dim=128, pairs=pairs)
-    assert index["fwd"] == 2 * 16 * 134_225_920 * 64
+    # the two kernels that run: the forward one product, the one backward
+    # kernel (PR 57) the scores again, dQ and dK
+    assert index == {"fwd": 2 * 16 * 134_225_920 * 64,
+                     "bwd": 3 * 2 * 16 * 134_225_920 * 64}
     layer = (6.0 * (attention + expert) + 4.0 * indexer
              + (3 * sel["fwd"] + 3 * index["fwd"] + probs) / 16384)
     assert keye_vl_flops.flops_per_token(config, 16384) == pytest.approx(
@@ -273,32 +284,52 @@ def test_flops_of_the_listed_configuration():
     # the least bytes: the float32 array's causal half leads
     moved = keye_vl_flops.index_bytes_per_call(
         batch=1, seq=16384, heads=16, dim=64)
-    assert moved["fwd"] == 4 * 134_225_920 + 16384 * (2 * 1024 + 128 + 64)
+    rows = 16384 * (2 * 1024 + 128 + 64)    # q, k, w; dq, dk, dw as large
+    assert moved == {"fwd": 4 * 134_225_920 + rows,
+                     "bwd": 4 * 134_225_920 + 2 * rows}
     assert keye_vl_flops.probs_bytes_per_call(
         batch=1, seq=16384, heads=32, kv_heads=4, dim=128) == (
             5 * 134_225_920 + 16384 * (2 * 128 * 36 + 128))
+
+
+def test_the_index_roofline_reads_the_forward_and_the_one_backward():
+    """``kvl_dsa_index_roofline`` on a made-up trace of the listed cell
+    on a v5e: a forward and a backward call, each at exactly four times
+    its least time (FLOPs bind both at 16 x 64), read 25 %; the pair
+    PR 57 replaced is not matched."""
+    flops = dots3_flops.index_flops_per_call(
+        batch=1, seq=16384, heads=16, dim=64)
+    moved = keye_vl_flops.index_bytes_per_call(
+        batch=1, seq=16384, heads=16, dim=64)
+    least = {k: max(flops[k] / 197e12, moved[k] / 819e9) for k in flops}
+    assert all(least[k] == flops[k] / 197e12 for k in flops)
+    ctx = made_up_v5e_ctx(CELL, [
+        ("dsa_index_fwd.1", 4 * least["fwd"]),
+        ("dsa_index_bwd", 4 * least["bwd"]),
+        ("dsa_index_bwd_dk.4", 1.0), ("fusion.2", 0.5)])
+    assert keye_vl_flops.read_index_roofline({}, ctx) == pytest.approx(25.0)
+    assert sum("25.00 % of the bf16 peak" in line
+               for line in ctx.logged) == 2
 
 
 def test_the_listed_metrics_are_this_cells_alone():
     with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
         benchmark = json.load(f)
     ours = [m for m in benchmark["per_layer"] if m["name"].startswith("kvl_")]
-    # BENCHMARK.json holds 128 per-layer metrics at most and had 123
     assert [m["name"] for m in ours] == list(READERS)
-    assert len(benchmark["per_layer"]) <= 128
-    # the new entries close their lists
-    assert benchmark["per_layer"][-len(ours):] == ours
-    assert benchmark["workloads"][-1]["name"] == CELL
-    assert benchmark["configs"][-1]["file"] == "benchmarks/configs/" + LISTED
+    at = benchmark["per_layer"].index(ours[0])                  # one block
+    assert benchmark["per_layer"][at:at + len(ours)] == ours
+    assert set(SHARED) <= set(cell_metrics(CELL))
+    listed, = [w for w in benchmark["workloads"] if w["name"] == CELL]
+    held, = [c for c in benchmark["configs"]
+             if c["file"] == "benchmarks/configs/" + LISTED]
+    assert listed["config"] == held["name"]
     for m in ours:
         assert m["workloads"] == [CELL], m["name"]
         spec = load_json("layer_metrics", m["name"] + ".json")
         assert (spec["unit"], spec["better"], spec["source"], spec["layer"],
                 spec["moves"]) == (m["unit"], m["better"], m["source"],
                                    m["layer"], m["moves"])
-    for m in benchmark["per_layer"]:
-        if not m["name"].startswith("kvl_"):
-            assert CELL not in m.get("workloads", ())
     # no file of a metric that the list has no room for
     assert sorted(
         f[:-len(".json")] for f in os.listdir(
@@ -308,16 +339,14 @@ def test_the_listed_metrics_are_this_cells_alone():
 
 def test_new_readers_report_nothing_without_their_kernels():
     """On a program that lacks the kernels and scopes (the parent's), and
-    off the chip, the trace's readers return None and do not raise (the
-    fifth reads a gauge of the process, which an earlier test's step may
-    have set)."""
+    off the chip, the trace's readers return None and do not raise."""
     ctx = _ctx()
     ctx.trace = types.SimpleNamespace(
         devices={"d0": [(0.0, 10.0, "fusion.1", "")]}, spans=[(0, 10, "step")],
         window_ns=(0.0, 10.0))
     ctx.step_op_names = {"fusion.1": "jit(step)/add"}
     ctx.counters = {}
-    for name in READERS[:4]:
+    for name in READERS:
         spec = importlib.util.spec_from_file_location(
             name, os.path.join(BENCH, "layer_metrics", name + ".py"))
         module = importlib.util.module_from_spec(spec)

@@ -14,7 +14,7 @@ import types
 
 import pytest
 
-from conftest import BENCH, ROOT, load_json, one_device_mesh
+from conftest import BENCH, ROOT, cell_metrics, load_json, one_device_mesh
 
 from benchmarks.families import minicpm_sala as family
 from benchmarks.harness import minicpm_sala_flops as flops
@@ -26,10 +26,12 @@ METRICS = ("sala_lightning_ms", "sala_lightning_chunk_ms",
            "sala_lightning_chunk_roofline", "sala_blk_select_ms",
            "sala_blk_score_roofline", "sala_blk_flash_ms",
            "sala_blk_flash_roofline", "sala_attn_proj_ms",
-           "sala_dense_mlp_ms", "sala_embed_ms", "sala_hbm_peak_gib",
-           "sala_blk_live_tiles", "sala_blk_live_tiles_drift",
-           "sala_build_lower_s", "sala_build_xla_s", "sala_first_step_host_s",
-           "sala_step_dispatch_ms", "sala_trainer_idle_ms")
+           "sala_dense_mlp_ms", "sala_blk_live_tiles")
+#: readers every family shares, which list this cell (or every cell) since
+#: PR 58, where they were copies under names of this cell's
+SHARED = ("embed_ms", "hbm_peak_gib", "live_rows_drift", "build_lower_s",
+          "build_xla_s", "first_step_host_s", "step_dispatch_ms",
+          "trainer_idle_ms")
 
 
 def _ctx(cell_name="tiny-cpu-minicpm-sala-steady", seconds=0.5, seed=7):
@@ -176,7 +178,7 @@ def test_the_rehearsal_prints_a_well_formed_last_line():
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0
     # on the CPU no kernel runs and no roofline has a peak to read
-    for name in METRICS:
+    for name in METRICS + SHARED:
         if not name.endswith("_roofline") and name != "sala_blk_flash_ms":
             assert math.isfinite(line["metrics"][name]["value"]), name
     assert line["device"]["busy_s"] > 0
@@ -218,18 +220,19 @@ def test_the_listed_metrics_are_this_cells_alone():
         benchmark = json.load(f)
     ours = [m for m in benchmark["per_layer"] if m["name"].startswith("sala_")]
     assert tuple(m["name"] for m in ours) == METRICS
-    assert benchmark["per_layer"][-len(ours):] == ours      # appended
+    at = benchmark["per_layer"].index(ours[0])                  # one block
+    assert benchmark["per_layer"][at:at + len(ours)] == ours
+    assert set(SHARED) <= set(cell_metrics(CELL))
     for m in ours:
         assert m["workloads"] == [CELL], m["name"]
         spec = load_json("layer_metrics", m["name"] + ".json")
         assert (spec["unit"], spec["better"], spec["source"], spec["layer"],
                 spec["moves"]) == (m["unit"], m["better"], m["source"],
                                    m["layer"], m["moves"])
-    for m in benchmark["per_layer"]:
-        if not m["name"].startswith("sala_"):
-            assert CELL not in m.get("workloads", ())
-    assert benchmark["workloads"][-1]["name"] == CELL
-    assert benchmark["configs"][-1]["file"] == "benchmarks/configs/" + LISTED
+    listed, = [w for w in benchmark["workloads"] if w["name"] == CELL]
+    held, = [c for c in benchmark["configs"]
+             if c["file"] == "benchmarks/configs/" + LISTED]
+    assert listed["config"] == held["name"]
     cell = load_json("workloads", CELL + ".json")
     assert cell["params"] == dict(seq=16384, batch=1, save_every=0,
                                   trace_steps=5, reference_seq=16384)

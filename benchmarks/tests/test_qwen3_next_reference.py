@@ -15,7 +15,7 @@ import types
 
 import pytest
 
-from conftest import BENCH, ROOT, load_json, one_device_mesh
+from conftest import BENCH, ROOT, cell_metrics, load_json, one_device_mesh
 
 from benchmarks.families import qwen3_next as family
 from benchmarks.harness import qwen3_next_flops as flops
@@ -25,10 +25,11 @@ LISTED = "qwen3-next-80b-a3b-ep16-1chip.json"
 CELL = "qwen3next-ep16-1chip-steady"
 METRICS = ("q3n_gdn_ms", "q3n_gdn_chunk_ms", "q3n_gdn_chunk_roofline",
            "q3n_gattn_flash_ms", "q3n_gattn_flash_roofline",
-           "q3n_attn_proj_ms", "q3n_moe_share_ms", "q3n_moe_live_rows",
-           "q3n_moe_live_rows_drift", "q3n_hbm_peak_gib",
-           "q3n_moe_experts_ms", "q3n_moe_experts_roofline",
-           "q3n_moe_dispatch_ms", "q3n_embed_ms")
+           "q3n_attn_proj_ms", "q3n_moe_experts_roofline")
+#: readers every family shares, which list this cell (or every cell) since
+#: PR 58, where they were copies under names of this cell's
+SHARED = ("moe_share_ms", "moe_live_rows", "live_rows_drift", "hbm_peak_gib",
+          "moe_experts_ms", "moe_dispatch_ms", "embed_ms")
 
 
 def _ctx(cell_name="tiny-cpu-qwen3-next-steady", seconds=0.5, seed=7):
@@ -222,13 +223,13 @@ def test_a_wrong_backward_of_the_passes_fails_the_mixers_vjp_alone(
 
 
 def test_the_memory_metric_reads_the_compilers_plan_not_the_sum(monkeypatch):
-    """``q3n_hbm_peak_gib`` reads the compiler's own peak + code (the
-    chip's numbers of this cell's step), not ``hbm_peak_gib``'s sum,
+    """``hbm_peak_gib`` reads the compiler's own peak + code (the
+    chip's numbers of this cell's step), not ``memory_analysis()``'s sum,
     which counts 17.11 GiB on a chip of 15.75; a program without the
-    gauge (the parent) leaves the metric out."""
+    gauge leaves the metric out."""
     from benchmarks.harness import program_spans
 
-    spec = load_json("layer_metrics", "q3n_hbm_peak_gib.json")
+    spec = load_json("layer_metrics", "hbm_peak_gib.json")
     gauges = {"step.hbm_peak_bytes": 18375170560}
     monkeypatch.setattr(program_spans, "_program_table", lambda _: gauges)
     assert program_spans.gauge(spec, None) is None
@@ -266,7 +267,7 @@ def test_the_rehearsal_prints_a_well_formed_last_line():
     assert line["attempted"] > 0
     # a traced run's line carries the per-layer metrics; on the CPU no
     # flash kernel runs and no roofline has a peak to read
-    for name in METRICS:
+    for name in METRICS + SHARED:
         if not name.endswith("_roofline") and name != "q3n_gattn_flash_ms":
             assert math.isfinite(line["metrics"][name]["value"]), name
     assert line["device"]["busy_s"] > 0
@@ -323,18 +324,19 @@ def test_the_listed_metrics_are_this_cells_alone():
         benchmark = json.load(f)
     ours = [m for m in benchmark["per_layer"] if m["name"].startswith("q3n_")]
     assert tuple(m["name"] for m in ours) == METRICS
-    assert benchmark["per_layer"][-len(ours):] == ours      # appended
+    at = benchmark["per_layer"].index(ours[0])                  # one block
+    assert benchmark["per_layer"][at:at + len(ours)] == ours
+    assert set(SHARED) <= set(cell_metrics(CELL))
     for m in ours:
         assert m["workloads"] == [CELL], m["name"]
         spec = load_json("layer_metrics", m["name"] + ".json")
         assert (spec["unit"], spec["better"], spec["source"], spec["layer"],
                 spec["moves"]) == (m["unit"], m["better"], m["source"],
                                    m["layer"], m["moves"])
-    for m in benchmark["per_layer"]:
-        if not m["name"].startswith("q3n_"):
-            assert CELL not in m.get("workloads", ())
-    assert benchmark["workloads"][-1]["name"] == CELL
-    assert benchmark["configs"][-1]["file"] == "benchmarks/configs/" + LISTED
+    listed, = [w for w in benchmark["workloads"] if w["name"] == CELL]
+    held, = [c for c in benchmark["configs"]
+             if c["file"] == "benchmarks/configs/" + LISTED]
+    assert listed["config"] == held["name"]
     cell = load_json("workloads", CELL + ".json")
     assert cell["params"] == dict(seq=16384, batch=1, save_every=0,
                                   trace_steps=5, reference_seq=16384)
@@ -353,8 +355,8 @@ def test_new_readers_report_nothing_without_their_scopes():
     ctx.counters = {}
     for name in ("q3n_gdn_ms", "q3n_gdn_chunk_ms", "q3n_gdn_chunk_roofline",
                  "q3n_gattn_flash_roofline", "q3n_attn_proj_ms",
-                 "q3n_moe_experts_ms", "q3n_moe_experts_roofline",
-                 "q3n_moe_dispatch_ms", "q3n_embed_ms"):
+                 "moe_experts_ms", "q3n_moe_experts_roofline",
+                 "moe_dispatch_ms", "embed_ms"):
         spec = load_json("layer_metrics", name + ".json")
         path = os.path.join(BENCH, "layer_metrics", name + ".py")
         module_spec = importlib.util.spec_from_file_location(name, path)
