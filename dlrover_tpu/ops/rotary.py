@@ -60,17 +60,24 @@ def apply_rope(
     x: jnp.ndarray,          # (..., seq, n_heads, head_dim)
     positions: jnp.ndarray,  # (..., seq) int32 global positions
     inv_freq: jnp.ndarray,   # (rotary_dim // 2,)
+    magnitude: float = 1.0,
 ) -> jnp.ndarray:
     """Rotary on a head's first ``2 len(inv_freq)`` channels, their first
     half against their second; the whole head where that is its width,
     the channels past it unrotated (a partial rotary: Qwen3-Next turns a
-    quarter of a 256-wide head)."""
+    quarter of a 256-wide head). ``magnitude`` multiplies cos and sin, so
+    the turned channels alone (yarn's ``attention_factor`` where a config
+    states it as a number: Laguna's full layers)."""
     rotary_dim = 2 * inv_freq.shape[0]
     if rotary_dim < x.shape[-1]:
-        turned = apply_rope(x[..., :rotary_dim], positions, inv_freq)
+        turned = apply_rope(x[..., :rotary_dim], positions, inv_freq,
+                            magnitude)
         return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     angles = positions[..., :, None].astype(jnp.float32) * inv_freq  # (...,s,d/2)
-    return turn(x, jnp.cos(angles), jnp.sin(angles))
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if magnitude != 1.0:
+        cos, sin = cos * magnitude, sin * magnitude
+    return turn(x, cos, sin)
 
 
 def turn(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
