@@ -19,8 +19,10 @@ where ``lse`` is the per-row logsumexp of the attention logits:
 How the three kernels walk the (q, k) plane:
 
 - **tiles** come from `choose_tiles`: per kernel, from the sequence
-  lengths, ``head_dim``, the GQA group, the operand dtype and causal or
-  not, the largest that divide the sequences and fit ``_VMEM_BUDGET``.
+  lengths, ``head_dim``, the GQA group, the operand dtype and the
+  window, the largest that divide the sequences and fit
+  ``_VMEM_BUDGET``; under a window those whose walk of the band
+  computes least, a grid step counted as ``_STEP_PAIRS`` pairs.
   ``block_q=None, block_k=None`` on the public entry points means
   "choose"; there is no knob.
 - **GQA**: forward and dq take a kv head's whole group of query heads
@@ -203,9 +205,27 @@ _VMEM_BUDGET = 40 * 2**20
 #: within 5 % of their best from (1024, 512) up, and past these sides
 #: the area a causal call computes and masks away, about
 #: ``(block_q + block_k) / 2 / seq`` of it, outgrows the grid steps saved.
+#: A window call masks away about ``(block_q + block_k) / window``, at two
+#: edges: under one these are the largest sides offered, and
+#: `choose_tiles` takes the pair whose walk of the band costs least.
 _MAX_TILE = {"fwd": (2048, 512), "dq": (2048, 512), "dkv": (1024, 1024)}
 
 _KERNELS = tuple(_MAX_TILE)
+
+#: Under a window, what `choose_tiles` counts a grid step as, in pairs of
+#: the score plane: 0.39 us a step where a computed pair costs 4.9 us a
+#: million (the forward at group 1, where a step is small enough to show
+#: it; 0.1-0.6 us over the three kernels and the tiles of the sweep:
+#: docs/design/kernels.md 1b, PR 61, on the v5e).
+_STEP_PAIRS = 80_000
+
+#: Under a window, the narrowest ``block_k`` each kernel is offered.
+#: dq's products at 256 keys cost more a pair than the band's edges give
+#: back: 13 % slower at (256, 256) than at (256, 512) with a quarter
+#: fewer pairs (window 512, group 8), 9 % slower at window 4096. The
+#: forward at 128 keys costs a fifth more than at 256 by the same count
+#: of pairs and steps. dk/dv needs no floor but that count.
+_WINDOW_MIN_K = {"fwd": 256, "dq": 512, "dkv": 0}
 
 _STAT_LANES = 128  # running max / sum: every lane of a row holds it
 
@@ -262,20 +282,30 @@ def _tile_sides(s: int, cap: int, lanes_only: bool):
 
 
 def choose_tiles(kernel: str, sq: int, sk: int, head_dim: int, group: int,
-                 dtype, v_head_dim: Optional[int] = None
+                 dtype, v_head_dim: Optional[int] = None,
+                 window: Optional[int] = None
                  ) -> Optional[Tuple[int, int]]:
     """``(block_q, block_k)`` for one of the three kernels (``"fwd"``,
-    ``"dq"``, ``"dkv"``): the pair of largest area, among the sides
-    `_tile_sides` offers up to ``_MAX_TILE``, whose `_vmem_bytes` fit
-    ``_VMEM_BUDGET``. ``None`` if not even the smallest pair fits (a
+    ``"dq"``, ``"dkv"``), among the pairs of sides `_tile_sides` offers
+    up to ``_MAX_TILE`` whose `_vmem_bytes` fit ``_VMEM_BUDGET``: the
+    pair of largest area. ``None`` if not even the smallest pair fits (a
     sequence with no aligned divisor that is too long to be one block):
     the caller has the reference path. Causal or not does not enter: on
     the chip both want the same tiles. ``head_dim`` is the q/k head's
-    width, ``v_head_dim`` the v head's where it differs. Nor does a
-    window enter: a window call computes and masks away about
-    ``(block_q + block_k) / window`` of its work at the band's two
-    edges, and on the v5e at window 4096 smaller tiles cost as much in
-    grid steps as they saved there (docs/design/kernels.md, PR 37).
+    width, ``v_head_dim`` the v head's where it differs.
+
+    Under a ``window`` shorter than the sequence (of causal
+    self-attention: ``sq == sk``) the largest area is not the least
+    work: a call computes, and masks away, whatever of its visited
+    blocks lies outside the band, about ``(block_q + block_k) / window``
+    of it at the band's two edges. The pair is then the one whose walk
+    of the band costs least by `band_work`'s count: the pairs it
+    computes plus ``_STEP_PAIRS`` for every grid step, among the pairs
+    no narrower in ``block_k`` than ``_WINDOW_MIN_K`` (where the
+    sequence offers such a side). At a window of 4096 that is the pair
+    of largest area again; at 512 dk/dv takes (512, 512), where (1024,
+    1024) leaves a quarter of its pairs under the band, and the forward
+    at group 8 (256, 256) (docs/design/kernels.md 1b, PR 61).
 
     Short and awkward sequences come out as before there was a
     chooser: 8 and 64 as one block, 196 and 197 as one block, anything
@@ -290,27 +320,50 @@ def choose_tiles(kernel: str, sq: int, sk: int, head_dim: int, group: int,
         q_sides = _tile_sides(sq, max(max_rows // group, 128),
                               lanes_only=False)
     k_sides = _tile_sides(sk, max_k, lanes_only=False)
-    best = None
-    for bk in k_sides:
-        for bq in q_sides:
-            if _vmem_bytes(kernel, bq, bk, head_dim, group,
-                           itemsize, v_head_dim) > _VMEM_BUDGET:
-                continue
-            # largest area; of equals the squarer, then the wider block_k
-            if best is None or (bq * bk, min(bq, bk)) > (
-                    best[0] * best[1], min(best)):
-                best = (bq, bk)
-            break  # q_sides descend: smaller ones have smaller area
-    return best
+    # the sides descend, so of equals the first met has the wider block_k
+    fits = [(bq, bk) for bk in k_sides for bq in q_sides
+            if _vmem_bytes(kernel, bq, bk, head_dim, group, itemsize,
+                           v_head_dim) <= _VMEM_BUDGET]
+    if not fits:
+        return None
+    if window is None or window >= sq:
+        # largest area; of equals the squarer
+        return max(fits, key=lambda t: (t[0] * t[1], min(t)))
+    fits = [t for t in fits if t[1] >= _WINDOW_MIN_K[kernel]] or fits
+
+    def cost(tile):
+        work = band_work(kernel, sq, *tile, group, window)
+        return work["computed"] + _STEP_PAIRS * work["steps"]
+
+    # least cost; of equals the larger area
+    return min(fits, key=lambda t: (cost(t), -t[0] * t[1]))
+
+
+def band_work(kernel: str, s: int, block_q: int, block_k: int, group: int,
+              window: int) -> dict:
+    """What one kv head's walk of the band costs ``kernel`` at these
+    tiles, by `_band_blocks`' count: the pairs it ``computed`` (the
+    visited blocks x ``block_q`` x ``block_k``, every head of the
+    group), the ``band``'s own (``0 <= i - j < window``) and the grid's
+    ``steps``, the empty ones of the shorter sweeps included."""
+    n_q, n_k = s // block_q, s // block_k
+    blocks = _band_blocks("q" if kernel == "dkv" else "k", n_q, n_k,
+                          block_q, block_k, window)
+    heads = group if kernel == "dkv" else 1  # forward and dq: one operand
+    w = min(window, s)
+    return {"computed": group * sum(blocks) * block_q * block_k,
+            "band": group * (w * (w + 1) // 2 + (s - w) * w),
+            "steps": heads * len(blocks) * max(blocks)}
 
 
 def flash_tiles(sq: int, sk: int, head_dim: int, group: int, dtype,
-                v_head_dim: Optional[int] = None):
+                v_head_dim: Optional[int] = None,
+                window: Optional[int] = None):
     """``{kernel: (block_q, block_k)}`` for the three kernels of one
     call, or None if one of them has no tile that fits."""
     tiles = {
         kernel: choose_tiles(kernel, sq, sk, head_dim, group, dtype,
-                             v_head_dim)
+                             v_head_dim, window)
         for kernel in _KERNELS
     }
     return None if None in tiles.values() else tiles
@@ -329,16 +382,17 @@ def reset_tile_report():
     trace.gauge("attn.tile_fallback", 0)
 
 
-def _tiles_for(q, k, v, block_q, block_k):
+def _tiles_for(q, k, v, block_q, block_k, window: Optional[int] = None):
     """``{kernel: (block_q, block_k)}`` for this call: a pinned pair
     goes to all three kernels; None (both) is `flash_tiles`' choice,
-    made while the step is traced."""
+    made while the step is traced, from the call's effective window
+    too."""
     if block_q is not None or block_k is not None:
         assert block_q is not None and block_k is not None, (block_q, block_k)
         return dict.fromkeys(_KERNELS, (block_q, block_k))
     sq, h, d = q.shape[1:]
     sk, hkv = k.shape[1:3]
-    tiles = flash_tiles(sq, sk, d, h // hkv, q.dtype, v.shape[3])
+    tiles = flash_tiles(sq, sk, d, h // hkv, q.dtype, v.shape[3], window)
     if tiles is None:
         raise ValueError(
             f"flash attention: no tile of seq ({sq}, {sk}) at head_dim "
@@ -349,14 +403,30 @@ def _tiles_for(q, k, v, block_q, block_k):
     return tiles
 
 
-def _report_tiles(block_q: int, block_k: int, window: Optional[int] = None):
+#: matmuls a pair of each kernel: what weighs its computed pairs
+_PRODUCTS = {"fwd": 2, "dq": 3, "dkv": 4}
+
+
+def _report_tiles(tiles: dict, seq: int = 0, window: Optional[int] = None):
     """The gauges that say which tiling the job runs: the forward's own
-    chosen tiles (a window call's under names of their own), and the
-    count of call sites left at 128 or less."""
+    chosen tiles (a window call's under names of their own, with dk/dv's
+    beside them and the share of the pairs the three kernels compute,
+    weighted by their products, that lie under the band), and the count
+    of call sites left at 128 or less."""
     global _fallback_sites
+    block_q, block_k = tiles["fwd"]
     kind = "" if window is None else "window_"
     trace.gauge(f"attn.{kind}block_q", block_q)
     trace.gauge(f"attn.{kind}block_k", block_k)
+    if window is not None:
+        trace.gauge("attn.window_dkv_block_q", tiles["dkv"][0])
+        trace.gauge("attn.window_dkv_block_k", tiles["dkv"][1])
+        work = {kernel: band_work(kernel, seq, *tiles[kernel], 1, window)
+                for kernel in _KERNELS}
+        trace.gauge("attn.window_band_pct", round(
+            100 * sum(_PRODUCTS.values()) * work["fwd"]["band"]
+            / sum(n * work[kernel]["computed"]
+                  for kernel, n in _PRODUCTS.items()), 1))
     if max(block_q, block_k) <= 128:
         _fallback_sites += 1
         trace.gauge("attn.tile_fallback", _fallback_sites)
@@ -416,21 +486,28 @@ def _last_q_block(ki, block_q: int, block_k: int, n_q: int, window: int):
         (ki * block_k + block_k - 1 + window - 1) // block_q, n_q - 1)
 
 
+def _band_blocks(walk: str, n_q: int, n_k: int, block_q: int, block_k: int,
+                 window: int) -> list:
+    """The blocks a window call visits: of every q block its k blocks
+    (``walk="k"``: forward and dq), of every k block its q blocks
+    (``walk="q"``: dk/dv). The four edges above again, on Python ints."""
+    if walk == "k":
+        return [
+            min(((qi + 1) * block_q - 1) // block_k, n_k - 1)
+            - max(qi * block_q - (window - 1), 0) // block_k + 1
+            for qi in range(n_q)]
+    return [
+        min((ki * block_k + block_k - 1 + window - 1) // block_q, n_q - 1)
+        - min(ki * block_k // block_q, n_q - 1) + 1
+        for ki in range(n_k)]
+
+
 def _band_steps(walk: str, n_q: int, n_k: int, block_q: int, block_k: int,
                 window: int) -> int:
     """The length of a window call's inner grid axis: the most k blocks
-    one q block needs (``walk="k"``: forward and dq) or the most q
-    blocks one k block needs (``walk="q"``: dk/dv). The four edges above
-    again, on Python ints: a grid's length is static."""
-    if walk == "k":
-        return max(
-            min(((qi + 1) * block_q - 1) // block_k, n_k - 1)
-            - max(qi * block_q - (window - 1), 0) // block_k + 1
-            for qi in range(n_q))
-    return max(
-        min((ki * block_k + block_k - 1 + window - 1) // block_q, n_q - 1)
-        - min(ki * block_k // block_q, n_q - 1) + 1
-        for ki in range(n_k))
+    one q block needs or the most q blocks one k block needs
+    (`_band_blocks`); a grid's length is static."""
+    return max(_band_blocks(walk, n_q, n_k, block_q, block_k, window))
 
 
 def _when_needed(causal: bool, qi, ki, block_q: int, block_k: int):
@@ -1059,9 +1136,9 @@ def _flash_with_lse_fwd(q, k, v, causal, block_q, block_k, interpret,
     # (profiler/kernel_ledger.py classifies HLO sites by op_name path)
     with trace.scope("attention_fwd"):
         if interpret or _on_tpu():
-            tiles = _tiles_for(q, k, v, block_q, block_k)
+            tiles = _tiles_for(q, k, v, block_q, block_k, window)
             if block_q is None:
-                _report_tiles(*tiles["fwd"], window)
+                _report_tiles(tiles, q.shape[1], window)
             out, lse = _flash_fwd_pallas(q, k, v, causal, *tiles["fwd"],
                                          interpret=interpret, scale=scale,
                                          window=window)
@@ -1079,7 +1156,7 @@ def _flash_with_lse_bwd(causal, block_q, block_k, interpret, scale, window,
     window = _effective_window(q, k, causal, window)
     with trace.scope("attention_bwd"):
         if interpret or _on_tpu():
-            tiles = _tiles_for(q, k, v, block_q, block_k)
+            tiles = _tiles_for(q, k, v, block_q, block_k, window)
             return _flash_bwd_pallas(
                 q, k, v, o, lse, g_out, g_lse, causal,
                 tiles["dq"], tiles["dkv"], interpret=interpret, scale=scale,
@@ -1157,7 +1234,7 @@ def _flash_select_fwd(q, k, v, select, block_q, block_k, interpret, scale,
         if interpret or _on_tpu():
             tiles = _tiles_for(q, k, v, block_q, block_k)
             if block_q is None and select_block:
-                _report_tiles(*tiles["fwd"])
+                _report_tiles(tiles)
             elif block_q is None:
                 trace.gauge("attn.select_block_q", tiles["fwd"][0])
                 trace.gauge("attn.select_block_k", tiles["fwd"][1])

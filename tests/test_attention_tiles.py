@@ -57,6 +57,35 @@ def test_chosen_tiles_divide_and_fit(name, kernel):
         assert max(bq, bk) > 128 and min(bq, bk) >= 128, (bq, bk)
 
 
+#: what the chooser returned for those shapes before a window entered
+#: it (PR 60's tree): the same call still does
+_SQUARE = {"fwd": (512, 512), "dq": (512, 512), "dkv": (1024, 1024)}
+WITHOUT_A_WINDOW = {
+    "mistral7b-d5-steady": _SQUARE,
+    "mistral7b-d20-fsdp4-steady": _SQUARE,
+    "chip_smoke llama-3-8b": _SQUARE,
+    "ring chunk": _SQUARE,
+    "ring chunk, group 1": dict(_SQUARE, fwd=(1024, 512), dq=(1024, 512)),
+    "vit-b/16 patches": dict.fromkeys(_SQUARE, (196, 196)),
+    "vit-b/16 patches + cls": dict.fromkeys(_SQUARE, (197, 197)),
+    "cpu tests, seq 8": dict.fromkeys(_SQUARE, (8, 8)),
+    "cpu tests, seq 64": dict.fromkeys(_SQUARE, (64, 64)),
+    "head_dim 64, mha": dict(_SQUARE, fwd=(2048, 512), dq=(2048, 512)),
+    "group 8 (llama-3-70b)": dict(_SQUARE, fwd=(256, 512), dq=(256, 512)),
+    "f32 at the cell's shape": _SQUARE,
+    "seq 1000: aligned to 8 only": {
+        "fwd": (200, 1000), "dq": (200, 1000), "dkv": (1000, 1000)},
+}
+
+
+@pytest.mark.parametrize("name", list(SHAPES))
+def test_without_a_window_the_tiles_are_what_they_were(name):
+    s, d, group, dtype, _ = SHAPES[name]
+    assert flash_tiles(s, s, d, group, dtype) == WITHOUT_A_WINDOW[name]
+    for kernel, pair in WITHOUT_A_WINDOW[name].items():
+        assert choose_tiles(kernel, s, s, d, group, dtype, window=None) == pair
+
+
 def test_a_larger_group_takes_a_shorter_q_block():
     """The group's heads share the q tile's rows: at the same budget a
     group of 8 gets half the block_q a group of 4 gets."""

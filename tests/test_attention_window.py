@@ -13,6 +13,8 @@ from dlrover_tpu.observability import trace
 from dlrover_tpu.ops import attention
 from dlrover_tpu.ops.attention import (
     _band_steps,
+    band_work,
+    choose_tiles,
     flash_attention,
     flash_attention_with_lse,
     flash_tiles,
@@ -171,11 +173,16 @@ def test_the_band_walk_is_as_long_as_the_band():
 
 @pytest.mark.parametrize("seq,bq,bk,window", [
     (16384, 256, 512, 4096), (16384, 1024, 1024, 4096), (512, 64, 128, 100),
-    (512, 128, 64, 1), (256, 64, 64, 256), (384, 128, 64, 130)])
+    (512, 128, 64, 1), (256, 64, 64, 256), (384, 128, 64, 130),
+    # the tiles a narrow window gets (PR 61): at the cells' lengths and at
+    # the length the kernels are held to the reference below
+    (16384, 256, 256, 512), (16384, 512, 512, 512), (8192, 512, 512, 513),
+    (2048, 256, 256, 512), (2048, 512, 512, 512), (2048, 512, 512, 513)])
 def test_the_band_walk_covers_every_blocks_edges(seq, bq, bk, window):
     """`_band_steps` (Python ints: a grid's length is static) against the
     four edges the kernels and the index maps compute: no block needs
-    more steps than the grid has, and one needs them all."""
+    more steps than the grid has, and one needs them all; `band_work`
+    counts the same blocks."""
     n_q, n_k = seq // bq, seq // bk
     qi, ki = np.arange(n_q), np.arange(n_k)
     k_need = (np.asarray(attention._last_k_block(qi, bq, bk, n_k))
@@ -185,6 +192,35 @@ def test_the_band_walk_covers_every_blocks_edges(seq, bq, bk, window):
     assert _band_steps("k", n_q, n_k, bq, bk, window) == k_need.max()
     assert _band_steps("q", n_q, n_k, bq, bk, window) == q_need.max()
     assert k_need.min() >= 1 and q_need.min() >= 1
+    band = sum(min(i + 1, window) for i in range(seq))
+    for kernel, need, steps in (("fwd", k_need, n_q * k_need.max()),
+                                ("dkv", q_need, n_k * 3 * q_need.max())):
+        assert band_work(kernel, seq, bq, bk, 3, window) == {
+            "computed": 3 * int(need.sum()) * bq * bk, "band": 3 * band,
+            "steps": steps}
+
+
+@pytest.mark.parametrize("window,tiles,d,dv", [
+    (512, (512, 512), 32, 32), (512, (256, 256), 32, 32),
+    (513, (512, 512), 64, 32)])
+def test_the_tiles_of_a_narrow_window_against_the_reference(
+        window, tiles, d, dv):
+    """The pairs `choose_tiles` gives a band of 512 (PR 61), pinned here
+    at a length tier-1 has time for: out, lse, dq, dk, dv and the lse
+    cotangent, at one head width and at two."""
+    q, k, v = _qkv(s=2048, h=2, hkv=1, d=d, dv=dv, seed=12)
+    keys = jax.random.split(jax.random.key(13), 2)
+    w_out = jax.random.normal(keys[0], (1, 2048, 2, dv))
+    w_lse = jax.random.normal(keys[1], (1, 2, 2048))
+    want = _with_lse(
+        lambda q, k, v: mha_reference_with_lse(q, k, v, window=window),
+        q, k, v, w_out, w_lse)
+    got = _with_lse(
+        lambda q, k, v: flash_attention_with_lse(
+            q, k, v, True, *tiles, True, None, window),
+        q, k, v, w_out, w_lse)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
 
 
 def test_the_kernels_are_named_apart(monkeypatch):
@@ -205,20 +241,78 @@ def test_the_kernels_are_named_apart(monkeypatch):
         "attention_fwd_swa", "attention_bwd_dq_swa", "attention_bwd_dkv_swa"]
 
 
+#: (seq, q/k width, v width, group, window): the cells that pass a window
+#: and the pairs the chooser gives them (docs/design/kernels.md 1b, PR 61)
+CELLS = {
+    # a band of eight tiles: the pairs of largest area, as without one
+    "smallthinker": ((16384, 128, 128, 7, 4096), {
+        "fwd": (256, 512), "dq": (256, 512), "dkv": (1024, 1024)}),
+    "laguna": ((16384, 128, 128, 8, 512), {
+        "fwd": (256, 256), "dq": (256, 512), "dkv": (512, 512)}),
+    "dots3": ((8192, 256, 128, 1, 513), dict.fromkeys(
+        ("fwd", "dq", "dkv"), (512, 512))),
+}
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_the_window_enters_the_tile_choice(cell, kernel):
+    (s, d, dv, group, window), tiles = CELLS[cell]
+    bq, bk = choose_tiles(kernel, s, s, d, group, BF16, dv, window)
+    assert (bq, bk) == tiles[kernel]
+    assert s % bq == 0 and s % bk == 0
+    assert attention._vmem_bytes(
+        kernel, bq, bk, d, group, 2, dv) <= attention._VMEM_BUDGET
+    assert flash_tiles(s, s, d, group, BF16, dv, window)[kernel] == (bq, bk)
+    # no window, or one no shorter than the sequence: the causal call's
+    rows = (2048, 512) if group == 1 else (256, 512)
+    causal = {"fwd": rows, "dq": rows, "dkv": (1024, 1024)}[kernel]
+    assert choose_tiles(kernel, s, s, d, group, BF16, dv) == causal
+    assert choose_tiles(kernel, s, s, d, group, BF16, dv, s) == causal
+
+
+@pytest.mark.parametrize("s,window", [(128, 16), (64, 9), (256, 1)])
+def test_a_window_narrower_than_every_side_takes_what_is_offered(s, window):
+    # the tiny CPU configurations: one side is offered, or the step a
+    # second block would cost outweighs the pairs it spares
+    assert flash_tiles(s, s, 32, 2, jnp.float32, window=window) == (
+        dict.fromkeys(("fwd", "dq", "dkv"), (s, s)))
+
+
 def test_tiles_and_their_gauges_under_a_window():
     # the smallthinker cell's layers, window or none: group 7, 16384
-    # positions (a window does not enter the choice: kernels.md, PR 37)
-    assert flash_tiles(16384, 16384, 128, 7, BF16) == {
-        "fwd": (256, 512), "dq": (256, 512), "dkv": (1024, 1024)}
+    # positions (at a window of 4096 the pairs of largest area cost
+    # least: kernels.md, PRs 37 and 61)
+    for window in (None, 4096):
+        assert flash_tiles(16384, 16384, 128, 7, BF16, window=window) == {
+            "fwd": (256, 512), "dq": (256, 512), "dkv": (1024, 1024)}
     # a window call reports its tiles under names of its own
     q, k, v = _qkv(s=256)
     trace.gauge("attn.block_q", -1)
     flash_attention(q, k, v, True, interpret=True, window=64)
     g = trace.gauges()
     assert (g["attn.window_block_q"], g["attn.window_block_k"]) == (256, 256)
+    assert (g["attn.window_dkv_block_q"],
+            g["attn.window_dkv_block_k"]) == (256, 256)
+    # one block of 256 x 256 a kernel for a band of 64
+    assert g["attn.window_band_pct"] == round(
+        100 * (64 * 65 // 2 + 192 * 64) / 256 ** 2, 1)
     assert g["attn.block_q"] == -1
     flash_attention(q, k, v, True, interpret=True)
     assert trace.gauges()["attn.block_q"] == 256
+
+
+@pytest.mark.parametrize("tiles,pct", [
+    # Laguna's window layers at the causal call's tiles, and at theirs
+    (CELLS["smallthinker"][1], 35.0), (CELLS["laguna"][1], 52.9)])
+def test_the_gauge_of_the_pairs_under_the_band(tiles, pct):
+    attention._report_tiles(tiles, 16384, 512)
+    g = trace.gauges()
+    assert g["attn.window_band_pct"] == pct
+    assert (g["attn.window_dkv_block_q"],
+            g["attn.window_dkv_block_k"]) == tiles["dkv"]
+    assert (g["attn.window_block_q"],
+            g["attn.window_block_k"]) == tiles["fwd"]
 
 
 @pytest.mark.parametrize("kw", [

@@ -139,16 +139,12 @@ def test_flash_olmoe_cell_compiles_at_chosen_tiles(
     assert hlo.count("tpu_custom_call") == 3  # fwd, dq, dk/dv
 
 
-# smallthinker-ep4-1chip-steady (PR 37): 28 query heads on 4 kv heads of
-# 128 (group 7) at 16384 positions, the full layers' causal kernels and
-# the window layers' (window 4096: the band's walk, kernels named _swa),
-# at the tiles the kernels choose
-@pytest.mark.parametrize("window", [None, 4096])
-def test_flash_smallthinker_cell_compiles_at_chosen_tiles(
-        one_chip, kernels_are_the_path, window):
-    q = jax.ShapeDtypeStruct((1, 16384, 28, 128), jnp.bfloat16,
+def _grouped_call_compiles(one_chip, heads, kv_heads, window):
+    """A call of 128-wide heads at 16384 positions under `jax.grad`, at
+    the tiles the kernels choose: three kernels, named by the window."""
+    q = jax.ShapeDtypeStruct((1, 16384, heads, 128), jnp.bfloat16,
                              sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((1, 16384, 4, 128), jnp.bfloat16,
+    kv = jax.ShapeDtypeStruct((1, 16384, kv_heads, 128), jnp.bfloat16,
                               sharding=one_chip)
 
     def loss(q, k, v):
@@ -161,6 +157,34 @@ def test_flash_smallthinker_cell_compiles_at_chosen_tiles(
     for name in ("attention_fwd", "attention_bwd_dq", "attention_bwd_dkv"):
         assert re.search(rf"%{name}{suffix}(\.\d+)? = ", hlo), name
     assert ("_swa" in hlo) == bool(window)
+
+
+def _window_tiles():
+    g = trace.gauges()
+    return tuple((g[f"attn.window_{kernel}block_q"],
+                  g[f"attn.window_{kernel}block_k"])
+                 for kernel in ("", "dkv_"))
+
+
+# smallthinker-ep4-1chip-steady (PR 37): 28 query heads on 4 kv heads of
+# 128 (group 7) at 16384 positions, the full layers' causal kernels and
+# the window layers' (window 4096: the band's walk, kernels named _swa),
+# at the tiles the kernels choose: a band of eight tiles moves none
+@pytest.mark.parametrize("window", [None, 4096])
+def test_flash_smallthinker_cell_compiles_at_chosen_tiles(
+        one_chip, kernels_are_the_path, window):
+    _grouped_call_compiles(one_chip, 28, 4, window)
+    if window:
+        assert _window_tiles() == ((256, 512), (1024, 1024))
+
+
+# laguna-xs2-ep8-1chip-steady's window layers (PR 60): 64 query heads on
+# 8 (group 8), a window of 512, whose tiles the window narrows (PR 61)
+def test_flash_laguna_window_layers_compile_at_chosen_tiles(
+        one_chip, kernels_are_the_path):
+    _grouped_call_compiles(one_chip, 64, 8, 512)
+    assert _window_tiles() == ((256, 256), (512, 512))
+    assert trace.gauges()["attn.window_band_pct"] == 52.9
 
 
 # dots3-ep32-1chip-steady (PR 40): b1, s8192. A full layer's 32 held
@@ -188,6 +212,9 @@ def test_flash_dots3_cell_compiles_at_chosen_tiles(
     suffix = "_sel" if kind == "select" else "_swa"
     for name in ("attention_fwd", "attention_bwd_dq", "attention_bwd_dkv"):
         assert re.search(rf"%{name}{suffix}(\.\d+)? = ", hlo), name
+    if kind == "window":  # a band of 513 in tiles of 512 (PR 61)
+        assert _window_tiles() == ((512, 512), (512, 512))
+        assert trace.gauges()["attn.window_band_pct"] == 50.1
     # the selection is one byte a pair: never widened to a tensor a head
     assert "s8[1,8192,8192]" in hlo or kind == "window"
     assert not re.search(r"\[1,32,8192,8192\]|\[1,8192,8192,32\]", hlo)

@@ -19,7 +19,7 @@ from tests.chip_compile import (  # noqa: F401  (fixtures by import)
 # to the byte. Depth 8, 1 x 16384, every block keeping its flash output
 # and lse (the ladder's first rung, ISSUE 60). Some slack may be added to
 # it, no more.
-LAGUNA_STEP_PLANNED_PEAK = 14452886528
+LAGUNA_STEP_PLANNED_PEAK = 14450692096
 
 
 def test_laguna_step_fits_the_chip_with_the_flash_kernels_at_both_shapes(
@@ -71,6 +71,13 @@ def test_laguna_step_fits_the_chip_with_the_flash_kernels_at_both_shapes(
     assert (gauges["attn.group_full"], gauges["attn.group_window"]) == (6, 8)
     assert (gauges["attn.window"], gauges["attn.gate"]) == (512, 1)
     assert gauges["attn.out_kept"] == 1
+    # the window layers' tiles are the window's (PR 61), the full layers'
+    # the causal call's
+    assert (gauges["attn.window_block_q"], gauges["attn.window_block_k"],
+            gauges["attn.window_dkv_block_q"],
+            gauges["attn.window_dkv_block_k"]) == (256, 256, 512, 512)
+    assert gauges["attn.window_band_pct"] == 52.9
+    assert (gauges["attn.block_q"], gauges["attn.block_k"]) == (256, 512)
     assert (gauges["rotary.dims_full"], gauges["rotary.dims_window"]) == (
         64, 128)
     assert (gauges["moe.experts"], gauges["moe.experts_held"],
