@@ -384,7 +384,7 @@ def attention(cfg: KeyeVLConfig, mesh, tables, lp: Params, y,
         *projections(cfg, tables, lp, y), cfg.index_topk, cfg.softmax_scale,
         interpret=interpret, mesh=mesh)
     # under the prefix the jobs' `gauges:` line prints
-    for name in ("select_kernel", "index_bwd_kernels"):
+    for name in ("select_kernel", "index_bwd_kernels", "probs_heads_a_trip"):
         trace.gauge("attn." + name, trace.gauges().get("dsa." + name, 0))
     with trace.scope("attn_proj"):
         return (out.reshape(b, s, -1) @ lp["wo"].astype(cfg.dtype), l_i,

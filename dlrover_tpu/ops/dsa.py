@@ -43,9 +43,11 @@ probabilities summed over heads. What this file computes:
   `select_threshold`'s passes as XLA ops; the same mask bit for bit.
 - `head_summed_probs`: ``p[t, s] = sum_h exp(scale q_h[t] . k_h[s] -
   lse[t, h])`` over the selected pairs, from q, k, the flash forward's
-  ``lse`` and the mask; a Pallas kernel on the TPU (``dsa_probs``: heads
-  innermost, the ``(block_q, block_k)`` float32 tile accumulated in its
-  output block), not differentiated.
+  ``lse`` and the mask; a Pallas kernel on the TPU (``dsa_probs``: a
+  grid step is a (query block, key block) tile as the index kernels'
+  is, every head's q block resident for a row of tiles, the heads a
+  loop inside it several a trip, their sum masked and written once a
+  tile), not differentiated.
 - `indexer_loss`: the KL, summed over the rows (XLA ops over ``(s, s)``);
   its forward also forms the gradient with respect to the scores, the
   one array its backward reads (a ``custom_vjp``).
@@ -82,6 +84,7 @@ from dlrover_tpu.ops.attention import (
     _VMEM_LIMIT,
     _dot,
     _last_k_block as _last_k,
+    _round_up,
     flash_attention,
 )
 from dlrover_tpu.ops.kda import _over_batch_rows
@@ -661,57 +664,111 @@ def _probs_xla(q, k, lse, mask, scale: float):
     return jnp.moveaxis(out, 0, 1).reshape(b, s, s)
 
 
-def _probs_kernel(q_ref, k_ref, lse_ref, m_ref, o_ref, *, bq: int, bk: int,
-                  scale: float):
-    qi, ki, hi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+#: query heads a trip of `dsa_probs`' head loop, tried on the v5e at both
+#: cells' shapes (PERF.md section 6, PR 62): a head's chain of product,
+#: scale, subtract, ``exp`` and add is lighter than the index backward's,
+#: and eight a trip run 9 % under four (16 and 32 win 2 and 7 % more of
+#: the kernel for 1.5 and 3 s of compile)
+_PROBS_HEADS_A_TRIP = 8
 
-    @pl.when(hi == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
+
+def _probs_trip(heads: int) -> int:
+    return math.gcd(heads, _PROBS_HEADS_A_TRIP)
+
+
+def _probs_tiles(s: int, h: int, hkv: int, d: int,
+                 itemsize: int) -> Tuple[int, int]:
+    """``(block_q, block_k)`` of `dsa_probs`: the sides up to
+    ``_MAX_TILE["probs"]`` whose resident blocks fit `_VMEM_BUDGET`, the
+    tallest q block first (every key head's block is fetched a tile,
+    every query head's a row of tiles), then the widest k block."""
+    def held(bq, bk):
+        tile = bq * bk * 4
+        # a head's chain runs a vreg at a time: beside the sum no
+        # temporary of a tile's size is kept (the compiler's own count
+        # is 13 MiB at keye-vl's shape: `tests/test_chip_compile.py`)
+        return (2 * (h * bq + hkv * bk) * _round_up(d, 128) * itemsize  # q, k
+                + 2 * bq * _round_up(h, 128) * 4             # lse
+                + 2 * bq * bk + 2 * tile                     # mask, output
+                + 2 * tile)                    # the sum, and one to spare
+
+    top_q, top_k = _tiles("probs", s)
+    sides = [(bq, bk) for bq in range(top_q, 0, -128) if s % bq == 0
+             for bk in range(top_k, 0, -128) if s % bk == 0]
+    return next((t for t in sides if held(*t) <= _VMEM_BUDGET), sides[-1])
+
+
+def _probs_kernel(q_ref, k_ref, lse_ref, m_ref, o_ref, acc_ref, *, bq: int,
+                  bk: int, group: int, scale: float):
+    qi, ki = pl.program_id(1), pl.program_id(2)
+    heads = q_ref.shape[1]
 
     @pl.when(ki * bk <= qi * bq + bq - 1)
     def _compute():
-        logits = _dot(q_ref[0, 0], k_ref[0, 0], _NT) * scale
-        p = jnp.exp(logits - lse_ref[0, 0][:, :1])
-        seen = m_ref[0].astype(jnp.int32) != 0
-        o_ref[0] = o_ref[0] + jnp.where(seen, p, 0.0)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        def head(j):
+            # the head's column of the rows' (bq, heads) lse: a masked
+            # lane sum, on units the products leave idle
+            of_head = lax.broadcasted_iota(
+                jnp.int32, lse_ref.shape[1:], 1) == j
+            lse = jnp.sum(
+                jnp.where(of_head, lse_ref[0], 0.0), axis=1, keepdims=True)
+            logits = _dot(q_ref[0, j], k_ref[0, j // group], _NT) * scale
+            acc_ref[...] = acc_ref[...] + jnp.exp(logits - lse)
+
+        a_trip = _probs_trip(heads)
+
+        def trip(i, carry):
+            for u in range(a_trip):
+                head(i * a_trip + u)
+            return carry
+
+        lax.fori_loop(0, heads // a_trip, trip, 0)
+        # what the mask hides of a head may be inf, never NaN: selected
+        # away once a tile, after the sum
+        o_ref[0] = jnp.where(
+            m_ref[0].astype(jnp.int32) != 0, acc_ref[...], 0.0)
+
+    @pl.when(ki * bk > qi * bq + bq - 1)
+    def _above():
+        o_ref[0] = jnp.zeros((bq, bk), jnp.float32)
 
 
 def _probs_pallas(q, k, lse, mask, scale: float, interpret: bool):
+    """One grid step a (query block, key block) tile, as the index
+    kernels': every head's q block and the rows' ``lse`` stay in VMEM
+    for a row of tiles, every key head's k block comes a tile, and the
+    heads are a loop inside, `_PROBS_HEADS_A_TRIP` a trip, summed in a
+    scratch that is masked and written once a tile."""
     b, s, h, d = q.shape
-    group = h // k.shape[2]
-    bq, bk = _tiles("probs", s)
+    hkv = k.shape[2]
+    bq, bk = _probs_tiles(s, h, hkv, d, q.dtype.itemsize)
     n_q, n_k = s // bq, s // bk
 
     def k_index(qi, ki):
         return jnp.minimum(ki, _last_k(qi, bq, bk, n_k))
 
-    def k_head(hi):
-        # the heads are the innermost grid axis: a group's query heads
-        # follow one another and their key block is fetched once
-        return hi if group == 1 else hi // group
-
     return pl.pallas_call(
-        functools.partial(_probs_kernel, bq=bq, bk=bk, scale=scale),
-        grid=(b, n_q, n_k, h),
+        functools.partial(
+            _probs_kernel, bq=bq, bk=bk, group=h // hkv, scale=scale),
+        grid=(b, n_q, n_k),
         in_specs=[
-            pl.BlockSpec((1, 1, bq, d),
-                         lambda bi, qi, ki, hi: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda bi, qi, ki, hi: (
-                bi, k_head(hi), k_index(qi, ki), 0)),
-            pl.BlockSpec((1, 1, bq, _LANES),
-                         lambda bi, qi, ki, hi: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, h, bq, d), lambda bi, qi, ki: (bi, 0, qi, 0)),
+            pl.BlockSpec((1, hkv, bk, d), lambda bi, qi, ki: (
+                bi, 0, k_index(qi, ki), 0)),
+            pl.BlockSpec((1, bq, h), lambda bi, qi, ki: (bi, qi, 0)),
             pl.BlockSpec((1, bq, bk),
-                         lambda bi, qi, ki, hi: (bi, qi, k_index(qi, ki))),
+                         lambda bi, qi, ki: (bi, qi, k_index(qi, ki))),
         ],
-        out_specs=pl.BlockSpec(
-            (1, bq, bk), lambda bi, qi, ki, hi: (bi, qi, ki)),
+        out_specs=pl.BlockSpec((1, bq, bk), lambda bi, qi, ki: (bi, qi, ki)),
         out_shape=jax.ShapeDtypeStruct((b, s, s), jnp.float32),
-        compiler_params=_params(
-            "parallel", "parallel", "parallel", "arbitrary"),
+        scratch_shapes=[pltpu.VMEM((bq, bk), jnp.float32)],
+        compiler_params=_params("parallel", "parallel", "parallel"),
         interpret=interpret,
         name="dsa_probs",
-    )(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), _lanes(lse), mask)
+    )(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+      lse.transpose(0, 2, 1), mask)
 
 
 def head_summed_probs(q, k, lse, mask, scale: float, *,
@@ -729,7 +786,11 @@ def head_summed_probs(q, k, lse, mask, scale: float, *,
             "evenly")
     q, k, lse = (lax.stop_gradient(a) for a in (q, k, lse))
     scale = float(scale)
-    if not (interpret or _on_tpu()):
+    kernels = interpret or _on_tpu()
+    # heads a trip of the kernel's loop; 0: XLA's form
+    trace.gauge("dsa.probs_heads_a_trip",
+                _probs_trip(q.shape[2]) if kernels else 0)
+    if not kernels:
         return _probs_xla(q, k, lse, mask, scale)
     return _over_batch(
         lambda q, k, lse, mask: _probs_pallas(

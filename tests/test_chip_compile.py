@@ -242,6 +242,25 @@ def test_dsa_index_kernels_compile_at_the_cell_shape(
     assert not re.search(r"8192,8192,64\]|8192,64,8192\]|64,8192,8192\]", hlo)
 
 
+def _least_vmem_limit(monkeypatch, fn, *shapes):
+    """``(the compiled text, the scoped-VMEM limit in bytes it first
+    compiled under)``: the limit follows the compiler's refusals up
+    from 8 MiB."""
+    limit = 8 * 2**20
+    for _ in range(6):
+        monkeypatch.setattr(dsa, "_VMEM_LIMIT", limit)
+        try:
+            # a function of its own a limit: a jit's trace is cached by
+            # its function, and the limit is read while it is traced
+            return _compile(lambda *a: fn(*a), *shapes), limit
+        except Exception as refused:
+            reached = re.search(
+                r"Scoped allocation with size ([\d.]+)M", str(refused))
+            assert reached, refused
+            limit = math.ceil(float(reached.group(1))) * 2**20
+    pytest.fail("the compiler refused six limits in a row")
+
+
 # The fused backward (PR 57) holds the key's whole gradient, (s, d)
 # float32, in VMEM beside its blocks and the accumulators a head. What it
 # takes is read from the compiler: under a limit too small the refusal
@@ -255,37 +274,30 @@ def test_dsa_index_bwd_stands_under_the_vmem_limit_at_the_cells_shapes(
     k = jax.ShapeDtypeStruct((1, s, d), jnp.bfloat16, sharding=one_chip)
     w = jax.ShapeDtypeStruct((1, s, h), jnp.float32, sharding=one_chip)
     g = jax.ShapeDtypeStruct((1, s, s), jnp.float32, sharding=one_chip)
-    limit = 8 * 2**20
-    for _ in range(6):
-        monkeypatch.setattr(dsa, "_VMEM_LIMIT", limit)
-        try:
-            hlo = _compile(
-                lambda *a: dsa._index_bwd_pallas(*a, False), q, k, w, g)
-            break
-        except Exception as refused:
-            reached = re.search(
-                r"Scoped allocation with size ([\d.]+)M", str(refused))
-            assert reached, refused
-            limit = math.ceil(float(reached.group(1))) * 2**20
-    else:
-        pytest.fail("the compiler refused six limits in a row")
+    hlo, limit = _least_vmem_limit(
+        monkeypatch, lambda *a: dsa._index_bwd_pallas(*a, False), q, k, w, g)
     assert hlo.count("tpu_custom_call") == 1
     print(f"dsa_index_bwd at {(s, h, d)} compiles in {limit >> 20} MiB")
     assert 8 * 2**20 < limit < attention._VMEM_LIMIT
 
 
 def test_dsa_probs_kernel_compiles_at_the_cell_shape(
-        one_chip, kernels_are_the_path):
+        one_chip, kernels_are_the_path, monkeypatch):
     q = jax.ShapeDtypeStruct((1, 8192, 32, 192), jnp.bfloat16,
                              sharding=one_chip)
     lse = jax.ShapeDtypeStruct((1, 32, 8192), jnp.float32, sharding=one_chip)
     mask = jax.ShapeDtypeStruct((1, 8192, 8192), jnp.int8,
                                 sharding=one_chip)
-    hlo = _compile(
-        lambda q, k, lse, mask: dsa.head_summed_probs(
+    hlo, limit = _least_vmem_limit(
+        monkeypatch, lambda q, k, lse, mask: dsa.head_summed_probs(
             q, k, lse, mask, 192 ** -0.5), q, q, lse, mask)
     assert hlo.count("tpu_custom_call") == 1 and "dsa_probs" in hlo
     assert not re.search(r"32,8192,8192\]|8192,8192,32\]", hlo)
+    # every head's q block and every key head's k block are resident
+    # (PR 62): what that takes, read as `dsa_index_bwd`'s is
+    print(f"dsa_probs at {q.shape} on {q.shape} compiles in "
+          f"{limit >> 20} MiB")
+    assert 8 * 2**20 < limit < attention._VMEM_LIMIT
 
 
 # The threshold of both cells that select (PR 55): one kernel whose grid
@@ -349,7 +361,7 @@ def test_dsa_index_kernels_compile_at_sixteen_heads_of_64(
 
 
 def test_dsa_probs_kernel_reads_grouped_keys_where_they_lie(
-        one_chip, kernels_are_the_path):
+        one_chip, kernels_are_the_path, monkeypatch):
     q = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16,
                              sharding=one_chip)
     k = jax.ShapeDtypeStruct((1, 16384, 4, 128), jnp.bfloat16,
@@ -358,14 +370,17 @@ def test_dsa_probs_kernel_reads_grouped_keys_where_they_lie(
                                sharding=one_chip)
     mask = jax.ShapeDtypeStruct((1, 16384, 16384), jnp.int8,
                                 sharding=one_chip)
-    hlo = _compile(
-        lambda q, k, lse, mask: dsa.head_summed_probs(
+    hlo, limit = _least_vmem_limit(
+        monkeypatch, lambda q, k, lse, mask: dsa.head_summed_probs(
             q, k, lse, mask, 128 ** -0.5), q, k, lse, mask)
     assert hlo.count("tpu_custom_call") == 1 and "dsa_probs" in hlo
     # the key goes in at its 4 heads: nothing of it at 32
     assert not re.search(r"bf16\[1,(32,16384|16384,32),128\][^\n]*broadcast",
                          hlo)
     assert not re.search(r"32,16384,16384\]|16384,16384,32\]", hlo)
+    print(f"dsa_probs at {q.shape} on {k.shape} compiles in "
+          f"{limit >> 20} MiB")
+    assert 8 * 2**20 < limit < attention._VMEM_LIMIT
 
 
 # minicpm-sala-d4-1chip-steady (PR 48): b1, s16384. The minicpm4 layer's

@@ -304,26 +304,95 @@ def test_ordered_bits_order_as_the_floats_do():
 
 # -- what the indexer learns from ----------------------------------------------
 
-def _attention(seed=5, h=3, d=24):
+def _grouped_attention(h=8, hkv=2, d=24, seed=6):
     kq, kk, kv, ks = jax.random.split(jax.random.key(seed), 4)
     q = jax.random.normal(kq, (2, S, h, d))
-    k = jax.random.normal(kk, (2, S, h, d))
-    v = jax.random.normal(kv, (2, S, h, 16))
+    k = jax.random.normal(kk, (2, S, hkv, d))
+    v = jax.random.normal(kv, (2, S, hkv, 16))
     mask = dsa.selection_mask(jax.random.normal(ks, (2, S, S)), 40)
     _, lse = mha_reference_with_lse(q, k, v, select=mask)
     return q, k, lse, mask, d ** -0.5
 
 
+def _attention(seed=5, h=3, d=24):
+    return _grouped_attention(h, h, d, seed)
+
+
+# (heads, key heads): one head a key head, groups of 4 and 3 (a head
+# count the trip does not divide), the keye-vl cell's 32 on 4
+@pytest.mark.parametrize("h,hkv", [(4, 4), (8, 2), (6, 2), (32, 4)])
 @pytest.mark.parametrize("interpret", [False, True])
-def test_head_summed_probs(interpret, small_tiles):
-    q, k, lse, mask, scale = _attention()
+def test_head_summed_probs(interpret, h, hkv, small_tiles):
+    """The definition, query head ``j`` reading key head ``j // group``
+    where it lies: the same numbers as the XLA form on ``k`` repeated a
+    group's times."""
+    q, k, lse, mask, scale = _grouped_attention(h, hkv)
     got = dsa.head_summed_probs(q, k, lse, mask, scale, interpret=interpret)
-    logits = jnp.einsum("bthd,bshd->bhts", q, k) * scale
+    assert got.shape == (2, S, S) and got.dtype == jnp.float32
+    repeated = jnp.repeat(k, h // hkv, axis=2)
+    logits = jnp.einsum("bthd,bshd->bhts", q, repeated) * scale
     want = jnp.sum(jnp.where(
         (mask != 0)[:, None], jnp.exp(logits - lse[..., None]), 0.0), axis=1)
-    np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-5)
+    np.testing.assert_allclose(got, want, atol=2e-6 * h, rtol=2e-5)
+    np.testing.assert_allclose(
+        got, dsa._probs_xla(q, repeated, lse, mask, scale),
+        atol=2e-6, rtol=2e-5)
     # a head's probabilities sum to one over the selection
-    np.testing.assert_allclose(got.sum(-1), 3.0, rtol=1e-5)
+    np.testing.assert_allclose(got.sum(-1), h, rtol=1e-5)
+    assert not np.asarray(got)[np.asarray(mask) == 0].any()
+
+
+def test_probs_tiles_wholly_above_the_diagonal_are_zeros(small_tiles):
+    """A dead tile is written, never computed: under a mask of ones the
+    XLA form has every pair, the kernel the tiles a causal row reaches."""
+    q, k, lse, _, scale = _grouped_attention()
+    ones = jnp.ones((2, S, S), jnp.int8)
+    got = np.asarray(
+        dsa.head_summed_probs(q, k, lse, ones, scale, interpret=True))
+    want = np.asarray(dsa._probs_xla(q, k, lse, ones, scale))
+    assert want[:, :128, 128:].all() and not got[:, :128, 128:].any()
+    for rows, cols in ((0, 0), (1, 0), (1, 1)):
+        tile = np.s_[:, rows * 128:(rows + 1) * 128,
+                     cols * 128:(cols + 1) * 128]
+        np.testing.assert_allclose(got[tile], want[tile], rtol=2e-5)
+
+
+def test_the_probabilities_are_one_kernel_over_tiles(monkeypatch, small_tiles):
+    """The heads are a loop inside a (batch row, query block, key block)
+    grid step, not a grid axis."""
+    calls = []
+    real = dsa.pl.pallas_call
+
+    def spy(*a, **kw):
+        calls.append((kw.get("name"), kw.get("grid")))
+        return real(*a, **kw)
+
+    monkeypatch.setattr(dsa.pl, "pallas_call", spy)
+    q, k, lse, mask, scale = _grouped_attention()
+    dsa.head_summed_probs(q, k, lse, mask, scale, interpret=True)
+    assert calls == [("dsa_probs", (2, 2, 2))]
+
+
+@pytest.mark.parametrize("interpret", [False, True])
+@pytest.mark.parametrize("h,hkv,a_trip", [(8, 2, 8), (6, 2, 2), (32, 4, 8)])
+def test_the_gauge_has_the_heads_a_trip(interpret, h, hkv, a_trip,
+                                        small_tiles):
+    q, k, lse, mask, scale = _grouped_attention(h, hkv)
+    dsa.head_summed_probs(q, k, lse, mask, scale, interpret=interpret)
+    assert trace.gauges()["dsa.probs_heads_a_trip"] == (
+        a_trip if interpret else 0)
+
+
+@pytest.mark.parametrize("s,h,hkv,d,tiles", [
+    (16384, 32, 4, 128, (512, 512)),      # keye-vl: q 4 MiB a buffer
+    (8192, 32, 32, 192, (512, 512)),      # dots3: q and k 8 MiB each
+    (8192, 64, 64, 192, (256, 256)),      # twice the heads
+    (8192, 128, 128, 192, (128, 128)),
+    (256, 4, 4, 32, (256, 256)),
+    (200, 4, 2, 32, (200, 200)),          # 128 does not divide it
+])
+def test_probs_tiles_follow_the_shapes(s, h, hkv, d, tiles):
+    assert dsa._probs_tiles(s, h, hkv, d, 2) == tiles
 
 
 def test_head_summed_probs_are_constants(small_tiles):
@@ -442,30 +511,6 @@ def test_the_kept_gradient_carries_its_name():
 
 
 # -- grouped heads: 32 query heads on 4 key heads, 16 index heads of 64 ---------
-
-def _grouped_attention(h=8, hkv=2, d=24, seed=6):
-    kq, kk, kv, ks = jax.random.split(jax.random.key(seed), 4)
-    q = jax.random.normal(kq, (2, S, h, d))
-    k = jax.random.normal(kk, (2, S, hkv, d))
-    v = jax.random.normal(kv, (2, S, hkv, 16))
-    mask = dsa.selection_mask(jax.random.normal(ks, (2, S, S)), 40)
-    _, lse = mha_reference_with_lse(q, k, v, select=mask)
-    return q, k, lse, mask, d ** -0.5
-
-
-@pytest.mark.parametrize("interpret", [False, True])
-def test_grouped_head_summed_probs_are_the_repeated_keys(
-        interpret, small_tiles):
-    """Query head ``j`` reads key head ``j // group`` where it lies: the
-    same numbers as the call with ``k`` repeated a group's times."""
-    q, k, lse, mask, scale = _grouped_attention()
-    got = dsa.head_summed_probs(q, k, lse, mask, scale, interpret=interpret)
-    want = dsa.head_summed_probs(
-        q, jnp.repeat(k, q.shape[2] // k.shape[2], axis=2), lse, mask, scale,
-        interpret=interpret)
-    np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-5)
-    np.testing.assert_allclose(got.sum(-1), q.shape[2], rtol=1e-5)
-
 
 def test_heads_that_do_not_share_the_key_heads_evenly_are_refused():
     q, k, lse, mask, scale = _grouped_attention()

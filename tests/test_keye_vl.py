@@ -361,6 +361,8 @@ def test_the_select_kernel_changes_nothing_of_a_layer(
     assert trace.gauges()["dsa.select_kernel"] == 1
     assert trace.gauges()["attn.select_kernel"] == 1
     assert trace.gauges()["attn.index_bwd_kernels"] == 1
+    assert trace.gauges()["attn.probs_heads_a_trip"] == dsa._probs_trip(
+        cfg.n_heads)
     monkeypatch.setattr(dsa, "_select_rows", lambda s: None)
     (_, want), want_grads = run()
     assert trace.gauges()["dsa.select_kernel"] == 0
