@@ -257,21 +257,22 @@ def test_one_file_under_models_calls_jax_checkpoint():
     (r"attention\.KEPT|attn_ops\.KEPT", {
         "dots3.py", "qwen3_next.py", "xing4.py", "kimi_linear.py",
         "smallthinker.py", "minicpm_sala.py", "llama.py",
-        "granite_hybrid.py", "keye_vl.py", "laguna.py"}),
+        "granite_hybrid.py", "keye_vl.py", "laguna.py", "phi4flash.py"}),
     (r"kda\.KEPT", {"kimi_linear.py"}),
     (r"lightning\.KEPT", {"minicpm_sala.py"}),
     (r"ssd\.KEPT", {"granite_hybrid.py"}),
+    (r"selective_scan\.KEPT", {"phi4flash.py"}),
 ])
 def test_families_keep_a_forward_kernels_residuals_at_their_call_site(
         names, keepers):
     """The keep is each family's own choice where it calls `recompute`
     (its cell's planned peak has the room), not a rule of `stack.py` or
-    of the kernels: the flash forward's pair in ten files (Llama's
+    of the kernels: the flash forward's pair in eleven files (Llama's
     `_maybe_remat`, which `moe.py`'s layer goes through, keeps q, k, v
     beside it), the delta rule's in kimi's alone (qwen3next's step has
     not the room), the lightning rule's in minicpm_sala's, the
     state-space scan's in granite_hybrid's (where its configuration says
-    so); ViT names nothing."""
+    so), the selective scan's in phi4flash's; ViT names nothing."""
     sources = _sources()
     assert {name for name, text in sources.items()
             if re.search(names, text)} == keepers
@@ -279,7 +280,7 @@ def test_families_keep_a_forward_kernels_residuals_at_their_call_site(
             if re.search(r"\bKEPT\b", text)} == {
         "dots3.py", "qwen3_next.py", "xing4.py", "kimi_linear.py",
         "smallthinker.py", "minicpm_sala.py", "llama.py",
-        "granite_hybrid.py", "keye_vl.py", "laguna.py"}
+        "granite_hybrid.py", "keye_vl.py", "laguna.py", "phi4flash.py"}
     assert "KEPT" not in sources["stack.py"]
 
 
@@ -287,7 +288,7 @@ def test_no_family_walks_its_layers_or_shifts_its_targets_itself():
     sources = _sources()
     for name in ("kimi_linear.py", "smallthinker.py", "dots3.py",
                  "qwen3_next.py", "minicpm_sala.py", "granite_hybrid.py",
-                 "keye_vl.py", "laguna.py"):
+                 "keye_vl.py", "laguna.py", "phi4flash.py"):
         assert "lax.scan(" not in sources[name], name
     assert {name for name, text in sources.items()
             if "_shift_targets" in text} == {"llama.py"}
